@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test test-unit test-e2e test-stress bench run run-multi lint lint-acp \
+.PHONY: test test-unit test-e2e test-stress bench smoke run run-multi lint lint-acp \
 	chaos-smoke chaos-soak \
 	dryrun ci docker-build docker-run observability-up observability-down
 
@@ -34,6 +34,11 @@ test-e2e:
 test-stress:
 	ACP_STRESS=1 $(PY) -m pytest tests/e2e/test_tpu_provider.py -k test_64_concurrent_tasks_stress -x -q
 
+smoke:  ## does provider: tpu still start on the chip? (one TPU; exits non-zero without one)
+	$(PY) chip_smoke.py
+
+# requires the tpu backend: with no chip it exits non-zero and prints no
+# number. The cell matrix the driver records is ROADMAP A1, not this file.
 bench:
 	$(PY) bench.py
 

@@ -24,9 +24,13 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import logging
 import os
 import sys
+import threading
 import time
+
+log = logging.getLogger("acp_tpu.cli")
 
 DEFAULT_SERVER = os.environ.get("ACP_TPU_SERVER", "http://127.0.0.1:8082")
 
@@ -230,6 +234,34 @@ def _build_engine(args, coordination=None, **engine_kw):
     return Engine(config=args.tpu_preset, tokenizer=ByteTokenizer(), **kw)
 
 
+class EnginePrewarm(threading.Thread):
+    """Compile the serving programs in the background: the REST API comes
+    up immediately and early requests simply queue behind the same compiles
+    they would have caused.
+
+    A prewarm that raises — a program the chip's compiler refuses, a
+    program that does not fit the device — must not die silently while the
+    server stays up: the error is kept on ``error``, the engine is stopped
+    (requests then get 503, instead of each re-triggering the failure), and
+    ``on_failure`` runs (`acp-tpu run` uses it to exit non-zero)."""
+
+    def __init__(self, engine, on_failure=None):
+        super().__init__(name="tpu-prewarm", daemon=True)
+        self.engine = engine
+        self.error: BaseException | None = None
+        self._on_failure = on_failure
+
+    def run(self) -> None:
+        try:
+            self.engine.prewarm(constrained=True)
+        except Exception as e:
+            log.exception("engine prewarm failed; stopping the engine")
+            self.error = e
+            self.engine.stop()
+            if self._on_failure is not None:
+                self._on_failure()
+
+
 def cmd_engine_follower(args) -> int:
     """A non-zero rank of a multi-host serving cluster: joins the
     jax.distributed runtime, replays rank 0's admission frames, and serves
@@ -278,6 +310,7 @@ def cmd_run(args) -> int:
         print("error: --tpu-lora requires --tpu-checkpoint", file=sys.stderr)
         return 2
     engine = None
+    prewarm = None
     if args.tpu_preset or args.tpu_checkpoint:
         # multi-host serving: join the jax.distributed cluster (env-driven
         # no-op single-host); this leader process broadcasts admission
@@ -330,15 +363,14 @@ def cmd_run(args) -> int:
         engine = _build_engine(args, coordination)
         engine.start()
         if args.tpu_prewarm:
-            # background: the REST API comes up immediately; early requests
-            # simply queue behind the same compiles they would have caused
-            import threading
+            import signal
 
-            threading.Thread(
-                target=lambda: engine.prewarm(constrained=True),
-                name="tpu-prewarm",
-                daemon=True,
-            ).start()
+            # a failed prewarm ends the serve loop below (SIGTERM is what
+            # serve_until_signalled waits on) and the exit code says why
+            prewarm = EnginePrewarm(
+                engine, on_failure=lambda: os.kill(os.getpid(), signal.SIGTERM)
+            )
+            prewarm.start()
 
     if args.store and (args.db or args.serve_store):
         raise SystemExit("--store joins a remote store; --db/--serve-store "
@@ -390,6 +422,9 @@ def cmd_run(args) -> int:
         asyncio.run(main())
     except KeyboardInterrupt:
         pass
+    if prewarm is not None and prewarm.error is not None:
+        print(f"error: engine prewarm failed: {prewarm.error}", file=sys.stderr)
+        return 1
     return 0
 
 

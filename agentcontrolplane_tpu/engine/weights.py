@@ -396,15 +396,25 @@ def sharded_init(
     )
 
 
+# std of an integer uniform on [-127, 127]: sqrt((255**2 - 1) / 12)
+_UNIFORM_INT8_STD = 73.61
+
+
 def random_quantized_init(config: LlamaConfig, seed: int = 0) -> dict:
-    """Random int8 params built HOST-SIDE tensor-by-tensor (benchmarks).
+    """Random int8 params built HOST-SIDE tensor-by-tensor (benchmarks,
+    chip_smoke.py).
 
     The device-init-then-quantize path peaks at the full bf16 model plus
-    one tensor — 16GB for Llama-3-8B, which alone fills a v5e chip. This
-    mirrors the load-time quantization of ``params_from_state_dict``: each
-    quantizable matrix is generated and quantized in host RAM and only the
-    int8 values + f32 scales (plus the bf16 embeddings/norms/head) ever
-    reach the device. Same pytree layout as ``models.llama.init_params``."""
+    one tensor — 16GB for Llama-3-8B, which alone fills a v5e chip. Here
+    only the int8 values + f32 scales (plus the bf16 embeddings/norms/head)
+    ever reach the device. Same pytree layout as
+    ``models.llama.init_params``.
+
+    The int8 values are drawn directly — raw generator bits, uniform over
+    [-127, 127] — with one scale per output channel that gives the matrix
+    the variance of a ``fan_in**-0.5`` normal init. Drawing float normals
+    and quantizing them on the host is minutes of single-threaded CPU at
+    7B parameters, for weights that are random either way."""
     from ..ops.quant import QUANTIZABLE, QuantizedTensor
 
     c = config
@@ -420,8 +430,6 @@ def random_quantized_init(config: LlamaConfig, seed: int = 0) -> dict:
     schema = jax.eval_shape(lambda: init_params(c, jax.random.key(0)))
 
     def leaf(path, sds) -> Any:
-        from ..ops.quant import quantize_np
-
         name = str(path[-1].key)
         in_layers = len(path) >= 2 and str(path[-2].key) == "layers"
         shape = sds.shape
@@ -430,12 +438,18 @@ def random_quantized_init(config: LlamaConfig, seed: int = 0) -> dict:
         if name.startswith("b"):
             return put(np.zeros(shape, dtype=np.float32))
         fan_in = shape[-1] if name == "embed" else shape[-2]
-        stacked = rng.standard_normal(shape, dtype=np.float32) * fan_in**-0.5
         if in_layers and name in QUANTIZABLE:
-            q, qscale = quantize_np(stacked)
+            n = int(np.prod(shape))
+            q = rng.bit_generator.random_raw(-(-n // 8)).view(np.int8)[:n]
+            q = np.maximum(q, -127, out=q).reshape(shape)  # symmetric int8
+            qscale = np.full(
+                shape[:-2] + (1, shape[-1]),
+                fan_in**-0.5 / _UNIFORM_INT8_STD,
+                dtype=np.float32,
+            )
             return QuantizedTensor(
                 q=put(q, keep_dtype=True), scale=put(qscale, True)
             )
-        return put(stacked)
+        return put(rng.standard_normal(shape, dtype=np.float32) * fan_in**-0.5)
 
     return jax.tree_util.tree_map_with_path(leaf, schema)

@@ -1070,8 +1070,8 @@ def decode_step_paged(
         layer, k_kv, v_kv = scanned  # read-only (value + optional scales)
         k_pages_l, v_pages_l = k_kv[0], v_kv[0]
         # int8 pages carry f32 scale twins; the Pallas path DMAs them with
-        # each page fetch and dequantizes in VMEM (same formula as the
-        # reference, so the parity pin holds bit-for-bit in f32)
+        # each page fetch and applies them in VMEM (the same f32 math as
+        # the reference up to rounding order; parity pinned at 1e-5)
         k_scales_l = k_kv[1] if quantized else None
         v_scales_l = v_kv[1] if quantized else None
 

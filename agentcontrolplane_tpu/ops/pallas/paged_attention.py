@@ -19,23 +19,33 @@ Grid: one program per slot. Per-program working set is
 VMEM for Llama-3-8B geometry (page 16, 8 KV heads, d 128).
 
 Geometry note: the kernel targets head_dim % 128 == 0 (the TPU lane width;
-128 for llama/qwen/mistral, 256 for gemma — both validated compiled on
-hardware); the engine falls back to the XLA reference otherwise. Dots are
-expressed
-as multiply+reduce — a batched matvec (empty lhs non-contracting dims)
-trips a Mosaic TPU_DotDimensionNumbersAttr round-trip bug on real
-hardware, and at these shapes the MXU has nothing to offer over the VPU.
+128 for llama/qwen/mistral, 256 for gemma); the engine falls back to the
+XLA reference otherwise. The body is a static loop over the KV heads: each
+takes its lane-aligned ``[P, d]`` column window of the page buffer and two
+plain 2-D products with its ``[n_rep, d]`` query group. Every shape in the
+body is 2-D because that is what Mosaic lays out — the earlier grouped
+form (``p.reshape(P, H_kv, n_rep)[..., None] * v[:, :, None, :]``) passed
+every interpret-mode test and was refused by the chip's compiler at every
+head ratio ("infer-vector-layout: unsupported shape cast"), and a batched
+matvec trips a Mosaic dot-dimension bug. q and the (acc, m, l) outputs
+cross the kernel boundary grouped ``[S, H_kv, n_rep, .]`` for the same
+reason; the wrapper reshapes them outside.
 
 int8 page walk: with ``k_scales``/``v_scales`` (the allocator's per-row-
 per-head f32 scale twins, natural [num_pages, P, H_kv] layout) each page
-fetch also DMAs its scale rows on dedicated semaphore lanes and the body
-dequantizes in VMEM — ``value.astype(f32) * scale`` (exactly
-``ops.quant.kv_dequantize``), so quantized paged decode keeps the kernel
-path AND int8's HBM-bandwidth win: the f32 copy of a page only ever exists
-in VMEM scratch.
+fetch also DMAs its scale row on dedicated semaphore lanes. The per-row
+scale factors out of both products — ``q . (k_int8 * s) == (q . k_int8) *
+s`` — so the body scales the ``[n_rep, P]`` logits and softmax weights by
+the head's ``[1, P]`` scale row and never builds a dequantized page; the
+pool stays int8 in HBM and only int8 bytes cross to VMEM. The scale rows
+reach the kernel head-major and padded to whole 128-lane rows (built by
+XLA outside the kernel): Mosaic cannot slice an HBM operand whose minor
+dim is under a lane tile.
 
-Tested in interpreter mode on CPU against the exact reference; runs compiled
-on TPU (tests/engine/test_tpu_hardware.py).
+Tested in interpreter mode on CPU against the exact reference
+(tests/engine/test_paged*.py), compiled for a described v5e
+(tests/engine/test_chip_compile.py), and run compiled on the chip against
+the reference (chip_smoke.py, tests/engine/test_tpu_hardware.py).
 """
 
 from __future__ import annotations
@@ -47,10 +57,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ... import compat as _compat  # noqa: F401  (installs jax.shard_map on old jax)
-
 NEG_INF = -1e30
 NBUF = 4  # DMA pipeline depth: NBUF-1 page fetches kept in flight per walk
+# Both in-kernel products run at f32 contract precision: the walk is the
+# same f32 math as the XLA reference, not a bf16-pass approximation of it
+# (the operands are tiny — [n_rep, d] x [d, P] — so the MXU passes are free).
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(
@@ -59,32 +71,30 @@ def _kernel(
     seq_lens_ref,  # [S] int32 (SMEM)
     pos_base_ref,  # [1] int32 (SMEM) — this rank's within-page offset
     # inputs
-    q_ref,  # [1, H, d] (VMEM) — this program's slot
+    q_ref,  # [1, H_kv, n_rep, d] (VMEM) — this program's slot, grouped by KV head
     k_pages_ref,  # [num_pages, P_local, H_kv * d] (HBM/ANY)
     v_pages_ref,  # [num_pages, P_local, H_kv * d]
     # quantized=True only: ks_pages_ref / vs_pages_ref
-    #   [num_pages, P_local, H_kv] f32 (HBM/ANY) — per-row-per-head scales
-    # outputs
-    # acc_ref: [1, H, d] f32 — unnormalized weighted V sum
-    # m_ref:   [1, 1, H] f32 — running max (unit middle dim: TPU block shapes
-    # l_ref:   [1, 1, H] f32 — need the trailing dims to tile or match)
+    #   [num_pages, 1, SC] f32 (HBM/ANY) — a page's per-row-per-head
+    #   scales, head-major ([H_kv, P_local] flattened), lane-padded to SC
+    # outputs, grouped by KV head like q:
+    # acc_ref: [1, H_kv, n_rep, d] f32 — unnormalized weighted V sum
+    # m_ref:   [1, H_kv, n_rep, 1] f32 — running max
+    # l_ref:   [1, H_kv, n_rep, 1] f32 — running denominator
     # scratch
     # k_buf / v_buf: [NBUF, P_local, H_kv * d] (VMEM)
-    # quantized=True only: ks_buf / vs_buf [NBUF, P_local, H_kv] f32 (VMEM)
+    # quantized=True only: ks_buf / vs_buf [NBUF, 1, SC] f32 (VMEM)
     # sems: DMA sems [NBUF, 4 if quantized else 2]
     *rest,
     page_size: int,  # GLOBAL page size (pages hold this many tokens)
-    n_kv_heads: int,
-    head_dim: int,
-    max_pages: int,
     quantized: bool = False,
 ):
     # int8 walk (quantized=True): pages hold int8 values plus f32 scale
-    # twins ([.., P, H_kv], one scale per row per KV head). The fetch loop
-    # DMAs the scale rows alongside the pages on their own semaphore lanes
-    # and the body dequantizes in VMEM — value * scale, identical to
-    # ops.quant.kv_dequantize — so int8 decode takes the kernel path with
-    # the same (acc, m, l) contract as the f32 walk.
+    # twins (one scale per row per KV head). The fetch loop DMAs each
+    # page's scale row alongside it on its own semaphore lanes and the body
+    # applies the scales in VMEM (see the KV-head loop), so int8 decode
+    # takes the kernel path with the same (acc, m, l) contract as the f32
+    # walk.
     if quantized:
         (ks_pages_ref, vs_pages_ref, acc_ref, m_ref, l_ref,
          k_buf, v_buf, ks_buf, vs_buf, sems) = rest
@@ -99,15 +109,14 @@ def _kernel(
     s = pl.program_id(0)
     seq_len = seq_lens_ref[s]
     n_pages = jax.lax.div(seq_len + page_size - 1, page_size)
-    H = q_ref.shape[1]
-    n_rep = H // n_kv_heads
-    d = head_dim
+    _, n_kv_heads, n_rep, d = q_ref.shape
     P = k_pages_ref.shape[1]  # local slice length
     pos_base = pos_base_ref[0]
     NBUF = k_buf.shape[0]
 
-    q = q_ref[0].astype(jnp.float32)  # [H, d]
     scale = 1.0 / (d**0.5)
+    # one [n_rep, d] query group per KV head, pre-scaled once
+    qs = [q_ref[0, h].astype(jnp.float32) * scale for h in range(n_kv_heads)]
 
     def start_fetch(j, slot):
         page = block_tables_ref[s, j]
@@ -136,7 +145,6 @@ def _kernel(
     jax.lax.fori_loop(0, NBUF - 1, ramp, 0)
 
     def body(j, carry):
-        m, l, acc = carry  # [1,H], [1,H], [1,H,d] running online-softmax state
         slot = jax.lax.rem(j, NBUF)
         # issue the deepest prefetch; its buffer was consumed at j-1
         nxt = j + NBUF - 1
@@ -146,44 +154,60 @@ def _kernel(
             start_fetch(nxt, jax.lax.rem(nxt, NBUF))
 
         wait_fetch(j, slot)
-        # grouped GQA compute: keep K/V at [P, H_kv, d] and fold the repeat
-        # into a reshape of q/p — no [P, H, d] repeated materialization
-        k = k_buf[slot].reshape(P, n_kv_heads, d).astype(jnp.float32)
-        v = v_buf[slot].reshape(P, n_kv_heads, d).astype(jnp.float32)
-        if quantized:
-            # dequantize in VMEM: value * per-row-per-head scale, exactly
-            # kv_dequantize — masked rows (stale scales incl. TRASH_PAGE)
-            # stay finite, so the pos mask zeroes their weight as in f32
-            k = k * ks_buf[slot].reshape(P, n_kv_heads, 1)
-            v = v * vs_buf[slot].reshape(P, n_kv_heads, 1)
-        qg = q.reshape(n_kv_heads, n_rep, d)
-        # logits via multiply+reduce, NOT dot_general (see module doc)
-        logits = (
-            jnp.sum(qg[None] * k[:, :, None, :], axis=-1).reshape(P, H) * scale
-        )  # [P, H]
         pos = (
             j * page_size + pos_base
-            + jax.lax.broadcasted_iota(jnp.int32, (P, 1), 0)
+            + jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
         )
-        logits = jnp.where(pos < seq_len, logits, NEG_INF)
+        valid = pos < seq_len  # [1, P]
+        if quantized:
+            ks = ks_buf[slot]  # [1, >= H_kv * P], head-major
+            vs = vs_buf[slot]
+        out = []
+        # Static loop over the KV heads. Each takes its lane-aligned [P, d]
+        # column window of the page buffer (d % 128 == 0) and two plain 2-D
+        # products with its [n_rep, d] query group — the only shapes in the
+        # body are 2-D, which is what Mosaic lays out (a 4-D grouped
+        # reshape of the logits is refused: "unsupported shape cast").
+        for h in range(n_kv_heads):
+            m, l, acc = carry[h]  # [n_rep,1], [n_rep,1], [n_rep,d]
+            k = k_buf[slot, :, h * d:(h + 1) * d].astype(jnp.float32)  # [P, d]
+            v = v_buf[slot, :, h * d:(h + 1) * d].astype(jnp.float32)
+            logits = jax.lax.dot_general(
+                qs[h], k, (((1,), (1,)), ((), ())),
+                precision=_F32, preferred_element_type=jnp.float32,
+            )  # [n_rep, P]
+            if quantized:
+                # the per-row scale factors out of both products: scale the
+                # [n_rep, P] logits and weights by this head's [1, P] scale
+                # row, never the [P, d] page. Masked rows (stale scales
+                # incl. TRASH_PAGE) stay finite, so the pos mask zeroes
+                # their weight as in f32.
+                logits = logits * ks[:, h * P:(h + 1) * P]
+            logits = jnp.where(valid, logits, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
+            p = jnp.exp(logits - m_new)  # [n_rep, P]
+            correction = jnp.exp(m - m_new)  # [n_rep, 1]
+            l = l * correction + jnp.sum(p, axis=1, keepdims=True)
+            pw = p * vs[:, h * P:(h + 1) * P] if quantized else p
+            pv = jnp.dot(
+                pw, v, precision=_F32, preferred_element_type=jnp.float32
+            )  # [n_rep, d]
+            out.append((m_new, l, acc * correction + pv))
+        return tuple(out)
 
-        m_blk = jnp.max(logits, axis=0, keepdims=True)  # [1,H]
-        m_new = jnp.maximum(m, m_blk)
-        p = jnp.exp(logits - m_new)  # [P,H]
-        correction = jnp.exp(m - m_new)  # [1,H]
-        l = l * correction + jnp.sum(p, axis=0, keepdims=True)
-        pg = p.reshape(P, n_kv_heads, n_rep)
-        pv = jnp.sum(pg[..., None] * v[:, :, None, :], axis=0).reshape(1, H, d)
-        acc = acc * correction[:, :, None] + pv
-        return m_new, l, acc
-
-    m0 = jnp.full((1, H), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((1, H), dtype=jnp.float32)
-    acc0 = jnp.zeros((1, H, d), dtype=jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
-    acc_ref[0] = acc[0]
-    m_ref[0] = m
-    l_ref[0] = l
+    init = tuple(
+        (
+            jnp.full((n_rep, 1), NEG_INF, dtype=jnp.float32),
+            jnp.zeros((n_rep, 1), dtype=jnp.float32),
+            jnp.zeros((n_rep, d), dtype=jnp.float32),
+        )
+        for _ in range(n_kv_heads)
+    )
+    state = jax.lax.fori_loop(0, n_pages, body, init)
+    for h, (m, l, acc) in enumerate(state):
+        acc_ref[0, h] = acc
+        m_ref[0, h] = m
+        l_ref[0, h] = l
 
 
 def _paged_state(
@@ -200,14 +224,13 @@ def _paged_state(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the kernel -> unnormalized (acc [S,H,d] f32, m [S,H], l [S,H]).
 
-    With ``k_scales``/``v_scales`` the pages are int8 and the kernel DMAs
-    the scale rows alongside each page fetch (natural [num_pages, P, H_kv]
-    layout — no lane padding; the transfers are small and strided, which
-    Mosaic handles, and the VMEM dequant keeps int8's HBM-bandwidth win).
+    With ``k_scales``/``v_scales`` (natural [num_pages, P, H_kv] layout)
+    the pages are int8 and the kernel DMAs each page's scale row alongside
+    the page fetch; applying them in VMEM keeps int8's HBM-bandwidth win.
     """
     S, H, d = q.shape
     num_pages, P, H_kv, _ = k_pages.shape
-    max_pages = block_tables.shape[1]
+    n_rep = H // H_kv
     if pos_base is None:
         pos_base = jnp.zeros((1,), dtype=jnp.int32)
     quantized = k_scales is not None
@@ -215,13 +238,18 @@ def _paged_state(
     kernel = functools.partial(
         _kernel,
         page_size=global_page_size or P,
-        n_kv_heads=H_kv,
-        head_dim=d,
-        max_pages=max_pages,
         quantized=quantized,
     )
+
+    def per_slot(*tail):
+        # one slot per program; trailing dims are whole, so they tile
+        return pl.BlockSpec(
+            (1, H_kv, n_rep) + tail, lambda s, *_: (s, 0, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+
     in_specs = [
-        pl.BlockSpec((1, H, d), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM),
+        per_slot(d),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -233,7 +261,7 @@ def _paged_state(
         block_tables,
         seq_lens,
         pos_base.astype(jnp.int32),
-        q,
+        q.reshape(S, H_kv, n_rep, d),
         k_pages.reshape(num_pages, P, H_kv * d),
         v_pages.reshape(num_pages, P, H_kv * d),
     ]
@@ -242,37 +270,43 @@ def _paged_state(
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
+        # A page's scale rows reach the kernel head-major and padded to
+        # whole 128-lane rows, [num_pages, 1, SC]: Mosaic cannot slice an
+        # HBM operand whose minor dim is under a lane tile, and the body
+        # wants each head's scales as a [1, P] row.
+        SC = -(-H_kv * P // 128) * 128
+
+        def scale_rows(scales):
+            rows = scales.astype(jnp.float32).transpose(0, 2, 1).reshape(
+                num_pages, 1, H_kv * P
+            )
+            return jnp.pad(rows, ((0, 0), (0, 0), (0, SC - H_kv * P)))
+
         scratch_shapes += [
-            pltpu.VMEM((NBUF, P, H_kv), jnp.float32),
-            pltpu.VMEM((NBUF, P, H_kv), jnp.float32),
+            pltpu.VMEM((NBUF, 1, SC), jnp.float32),
+            pltpu.VMEM((NBUF, 1, SC), jnp.float32),
         ]
-        operands += [
-            k_scales.astype(jnp.float32),
-            v_scales.astype(jnp.float32),
-        ]
+        operands += [scale_rows(k_scales), scale_rows(v_scales)]
     scratch_shapes.append(pltpu.SemaphoreType.DMA((NBUF, 4 if quantized else 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, H, d), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, H), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, H), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM),
-        ],
+        out_specs=[per_slot(d), per_slot(1), per_slot(1)],
         scratch_shapes=scratch_shapes,
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((S, H, d), jnp.float32),
-            jax.ShapeDtypeStruct((S, 1, H), jnp.float32),
-            jax.ShapeDtypeStruct((S, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((S, H_kv, n_rep, d), jnp.float32),
+            jax.ShapeDtypeStruct((S, H_kv, n_rep, 1), jnp.float32),
+            jax.ShapeDtypeStruct((S, H_kv, n_rep, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_page_walk",
     )(*operands)
-    return acc, m[:, 0], l[:, 0]
+    return acc.reshape(S, H, d), m.reshape(S, H), l.reshape(S, H)
 
 
 def paged_decode_attention(

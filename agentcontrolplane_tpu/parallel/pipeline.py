@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat as _compat  # noqa: F401  (installs jax.shard_map on old jax)
 from ..models.llama import LlamaConfig, _attn_mlp, _embed, _final_norm_w, _head_logits
 from ..ops.attention import causal_attention
 from ..ops.norms import rms_norm

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import compat as _compat  # noqa: F401  (installs jax.shard_map on old jax)
-
 from ..ops.attention import repeat_kv
 
 NEG_INF = -1e30

@@ -1,0 +1,148 @@
+"""Compile the main path's kernels for a DESCRIBED v5e — no chip attached.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+topology that is described, not attached; it raises what the chip's
+compiler would raise. Interpret mode cannot: the page walk passed every
+interpret-mode test while Mosaic refused it at every real geometry
+("unsupported shape cast" on the grouped 4-D reshape; scale-row DMAs under
+a lane tile). These cases guard that at no chip time, ~2 s each.
+
+A compile that passes is not a chip run: nothing executes here.
+`chip_smoke.py` and `tests/engine/test_tpu_hardware.py` run the same
+kernels on the device against the reference.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from agentcontrolplane_tpu.models.llama import PRESETS
+
+PAGE, SLOTS, MAX_PAGES, NUM_PAGES = 16, 8, 16, 256
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four described v5e devices, with the persistent compile cache off
+    around the module: a compile for an unattached chip is written to the
+    cache but cannot be read back without one (the next run would warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _walk_args(sharding, H, H_kv, d, dtype, int8):
+    """Abstract operands of the serving hot-path form (read-only pages +
+    the new token's self term), every one placed by ``sharding(spec)``."""
+
+    def sds(shape, dt, *spec):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding(P(*spec)))
+
+    pages = sds(
+        (NUM_PAGES, PAGE, H_kv, d), jnp.int8 if int8 else dtype,
+        None, None, "tp", None,
+    )
+    new = sds((SLOTS, H_kv, d), dtype, None, "tp", None)
+    args = [
+        sds((SLOTS, H, d), dtype, None, "tp", None),
+        pages, pages,
+        sds((SLOTS, MAX_PAGES), jnp.int32),
+        sds((SLOTS,), jnp.int32),
+        new, new,
+    ]
+    if int8:
+        scales = sds((NUM_PAGES, PAGE, H_kv), jnp.float32, None, None, "tp")
+        args += [scales, scales]
+    return args
+
+
+def _compile_walk(v5e, H, H_kv, d, dtype, int8, tp=1):
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    if tp == 1:
+        one_chip = SingleDeviceSharding(v5e[0])
+        args = _walk_args(lambda spec: one_chip, H, H_kv, d, dtype, int8)
+        fn = pa.paged_decode_attention_cache_plus_new
+    else:
+        mesh = Mesh(v5e[:tp], ("tp",))
+        args = _walk_args(
+            lambda spec: NamedSharding(mesh, spec), H, H_kv, d, dtype, int8
+        )
+        fn = lambda *a, **kw: pa.paged_decode_attention_cache_plus_new_sharded(  # noqa: E731
+            mesh, *a, **kw
+        )
+    if int8:
+        call = lambda *a: fn(*a[:7], k_scales=a[7], v_scales=a[8])  # noqa: E731
+    else:
+        call = fn
+    return jax.jit(call).lower(*args).compile()
+
+
+def _compile_slot_decode_step(v5e):
+    """`models.llama.decode_step` (the CLI-default slot layout) at
+    Qwen2.5-7B widths and depth with int8 weights, 8 slots x 1,024 ctx."""
+    from agentcontrolplane_tpu.models.llama import decode_step, init_kv_cache, init_params
+    from agentcontrolplane_tpu.ops.quant import quantize_params
+
+    c = PRESETS["qwen2.5-7b"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree
+    )
+    params = place(jax.eval_shape(
+        lambda: quantize_params(init_params(c, jax.random.key(0)))
+    ))
+    cache = place(jax.eval_shape(lambda: init_kv_cache(c, SLOTS, 1024)))
+    vec = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    return jax.jit(
+        lambda p, kv, tok, lens: decode_step(p, kv, tok, lens, c)
+    ).lower(params, cache, vec, vec).compile()
+
+
+# (id, query heads, KV heads, head_dim, dtype, int8 pages, tp)
+_WALKS = [
+    ("walk-qwen2.5-7b-bf16", 28, 4, 128, jnp.bfloat16, False, 1),
+    ("walk-qwen2.5-7b-int8", 28, 4, 128, jnp.bfloat16, True, 1),
+    ("walk-llama3-8b-bf16", 32, 8, 128, jnp.bfloat16, False, 1),
+    ("walk-gemma-2b-mqa", 8, 1, 256, jnp.bfloat16, False, 1),
+    ("walk-mha-f32", 8, 8, 128, jnp.float32, False, 1),
+    # one KV head per chip: the divisibility edge of the shard_map wrapper
+    ("walk-qwen2.5-7b-bf16-tp4", 28, 4, 128, jnp.bfloat16, False, 4),
+    ("walk-qwen2.5-7b-int8-tp4", 28, 4, 128, jnp.bfloat16, True, 4),
+]
+
+
+@pytest.mark.parametrize(
+    "case", _WALKS + [("slot-decode-step-qwen2.5-7b-int8w",)], ids=lambda c: c[0]
+)
+def test_compiles_for_described_v5e(v5e, case):
+    if len(case) == 1:
+        compiled = _compile_slot_decode_step(v5e)
+        # the slot layout has no kernel; it must fit one chip's 16 GB
+        mem = compiled.memory_analysis()
+        resident = (
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+        )
+        assert resident < 16e9, f"slot decode step needs {resident / 1e9:.1f} GB"
+        return
+    _, H, H_kv, d, dtype, int8, tp = case
+    text = _compile_walk(v5e, H, H_kv, d, dtype, int8, tp).as_text()
+    assert "tpu_custom_call" in text, "the Pallas page walk is not in the program"

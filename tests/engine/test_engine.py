@@ -125,6 +125,47 @@ def test_cancel_frees_slot_and_waiting_request(engine):
     assert engine.stats()["active_slots"] == 0
 
 
+def test_deterministic_crash_does_not_restart_forever():
+    """A failure that repeats before any request finishes (on a chip: a
+    program the compiler refuses) must surface as the request's error and
+    then leave the engine DOWN — not loop crash -> ensure_running restart,
+    one lap per request."""
+    from agentcontrolplane_tpu.observability.metrics import REGISTRY
+
+    def restarts():
+        m = REGISTRY._metrics.get("acp_engine_restarts_total")
+        return 0.0 if m is None else m.values.get((), 0.0)
+
+    eng = Engine(
+        config=dataclasses.replace(PRESETS["tiny"], vocab_size=512),
+        tokenizer=TOK,
+        mesh=jax.sharding.Mesh(jax.devices()[:1], ("tp",)),
+        max_slots=2, max_ctx=128, prefill_buckets=(64, 128),
+    )
+
+    def refuse(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: synthetic")
+
+    eng._jit_decode = refuse  # survives restarts: ensure_running keeps programs
+    eng.start()
+    try:
+        before = restarts()
+        for lap in range(2):
+            fut = eng.submit("x", SamplingParams(temperature=0.0, max_tokens=6))
+            with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+                fut.result(timeout=60)  # the compiler's message reaches the caller
+            if eng._thread is not None:
+                eng._thread.join(timeout=30)
+            if lap == 0:
+                assert eng.ensure_running() is True  # one honest retry
+        # second crash with no finished request in between: final
+        assert eng._crashed is False
+        assert eng.ensure_running() is False
+        assert restarts() == before + 1
+    finally:
+        eng.stop()
+
+
 def test_engine_crash_recovery():
     """Failure recovery for the data plane: a crashed engine loop is
     rebuilt (fresh KV/slot state, params kept) by ensure_running(); in-flight

@@ -245,3 +245,30 @@ def test_pallas_cache_plus_new_sp_sharded_interpret():
             np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5,
             err_msg=str(axes),
         )
+
+
+@pytest.mark.parametrize(
+    "dtype,int8,plus_new",
+    [
+        ("float32", False, False),
+        ("float32", True, True),
+        ("bfloat16", False, True),
+        ("bfloat16", True, True),
+    ],
+)
+def test_shared_parity_helper_interpret(dtype, int8, plus_new):
+    """The helper chip_smoke.py and test_tpu_hardware.py run compiled on
+    the chip, here in interpret mode: seeded ragged pages, kernel vs
+    reference, judged against the dtype's tolerance."""
+    from agentcontrolplane_tpu.engine.kernel_parity import (
+        make_paged_case,
+        page_walk_parity,
+    )
+
+    case = make_paged_case(
+        11, S=3, H=4, H_kv=2, d=8, P=4, max_pages=4, num_pages=32,
+        dtype=jnp.dtype(dtype), int8=int8,
+    )
+    got = page_walk_parity(case, plus_new=plus_new, interpret=True)
+    assert got["ok"], got
+    assert got["shape"] == (3, 4, 8) and len(got["seq_lens"]) == 3

@@ -1,10 +1,11 @@
 """int8 Pallas page walk (interpreter mode) vs the XLA dequant reference.
 
-The kernel DMAs int8 pages plus their f32 scale rows and dequantizes in
-VMEM with the exact ``kv_dequantize`` formula — so against the reference
-(which dequantizes after the per-slot gather) the two paths compute the
-same f32 math and the pin is the usual 1e-5, not a loose quantization
-tolerance. Covers both entry forms, both sharded wrappers, scale-row
+The kernel DMAs int8 pages plus their f32 scale rows and applies the
+scales in VMEM (factored out of both products: ``(q . k_int8) * s``) — so
+against the reference (which dequantizes after the per-slot gather) the
+two paths compute the same f32 math up to rounding order and the pin is
+the usual 1e-5, not a loose quantization tolerance. Covers both entry
+forms, both sharded wrappers, scale-row
 alignment edges (mid-page seq_lens, exact page boundaries, single-token
 rows) and TRASH_PAGE / tail-row masking with poisoned scales.
 """
@@ -91,7 +92,7 @@ def test_int8_cache_plus_new_matches_reference_interpret():
 
 
 def test_int8_walk_scale_row_alignment_edges():
-    """Scale rows are [num_pages, P, H_kv] — NOT lane-padded — so the edge
+    """Each page's scale row is fetched whole with the page, so the edge
     cases are sequence lengths that end mid-page, exactly on a page
     boundary, and a single-token row (the first fetch is also the last)."""
     base = _setup(seed=7, S=3, H=4, Hkv=2, d=8, P=4, max_pages=6, num_pages=32)

@@ -35,8 +35,7 @@ def test_ring_attention_matches_dense():
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
     dense = causal_attention(q, k, v, positions)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else _nullcontext():
-        ring = ring_causal_attention(mesh, q, k, v, positions)
+    ring = ring_causal_attention(mesh, q, k, v, positions)
     np.testing.assert_allclose(np.asarray(ring), np.asarray(dense), rtol=1e-5, atol=1e-5)
 
 
@@ -116,14 +115,6 @@ def test_train_step_sequence_parallel_matches_dense():
     np.testing.assert_allclose(
         np.asarray(p_sp["norm"]), np.asarray(p_dn["norm"]), rtol=1e-4, atol=1e-5
     )
-
-
-class _nullcontext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False
 
 
 def test_ring_attention_gradients_match_dense():
