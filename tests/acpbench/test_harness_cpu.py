@@ -1,0 +1,96 @@
+"""The harness end to end on the CPU at a tiny size: weights, engine,
+warm-up, ramp, window, drain, readers, output check. One chip's path and
+the four-chip path on virtual devices. Nothing here is a device metric:
+the command itself refuses a CPU (test_run_refuses_cpu.py)."""
+
+import os
+
+import jax
+import pytest
+
+from acpbench import run as runner
+from acpbench import spec
+from acpbench.systems.engine import CompileCounter, System
+
+DATA = os.path.join(os.path.dirname(__file__), "data")
+
+
+BENCH = spec.benchmark()
+CASES = [
+    pytest.param(("tiny-open", "q7b-chat-mixed", 1), id="tiny-open-tp1"),
+    pytest.param(("tiny-closed", "q32b-tp4-decode", 4), id="tiny-closed-tp4"),
+]
+
+
+@pytest.fixture(scope="module", params=CASES)
+def rehearsal(request):
+    mix, workload, tp = request.param
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} (virtual) devices")
+    config = spec.load_json(os.path.join(DATA, "tiny-config.json"))
+    config["engine"]["tensor_parallelism"] = tp
+    cell = {"workload": {"name": workload, "chips": tp}, "config": config,
+            "mix": spec.load_json(os.path.join(DATA, mix + ".json"))}
+    counter = CompileCounter()
+    system = System(config, 2**31 + 11)
+    try:
+        runner.warm_up(system, cell, 5, counter)
+        before = counter.count
+        run = runner.measure(system, cell, 5, 2.0, False, "")
+        run.setup_s, run.device_kind = 1.0, jax.devices()[0].device_kind
+        compiled = counter.count - before
+        check = runner.output_check(system, cell, 5)
+    finally:
+        system.stop()
+    return run, compiled, check
+
+
+def test_nothing_compiles_after_the_warm_up(rehearsal):
+    assert rehearsal[1] == 0
+
+
+def test_requests_are_counted_and_none_fails(rehearsal):
+    run = rehearsal[0]
+    attempted, failed = runner.count_requests(run)
+    assert attempted >= 3 and failed == 0
+    assert all(r.error is None for r in run.records if not r.censored)
+    assert set(run.stats) >= {"open", "close"}
+
+
+def test_no_answer_is_cut_short_by_a_stop_token(rehearsal):
+    """With `ignore_stop_tokens` the seed cannot change the work: at a
+    vocabulary of 512 a sampled answer would else meet one of the byte
+    tokenizer's two stop tokens about once in 256 tokens."""
+    ended = [r for r in rehearsal[0].records if r.end_t is not None and not r.censored]
+    assert ended and all(r.finish == "length" and r.n_tokens == r.max_tokens for r in ended)
+
+
+def test_the_cells_end_to_end_metrics_are_read(rehearsal):
+    run = rehearsal[0]
+    got = runner.read_metrics(BENCH, "end_to_end", run)
+    want = {m["name"] for m in spec.metrics_for(BENCH, run.cell["workload"]["name"], "end_to_end")}
+    assert set(got) == want
+    assert all(v["value"] > 0 for v in got.values())
+
+
+def test_counters_are_read_and_device_metrics_are_not(rehearsal):
+    run = rehearsal[0]
+    got = runner.read_metrics(BENCH, "per_layer", run)
+    per_layer = {m["name"]: m for m in spec.metrics_for(BENCH, run.cell["workload"]["name"], "per_layer")}
+    for name, m in per_layer.items():
+        if m["source"] == "device_trace":
+            assert name not in got  # no trace on a CPU: the reader finds nothing and says nothing
+        else:
+            assert name in got
+    if "batch_occupancy" in got:
+        assert 0 < got["batch_occupancy"]["value"] <= 100
+
+
+def test_outputs_agree_with_the_reference(rehearsal):
+    ok, lines = rehearsal[2]
+    assert ok, lines
+    assert any(line.startswith("logit_rel_rms=") and "limit=" in line for line in lines)
+    assert any(line.startswith("cache_excess=") for line in lines)
+    # the cell's own path: greedy requests through submit, every token looked up in the reference
+    assert any(line.startswith("greedy_regret=") and "limit=" in line for line in lines)
+    assert any(line.startswith("stream_mismatch=0 limit=0 ok") for line in lines)
