@@ -1,0 +1,9 @@
+"""Scheduler: milliseconds a decode block in which the chip sat idle while
+the engine thread was in `acp.commit` (consuming tokens, running the callers' callbacks, handing results over), from the
+program's spans on the trace's clock (host_spans.py)."""
+
+from .. import host_spans
+
+
+def read(run):
+    return host_spans.idle_ms_per_block(run, "commit")

@@ -792,8 +792,9 @@ def cmd_perf(args) -> int:
     """Compute efficiency observatory: per-program dispatch telemetry
     (where device time goes, how much of each dispatch is padding), the
     cold-compile observatory (compiles real traffic paid for after
-    prewarm), and the goodput/waste ledger (tokens computed vs emitted,
-    waste attributed by cause)."""
+    prewarm), the goodput/waste ledger (tokens computed vs emitted,
+    waste attributed by cause), and the engine loop's phase table (host
+    ms per cycle by phase)."""
     with _client(args) as http:
         resp = http.get("/v1/engine/perf")
         if resp.status_code != 200:
@@ -823,6 +824,18 @@ def cmd_perf(args) -> int:
                   "(each was a latency stall — widen prewarm coverage)")
             for ev in cold.get("events", []):
                 print(f"  {ev['program']:<34}{ev['wall_s'] * 1e3:>10.1f}ms")
+        phases = doc.get("phases", {})
+        cycles = doc.get("cycles", 0)
+        busy = sum(p["s"] for name, p in phases.items() if name != "park")
+        if phases and cycles and busy:
+            # the engine loop's own phases: self time, so the rows partition
+            # the loop's busy time (park = waiting on an empty queue)
+            print(f"engine loop: {cycles} busy cycles, "
+                  f"{doc.get('blocks', 0)} decode blocks")
+            print(f"{'PHASE':<10}{'N':>9}{'ms/CYCLE':>11}{'SHARE':>8}")
+            for name, p in sorted(phases.items(), key=lambda kv: -kv[1]["s"]):
+                share = f"{p['s'] / busy:>8.1%}" if name != "park" else f"{'-':>8}"
+                print(f"{name:<10}{p['n']:>9}{p['s'] * 1e3 / cycles:>11.3f}{share}")
         programs = doc.get("programs", {})
         if programs:
             print(f"{'PROGRAM':<34}{'N':>7}{'HOST ms':>10}{'DEV ms':>10}"

@@ -28,6 +28,7 @@ lease layer never serializes the engine.
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import heapq
 import logging
@@ -66,6 +67,21 @@ log = logging.getLogger("acp_tpu.engine")
 # consecutive crashes, with no request finished in between, after which the
 # engine stops being restartable (ensure_running returns False)
 _CRASH_LOOP_LIMIT = 2
+
+
+def _in_phase(name: str):
+    """Run an engine-thread method inside the profiler's ``name`` phase
+    (observability/profiler.py): for methods that are one phase whole."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(self, *args, **kwargs):
+            with self.profiler.phase(name):
+                return fn(self, *args, **kwargs)
+
+        return inner
+
+    return wrap
 
 
 class EngineOverloadedError(RuntimeError):
@@ -795,6 +811,10 @@ class Engine:
         self._prefilling_count = 0  # acp: mirror — int mirror for cross-thread stats()
         self.prefill_chunks = 0  # chunk dispatches (per-slot chunks)
         self.hol_wait_s = 0.0  # decode-stall seconds attributable to prefill
+        # enqueue -> first admission, summed over requests (prewarm and
+        # resumes excluded), and how many: the scheduler's queue wait
+        self.queue_wait_s = 0.0
+        self.queue_admits = 0
         # (budget, tokens spent) last cycle — replaced atomically as a whole
         # tuple, never mutated in place, so scrape reads are torn-free
         self._budget_last = (0, 0)  # acp: mirror
@@ -1934,6 +1954,9 @@ class Engine:
                 "token_budget": self.token_budget,  # 0 = auto-sized
                 "prefill_chunks_total": self.prefill_chunks,
                 "hol_wait_seconds": round(self.hol_wait_s, 4),
+                "queue_wait": {
+                    "s": round(self.queue_wait_s, 6), "n": self.queue_admits,
+                },
                 "budget_utilization_last": (
                     round(min(1.0, self._budget_last[1] / self._budget_last[0]), 4)
                     if self._budget_last[0] else 0.0
@@ -2062,7 +2085,15 @@ class Engine:
     def _run(self) -> None:  # acp: idle-loop
         try:
             while not self._stopping:
-                admitted = self._admit(block=not self._has_work())
+                # a new iteration: the profiler closes the cycle of the one
+                # before and opens this one's once it has work (slots to
+                # advance or requests to admit on entry, or a request that
+                # arrives while _admit is parked)
+                busy = self._has_work()
+                self.profiler.cycle(
+                    busy or (bool(self._waiting) and not self._admission_held)
+                )
+                admitted = self._admit(block=not busy)
                 if self._stopping:
                     break
                 # stall-watchdog window: everything between here and the
@@ -2108,7 +2139,8 @@ class Engine:
                     )
                     if slow is not None:
                         time.sleep(float(slow.get("delay_s", 0.01)))
-                self._sweep_parked()
+                with self.profiler.phase("admit"):
+                    self._sweep_parked()
                 if not self._has_work():
                     if not admitted:
                         # park sweeps / admission pressure can free shared
@@ -2116,28 +2148,39 @@ class Engine:
                         # keep the memory mirrors fresh on the idle path too
                         self._publish_memory_state()
                         continue
-                self._dispatch_once()
-                self._stall_check(time.monotonic() - t_cycle)
-                # memory-tier mirrors/gauges refresh BEFORE the armed audit
-                # below, so mirror-vs-truth checks see post-cycle state
-                self._publish_memory_state()
-                # goodput/waste ledger counters + ratio gauge (delta-based;
-                # the scrape path refreshes them too via stats())
-                self.profiler.publish()
-                if self._autopilot is not None:
-                    self._autopilot_tick()
-                if self._brownout is not None:
-                    self._brownout_tick()
-                if self.check_invariants:
-                    if self._faults.enabled and self._faults.pop(
-                        "engine.invariant_break"
-                    ) is not None:
-                        # deterministic mirror corruption: prove the armed
-                        # checker trips end to end (see faults.py)
-                        self._parked_count += 1
-                    from .invariants import check_engine_invariants
+                # the cycle's scheduling (cancels, expiries, chunk and
+                # budget planning) is `admit` self time; the dispatches
+                # inside open their own launch / fetch / commit
+                with self.profiler.phase("admit"):
+                    self._dispatch_once()
+                with self.profiler.phase("publish"):
+                    self._stall_check(time.monotonic() - t_cycle)
+                    # memory-tier mirrors/gauges refresh BEFORE the armed audit
+                    # below, so mirror-vs-truth checks see post-cycle state
+                    self._publish_memory_state()
+                    # goodput/waste ledger counters + ratio gauge (delta-based;
+                    # the scrape path refreshes them too via stats())
+                    self.profiler.publish()
+                    if self._autopilot is not None:
+                        self._autopilot_tick()
+                    if self._brownout is not None:
+                        self._brownout_tick()
+                    if self.check_invariants:
+                        if self._faults.enabled and self._faults.pop(
+                            "engine.invariant_break"
+                        ) is not None:
+                            # deterministic mirror corruption: prove the armed
+                            # checker trips end to end (see faults.py)
+                            self._parked_count += 1
+                        from .invariants import check_engine_invariants
 
-                    check_engine_invariants(self)
+                        check_engine_invariants(self)
+            if self._coordination is not None and not self._coord_follower:
+                # a stop() that lands mid-iteration ends the loop at its
+                # `while` before _admit drains the sentinel and publishes
+                # the stop frame: the followers must get one all the same
+                # (a second stop frame finds them gone, which is harmless)
+                self._coordination.publish([], [], stop=True)
         except Exception as e:  # an engine crash must not hang callers
             log.exception("engine loop crashed")
             # flight-record the crash and snapshot the black box BEFORE any
@@ -2168,6 +2211,7 @@ class Engine:
             for fut in list(self._outstanding):
                 if not fut.done():
                     fut.set_exception(RuntimeError(f"engine crashed: {e}"))
+        self.profiler.end_cycle()
         # drain: fail any queued/waiting requests
         while True:
             try:
@@ -2211,8 +2255,28 @@ class Engine:
         input to admission, so the leader broadcasts each iteration's drained
         requests + cancel snapshot as a frame and followers replay it — every
         process then runs the identical pure admission logic and joins the
-        identical global dispatches (see engine/coordination.py)."""
-        may_block = block and not self._waiting and not self._has_work()
+        identical global dispatches (see engine/coordination.py).
+
+        An idle engine parks here: the first arrival is waited for in the
+        profiler's ``park`` phase, outside any cycle, and opens the cycle;
+        the rest is ``admit``. (A follower waits for the leader's frame
+        inside ``admit``.)"""
+        head: list = []  # what arrived while parked, sentinel included
+        if (
+            block and not self._waiting and not self._has_work()
+            and not self._coord_follower
+        ):
+            with self.profiler.phase("park"):
+                with contextlib.suppress(queue.Empty):
+                    head.append(self._queue.get(timeout=0.05))
+            if head:
+                self.profiler.begin_cycle()
+        with self.profiler.phase("admit"):
+            return self._admit_arrivals(head)
+
+    def _admit_arrivals(self, head: list) -> bool:
+        """The admission proper (``_admit`` less the idle wait): drain the
+        queue behind ``head``, apply cancels and expiries, fill slots."""
         if self._coord_follower:
             try:
                 frame = self._coordination.recv()
@@ -2235,10 +2299,9 @@ class Engine:
             saw_stop = False
             while True:
                 try:
-                    req = self._queue.get(timeout=0.05) if may_block else self._queue.get_nowait()
+                    req = head.pop() if head else self._queue.get_nowait()
                 except queue.Empty:
                     break
-                may_block = False
                 if req is None:
                     saw_stop = True
                     break
@@ -2420,6 +2483,7 @@ class Engine:
             # overlong remainder through intermediate continuation chunks
             # (chunked prefill — both layouts)
             enriched: list[list] = []  # [item, start, swap_entry, share_of]
+            now = time.monotonic()  # one reading a group: queue wait ends here
             for item in group:
                 req, slot, _pages, match = item
                 start = 0
@@ -2464,7 +2528,13 @@ class Engine:
                         adopted=bool(match is not None and match[1].get("in_slot")),
                         chunked=bool(self.prefill_chunk),
                         swapped=swap is not None, shared=share is not None,
+                        cycle=self.profiler.cycle_n,
                     )
+                    if not req.preempt_count:
+                        # a request's FIRST admission ends its queue wait
+                        # (a resume's wait is preempt_stall, not queueing)
+                        self.queue_wait_s += now - req.enqueued
+                        self.queue_admits += 1
                 enriched.append([item, start, swap, share])
             if self.kv_layout == "paged":
                 # block tables must exist before spill chunks reference them
@@ -2531,66 +2601,67 @@ class Engine:
             if not need:
                 return
             for batch in _pow2_chunks(need, self.prefill_batch_max):
-                B = len(batch)
-                self._spill_batch_sizes.add(B)
-                toks = np.zeros((B, CH), dtype=np.int32)
-                starts = np.zeros(B, dtype=np.int32)
-                slots = np.zeros(B, dtype=np.int32)
-                for i, e in enumerate(batch):
-                    (req, slot, _, _m), start = e[0], e[1]
-                    toks[i] = self._full_row(req)[start : start + CH]
-                    starts[i] = start
-                    slots[i] = slot
-                self._rng, step_rng = jax.random.split(self._rng)
-                tail = (
-                    step_rng,
-                    self._put(np.zeros(B, dtype=np.float32)),  # temps (unused sample)
-                    self._put(np.zeros(B, dtype=np.int32)),
-                    self._put(np.ones(B, dtype=np.float32)),
-                    self._dummy_table,
-                    self._put(np.zeros(B, dtype=np.int32)),
-                    self._put(np.zeros(B, dtype=bool)),  # unconstrained
-                    self._dummy_min_close,
-                    self._put(np.ones(B, dtype=np.int32)),
-                )
-                prof_t0 = self.profiler.start()
-                if self.kv_layout == "paged":
-                    P = self.page_size
-                    page_ids = np.zeros((B, CH // P), dtype=np.int32)
+                with self.profiler.phase("launch"):
+                    B = len(batch)
+                    self._spill_batch_sizes.add(B)
+                    toks = np.zeros((B, CH), dtype=np.int32)
+                    starts = np.zeros(B, dtype=np.int32)
+                    slots = np.zeros(B, dtype=np.int32)
                     for i, e in enumerate(batch):
-                        slot, start = e[0][1], e[1]
-                        page_ids[i] = self._slot_pages[slot][start // P : (start + CH) // P]
-                    block_tables = self._put(
-                        self._block_tables[[it[0][1] for it in batch]]
+                        (req, slot, _, _m), start = e[0], e[1]
+                        toks[i] = self._full_row(req)[start : start + CH]
+                        starts[i] = start
+                        slots[i] = slot
+                    self._rng, step_rng = jax.random.split(self._rng)
+                    tail = (
+                        step_rng,
+                        self._put(np.zeros(B, dtype=np.float32)),  # temps (unused sample)
+                        self._put(np.zeros(B, dtype=np.int32)),
+                        self._put(np.ones(B, dtype=np.float32)),
+                        self._dummy_table,
+                        self._put(np.zeros(B, dtype=np.int32)),
+                        self._put(np.zeros(B, dtype=bool)),  # unconstrained
+                        self._dummy_min_close,
+                        self._put(np.ones(B, dtype=np.int32)),
                     )
-                    self.cache, _tok, _state = self._jit_prefill_paged_continue(
-                        self.params,
-                        self.cache,
-                        self._put(toks),
-                        self._put(np.full(B, CH, dtype=np.int32)),
-                        self._put(starts),
-                        self._put(page_ids),
-                        block_tables,
-                        *tail,
-                    )
-                else:
-                    self.cache, _tok, _state = self._jit_prefill_continue(
-                        self.params,
-                        self.cache,
-                        self._put(toks),
-                        self._put(np.full(B, CH, dtype=np.int32)),
-                        self._put(starts),
-                        self._put(slots),
-                        *tail,
-                    )
-                if self.profiler.enabled:
-                    # spill rounds run full CH-token rows: no bucket padding
-                    self.profiler.record(
-                        f"spill[{self.kv_layout},{CH}x{B}]", prof_t0,
-                        out=_tok, real_tokens=B * CH, real_slots=B,
-                    )
-                    pre = sum(CH for e in batch if e[0][0].prewarm)
-                    self.profiler.account(goodput=B * CH - pre, prewarm=pre)
+                    prof_t0 = self.profiler.start()
+                    if self.kv_layout == "paged":
+                        P = self.page_size
+                        page_ids = np.zeros((B, CH // P), dtype=np.int32)
+                        for i, e in enumerate(batch):
+                            slot, start = e[0][1], e[1]
+                            page_ids[i] = self._slot_pages[slot][start // P : (start + CH) // P]
+                        block_tables = self._put(
+                            self._block_tables[[it[0][1] for it in batch]]
+                        )
+                        self.cache, _tok, _state = self._jit_prefill_paged_continue(
+                            self.params,
+                            self.cache,
+                            self._put(toks),
+                            self._put(np.full(B, CH, dtype=np.int32)),
+                            self._put(starts),
+                            self._put(page_ids),
+                            block_tables,
+                            *tail,
+                        )
+                    else:
+                        self.cache, _tok, _state = self._jit_prefill_continue(
+                            self.params,
+                            self.cache,
+                            self._put(toks),
+                            self._put(np.full(B, CH, dtype=np.int32)),
+                            self._put(starts),
+                            self._put(slots),
+                            *tail,
+                        )
+                    if self.profiler.enabled:
+                        # spill rounds run full CH-token rows: no bucket padding
+                        self.profiler.record(
+                            f"spill[{self.kv_layout},{CH}x{B}]", prof_t0,
+                            out=_tok, real_tokens=B * CH, real_slots=B,
+                        )
+                        pre = sum(CH for e in batch if e[0][0].prewarm)
+                        self.profiler.account(goodput=B * CH - pre, prewarm=pre)
                 for e in batch:
                     e[1] += CH
 
@@ -2835,7 +2906,10 @@ class Engine:
         pinned by tests: decode dispatches EVERY cycle active slots exist
         (never starved by pending chunks), and at least one chunk advances
         per cycle (a tight budget throttles prefill, never deadlocks it)."""
-        t0 = time.monotonic()
+        # the planner's cycle clock runs from the phase boundary that
+        # opened this call (the loop's `admit`) to the last one inside it
+        # (the final commit's close): the profiler's stamps, not new reads
+        t0 = self.profiler.stamp()
         if not self._prefilling_count:
             # chunked off, or nothing mid-prefill: the legacy decode
             # iteration. Keyed on _prefilling_count, not the knob: slots
@@ -2843,7 +2917,7 @@ class Engine:
             # if prefill_chunk was toggled off mid-flight (benches/tests
             # A/B the knob on a live engine).
             self._decode_once()
-            self._cycle_clock.observe(time.monotonic() - t0)
+            self._cycle_clock.observe(self.profiler.stamp() - t0)
             return
         self._apply_cancels()
         self._expire_prefilling()
@@ -2866,7 +2940,7 @@ class Engine:
                 spent += n_active * min(
                     self.decode_steps - steps0, self.decode_block_size
                 )
-        self._cycle_clock.observe(time.monotonic() - t0)
+        self._cycle_clock.observe(self.profiler.stamp() - t0)
         self._budget_last = (budget, spent)
         self._budget_spent_total += spent
         self._budget_total += budget
@@ -3059,6 +3133,7 @@ class Engine:
         elif self.host_prefetch and self.kv_layout == "paged":
             self._stage_swap_in(slot, sl)
 
+    @_in_phase("launch")
     def _commit_staged_swap(self, groups: list) -> float:  # acp: megastep-seam # acp: kv-seam # acp: swap-stage
         """Commit half of the prefetch split (split-dispatch form): scatter
         the staged device arrays into the pages with the SAME jitted
@@ -3319,6 +3394,7 @@ class Engine:
         self._record_chunk_round(landed, spent, chunk_budget, restore_slots)
         return spent
 
+    @_in_phase("launch")
     def _chunk_dispatch(  # acp: megastep-seam — split chunk program (fused fallback)
         # acp: dispatch-lanes toks,lengths,starts,slots,page_ids
         self, batch: list[tuple[int, "_Slot", int, int]]
@@ -3428,6 +3504,7 @@ class Engine:
             self._prefix_cache.move_to_end(best_key)
             return (best_key, best)
 
+    @_in_phase("launch")
     def _copy_prefix_into_slot(self, slot: int, entry: dict) -> None:  # acp: megastep-seam # acp: kv-seam
         cut = entry["cut"]
         fn = self._jit_copy_prefix.get(cut)
@@ -3510,12 +3587,13 @@ class Engine:
 
                 fn = jax.jit(extract)  # read-only: cache NOT donated
                 self._jit_extract_prefix[cut] = fn
-            prof_t0 = self.profiler.start()
-            rows = fn(self.cache, jnp.int32(slot))
-            self.profiler.record(
-                f"prefix_extract[{cut}]", prof_t0, out=rows["k"],
-                real_tokens=cut, real_slots=1,
-            )
+            with self.profiler.phase("launch"):
+                prof_t0 = self.profiler.start()
+                rows = fn(self.cache, jnp.int32(slot))
+                self.profiler.record(
+                    f"prefix_extract[{cut}]", prof_t0, out=rows["k"],
+                    real_tokens=cut, real_slots=1,
+                )
             entry = {"cut": cut, **rows}
         with self._prefix_lock:
             self._prefix_cache[key] = entry
@@ -3830,88 +3908,91 @@ class Engine:
         chunked-prefill remainders; slot KV below each start is already
         populated), only the SUFFIX runs through the model
         (prefill_continue)."""
-        B = len(chunk)
-        starts = starts_np if starts_np is not None else np.zeros(B, dtype=np.int32)
-        ln = self._prefill_lanes(chunk, starts)
-        bucket, full_lens, lengths = ln["bucket"], ln["full_lens"], ln["lengths"]
-        table, min_close = ln["table"], ln["min_close"]
-        if starts_np is None:
-            self._full_batch_shapes.add((bucket, B))
-        self._rng, step_rng = jax.random.split(self._rng)
-        common = (
-            self._put(ln["tokens"]),
-            self._put(lengths),
-        )
-        tail = (
-            step_rng,
-            self._put(ln["temps"]),
-            self._put(ln["top_ks"]),
-            self._put(ln["top_ps"]),
-            table,
-            self._put(ln["con_states0"]),
-            self._put(ln["constrained0"]),
-            min_close,
-            self._put(ln["budgets"]),
-        )
-        prof_t0 = self.profiler.start()
-        if self.kv_layout == "paged":
-            P = self.page_size
-            # suffix pages only (the model writes just the suffix; shared
-            # prefix pages are referenced via the block table, never written)
-            # slot pages / block tables were installed at admission (they
-            # must exist before spill chunks reference them)
-            page_ids = np.full((B, bucket // P), TRASH_PAGE, dtype=np.int32)
-            for i, (_req, _slot, pages, _m) in enumerate(chunk):
-                assert pages is not None
-                fresh = pages[int(starts[i]) // P :]
-                page_ids[i, : len(fresh)] = fresh
-            if starts_np is not None:
+        with self.profiler.phase("launch"):
+            B = len(chunk)
+            starts = starts_np if starts_np is not None else np.zeros(B, dtype=np.int32)
+            ln = self._prefill_lanes(chunk, starts)
+            bucket, full_lens, lengths = ln["bucket"], ln["full_lens"], ln["lengths"]
+            table, min_close = ln["table"], ln["min_close"]
+            if starts_np is None:
+                self._full_batch_shapes.add((bucket, B))
+            self._rng, step_rng = jax.random.split(self._rng)
+            common = (
+                self._put(ln["tokens"]),
+                self._put(lengths),
+            )
+            tail = (
+                step_rng,
+                self._put(ln["temps"]),
+                self._put(ln["top_ks"]),
+                self._put(ln["top_ps"]),
+                table,
+                self._put(ln["con_states0"]),
+                self._put(ln["constrained0"]),
+                min_close,
+                self._put(ln["budgets"]),
+            )
+            prof_t0 = self.profiler.start()
+            if self.kv_layout == "paged":
+                P = self.page_size
+                # suffix pages only (the model writes just the suffix; shared
+                # prefix pages are referenced via the block table, never written)
+                # slot pages / block tables were installed at admission (they
+                # must exist before spill chunks reference them)
+                page_ids = np.full((B, bucket // P), TRASH_PAGE, dtype=np.int32)
+                for i, (_req, _slot, pages, _m) in enumerate(chunk):
+                    assert pages is not None
+                    fresh = pages[int(starts[i]) // P :]
+                    page_ids[i, : len(fresh)] = fresh
+                if starts_np is not None:
+                    self._cont_batch_sizes.add(B)
+                    block_tables = self._put(
+                        self._block_tables[[slot for _, slot, _, _ in chunk]]
+                    )
+                    cache, firsts, con_states = self._jit_prefill_paged_continue(
+                        self.params, self.cache, *common,
+                        self._put(starts), self._put(page_ids), block_tables, *tail,
+                    )
+                else:
+                    cache, firsts, con_states = self._jit_prefill_paged(
+                        self.params, self.cache, *common, self._put(page_ids), *tail
+                    )
+            elif starts_np is not None:
                 self._cont_batch_sizes.add(B)
-                block_tables = self._put(
-                    self._block_tables[[slot for _, slot, _, _ in chunk]]
-                )
-                cache, firsts, con_states = self._jit_prefill_paged_continue(
+                cache, firsts, con_states = self._jit_prefill_continue(
                     self.params, self.cache, *common,
-                    self._put(starts), self._put(page_ids), block_tables, *tail,
+                    self._put(starts), self._put(ln["slots"]), *tail,
                 )
             else:
-                cache, firsts, con_states = self._jit_prefill_paged(
-                    self.params, self.cache, *common, self._put(page_ids), *tail
+                cache, firsts, con_states = self._jit_prefill(
+                    self.params, self.cache, *common, self._put(ln["slots"]), *tail
                 )
-        elif starts_np is not None:
-            self._cont_batch_sizes.add(B)
-            cache, firsts, con_states = self._jit_prefill_continue(
-                self.params, self.cache, *common,
-                self._put(starts), self._put(ln["slots"]), *tail,
-            )
-        else:
-            cache, firsts, con_states = self._jit_prefill(
-                self.params, self.cache, *common, self._put(ln["slots"]), *tail
-            )
-        self.cache = cache
-        if self.profiler.enabled:
-            # program key mirrors the jit cache keying: kind x bucket x
-            # batch x layout, +tbl once the real grammar table shape traces
-            kind = "prefill_cont" if starts_np is not None else "prefill"
-            tbl = "+tbl" if table is not self._dummy_table else ""
-            real = int(lengths.sum())
-            self.profiler.record(
-                f"{kind}[{self.kv_layout},{bucket}x{B}{tbl}]", prof_t0,
-                out=firsts, real_tokens=real,
-                padded_tokens=B * bucket - real, real_slots=B,
-            )
-            pre = sum(
-                int(lengths[i]) for i, (r, _, _, _) in enumerate(chunk)
-                if r.prewarm
-            )
-            self.profiler.account(
-                goodput=real - pre, prewarm=pre, pad_bucket=B * bucket - real
-            )
-        # one combined round trip (see _decode_once; the fetch floor is
-        # per transfer, not per byte)
-        firsts, con_states = jax.device_get((firsts, con_states))
+            self.cache = cache
+            if self.profiler.enabled:
+                # program key mirrors the jit cache keying: kind x bucket x
+                # batch x layout, +tbl once the real grammar table shape traces
+                kind = "prefill_cont" if starts_np is not None else "prefill"
+                tbl = "+tbl" if table is not self._dummy_table else ""
+                real = int(lengths.sum())
+                self.profiler.record(
+                    f"{kind}[{self.kv_layout},{bucket}x{B}{tbl}]", prof_t0,
+                    out=firsts, real_tokens=real,
+                    padded_tokens=B * bucket - real, real_slots=B,
+                )
+                pre = sum(
+                    int(lengths[i]) for i, (r, _, _, _) in enumerate(chunk)
+                    if r.prewarm
+                )
+                self.profiler.account(
+                    goodput=real - pre, prewarm=pre, pad_bucket=B * bucket - real
+                )
+        with self.profiler.phase("fetch"):
+            # one combined round trip (see _decode_once; the fetch floor is
+            # per transfer, not per byte)
+            firsts, con_states = jax.device_get((firsts, con_states))
         self._finish_prefill_dispatch(chunk, firsts, con_states, full_lens)
 
+    @_in_phase("commit")
     def _finish_prefill_dispatch(  # acp: megastep-seam — _save_prefix extracts KV
         self,
         chunk: list,
@@ -3954,6 +4035,7 @@ class Engine:
                 self.flight.record(
                     "prefill_done", rid=req.rid, slot=slot,
                     seq=int(full_lens[i]), first=is_first,
+                    cycle=self.profiler.cycle_n,
                 )
             prior = self._slots.get(slot)
             if prior is not None and prior.prefilling:
@@ -4286,6 +4368,7 @@ class Engine:
             self.flight.finish(
                 req.rid, "length", trace=req.trace,
                 tokens=len(gen), preempts=req.preempt_count,
+                cycle=self.profiler.cycle_n,
             )
         if not req.future.done():
             req.future.set_result(result)
@@ -4385,65 +4468,68 @@ class Engine:
         # cycle's pending chunk lanes ride whichever dispatch wins.
         if self.spec_len and self._decode_spec(pending):
             return
-        K = self.decode_block_size
-        if self.kv_layout == "paged":
-            self._ensure_pages_for_block()
-            if not self._n_active():
-                self._megastep_flush(pending)
-                return
-        d = self._ensure_dev_state()
-        W = d["W"]
-        n_act = self._n_active()
-        KB = self.decode_block_size
-        if pending is not None:
-            out = self._megastep_dispatch(pending, d=d, n_act=n_act)
-            if out is not None:
-                return
-            # fused shape over the program bound: dispatch the pending
-            # chunk lanes through the split programs, then the plain block
-            self._dispatch_pending_split(pending)
-            if not self._n_active():
-                self._publish_decode_gauges()
-                return
-            d = self._ensure_dev_state()  # finals may have joined
+        with self.profiler.phase("launch"):
+            K = self.decode_block_size
+            if self.kv_layout == "paged":
+                self._ensure_pages_for_block()
+                if not self._n_active():
+                    self._megastep_flush(pending)
+                    return
+            d = self._ensure_dev_state()
             W = d["W"]
             n_act = self._n_active()
-        common = (
-            d["tokens"], d["seq_lens"], d["active"], d["rng"],
-            d["temps"], d["top_ks"], d["top_ps"], d["table"],
-            d["con_states"], d["constrained"], d["min_close"], d["budgets"],
-        )
-        prof_t0 = self.profiler.start()
-        if self.kv_layout == "paged":
-            cache, tok_block, carry = self._jit_decode_paged(
-                self.params, self.cache, *common, d["block_tables"]
+            KB = self.decode_block_size
+            if pending is not None:
+                out = self._megastep_dispatch(pending, d=d, n_act=n_act)
+                if out is not None:
+                    return
+                # fused shape over the program bound: dispatch the pending
+                # chunk lanes through the split programs, then the plain block
+                self._dispatch_pending_split(pending)
+                if not self._n_active():
+                    self._publish_decode_gauges()
+                    return
+                d = self._ensure_dev_state()  # finals may have joined
+                W = d["W"]
+                n_act = self._n_active()
+            common = (
+                d["tokens"], d["seq_lens"], d["active"], d["rng"],
+                d["temps"], d["top_ks"], d["top_ps"], d["table"],
+                d["con_states"], d["constrained"], d["min_close"], d["budgets"],
             )
-        else:
-            cache, tok_block, carry = self._jit_decode(
-                self.params, self.cache, *common
+            prof_t0 = self.profiler.start()
+            if self.kv_layout == "paged":
+                cache, tok_block, carry = self._jit_decode_paged(
+                    self.params, self.cache, *common, d["block_tables"]
+                )
+            else:
+                cache, tok_block, carry = self._jit_decode(
+                    self.params, self.cache, *common
+                )
+            prog_key = (
+                f"decode[{self.kv_layout},{W}x{KB}"
+                f"{'+tbl' if d['table'] is not self._dummy_table else ''}]"
             )
-        prog_key = (
-            f"decode[{self.kv_layout},{W}x{KB}"
-            f"{'+tbl' if d['table'] is not self._dummy_table else ''}]"
-        )
-        if self.profiler.enabled:
-            # real/padded here are the DISPATCH-time view (lanes active as
-            # uploaded); mid-block deactivations land precisely in the
-            # account() call after the commit loop below
-            self.profiler.record(
-                prog_key, prof_t0,
-                out=tok_block, real_tokens=n_act * KB,
-                padded_tokens=(W - n_act) * KB,
-                real_slots=n_act, padded_slots=W - n_act,
-            )
-        # ONE host round trip for both results — through a high-RTT link
-        # sequential np.asarray fetches double the per-block latency floor.
-        # con_states must stay mirrored so the next dirty upload (admission
-        # into some other slot) doesn't clobber live automaton states.
-        con_states, tok_block = jax.device_get((carry[2], tok_block))
+            if self.profiler.enabled:
+                # real/padded here are the DISPATCH-time view (lanes active as
+                # uploaded); mid-block deactivations land precisely in the
+                # account() call after the commit loop below
+                self.profiler.record(
+                    prog_key, prof_t0,
+                    out=tok_block, real_tokens=n_act * KB,
+                    padded_tokens=(W - n_act) * KB,
+                    real_slots=n_act, padded_slots=W - n_act, blocks=1,
+                )
+        with self.profiler.phase("fetch"):
+            # ONE host round trip for both results — through a high-RTT link
+            # sequential np.asarray fetches double the per-block latency floor.
+            # con_states must stay mirrored so the next dirty upload (admission
+            # into some other slot) doesn't clobber live automaton states.
+            con_states, tok_block = jax.device_get((carry[2], tok_block))
         self.cache = cache
         self._commit_decode_block(tok_block, con_states, carry, d, prog_key)
 
+    @_in_phase("commit")
     def _commit_decode_block(
         self,
         tok_block: np.ndarray,
@@ -4465,7 +4551,7 @@ class Engine:
         # a timeline reader sees the cadence, not a flood
         self.flight.record(
             "decode_block", width=W, steps=K, active=self._n_active(),
-            program=prog_key,
+            program=prog_key, cycle=self.profiler.cycle_n,
         )
         emitted = pre_emitted = 0
         for slot, sl in list(self._slots.items()):
@@ -4813,178 +4899,182 @@ class Engine:
                 "distinct fused jit entries)",
             )
             return None
-        mid_lanes = fin_lanes = pl_lanes = swap_arg = None
-        fin_chunk = fin_ln = pl_chunk = pl_ln = None
-        if swaps:
-            swap_arg = tuple(
-                (ids, blocks)
-                for _slot, _sl, _st, _n, groups in swaps
-                for ids, blocks in groups
-            )
-        if mids:
-            mid_lanes, mid_bucket, mid_Bp = self._fuse_mid_lanes(mids)
-        if plains:
-            pl_lanes, pl_bucket, pl_Bp, pl_chunk, pl_ln = (
-                self._fuse_plain_lanes(plains)
-            )
-        if finals:
-            fin_lanes, fin_bucket, fin_Bp, fin_chunk, fin_ln = (
-                self._fuse_final_lanes(finals)
-            )
-        dec_carry = dec_aux = None
-        if d is not None:
-            dec_carry = (
-                d["tokens"], d["seq_lens"], d["con_states"], d["budgets"],
-                d["active"], d["rng"],
-            )
-            extra = (d["block_tables"],) if self.kv_layout == "paged" else ()
-            dec_aux = (
-                d["temps"], d["top_ks"], d["top_ps"], d["table"],
-                d["constrained"], d["min_close"], extra,
-            )
-        key = f"megastep[{self.kv_layout},{'+'.join(parts)}{tbl}]"
-        new_shape = shape not in self._megastep_shapes
-        self._megastep_shapes.add(shape)
-        prof_t0 = self.profiler.start()
-        cache, p_out, f_out, d_out, v_out = self._jit_megastep(
-            self.params, self.cache, swap_arg, mid_lanes, pl_lanes,
-            fin_lanes, dec_carry, dec_aux, ver,
-        )
-        self.megastep_dispatches += 1
-        if new_shape:
-            self.flight.record("megastep_shape", program=key)
-        mid_real = sum(n for _, _, _, n in mids)
-        pl_real = int(pl_ln["lengths"].sum()) if plains else 0
-        fin_real = int(fin_ln["lengths"].sum()) if finals else 0
-        swap_real = sum(
-            int(ids.shape[0]) * self.page_size for ids, _ in (swap_arg or ())
-        )
-        if self.profiler.enabled:
-            # swap rows count as real tokens only (the split scatter
-            # records real_tokens with no goodput accounting; fused keeps
-            # that) — restored rows are moved KV, not computed tokens
-            real = mid_real + pl_real + fin_real + swap_real
-            padded = 0
+        with self.profiler.phase("launch"):
+            mid_lanes = fin_lanes = pl_lanes = swap_arg = None
+            fin_chunk = fin_ln = pl_chunk = pl_ln = None
+            if swaps:
+                swap_arg = tuple(
+                    (ids, blocks)
+                    for _slot, _sl, _st, _n, groups in swaps
+                    for ids, blocks in groups
+                )
             if mids:
-                padded += mid_Bp * mid_bucket - mid_real
+                mid_lanes, mid_bucket, mid_Bp = self._fuse_mid_lanes(mids)
             if plains:
-                padded += pl_Bp * pl_bucket - pl_real
+                pl_lanes, pl_bucket, pl_Bp, pl_chunk, pl_ln = (
+                    self._fuse_plain_lanes(plains)
+                )
             if finals:
-                padded += fin_Bp * fin_bucket - fin_real
-            real_slots = len(mids) + len(plains) + len(finals)
-            padded_slots = (
-                (mid_Bp - len(mids)) + (pl_Bp - len(plains))
-                + (fin_Bp - len(finals))
-            )
+                fin_lanes, fin_bucket, fin_Bp, fin_chunk, fin_ln = (
+                    self._fuse_final_lanes(finals)
+                )
+            dec_carry = dec_aux = None
             if d is not None:
-                real += n_act * KB
-                padded += (W - n_act) * KB
-                real_slots += n_act
-                padded_slots += W - n_act
-            elif ver is not None:
-                real += ver_meta["real_in"]
-                padded += W * T - ver_meta["real_in"]
-                real_slots += ver_meta["n_part"]
-                padded_slots += W - ver_meta["n_part"]
-            out_probe = (
-                d_out[0] if d_out is not None
-                else v_out[0] if v_out is not None
-                else f_out[0] if f_out is not None
-                else p_out[0] if p_out is not None
-                else cache["k"]  # chunks-only: block on the committed KV
-            )
-            self.profiler.record(
-                key, prof_t0, out=out_probe, real_tokens=real,
-                padded_tokens=padded, real_slots=real_slots,
-                padded_slots=padded_slots,
-            )
-            # the fused phases classify exactly as their split programs
-            # would, plus pad_fuse for the pow2-padding rows fusion adds
-            # (the split pow2 DECOMPOSITION has none)
-            if mids:
-                pre = sum(n for _, sl, _, n in mids if sl.request.prewarm)
-                self.profiler.account(
-                    goodput=mid_real - pre, prewarm=pre,
-                    pad_bucket=len(mids) * mid_bucket - mid_real,
-                    pad_fuse=(mid_Bp - len(mids)) * mid_bucket,
+                dec_carry = (
+                    d["tokens"], d["seq_lens"], d["con_states"], d["budgets"],
+                    d["active"], d["rng"],
                 )
-            if plains:
-                pre = sum(
-                    int(pl_ln["lengths"][i])
-                    for i, (r, _, _, _) in enumerate(pl_chunk)
-                    if r.prewarm
+                extra = (d["block_tables"],) if self.kv_layout == "paged" else ()
+                dec_aux = (
+                    d["temps"], d["top_ks"], d["top_ps"], d["table"],
+                    d["constrained"], d["min_close"], extra,
                 )
-                self.profiler.account(
-                    goodput=pl_real - pre, prewarm=pre,
-                    pad_bucket=len(plains) * pl_bucket - pl_real,
-                    pad_fuse=(pl_Bp - len(plains)) * pl_bucket,
+            key = f"megastep[{self.kv_layout},{'+'.join(parts)}{tbl}]"
+            new_shape = shape not in self._megastep_shapes
+            self._megastep_shapes.add(shape)
+            prof_t0 = self.profiler.start()
+            cache, p_out, f_out, d_out, v_out = self._jit_megastep(
+                self.params, self.cache, swap_arg, mid_lanes, pl_lanes,
+                fin_lanes, dec_carry, dec_aux, ver,
+            )
+            self.megastep_dispatches += 1
+            if new_shape:
+                self.flight.record("megastep_shape", program=key)
+            mid_real = sum(n for _, _, _, n in mids)
+            pl_real = int(pl_ln["lengths"].sum()) if plains else 0
+            fin_real = int(fin_ln["lengths"].sum()) if finals else 0
+            swap_real = sum(
+                int(ids.shape[0]) * self.page_size for ids, _ in (swap_arg or ())
+            )
+            if self.profiler.enabled:
+                # swap rows count as real tokens only (the split scatter
+                # records real_tokens with no goodput accounting; fused keeps
+                # that) — restored rows are moved KV, not computed tokens
+                real = mid_real + pl_real + fin_real + swap_real
+                padded = 0
+                if mids:
+                    padded += mid_Bp * mid_bucket - mid_real
+                if plains:
+                    padded += pl_Bp * pl_bucket - pl_real
+                if finals:
+                    padded += fin_Bp * fin_bucket - fin_real
+                real_slots = len(mids) + len(plains) + len(finals)
+                padded_slots = (
+                    (mid_Bp - len(mids)) + (pl_Bp - len(plains))
+                    + (fin_Bp - len(finals))
+                )
+                if d is not None:
+                    real += n_act * KB
+                    padded += (W - n_act) * KB
+                    real_slots += n_act
+                    padded_slots += W - n_act
+                elif ver is not None:
+                    real += ver_meta["real_in"]
+                    padded += W * T - ver_meta["real_in"]
+                    real_slots += ver_meta["n_part"]
+                    padded_slots += W - ver_meta["n_part"]
+                out_probe = (
+                    d_out[0] if d_out is not None
+                    else v_out[0] if v_out is not None
+                    else f_out[0] if f_out is not None
+                    else p_out[0] if p_out is not None
+                    else cache["k"]  # chunks-only: block on the committed KV
+                )
+                self.profiler.record(
+                    key, prof_t0, out=out_probe, real_tokens=real,
+                    padded_tokens=padded, real_slots=real_slots,
+                    padded_slots=padded_slots,
+                    blocks=1 if d is not None else 0,
+                )
+                # the fused phases classify exactly as their split programs
+                # would, plus pad_fuse for the pow2-padding rows fusion adds
+                # (the split pow2 DECOMPOSITION has none)
+                if mids:
+                    pre = sum(n for _, sl, _, n in mids if sl.request.prewarm)
+                    self.profiler.account(
+                        goodput=mid_real - pre, prewarm=pre,
+                        pad_bucket=len(mids) * mid_bucket - mid_real,
+                        pad_fuse=(mid_Bp - len(mids)) * mid_bucket,
+                    )
+                if plains:
+                    pre = sum(
+                        int(pl_ln["lengths"][i])
+                        for i, (r, _, _, _) in enumerate(pl_chunk)
+                        if r.prewarm
+                    )
+                    self.profiler.account(
+                        goodput=pl_real - pre, prewarm=pre,
+                        pad_bucket=len(plains) * pl_bucket - pl_real,
+                        pad_fuse=(pl_Bp - len(plains)) * pl_bucket,
+                    )
+                if finals:
+                    pre = sum(
+                        int(fin_ln["lengths"][i])
+                        for i, (r, _, _, _) in enumerate(fin_chunk)
+                        if r.prewarm
+                    )
+                    self.profiler.account(
+                        goodput=fin_real - pre, prewarm=pre,
+                        pad_bucket=len(finals) * fin_bucket - fin_real,
+                        pad_fuse=(fin_Bp - len(finals)) * fin_bucket,
+                    )
+        with self.profiler.phase("fetch"):
+            # ONE host round trip for every phase's results (None phases fetch
+            # nothing — device_get maps over the pytree)
+            carry = d_out[1] if d_out is not None else None
+            f_np, p_np, dec_fetch, ver_np = jax.device_get((
+                f_out,
+                p_out,
+                (carry[2], d_out[0]) if d_out is not None else None,
+                v_out,
+            ))
+        with self.profiler.phase("commit"):
+            self.cache = cache
+            # commit order matters: swap bookkeeping and mid chunks advance
+            # first (bookkeeping only — their cache writes already landed in
+            # the program), then the decode/verify commit — its lanes predate
+            # this cycle's finals, so it must run BEFORE finals/plains flip
+            # their slots to ACTIVE (a freed-and-reused slot id would
+            # otherwise read garbage lanes) — and the finals/plains commit
+            # last. A fused restore adds NO stall seconds: the host->device
+            # copy overlapped last cycle and the scatter rode this dispatch.
+            for slot, sl, st, n, _groups in swaps:
+                REGISTRY.counter_add(
+                    "acp_engine_kv_prefetch_commits_total", 1.0,
+                    help="host-KV restore chunks whose rows were prefetched "
+                    "(staged host->device a cycle early) and landed by scatter "
+                    "commit — the async-prefetch overlap win; chunks NOT "
+                    "counted here paid the blocking copy as host_stall",
+                )
+                self._advance_restore(slot, sl, st, n)
+            for slot, sl, st, n in mids:
+                sl.prefill_pos = st + n
+                self._seq_lens[slot] = sl.prefill_pos
+            if d_out is not None:
+                con_states, tok_block = dec_fetch
+                self._commit_decode_block(tok_block, con_states, carry, d, key)
+            if v_out is not None:
+                out_toks, n_emit, new_states = ver_np
+                self._commit_spec_verify(
+                    out_toks, n_emit, new_states, ver_meta, key
                 )
             if finals:
-                pre = sum(
-                    int(fin_ln["lengths"][i])
-                    for i, (r, _, _, _) in enumerate(fin_chunk)
-                    if r.prewarm
+                firsts, fstates = f_np
+                B = len(finals)
+                self._finish_prefill_dispatch(
+                    fin_chunk, firsts[:B], fstates[:B], fin_ln["full_lens"]
                 )
-                self.profiler.account(
-                    goodput=fin_real - pre, prewarm=pre,
-                    pad_bucket=len(finals) * fin_bucket - fin_real,
-                    pad_fuse=(fin_Bp - len(finals)) * fin_bucket,
+            if plains:
+                p_firsts, p_states = p_np
+                B = len(plains)
+                self._finish_prefill_dispatch(
+                    pl_chunk, p_firsts[:B], p_states[:B], pl_ln["full_lens"]
                 )
-        # ONE host round trip for every phase's results (None phases fetch
-        # nothing — device_get maps over the pytree)
-        carry = d_out[1] if d_out is not None else None
-        f_np, p_np, dec_fetch, ver_np = jax.device_get((
-            f_out,
-            p_out,
-            (carry[2], d_out[0]) if d_out is not None else None,
-            v_out,
-        ))
-        self.cache = cache
-        # commit order matters: swap bookkeeping and mid chunks advance
-        # first (bookkeeping only — their cache writes already landed in
-        # the program), then the decode/verify commit — its lanes predate
-        # this cycle's finals, so it must run BEFORE finals/plains flip
-        # their slots to ACTIVE (a freed-and-reused slot id would
-        # otherwise read garbage lanes) — and the finals/plains commit
-        # last. A fused restore adds NO stall seconds: the host->device
-        # copy overlapped last cycle and the scatter rode this dispatch.
-        for slot, sl, st, n, _groups in swaps:
-            REGISTRY.counter_add(
-                "acp_engine_kv_prefetch_commits_total", 1.0,
-                help="host-KV restore chunks whose rows were prefetched "
-                "(staged host->device a cycle early) and landed by scatter "
-                "commit — the async-prefetch overlap win; chunks NOT "
-                "counted here paid the blocking copy as host_stall",
+            self._record_chunk_round(
+                pending["landed"] + [c[:4] for c in swaps] + mids + plains
+                + finals, pending["spent"], pending["budget"],
+                pending["restores"],
             )
-            self._advance_restore(slot, sl, st, n)
-        for slot, sl, st, n in mids:
-            sl.prefill_pos = st + n
-            self._seq_lens[slot] = sl.prefill_pos
-        if d_out is not None:
-            con_states, tok_block = dec_fetch
-            self._commit_decode_block(tok_block, con_states, carry, d, key)
-        if v_out is not None:
-            out_toks, n_emit, new_states = ver_np
-            self._commit_spec_verify(
-                out_toks, n_emit, new_states, ver_meta, key
-            )
-        if finals:
-            firsts, fstates = f_np
-            B = len(finals)
-            self._finish_prefill_dispatch(
-                fin_chunk, firsts[:B], fstates[:B], fin_ln["full_lens"]
-            )
-        if plains:
-            p_firsts, p_states = p_np
-            B = len(plains)
-            self._finish_prefill_dispatch(
-                pl_chunk, p_firsts[:B], p_states[:B], pl_ln["full_lens"]
-            )
-        self._record_chunk_round(
-            pending["landed"] + [c[:4] for c in swaps] + mids + plains
-            + finals, pending["spent"], pending["budget"],
-            pending["restores"],
-        )
         return True
 
     def _consume_tokens(self, slot: int, sl: _Slot, toks) -> None:
@@ -5077,6 +5167,7 @@ class Engine:
                     log.exception("on_tool_call failed; disabling for rid %s", req.rid)
                     req.on_tool_call = None
 
+    @_in_phase("publish")
     def _publish_decode_gauges(self) -> None:
         REGISTRY.gauge_set(
             "acp_engine_active_slots", self._n_active(),
@@ -5154,143 +5245,146 @@ class Engine:
           ``_ensure_pages_for_block`` preempts exactly as in the block path
           (preempted slots are dropped from this dispatch).
         """
-        from .spec import ngram_propose
+        with self.profiler.phase("launch"):
+            from .spec import ngram_propose
 
-        T = self.spec_len + 1  # one trace shape per width bucket
-        drafts: dict[int, list[int]] = {}
-        budgets_eff: dict[int, int] = {}
-        any_draft = False
-        for slot, sl in self._slots.items():
-            if sl.parked or sl.prefilling:
-                continue
-            budget = self._slot_budget(slot, sl)
-            budgets_eff[slot] = budget
-            # the dispatch emits up to draft+1 tokens and writes draft+1 KV
-            # rows: cap the draft so both stay within budget (and therefore
-            # within the context edge — budget <= ctx_left)
-            cap = min(sl.spec.cap(), budget - 1) if sl.spec else 0
-            d: list[int] = []
-            if cap > 0:
-                d = ngram_propose(self._slot_ctx(sl), self.spec_ngram, cap)
-            drafts[slot] = d
-            any_draft = any_draft or bool(d)
-        if not any_draft:
-            return False
-        if self.kv_layout == "paged":
-            # page coverage for the widest row each slot verifies; a slot
-            # preempted under pressure here simply leaves the dispatch
-            self._ensure_pages_for_block(
-                {slot: 1 + len(d) for slot, d in drafts.items()}
+            T = self.spec_len + 1  # one trace shape per width bucket
+            drafts: dict[int, list[int]] = {}
+            budgets_eff: dict[int, int] = {}
+            any_draft = False
+            for slot, sl in self._slots.items():
+                if sl.parked or sl.prefilling:
+                    continue
+                budget = self._slot_budget(slot, sl)
+                budgets_eff[slot] = budget
+                # the dispatch emits up to draft+1 tokens and writes draft+1 KV
+                # rows: cap the draft so both stay within budget (and therefore
+                # within the context edge — budget <= ctx_left)
+                cap = min(sl.spec.cap(), budget - 1) if sl.spec else 0
+                d: list[int] = []
+                if cap > 0:
+                    d = ngram_propose(self._slot_ctx(sl), self.spec_ngram, cap)
+                drafts[slot] = d
+                any_draft = any_draft or bool(d)
+            if not any_draft:
+                return False
+            if self.kv_layout == "paged":
+                # page coverage for the widest row each slot verifies; a slot
+                # preempted under pressure here simply leaves the dispatch
+                self._ensure_pages_for_block(
+                    {slot: 1 + len(d) for slot, d in drafts.items()}
+                )
+                if not self._n_active():
+                    self._megastep_flush(pending)
+                    return True
+                drafts = {s: d for s, d in drafts.items() if s in self._slots}
+                if not any(drafts.values()):
+                    return False  # the drafted slots were preempted; block-decode
+            force_reject = bool(
+                self._faults.enabled
+                and self._faults.pop("engine.spec_mismatch") is not None
             )
-            if not self._n_active():
-                self._megastep_flush(pending)
-                return True
-            drafts = {s: d for s, d in drafts.items() if s in self._slots}
-            if not any(drafts.values()):
-                return False  # the drafted slots were preempted; block-decode
-        force_reject = bool(
-            self._faults.enabled
-            and self._faults.pop("engine.spec_mismatch") is not None
-        )
-        W = next(
-            w for w in self.width_buckets
-            if w >= max(
-                s for s, sl in self._slots.items()
-                if not sl.parked and not sl.prefilling
-            ) + 1
-        )
-        inputs = np.zeros((W, T), dtype=np.int32)
-        # lanes NOT in this dispatch (free, parked, mid-prefill) must write
-        # their optimistic K/V somewhere HARMLESS: n_input=0 sends every
-        # paged write to the trash page (token_write_targets masks by
-        # length), and starts=max_ctx clamps the slot layout's scatter to
-        # row max_ctx-1, which attention can never read (a lane deactivates
-        # at max_ctx-1). The old defaults (n_input=1, starts=0) scattered
-        # one garbage row into position 0 of the lane's LIVE KV — harmless
-        # for free lanes (the next prefill overwrites from 0) but corrupting
-        # for parked prompt KV awaiting adoption and for mid-prefill slots.
-        n_input = np.zeros(W, dtype=np.int32)
-        starts = np.full(W, self.max_ctx, dtype=np.int32)
-        active = np.zeros(W, dtype=bool)
-        budgets = np.zeros(W, dtype=np.int32)
-        proposed = np.zeros(W, dtype=np.int32)
-        for slot, sl in self._slots.items():
-            if sl.parked or sl.prefilling:
-                continue
-            d = drafts.get(slot, [])
-            inputs[slot, 0] = self._last_tokens[slot]
-            if d:
-                inputs[slot, 1 : 1 + len(d)] = d
-            n_input[slot] = 1 + len(d)
-            starts[slot] = self._seq_lens[slot]
-            active[slot] = True
-            budgets[slot] = budgets_eff[slot]
-            proposed[slot] = len(d)
-        use_real = self._token_table is not None
-        self._rng, step_rng = jax.random.split(self._rng)
-        args = [
-            self.params,
-            self.cache,
-            self._put(inputs),
-            self._put(n_input),
-            self._put(starts),
-            self._put(active),
-            step_rng,
-            self._put(self._temps[:W]),
-            self._put(self._top_ks[:W]),
-            self._put(self._top_ps[:W]),
-            self._token_table if use_real else self._dummy_table,
-            self._put(self._con_states[:W]),
-            self._put(self._constrained[:W]),
-            self._min_close if use_real else self._dummy_min_close,
-            self._put(budgets),
-            self._put(np.asarray(force_reject)),
-        ]
-        if self.kv_layout == "paged":
-            args.append(self._put(self._block_tables[:W]))
-        ver_meta = {
-            "W": W, "T": T, "drafts": drafts, "proposed": proposed,
-            "force_reject": force_reject, "real_in": int(n_input.sum()),
-            "n_part": int(active.sum()),
-        }
-        if pending is not None:
-            # fused cycle: the verify pass rides the megastep with the
-            # pending chunk lanes (one dispatch). Shape-bound fallback
-            # split-dispatches the chunks, then verifies standalone below
-            # (finals activated by the fallback join the NEXT cycle's
-            # lanes — per-request greedy bytes are unaffected).
-            if self._megastep_dispatch(
-                pending, ver=tuple(args[2:]), ver_meta=ver_meta
-            ):
-                return True
-            self._dispatch_pending_split(pending)
-            # the fallback's chunk dispatches DONATED the cache args[1]
-            # captured above and reassigned self.cache — verifying against
-            # the stale buffer would crash (deleted buffer) or silently
-            # discard this cycle's chunk KV writes
-            args[1] = self.cache
-        prof_t0 = self.profiler.start()
-        cache, out_toks, n_emit, new_states = self._jit_verify(*args)
-        self.cache = cache
-        spec_prog_key = (
-            f"spec_verify[{self.kv_layout},{W}x{T}{'+tbl' if use_real else ''}]"
-        )
-        if self.profiler.enabled:
-            n_part = ver_meta["n_part"]
-            real_in = ver_meta["real_in"]
-            self.profiler.record(
-                spec_prog_key, prof_t0,
-                out=out_toks, real_tokens=real_in,
-                padded_tokens=W * T - real_in,
-                real_slots=n_part, padded_slots=W - n_part,
+            W = next(
+                w for w in self.width_buckets
+                if w >= max(
+                    s for s, sl in self._slots.items()
+                    if not sl.parked and not sl.prefilling
+                ) + 1
             )
-        # one combined host round trip, same discipline as the block path
-        out_toks, n_emit, new_states = jax.device_get((out_toks, n_emit, new_states))
+            inputs = np.zeros((W, T), dtype=np.int32)
+            # lanes NOT in this dispatch (free, parked, mid-prefill) must write
+            # their optimistic K/V somewhere HARMLESS: n_input=0 sends every
+            # paged write to the trash page (token_write_targets masks by
+            # length), and starts=max_ctx clamps the slot layout's scatter to
+            # row max_ctx-1, which attention can never read (a lane deactivates
+            # at max_ctx-1). The old defaults (n_input=1, starts=0) scattered
+            # one garbage row into position 0 of the lane's LIVE KV — harmless
+            # for free lanes (the next prefill overwrites from 0) but corrupting
+            # for parked prompt KV awaiting adoption and for mid-prefill slots.
+            n_input = np.zeros(W, dtype=np.int32)
+            starts = np.full(W, self.max_ctx, dtype=np.int32)
+            active = np.zeros(W, dtype=bool)
+            budgets = np.zeros(W, dtype=np.int32)
+            proposed = np.zeros(W, dtype=np.int32)
+            for slot, sl in self._slots.items():
+                if sl.parked or sl.prefilling:
+                    continue
+                d = drafts.get(slot, [])
+                inputs[slot, 0] = self._last_tokens[slot]
+                if d:
+                    inputs[slot, 1 : 1 + len(d)] = d
+                n_input[slot] = 1 + len(d)
+                starts[slot] = self._seq_lens[slot]
+                active[slot] = True
+                budgets[slot] = budgets_eff[slot]
+                proposed[slot] = len(d)
+            use_real = self._token_table is not None
+            self._rng, step_rng = jax.random.split(self._rng)
+            args = [
+                self.params,
+                self.cache,
+                self._put(inputs),
+                self._put(n_input),
+                self._put(starts),
+                self._put(active),
+                step_rng,
+                self._put(self._temps[:W]),
+                self._put(self._top_ks[:W]),
+                self._put(self._top_ps[:W]),
+                self._token_table if use_real else self._dummy_table,
+                self._put(self._con_states[:W]),
+                self._put(self._constrained[:W]),
+                self._min_close if use_real else self._dummy_min_close,
+                self._put(budgets),
+                self._put(np.asarray(force_reject)),
+            ]
+            if self.kv_layout == "paged":
+                args.append(self._put(self._block_tables[:W]))
+            ver_meta = {
+                "W": W, "T": T, "drafts": drafts, "proposed": proposed,
+                "force_reject": force_reject, "real_in": int(n_input.sum()),
+                "n_part": int(active.sum()),
+            }
+            if pending is not None:
+                # fused cycle: the verify pass rides the megastep with the
+                # pending chunk lanes (one dispatch). Shape-bound fallback
+                # split-dispatches the chunks, then verifies standalone below
+                # (finals activated by the fallback join the NEXT cycle's
+                # lanes — per-request greedy bytes are unaffected).
+                if self._megastep_dispatch(
+                    pending, ver=tuple(args[2:]), ver_meta=ver_meta
+                ):
+                    return True
+                self._dispatch_pending_split(pending)
+                # the fallback's chunk dispatches DONATED the cache args[1]
+                # captured above and reassigned self.cache — verifying against
+                # the stale buffer would crash (deleted buffer) or silently
+                # discard this cycle's chunk KV writes
+                args[1] = self.cache
+            prof_t0 = self.profiler.start()
+            cache, out_toks, n_emit, new_states = self._jit_verify(*args)
+            self.cache = cache
+            spec_prog_key = (
+                f"spec_verify[{self.kv_layout},{W}x{T}{'+tbl' if use_real else ''}]"
+            )
+            if self.profiler.enabled:
+                n_part = ver_meta["n_part"]
+                real_in = ver_meta["real_in"]
+                self.profiler.record(
+                    spec_prog_key, prof_t0,
+                    out=out_toks, real_tokens=real_in,
+                    padded_tokens=W * T - real_in,
+                    real_slots=n_part, padded_slots=W - n_part,
+                )
+        with self.profiler.phase("fetch"):
+            # one combined host round trip, same discipline as the block path
+            out_toks, n_emit, new_states = jax.device_get((out_toks, n_emit, new_states))
         self._commit_spec_verify(
             out_toks, n_emit, new_states, ver_meta, spec_prog_key
         )
         return True
 
+    @_in_phase("commit")
     def _commit_spec_verify(
         self,
         out_toks: np.ndarray,
@@ -5374,7 +5468,7 @@ class Engine:
                 proposed=int(sum(len(d) for d in drafts.values())),
                 emitted=int(sum(int(n_emit[s]) for s in drafts)),
                 forced_reject=force_reject,
-                program=prog_key,
+                program=prog_key, cycle=self.profiler.cycle_n,
             )
         self._publish_decode_gauges()
 
@@ -5480,6 +5574,7 @@ class Engine:
             self.flight.finish(
                 sl.request.rid, reason, slot=slot, trace=sl.request.trace,
                 tokens=len(gen), preempts=sl.request.preempt_count,
+                cycle=self.profiler.cycle_n,
             )
         if not sl.request.future.done():
             sl.request.future.set_result(result)
@@ -5901,6 +5996,7 @@ class Engine:
         self._publish_memory_state()
         return True
 
+    @_in_phase("launch")
     def _extract_pages(self, pages: list[int]) -> dict[str, np.ndarray]:  # acp: megastep-seam # acp: kv-seam
         """Gather paged KV pages to host numpy, token-major
         ``{"k"/"v": [L, nP, H, d]}`` plus ``"ks"/"vs": [L, nP, H]`` scale
@@ -5934,14 +6030,16 @@ class Engine:
                     a.copy_to_host_async()
         T = len(pages) * P
         out_np: dict[str, np.ndarray] = {}
-        for name in self.cache:
-            parts = [np.asarray(ch[name]) for ch in chunks]
-            merged = np.concatenate(parts, axis=1)  # [L, nP_total, P, ...]
-            out_np[name] = merged.reshape(
-                (cfg.n_layers, T) + merged.shape[3:]
-            )
+        with self.profiler.phase("fetch"):
+            for name in self.cache:
+                parts = [np.asarray(ch[name]) for ch in chunks]
+                merged = np.concatenate(parts, axis=1)  # [L, nP_total, P, ...]
+                out_np[name] = merged.reshape(
+                    (cfg.n_layers, T) + merged.shape[3:]
+                )
         return out_np
 
+    @_in_phase("launch")
     def _extract_rows(self, slot: int, cut: int) -> dict[str, np.ndarray]:  # acp: megastep-seam # acp: kv-seam
         """Slot layout: slice rows [0, cut) of ``slot`` out of the cache to
         host numpy ``{"k"/"v": [L, cut, H, d]}`` (+ scale rows for a
@@ -5976,11 +6074,13 @@ class Engine:
             for a in ch.values():
                 if hasattr(a, "copy_to_host_async"):
                     a.copy_to_host_async()
-        return {
-            name: np.concatenate([np.asarray(ch[name]) for ch in chunks], axis=1)
-            for name in self.cache
-        }
+        with self.profiler.phase("fetch"):
+            return {
+                name: np.concatenate([np.asarray(ch[name]) for ch in chunks], axis=1)
+                for name in self.cache
+            }
 
+    @_in_phase("launch")
     def _swap_in_rows(self, slot: int, entry, start: int, n: int) -> float:  # acp: megastep-seam # acp: kv-seam
         """Restore rows [start, start+n) of a host entry into ``slot``'s
         KV (page-aligned in paged mode — callers schedule page-grain

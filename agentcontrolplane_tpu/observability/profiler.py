@@ -49,8 +49,28 @@ by the engine (``engine.profiler``):
   ``acp_engine_tokens_computed_total{cause=}`` plus the
   ``acp_engine_goodput_ratio`` gauge.
 
+- **engine-cycle phases** — :meth:`phase` is a context manager the engine
+  loop opens at its own boundaries (dispatch granularity, never per slot
+  or per token): ``admit`` (scheduler self time: queue drain, group
+  collection, page allocation, prefix lookup, park sweep, cancels,
+  expiries, chunk planning), ``park`` (waiting on an empty queue),
+  ``launch`` (first host work for one program to the return of its jitted
+  call), ``fetch`` (``jax.device_get`` of a dispatch's results, and this
+  profiler's own sampled ``block_until_ready``), ``commit`` (token
+  consumption, ``on_tokens`` callbacks, finishes, result hand-over) and
+  ``publish`` (watchdog, gauges, mirrors, ledger, armed audit). Each phase
+  has two outputs: a ``jax.profiler.TraceAnnotation`` named ``acp.<name>``
+  carrying ``cycle=<n>`` (``launch`` also ``program=<key>`` and
+  ``call_us``, the offset of the jitted call into the span), which lands
+  on the host plane of a profiler trace on the device trace's clock, and
+  cumulative SELF seconds + count in ``stats()["phases"]``: a phase opened
+  inside another suspends the outer one, so the phases of a cycle
+  partition its wall time. :meth:`cycle` wraps one loop iteration in a
+  ``StepTraceAnnotation`` (``acp.cycle``, ``step_num=<n>``) once it has
+  work; its self time (loop glue no phase covers) is the ``cycle`` row.
+
 Cross-thread contract: the write side (``record``/``account``/
-``reclassify``) runs on the engine thread; the read side (``stats`` /
+``reclassify``/``phase``/``cycle``) runs on the engine thread; the read side (``stats`` /
 ``ledger`` / ``publish``) runs on REST scrape threads and takes the same
 lock — enforced by the acplint thread-ownership pass (read methods are
 declared ``# acp: cross-thread``; server code must go through them, never
@@ -60,6 +80,7 @@ the profiler's privates).
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import threading
 import time
@@ -104,6 +125,60 @@ class _Program:
         self.padded_slots = 0
         self.first_wall_s = 0.0   # first dispatch = trace + compile wall
         self.cold = False         # first dispatch landed AFTER prewarm
+
+
+_NULL_PHASE = contextlib.nullcontext()  # what phase() returns while disabled
+
+
+class _Phase:
+    """One open phase of the engine loop: a trace annotation plus self-time
+    accounting on the profiler's per-thread stack."""
+
+    __slots__ = ("_prof", "name", "_ann", "_self_s", "_resumed", "_opened")
+
+    def __init__(self, prof: "DispatchProfiler", name: str, ann):
+        self._prof = prof
+        self.name = name
+        self._ann = ann
+        self._self_s = 0.0
+        self._resumed = 0.0
+        self._opened = 0.0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        prof = self._prof
+        stack = prof._stack()
+        now = prof._stamp = time.monotonic()
+        if stack:
+            outer = stack[-1]
+            outer._self_s += now - outer._resumed  # the outer phase is suspended
+        self._resumed = self._opened = now
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        prof = self._prof
+        stack = prof._stack()
+        now = prof._stamp = time.monotonic()
+        self._self_s += now - self._resumed
+        stack.pop()
+        if stack:
+            stack[-1]._resumed = now
+        with prof._lock:
+            row = prof._phases.setdefault(self.name, [0.0, 0])
+            row[0] += self._self_s
+            row[1] += 1
+        self._ann.__exit__(*exc)
+
+    def program(self, key: str, called: float) -> None:
+        """Name the compiled program a ``launch`` span dispatched (the key
+        is built after the jitted call returns, as ``record`` has it), and
+        say how many microseconds into the span its jitted call began
+        (``start()``'s stamp): the device cannot have started the program
+        before, which is what ties the device trace's clock to this one."""
+        self._ann.set_metadata(
+            program=key, call_us=int((called - self._opened) * 1e6)
+        )
 
 
 class DispatchProfiler:
@@ -151,8 +226,75 @@ class DispatchProfiler:
         # concurrent publishers can't double-count
         self._pub_tokens: dict[str, int] = {}
         self._pub_prog: dict[tuple[str, str], int] = {}
+        # engine-cycle phases: cumulative self seconds + count per phase
+        # name (under the lock: scrape threads read them), the open phases
+        # of each thread (the engine thread's, in practice), the clock
+        # reading of the latest phase boundary, and the cycle counter
+        self._phases: dict[str, list] = {}
+        self._blocks = 0
+        self._local = threading.local()
+        self._stamp = 0.0
+        self._cycle_phase: Optional[_Phase] = None  # the open acp.cycle
+        self.cycle_n = 0  # cycles begun: the number of the open (else the latest) one
 
     # -- write side (engine thread) ---------------------------------------
+
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def phase(self, name: str):
+        """Open engine-loop phase ``name`` (see the module docstring for
+        the vocabulary): an ``acp.<name>`` trace annotation tagged with the
+        cycle number, and self time into ``stats()["phases"]``. Dispatch
+        granularity only — never per slot or per token."""
+        if not self.enabled:
+            return _NULL_PHASE
+        import jax
+
+        return _Phase(self, name, jax.profiler.TraceAnnotation(
+            "acp." + name, cycle=self.cycle_n if self._cycle_phase else 0,
+        ))
+
+    def cycle(self, busy: bool) -> None:
+        """A new iteration of the engine loop begins. The cycle of the
+        iteration before, if it opened one, closes here; this one's opens
+        at once when ``busy`` (slots to advance, requests to admit), else
+        only if :meth:`begin_cycle` is called (a request arrived while the
+        loop was parked). An iteration that never has work opens none."""
+        self.end_cycle()
+        if busy:
+            self.begin_cycle()
+
+    def begin_cycle(self) -> None:
+        """The iteration in progress has work after all (idempotent):
+        ``acp.cycle`` (a ``StepTraceAnnotation``, ``step_num=<n>``) opens,
+        and ``<n>`` tags every phase and flight event until it closes."""
+        if not self.enabled or self._cycle_phase is not None:
+            return
+        import jax
+
+        self.cycle_n += 1
+        self._cycle_phase = _Phase(self, "cycle", jax.profiler.StepTraceAnnotation(
+            "acp.cycle", step_num=self.cycle_n,
+        ))
+        self._cycle_phase.__enter__()
+
+    def end_cycle(self) -> None:
+        """Close the open cycle (the next iteration's ``cycle()`` does it;
+        the engine loop calls this itself only on its way out)."""
+        if self._cycle_phase is not None:
+            self._cycle_phase.__exit__(None, None, None)
+            self._cycle_phase = None
+
+    def stamp(self) -> float:
+        """The clock reading of the latest phase boundary: for a consumer
+        whose window coincides with phase boundaries (the planner's cycle
+        clock), so that it does not read the clock again. With the
+        profiler disabled, the clock itself."""
+        return self._stamp if self.enabled else time.monotonic()
 
     def start(self) -> float:
         """Stamp a dispatch about to be issued (0.0 when disabled — the
@@ -168,6 +310,7 @@ class DispatchProfiler:
         padded_tokens: int = 0,
         real_slots: int = 0,
         padded_slots: int = 0,
+        blocks: int = 0,
     ) -> None:
         """One dispatch of compiled program ``key``: host wall time since
         ``t0`` plus real/padded token+slot counts. ``out`` (any jax value
@@ -175,7 +318,10 @@ class DispatchProfiler:
         dispatch of a key, whose wall time is the compile cost — block
         until device-ready for a device-inclusive time. Sampling bounds the
         overhead; blocking changes timing only, never values, so profiler
-        on/off stays byte-identical."""
+        on/off stays byte-identical. ``blocks`` is 1 for a dispatch that
+        runs a decode block (split or fused): the denominator of the
+        per-block host time. An open ``launch`` phase is named for
+        ``key``."""
         if not self.enabled or not t0:
             # t0 == 0.0 means start() ran while the profiler was disabled
             # and `enabled` flipped mid-dispatch (bench A/B legs toggle it
@@ -183,17 +329,25 @@ class DispatchProfiler:
             # zero stamp would corrupt the program's stats
             return
         host_s = time.monotonic() - t0
+        stack = self._stack()
+        if stack and stack[-1].name == "launch":
+            stack[-1].program(key, t0)
         with self._lock:
             p = self._programs.get(key)
             first = p is None
             if first:
                 p = self._programs[key] = _Program()
             sample = first or (p.dispatches % self.sample_every == 0)
+            if blocks:
+                self._blocks += blocks
         blocked_s = None
         if sample and out is not None:
             import jax
 
-            jax.block_until_ready(out)
+            # waiting on the device is `fetch`, whoever waits: the sampled
+            # leg must not read as host time of the launch it sits in
+            with self.phase("fetch"):
+                jax.block_until_ready(out)
             blocked_s = time.monotonic() - t0
         cold = False
         wall = blocked_s if blocked_s is not None else host_s
@@ -374,6 +528,10 @@ class DispatchProfiler:
                 }
             waste = dict(self._waste)
             computed, goodput = self._computed, self._goodput
+            phases = {
+                name: {"s": round(row[0], 6), "n": row[1]}
+                for name, row in self._phases.items()
+            }
             doc = {
                 "enabled": self.enabled,
                 "sample_every": self.sample_every,
@@ -389,6 +547,11 @@ class DispatchProfiler:
                     "ratio": round(goodput / computed, 4) if computed else 1.0,
                     "waste": waste,
                 },
+                # engine-cycle phases: self seconds + count per phase, the
+                # busy cycles they partition, and decode-block dispatches
+                "phases": phases,
+                "cycles": self.cycle_n,
+                "blocks": self._blocks,
             }
         return doc
 

@@ -297,3 +297,156 @@ def test_token_conservation_under_fault_matrix():
         )
     finally:
         eng.stop()
+
+
+# -- engine-cycle phases, queue wait, spans on the profiler's trace -----------
+
+
+PHASE_NAMES = {"admit", "park", "launch", "fetch", "commit", "publish", "cycle"}
+
+
+def _serve(eng: Engine, n: int, max_tokens: int = 10) -> list:
+    sp = SamplingParams(temperature=0.0, max_tokens=max_tokens)
+    futs = [eng.submit(f"phase {i} " * 3, sp) for i in range(n)]
+    return [f.result(timeout=600) for f in futs]
+
+
+@pytest.mark.parametrize("kw", [
+    pytest.param({}, id="plain"),
+    pytest.param({"prefill_chunk": 16, "spec_len": 4}, id="chunked-spec-megastep"),
+])
+def test_phases_partition_the_engine_loop(kw):
+    """Every busy cycle launches, fetches and commits at least once, and
+    the phases' self times partition the loop's wall time."""
+    t0 = time.monotonic()
+    eng = make_engine(kv_layout="paged", **kw)
+    try:
+        _serve(eng, 6)
+        _settle(eng)
+        perf = eng.stats()["perf"]
+        wall = time.monotonic() - t0
+    finally:
+        eng.stop()
+    phases = perf["phases"]
+    assert set(phases) <= PHASE_NAMES and {"admit", "launch", "fetch", "commit", "publish"} <= set(phases)
+    assert perf["cycles"] >= 3
+    for name in ("launch", "fetch", "commit", "publish"):
+        assert phases[name]["n"] >= perf["cycles"], (name, phases, perf["cycles"])
+    assert phases["cycle"]["n"] == perf["cycles"]
+    total = sum(row["s"] for row in phases.values())
+    # the loop thread started after t0 and an open park is not yet counted
+    assert 0.9 * (wall - 0.5) <= total <= wall
+    if not kw:
+        assert perf["blocks"] == len(eng.flight.events(kind="decode_block")) > 0
+    # the loop's glue outside every phase is next to nothing
+    assert phases["cycle"]["s"] < 0.05 * total
+
+
+def test_queue_wait_counts_first_admissions_only():
+    eng = make_engine(kv_layout="paged")
+    try:
+        _serve(eng, 2)
+        _settle(eng)
+        before = eng.stats()["scheduler"]["queue_wait"]
+        assert before["n"] == 2 and before["s"] > 0
+        # a forced preemption re-admits its victim: a resume, not an arrival
+        FAULTS.arm("engine.force_preempt", times=1)
+        results = _serve(eng, 7, max_tokens=16)  # 7 over 4 slots: some wait a generation out
+        _settle(eng)
+        after = eng.stats()
+    finally:
+        eng.stop()
+    assert len(results) == 7
+    assert after["preemptions"] >= 1
+    qw = after["scheduler"]["queue_wait"]
+    assert qw["n"] == before["n"] + 7
+    admits = [e for e in eng.flight.events(kind="admit")]
+    assert sum(1 for e in admits if not e["detail"]["resumed"]) == qw["n"]
+    assert sum(1 for e in admits if e["detail"]["resumed"]) >= 1
+    # the counter is the flight recorder's submit -> admit, summed
+    assert qw["s"] > before["s"]
+
+
+def test_flight_events_name_the_cycle_they_were_served_in():
+    eng = make_engine(kv_layout="paged")
+    try:
+        res = _serve(eng, 1, max_tokens=12)[0]
+        _settle(eng)
+        cycles = eng.stats()["perf"]["cycles"]
+        rid = eng.flight.request_ids()[-1]
+        timeline = eng.flight.timeline(rid)
+        blocks = eng.flight.events(kind="decode_block")
+    finally:
+        eng.stop()
+    assert len(res.tokens) == 12
+    tagged = [(e["kind"], e["detail"]["cycle"]) for e in timeline if "cycle" in (e.get("detail") or {})]
+    assert [k for k, _ in tagged] == ["admit", "prefill_done", "finish"]
+    assert 1 <= tagged[0][1] == tagged[1][1] <= tagged[2][1] <= cycles
+    nums = [e["detail"]["cycle"] for e in blocks]
+    assert nums == sorted(nums) and len(set(nums)) == len(nums)  # one decode block a cycle
+    assert nums[-1] == tagged[2][1]  # the request finished in the cycle of its last block
+
+
+def test_disabled_profiler_serves_the_same_tokens_and_records_no_phase(monkeypatch):
+    outs = []
+    for flag in ("1", "0"):
+        monkeypatch.setenv("ACP_PROF", flag)
+        eng = make_engine(kv_layout="paged")
+        try:
+            outs.append([r.tokens for r in _serve(eng, 3)])
+            _settle(eng)
+            st = eng.stats()
+        finally:
+            eng.stop()
+        if flag == "0":
+            assert st["perf"]["phases"] == {} and st["perf"]["cycles"] == 0
+            # the counter of the scheduler does not hang on the profiler
+            assert st["scheduler"]["queue_wait"]["n"] == 3
+            assert st["cycle_s"] > 0  # the planner's clock falls back to its own reads
+    assert outs[0] == outs[1]
+
+
+def test_spans_land_on_the_host_plane_of_a_profiler_trace(tmp_path):
+    """`jax.profiler` around a serving engine: `acp.cycle` and its children
+    are on the host plane, with increasing cycle numbers, the launches named
+    for their programs."""
+    import glob
+
+    eng = make_engine(kv_layout="paged")
+    try:
+        _serve(eng, 3)  # compile outside the trace
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            _serve(eng, 3)
+            _settle(eng)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    path = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("acp."):
+                    spans.append((e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats)))
+    cycles = sorted(s for s in spans if s[2] == "acp.cycle")
+    assert len(cycles) >= 3
+    steps = [s[3]["step_num"] for s in cycles]
+    assert steps == sorted(steps) and len(set(steps)) == len(steps)
+    kids = {"acp.admit", "acp.launch", "acp.fetch", "acp.commit", "acp.publish"}
+    for start, end, _, stats in cycles:
+        inside = [s for s in spans if s[2] != "acp.cycle" and start <= s[0] and s[1] <= end]
+        assert {s[2] for s in inside} >= kids - {"acp.admit"} or not inside
+        assert all(s[3]["cycle"] == stats["step_num"] for s in inside)
+    assert kids <= {s[2] for s in spans}
+    launches = [s for s in spans if s[2] == "acp.launch" and "program" in s[3]]
+    # the jitted call began inside the span: what ties the device trace's clock to this one
+    assert launches and all(0 <= s[3]["call_us"] * 1000 <= s[1] - s[0] for s in launches)
+    programs = {s[3].get("program") for s in spans if s[2] == "acp.launch"}
+    assert any(p and p.startswith("decode[paged,") for p in programs)
+    assert any(p and p.startswith("prefill[paged,") for p in programs)
