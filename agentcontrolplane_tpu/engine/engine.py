@@ -495,6 +495,14 @@ class Engine:
         from ..xla_cache import enable_persistent_compilation_cache
 
         enable_persistent_compilation_cache()
+        # Every op the engine's programs lower keeps its name stack, not
+        # ten Python frames: the frames ride into the HLO's metadata, and
+        # the profiler pays for them on every op event when a trace is
+        # stopped — a 6 s trace of the 7B's decode took 45.6 s to stop with
+        # them, 30.8 s without (PERF.md, PR 28), and that time grows with
+        # every step the engine gets faster. Kernel and program names (what
+        # trace readers match) are unchanged.
+        jax.config.update("jax_traceback_in_locations_limit", 0)
         self._coordination = coordination
         self._coord_follower = coordination is not None and hasattr(coordination, "recv")
         self.decode_block_size = max(1, decode_block_size)
@@ -705,6 +713,27 @@ class Engine:
                     "paged-decode path dispatches the int8 Pallas walk",
                 )
                 self._kernel_fallback_reason = reason
+            # The walk's width is static, chosen by the kernel from what it
+            # sees on one device (rows of a page a rank holds, page dtype,
+            # KV heads a chip): report the G this engine's walks compile
+            # with, so a geometry that fell to one page a turn is seen in
+            # stats() and the log, not guessed. 0: no kernel (reference).
+            self.pages_per_turn = 0
+            if self._use_pallas:
+                from ..ops.pallas.paged_attention import pages_per_turn
+
+                axes = dict(self.mesh.shape)
+                k = self.cache["k"]  # [L, num_pages, page_size, H_kv, d]
+                rows = k.shape[2] // axes.get("sp", 1)  # of a page, a rank
+                self.pages_per_turn = pages_per_turn(
+                    rows, k.dtype, k.shape[3] // axes.get("tp", 1), k.shape[4],
+                    self.quantize_kv,
+                )
+                log.info(
+                    "paged decode: Pallas page walk, pages_per_turn=%d "
+                    "(%d tokens a turn)",
+                    self.pages_per_turn, self.pages_per_turn * rows,
+                )
         log.info("engine init: params+cache in %.1fs", time.monotonic() - t0)
 
         # computed ON device (jit + out_shardings) rather than device_put so
@@ -2048,6 +2077,8 @@ class Engine:
                 # behind a property the AST pass can't see through
                 "free": self._allocator.free_count,  # acp-lint: disable=thread-ownership
                 "page_size": self.page_size,
+                # pages one turn of the compiled walk folds (0: no kernel)
+                "pages_per_turn": self.pages_per_turn,
                 "table_uploads": self.table_uploads,
             }
         if self._prefix_enabled:

@@ -14,15 +14,21 @@ cache pages a read-only operand: the engine's decode step commits all
 layers' new K/V with one scatter after the layer scan instead of writing
 pages before every attention call (see models/llama.py decode_step_paged).
 
-Grid: one program per slot. Per-program working set is
-2 (double buffer) x 2 (K+V) x [page_size, H_kv * d] — a few hundred KB in
-VMEM for Llama-3-8B geometry (page 16, 8 KV heads, d 128).
+Grid: one program per slot. A turn of the walk covers G pages, G chosen
+so that a turn is one 128-lane tile of tokens (``pages_per_turn``: 8 at the
+engine's page 16, 1 at page 128): the turn's pages are DMA'd each into its
+own row window of one ``[G * P, H_kv * d]`` buffer, and the body runs one
+pair of products per KV head over all of them. The walk is bound by what a
+turn costs (DMA waits, the chained softmax update, MXU fill and drain), not
+by bytes, so fewer, fuller turns are the lever. Per-program working set is
+NBUF x 2 (K+V) x [G * P, H_kv * d] — 1 MB in VMEM for Qwen2.5-7B geometry
+(page 16, 4 KV heads, d 128, bf16).
 
 Geometry note: the kernel targets head_dim % 128 == 0 (the TPU lane width;
 128 for llama/qwen/mistral, 256 for gemma); the engine falls back to the
 XLA reference otherwise. The body is a static loop over the KV heads: each
-takes its lane-aligned ``[P, d]`` column window of the page buffer and two
-plain 2-D products with its ``[n_rep, d]`` query group. Every shape in the
+takes its lane-aligned ``[G * P, d]`` column window of the turn's buffer and
+two plain 2-D products with its ``[n_rep, d]`` query group. Every shape in the
 body is 2-D because that is what Mosaic lays out — the earlier grouped
 form (``p.reshape(P, H_kv, n_rep)[..., None] * v[:, :, None, :]``) passed
 every interpret-mode test and was refused by the chip's compiler at every
@@ -58,11 +64,35 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-NBUF = 4  # DMA pipeline depth: NBUF-1 page fetches kept in flight per walk
-# Both in-kernel products run at f32 contract precision: the walk is the
-# same f32 math as the XLA reference, not a bf16-pass approximation of it
-# (the operands are tiny — [n_rep, d] x [d, P] — so the MXU passes are free).
+NBUF = 4  # DMA pipeline depth: NBUF-1 turns' fetches kept in flight per walk
+LANES = 128  # a turn of the walk covers one lane tile of tokens
+# scratch the walk may claim of the 16 MiB scoped VMEM a kernel gets by
+# default; the rest is the body's f32 windows and the pipelined q / outputs
+_SCRATCH_BUDGET = 8 << 20
+# In-kernel products run at f32 contract precision: the walk is the same
+# f32 math as the XLA reference, not a bf16-pass approximation of it. The
+# one exception is exact: q . k with both sides bf16 (see the KV-head loop).
 _F32 = jax.lax.Precision.HIGHEST
+
+
+def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False) -> int:
+    """G, the pages one turn of the walk fetches and folds: as many as make
+    a turn one lane tile of tokens, from what the kernel can see alone.
+
+    G = 1 (a page a turn, each page DMA'd into a whole buffer) where a page
+    cannot land on a whole-tile row window of a shared buffer — its rows
+    must be a multiple of the dtype's sublane tile: 8 for f32, 16 for bf16,
+    32 for int8 — and for int8 pages at any size: their scale rows are laid
+    out head-major per page and do not follow a G-page turn. G halves until
+    the K and V scratch fits ``_SCRATCH_BUDGET``.
+    """
+    itemsize = jnp.dtype(dtype).itemsize
+    G = max(1, LANES // P_local)
+    if quantized or P_local % (32 // itemsize):
+        G = 1
+    while G > 1 and 2 * NBUF * G * P_local * H_kv * d * itemsize > _SCRATCH_BUDGET:
+        G //= 2
+    return G
 
 
 def _kernel(
@@ -82,8 +112,8 @@ def _kernel(
     # m_ref:   [1, H_kv, n_rep, 1] f32 — running max
     # l_ref:   [1, H_kv, n_rep, 1] f32 — running denominator
     # scratch
-    # k_buf / v_buf: [NBUF, P_local, H_kv * d] (VMEM)
-    # quantized=True only: ks_buf / vs_buf [NBUF, 1, SC] f32 (VMEM)
+    # k_buf / v_buf: [NBUF, G * P_local, H_kv * d] (VMEM) — a turn's G pages
+    # quantized=True only (G == 1): ks_buf / vs_buf [NBUF, 1, SC] f32 (VMEM)
     # sems: DMA sems [NBUF, 4 if quantized else 2]
     *rest,
     page_size: int,  # GLOBAL page size (pages hold this many tokens)
@@ -112,70 +142,109 @@ def _kernel(
     _, n_kv_heads, n_rep, d = q_ref.shape
     P = k_pages_ref.shape[1]  # local slice length
     pos_base = pos_base_ref[0]
-    NBUF = k_buf.shape[0]
+    NBUF, T = k_buf.shape[:2]  # T = G * P tokens a turn
+    G = T // P
+    n_turns = jax.lax.div(n_pages + G - 1, G)
 
     scale = 1.0 / (d**0.5)
-    # one [n_rep, d] query group per KV head, pre-scaled once
-    qs = [q_ref[0, h].astype(jnp.float32) * scale for h in range(n_kv_heads)]
+    # q . k in one bf16 MXU pass where both sides are bf16 (int8 widens to
+    # bf16 exactly): bf16 x bf16 products are exact in the f32 accumulator,
+    # so with 1/sqrt(d) applied to the f32 logits this is the f32 product.
+    # f32 q or pages take the f32 contract with q pre-scaled once.
+    one_pass = q_ref.dtype == jnp.bfloat16 and k_buf.dtype in (jnp.bfloat16, jnp.int8)
+    if one_pass:
+        qs = [q_ref[0, h] for h in range(n_kv_heads)]
+    else:
+        qs = [q_ref[0, h].astype(jnp.float32) * scale for h in range(n_kv_heads)]
 
-    def start_fetch(j, slot):
-        page = block_tables_ref[s, j]
-        pltpu.make_async_copy(k_pages_ref.at[page], k_buf.at[slot], sems.at[slot, 0]).start()
-        pltpu.make_async_copy(v_pages_ref.at[page], v_buf.at[slot], sems.at[slot, 1]).start()
-        if quantized:
-            pltpu.make_async_copy(ks_pages_ref.at[page], ks_buf.at[slot], sems.at[slot, 2]).start()
-            pltpu.make_async_copy(vs_pages_ref.at[page], vs_buf.at[slot], sems.at[slot, 3]).start()
+    def fetch(t, slot, act):  # act: "start" or "wait", every DMA of turn t
+        # a turn's DMAs: page t*G+g of the block table into rows
+        # [g*P, (g+1)*P) of the turn's buffer. The caller holds t < n_turns,
+        # so the turn's first page is live; the rest are guarded one by one
+        # (G = 1: one unguarded page into the whole buffer).
+        for g in range(G):
+            def run(g=g):
+                page = block_tables_ref[s, t * G + g]
+                rows = pl.ds(g * P, P)
+                copies = [
+                    (k_pages_ref.at[page], k_buf.at[slot, rows]),
+                    (v_pages_ref.at[page], v_buf.at[slot, rows]),
+                ]
+                if quantized:
+                    copies += [
+                        (ks_pages_ref.at[page], ks_buf.at[slot]),
+                        (vs_pages_ref.at[page], vs_buf.at[slot]),
+                    ]
+                for i, (src, dst) in enumerate(copies):
+                    getattr(pltpu.make_async_copy(src, dst, sems.at[slot, i]), act)()
 
-    def wait_fetch(j, slot):
-        page = block_tables_ref[s, j]
-        pltpu.make_async_copy(k_pages_ref.at[page], k_buf.at[slot], sems.at[slot, 0]).wait()
-        pltpu.make_async_copy(v_pages_ref.at[page], v_buf.at[slot], sems.at[slot, 1]).wait()
-        if quantized:
-            pltpu.make_async_copy(ks_pages_ref.at[page], ks_buf.at[slot], sems.at[slot, 2]).wait()
-            pltpu.make_async_copy(vs_pages_ref.at[page], vs_buf.at[slot], sems.at[slot, 3]).wait()
+            if g == 0:
+                run()
+            else:
+                pl.when(t * G + g < n_pages)(run)
 
-    # page walks are small-transfer latency-bound: keep NBUF-1 fetches in
-    # flight (ramp pages 0..NBUF-2 here, steady state issues j+NBUF-1)
-    def ramp(j, _):
-        @pl.when(j < n_pages)
+    if G > 1:
+        # Rows of a turn's buffer that no DMA wrote (the pages past n_pages
+        # in the walk's last turn) carry weight 0 into p . v, and 0 x NaN is
+        # NaN: start every walk from a zeroed V scratch. A buffer's later
+        # turns leave only fetched, finite rows behind. K needs none of
+        # this: its logits are replaced by the mask, not multiplied.
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+    # page walks are small-transfer latency-bound: keep NBUF-1 turns'
+    # fetches in flight (ramp turns 0..NBUF-2 here, steady state issues
+    # t+NBUF-1)
+    def ramp(t, _):
+        @pl.when(t < n_turns)
         def _():
-            start_fetch(j, j)
+            fetch(t, t, "start")
         return 0
 
     jax.lax.fori_loop(0, NBUF - 1, ramp, 0)
 
-    def body(j, carry):
-        slot = jax.lax.rem(j, NBUF)
-        # issue the deepest prefetch; its buffer was consumed at j-1
-        nxt = j + NBUF - 1
+    # token position of a turn's column c, less the turn's first: row c % P
+    # of the turn's page c // P, pages page_size tokens apart (sp=1: c)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    col = lane
+    if page_size != P:
+        for g in range(1, G):
+            col = col + jnp.where(lane >= g * P, page_size - P, 0)
 
-        @pl.when(nxt < n_pages)
+    def body(t, carry):
+        slot = jax.lax.rem(t, NBUF)
+        # issue the deepest prefetch; its buffer was consumed at t-1
+        nxt = t + NBUF - 1
+
+        @pl.when(nxt < n_turns)
         def _():
-            start_fetch(nxt, jax.lax.rem(nxt, NBUF))
+            fetch(nxt, jax.lax.rem(nxt, NBUF), "start")
 
-        wait_fetch(j, slot)
-        pos = (
-            j * page_size + pos_base
-            + jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
-        )
-        valid = pos < seq_len  # [1, P]
+        fetch(t, slot, "wait")
+        pos = t * (G * page_size) + pos_base + col
+        valid = pos < seq_len  # [1, T]
         if quantized:
             ks = ks_buf[slot]  # [1, >= H_kv * P], head-major
             vs = vs_buf[slot]
         out = []
-        # Static loop over the KV heads. Each takes its lane-aligned [P, d]
-        # column window of the page buffer (d % 128 == 0) and two plain 2-D
-        # products with its [n_rep, d] query group — the only shapes in the
-        # body are 2-D, which is what Mosaic lays out (a 4-D grouped
+        # Static loop over the KV heads. Each takes its lane-aligned [T, d]
+        # column window of the turn's buffer (d % 128 == 0) and two plain
+        # 2-D products with its [n_rep, d] query group — the only shapes in
+        # the body are 2-D, which is what Mosaic lays out (a 4-D grouped
         # reshape of the logits is refused: "unsupported shape cast").
         for h in range(n_kv_heads):
             m, l, acc = carry[h]  # [n_rep,1], [n_rep,1], [n_rep,d]
-            k = k_buf[slot, :, h * d:(h + 1) * d].astype(jnp.float32)  # [P, d]
+            k = k_buf[slot, :, h * d:(h + 1) * d]  # [T, d]
             v = v_buf[slot, :, h * d:(h + 1) * d].astype(jnp.float32)
-            logits = jax.lax.dot_general(
-                qs[h], k, (((1,), (1,)), ((), ())),
-                precision=_F32, preferred_element_type=jnp.float32,
-            )  # [n_rep, P]
+            if one_pass:
+                logits = jax.lax.dot_general(
+                    qs[h], k.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [n_rep, T]
+            else:
+                logits = jax.lax.dot_general(
+                    qs[h], k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                    precision=_F32, preferred_element_type=jnp.float32,
+                )
             if quantized:
                 # the per-row scale factors out of both products: scale the
                 # [n_rep, P] logits and weights by this head's [1, P] scale
@@ -185,7 +254,7 @@ def _kernel(
                 logits = logits * ks[:, h * P:(h + 1) * P]
             logits = jnp.where(valid, logits, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
-            p = jnp.exp(logits - m_new)  # [n_rep, P]
+            p = jnp.exp(logits - m_new)  # [n_rep, T]
             correction = jnp.exp(m - m_new)  # [n_rep, 1]
             l = l * correction + jnp.sum(p, axis=1, keepdims=True)
             pw = p * vs[:, h * P:(h + 1) * P] if quantized else p
@@ -203,7 +272,7 @@ def _kernel(
         )
         for _ in range(n_kv_heads)
     )
-    state = jax.lax.fori_loop(0, n_pages, body, init)
+    state = jax.lax.fori_loop(0, n_turns, body, init)
     for h, (m, l, acc) in enumerate(state):
         acc_ref[0, h] = acc
         m_ref[0, h] = m
@@ -253,9 +322,10 @@ def _paged_state(
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
+    G = pages_per_turn(P, k_pages.dtype, H_kv, d, quantized)
     scratch_shapes = [
-        pltpu.VMEM((NBUF, P, H_kv * d), k_pages.dtype),
-        pltpu.VMEM((NBUF, P, H_kv * d), v_pages.dtype),
+        pltpu.VMEM((NBUF, G * P, H_kv * d), k_pages.dtype),
+        pltpu.VMEM((NBUF, G * P, H_kv * d), v_pages.dtype),
     ]
     operands = [
         block_tables,

@@ -298,7 +298,8 @@ async def phase_serve(layout: str, device) -> dict:
     if layout == "paged":
         check(engine._use_pallas, phase,
               "the paged engine did not take the Pallas page walk on a TPU")
-        say(phase, "engine._use_pallas=True: decode walks pages with the compiled kernel")
+        say(phase, "engine._use_pallas=True: decode walks pages with the compiled kernel, "
+                   f"pages_per_turn={engine.stats()['kv_pages']['pages_per_turn']}")
     t0 = time.monotonic()
     prewarm = cli.EnginePrewarm(engine)
     prewarm.start()

@@ -25,7 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from agentcontrolplane_tpu.models.llama import PRESETS
 
-PAGE, SLOTS, MAX_PAGES, NUM_PAGES = 16, 8, 16, 256
+# MAX_PAGES over 128 // PAGE: a walk of eight pages a turn has several turns
+PAGE, SLOTS, MAX_PAGES, NUM_PAGES = 16, 8, 24, 256
 
 
 @pytest.fixture(scope="module")
@@ -126,6 +127,8 @@ _WALKS = [
     # one KV head per chip: the divisibility edge of the shard_map wrapper
     ("walk-qwen2.5-7b-bf16-tp4", 28, 4, 128, jnp.bfloat16, False, 4),
     ("walk-qwen2.5-7b-int8-tp4", 28, 4, 128, jnp.bfloat16, True, 4),
+    # what the q32b-tp4-decode cell runs: 2 KV heads, n_rep 5 a chip
+    ("walk-qwen2.5-32b-bf16-tp4", 40, 8, 128, jnp.bfloat16, False, 4),
 ]
 
 

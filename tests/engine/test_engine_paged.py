@@ -167,3 +167,12 @@ def test_pass1_reclaims_other_slots_lookahead_pages():
     finally:
         eng._slots.clear()
         eng.stop()
+
+
+def test_stats_name_the_walks_pages_per_turn(engines):
+    """`kv_pages.pages_per_turn` is the G the compiled page walk runs with;
+    on the CPU no walk is compiled (the XLA reference serves): 0."""
+    slot, paged = engines
+    assert not paged._use_pallas
+    assert paged.stats()["kv_pages"]["pages_per_turn"] == 0
+    assert "kv_pages" not in slot.stats()

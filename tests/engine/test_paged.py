@@ -272,3 +272,168 @@ def test_shared_parity_helper_interpret(dtype, int8, plus_new):
     got = page_walk_parity(case, plus_new=plus_new, interpret=True)
     assert got["ok"], got
     assert got["shape"] == (3, 4, 8) and len(got["seq_lens"]) == 3
+
+
+def _straddle_case(dtype, seed=21, P=16, H=4, Hkv=2, d=8):
+    """One batch of ragged contexts that straddle a turn of the walk:
+    lengths 0 (an inactive slot), 1, P-1, P, G*P-1, G*P, G*P+1, 3*G*P+5.
+    Every pool page that no block table names — the trash page the tables'
+    padding names is the one exception — is NaN, so a read of a wrong page
+    fails loudly; rows of a turn's buffer that no DMA wrote are NaN in
+    interpret mode (uninitialized scratch), so an unfetched row does too."""
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import pages_per_turn
+
+    G = pages_per_turn(P, dtype, Hkv, d)
+    T = G * P
+    seq_lens = np.asarray([0, 1, P - 1, P, T - 1, T, T + 1, 3 * T + 5], dtype=np.int32)
+    S, max_pages = len(seq_lens), 3 * G + 2
+    num_pages = int(sum(-(-int(n) // P) for n in seq_lens)) + 9
+    rng = np.random.default_rng(seed)
+    k_pages = np.full((num_pages, P, Hkv, d), np.nan, dtype=np.float32)
+    v_pages = np.full((num_pages, P, Hkv, d), np.nan, dtype=np.float32)
+    k_pages[TRASH_PAGE] = v_pages[TRASH_PAGE] = 0.0
+    alloc = PageAllocator(num_pages)
+    tables = np.full((S, max_pages), TRASH_PAGE, dtype=np.int32)
+    # scatter: interleave the slots' pages so no walk reads a contiguous run
+    order = [(s, j) for s in range(S) for j in range(-(-int(seq_lens[s]) // P))]
+    rng.shuffle(order)
+    for s, j in order:
+        (page,) = alloc.alloc(1)
+        tables[s, j] = page
+        # whole pages are written (rows past seq_len hold finite stale data,
+        # as a recycled page does in the engine)
+        k_pages[page] = rng.normal(size=(P, Hkv, d))
+        v_pages[page] = rng.normal(size=(P, Hkv, d))
+    as_dt = lambda x: jnp.asarray(x, dtype=dtype)  # noqa: E731
+    return dict(
+        G=G,
+        q=as_dt(rng.normal(size=(S, H, d))),
+        k_pages=as_dt(k_pages), v_pages=as_dt(v_pages),
+        tables=jnp.asarray(tables), seq_lens=jnp.asarray(seq_lens),
+        k_new=as_dt(rng.normal(size=(S, Hkv, d))),
+        v_new=as_dt(rng.normal(size=(S, Hkv, d))),
+    )
+
+
+@pytest.mark.parametrize("plus_new", [False, True], ids=["plain", "cache-plus-new"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_walk_parity_contexts_straddling_a_turn(dtype, plus_new):
+    """A turn of the walk covers G pages (one lane tile of tokens): contexts
+    on both sides of every turn edge, in one batch, against a reference fed
+    only the rows the block tables name."""
+    from agentcontrolplane_tpu.engine.kernel_parity import TOLERANCE
+    from agentcontrolplane_tpu.ops.paged import (
+        paged_decode_attention_reference_cache_plus_new,
+    )
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_cache_plus_new,
+    )
+
+    c = _straddle_case(jnp.dtype(dtype))
+    assert c["G"] == 128 // 16
+    # the reference gathers whole tables: give it the same pool with the
+    # unnamed pages zeroed (its mask then drops them exactly)
+    clean = lambda x: jnp.nan_to_num(x.astype(jnp.float32)).astype(x.dtype)  # noqa: E731
+    args = [c["q"], c["k_pages"], c["v_pages"], c["tables"], c["seq_lens"]]
+    ref_args = [c["q"], clean(c["k_pages"]), clean(c["v_pages"]), c["tables"], c["seq_lens"]]
+    if plus_new:
+        kernel, reference = (
+            paged_decode_attention_cache_plus_new,
+            paged_decode_attention_reference_cache_plus_new,
+        )
+        args += [c["k_new"], c["v_new"]]
+        ref_args += [c["k_new"], c["v_new"]]
+    else:
+        kernel, reference = paged_decode_attention, paged_decode_attention_reference
+    out = np.asarray(kernel(*args, interpret=True).astype(jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(reference(*ref_args).astype(jnp.float32))
+    live = np.asarray(c["seq_lens"]) > 0
+    if plus_new:
+        live[:] = True  # the self term gives an empty slot its one token
+    assert np.isfinite(out[live]).all(), "a walk read an unnamed page or an unfetched row"
+    atol = {"float32": 1e-5, "bfloat16": TOLERANCE["bfloat16"]}[dtype]
+    np.testing.assert_allclose(out[live], ref[live], rtol=0, atol=atol)
+    if not plus_new:
+        # an inactive slot walks nothing: acc 0 over the floor of l
+        np.testing.assert_array_equal(out[~live], 0.0)
+
+
+@pytest.mark.parametrize(
+    "P_local,dtype,H_kv,d,quantized,want",
+    [
+        (16, "bfloat16", 4, 128, False, 8),   # the engine's page: a lane tile a turn
+        (16, "float32", 8, 128, False, 8),
+        (128, "bfloat16", 4, 128, False, 1),  # a page is a tile already
+        (8, "bfloat16", 4, 128, False, 1),    # sp=2 slice of page 16: under a bf16 tile
+        (8, "float32", 4, 128, False, 16),    # the same slice in f32: on its tile
+        (16, "int8", 4, 128, True, 1),        # int8 tile is 32 rows; scale rows per page
+        (32, "int8", 4, 128, True, 1),
+        (16, "float32", 32, 128, False, 4),   # MHA 32 heads f32: scratch over budget, halved
+    ],
+)
+def test_pages_per_turn_rule(P_local, dtype, H_kv, d, quantized, want):
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import pages_per_turn
+
+    assert pages_per_turn(P_local, jnp.dtype(dtype), H_kv, d, quantized) == want
+
+
+def test_excluded_geometry_walks_one_page_a_turn():
+    """int8 pages stay at G = 1 — the scratch the walk allocates is one
+    page deep — and still match the reference."""
+    from agentcontrolplane_tpu.engine.kernel_parity import make_paged_case, page_walk_parity
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    case = make_paged_case(
+        5, S=4, H=4, H_kv=2, d=8, P=16, max_pages=10, num_pages=64,
+        dtype=jnp.bfloat16, int8=True,
+    )
+    jaxpr = jax.make_jaxpr(
+        lambda c: pa.paged_decode_attention(
+            c["q"], c["k_pages"], c["v_pages"], c["block_tables"], c["seq_lens"],
+            interpret=True, **c["scales"],
+        )
+    )(case)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    k_buf = call.params["jaxpr"].invars[-5].aval  # k_buf, v_buf, ks_buf, vs_buf, sems
+    assert k_buf.shape == (pa.NBUF, 16, 2 * 8), k_buf.shape
+    assert page_walk_parity(case, plus_new=True, interpret=True)["ok"]
+
+
+def test_sp_slices_walk_a_tile_a_turn_interpret():
+    """Context-parallel slices that land on their dtype's tile (f32, page
+    16 over sp=2: 8 rows a rank, 16 pages a turn): a turn's columns lie
+    page_size tokens apart page to page, and the ranks' (acc, m, l) merge
+    to the exact reference."""
+    from agentcontrolplane_tpu.ops.paged import (
+        paged_decode_attention_reference_cache_plus_new,
+    )
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_cache_plus_new_sharded,
+        pages_per_turn,
+    )
+    from agentcontrolplane_tpu.parallel.mesh import make_mesh
+
+    assert pages_per_turn(16 // 2, jnp.float32, 2, 8) == 16
+    S, H, Hkv, d, P, G = 3, 4, 2, 8, 16, 16
+    # under one turn, past one, past two; pages handed out in table order
+    seq_lens = np.asarray([G * P + 3, 7, 2 * G * P + 2 * P + 1], dtype=np.int32)
+    tables = np.full((S, 2 * G + 3), TRASH_PAGE, dtype=np.int32)
+    nxt = 1
+    for s in range(S):
+        n = -(-int(seq_lens[s]) // P)
+        tables[s, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    rng = np.random.default_rng(13)
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype=jnp.float32)  # noqa: E731
+    q, k_pages, v_pages = f32(S, H, d), f32(nxt, P, Hkv, d), f32(nxt, P, Hkv, d)
+    k_new, v_new = f32(S, Hkv, d), f32(S, Hkv, d)
+    tables, seq_lens = jnp.asarray(tables), jnp.asarray(seq_lens)
+    ref = paged_decode_attention_reference_cache_plus_new(
+        q, k_pages, v_pages, tables, seq_lens, k_new, v_new
+    )
+    mesh = make_mesh({"sp": 2, "tp": 1}, devices=jax.devices()[:2])
+    out = paged_decode_attention_cache_plus_new_sharded(
+        mesh, q, k_pages, v_pages, tables, seq_lens, k_new, v_new, interpret=True
+    )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
