@@ -1,11 +1,16 @@
-"""Decides `correct`, outside the window.
+"""Decides `correct`, outside the window. Knows no model family: the
+program's side and the reference's come from the module the
+configuration's file names (`acpbench/families/`).
 
-The program's own model programs (`prefill_paged_batch`, then
-`decode_step_paged` through a paged pool with the page walk the engine
-uses, on the engine's mesh) run a seeded sample of sequences: prefill,
-then teacher-forced decode steps through the cache. Their logits are held
-against `reference.py`'s float32 pass over whole sequences. Logits, not
-tokens: with random weights the largest logit changes on rounding.
+The program's own model programs (the family's `cached_logits`: its
+prefill, then its decode step through its cache with the kernels the
+engine uses, on the engine's mesh) run a seeded sample of sequences:
+prefill, then teacher-forced decode steps through the cache. Their logits
+are held against the family's plain reference, a float32 pass over whole
+sequences (`reference` below: `(tokens, rows, lower=None) -> logits`, the
+family's `reference_logits` over a run's configuration and weights).
+Logits, not tokens: with random weights the largest logit changes on
+rounding.
 
 The cell's own path is held to the reference too: the sample's prompts go
 through `submit` as greedy requests, admitted as one group, so that the
@@ -76,55 +81,6 @@ def page_ids(s: dict, lengths) -> np.ndarray:
     return out
 
 
-def program_logits(params, llama_config, mesh, use_pallas: bool, page_size: int,
-                   s: dict, quantize_kv: bool = False):
-    """(pre [B, N+1, V], dec [B, N, V]) float32 from the program: prefills
-    of the prompt and of the prompt plus 1..N forced tokens (row j predicts
-    token length + j), then N decode steps from the prompt's prefill
-    (step j is the cache's reading of row j + 1)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from agentcontrolplane_tpu.models.llama import (
-        decode_step_paged, init_paged_cache, prefill_paged_batch,
-    )
-
-    rep = NamedSharding(mesh, P())
-    page_sh = NamedSharding(mesh, P(None, None, None, "tp", None))
-    shardings = {"k": page_sh, "v": page_sh}
-    if quantize_kv:
-        shardings["ks"] = shardings["vs"] = NamedSharding(mesh, P(None, None, None, "tp"))
-    pool = jax.jit(lambda: init_paged_cache(llama_config, s["pool_pages"], page_size, quantize_kv=quantize_kv),
-                   out_shardings=shardings)()
-    put = lambda a: jax.device_put(jnp.asarray(a), rep)  # noqa: E731
-    prefill = jax.jit(lambda p, pages, t, n, ids: prefill_paged_batch(p, pages, t, n, ids, llama_config),
-                      donate_argnums=(1,))
-    decode = jax.jit(
-        lambda p, pages, t, n, tb: decode_step_paged(
-            p, pages, t, n, tb, jnp.ones(t.shape, bool), llama_config, use_pallas=use_pallas, mesh=mesh),
-        donate_argnums=(1,))
-    T, N, lengths = s["T"], s["N"], s["lengths"]
-
-    def prefilled(extra: int):
-        nonlocal pool
-        n = lengths + extra
-        prompt = np.where(np.arange(T)[None, :] < n[:, None], s["tokens"][:, :T], 0)
-        pool, logits = prefill(params, pool, put(prompt), put(n), put(page_ids(s, n)))
-        return logits.astype(jnp.float32)
-
-    # the longer prefills first: the last one leaves the pool as a request
-    # of `lengths` tokens would, and the decode steps go on from there
-    pre = [prefilled(j) for j in range(N, -1, -1)][::-1]
-    dec = []
-    tables = put(s["tables"])
-    for j in range(N):
-        forced = s["tokens"][np.arange(s["B"]), lengths + j]
-        pool, logits = decode(params, pool, put(forced), put(lengths + j), tables)
-        dec.append(logits.astype(jnp.float32))
-    return jnp.stack(pre, axis=1), jnp.stack(dec, axis=1)
-
-
 def engine_path(system, s: dict, n_tokens: int) -> dict:
     """The sample's prompts as greedy requests through the system's own
     `submit`, admitted together: what each emitted, as streamed and as
@@ -150,14 +106,12 @@ def swapped_page(s: dict, tokens: np.ndarray) -> np.ndarray:
     return out
 
 
-def engine_numbers(params, model: dict, s: dict, path: dict, control: bool = False) -> dict:
+def engine_numbers(reference, s: dict, path: dict, control: bool = False) -> dict:
     """`greedy_regret` and `stream_mismatch` of what the engine emitted.
     With `control`, the emitter judged is the reference itself reading a
     swapped page (its first choice at every position, the engine's tokens
     as context), in the engine's place."""
     import jax.numpy as jnp
-
-    from . import reference
 
     emitted = path["returned"]
     R = max(1, max(len(e) for e in emitted))
@@ -171,9 +125,9 @@ def engine_numbers(params, model: dict, s: dict, path: dict, control: bool = Fal
         tokens[b, n: n + len(e)] = e
         valid[b, : len(e)], picked[b, : len(e)] = True, e
     rows = s["lengths"][:, None] - 1 + np.arange(R)[None, :]
-    want = reference.logits(params, model, tokens, rows)
+    want = reference(tokens, rows)
     if control:
-        picked = np.asarray(jnp.argmax(reference.logits(params, model, swapped_page(s, tokens), rows), -1))
+        picked = np.asarray(jnp.argmax(reference(swapped_page(s, tokens), rows), -1))
     chosen = jnp.take_along_axis(want, jnp.asarray(picked)[..., None], axis=-1)[..., 0]
     regret = (jnp.max(want, -1) - chosen) / jnp.std(want, -1)
     mismatch = sum(
@@ -188,16 +142,14 @@ def engine_numbers(params, model: dict, s: dict, path: dict, control: bool = Fal
     }
 
 
-def reference_logits(params, model: dict, s: dict, lower: str | None = None):
-    from . import reference
-
+def reference_logits(reference, s: dict, lower: str | None = None):
     # sequence b is tokens[b, : length + N]; what lies beyond is never read
     # by a compared row (causal), so the one [B, T + N] array serves all
-    return reference.logits(params, model, s["tokens"], s["rows"], lower=lower)
+    return reference(s["tokens"], s["rows"], lower=lower)
 
 
 def compare(got, want) -> dict:
-    """`got` is (pre, dec) as `program_logits` gives them, or one
+    """`got` is (pre, dec) as a family's `cached_logits` gives them, or one
     [B, N+1, V] array from a reference in a lower precision, which has no
     cache and reads 0 for `cache_excess`."""
     import jax.numpy as jnp

@@ -4,7 +4,7 @@ batching tricks, no kernel. Written from the published description
 (Qwen2.5 / Llama: RMSNorm, biased QKV, split-half RoPE, grouped-query
 causal softmax attention, SwiGLU, untied head), not from the program.
 
-It takes the weights `weights.py` drew from the seed (int8 values times
+It takes the weights `llama_weights.py` drew from the seed (int8 values times
 their per-channel scales are the weights; there is nothing to "dequantize"
 but a product) and token ids. Layers stream one at a time so that only one
 layer's float32 copy exists at once.

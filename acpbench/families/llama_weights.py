@@ -10,7 +10,7 @@ matrix drawn layer by layer inside the call so that the generator's
 temporaries stay one layer wide.
 
 These arrays are the benchmark's inputs: the engine serves them and
-`reference.py` reads the same arrays. Neither sees anything the other made.
+`llama_reference.py` reads the same arrays. Neither sees anything the other made.
 """
 
 from __future__ import annotations

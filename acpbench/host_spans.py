@@ -136,23 +136,25 @@ def _overlap(intervals, segments) -> dict[str, int]:
     return out
 
 
-def attribute(op_intervals, spans, offset_ns: int = 0) -> dict:
-    """Every idle nanosecond of every chip, between the slice's first and
-    last device op, given to the innermost `acp.*` phase that covers it,
-    else to `unnamed`; seconds, mean over chips. The parts sum to the
-    reduced trace's `window_s - busy_s`. `offset_ns` is added to device
-    times first (a clock correction, 0 when the planes agree)."""
+def attribute(op_intervals, spans, offset_ns: int = 0, windows=None) -> dict:
+    """Every idle nanosecond of every chip inside its window (the reduced
+    trace's `windows`: whole program runs; without them, from the first
+    device op to the last), given to the innermost `acp.*` phase that
+    covers it, else to `unnamed`; seconds, mean over chips. The parts sum
+    to the reduced trace's `window_s - busy_s`. `offset_ns` is added to
+    device times first (a clock correction, 0 when the planes agree)."""
     segments = innermost(spans)
-    start = min(s for ops in op_intervals for s, _, _ in ops) + offset_ns
-    end = max(e for ops in op_intervals for _, e, _ in ops) + offset_ns
+    if windows is None:
+        windows = [(min(s for ops in op_intervals for s, _, _ in ops),
+                    max(e for ops in op_intervals for _, e, _ in ops))] * len(op_intervals)
     total: dict[str, int] = {}
-    for ops in op_intervals:
-        idle = _idle([(s + offset_ns, e + offset_ns) for s, e, _ in ops], start, end)
+    for ops, (start, end) in zip(op_intervals, windows):
+        idle = _idle([(s + offset_ns, e + offset_ns) for s, e, _ in ops], start + offset_ns, end + offset_ns)
         for name, ns in _overlap(idle, segments).items():
             total[name] = total.get(name, 0) + ns
     n = len(op_intervals)
     by_phase = {name: ns / 1e9 / n for name, ns in total.items()}
-    host = _overlap([(start, end)], segments)
+    host = _overlap([(min(w[0] for w in windows) + offset_ns, max(w[1] for w in windows) + offset_ns)], segments)
     return {"idle_s": sum(total.values()) / 1e9 / n, "by_phase": by_phase,
             "host_s": {name: ns / 1e9 for name, ns in host.items() if name != "unnamed"}}
 
@@ -232,9 +234,10 @@ def analyse_profile(profile, reduced: dict) -> dict | None:
     spans = read_profile(profile)
     if not spans:
         return None
-    align = alignment(spans, device_runs(profile))
+    start, end = reduced["windows"][0]
+    align = alignment(spans, [r for r in device_runs(profile) if start <= r[0] and r[1] <= end])
     offset = int(align["offset_ms"] * 1e6) if align["corrected"] else 0
-    out = attribute(reduced["op_intervals"], spans, offset)
+    out = attribute(reduced["op_intervals"], spans, offset, reduced["windows"])
     out["align"] = align
     out["blocks"] = trace_reduce.runs_of(reduced, DECODE_MODULE)
     out["spans"] = len(spans)

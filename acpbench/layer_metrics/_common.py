@@ -38,3 +38,12 @@ def decode_step_ms(run):
     if not steps:
         return None
     return trace_reduce.seconds_of(run.trace, "modules", DECODE) / steps * 1e3
+
+
+def traced_window(run):
+    """What the reduced trace covers, on the benchmark's clock: the whole
+    program runs of the slice, counted from when the profiler was started
+    (the first op it saw came within milliseconds of that)."""
+    if run.trace is None or run.traced is None:
+        return None
+    return tuple(run.traced[0] + s for s in run.trace["slice_s"])

@@ -4,7 +4,7 @@ walk kernel's device time, in %."""
 
 from .. import peaks, trace_reduce
 from ..kernels import page_walk
-from ._common import decode_steps_traced
+from ._common import decode_steps_traced, traced_window
 
 SAMPLES = 24
 
@@ -21,13 +21,13 @@ def live_lengths(run, t: float) -> list[int]:
 
 def read(run):
     steps = decode_steps_traced(run)
-    if not steps or run.traced is None:
+    if not steps:
         return None
     kernel_s = trace_reduce.seconds_of(run.trace, "ops", r"page_walk")
     if not kernel_s:
         return None
     c, tp = run.config, run.config["engine"].get("tensor_parallelism", 1)
-    t0, t1 = run.traced
+    t0, t1 = traced_window(run)
     per_step = [
         page_walk.bytes_per_step(
             live_lengths(run, t0 + (t1 - t0) * (i + 0.5) / SAMPLES),

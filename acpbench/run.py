@@ -13,6 +13,7 @@ the cell's chips gets no result and exit code 3.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import shutil
@@ -109,6 +110,7 @@ def measure(system, cell: dict, seed: int, seconds: float, trace: bool, trace_di
             snaps["trace_stop"] = system.stats()
             traced[1] = time.monotonic()
             jax.profiler.stop_trace()
+            snaps["stop_trace_s"] = time.monotonic() - traced[1]
 
         events += [(traced[0], start), (traced[0] + span, stop)]
     marks = loadgen.Marks(events)
@@ -151,18 +153,27 @@ def read_metrics(bench: dict, table: str, run: Run) -> dict:
     return out
 
 
+def output_numbers(system, config: dict, seed: int) -> dict:
+    """Every number the check reads, the compared ones among them: the
+    family's cache check and the engine's own path against its reference."""
+    from . import check
+
+    family = system.family
+    s = check.sample(config["check"], config["vocab_size"], config["engine"]["page_size"], seed)
+    got = family.cached_logits(config, system.program_config, system.params, system.mesh, s,
+                               system.engine._use_pallas)
+    reference = functools.partial(family.reference_logits, config, system.params)
+    numbers = check.compare(got, check.reference_logits(reference, s))
+    path = check.engine_path(system, s, config["check"]["engine_tokens"])
+    numbers.update(check.engine_numbers(reference, s, path))
+    return numbers
+
+
 def output_check(system, cell: dict, seed: int) -> tuple[bool, list[str]]:
     from . import check
 
-    config = cell["config"]
-    s = check.sample(config["check"], config["vocab_size"], config["engine"]["page_size"], seed)
-    got = check.program_logits(system.params, system.llama, system.mesh, system.engine._use_pallas,
-                               config["engine"]["page_size"], s)
-    want = check.reference_logits(system.params, spec.model_sizes(config), s)
-    numbers = check.compare(got, want)
-    path = check.engine_path(system, s, config["check"]["engine_tokens"])
-    numbers.update(check.engine_numbers(system.params, spec.model_sizes(config), s, path))
-    ok, lines = check.decide(numbers, config["check"]["limits"])
+    numbers = output_numbers(system, cell["config"], seed)
+    ok, lines = check.decide(numbers, cell["config"]["check"]["limits"])
     return ok, lines + [f"engine_tokens={numbers['engine_tokens']} engine_top1_agree={numbers['engine_top1_agree']:.4f}"]
 
 
@@ -216,6 +227,8 @@ def main(argv=None) -> int:
 
     device = dict(found)
     if args.trace:
+        say("trace", f"slice of {run.traced[1] - run.traced[0]:.2f}s; jax.profiler.stop_trace took "
+                     f"{run.stats['stop_trace_s']:.1f}s (about 0.1 ms an op event: PERF.md, the run's own clock)")
         path = trace_reduce.find_xplane(trace_dir)
         run.trace = trace_reduce.reduce(path) if path else None
         if run.trace is None:
@@ -225,11 +238,12 @@ def main(argv=None) -> int:
     table = "per_layer" if args.trace else "end_to_end"
     out_metrics = read_metrics(bench, table, run)
 
+    # read before the check: its logits and the reference's layers would else be in the program's peak
+    stats = [d.memory_stats() or {} for d in devices]
+    device["memory_peak_bytes"] = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
     ok, lines = output_check(system, cell, args.seed)
     for line in lines:
         say("check", line)
-    stats = [d.memory_stats() or {} for d in devices]
-    device["memory_peak_bytes"] = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
     system.stop()
 
     result = {"correct": bool(ok and in_window == 0), "attempted": attempted, "failed": failed,
@@ -237,6 +251,7 @@ def main(argv=None) -> int:
     if run.trace is not None:
         result["breakdown"] = trace_reduce.breakdown(run.trace)
     shutil.rmtree(trace_dir, ignore_errors=True)
+    say("run", f"start to result {time.monotonic() - t_start:.1f}s")
     print(json.dumps(result), flush=True)
     return 0
 

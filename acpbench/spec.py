@@ -1,5 +1,6 @@
-"""Finds a cell's files by the names `BENCHMARK.json` gives them. No cell,
-configuration, mix or metric is known here by name."""
+"""Finds a cell's files by the names `BENCHMARK.json` gives them, and a
+configuration's model family by the name its file gives. No cell,
+configuration, mix, metric or family is known here by name."""
 
 from __future__ import annotations
 
@@ -10,6 +11,7 @@ import os
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRAFFIC_DIRS = [os.path.join(HERE, "traffic")]
+FAMILY_PACKAGES = ["acpbench.families"]
 
 
 def load_json(path: str) -> dict:
@@ -45,6 +47,20 @@ def generator(kind: str):
     return importlib.import_module(f"acpbench.generators.{kind}")
 
 
+def family(config: dict):
+    """The module the configuration's file names under `family`: the
+    program's config, the seeded weights, the plain reference and the cache
+    check (acpbench/families/__init__.py has the interface)."""
+    name = config["family"]
+    for package in FAMILY_PACKAGES:
+        try:
+            return importlib.import_module(f"{package}.{name}")
+        except ModuleNotFoundError as e:
+            if e.name != f"{package}.{name}":
+                raise
+    raise ModuleNotFoundError(f"family {name!r} in none of {FAMILY_PACKAGES}")
+
+
 def metrics_for(bench: dict, workload: str, table: str) -> list[dict]:
     """The entries of `end_to_end` or `per_layer` this cell reports."""
     out = []
@@ -60,27 +76,3 @@ def reader(table: str, name: str):
     the characters a module may not have ('.', '-') written as '_'."""
     package = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}[table]
     return importlib.import_module(f"acpbench.{package}.{name.replace('.', '_').replace('-', '_')}")
-
-
-LLAMA_FIELDS = {
-    "vocab_size": "vocab_size", "hidden_size": "dim", "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "ffn_dim", "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-    "max_position_embeddings": "max_seq_len", "tie_word_embeddings": "tie_embeddings",
-}
-
-
-def llama_kwargs(config: dict) -> dict:
-    """The source's `config.json` keys under the program's names, plus what
-    the file states under `llama_config` in the program's own names."""
-    kw = {ours: config[theirs] for theirs, ours in LLAMA_FIELDS.items() if theirs in config}
-    kw.update(config.get("llama_config", {}))
-    return kw
-
-
-def model_sizes(config: dict) -> dict:
-    """What the plain reference needs, from the source's keys alone."""
-    return {
-        "n_heads": config["num_attention_heads"], "n_kv_heads": config["num_key_value_heads"],
-        "norm_eps": config["rms_norm_eps"], "rope_theta": config["rope_theta"],
-    }

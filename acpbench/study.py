@@ -6,7 +6,9 @@ run by the benchmark; kept so that a reader can make the readings again.
         control it builds the engine for each seed, reads the engine's path
         too, and beside it the structural control (a swapped page).
         `program` is the sound program's logits with no engine built,
-        `ref_nobias` the reference with the q, k and v biases left out
+        `kv_int8` the same through the program's int8 KV pages, `ref_<name>`
+        the family's reference under its control <name> (llama: int8, fp8,
+        and nobias, the q, k and v biases left out)
     python -m acpbench.study sweep --workload <cell> --rates 1,2,3,4,5 --seconds 30
         an open-loop cell at several rates in one process: where the knee is
     python -m acpbench.study small-trace --out chiprun_out/small_trace
@@ -17,6 +19,7 @@ run by the benchmark; kept so that a reader can make the readings again.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -26,18 +29,48 @@ from . import check, metrics, spec, trace_reduce
 
 
 def _engine_free_system(config: dict, seed: int):
-    """Weights, mesh and the program's model config, with no engine."""
+    """The family's config for the program, a mesh and the seeded weights,
+    with no engine."""
     import jax
 
-    from agentcontrolplane_tpu.models.llama import LlamaConfig
     from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
-    from . import weights
-
+    family = spec.family(config)
     tp = config["engine"].get("tensor_parallelism", 1)
-    llama = LlamaConfig(**spec.llama_kwargs(config))
+    program_config = family.program_config(config)
     mesh = make_mesh({"tp": tp}, devices=jax.devices()[:tp])
-    return llama, mesh, weights.make(llama, mesh, seed)
+    return program_config, mesh, family.weights(config, program_config, mesh, seed)
+
+
+def readings(config: dict, seed: int, control: str, use_pallas: bool) -> dict:
+    """The output check's numbers at one seed, through the configuration's
+    family. `ref_<lower>` puts the family's reference under that control in
+    the program's place; `kv_int8` is the program's own int8 KV pages."""
+    family = spec.family(config)
+    system = None
+    if control == "none":
+        from .systems.engine import System
+
+        system = System(config, seed)
+        program_config, mesh, params = system.program_config, system.mesh, system.params
+    else:
+        program_config, mesh, params = _engine_free_system(config, seed)
+    reference = functools.partial(family.reference_logits, config, params)
+    s = check.sample(config["check"], config["vocab_size"], config["engine"]["page_size"], seed)
+    want = check.reference_logits(reference, s)
+    if control.startswith("ref_"):
+        got = check.reference_logits(reference, s, lower=control[4:])
+    else:
+        lower = {"quantize_kv": True} if control == "kv_int8" else {}
+        got = family.cached_logits(config, program_config, params, mesh, s, use_pallas, **lower)
+    numbers = check.compare(got, want)
+    if system is not None:
+        path = check.engine_path(system, s, config["check"]["engine_tokens"])
+        numbers.update(check.engine_numbers(reference, s, path))
+        swapped = check.engine_numbers(reference, s, path, control=True)
+        print(f"[outputs] control=page_swap seed={seed} {json.dumps(swapped)}", flush=True)
+        system.stop()
+    return numbers
 
 
 def outputs(args) -> int:
@@ -48,37 +81,11 @@ def outputs(args) -> int:
     config = spec.load_json(os.path.join(spec.ROOT, conf["file"]))
     use_pallas = jax.default_backend() == "tpu"
     rows = []
-    model = spec.model_sizes(config)
     for i in range(args.seeds):
         seed = args.first_seed + 104729 * i
-        system = None
-        if args.control == "none":
-            from .systems.engine import System
-
-            system = System(config, seed)
-            llama, mesh, params = system.llama, system.mesh, system.params
-        else:
-            llama, mesh, params = _engine_free_system(config, seed)
-        s = check.sample(config["check"], config["vocab_size"], config["engine"]["page_size"], seed)
-        want = check.reference_logits(params, model, s)
-        if args.control == "ref_nobias":
-            layers = {k: v for k, v in params["layers"].items() if k not in ("bq", "bk", "bv")}
-            got = check.reference_logits(dict(params, layers=layers), model, s)
-        elif args.control.startswith("ref_"):
-            got = check.reference_logits(params, model, s, lower=args.control[4:])
-        else:
-            got = check.program_logits(params, llama, mesh, use_pallas, config["engine"]["page_size"], s,
-                                       quantize_kv=args.control == "kv_int8")
-        numbers = check.compare(got, want)
-        if system is not None:
-            path = check.engine_path(system, s, config["check"]["engine_tokens"])
-            numbers.update(check.engine_numbers(params, model, s, path))
-            swapped = check.engine_numbers(params, model, s, path, control=True)
-            print(f"[outputs] control=page_swap seed={seed} {json.dumps(swapped)}", flush=True)
-            system.stop()
+        numbers = readings(config, seed, args.control, use_pallas)
         rows.append(numbers)
         print(f"[outputs] control={args.control} seed={seed} {json.dumps(numbers)}", flush=True)
-        del params, got, want, system
     for key in ("logit_rel_rms", "cache_excess", "greedy_regret"):
         vals = [r[key] for r in rows if key in r]
         if not vals:

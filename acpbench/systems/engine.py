@@ -1,11 +1,13 @@
 """The system under test: the program's engine alone, built from a
-configuration's file with the benchmark's weights."""
+configuration's file with the benchmark's weights. The model's config and
+weights come from the family the file names; the engine's options, the
+weight precision (`quantize`) among them, from the file's `engine` block."""
 
 from __future__ import annotations
 
 import time
 
-from .. import loadgen, spec, weights
+from .. import loadgen, spec
 
 
 class System:
@@ -15,24 +17,23 @@ class System:
         import jax
 
         from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
-        from agentcontrolplane_tpu.models.llama import LlamaConfig
         from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
         self._sampling = SamplingParams
         opts = dict(config["engine"])
         tp = opts.pop("tensor_parallelism", 1)
-        self.llama = LlamaConfig(**spec.llama_kwargs(config))
+        self.family = spec.family(config)
+        self.program_config = self.family.program_config(config)
         self.mesh = make_mesh({"tp": tp}, devices=jax.devices()[:tp])
         t0 = time.monotonic()
-        self.params = weights.make(self.llama, self.mesh, seed)
+        self.params = self.family.weights(config, self.program_config, self.mesh, seed)
         jax.block_until_ready(self.params)
         self.weights_s = time.monotonic() - t0
         for key in ("prefill_buckets", "width_buckets"):
             if key in opts:
                 opts[key] = tuple(opts[key])
-        self.engine = Engine(config=self.llama, params=self.params, mesh=self.mesh,
-                             quantize="int8", seed=seed & 0x7FFFFFFF,
-                             tokenizer=tokenizer(config), **opts)
+        self.engine = Engine(config=self.program_config, params=self.params, mesh=self.mesh,
+                             seed=seed & 0x7FFFFFFF, tokenizer=tokenizer(config), **opts)
         self.engine.start()
 
     def submit(self, request: dict, on_tokens):

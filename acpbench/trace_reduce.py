@@ -10,7 +10,19 @@ here reduces those two lines; times are nanoseconds on the device clock.
       "ops": {name: seconds, mean over chips}, "modules": {name: {"n", "s"}},
       "gaps": {"<module before>-<module after>": seconds},   # idle between programs
       "op_intervals": per device [(start, end, name)] for readers that need overlap
+      "windows": per device (start, end) of what was reduced, "slice_s": the first chip's
+          window in seconds after the first op the profiler saw (for the host's clock)
     }
+
+Whole program runs only. The profiler starts and stops in the middle of the
+stream: a chip's first module event begins with the first op the profiler
+saw, most of its ops missing, and its last may be cut at the stop. Counted
+as runs they make every per-run number read low, by more the shorter the
+slice (two cut blocks of eight in a 3 s slice at tp=4: a decode step 3.7%
+short, idle a block 20% short; my chip runs, PR 29). So each chip's first
+and last run are dropped with their ops, and its window runs from the end
+of the first to the end of the last run kept: whole runs, each with the
+idle gap before it.
 
 Loops, conditionals and calls are dropped as the line is read: the ops they
 run are on the line themselves, and an event that wraps them would cover
@@ -85,13 +97,14 @@ def reduce_profile(profile) -> dict | None:
             (int(e.start_ns), int(e.start_ns + e.duration_ns), module_name(e.name))
             for e in (mod_line.events if mod_line is not None else [])
         )
-        if ops:
-            per_dev.append({"ops": ops, "mods": mods})
-    if not per_dev:
+        if len(mods) < 3 or not ops:
+            continue  # no whole run between a first and a last
+        window = (mods[0][1], mods[-2][1])
+        per_dev.append({"ops": [o for o in ops if window[0] <= o[0] < window[1]], "mods": mods[1:-1],
+                        "cut": mods[0], "window": window, "first_op": min(o[0] for o in ops)})
+    if not per_dev or not all(d["ops"] for d in per_dev):
         return None
     n = len(per_dev)
-    start = min(min(o[0] for o in d["ops"]) for d in per_dev)
-    end = max(max(o[1] for o in d["ops"]) for d in per_dev)
     busy = sum(_union([(s, e) for s, e, _ in d["ops"]]) for d in per_dev) / n
     ops: dict[str, float] = {}
     modules: dict[str, dict] = {}
@@ -104,21 +117,24 @@ def reduce_profile(profile) -> dict | None:
             m = modules.setdefault(name, {"n": 0.0, "s": 0.0})
             m["n"] += 1.0 / n
             m["s"] += (e - s) / 1e9 / n
-        prev = None
+        prev = d["cut"]
         for s, e, name in d["mods"]:
-            if prev is not None and s > prev[1]:
+            if s > prev[1]:
                 key = f"{prev[2]}-{name}"
                 gaps[key] = gaps.get(key, 0.0) + (s - prev[1]) / 1e9 / n
-            if prev is None or e > prev[1]:
+            if e > prev[1]:
                 prev = (s, e, name)
+    first = per_dev[0]
     return {
         "devices": n,
-        "window_s": (end - start) / 1e9,
+        "window_s": sum(d["window"][1] - d["window"][0] for d in per_dev) / 1e9 / n,
         "busy_s": busy / 1e9,
         "ops": ops,
         "modules": modules,
         "gaps": gaps,
         "op_intervals": [d["ops"] for d in per_dev],
+        "windows": [d["window"] for d in per_dev],
+        "slice_s": tuple((w - first["first_op"]) / 1e9 for w in first["window"]),
     }
 
 

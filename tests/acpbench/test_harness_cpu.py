@@ -9,26 +9,43 @@ import jax
 import pytest
 
 from acpbench import run as runner
-from acpbench import spec
+from acpbench import spec, study
 from acpbench.systems.engine import CompileCounter, System
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
+PARTS = {"program_config", "weights", "reference_logits", "cached_logits"}
 
 
 BENCH = spec.benchmark()
 CASES = [
-    pytest.param(("tiny-open", "q7b-chat-mixed", 1), id="tiny-open-tp1"),
-    pytest.param(("tiny-closed", "q32b-tp4-decode", 4), id="tiny-closed-tp4"),
+    pytest.param((config, mix, workload, tp), id=f"{mix}-tp{tp}-{family}")
+    for family, config in (("llama", "tiny-config"), ("recording", "tiny-config-recording"))
+    for mix, workload, tp in (("tiny-open", "q7b-chat-mixed", 1), ("tiny-closed", "q32b-tp4-decode", 4))
 ]
 
 
+@pytest.fixture(scope="module")
+def a_second_family():
+    """`recording`, a family this test brings as files only: a package
+    added to the list `spec.family` searches, and a configuration's file
+    that names it. Yields its record of calls."""
+    from .data.families import recording
+
+    spec.FAMILY_PACKAGES.append(recording.__package__)
+    try:
+        yield recording.CALLS
+    finally:
+        spec.FAMILY_PACKAGES.remove(recording.__package__)
+
+
 @pytest.fixture(scope="module", params=CASES)
-def rehearsal(request):
-    mix, workload, tp = request.param
+def rehearsal(request, a_second_family):
+    file, mix, workload, tp = request.param
     if len(jax.devices()) < tp:
         pytest.skip(f"needs {tp} (virtual) devices")
-    config = spec.load_json(os.path.join(DATA, "tiny-config.json"))
+    config = spec.load_json(os.path.join(DATA, file + ".json"))
     config["engine"]["tensor_parallelism"] = tp
+    a_second_family.clear()
     cell = {"workload": {"name": workload, "chips": tp}, "config": config,
             "mix": spec.load_json(os.path.join(DATA, mix + ".json"))}
     counter = CompileCounter()
@@ -42,7 +59,7 @@ def rehearsal(request):
         check = runner.output_check(system, cell, 5)
     finally:
         system.stop()
-    return run, compiled, check
+    return run, compiled, check, list(a_second_family)
 
 
 def test_nothing_compiles_after_the_warm_up(rehearsal):
@@ -94,3 +111,57 @@ def test_outputs_agree_with_the_reference(rehearsal):
     # the cell's own path: greedy requests through submit, every token looked up in the reference
     assert any(line.startswith("greedy_regret=") and "limit=" in line for line in lines)
     assert any(line.startswith("stream_mismatch=0 limit=0 ok") for line in lines)
+
+
+def test_every_part_comes_from_the_family_the_file_names(rehearsal):
+    """`System`, `warm_up`, `measure` and `output_check` reach the
+    program's config, the weights, the reference and the cache check
+    through the module the configuration's file names, and through no
+    other: the recording family sees all four parts called when it is
+    named, and nothing when `llama` is."""
+    run, calls = rehearsal[0], rehearsal[3]
+    assert set(calls) == (PARTS if run.config["family"] == "recording" else set())
+    if calls:
+        assert calls[:2] == ["program_config", "weights"] and calls.count("weights") == 1
+        assert calls.count("reference_logits") == 2  # the sample's logits, then the engine's tokens
+
+
+@pytest.mark.parametrize("control,parts", [
+    ("program", PARTS), ("kv_int8", PARTS), ("ref_fp8", PARTS - {"cached_logits"}),
+])
+def test_study_reads_through_the_family_too(a_second_family, control, parts):
+    config = spec.load_json(os.path.join(DATA, "tiny-config-recording.json"))
+    a_second_family.clear()
+    numbers = study.readings(config, 3, control, use_pallas=False)
+    assert set(a_second_family) == parts and numbers["finite"]
+
+
+def test_a_family_no_package_has_is_an_error():
+    with pytest.raises(ModuleNotFoundError, match="no-such-family"):
+        spec.family({"family": "no-such-family"})
+
+
+def test_only_families_know_the_programs_models():
+    """Outside `acpbench/families/` no file of the harness imports the
+    program's model modules or builds its config, and `spec.py` finds a
+    family by the name a file gives and knows none itself."""
+    here = os.path.join(spec.ROOT, "acpbench")
+    families = {name[:-3] for name in os.listdir(os.path.join(here, "families")) if name.endswith(".py")}
+    seen = 0
+    for folder, _, names in os.walk(here):
+        if os.path.basename(folder) in ("families", "__pycache__"):
+            continue
+        for name in names:
+            if not name.endswith(".py"):
+                continue
+            with open(os.path.join(folder, name)) as f:
+                text = f.read()
+            seen += 1
+            for banned in ("agentcontrolplane_tpu.models", "LlamaConfig", "llama_kwargs"):
+                assert banned not in text, (name, banned)
+    assert seen > 20
+    with open(os.path.join(here, "spec.py")) as f:
+        text = f.read().lower()
+    assert "llama" in families and not any(name.split("_")[0] in text for name in families if name != "__init__")
+    with open(os.path.join(here, "systems", "engine.py")) as f:
+        assert "quantize=" not in f.read()  # the weight precision comes from the file's engine block

@@ -21,7 +21,12 @@ def ev(name, start, dur, **stats):
 
 
 def device(index, runs, skew=0):
-    """One op and one module event a run: (start, duration, module)."""
+    """One op and one module event a run: (start, duration, module). As
+    in a profiler's trace, a run cut at either edge comes before and after
+    them; the reduction drops both, and its window opens where the first
+    ends: here, at the first whole run's start."""
+    cut = "jit_cut_at_an_edge"
+    runs = [(runs[0][0] - MS, MS, cut)] + list(runs) + [(runs[-1][0] + runs[-1][1] + MS, MS, cut)]
     ops = [ev("%fusion.1 = bf16[8]{0} fusion(...)", s + skew, d) for s, d, _ in runs]
     mods = [ev(f"{m}(7)", s + skew, d) for s, d, m in runs]
     return NS(name=f"/device:TPU:{index}", lines=[NS(name="XLA Ops", events=ops), NS(name="XLA Modules", events=mods)])
@@ -239,7 +244,7 @@ def test_recorded_trace_attributes_every_idle_nanosecond(recorded):
     named = sum(v for k, v in found["by_phase"].items() if k not in ("unnamed", "cycle"))
     assert named / idle > 0.95  # the loop is serial: the chip idles while the host launches, fetches, commits
     assert found["by_phase"]["launch"] > found["by_phase"]["fetch"] > found["by_phase"]["commit"] > 0
-    assert found["blocks"] == 3
+    assert found["blocks"] == 2  # whole runs: the trace's first decode block is dropped
 
 
 def test_recorded_trace_shows_the_device_planes_lagging_the_host_plane(recorded):
@@ -251,6 +256,6 @@ def test_recorded_trace_shows_the_device_planes_lagging_the_host_plane(recorded)
     lo, hi = a["bounds_ms"]
     assert 0 < lo < hi < 5
     assert a["corrected"] and a["offset_ms"] == pytest.approx((lo + hi) / 2, abs=1e-6)
-    assert a["share_uncorrected"] == 0.0 and a["share"] == 1.0 and a["blocks"] == a["runs"] == 3
+    assert a["share_uncorrected"] == 0.0 and a["share"] == 1.0 and a["blocks"] == a["runs"] == 2
     assert 0 < a["latency_ms"] < hi - lo
     assert "CORRECTED by" in hs.line(hs.analyse_profile(profile, reduced), reduced)

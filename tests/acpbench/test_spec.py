@@ -84,8 +84,9 @@ def test_configuration_files_cut_no_width(conf):
     file = spec.load_json(os.path.join(spec.ROOT, conf["file"]))
     assert conf["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
     assert file["source"] == conf["source"] and file["reduced"] == conf["reduced"] == []
-    kw = spec.llama_kwargs(file)
-    assert kw["dim"] // kw["n_heads"] == 128 and kw["qkv_bias"] is True
+    program = spec.family(file).program_config(file)
+    assert program.dim // program.n_heads == 128 and program.qkv_bias is True
+    assert file["engine"]["quantize"] == "int8" and "int8" in file["precision"]["weights"]
     assert any(c["config"] == conf["name"] for c in BENCH["workloads"])
 
 
