@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import gc
 import hashlib
 import heapq
 import logging
@@ -45,13 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import (
-    LlamaConfig,
-    PRESETS,
-    decode_step,
-    init_kv_cache,
-    prefill_batch,
-)
+from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
 from ..observability.metrics import REGISTRY
 from ..ops.paged import TRASH_PAGE
 from ..ops.sampling import sample
@@ -198,6 +193,11 @@ class _Request:
     # of parking a default-executor thread per queued request — 64 queued
     # requests would otherwise exhaust the shared executor.
     admitted: Future = field(default_factory=Future)
+    # families with per-slot state beside the pages (models.programs(...)
+    # .has_state): the one page-aligned length of this admission at which
+    # the prefill saves the state (a prefix entry, a park or a host swap at
+    # exactly that length can resume); 0 = none was saved
+    state_cut: int = 0
 
     def emit(self, tokens: list[int]) -> None:
         if self.on_tokens is not None and tokens:
@@ -503,6 +503,7 @@ class Engine:
         # every step the engine gets faster. Kernel and program names (what
         # trace readers match) are unchanged.
         jax.config.update("jax_traceback_in_locations_limit", 0)
+        self._gc_frozen = False  # prewarm froze the heap; stop() gives it back
         self._coordination = coordination
         self._coord_follower = coordination is not None and hasattr(coordination, "recv")
         self.decode_block_size = max(1, decode_block_size)
@@ -512,8 +513,13 @@ class Engine:
         self.page_size = page_size
         self.page_lookahead_blocks = max(1, page_lookahead_blocks)
         if isinstance(config, str):
-            config = PRESETS[config]
+            config = preset(config)
         self.config = config
+        # the one seam to the model family's programs (models/__init__.py)
+        self._model = programs(config)
+        self._has_state = self._model.has_state
+        # device counters a family keeps in its cache (None: it keeps none)
+        self._counters = self._model.counters
         self.tokenizer = tokenizer or ByteTokenizer()
         self.max_slots = max_slots
         self.max_ctx = min(max_ctx, config.max_seq_len)
@@ -583,6 +589,22 @@ class Engine:
                 "layers make serving exact only within one window — lower "
                 "--tpu-ctx to the window size"
             )
+        if self._has_state:
+            # per-slot state beside the pages: what the engine cannot yet do
+            # for this family it refuses here, in words, and never serves
+            # wrongly
+            refused = [
+                (kv_layout != "paged", "kv_layout='slot': its state lives beside the paged pool; serve it with kv_layout='paged'"),
+                (spec_len > 0, "spec_len > 0: speculation needs the per-slot state rolled back on a rejected draft"),
+                (tp > 1 or sp > 1, "tensor or context parallelism: its weights and state have no sharding here; serve it on a tp=1 mesh"),
+                (bool(quantize) or quantize_weights, "weight-only int8: its matrices are served in the dtype they were made in"),
+                (coordination is not None, "multi-host lockstep serving"),
+            ]
+            for hit, why in refused:
+                if hit:
+                    raise ValueError(
+                        f"the {self._model.family} family does not serve with {why}"
+                    )
         self.prefill_batch_max = max(1, prefill_batch_max)
         # decode dispatch widths: smallest bucket covering the active slots
         # (each width is its own jit cache entry; keep the set small so cold
@@ -605,10 +627,13 @@ class Engine:
 
             params = random_quantized_init(config, seed=seed)
         elif params is None:
-            from ..models.llama import init_params as _init
-
+            _init = self._model.init_params
             abstract = jax.eval_shape(lambda k: _init(config, k), jax.random.key(0))
-            shardings = param_shardings(self.mesh, config, abstract)
+            shardings = (
+                jax.tree_util.tree_map(lambda _: self._replicated, abstract)
+                if self._has_state  # tp=1 only (refused above): all whole
+                else param_shardings(self.mesh, config, abstract)
+            )
             params = jax.jit(
                 lambda k: _init(config, k), out_shardings=shardings
             )(jax.random.key(seed))
@@ -671,12 +696,15 @@ class Engine:
             # shard_map wrapper over head-sharded pages — GSPMD treats
             # pallas_call as opaque); CPU uses the exact XLA reference
             # (interpret-mode kernel equivalence is in tests). The kernel
-            # targets hardware-native geometry: each KV head's [P, d] column
-            # window of the page buffer must be lane-aligned, so head_dim
-            # must be a multiple of the 128-lane width (128 for
-            # llama/qwen/mistral, 256 for gemma; compiled for a described
-            # v5e in tests/engine/test_chip_compile.py). Other widths (e.g.
-            # the tiny CPU-test configs) fall back to the exact XLA gather
+            # takes head widths 64, 128 and 256 (paged_attention.py
+            # heads_per_window): a KV head's [P, d] column window of the
+            # page buffer whole where head_dim is a multiple of the 128-lane
+            # width (128 for llama/qwen/mistral, 256 for gemma), two KV
+            # heads to a lane window at 64 (lfm2: bf16 or f32 pages, an even
+            # number of KV heads a chip); each compiled for a described v5e
+            # in tests/engine/test_chip_compile.py. What is still refused
+            # (other widths such as the tiny CPU-test configs', an odd head
+            # count or int8 pages at 64) falls back to the exact XLA gather
             # reference — on a TPU that is a finding, counted below, and
             # chip_smoke.py fails on it.
             # sp>1 composes: each context-parallel rank runs the kernel
@@ -687,8 +715,12 @@ class Engine:
             # f32 scale rows with each fetch and applies them in VMEM
             # (paged_attention.py), so the pool stays int8 in HBM and decode
             # keeps the no-gather path.
-            self._use_pallas = (
-                jax.default_backend() == "tpu" and config.head_dim % 128 == 0
+            from ..ops.pallas.paged_attention import heads_per_window
+
+            self._use_pallas = jax.default_backend() == "tpu" and bool(
+                heads_per_window(
+                    config.head_dim, config.n_kv_heads // tp, self.quantize_kv
+                )
             )
             if jax.default_backend() == "tpu" and not self._use_pallas:
                 reason = "head_dim"
@@ -696,7 +728,10 @@ class Engine:
                     "paged kv_layout on TPU without the Pallas kernel: %s; "
                     "decode uses the XLA gather reference (materializes the "
                     "gathered context every step)",
-                    f"head_dim {config.head_dim} is not a multiple of 128",
+                    f"head_dim {config.head_dim} with {config.n_kv_heads // tp} "
+                    f"KV heads a chip{' and int8 pages' if self.quantize_kv else ''} "
+                    "is none of the walk's geometries (128 or 256 wide, or 64 "
+                    "wide with bf16/f32 pages and an even number of KV heads)",
                 )
                 # a silent perf cliff deserves a first-class signal: count
                 # it and drop a flight breadcrumb so dashboards and dumps
@@ -723,11 +758,11 @@ class Engine:
                 from ..ops.pallas.paged_attention import pages_per_turn
 
                 axes = dict(self.mesh.shape)
-                k = self.cache["k"]  # [L, num_pages, page_size, H_kv, d]
+                k = self.cache["k"]  # [L, num_pages, page_size, ...]
                 rows = k.shape[2] // axes.get("sp", 1)  # of a page, a rank
                 self.pages_per_turn = pages_per_turn(
-                    rows, k.dtype, k.shape[3] // axes.get("tp", 1), k.shape[4],
-                    self.quantize_kv,
+                    rows, k.dtype, config.n_kv_heads // axes.get("tp", 1),
+                    config.head_dim, self.quantize_kv,
                 )
                 log.info(
                     "paged decode: Pallas page walk, pages_per_turn=%d "
@@ -942,6 +977,22 @@ class Engine:
         self._kv_inject: "queue.Queue" = queue.Queue()
         self.kv_injects = 0  # handoff entries landed in the host pool
         self.kv_swap_outs = 0  # KV rows offloaded to the host tier (events)
+        # per-slot state beside the pages (state families): copies taken of
+        # a slot's saved state (prefix entries, host swaps, handoffs),
+        # states installed into a slot before a continuation, and prefix,
+        # dedup or host hits refused for want of a state saved at their cut
+        self.state_saves = 0
+        self.state_restores = 0
+        self.state_refused = 0
+        self._jit_install_state = None
+        self._jit_saved_state = None
+        # the family's device counters (self._counters): the newest copy a
+        # program gave back beside the cache (_with_counters), what stats()
+        # last read of it, and the sum of their differences (np.uint64)
+        self._counters_snap = None
+        self._counters_seen = None
+        self._counters_total = None
+        self._counters_lock = threading.Lock()
         self.kv_swap_ins = 0  # host-tier restores (swap-in completions)
         self.prefix_shares = 0  # admissions that refcount-shared prompt pages
         self._host_kv_used = 0  # acp: mirror — host pool bytes in use
@@ -1179,10 +1230,10 @@ class Engine:
                 p_out = f_out = d_out = v_out = None
                 if swaps is not None:
                     for s_ids, s_blocks in swaps:
-                        cache = {
+                        cache = {**cache, **{
                             name: cache[name].at[:, s_ids].set(s_blocks[name])
-                            for name in cache
-                        }
+                            for name in s_blocks
+                        }}
                 if mids is not None:
                     cache = mid_fn(params, cache, *mids)
                 if plains is not None:
@@ -1217,14 +1268,14 @@ class Engine:
                     v_out = (out_toks, n_emit, new_states)
                 return cache, p_out, f_out, d_out, v_out
 
-            return jax.jit(megastep, donate_argnums=(1, 6))
+            return self._with_counters(megastep)(
+                lambda f: jax.jit(f, donate_argnums=(1, 6))
+            )
 
         if self.kv_layout == "paged":
-            from ..models.llama import (
-                decode_step_paged,
-                prefill_paged_batch,
-                prefill_paged_continue,
-            )
+            decode_step_paged = self._model.decode_step_paged
+            prefill_paged_batch = self._model.prefill_paged_batch
+            prefill_paged_continue = self._model.prefill_paged_continue
 
             use_pallas = self._use_pallas
 
@@ -1233,7 +1284,9 @@ class Engine:
                 toks, states = sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets)
                 return pages, toks, states
 
-            self._jit_prefill_paged = jax.jit(prefill_and_sample, donate_argnums=(1,))
+            self._jit_prefill_paged = self._with_counters(prefill_and_sample)(
+                lambda f: jax.jit(f, donate_argnums=(1,))
+            )
 
             def paged_continue_and_sample(params, pages, tokens, lengths, starts, page_ids, block_tables, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
                 pages, logits = prefill_paged_continue(
@@ -1242,9 +1295,9 @@ class Engine:
                 toks, states = sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets)
                 return pages, toks, states
 
-            self._jit_prefill_paged_continue = jax.jit(
-                paged_continue_and_sample, donate_argnums=(1,)
-            )
+            self._jit_prefill_paged_continue = self._with_counters(
+                paged_continue_and_sample
+            )(lambda f: jax.jit(f, donate_argnums=(1,)))
             mesh = self.mesh
             decode_block = make_decode_block(
                 lambda params, pages, tokens, seq_lens, active, block_tables: decode_step_paged(
@@ -1252,18 +1305,18 @@ class Engine:
                     use_pallas=use_pallas, mesh=mesh,
                 )
             )
-            self._jit_decode_paged = jax.jit(
-                decode_block, donate_argnums=(1, 2, 3, 4, 5, 10, 13)
+            self._jit_decode_paged = self._with_counters(decode_block)(
+                lambda f: jax.jit(f, donate_argnums=(1, 2, 3, 4, 5, 10, 13))
             )
-            from ..models.llama import verify_paged_continue
-
+            # spec_len > 0 is refused for a family without a verify program
+            verify_paged_continue = getattr(self._model, "verify_paged_continue", None)
             verify_block = make_verify(
                 lambda params, pages, inputs, n_input, starts, block_tables: verify_paged_continue(
                     params, pages, inputs, n_input, starts, block_tables, config
                 )
             )
             self._jit_verify = jax.jit(verify_block, donate_argnums=(1,))
-            from ..models.llama import prefill_paged_continue_kv
+            prefill_paged_continue_kv = self._model.prefill_paged_continue_kv
 
             self._jit_megastep = make_megastep(
                 lambda params, pages, toks, lens, starts, page_ids, tables: (
@@ -1283,6 +1336,8 @@ class Engine:
                 ),
             )
         else:
+            prefill_batch = self._model.prefill_batch
+            decode_step = self._model.decode_step
 
             def prefill_and_sample(params, cache, tokens, lengths, slots, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
                 cache, logits = prefill_batch(params, cache, tokens, lengths, slots, config)
@@ -1291,7 +1346,7 @@ class Engine:
 
             self._jit_prefill = jax.jit(prefill_and_sample, donate_argnums=(1,))
 
-            from ..models.llama import prefill_continue
+            prefill_continue = self._model.prefill_continue
 
             def continue_and_sample(params, cache, tokens, lengths, starts, slots, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
                 cache, logits = prefill_continue(
@@ -1309,16 +1364,14 @@ class Engine:
             self._jit_decode = jax.jit(
                 decode_block, donate_argnums=(1, 2, 3, 4, 5, 10, 13)
             )
-            from ..models.llama import verify_continue
-
+            verify_continue = self._model.verify_continue
             verify_block = make_verify(
                 lambda params, cache, inputs, n_input, starts: verify_continue(
                     params, cache, inputs, n_input, starts, config
                 )
             )
             self._jit_verify = jax.jit(verify_block, donate_argnums=(1,))
-            from ..models.llama import prefill_continue_kv
-
+            prefill_continue_kv = self._model.prefill_continue_kv
             self._jit_megastep = make_megastep(
                 lambda params, cache, toks, lens, starts, slots_: (
                     prefill_continue_kv(
@@ -1345,7 +1398,7 @@ class Engine:
         self._tables_dirty = True
         if self.kv_layout == "slot":
             self.cache = jax.jit(  # acp: donated
-                lambda: init_kv_cache(
+                lambda: self._model.init_kv_cache(
                     self.config, self.max_slots, self.max_ctx,
                     quantize_kv=self.quantize_kv,
                 ),
@@ -1354,7 +1407,6 @@ class Engine:
         else:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from ..models.llama import init_paged_cache
             from ..ops.paged import PageAllocator
 
             sp_axis = (
@@ -1375,12 +1427,23 @@ class Engine:
                 scale_spec = NamedSharding(self.mesh, P(None, None, sp_axis, "tp"))
                 page_shardings["ks"] = scale_spec
                 page_shardings["vs"] = scale_spec
+            init_cache = lambda: self._model.init_paged_cache(  # noqa: E731
+                self.config, self.num_pages, self.page_size,
+                quantize_kv=self.quantize_kv, max_slots=self.max_slots,
+            )
+            if self._has_state:
+                # the family's own pool layout and whatever tree it keeps
+                # beside the pages under "state", whole on every device
+                # (tp=1, refused otherwise at construction)
+                page_shardings = jax.tree_util.tree_map(
+                    lambda _: self._replicated, jax.eval_shape(init_cache)
+                )
+            # a family's device counters start from 0 again (and wrap at
+            # 2**32): stats() sums their differences into _counters_total
+            self._counters_seen = None
+            self._counters_snap = None
             self.cache = jax.jit(  # acp: donated
-                lambda: init_paged_cache(
-                    self.config, self.num_pages, self.page_size,
-                    quantize_kv=self.quantize_kv,
-                ),
-                out_shardings=page_shardings,
+                init_cache, out_shardings=page_shardings
             )()
             self._allocator = PageAllocator(
                 self.num_pages, track_scales=self.quantize_kv
@@ -1403,6 +1466,10 @@ class Engine:
         # by a late ensure_running)
         with self._restart_lock:
             self._crashed = False
+            if self._gc_frozen:
+                # what prewarm froze goes back to the collector with the engine
+                self._gc_frozen = False
+                gc.unfreeze()
             if self._thread is None:
                 return
             self._stopping = True
@@ -1618,6 +1685,17 @@ class Engine:
         # pays for: the profiler turns it into a cold_compile flight event
         # + acp_engine_cold_compiles_total (serving-time latency bug)
         self.profiler.mark_prewarmed()
+        # Everything alive now (the traced programs' closures, jax's caches,
+        # the prewarm's own requests) lives as long as the engine: out of the
+        # collector's reach until stop(), so that a full collection while
+        # serving walks what requests made since and not the whole heap. The
+        # engine loop is serial, and a full collection over this process's
+        # heap is a block-long pause the device sits through (one or two a
+        # minute; PERF.md, PR 31). Process-wide: docs/serving-engine.md, "The
+        # host's collector".
+        gc.collect()
+        gc.freeze()
+        self._gc_frozen = True
         log.info("engine prewarm complete (constrained=%s)", constrained)
 
     def _prewarm_gap(self, phase: str, **detail) -> None:
@@ -2081,6 +2159,21 @@ class Engine:
                 "pages_per_turn": self.pages_per_turn,
                 "table_uploads": self.table_uploads,
             }
+            model = programs(self.config)  # not the engine thread's fields: any thread asks
+            if model.has_state:
+                # per-slot state beside the pages: copies taken of a saved
+                # state, states installed before a continuation, and hits
+                # refused because no state was saved at their cut
+                out["kv_pages"].update(
+                    state_saves=self.state_saves,
+                    state_restores=self.state_restores,
+                    state_refused=self.state_refused,
+                )
+            if model.counters is not None:
+                name, described = model.describe_counters(
+                    self.config, self._read_counters()
+                )
+                out[name] = described
         if self._prefix_enabled:
             with self._prefix_lock:
                 out["prefix_cache"] = {
@@ -2091,6 +2184,55 @@ class Engine:
                     "cached_tokens": self._cached_tokens_locked(),
                 }
         return out
+
+    def _with_counters(self, program):
+        """``program(params, cache, ...) -> (cache, ...)`` jitted by the
+        caller's ``jit``: for a family that keeps counters in its cache
+        (``models.programs(...).counters``) the traced program gives a second
+        copy of them back beside the cache, a buffer of its own that no later
+        dispatch donates, and the engine thread keeps the newest for
+        ``stats()`` to read from any thread. No program of its own, nothing
+        fetched until ``stats()`` asks. Other families' programs are jitted
+        as they are."""
+        counters = self._counters
+        if counters is None:
+            return lambda jit: jit(program)
+
+        # the program keeps its name (decode_block, prefill_and_sample, ...):
+        # the device's module is called after it and trace readers match on it
+        @functools.wraps(program)
+        def counted(*args):
+            cache, *rest = program(*args)
+            return ((cache, counters(cache)), *rest)
+
+        def wrap(jit):
+            jitted = jit(counted)
+
+            @functools.wraps(jitted)
+            def call(*args):
+                (cache, snap), *rest = jitted(*args)
+                self._counters_snap = snap  # acp: engine thread; one reference store
+                return (cache, *rest)
+
+            return call
+
+        return wrap
+
+    def _read_counters(self):  # acp: cross-thread
+        """The family's counters summed since construction (np.uint64, or
+        None before the first dispatch): the device's wrap at 2**32, so the
+        differences between readings are what is summed."""
+        with self._counters_lock:
+            snap = self._counters_snap
+            if snap is not None:
+                cur = np.asarray(snap).astype(np.uint64)
+                seen = self._counters_seen if self._counters_seen is not None else np.zeros_like(cur)
+                delta = (cur - seen) & np.uint64(0xFFFFFFFF)
+                self._counters_total = (
+                    delta if self._counters_total is None else self._counters_total + delta
+                )
+                self._counters_seen = cur
+            return self._counters_total
 
     def _preempted_waiting(self) -> int:  # acp: cross-thread
         """Requeued-after-preemption count; tolerant of cross-thread reads
@@ -2548,6 +2690,18 @@ class Engine:
                 elif self._prefix_enabled and not req.truncated:
                     self._prefix_misses += 1
                     REGISTRY.counter_add("acp_engine_prefix_cache_miss_requests", 1.0)
+                if self._has_state:
+                    # the state the suffix resumes from, into the slot's
+                    # live state before any program of this admission runs
+                    if swap is not None:
+                        self._install_state(slot, swap.state)
+                    elif match is not None and match[1].get("in_slot"):
+                        self._install_state(slot, slot)  # its own snapshot
+                    elif start > 0:
+                        self._install_state(slot, match[1]["state"])
+                    req.state_cut = self._state_cut_for(
+                        req, swap.cut if swap is not None else start
+                    )
                 if not req.prewarm:
                     # admit = the reservation decision: slot id (+ pages in
                     # paged mode) taken, prefix-cache start resolved. In
@@ -2671,7 +2825,7 @@ class Engine:
                             self._put(toks),
                             self._put(np.full(B, CH, dtype=np.int32)),
                             self._put(starts),
-                            self._put(page_ids),
+                            self._page_arg(page_ids, slots, [e[0][0] for e in batch]),
                             block_tables,
                             *tail,
                         )
@@ -3179,10 +3333,10 @@ class Engine:
             fn = self._jit_swap_scatter.get(m)
             if fn is None:
                 fn = jax.jit(
-                    lambda c, ids, blocks: {
+                    lambda c, ids, blocks: {**c, **{
                         name: c[name].at[:, ids].set(blocks[name])
-                        for name in c
-                    },
+                        for name in blocks
+                    }},
                     donate_argnums=(0,),
                 )
                 self._jit_swap_scatter[m] = fn
@@ -3478,7 +3632,7 @@ class Engine:
                 self._put(toks),
                 self._put(lengths),
                 self._put(starts),
-                self._put(page_ids),
+                self._page_arg(page_ids, slots, [sl.request for _, sl, _, _ in batch]),
                 block_tables,
                 *tail,
             )
@@ -3503,6 +3657,61 @@ class Engine:
             self.profiler.account(
                 goodput=real - pre, prewarm=pre, pad_bucket=B * bucket - real
             )
+
+    # -- per-slot state beside the pages (models.programs().has_state) ----
+
+    def _page_arg(self, page_ids: np.ndarray, slot_ids, reqs: list):
+        # acp: dispatch-lanes slots,snap_at
+        """What a paged prefill program gets for its page ids: the ids
+        alone, or for a family with per-slot state the pair ``(ids, (slots,
+        snap_at))``: which slot's state each row reads and writes, and the
+        length at which its snapshot is due (-1: none). Rows past ``reqs``
+        are a fused dispatch's padding lanes: a slot out of range, whose
+        state writes are dropped."""
+        if not self._has_state:
+            return self._put(page_ids)
+        rows = page_ids.shape[0]
+        slots = np.full(rows, self.max_slots, dtype=np.int32)
+        slots[: len(reqs)] = np.asarray(slot_ids, dtype=np.int32)[: len(reqs)]
+        snap_at = np.full(rows, -1, dtype=np.int32)
+        snap_at[: len(reqs)] = [r.state_cut or -1 for r in reqs]
+        return self._put(page_ids), (self._put(slots), self._put(snap_at))
+
+    def _state_cut_for(self, req: _Request, start: int) -> int:
+        """The one length of this admission at which the state is saved:
+        the prompt's last page boundary (``_save_prefix``'s cut, which a
+        park keeps to as well), 0 where the admission starts past it and so
+        never computes it."""
+        cap = min(len(req.prompt), len(self._full_row(req)) - 1)
+        cut = (cap // self.page_size) * self.page_size
+        return cut if start <= cut else 0
+
+    @_in_phase("launch")
+    def _install_state(self, slot: int, state) -> None:  # acp: megastep-seam — once an admission that resumes
+        """``conv[:, slot] = state``: what a continuation that starts past
+        0 resumes from. ``state`` is a device or host array
+        [n_conv, taps-1, D], or an int: the slot whose snapshot to copy."""
+        if self._jit_install_state is None:
+            install, saved = self._model.install_state, self._model.saved_state
+            self._jit_install_state = (
+                jax.jit(lambda c, s, st: install(c, s, st), donate_argnums=(0,)),
+                jax.jit(lambda c, s, src: install(c, s, saved(c, src)), donate_argnums=(0,)),
+            )
+        by_array, by_slot = self._jit_install_state
+        if isinstance(state, int):
+            self.cache = by_slot(self.cache, jnp.int32(slot), jnp.int32(state))
+        else:
+            if not isinstance(state, jax.Array):  # a host entry's
+                state = self._put(np.asarray(state))
+            self.cache = by_array(self.cache, jnp.int32(slot), state)
+        self.state_restores += 1
+
+    def _saved_state(self, slot: int):  # acp: megastep-seam — once a prefix entry, swap-out or handoff
+        """A device copy of the slot's snapshot (its state at state_cut)."""
+        if self._jit_saved_state is None:
+            self._jit_saved_state = jax.jit(self._model.saved_state)
+        self.state_saves += 1
+        return self._jit_saved_state(self.cache, jnp.int32(slot))
 
     # -- prefix KV cache (slot layout) -----------------------------------
 
@@ -3564,7 +3773,7 @@ class Engine:
             real_tokens=cut, real_slots=1,
         )
 
-    def _save_prefix(self, full: list[int], prompt_len: int, slot: int) -> None:  # acp: megastep-seam # acp: kv-seam
+    def _save_prefix(self, full: list[int], prompt_len: int, slot: int, state_cut: int = 0) -> None:  # acp: megastep-seam # acp: kv-seam
         """After a prefill: snapshot the slot's leading KV as a reusable
         prefix entry (LRU-capped). Slot layout: a device COPY at the largest
         bucket/chunk boundary. Paged layout: zero-copy — take a reference on
@@ -3590,6 +3799,8 @@ class Engine:
             cut = max(cut, (cap // CH) * CH)
         if cut < min(self.prefill_buckets[0], 4 * self.page_size):
             return  # too short to be worth caching
+        if self._has_state and state_cut != cut:
+            return  # no state was saved at this length: nothing could resume from it
         key = tuple(full[:cut])
         with self._prefix_lock:
             if key in self._prefix_cache:
@@ -3599,6 +3810,8 @@ class Engine:
             pages = self._slot_pages[slot][: cut // self.page_size]
             self._allocator.share(pages)
             entry = {"cut": cut, "pages": list(pages)}
+            if self._has_state:
+                entry["state"] = self._saved_state(slot)
         else:
             fn = self._jit_extract_prefix.get(cut)
             if fn is None:
@@ -3716,6 +3929,13 @@ class Engine:
                     if self.kv_layout == "paged":
                         host_cut = (host_cut // self.page_size) * self.page_size
                     if host_cut < self._swap_min_rows():
+                        host_e, host_cut = None, 0
+                    elif self._has_state and (
+                        host_e.state is None or host_cut != host_e.cut
+                    ):
+                        # the rows could be restored, but no state was saved
+                        # at the length they would resume from: a miss
+                        self.state_refused += 1
                         host_e, host_cut = None, 0
             # dedup candidate: share a live slot's (or an earlier group
             # member's) prompt pages instead of materializing a copy
@@ -3975,6 +4195,9 @@ class Engine:
                     assert pages is not None
                     fresh = pages[int(starts[i]) // P :]
                     page_ids[i, : len(fresh)] = fresh
+                page_arg = self._page_arg(
+                    page_ids, ln["slots"], [r for r, _, _, _ in chunk]
+                )
                 if starts_np is not None:
                     self._cont_batch_sizes.add(B)
                     block_tables = self._put(
@@ -3982,11 +4205,11 @@ class Engine:
                     )
                     cache, firsts, con_states = self._jit_prefill_paged_continue(
                         self.params, self.cache, *common,
-                        self._put(starts), self._put(page_ids), block_tables, *tail,
+                        self._put(starts), page_arg, block_tables, *tail,
                     )
                 else:
                     cache, firsts, con_states = self._jit_prefill_paged(
-                        self.params, self.cache, *common, self._put(page_ids), *tail
+                        self.params, self.cache, *common, page_arg, *tail
                     )
             elif starts_np is not None:
                 self._cont_batch_sizes.add(B)
@@ -4044,7 +4267,9 @@ class Engine:
         if self._prefix_enabled:
             for req, slot, _, _m in chunk:
                 if not req.truncated:
-                    self._save_prefix(self._full_row(req), len(req.prompt), slot)
+                    self._save_prefix(
+                        self._full_row(req), len(req.prompt), slot, req.state_cut
+                    )
         self._state_dirty = True  # new slots: decode must re-upload state
         now = time.monotonic()
         for i, (req, slot, _, _m) in enumerate(chunk):
@@ -4729,7 +4954,8 @@ class Engine:
             tables[:B] = self._block_tables[[slot for slot, _, _, _ in batch]]
             lanes = (
                 self._put(toks), self._put(lengths), self._put(starts),
-                self._put(page_ids), self._put(tables),
+                self._page_arg(page_ids, slots[:B], [sl.request for _, sl, _, _ in batch]),
+                self._put(tables),
             )
         else:
             lanes = (
@@ -4785,7 +5011,9 @@ class Engine:
             )
             tables[:B] = self._block_tables[[slot for slot, _, _, _ in batch]]
             model_lanes = (
-                toks_d, lens_d, starts_d, self._put(page_ids), self._put(tables)
+                toks_d, lens_d, starts_d,
+                self._page_arg(page_ids, ln["slots"], [r for r, _, _, _ in chunk]),
+                self._put(tables),
             )
         else:
             model_lanes = (
@@ -4834,7 +5062,7 @@ class Engine:
         model_lanes = (
             self._put(pad(ln["tokens"], 0)),
             self._put(pad(ln["lengths"], 0)),
-            self._put(page_ids),
+            self._page_arg(page_ids, ln["slots"], [r for r, _, _, _ in chunk]),
         )
         return (model_lanes, sample), bucket, Bp, chunk, ln
 
@@ -5620,6 +5848,9 @@ class Engine:
         the PROMPT rows only (the next turn re-renders the assistant
         message, so generated-token KV can never match), page-aligned in
         paged mode because continuation prefill resumes at page grain."""
+        if self._has_state:
+            # only where this admission saved the state can a turn resume
+            return sl.request.state_cut
         if self.kv_layout == "paged":
             return (sl.prompt_len // self.page_size) * self.page_size
         return sl.prompt_len
@@ -5909,6 +6140,8 @@ class Engine:
         cut = min(rows, len(row) - 1)  # strict prefix: decode must model >= 1
         if self.kv_layout == "paged":
             cut = (cut // self.page_size) * self.page_size
+        if self._has_state:
+            cut = min(cut, req.state_cut)  # the one length with a saved state
         if cut < self._swap_min_rows():
             return None
         from ..ops.paged import HostKVEntry
@@ -5923,6 +6156,7 @@ class Engine:
             rid=f"handoff-{req.rid}", tokens=tuple(row[:cut]),
             k=out["k"], v=out["v"],
             k_scale=out.get("ks"), v_scale=out.get("vs"),
+            state=np.asarray(self._saved_state(slot)) if self._has_state else None,
         )
         self.flight.record(
             "handoff_export", rid=req.rid, slot=slot, tokens=cut,
@@ -5962,6 +6196,15 @@ class Engine:
         cut = min(rows, len(row) - 1)  # strict prefix: resume must model >= 1 token
         if self.kv_layout == "paged":
             cut = (cut // self.page_size) * self.page_size
+        if self._has_state and not (sl.prefilling and sl.swap_entry is not None):
+            # rows past the one length whose state was saved could not be
+            # resumed from: offload up to it, or nothing if it is not
+            # written yet
+            if req.state_cut > cut:
+                if rows >= self._swap_min_rows():
+                    self.state_refused += 1
+                return False
+            cut = req.state_cut
         if cut < self._swap_min_rows() and not (
             sl.prefilling and sl.swap_entry is not None
         ):
@@ -5994,6 +6237,7 @@ class Engine:
                 entry = HostKVEntry(
                     rid=req.rid, tokens=entry.tokens, k=entry.k, v=entry.v,
                     k_scale=entry.k_scale, v_scale=entry.v_scale,
+                    state=entry.state,
                 )
             cut = entry.cut
         else:
@@ -6008,6 +6252,7 @@ class Engine:
                 rid=req.rid, tokens=tuple(row[:cut]),
                 k=rows["k"], v=rows["v"],
                 k_scale=rows.get("ks"), v_scale=rows.get("vs"),
+                state=np.asarray(self._saved_state(slot)) if self._has_state else None,
             )
         if not pool.put(entry):
             return False  # bigger than the whole budget: recompute instead
@@ -6044,7 +6289,9 @@ class Engine:
             fn = self._jit_swap_gather.get(n)
             if fn is None:
                 fn = jax.jit(
-                    lambda c, ids: {name: a[:, ids] for name, a in c.items()}
+                    lambda c, ids: {
+                        name: a[:, ids] for name, a in c.items() if name != "state"
+                    }
                 )
                 self._jit_swap_gather[n] = fn
             ids = np.asarray(pages[i : i + n], dtype=np.int32)
@@ -6062,11 +6309,11 @@ class Engine:
         T = len(pages) * P
         out_np: dict[str, np.ndarray] = {}
         with self.profiler.phase("fetch"):
-            for name in self.cache:
+            for name in chunks[0]:
                 parts = [np.asarray(ch[name]) for ch in chunks]
                 merged = np.concatenate(parts, axis=1)  # [L, nP_total, P, ...]
                 out_np[name] = merged.reshape(
-                    (cfg.n_layers, T) + merged.shape[3:]
+                    (merged.shape[0], T) + merged.shape[3:]
                 )
         return out_np
 
@@ -6133,10 +6380,10 @@ class Engine:
                 fn = self._jit_swap_scatter.get(m)
                 if fn is None:
                     fn = jax.jit(
-                        lambda c, ids, blocks: {
+                        lambda c, ids, blocks: {**c, **{
                             name: c[name].at[:, ids].set(blocks[name])
-                            for name in c
-                        },
+                            for name in blocks
+                        }},
                         donate_argnums=(0,),
                     )
                     self._jit_swap_scatter[m] = fn
@@ -6261,6 +6508,12 @@ class Engine:
         the follower-wait test would lie); ties keep the first candidate,
         so a burst chains every follower to the one root writer."""
         if self.kv_layout != "paged" or not self.prefix_dedup:
+            return None
+        if self._has_state:
+            # a live leader's pages could be shared, but its state at the
+            # common cut was not saved (its one snapshot is at its own
+            # prompt's last page boundary, and may not be written yet):
+            # counted in _collect_group where a share was otherwise due
             return None
         best: Optional[tuple] = None
         for s, sl in self._slots.items():

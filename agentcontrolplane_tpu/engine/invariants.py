@@ -201,7 +201,8 @@ def _verify_quantized_cache(engine) -> list[str]:
     identical plain cache). Shape/dtype metadata only — no device
     transfer."""
     problems: list[str] = []
-    keys = set(engine.cache)
+    # a family's per-slot state beside the pages is no part of the KV pool
+    keys = set(engine.cache) - {"state"}
     if not engine.quantize_kv:
         if keys != {"k", "v"}:
             problems.append(

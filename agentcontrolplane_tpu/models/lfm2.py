@@ -1,0 +1,639 @@
+"""LFM2-MoE model family (``model_type: lfm2_moe``): more than one kind of
+layer in one model.
+
+Every layer is ``h = x + Op(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))`` with
+
+- ``Op`` a gated short convolution (``conv``): ``[B, C, u] = split3(x W_in)``,
+  ``s_t = B_t * u_t``, ``c_t = sum_j w[:, j] * s_{t-(taps-1)+j}`` (depthwise,
+  causal, no bias, no activation), ``Op = (C_t * c_t) W_out``; or GQA
+  attention (``attention``) with an RMSNorm over each head of q and of k
+  before rotary;
+- ``FF`` a dense SwiGLU (the first ``num_dense_layers`` layers) or routed
+  experts (``ops.moe.routed_experts``: sigmoid scores, a selection bias,
+  top-k renormalised, of which this chip holds ``experts_held``).
+
+Layout for XLA (``plan``): the leading dense layers are written out; the
+expert layers are one ``lax.scan`` whose body is one layer, a switch on its
+kind between the two operators (weights stacked by kind, each layer reading
+its own row) and the expert FF (router stacked over the scan; the experts of
+all layers flattened to one axis and closed over, indexed by the grouped
+matmul itself). The HLO holds each piece once whatever the depth and the
+pattern.
+
+Serving state (paged layout only): the KV pool holds the attention layers
+alone, in the layout the page walk reads, ``[n_attention, pages, P, H_kv *
+d]`` (a page's row is its KV heads side by side: the walk's DMA source as it
+is, where a ``[.., H_kv, d]`` pool is relaid on the chip's tiling every time
+it is merged; int8 pages keep a scale a row and head, ``[.., P, H_kv]``), and
+beside it ``cache["state"]``:
+
+- ``conv``  ``[n_conv, slots, taps-1, D]`` in the model's dtype: the last
+  ``taps-1`` values of ``s`` of every slot and conv layer, what a decode
+  step reads and shifts (0.25 MB a slot at the published widths);
+- ``snap``  the same shape: a copy taken inside a prefill at the one
+  page-aligned length the engine names (``snap_at``), the state a prefix
+  hit, a park or a host swap at that length resumes from;
+- ``moe``   ``[2, 1 + COUNTS_HEAD + held]`` uint32 counters of the expert
+  layers (row 0 decode steps, row 1 prefills), wrapping; ``Engine.stats``
+  sums their differences.
+
+Every program takes ``lanes = (slots, snap_at)`` beside the page ids: which
+slot's state each row reads and writes, and where (absolute tokens, -1:
+nowhere) its snapshot is due. A row that starts at 0 starts from a zero
+state; one that starts later reads ``conv[:, slot]``, which the engine has
+set (the previous chunk left it, or ``install_state`` copied it in).
+
+The decode walk reads the whole pool flattened to ``[L * pages, P, ...]``
+through block tables offset by the layer, so no layer of the pool is sliced
+out or relaid per step.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
+from ..ops.moe import COUNTS_HEAD, routed_experts
+from ..ops.norms import rms_norm
+from ..ops.quant import kv_dequantize, kv_quantize
+from ..ops.rope import apply_rope
+
+PERIOD = ("attention", "conv", "conv", "conv")
+
+
+def _pattern(prologue: int, periods: int, tail: tuple[str, ...]) -> tuple[str, ...]:
+    return ("conv",) * prologue + PERIOD * periods + tail
+
+
+@dataclass(frozen=True)
+class Lfm2Config:
+    vocab_size: int = 65536
+    dim: int = 2048
+    n_heads: int = 32
+    n_kv_heads: int = 8
+    head_dim: int = 64
+    layer_types: tuple[str, ...] = _pattern(2, 9, ("attention", "conv"))
+    num_dense_layers: int = 2
+    ffn_dim: int = 11776  # the dense layers' SwiGLU
+    expert_ffn_dim: int = 1536
+    n_experts: int = 64  # the router's width
+    experts_per_token: int = 4
+    # global ids of the experts this chip holds, in the order of its
+    # weights' leading axis; None holds all
+    experts_held: Optional[tuple[int, ...]] = None
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    conv_taps: int = 3  # conv_L_cache
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    max_seq_len: int = 128000
+    tie_embeddings: bool = True
+    dtype: Any = jnp.bfloat16
+    # what the engine asks of every config and this family has none of
+    attn_logit_softcap: float = 0.0
+    post_norms: bool = False
+    sliding_window: int = 0
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_attention(self) -> int:
+        return sum(t == "attention" for t in self.layer_types)
+
+    @property
+    def n_conv(self) -> int:
+        return sum(t == "conv" for t in self.layer_types)
+
+    @property
+    def held(self) -> tuple[int, ...]:
+        return tuple(range(self.n_experts)) if self.experts_held is None else self.experts_held
+
+
+PRESETS: dict[str, Lfm2Config] = {
+    # LiquidAI/LFM2-24B-A2B whole: 47.7 GB of bfloat16, no single chip
+    "lfm2-24b-a2b": Lfm2Config(),
+    # one of eight chips that share each layer: experts 0..7 of 64 held,
+    # everything else whole (7.5 GB of weights)
+    "lfm2-24b-a2b-ep8": Lfm2Config(experts_held=tuple(range(8))),
+    # CPU tests: every kind of layer, prologue, two periods and the tail
+    "lfm2-tiny": Lfm2Config(
+        vocab_size=256, dim=64, n_heads=4, n_kv_heads=2, head_dim=16,
+        layer_types=_pattern(2, 2, ("attention", "conv")), num_dense_layers=2,
+        ffn_dim=128, expert_ffn_dim=32, n_experts=8, experts_per_token=2,
+        max_seq_len=256, rope_theta=10000.0, dtype=jnp.float32,
+    ),
+}
+
+
+def plan(c: Lfm2Config) -> dict:
+    """The layer list as the leading dense layers' kinds (written out) and
+    the expert layers' (one scan), with each expert layer's index among the
+    attention or the conv layers of the scan."""
+    import numpy as np
+
+    types = tuple(c.layer_types)
+    bad = set(types) - {"conv", "attention"}
+    if bad:
+        raise ValueError(f"unknown layer types {sorted(bad)} (conv|attention)")
+    pro, body = types[:c.num_dense_layers], types[c.num_dense_layers:]
+    is_attn = np.array([k == "attention" for k in body], dtype=bool)
+    return {
+        "prologue": pro, "body": body, "is_attn": is_attn,
+        # an expert layer's row in the stack of its own kind (0 for the other's)
+        "attn_row": np.where(is_attn, np.cumsum(is_attn) - 1, 0).astype(np.int32),
+        "conv_row": np.where(~is_attn, np.cumsum(~is_attn) - 1, 0).astype(np.int32),
+    }
+
+
+def init_params(config: Lfm2Config, key: jax.Array) -> dict:
+    """Random init in the served layout: ``pro`` a tuple of whole layer
+    dicts (the leading dense layers), and the expert layers' weights stacked
+    by what they are: ``attn`` and ``conv`` (the operators, each over its
+    own layers in order) and ``ff`` (norm, router and experts, over all
+    expert layers)."""
+    c, pl_ = config, plan(config)
+    d, hd, eh, f = c.dim, c.head_dim, len(c.held), c.expert_ffn_dim
+    count = [0]
+
+    def w(shape, scale):
+        count[0] += 1
+        return (jax.random.normal(jax.random.fold_in(key, count[0]), shape) * scale).astype(c.dtype)
+
+    def conv(lead=()):
+        return {"ln1": jnp.ones(lead + (d,), c.dtype), "conv_in": w(lead + (d, 3 * d), d ** -0.5),
+                "conv_w": w(lead + (d, c.conv_taps), 0.5), "conv_out": w(lead + (d, d), d ** -0.5)}
+
+    def attn(lead=()):
+        return {"ln1": jnp.ones(lead + (d,), c.dtype),
+                "wq": w(lead + (d, c.n_heads * hd), d ** -0.5), "wk": w(lead + (d, c.n_kv_heads * hd), d ** -0.5),
+                "wv": w(lead + (d, c.n_kv_heads * hd), d ** -0.5), "wo": w(lead + (c.n_heads * hd, d), d ** -0.5),
+                "q_norm": jnp.ones(lead + (hd,), c.dtype), "k_norm": jnp.ones(lead + (hd,), c.dtype)}
+
+    def dense():
+        return {"ln2": jnp.ones((d,), c.dtype), "w1": w((d, c.ffn_dim), d ** -0.5),
+                "w3": w((d, c.ffn_dim), d ** -0.5), "w2": w((c.ffn_dim, d), c.ffn_dim ** -0.5)}
+
+    n_body, n_attn = len(pl_["body"]), int(pl_["is_attn"].sum())
+    params = {
+        "embed": w((c.vocab_size, d), d ** -0.5),
+        "norm": jnp.ones((d,), c.dtype),
+        "pro": tuple({**(conv() if k == "conv" else attn()), **dense()} for k in pl_["prologue"]),
+        "attn": attn((n_attn,)),
+        "conv": conv((n_body - n_attn,)),
+        "ff": {"ln2": jnp.ones((n_body, d), c.dtype), "router": w((n_body, d, c.n_experts), d ** -0.5),
+               "router_bias": jnp.zeros((n_body, c.n_experts), jnp.float32),
+               "w1": w((n_body, eh, d, f), d ** -0.5), "w3": w((n_body, eh, d, f), d ** -0.5),
+               "w2": w((n_body, eh, f, d), f ** -0.5)},
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = w((d, c.vocab_size), d ** -0.5)
+    return params
+
+
+def _mm(x, w):
+    return x @ w.astype(x.dtype)
+
+
+def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
+    """h [B, T, D] normed input; state_in [B, taps-1, D] (s before the
+    row's first token). -> (Op output [B, T, D], state at each row's end
+    [B, taps-1, D], state at ``snap_rel`` tokens into the row)."""
+    with jax.named_scope("short_conv"):
+        B, T, D = h.shape
+        n = c.conv_taps - 1
+        # the gates and the taps in float32 inside the operator, straight
+        # from the projection's accumulator; `s` itself in the model's dtype,
+        # the one the state keeps it in, so that a decode step that reads two
+        # values back convolves what the prefill convolved
+        bcu = jnp.matmul(h, layer["conv_in"].astype(h.dtype), preferred_element_type=jnp.float32)
+        b_, c_, u_ = bcu[..., :D], bcu[..., D:2 * D], bcu[..., 2 * D:]
+        s = (b_ * u_).astype(h.dtype)
+        s_ext = jnp.concatenate([state_in.astype(h.dtype), s], axis=1).astype(jnp.float32)  # [B, n + T, D]
+        taps = layer["conv_w"].astype(jnp.float32)  # [D, taps]
+        conv = sum(s_ext[:, j:j + T] * taps[:, j] for j in range(c.conv_taps))
+        out = _mm((c_ * conv).astype(h.dtype), layer["conv_out"])
+
+        def state_at(rel):  # the n values of s before token `rel` of the row
+            idx = jnp.clip(rel, 0, T)[:, None] + jnp.arange(n)[None, :]
+            return jnp.take_along_axis(s_ext, idx[:, :, None], axis=1).astype(h.dtype)
+
+        return out, state_at(lengths), state_at(snap_rel)
+
+
+def _attention_op(h, layer, c: Lfm2Config, positions, attn_fn):
+    """-> (Op output, k, v): k and v are the layer's new rows for the pool."""
+    B, T, _ = h.shape
+    q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
+    k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+    v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+    q = rms_norm(q, layer["q_norm"], c.norm_eps)
+    k = rms_norm(k, layer["k_norm"], c.norm_eps)
+    q = apply_rope(q, positions, c.rope_theta)
+    k = apply_rope(k, positions, c.rope_theta)
+    out = attn_fn(q, k, v)
+    return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
+
+
+def _experts(x, ff, stacks, layer_index, c: Lfm2Config, valid, chosen=None):
+    """The routed FF of expert layer ``layer_index`` (traced): ``ff`` holds
+    its router, ``stacks`` every expert layer's experts flattened to one
+    leading axis, which the grouped matmul indexes from ``layer_index *
+    held``: a slice of a stack handed to an opaque kernel would be copied
+    out first, every step. ``chosen`` [B, T, k] is a routing given and not
+    made (``route`` of the programs). -> (FF output [B, T, D], counters)."""
+    B, T, D = x.shape
+    y, counts = routed_experts(
+        x.reshape(B * T, D), ff["router"], *stacks, c.experts_per_token, held=c.held, score="sigmoid",
+        bias=ff["router_bias"] if c.use_expert_bias else None, renormalize=c.norm_topk_prob,
+        scale=c.routed_scaling_factor, valid=valid.reshape(B * T),
+        expert_base=layer_index * len(c.held),
+        chosen=None if chosen is None else chosen.reshape(B * T, c.experts_per_token),
+    )
+    return y.reshape(B, T, D), jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
+
+
+def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None):
+    """The whole stack. ``conv_state`` [n_conv, B, taps-1, D] is each conv
+    layer's state before the rows; ``make_attn(a)`` gives attention layer
+    ``a``'s (traced index) attention function; ``route``
+    [n_expert_layers, B, T, k] int32, where given, is every expert layer's
+    choice of experts, taken as it is (an output check's teacher-forced
+    routing; serving never gives one). -> (x, conv ends
+    [n_conv, B, taps-1, D], conv snaps, new k [n_attention, B, T, H_kv, d],
+    new v, expert counters).
+
+    The leading dense layers are written out. The expert layers are ONE
+    scan whose body is one layer: a switch on the layer's kind between the
+    two operators (each reading its own row of its own stack) and the
+    expert FF, so a program holds each piece once whatever the depth and
+    the pattern, and compiles in a third of the time of a body that is a
+    whole period with prologue and tail beside it (PERF.md, PR 31)."""
+    pl_ = plan(c)
+    B, T, D = x.shape
+    n = c.conv_taps - 1
+    kv_shape = (B, T, c.n_kv_heads, c.head_dim)
+    ends, snaps, ks, vs = [], [], [], []
+    ci = ai = 0
+    # the residual stream in the model's dtype, as the source serves it:
+    # 80 sublayers' sums rounded to bfloat16 each are the largest part of
+    # what the program loses against its float32 reference (PERF.md, PR 31)
+    dt = x.dtype
+    norm = lambda x, w: rms_norm(x, w, c.norm_eps)  # noqa: E731
+    for kind, layer in zip(pl_["prologue"], params["pro"]):
+        h = norm(x, layer["ln1"])
+        if kind == "conv":
+            op, end, snap = _conv_op(h, layer, c, conv_state[ci], ctx["lengths"], ctx["snap_rel"])
+            ends.append(end[None])
+            snaps.append(snap[None])
+            ci += 1
+        else:
+            op, k, v = _attention_op(h, layer, c, ctx["positions"], make_attn(ai))
+            ks.append(k[None])
+            vs.append(v[None])
+            ai += 1
+        x = x + op
+        h = norm(x, layer["ln2"])
+        x = x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
+
+    counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
+    n_body = len(pl_["body"])
+    if n_body:
+        ci0, ai0 = ci, ai
+        ff = params["ff"]
+        stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
+        row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
+
+        def attention(x, a_row, c_row):
+            layer = row(params["attn"], a_row)
+            op, k, v = _attention_op(norm(x, layer["ln1"]), layer, c, ctx["positions"], make_attn(ai0 + a_row))
+            zero = jnp.zeros((B, n, D), dt)
+            return op, zero, zero, k.astype(dt), v.astype(dt)
+
+        def conv(x, a_row, c_row):
+            layer = row(params["conv"], c_row)
+            op, end, snap = _conv_op(norm(x, layer["ln1"]), layer, c, conv_state[ci0 + c_row],
+                                     ctx["lengths"], ctx["snap_rel"])
+            zero = jnp.zeros(kv_shape, dt)
+            return op, end, snap, zero, zero
+
+        def body(carry, scanned):
+            x, counts = carry
+            small, index, is_attn, a_row, c_row, chosen = scanned
+            if len(set(pl_["body"])) == 1:  # one kind only: its stack alone has rows
+                op, end, snap, k, v = (attention if pl_["is_attn"][0] else conv)(x, a_row, c_row)
+            else:
+                op, end, snap, k, v = jax.lax.cond(is_attn, attention, conv, x, a_row, c_row)
+            x = x + op
+            y, m = _experts(norm(x, small["ln2"]), small, stacks, index, c, ctx["valid"], chosen)
+            return (x + y, counts + m), (end, snap, k, v)
+
+        small = {name: ff[name] for name in ("ln2", "router", "router_bias")}
+        (x, counts), (e, s_, kk, vv) = jax.lax.scan(
+            body, (x, counts),
+            (small, jnp.arange(n_body, dtype=jnp.int32), jnp.asarray(pl_["is_attn"]),
+             jnp.asarray(pl_["attn_row"]), jnp.asarray(pl_["conv_row"]), route))
+        attn_at, conv_at = pl_["is_attn"].nonzero()[0], (~pl_["is_attn"]).nonzero()[0]
+        ends.append(e[conv_at])
+        snaps.append(s_[conv_at])
+        ks.append(kk[attn_at])
+        vs.append(vv[attn_at])
+
+    cat = lambda parts, shape, dtype: (  # noqa: E731
+        jnp.concatenate(parts, axis=0) if parts else jnp.zeros((0,) + shape, dtype))
+    return (x, cat(ends, (B, n, D), dt), cat(snaps, (B, n, D), dt), cat(ks, kv_shape, dt),
+            cat(vs, kv_shape, dt), counts)
+
+
+def _head_logits(x, params, c: Lfm2Config):
+    head = params["embed"].T if c.tie_embeddings else params["lm_head"]
+    return (x.astype(c.dtype) @ head.astype(c.dtype)).astype(jnp.float32)
+
+
+def _embed(params, tokens, c: Lfm2Config):
+    return params["embed"][tokens].astype(c.dtype)
+
+
+def _zero_state(c: Lfm2Config, B: int) -> jax.Array:
+    """Every conv layer's state before a sequence starts."""
+    return jnp.zeros((c.n_conv, B, c.conv_taps - 1, c.dim), c.dtype)
+
+
+def forward(params: dict, tokens: jax.Array, config: Lfm2Config) -> jax.Array:
+    """Full-sequence causal forward -> logits [B, T, V] float32 (tests)."""
+    c = config
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    ctx = {"positions": positions, "valid": jnp.ones((B, T), bool),
+           "lengths": jnp.full((B,), T, jnp.int32), "snap_rel": jnp.zeros((B,), jnp.int32)}
+    x, *_ = _run_layers(params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
+                        lambda a: lambda q, k, v: causal_attention(q, k, v, positions))
+    return _head_logits(rms_norm(x, params["norm"], c.norm_eps), params, c)
+
+
+# ---------------------------------------------------------------------------
+# Serving: pages for the attention layers, state beside them
+# ---------------------------------------------------------------------------
+
+
+def init_paged_cache(config: Lfm2Config, num_pages: int, page_size: int, quantize_kv: bool = False,
+                     max_slots: int = 1) -> dict:
+    c = config
+    rows = (c.n_attention, num_pages, page_size)
+    width = c.n_kv_heads * c.head_dim
+    if quantize_kv:
+        cache = {"k": jnp.zeros(rows + (width,), jnp.int8), "v": jnp.zeros(rows + (width,), jnp.int8),
+                 "ks": jnp.zeros(rows + (c.n_kv_heads,), jnp.float32),
+                 "vs": jnp.zeros(rows + (c.n_kv_heads,), jnp.float32)}
+    else:
+        cache = {"k": jnp.zeros(rows + (width,), c.dtype), "v": jnp.zeros(rows + (width,), c.dtype)}
+    shape = (c.n_conv, max_slots, c.conv_taps - 1, c.dim)
+    cache["state"] = {
+        "conv": jnp.zeros(shape, c.dtype),
+        "snap": jnp.zeros(shape, c.dtype),
+        "moe": jnp.zeros((2, 1 + COUNTS_HEAD + len(c.held)), jnp.uint32),
+    }
+    return cache
+
+
+def _kv(cache: dict) -> dict:
+    return {k: v for k, v in cache.items() if k != "state"}
+
+
+def _kv_commit(pool: dict, new_k, new_v, setter) -> dict:
+    """Fresh K/V ``[L, ..., H_kv, d]`` into the pool through ``setter(array,
+    values)``: heads merged into the pool's row, int8 pools quantized here
+    a row and head, their scales through the same setter."""
+    merge = lambda t: t.reshape(t.shape[:-2] + (t.shape[-2] * t.shape[-1],))  # noqa: E731
+    if "ks" in pool:
+        (qk, sk), (qv, sv) = kv_quantize(new_k), kv_quantize(new_v)
+        return {"k": setter(pool["k"], merge(qk)), "v": setter(pool["v"], merge(qv)),
+                "ks": setter(pool["ks"], sk), "vs": setter(pool["vs"], sv)}
+    return {"k": setter(pool["k"], merge(new_k).astype(pool["k"].dtype)),
+            "v": setter(pool["v"], merge(new_v).astype(pool["v"].dtype))}
+
+
+def _commit_whole_pages(pool: dict, new_k, new_v, page_ids) -> dict:
+    """``new_k`` [L, B, T, H_kv, d] into pages ``page_ids`` [B, T // P]."""
+    L, B, T = new_k.shape[:3]
+    NP, P = pool["k"].shape[1:3]
+    # one scatter of whole pages into the pool flattened over its layers: a
+    # scatter windowed over the layer axis makes the compiler keep the pool
+    # layer-minor, and relay it for the walk inside every period of the scan
+    ids = (jnp.arange(L)[:, None] * NP + page_ids.reshape(-1)[None, :]).reshape(-1)
+
+    def setter(arr, val):
+        blocks = val.reshape((ids.shape[0], P) + val.shape[3:])
+        return arr.reshape((L * NP,) + arr.shape[2:]).at[ids].set(blocks).reshape(arr.shape)
+
+    return _kv_commit(pool, new_k, new_v, setter)
+
+
+def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, row):
+    """The cache with its pages replaced and the rows' state written: a
+    row's end state always, its snapshot where one fell inside the row."""
+    st = cache["state"]
+    conv = st["conv"].at[:, slots].set(ends.astype(st["conv"].dtype), mode="drop")
+    old = st["snap"][:, jnp.clip(slots, 0, st["snap"].shape[1] - 1)]
+    snap = st["snap"].at[:, slots].set(
+        jnp.where(snap_ok[None, :, None, None], snaps.astype(old.dtype), old), mode="drop")
+    return {**pages, "state": {"conv": conv, "snap": snap, "moe": st["moe"].at[row].add(counts)}}
+
+
+def _rows_ctx(lengths, starts, snap_at, T):
+    ar = jnp.arange(T)
+    valid = ar[None, :] < lengths[:, None]
+    positions = jnp.where(valid, starts[:, None] + ar[None, :], -1)
+    snap_rel = snap_at - starts
+    ctx = {"positions": positions, "valid": valid, "lengths": lengths, "snap_rel": snap_rel}
+    return ctx, (snap_rel >= 0) & (snap_rel <= lengths) & (lengths > 0)
+
+
+def _state_in(cache, slots, starts):
+    """[n_conv, B, taps-1, D]: zeros for a row that starts the sequence,
+    the slot's state otherwise. A padding lane's slot is out of range: it
+    reads any slot's and writes nowhere (``mode="drop"``)."""
+    conv = cache["state"]["conv"]
+    got = conv[:, jnp.clip(slots, 0, conv.shape[1] - 1)]
+    return jnp.where((starts > 0)[None, :, None, None], got, 0)
+
+
+def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config: Lfm2Config, route=None):
+    """B whole prompts in one dispatch: K/V into each row's pages, the conv
+    state at the prompt's end into its slot. -> (cache, logits [B, V])."""
+    c = config
+    slots, snap_at = lanes
+    B, T = tokens.shape
+    zero = jnp.zeros((B,), jnp.int32)
+    ctx, snap_ok = _rows_ctx(lengths, zero, snap_at, T)
+    positions = ctx["positions"]
+    x, ends, snaps, new_k, new_v, counts = _run_layers(
+        params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
+        lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions), route)
+    pages = _commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, 1)
+    x = rms_norm(x, params["norm"], c.norm_eps)
+    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, c)
+
+
+def _gather_rows(pool: dict, name: str, ids, dtype, n_kv_heads: int):
+    """Pages ``ids`` (any shape) of the flattened pool, heads apart again
+    ``[..., P, H_kv, d]``, int8 pages dequantized by their scale twins."""
+    flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])  # noqa: E731
+    rows = flat(pool[name])[ids]
+    rows = rows.reshape(rows.shape[:-1] + (n_kv_heads, rows.shape[-1] // n_kv_heads))
+    if name + "s" in pool:
+        return kv_dequantize(rows, flat(pool[name + "s"])[ids], dtype)
+    return rows.astype(dtype)
+
+
+def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
+    """Rows that start at ``starts`` (page-aligned), attending over their
+    gathered prefix pages plus themselves, conv layers carried on from the
+    slots' state. -> (x normed, new k, new v uncommitted, ends, snaps,
+    snap_ok, counts)."""
+    slots, snap_at = lanes
+    B, T = tokens.shape
+    ctx, snap_ok = _rows_ctx(lengths, starts, snap_at, T)
+    positions = ctx["positions"]
+    pool = _kv(cache)
+    NP, P = pool["k"].shape[1], pool["k"].shape[2]
+    M = block_tables.shape[1]
+    row_pos = jnp.arange(M * P)
+    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
+    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
+
+    def make_attn(a):
+        def attn(q, k, v):
+            ids = block_tables + a * NP
+            k_rows = _gather_rows(pool, "k", ids, k.dtype, c.n_kv_heads).reshape(B, M * P, *k.shape[2:])
+            v_rows = _gather_rows(pool, "v", ids, v.dtype, c.n_kv_heads).reshape(B, M * P, *v.shape[2:])
+            return continue_attention(q, jnp.concatenate([k_rows, k], axis=1),
+                                      jnp.concatenate([v_rows, v], axis=1), positions, key_pos)
+
+        return attn
+
+    x, ends, snaps, new_k, new_v, counts = _run_layers(
+        params, c, _embed(params, tokens, c), ctx, _state_in(cache, slots, starts), make_attn)
+    return rms_norm(x, params["norm"], c.norm_eps), new_k, new_v, ends, snaps, snap_ok, counts
+
+
+def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
+                           config: Lfm2Config):
+    """Continuation (a prefix hit's suffix, a later chunk of a long
+    prompt): -> (cache, last-token logits [B, V])."""
+    B = tokens.shape[0]
+    x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
+        params, cache, tokens, lengths, starts, block_tables, lanes, config)
+    pages = _commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
+    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, config)
+
+
+def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
+                              config: Lfm2Config):
+    """The continuation's writes without the head (a mid chunk)."""
+    _x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
+        params, cache, tokens, lengths, starts, block_tables, lanes, config)
+    pages = _commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
+
+
+def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, config: Lfm2Config,
+                      use_pallas: bool = False, mesh=None, route=None):
+    """One token for lanes 0..S-1 (lane b is slot b): attention layers walk
+    the pages, conv layers read and shift their slot's state; an inactive
+    lane's state and pages are left as they were."""
+    from ..ops.paged import TRASH_PAGE, paged_decode_attention_reference_cache_plus_new
+
+    c = config
+    S = tokens.shape[0]
+    pool = _kv(cache)
+    L, NP, P = pool["k"].shape[:3]
+    flat = lambda a: a.reshape((L * NP,) + a.shape[2:])  # noqa: E731
+    heads = lambda a: a.reshape(a.shape[:-1] + (c.n_kv_heads, c.head_dim))  # noqa: E731
+    # the walk takes the merged pool as it is; the XLA reference wants the
+    # heads apart
+    k_flat, v_flat = flat(pool["k"]), flat(pool["v"])
+    if not use_pallas:
+        k_flat, v_flat = heads(k_flat), heads(v_flat)
+    scales = (flat(pool["ks"]), flat(pool["vs"])) if "ks" in pool else (None, None)
+    ctx = {"positions": seq_lens[:, None], "valid": active[:, None],
+           "lengths": jnp.ones((S,), jnp.int32), "snap_rel": jnp.zeros((S,), jnp.int32)}
+
+    def make_attn(a):
+        def attn(q, k, v):
+            tables = block_tables + a * NP
+            args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
+            if use_pallas:
+                from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
+
+                out = paged_decode_attention_cache_plus_new(*args)
+            else:
+                out = paged_decode_attention_reference_cache_plus_new(
+                    *args, k_scales=scales[0], v_scales=scales[1])
+            return out[:, None]
+
+        return attn
+
+    st = cache["state"]
+    x, ends, _snaps, new_k, new_v, counts = _run_layers(
+        params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route)
+    target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
+    # one scatter of token rows into the pool flattened to rows (see
+    # _commit_whole_pages): row (layer, page(slot), offset(slot))
+    rows = ((jnp.arange(L)[:, None] * NP + target[None, :]) * P + (seq_lens % P)[None, :]).reshape(-1)
+    pages = _kv_commit(
+        pool, new_k[:, :, 0], new_v[:, :, 0],
+        lambda arr, val: arr.reshape((L * NP * P,) + arr.shape[3:]).at[rows].set(
+            val.reshape((L * S,) + val.shape[2:])).reshape(arr.shape))
+    conv = st["conv"].at[:, :S].set(
+        jnp.where(active[None, :, None, None], ends.astype(st["conv"].dtype), st["conv"][:, :S]))
+    cache = {**pages, "state": {"conv": conv, "snap": st["snap"], "moe": st["moe"].at[0].add(counts)}}
+    x = rms_norm(x[:, 0], params["norm"], c.norm_eps)
+    return cache, _head_logits(x, params, c)
+
+
+def install_state(cache: dict, slot, state: jax.Array) -> dict:
+    """``conv[:, slot] = state`` [n_conv, taps-1, D]: what a continuation
+    that starts past 0 in ``slot`` resumes from (a prefix entry's, a parked
+    turn's or a host entry's saved state)."""
+    st = cache["state"]
+    conv = jax.lax.dynamic_update_slice(st["conv"], state.astype(st["conv"].dtype)[:, None], (0, slot, 0, 0))
+    return {**cache, "state": {**st, "conv": conv}}
+
+
+def saved_state(cache: dict, slot) -> jax.Array:
+    """A copy of ``snap[:, slot]``: the state at the slot's ``snap_at``."""
+    snap = cache["state"]["snap"]
+    return jax.lax.dynamic_slice(snap, (0, slot, 0, 0), (snap.shape[0], 1) + snap.shape[2:])[:, 0]
+
+
+def counters(cache: dict) -> jax.Array:
+    """The expert layers' counters as the programs keep them (``moe``)."""
+    return cache["state"]["moe"]
+
+
+def describe_counters(config: Lfm2Config, total) -> tuple[str, dict]:
+    """``Engine.stats()["moe"]`` from the counters summed by the engine
+    (``total`` [2, 1 + COUNTS_HEAD + held], None before the first dispatch):
+    decode steps and prefills apart, expert layers run, (token, choice)
+    pairs routed (padding lanes route nowhere and are not counted), pairs
+    that landed on held experts, held experts read (an expert with a pair
+    in a layer), and the pairs each held expert took."""
+    held = len(config.held)
+    if total is None:
+        total = [[0] * (1 + COUNTS_HEAD + held)] * 2
+
+    def row(r):
+        return {"expert_layers": int(r[0]), "pairs_routed": int(r[1]), "pairs_held": int(r[2]),
+                "experts_read": int(r[3]), "tokens_per_held_expert": [int(n) for n in r[4:]]}
+
+    return "moe", {"experts": config.n_experts, "held": held, "experts_per_token": config.experts_per_token,
+                   "decode": row(total[0]), "prefill": row(total[1])}

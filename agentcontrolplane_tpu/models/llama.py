@@ -78,18 +78,11 @@ class LlamaConfig:
     # rarely need it; a windowed KV path is future work).
     sliding_window: int = 0
     # Mixture-of-Experts (Mixtral architecture): n_experts > 0 replaces the
-    # dense FFN with top-k routed SwiGLU experts (ops/moe.py). The expert
-    # axis shards over the mesh's 'ep' axis (expert parallelism).
+    # dense FFN with top-k routed SwiGLU experts (ops/moe.py routed_experts:
+    # no capacity, no dropped token). The expert axis shards over the mesh's
+    # 'ep' axis (expert parallelism).
     n_experts: int = 0
     experts_per_token: int = 2
-    # GShard capacity factor: each expert accepts at most
-    # ceil(factor * tokens * k / E) tokens per dispatch; overflow falls back
-    # to the residual stream. 2.0 keeps drops negligible at serving batch
-    # sizes; tests use no-drop capacities.
-    expert_capacity_factor: float = 2.0
-    # dispatch/combine group size: tokens are routed in fixed-size groups so
-    # the one-hot dispatch tensors stay O(group) per token instead of O(N)
-    moe_group_size: int = 512
     dtype: Any = jnp.bfloat16
 
     @property
@@ -275,7 +268,6 @@ PRESETS: dict[str, LlamaConfig] = {
         rope_theta=10000.0,
         n_experts=4,
         experts_per_token=2,
-        expert_capacity_factor=8.0,  # no drops: results batch-independent
         dtype=jnp.float32,
     ),
     # tiny config for CPU tests (matches an HF config in tests)
@@ -432,20 +424,13 @@ def _attn_mlp(
     x = x + attn_out
     h = rms_norm(x, norm_w(layer["ln2"]), c.norm_eps)
     if c.n_experts > 0:
-        from ..ops.moe import expert_capacity, moe_ffn
+        from ..ops.moe import routed_experts
 
-        cap = expert_capacity(
-            min(B * T, c.moe_group_size),
-            c.n_experts, c.experts_per_token, c.expert_capacity_factor,
-        )
-        y = moe_ffn(
-            h.reshape(B * T, D),
-            layer["router"],
-            layer["w1"], layer["w3"], layer["w2"],
-            experts_per_token=c.experts_per_token,
-            capacity=cap,
-            act=act,
-            group_size=c.moe_group_size,
+        # kernel=False: jax.lax.ragged_dot, which GSPMD partitions over the
+        # mesh's 'ep' and 'tp' axes (an opaque kernel it would replicate)
+        y, _counts = routed_experts(
+            h.reshape(B * T, D), layer["router"], layer["w1"], layer["w3"], layer["w2"],
+            c.experts_per_token, score="softmax", act=act, kernel=False,
         )
         x = x + y.reshape(B, T, D)
     else:

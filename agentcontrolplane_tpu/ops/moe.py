@@ -1,30 +1,33 @@
-"""Mixture-of-Experts FFN — GShard-style dispatch/combine, expert-parallel.
+"""Mixture-of-Experts FFN: one expert layer, sorted and grouped.
 
-No reference analogue (the reference runs no models); this is the MoE leg
-of the ``provider: tpu`` data plane, Mixtral-architecture (per-layer top-k
-routed SwiGLU experts replacing the dense FFN).
+The MoE leg of the ``provider: tpu`` data plane: per-layer top-k routed
+SwiGLU experts in the dense FFN's place (Mixtral: softmax over the chosen;
+LFM2-MoE: sigmoid scores, a selection bias, ``held`` experts of the
+router's width).
 
-TPU-first formulation: routing is expressed as two einsums against one-hot
-dispatch/combine tensors (the Switch/GShard pattern) rather than
-gather/scatter —
+``routed_experts`` routes over all ``E`` experts the router knows, is told
+which of them it HOLDS (``held``, global ids in the order of its weights'
+leading axis), and returns the weighted sum over each token's chosen
+experts that are held. What absent experts would add is left out: a chip
+that holds an eighth of a layer's experts computes its part of the sum and
+nothing stands in for the rest. ``held=None`` holds all (Mixtral, the uncut
+reference). There is no capacity and no token is dropped.
 
-    dispatch [N, E, C] one-hot   x  tokens [N, D]   -> expert batches [E, C, D]
-    expert FFN over the leading E axis (one big batched matmul per proj)
-    combine  [N, E, C] weighted  x  outputs [E, C, D] -> tokens [N, D]
+Compute: the (token, choice) pairs are sorted by held expert, each expert's
+group padded to whole row tiles, and one grouped matmul per projection runs
+over the rows that landed here (``ops/pallas/moe_gmm.py`` on the chip for
+one device's experts; ``jax.lax.ragged_dot`` elsewhere, and wherever GSPMD
+has to partition the layer: an 'ep' axis over the expert axis, 'tp' over
+each expert's hidden width). Static shapes: the row bound is tokens x k
+plus a tile of padding per held expert. An expert no token chose has no
+tile and costs no weight read.
 
-Everything is static-shaped (capacity C bounds each expert's batch), MXU
-batched, and shards naturally: the expert axis E carries the mesh's 'ep'
-axis (each rank holds E/ep experts and computes their batches), the FFN
-hidden dim still carries 'tp' within each expert, and the combine einsum's
-contraction over E becomes a psum under GSPMD — no hand-written
-collectives, same design as the rest of the stack.
-
-Capacity semantics (standard GShard): each expert accepts at most
-``C = ceil(capacity_factor * N * k / E)`` tokens; a token that overflows
-every chosen expert's capacity contributes nothing from those experts (its
-combine weights are zero there) and the residual connection carries it —
-the usual "token dropping" behavior. Tests use a capacity factor high
-enough that nothing drops, making results batch-composition-independent.
+The one-hot dispatch/combine formulation with its capacity and dropped
+tokens (GShard's; ``moe_ffn``, ``expert_capacity``) went with PR 31: at
+decode it read every expert's weights whether or not a token chose it, and
+nothing needed it (tests/engine/test_moe.py: the 'ep' x 'tp' forward, the
+dp x ep x tp train step, the engine on an 'ep' mesh, int8 expert stacks and
+the HF Mixtral logits all hold on the grouped layer).
 """
 
 from __future__ import annotations
@@ -37,7 +40,7 @@ def _maybe_dequant(w, dtype):
     from .quant import QuantizedTensor, dequantize
 
     if isinstance(w, QuantizedTensor):
-        return dequantize(w, dtype)  # XLA fuses into the einsum operand load
+        return dequantize(w, dtype)  # XLA fuses into the matmul's operand load
     return w
 
 
@@ -53,107 +56,6 @@ def route_topk(
     return top_idx, weights
 
 
-def moe_ffn(
-    x: jax.Array,  # [N, D] tokens (flattened batch)
-    router_w: jax.Array,  # [D, E]
-    w1: jax.Array,  # [E, D, F] gate_proj per expert
-    w3: jax.Array,  # [E, D, F] up_proj
-    w2: jax.Array,  # [E, F, D] down_proj
-    experts_per_token: int,
-    capacity: int,
-    act=jax.nn.silu,
-    group_size: int = 512,
-) -> jax.Array:
-    """Routed FFN over flattened tokens; returns [N, D] in x.dtype.
-
-    Tokens are processed in fixed-size GROUPS (GShard's grouping): the
-    dispatch/combine tensors are [G, E, C] per group with C derived from G,
-    so their size — and the dispatch einsum FLOPs — stay CONSTANT per token
-    as N grows. Without grouping both are O(N^2·k/E): a 4k-token Mixtral
-    prefill would spend orders of magnitude more on dispatch than on the
-    experts themselves. ``capacity`` is the PER-GROUP capacity (compute it
-    from group_size, e.g. ``expert_capacity(min(N, group_size), ...)``)."""
-    N, D = x.shape
-    if N > group_size:
-        G = group_size
-        n_groups = -(-N // G)
-        pad = n_groups * G - N
-        xp = jnp.pad(x, ((0, pad), (0, 0)))
-        # pad rows are masked out of dispatch/combine entirely (they must
-        # not consume any expert's capacity in the last group)
-        valid = (jnp.arange(n_groups * G) < N).reshape(n_groups, G)
-        grouped = jax.vmap(
-            lambda g, v: _moe_ffn_group(
-                g, router_w, w1, w3, w2, experts_per_token, capacity, act, v
-            )
-        )(xp.reshape(n_groups, G, D), valid)
-        return grouped.reshape(n_groups * G, D)[:N]
-    return _moe_ffn_group(
-        x, router_w, w1, w3, w2, experts_per_token, capacity, act, None
-    )
-
-
-def _moe_ffn_group(
-    x: jax.Array,  # [N, D] one group's tokens
-    router_w: jax.Array,
-    w1: jax.Array,
-    w3: jax.Array,
-    w2: jax.Array,
-    experts_per_token: int,
-    capacity: int,
-    act,
-    valid: jax.Array | None = None,  # [N] bool; False rows take no capacity
-) -> jax.Array:
-    N, D = x.shape
-    E = router_w.shape[-1]
-    k = experts_per_token
-    C = capacity
-
-    logits = (x.astype(jnp.float32) @ _maybe_dequant(router_w, jnp.float32))
-    top_idx, top_w = route_topk(logits, k)  # [N, k], [N, k] f32
-
-    # position of each (token, choice) within its expert's capacity batch:
-    # flatten choices in (choice-major, token) order so lower-k choices win
-    # slots first, then cumsum one-hots per expert. [k, N] -> [k*N, E]
-    choice_onehot = jax.nn.one_hot(top_idx.T.reshape(-1), E, dtype=jnp.int32)
-    if valid is not None:
-        choice_onehot = choice_onehot * jnp.tile(valid, k).astype(jnp.int32)[:, None]
-    pos_in_expert = jnp.cumsum(choice_onehot, axis=0) * choice_onehot - 1  # [k*N, E]
-    pos = jnp.max(pos_in_expert, axis=-1)  # [k*N] (-1 for masked-out rows)
-    fits = (pos < C) & (pos >= 0)
-
-    # dispatch/combine tensors [N, E, C]; overflowed choices vanish (zero
-    # rows) and the residual connection carries the token
-    kN_expert = top_idx.T.reshape(-1)  # [k*N]
-    token_of = jnp.tile(jnp.arange(N), k)  # [k*N]
-    weight_of = top_w.T.reshape(-1)  # [k*N] f32
-
-    dispatch = jnp.zeros((N, E, C), dtype=x.dtype)
-    clamped_pos = jnp.clip(pos, 0, C - 1)
-    dispatch = dispatch.at[token_of, kN_expert, clamped_pos].add(
-        fits.astype(x.dtype)
-    )
-    combine = jnp.zeros((N, E, C), dtype=jnp.float32)
-    combine = combine.at[token_of, kN_expert, clamped_pos].add(
-        jnp.where(fits, weight_of, 0.0)
-    )
-
-    # expert batches -> batched SwiGLU over the (ep-shardable) E axis
-    xe = jnp.einsum("nec,nd->ecd", dispatch, x)  # [E, C, D]
-    w1d = _maybe_dequant(w1, x.dtype)
-    w3d = _maybe_dequant(w3, x.dtype)
-    w2d = _maybe_dequant(w2, x.dtype)
-    h = act(jnp.einsum("ecd,edf->ecf", xe, w1d)) * jnp.einsum(
-        "ecd,edf->ecf", xe, w3d
-    )
-    ye = jnp.einsum("ecf,efd->ecd", h, w2d)  # [E, C, D]
-
-    # combine: contraction over (E, C) — under an 'ep' sharding this is the
-    # cross-expert psum GSPMD inserts
-    y = jnp.einsum("nec,ecd->nd", combine.astype(ye.dtype), ye)
-    return y.astype(x.dtype)
-
-
 def moe_ffn_reference(
     x: jax.Array,  # [N, D]
     router_w: jax.Array,
@@ -163,8 +65,8 @@ def moe_ffn_reference(
     experts_per_token: int,
     act=jax.nn.silu,
 ) -> jax.Array:
-    """Exact per-token reference (no capacity, no dispatch tensors) — the
-    semantics ``moe_ffn`` must match whenever capacity doesn't bind."""
+    """Exact per-token reference (a Python loop over each token's choices):
+    the semantics ``routed_experts`` must match with Mixtral's flags."""
     N, D = x.shape
     logits = x.astype(jnp.float32) @ _maybe_dequant(router_w, jnp.float32)
     top_idx, top_w = route_topk(logits, experts_per_token)
@@ -184,10 +86,142 @@ def moe_ffn_reference(
     return y.astype(x.dtype)
 
 
-def expert_capacity(
-    n_tokens: int, n_experts: int, experts_per_token: int, factor: float
-) -> int:
-    """GShard capacity rule, floored at 1 and at k (a single token must
-    always fit all of its own choices when N is tiny)."""
-    c = int(-(-factor * n_tokens * experts_per_token // n_experts))
-    return max(1, experts_per_token, c)
+COUNTS_HEAD = 3  # counts vector: pairs, landed, experts_read, then a token count per held expert
+
+
+def route_scores(
+    logits: jax.Array,  # [N, E] f32
+    k: int,
+    score: str = "softmax",  # "softmax" (Mixtral) | "sigmoid"
+    bias: jax.Array | None = None,  # [E] f32: enters the CHOICE only
+    renormalize: bool = True,
+    scale: float = 1.0,
+    chosen: jax.Array | None = None,  # [N, k] int32: the choice GIVEN, not made
+) -> tuple[jax.Array, jax.Array]:
+    """Top-k choice per token -> (indices [N, k], weights [N, k] f32).
+
+    The score is a softmax or a sigmoid over all experts; the choice is the
+    top k of the score plus `bias`; the weights are the scores at the
+    chosen, divided by their sum (`renormalize`; the sigmoid's sum gets the
+    1e-6 its source adds) and times `scale`. Softmax renormalized over the
+    chosen is Mixtral's `route_topk` exactly. With `chosen` no top k is
+    taken: a check that teacher-forces the routing as it forces tokens hands
+    the reference's choice in; the weights stay this router's own scores."""
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router score {score!r} (softmax|sigmoid)")
+    if chosen is None:
+        _, idx = jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)
+    else:
+        idx = chosen.astype(jnp.int32)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + (1e-6 if score == "sigmoid" else 0.0))
+    return idx, w * scale
+
+
+def row_tile(pairs: int) -> int:
+    """Rows of a tile of the grouped matmul: a bf16 sublane tile for decode
+    batches, an MXU-height tile once a prefill brings rows by the thousand."""
+    return 128 if pairs >= 2048 else 16
+
+
+def routed_experts(
+    x: jax.Array,  # [N, D]
+    router_w: jax.Array,  # [D, E]
+    w1: jax.Array,  # [E_held, D, F] gate
+    w3: jax.Array,  # [E_held, D, F] up
+    w2: jax.Array,  # [E_held, F, D] down
+    experts_per_token: int,
+    held: tuple[int, ...] | None = None,
+    score: str = "softmax",
+    bias: jax.Array | None = None,
+    renormalize: bool = True,
+    scale: float = 1.0,
+    valid: jax.Array | None = None,  # [N] bool: False rows route nowhere
+    act=jax.nn.silu,
+    kernel: bool | None = None,  # None: the Pallas grouped matmul on a TPU
+    interpret: bool = False,
+    expert_base: jax.Array | int = 0,
+    chosen: jax.Array | None = None,  # [N, k]: route_scores' `chosen`
+) -> tuple[jax.Array, jax.Array]:
+    """-> (y [N, D] in x.dtype, counts [COUNTS_HEAD + E_held] uint32).
+
+    ``w1``/``w3``/``w2`` may hold several layers' experts on one leading
+    axis (``[layers * E_held, ...]``); ``expert_base`` (traced or not) is
+    then the row of this layer's first expert. The grouped matmul indexes
+    the stack itself: a slice of it handed to the opaque kernel would be a
+    copy of the layer's experts every call."""
+    import numpy as np
+
+    N, D = x.shape
+    E, k = router_w.shape[-1], experts_per_token
+    held = tuple(range(E)) if held is None else tuple(int(e) for e in held)
+    Eh = len(held)
+    assert w1.shape[0] % Eh == 0, (w1.shape, Eh)
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    pairs = N * k
+    tm = row_tile(pairs)
+    M = -(-pairs // tm) * tm + Eh * tm
+
+    with jax.named_scope("moe_route"):
+        logits = x.astype(jnp.float32) @ _maybe_dequant(router_w, jnp.float32).astype(jnp.float32)
+        idx, wts = route_scores(logits, k, score, bias, renormalize, scale, chosen)
+        local = np.full((E,), Eh, dtype=np.int32)
+        local[list(held)] = np.arange(Eh, dtype=np.int32)
+        key = jnp.asarray(local)[idx.reshape(-1)]  # [N*k]: held expert, or Eh = not here
+        if valid is not None:
+            key = jnp.where(jnp.repeat(valid, k), key, Eh)
+
+    with jax.named_scope("moe_sort"):
+        n_valid = pairs if valid is None else jnp.sum(valid.astype(jnp.uint32)) * k
+        counts = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
+        padded = (counts + tm - 1) // tm * tm
+        ends = jnp.cumsum(padded)
+        starts = jnp.concatenate([ends - padded, jnp.zeros((1,), jnp.int32)])
+        firsts = jnp.concatenate([jnp.cumsum(counts) - counts, jnp.zeros((1,), jnp.int32)])
+        order = jnp.argsort(key, stable=True)  # pairs sorted by held expert, absent last
+        key_sorted = key[order]
+        row_sorted = jnp.where(
+            key_sorted < Eh, starts[key_sorted] + jnp.arange(pairs) - firsts[key_sorted], M)
+        dest = jnp.zeros((pairs,), jnp.int32).at[order].set(row_sorted)  # a pair's row, M = none
+        row_token = jnp.zeros((M,), jnp.int32).at[row_sorted].set(order // k, mode="drop")
+        n_live = ends[-1:] // tm
+        tiles = jnp.arange(M // tm, dtype=jnp.int32)
+        tile_expert = jnp.searchsorted(ends, jnp.minimum(tiles, jnp.maximum(n_live - 1, 0)) * tm,
+                                       side="right").astype(jnp.int32)
+        tile_expert = jnp.minimum(tile_expert, Eh - 1) + jnp.asarray(expert_base, jnp.int32)
+        xs = x[row_token]
+
+    with jax.named_scope("moe_gmm"):
+        w1d, w3d, w2d = (_maybe_dequant(w, x.dtype) for w in (w1, w3, w2))
+        if kernel or interpret:
+            from .pallas.moe_gmm import gmm, gmm_swiglu
+
+            h = gmm_swiglu(xs, w1d, w3d, tile_expert, n_live, tm, act=act, interpret=interpret)
+            ys = gmm(h, w2d, tile_expert, n_live, tm, interpret=interpret)
+        else:
+            if w1d.shape[0] != Eh:
+                w1d, w3d, w2d = (jax.lax.dynamic_slice_in_dim(w, expert_base, Eh) for w in (w1d, w3d, w2d))
+            prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+            dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+                a, w, padded, precision=prec, preferred_element_type=jnp.float32)
+            h = (act(dot(xs, w1d)) * dot(xs, w3d)).astype(x.dtype)
+            ys = dot(h, w2d).astype(x.dtype)
+            ys = jnp.where((jnp.arange(M) < ends[-1])[:, None], ys, 0)
+
+    with jax.named_scope("moe_combine"):
+        ys = jnp.concatenate([ys, jnp.zeros((1, D), ys.dtype)])
+        dest = dest.reshape(N, k)
+        w_here = jnp.where(dest < M, wts, 0.0)
+        y = jnp.sum(ys[dest].astype(jnp.float32) * w_here[..., None], axis=1)
+        stats = jnp.concatenate([
+            jnp.stack([jnp.asarray(n_valid, jnp.uint32), jnp.sum(counts).astype(jnp.uint32),
+                       jnp.sum(counts > 0).astype(jnp.uint32)]),
+            counts.astype(jnp.uint32),
+        ])
+    return y.astype(x.dtype), stats

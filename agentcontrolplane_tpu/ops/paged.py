@@ -324,6 +324,10 @@ class HostKVEntry:
     v: np.ndarray
     k_scale: Optional[np.ndarray] = None  # [L, cut, H_kv] f32
     v_scale: Optional[np.ndarray] = None
+    # families with per-slot state beside the pages: the state after
+    # exactly ``cut`` tokens ([n_conv, taps-1, D]); a restore resumes from
+    # it, and an entry without one is a miss for such a family
+    state: Optional[np.ndarray] = None
 
     @property
     def cut(self) -> int:
@@ -334,6 +338,8 @@ class HostKVEntry:
         n = int(self.k.nbytes) + int(self.v.nbytes)
         if self.k_scale is not None:
             n += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
+        if self.state is not None:
+            n += int(self.state.nbytes)
         return n
 
 

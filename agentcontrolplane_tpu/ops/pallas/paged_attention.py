@@ -24,9 +24,13 @@ by bytes, so fewer, fuller turns are the lever. Per-program working set is
 NBUF x 2 (K+V) x [G * P, H_kv * d] — 1 MB in VMEM for Qwen2.5-7B geometry
 (page 16, 4 KV heads, d 128, bf16).
 
-Geometry note: the kernel targets head_dim % 128 == 0 (the TPU lane width;
-128 for llama/qwen/mistral, 256 for gemma); the engine falls back to the
-XLA reference otherwise. The body is a static loop over the KV heads: each
+Geometry note: the walk takes head widths 64, 128 and 256
+(``heads_per_window``): a multiple of the 128-lane width whole (128 for
+llama/qwen/mistral, 256 for gemma), and 64 two KV heads to a lane window
+(LFM2; bf16 or f32 pages, an even number of KV heads a chip: the wrapper
+lays each pair's queries out on their own lanes of one query group, the
+pool keeps its bytes and its layout). The engine falls back to the XLA
+reference otherwise. The body is a static loop over the KV heads: each
 takes its lane-aligned ``[G * P, d]`` column window of the turn's buffer and
 two plain 2-D products with its ``[n_rep, d]`` query group. Every shape in the
 body is 2-D because that is what Mosaic lays out — the earlier grouped
@@ -95,6 +99,20 @@ def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = Fal
     return G
 
 
+def heads_per_window(d: int, H_kv: int, quantized: bool = False) -> int:
+    """KV heads that share one 128-lane window of the page buffer: 1 at the
+    widths the body slices whole (d % 128 == 0), 128 // d at a narrower
+    width that divides a lane tile and pairs its heads up evenly (64: two a
+    window), 0 where the walk does not go: another width, an odd head
+    count, or int8 pages at a narrow width (a window's rows would need each
+    head's own scale row)."""
+    if d % LANES == 0:
+        return 1
+    if LANES % d == 0 and d >= 64 and H_kv % (LANES // d) == 0 and not quantized:
+        return LANES // d
+    return 0
+
+
 def _kernel(
     # scalar prefetch
     block_tables_ref,  # [S, max_pages] int32 (SMEM)
@@ -118,6 +136,7 @@ def _kernel(
     *rest,
     page_size: int,  # GLOBAL page size (pages hold this many tokens)
     quantized: bool = False,
+    head_dim: int | None = None,  # the model's, where heads share a lane window
 ):
     # int8 walk (quantized=True): pages hold int8 values plus f32 scale
     # twins (one scale per row per KV head). The fetch loop DMAs each
@@ -146,7 +165,7 @@ def _kernel(
     G = T // P
     n_turns = jax.lax.div(n_pages + G - 1, G)
 
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / ((head_dim or d) ** 0.5)
     # q . k in one bf16 MXU pass where both sides are bf16 (int8 widens to
     # bf16 exactly): bf16 x bf16 products are exact in the f32 accumulator,
     # so with 1/sqrt(d) applied to the f32 logits this is the f32 product.
@@ -290,6 +309,8 @@ def _paged_state(
     global_page_size: int | None = None,  # tokens per page (sp>1: > P_local)
     k_scales: jax.Array | None = None,  # [num_pages, P_local, H_kv] f32
     v_scales: jax.Array | None = None,  # (int8 pages: per-row-per-head)
+    head_dim: int | None = None,  # softmax scale's width where it is not d
+    kv_heads: int | None = None,  # of pages given merged, [num_pages, P_local, H_kv * d]
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the kernel -> unnormalized (acc [S,H,d] f32, m [S,H], l [S,H]).
 
@@ -298,7 +319,34 @@ def _paged_state(
     the page fetch; applying them in VMEM keeps int8's HBM-bandwidth win.
     """
     S, H, d = q.shape
-    num_pages, P, H_kv, _ = k_pages.shape
+    # pages come [num_pages, P, H_kv, d], or already merged as the kernel
+    # reads them (a pool stored in that layout is never relaid)
+    num_pages, P = k_pages.shape[:2]
+    H_kv = k_pages.shape[2] if k_pages.ndim == 4 else kv_heads
+    pack = heads_per_window(d, H_kv, k_scales is not None)
+    if pack > 1:
+        # Narrow heads: `pack` KV heads share one lane tile of the page
+        # buffer, which stays [G * P, H_kv * d] untouched. The kernel walks
+        # H_kv / pack windows of 128 lanes; each window's query group holds
+        # its heads' queries on their own lanes and zeros on the others', so
+        # one [pack * n_rep, 128] x [128, G * P] product gives every head's
+        # logits exactly (the zeros add nothing), each row its own softmax.
+        # p . v then fills all 128 lanes of every row; a row's own head's
+        # lanes are picked out here, outside the kernel.
+        r = H // H_kv
+        W = H_kv // pack
+        eye = jnp.eye(pack, dtype=q.dtype)
+        q_w = jnp.einsum("swjrc,jl->swjrlc", q.reshape(S, W, pack, r, d), eye)
+        acc, m, l = _paged_state(
+            q_w.reshape(S, W * pack * r, pack * d),
+            k_pages.reshape(num_pages, P, W, pack * d),
+            v_pages.reshape(num_pages, P, W, pack * d),
+            block_tables, seq_lens, interpret, pos_base, global_page_size,
+            head_dim=d,
+        )
+        acc = jnp.einsum("swjrlc,jl->swjrc", acc.reshape(S, W, pack, r, pack, d),
+                         jnp.eye(pack, dtype=acc.dtype))
+        return acc.reshape(S, H, d), m, l
     n_rep = H // H_kv
     if pos_base is None:
         pos_base = jnp.zeros((1,), dtype=jnp.int32)
@@ -308,6 +356,7 @@ def _paged_state(
         _kernel,
         page_size=global_page_size or P,
         quantized=quantized,
+        head_dim=head_dim,
     )
 
     def per_slot(*tail):
@@ -444,7 +493,7 @@ def paged_decode_attention_cache_plus_new(
     not yet written to pages), so no scales apply to the self term."""
     acc, m, l = _paged_state(
         q, k_pages, v_pages, block_tables, seq_lens, interpret,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, kv_heads=k_new.shape[1],
     )
     return _fold_self_term(q, k_new, v_new, acc, m, l)
 

@@ -124,6 +124,9 @@ _WALKS = [
     ("walk-llama3-8b-bf16", 32, 8, 128, jnp.bfloat16, False, 1),
     ("walk-gemma-2b-mqa", 8, 1, 256, jnp.bfloat16, False, 1),
     ("walk-mha-f32", 8, 8, 128, jnp.float32, False, 1),
+    # head width 64, two KV heads to a lane window (LFM2-24B-A2B: 32/8 heads)
+    ("walk-lfm2-24b-bf16-d64", 32, 8, 64, jnp.bfloat16, False, 1),
+    ("walk-gqa-f32-d64", 8, 2, 64, jnp.float32, False, 1),
     # one KV head per chip: the divisibility edge of the shard_map wrapper
     ("walk-qwen2.5-7b-bf16-tp4", 28, 4, 128, jnp.bfloat16, False, 4),
     ("walk-qwen2.5-7b-int8-tp4", 28, 4, 128, jnp.bfloat16, True, 4),
@@ -149,3 +152,58 @@ def test_compiles_for_described_v5e(v5e, case):
     _, H, H_kv, d, dtype, int8, tp = case
     text = _compile_walk(v5e, H, H_kv, d, dtype, int8, tp).as_text()
     assert "tpu_custom_call" in text, "the Pallas page walk is not in the program"
+
+
+# -- the grouped expert matmul and the model that runs it ---------------------
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode-32-lanes", "prefill-8x512"])
+def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, tokens):
+    """`ops.moe.routed_experts` at LFM2-24B-A2B's widths, 8 of 64 experts
+    held out of a stack of 38 layers' experts: route, sort and the
+    `moe_gmm` kernels, at a decode step's rows and at a prefill's."""
+    from agentcontrolplane_tpu.ops.moe import routed_experts
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    bf16, D, F = jnp.bfloat16, 2048, 1536
+
+    def layer(x, router, bias, w1, w3, w2, index):
+        return routed_experts(x, router, w1, w3, w2, 4, held=tuple(range(8)), score="sigmoid", bias=bias,
+                              kernel=True, expert_base=index * 8)
+
+    args = [sds((tokens, D), bf16), sds((D, 64), bf16), sds((64,), jnp.float32), sds((38 * 8, D, F), bf16),
+            sds((38 * 8, D, F), bf16), sds((38 * 8, F, D), bf16), sds((), jnp.int32)]
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, "gate-and-up and down: two grouped matmuls"
+    assert "bf16[8,2048,1536]" not in text, "a layer's experts were sliced out of the stack (a copy a call)"
+
+
+def test_lfm2_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, monkeypatch):
+    """`models.lfm2.decode_step_paged` at the published widths and full
+    depth, this chip's eighth of the experts, 32 slots over 2,049 pages: it
+    fits one chip, holds each piece once (three kernels: the walk and two
+    grouped matmuls), and its temporaries are a fraction of the pool: no
+    layer of the pool, and no layer's experts, is copied out for a kernel."""
+    import functools
+
+    from agentcontrolplane_tpu.models import lfm2
+
+    monkeypatch.setattr(lfm2, "routed_experts", functools.partial(lfm2.routed_experts, kernel=True))
+    c = lfm2.PRESETS["lfm2-24b-a2b-ep8"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: lfm2.init_params(c, jax.random.key(0))))
+    cache = place(jax.eval_shape(lambda: lfm2.init_paged_cache(c, 2049, PAGE, max_slots=32)))
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda p, ca, tok, n, tables, active: lfm2.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True),
+        donate_argnums=(1,),
+    ).lower(params, cache, vec(32), vec(32), vec(32, 64), vec(32, dt=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    pool = 2 * 10 * 2049 * PAGE * 8 * 64 * 2
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert mem.temp_size_in_bytes < pool // 4, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB beside a {pool / 1e6:.0f} MB pool"
+    resident = mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert 0.25 * 16e9 < resident < 16e9, f"{resident / 1e9:.2f} GB"

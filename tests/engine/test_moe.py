@@ -1,7 +1,7 @@
 """Mixture-of-Experts FFN (ops/moe.py) + the Mixtral-architecture family.
 
-The GShard dispatch/combine formulation must match the exact per-token
-reference whenever capacity doesn't bind; expert parallelism ('ep' mesh
+The sorted, grouped expert layer must match the exact per-token reference,
+drop no token however uneven the routing, and expert parallelism ('ep' mesh
 axis) must be numerically transparent and must not all-gather the expert
 weights.
 """
@@ -17,12 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from agentcontrolplane_tpu.models.llama import PRESETS, forward, init_params
-from agentcontrolplane_tpu.ops.moe import (
-    expert_capacity,
-    moe_ffn,
-    moe_ffn_reference,
-    route_topk,
-)
+from agentcontrolplane_tpu.ops.moe import moe_ffn_reference, route_topk, routed_experts
 from agentcontrolplane_tpu.parallel.mesh import make_mesh, param_shardings
 
 MOE = PRESETS["moe-tiny"]
@@ -46,36 +41,35 @@ def test_route_topk_renormalizes_over_selection():
     np.testing.assert_allclose(np.sort(np.asarray(w[0]))[::-1], expect, rtol=1e-6)
 
 
+def _grouped(x, router, w1, w3, w2, **kw):
+    """Mixtral's flags through the grouped layer (softmax over the chosen,
+    no bias, all experts held), as models/llama.py calls it."""
+    return routed_experts(x, router, w1, w3, w2, 2, score="softmax", kernel=False, **kw)[0]
+
+
 def test_moe_ffn_matches_per_token_reference():
     router, w1, w3, w2 = _weights()
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(13, 64)), dtype=jnp.float32)
-    cap = expert_capacity(13, 4, 2, 8.0)  # generous: nothing drops
-    out = moe_ffn(x, router, w1, w3, w2, experts_per_token=2, capacity=cap)
+    out = _grouped(x, router, w1, w3, w2)
     ref = moe_ffn_reference(x, router, w1, w3, w2, experts_per_token=2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-def test_moe_capacity_overflow_drops_to_residual():
-    """With capacity 1 per expert, overflowed (token, expert) choices must
-    contribute ZERO (the residual carries the token) — never alias another
-    expert's slot."""
+def test_moe_drops_no_token_however_uneven_the_routing():
+    """Every token sent to the same two experts (a router that sees one
+    direction only): the capacity path dropped what overflowed an expert's
+    share to the residual; the grouped layer has no share to overflow, and
+    every row still matches the per-token reference."""
     router, w1, w3, w2 = _weights(seed=2)
     rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.normal(size=(9, 64)), dtype=jnp.float32)
-    out = moe_ffn(x, router, w1, w3, w2, experts_per_token=2, capacity=2)
-    # bounded: every output row is a convex-ish combination of expert FFNs
-    # of x rows; a scatter aliasing bug produces garbage magnitudes
-    assert np.isfinite(np.asarray(out)).all()
-    full = moe_ffn(
-        x, router, w1, w3, w2, experts_per_token=2,
-        capacity=expert_capacity(9, 4, 2, 8.0),
-    )
-    # capacity-2 keeps the first-fitting choices; rows whose choices ALL fit
-    # match the uncapped result exactly — verify at least one row does and
-    # none exceed the uncapped magnitude wildly
-    matches = np.isclose(np.asarray(out), np.asarray(full), rtol=1e-5, atol=1e-5)
-    assert matches.all(axis=1).any()
+    x = jnp.abs(jnp.asarray(rng.normal(size=(9, 64)), dtype=jnp.float32))
+    router = jnp.zeros_like(router).at[:, 1].set(1.0).at[:, 3].set(0.5)
+    out, counts = routed_experts(x, router, w1, w3, w2, 2, score="softmax", kernel=False)
+    ref = moe_ffn_reference(x, router, w1, w3, w2, experts_per_token=2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+    assert np.asarray(counts[:3]).tolist() == [18, 18, 2]  # pairs, landed, experts read
+    assert np.asarray(counts[3:]).tolist() == [0, 9, 0, 9]
 
 
 def test_forward_moe_tiny_finite_and_deterministic():
@@ -91,10 +85,9 @@ def test_forward_moe_tiny_finite_and_deterministic():
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits2))
 
 
-def test_forward_moe_batch_independent_with_slack_capacity():
-    """moe-tiny's capacity factor leaves no drops, so a row's logits must
-    not depend on what else is in the batch (serving correctness: solo ==
-    batched)."""
+def test_forward_moe_batch_independent():
+    """No capacity, no drops: a row's logits must not depend on what else
+    is in the batch (serving correctness: solo == batched)."""
     params = init_params(MOE, jax.random.key(0))
     rng = np.random.default_rng(1)
     a = jnp.asarray(rng.integers(1, MOE.vocab_size, size=(1, 12)), dtype=jnp.int32)
@@ -139,7 +132,8 @@ def test_expert_parallel_forward_matches_replicated_no_weight_allgather():
     # expert weights instead of dispatching tokens to them.
     expert_elems = MOE.n_experts * MOE.dim * MOE.ffn_dim
     for line in compiled.as_text().splitlines():
-        if "all-gather" not in line:
+        # the op itself, not an op that merely reads an all-gather's result
+        if not re.search(r"= \S+ all-gather(-start)?\(", line):
             continue
         dims = re.search(r"\[([0-9,]+)\]", line)
         assert dims is not None, line
@@ -201,7 +195,6 @@ def test_mixtral_logits_match_hf():
         rope_theta=10000.0,
         n_experts=4,
         experts_per_token=2,
-        expert_capacity_factor=8.0,  # no drops: HF routes without capacity
         dtype=jnp.float32,
     )
     hf_config = MixtralConfig(
@@ -308,36 +301,29 @@ def test_moe_serves_on_expert_parallel_mesh():
 
 def test_moe_int8_quantization():
     """Weight-only int8 applies per expert stack ([L, E, D, F] tensors;
-    per-channel scales over the contraction dim) and moe_ffn dequantizes
-    transparently — outputs close to bf16."""
+    per-channel scales over the contraction dim) and the expert layer
+    dequantizes transparently — outputs close to bf16."""
     from agentcontrolplane_tpu.ops.quant import quantize
 
     router, w1, w3, w2 = _weights(seed=5)
     rng = np.random.default_rng(6)
     x = jnp.asarray(rng.normal(size=(11, 64)), dtype=jnp.float32)
-    cap = expert_capacity(11, 4, 2, 8.0)
-    ref = moe_ffn(x, router, w1, w3, w2, experts_per_token=2, capacity=cap)
-    out = moe_ffn(
-        x, router, quantize(w1), quantize(w3), quantize(w2),
-        experts_per_token=2, capacity=cap,
-    )
+    ref = _grouped(x, router, w1, w3, w2)
+    out = _grouped(x, router, quantize(w1), quantize(w3), quantize(w2))
     assert quantize(w1).q.shape == (4, 64, 128)
     assert quantize(w1).scale.shape == (4, 1, 128)  # per-channel over D
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=0.1, atol=0.05)
 
 
-def test_moe_grouped_matches_reference_across_group_boundaries():
-    """N > group_size splits tokens into fixed-capacity groups (the thing
-    that keeps dispatch O(group) per token); with slack capacity the result
-    must still match the exact per-token reference — including the padded
-    final group, whose pad rows must consume no expert capacity."""
+@pytest.mark.parametrize("n", [21, 1100])
+def test_moe_grouped_matches_reference_across_tile_boundaries(n):
+    """Groups are padded to whole row tiles (16 rows at decode sizes, 128
+    once a prefill brings 2,048 pairs): with groups that end inside a tile
+    the result must still match the exact per-token reference, and padding
+    rows must add nothing."""
     router, w1, w3, w2 = _weights(seed=7)
     rng = np.random.default_rng(8)
-    x = jnp.asarray(rng.normal(size=(21, 64)), dtype=jnp.float32)
+    x = jnp.asarray(rng.normal(size=(n, 64)), dtype=jnp.float32)
     ref = moe_ffn_reference(x, router, w1, w3, w2, experts_per_token=2)
-    out = moe_ffn(
-        x, router, w1, w3, w2, experts_per_token=2,
-        capacity=expert_capacity(8, 4, 2, 8.0),  # per-group (G=8)
-        group_size=8,  # 21 tokens -> groups of 8, 8, 5(+3 pad)
-    )
+    out = _grouped(x, router, w1, w3, w2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
