@@ -41,6 +41,19 @@ functions:
     Keyword arguments beyond these are the family's own lower-precision
     paths, the controls of `cache_excess`.
 
+The pool. What the harness holds the program's `init_paged_cache` to, and
+all it holds it to: every leaf not under `"state"` is `[layers, pages, page
+rows, ...]`, and axis 3 carries the KV heads: apart (`[L, pages, P, H_kv,
+d]`), merged into the row (`[L, pages, P, H_kv*d]`, as the page walk reads
+it) or as a scale column (`[L, pages, P, H_kv]`). Axis 3 is the one a
+tensor-parallel mesh splits, so a family shards each such leaf
+`PartitionSpec(None, None, None, "tp")`, which is valid at any rank of 4 or
+more, and what is under `"state"` whole. Nothing else is asserted of a
+leaf's rank or trailing shape: the program's own functions
+(`prefill_paged_batch`, `decode_step_paged`) take the pool as
+`init_paged_cache` made it, and a program that changes the layout changes
+those three together and no file here.
+
 Everything else (`check.sample`, `compare`, `engine_path`, `engine_numbers`,
 `decide`, the generators, readers and the run itself) is shared and knows
 no family.

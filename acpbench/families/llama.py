@@ -6,7 +6,8 @@ program's own names (`qkv_bias`). Weights: `llama_weights.py`, int8 with
 `llama_reference.py`; its controls are `lower="int8"` and `"fp8"` (every
 matmul input, K and V rounded), and `"nobias"` (the q, k and v biases left
 out: a broken bias path). The cache's own control: `quantize_kv=True`, the
-program's int8 KV pages.
+program's int8 KV pages. The pool is taken in whatever layout
+`init_paged_cache` gives it (`families/__init__.py`, "The pool").
 """
 
 from __future__ import annotations
@@ -74,12 +75,12 @@ def cached_logits(config: dict, program_config, params, mesh, s: dict, use_palla
     )
 
     rep = NamedSharding(mesh, P())
-    page_sh = NamedSharding(mesh, P(None, None, None, "tp", None))
-    shardings = {"k": page_sh, "v": page_sh}
-    if quantize_kv:
-        shardings["ks"] = shardings["vs"] = NamedSharding(mesh, P(None, None, None, "tp"))
-    pool = jax.jit(lambda: init_paged_cache(program_config, s["pool_pages"], s["P"], quantize_kv=quantize_kv),
-                   out_shardings=shardings)()
+    # the pool as the program stores it (`families/__init__.py`, "The pool"): axis 3 over `tp` at any rank
+    heads_sh = NamedSharding(mesh, P(None, None, None, "tp"))
+    init = lambda: init_paged_cache(program_config, s["pool_pages"], s["P"], quantize_kv=quantize_kv)  # noqa: E731
+    shardings = {name: jax.tree_util.tree_map(lambda _: rep if name == "state" else heads_sh, leaves)
+                 for name, leaves in jax.eval_shape(init).items()}
+    pool = jax.jit(init, out_shardings=shardings)()
     put = lambda a: jax.device_put(jnp.asarray(a), rep)  # noqa: E731
     prefill = jax.jit(lambda p, pages, t, n, ids: prefill_paged_batch(p, pages, t, n, ids, program_config),
                       donate_argnums=(1,))

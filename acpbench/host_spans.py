@@ -39,7 +39,7 @@ PREFIX = "acp."
 CYCLE = "cycle"
 DECODE_MODULE = r"decode_block"  # the device's name for a decode block, split or alone
 DECODE_PROGRAM = re.compile(r"^decode\[|^megastep\[.*[,+]d\d+x\d+")  # the profiler's key for the same
-SHIFTS = range(-3, 4)  # how far the two sequences may be out of step at the slice's edges
+EDGE_RUNS = 2  # whole runs of a slice that may lack their spans: one at either edge
 
 
 def find(run) -> str | None:
@@ -178,23 +178,26 @@ def _windows(spans) -> list[tuple[int, int, int]]:
 
 
 def _pair(windows, runs) -> list[tuple[tuple[int, int, int], tuple[int, int]]]:
-    """(window, run) pairs, k-th with k-th: the two sequences are the same
-    decode blocks seen from the two sides, out of step by at most a few at
-    the slice's edges (a block launched before the trace began has a run
-    and no span). The shift is the one under which fetch end less device
-    end agrees best from block to block (blocks are irregular: a prefill
-    comes between some and not others)."""
-    best = None
-    for shift in SHIFTS:
-        pairs = [(windows[k + shift], runs[k]) for k in range(len(runs)) if 0 <= k + shift < len(windows)]
-        if len(pairs) < 2:
-            continue
-        diffs = [w[2] - r[1] for w, r in pairs]
-        mid = statistics.median(diffs)
-        key = (statistics.fmean(abs(d - mid) for d in diffs), -len(pairs), abs(mid))
-        if best is None or key < best[0]:
-            best = (key, pairs)
-    return best[1] if best else []
+    """(window, run) pairs, by where they lie and not by their order: a
+    run and a window (from its jitted call to the end of the fetch after
+    it) are paired when each is the other's nearest, middle to middle. The
+    planes are a millisecond or two apart and blocks follow one another at
+    a hundred or more, so the nearest is the run's own; a run whose launch
+    is not in the trace (it began before the trace did) finds no window
+    that finds it, and stays unpaired. An order says nothing once a block
+    is missing on either side, and with the four whole blocks of a short
+    slice, two pairs out of step can agree better among themselves than the
+    four true ones (PERF.md, Findings, PR 32)."""
+    if not windows or not runs:
+        return []
+    w_mid = [(w[1] + w[2]) // 2 for w in windows]
+    r_mid = [(r[0] + r[1]) // 2 for r in runs]
+
+    def nearest(t: int, mids: list[int]) -> int:
+        return min(range(len(mids)), key=lambda i: abs(mids[i] - t))
+
+    to_window = [nearest(t, w_mid) for t in r_mid]
+    return [(windows[j], runs[k]) for k, j in enumerate(to_window) if nearest(w_mid[j], r_mid) == k]
 
 
 def _held(pairs, offset_ns: int) -> int:
@@ -209,21 +212,37 @@ def alignment(spans, runs) -> dict:
     planes (device time + d = host time): d is at least every call start
     less device start, and at most every fetch end less device end. If 0
     lies outside the bounds the planes are offset: the reader corrects by
-    the middle of the bounds and says so. `share` is the part of the paired
-    blocks that start and end inside their window (after the correction, if
-    one was made; `share_uncorrected` before); `latency_ms` the median from
-    the jitted call's start to the device's."""
-    pairs = _pair(_windows(spans), runs)
-    out = {"runs": len(runs), "blocks": len(pairs), "offset_ms": 0.0, "corrected": False, "bounds_ms": None,
-           "share": 0.0, "share_uncorrected": 0.0, "latency_ms": None}
+    the middle of the bounds and says so. No correction is made that is not
+    credible: where more than `EDGE_RUNS` of the device's runs found no
+    window of their own, no one offset holds for every pair, or the offset
+    is longer than the shortest paired block (from its launch's start to
+    its fetch's end: the pairing is then as good a block further on),
+    `failed` says which, the `[spans]` line repeats it, and the planes are
+    read as they are. `share` is the part of the paired blocks that start
+    and end inside their window (after the correction, if one was made;
+    `share_uncorrected` before); `latency_ms` the median from the jitted
+    call's start to the device's."""
+    windows = _windows(spans)
+    pairs = _pair(windows, runs)
+    out = {"runs": len(runs), "windows": len(windows), "blocks": len(pairs), "offset_ms": 0.0, "corrected": False,
+           "failed": None, "bounds_ms": None, "share": 0.0, "share_uncorrected": 0.0, "latency_ms": None}
     if not pairs:
+        out["failed"] = f"none of the device's {len(runs)} decode-block runs has a launch-to-fetch window of its own"
         return out
     lo = max(w[1] - r[0] for w, r in pairs)
     hi = min(w[2] - r[1] for w, r in pairs)
-    offset = 0
+    offset = (lo + hi) // 2
+    shortest = min(w[2] - w[0] for w, _ in pairs)
     out.update(bounds_ms=(lo / 1e6, hi / 1e6), share_uncorrected=_held(pairs, 0) / len(pairs))
-    if lo <= hi and not lo <= 0 <= hi:
-        offset = (lo + hi) // 2
+    if len(runs) - len(pairs) > EDGE_RUNS:
+        out["failed"] = f"{len(runs) - len(pairs)} of the device's decode-block runs have no window of their own"
+    elif lo > hi:
+        out["failed"] = "no one offset puts every paired run inside its window"
+    elif abs(offset) > shortest:
+        out["failed"] = f"an offset of {offset / 1e6:+.3f} ms is longer than a block of {shortest / 1e6:.3f} ms"
+    if out["failed"] or lo <= 0 <= hi:
+        offset = 0
+    else:
         out.update(offset_ms=offset / 1e6, corrected=True)
     out["share"] = _held(pairs, offset) / len(pairs)
     out["latency_ms"] = statistics.median((r[0] + offset - w[1]) / 1e6 for w, r in pairs)
@@ -263,11 +282,13 @@ def line(found: dict | None, reduced: dict) -> str:
         return "no acp.* span on the host plane: the program opens none"
     a = found["align"]
     bounds = "none" if a["bounds_ms"] is None else f"{a['bounds_ms'][0]:+.3f}..{a['bounds_ms'][1]:+.3f}"
-    clock = (f"clock: {a['blocks']} of the device's {a['runs']} decode-block runs have their launch and fetch in the "
-             f"trace; {100 * a['share']:.1f}% of them start after their jitted call and end before their fetch does; "
-             f"call to device start p50 {a['latency_ms']} ms; offset of the device planes bounded to {bounds} ms; "
-             + (f"device planes CORRECTED by {a['offset_ms']:+.3f} ms, the middle of the bounds "
-                f"({100 * a['share_uncorrected']:.1f}% held before)" if a["corrected"] else "no correction"))
+    verdict = (f"ALIGNMENT FAILED ({a['failed']}): the planes are read as they are" if a["failed"] else
+               f"device planes CORRECTED by {a['offset_ms']:+.3f} ms, the middle of the bounds "
+               f"({100 * a['share_uncorrected']:.1f}% held before)" if a["corrected"] else "no correction")
+    clock = (f"clock: {a['blocks']} of the device's {a['runs']} whole decode-block runs are paired with a launch-to-"
+             f"fetch window of the host plane's {a['windows']}; {100 * a['share']:.1f}% of them start after their "
+             f"jitted call and end before their fetch does; call to device start p50 {a['latency_ms']} ms; offset of "
+             f"the device planes bounded to {bounds} ms; {verdict}")
     ms = {k: round(v * 1e3, 3) for k, v in sorted(found["by_phase"].items())}
     host = {k: round(v * 1e3, 3) for k, v in sorted(found["host_s"].items())}
     return (f"{found['spans']} spans; idle ms by phase {json.dumps(ms)} of {found['idle_s'] * 1e3:.3f} "

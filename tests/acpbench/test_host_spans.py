@@ -134,15 +134,16 @@ def test_a_block_launched_before_the_trace_began_is_left_out_of_the_check():
     assert a["runs"] == 5 and a["blocks"] == 4 and a["share"] == 1.0 and not a["corrected"]
 
 
-@pytest.mark.parametrize("skew_ms", [-150, -2, 37, 5000])
+@pytest.mark.parametrize("skew_ms", [-4, -2, 3])
 def test_a_skewed_clock_is_reported_and_corrected(skew_ms):
-    """The device planes `skew_ms` ahead of the host plane: the bounds leave 0
-    out, the correction is their middle, and the attribution is what it is
-    with no skew."""
+    """The device planes `skew_ms` ahead of the host plane, by less than
+    half a block (10 ms here; on the chip 1-3 ms of a hundred or more): the
+    bounds leave 0 out, the correction is their middle, and the attribution
+    is what it is with no skew."""
     p = profile(skew=skew_ms * MS)
     reduced = tr.reduce_profile(p)
     raw = hs.alignment(hs.read_profile(p), hs.device_runs(p))
-    assert raw["corrected"] and raw["share_uncorrected"] == 0.0 and raw["share"] == 1.0
+    assert raw["corrected"] and raw["share_uncorrected"] == 0.0 and raw["share"] == 1.0 and raw["failed"] is None
     assert raw["bounds_ms"] == (pytest.approx(-skew_ms - 0.5), pytest.approx(-skew_ms + 0.5))
     assert raw["offset_ms"] == pytest.approx(-skew_ms)
     found = hs.analyse_profile(p, reduced)
@@ -153,6 +154,88 @@ def test_a_skewed_clock_is_reported_and_corrected(skew_ms):
     for name, seconds in straight["by_phase"].items():
         assert found["by_phase"][name] == pytest.approx(seconds, abs=1e-9), name
     assert "no correction" in hs.line(straight, reduced)
+
+
+@pytest.mark.parametrize("skew_ms", [-150, 37, 5000])
+def test_an_offset_of_blocks_is_a_failed_alignment_and_corrects_nothing(skew_ms):
+    """No profiler sets the planes whole blocks apart: such a reading is a
+    pairing gone wrong (PR 31's warm tp=4 runs: 752 ms, two blocks). The
+    device's runs then find no window of their own, or no one offset holds
+    for the pairs they find; the `[spans]` line says that the alignment
+    failed, and the planes are read as they are: every reader still gives
+    its number."""
+    p = profile(skew=skew_ms * MS)
+    reduced = tr.reduce_profile(p)
+    found = hs.analyse_profile(p, reduced)
+    a = found["align"]
+    assert a["failed"] and not a["corrected"] and a["offset_ms"] == 0.0
+    text = hs.line(found, reduced)
+    assert "ALIGNMENT FAILED" in text and "CORRECTED" not in text
+    as_they_are = hs.attribute(reduced["op_intervals"], hs.read_profile(p), 0, reduced["windows"])
+    assert found["by_phase"] == as_they_are["by_phase"] and found["idle_s"] == pytest.approx(50e-3)
+    run = NS(trace=reduced, host_spans=found)
+    assert hs.idle_named_share(run) is not None and hs.idle_ms_per_block(run, "launch") is not None
+
+
+# A warm traced `q32b-tp4-decode` run as the chip recorded it (my chip run, PR 32, call 1, `warm1`; ms from the
+# slice's first whole run, chip 0): launch start, jitted call and the end of the fetch after it; the device's runs.
+# A prefill came between the second block and the third, whose launch prepared for 8.5 ms before its call.
+TP4_WINDOWS = [(3.6, 4.6, 344.2), (344.9, 345.9, 685.7), (747.1, 755.6, 1094.4), (1095.1, 1096.0, 1435.5)]
+TP4_RUNS = [(4.8, 336.8), (345.8, 337.3), (755.7, 336.6), (1096.1, 337.3)]
+
+
+def tp4_profile(windows, device_runs=TP4_RUNS):
+    host = []
+    for c, (launch, call, fetched) in enumerate(windows):
+        host += [ev("acp.cycle", int(launch * MS), int((fetched - launch + 5) * MS), step_num=c + 1),
+                 ev("acp.launch", int(launch * MS), int((call - launch + 0.3) * MS), cycle=c + 1,
+                    program="decode[paged,24x8]", call_us=int(round((call - launch) * 1000))),
+                 ev("acp.fetch", int((call + 0.3) * MS), int((fetched - call - 0.3) * MS), cycle=c + 1),
+                 ev("acp.commit", int(fetched * MS), int(0.4 * MS), cycle=c + 1)]
+    runs = [(int(s * MS), int(d * MS), "jit_decode_block") for s, d in device_runs]
+    return NS(planes=[NS(name="/host:CPU", lines=[NS(name="python3", events=host)]), device(0, runs)])
+
+
+@pytest.mark.parametrize("kept,paired,failed", [((0, 1, 2, 3), 4, False), ((2, 3), 2, False), ((0, 3), 2, False),
+                                                ((1,), 1, True)],
+                         ids=["all-four", "the-last-two", "the-outer-two", "one-of-four"])
+def test_four_blocks_of_a_short_slice_are_paired_by_where_they_lie(kept, paired, failed):
+    """Four device runs and the host's spans for all or some of them. The
+    parent's pairing tried the two sequences a few blocks out of step
+    either way and kept the step under which fetch end less device end
+    agreed best from block to block. With all four spans there it paired
+    the host's last two with the device's first two (they agree to 0.2 ms,
+    the true four to 0.25), read "2 of the device's 4 runs have their launch
+    and fetch in the trace" and "corrected" the planes by +751.6 ms, two
+    blocks: on the chip, and on this copy of that run (shown by hand with
+    the parent's file). With spans for two of the four the same rule chose
+    between two steps of two pairs each, by a tie. A run now goes with the
+    window nearest it that is nearest to it in turn: every span present is
+    paired with its own run, the offset is the planes' own millisecond or
+    two, and with spans for one run of four the alignment fails and
+    corrects nothing."""
+    p = tp4_profile([TP4_WINDOWS[k] for k in kept])
+    reduced = tr.reduce_profile(p)
+    found = hs.analyse_profile(p, reduced)
+    a = found["align"]
+    assert (a["runs"], a["windows"], a["blocks"]) == (4, len(kept), paired) and bool(a["failed"]) is failed
+    assert abs(a["offset_ms"]) < 3 and not (failed and a["corrected"])
+    if not failed:
+        assert a["share"] == 1.0 and -0.2 < a["bounds_ms"][0] < a["bounds_ms"][1] < 3
+    assert ("ALIGNMENT FAILED" in hs.line(found, reduced)) is failed
+    assert found["idle_s"] == pytest.approx(reduced["window_s"] - reduced["busy_s"])
+
+
+def test_an_offset_longer_than_a_block_is_not_credible():
+    """Blocks far apart (a loop that parks between them): each run still
+    finds the window nearest it, and one offset holds for all three, but it
+    is longer than a block from launch to fetch, so the run as well belongs
+    to no window at all: no correction."""
+    windows = [(100.0 * k, 100.0 * k + 1, 100.0 * k + 12) for k in range(3)]
+    p = tp4_profile(windows, [(100.0 * k + 31.5, 10.0) for k in range(3)])
+    a = hs.alignment(hs.read_profile(p), hs.device_runs(p))
+    assert a["blocks"] == a["runs"] == 3 and a["bounds_ms"] == (pytest.approx(-30.5), pytest.approx(-29.5))
+    assert "longer than a block" in a["failed"] and not a["corrected"] and a["offset_ms"] == 0.0 and a["share"] == 0.0
 
 
 def test_a_skew_inside_the_bounds_cannot_be_told_from_none():

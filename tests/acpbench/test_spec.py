@@ -79,15 +79,42 @@ def test_every_cell_finds_its_files_and_reports(cell):
                 assert callable(spec.reader(table, m["name"]).read)
 
 
+# what the contract calls a width: `reduced` may name none, in any family
+WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj\w*|head)_size$|_dim$|_rank$|expan|experts_per_tok")
+
+
+def _llama_facts(conf, file):
+    """The Qwen files' own: nothing cut at all, head width 128, a qkv bias,
+    weight-only int8."""
+    assert conf["reduced"] == []
+    program = spec.family(file).program_config(file)
+    assert program.dim // program.n_heads == 128 and program.qkv_bias is True
+    assert file["engine"]["quantize"] == "int8" and "int8" in file["precision"]["weights"]
+
+
 @pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_files_cut_no_width(conf):
     file = spec.load_json(os.path.join(spec.ROOT, conf["file"]))
     assert conf["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
-    assert file["source"] == conf["source"] and file["reduced"] == conf["reduced"] == []
-    program = spec.family(file).program_config(file)
-    assert program.dim // program.n_heads == 128 and program.qkv_bias is True
-    assert file["engine"]["quantize"] == "int8" and "int8" in file["precision"]["weights"]
+    assert file["source"] == conf["source"] and file["reduced"] == conf["reduced"]
+    assert not [key for key in conf["reduced"] if WIDTH.search(key)]
     assert any(c["config"] == conf["name"] for c in BENCH["workloads"])
+    if file["family"] == "llama":  # another family's own facts: a test file of its own (`test_lfm2_spec.py`)
+        _llama_facts(conf, file)
+
+
+def test_every_llama_configuration_is_held_to_the_llama_facts():
+    """The per-family assertion has something to bite on: the two Qwen
+    files name `llama`, and a width in `reduced` is seen."""
+    files = {c["name"]: spec.load_json(os.path.join(spec.ROOT, c["file"])) for c in BENCH["configs"]}
+    assert {n for n, f in files.items() if f["family"] == "llama"} >= {"qwen2.5-32b-int8-v5e4", "qwen2.5-7b-int8-v5e1"}
+    for key in ("hidden_size", "head_dim", "kv_lora_rank", "num_experts_per_tok", "ssm_state_size", "intermediate_size"):
+        assert WIDTH.search(key), key
+    for key in ("num_hidden_layers", "num_experts_held", "vocab_size", "max_position_embeddings"):
+        assert not WIDTH.search(key), key
+    cut = dict(BENCH["configs"][0], reduced=["num_hidden_layers"])
+    with pytest.raises(AssertionError):
+        _llama_facts(cut, files[cut["name"]])
 
 
 def test_gap_is_judged_in_no_closed_loop_cell():
