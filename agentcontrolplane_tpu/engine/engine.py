@@ -48,7 +48,7 @@ import numpy as np
 
 from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
 from ..observability.metrics import REGISTRY
-from ..ops.paged import TRASH_PAGE
+from ..ops.paged import TRASH_PAGE, set_pages
 from ..ops.sampling import sample
 from ..parallel.mesh import (
     kv_cache_shardings,
@@ -798,7 +798,7 @@ class Engine:
         # may be larger — those extra logits are simply forbidden under
         # constraint (constrain_logits pads the gathered rows)
         # prefix KV cache (slot layout): LRU of prompt-prefix -> device KV
-        # [L, cut, H_kv, d]. Agent workloads re-send growing conversations
+        # [L, cut, H_kv, d] (the paged layout shares pages instead). Agent workloads re-send growing conversations
         # with identical system prompts; a hit copies the cached KV into the
         # slot and prefills only the suffix — per-turn prefill becomes
         # O(new tokens) instead of O(whole conversation).
@@ -1231,7 +1231,7 @@ class Engine:
                 if swaps is not None:
                     for s_ids, s_blocks in swaps:
                         cache = {**cache, **{
-                            name: cache[name].at[:, s_ids].set(s_blocks[name])
+                            name: set_pages(cache[name], s_ids, s_blocks[name])
                             for name in s_blocks
                         }}
                 if mids is not None:
@@ -1414,19 +1414,19 @@ class Engine:
                 if "sp" in self.mesh.axis_names and dict(self.mesh.shape)["sp"] > 1
                 else None
             )
-            # [L, num_pages, page_size, H_kv, d]: heads over tp; within-page
+            # [L, num_pages, page_size, H_kv * d]: the row's KV heads over
+            # tp (H_kv / tp heads of d contiguous lanes a chip); within-page
             # over sp (context-parallel paged serving — page ids stay
             # rank-local, each rank holds a slice of every page)
-            page_spec = P(None, None, sp_axis, "tp", None)
+            page_spec = P(None, None, sp_axis, "tp")
             page_shardings = {
                 "k": NamedSharding(self.mesh, page_spec),
                 "v": NamedSharding(self.mesh, page_spec),
             }
             if self.quantize_kv:
-                # scale twins [L, NP, P, H_kv]: value spec minus head_dim
-                scale_spec = NamedSharding(self.mesh, P(None, None, sp_axis, "tp"))
-                page_shardings["ks"] = scale_spec
-                page_shardings["vs"] = scale_spec
+                # scale twins [L, NP, P, H_kv]: a chip's heads, like the row
+                page_shardings["ks"] = page_shardings["k"]
+                page_shardings["vs"] = page_shardings["v"]
             init_cache = lambda: self._model.init_paged_cache(  # noqa: E731
                 self.config, self.num_pages, self.page_size,
                 quantize_kv=self.quantize_kv, max_slots=self.max_slots,
@@ -3334,7 +3334,7 @@ class Engine:
             if fn is None:
                 fn = jax.jit(
                     lambda c, ids, blocks: {**c, **{
-                        name: c[name].at[:, ids].set(blocks[name])
+                        name: set_pages(c[name], ids, blocks[name])
                         for name in blocks
                     }},
                     donate_argnums=(0,),
@@ -6275,7 +6275,8 @@ class Engine:
     @_in_phase("launch")
     def _extract_pages(self, pages: list[int]) -> dict[str, np.ndarray]:  # acp: megastep-seam # acp: kv-seam
         """Gather paged KV pages to host numpy, token-major
-        ``{"k"/"v": [L, nP, H, d]}`` plus ``"ks"/"vs": [L, nP, H]`` scale
+        ``{"k"/"v": [L, tokens, H_kv * d]}`` (the pool's rows) plus ``"ks"/"vs":
+        [L, tokens, H_kv]`` scale
         rows when the pool is quantized (the host tier carries the int8
         bytes verbatim — no requantization round trip). Dispatches
         decompose into pow2 page counts (bounded jit entries); the
@@ -6381,7 +6382,7 @@ class Engine:
                 if fn is None:
                     fn = jax.jit(
                         lambda c, ids, blocks: {**c, **{
-                            name: c[name].at[:, ids].set(blocks[name])
+                            name: set_pages(c[name], ids, blocks[name])
                             for name in blocks
                         }},
                         donate_argnums=(0,),

@@ -58,6 +58,7 @@ single plain-bool branch (see ``Engine._run``).
 
 from __future__ import annotations
 
+import math
 from collections import Counter
 
 from ..observability.metrics import REGISTRY
@@ -194,8 +195,11 @@ def verify_engine(engine) -> list[str]:
 
 def _verify_quantized_cache(engine) -> list[str]:
     """Quantized-KV structural coupling (both layouts): a quantize_kv
-    engine's cache must carry int8 values plus scale twins whose leading
-    dims match the value arrays exactly — a scale array sheared off its
+    engine's cache must carry int8 values plus scale twins with one scale a
+    row and KV head: the values' three leading dims (layer, slot or page,
+    row) then ``H_kv``, beside values whose trailing dims hold ``H_kv * d``
+    (apart in the slot layout, one merged row in the paged pool) — a scale
+    array sheared off its
     values (wrong rows, missing key) dequantizes every later read into
     garbage. Knobs-off engines must carry NO scale storage (the byte-
     identical plain cache). Shape/dtype metadata only — no device
@@ -216,16 +220,19 @@ def _verify_quantized_cache(engine) -> list[str]:
             "(want k/v int8 values + ks/vs scale rows)"
         )
         return problems
+    c = engine.config
     for name in ("k", "v"):
         val, sc = engine.cache[name], engine.cache[name + "s"]
         if str(val.dtype) != "int8":
             problems.append(
                 f"quantized cache '{name}' has dtype {val.dtype}, not int8"
             )
-        if tuple(sc.shape) != tuple(val.shape[:-1]):
+        want = tuple(val.shape[:3]) + (c.n_kv_heads,)
+        if tuple(sc.shape) != want or math.prod(val.shape[3:]) != c.n_kv_heads * c.head_dim:
             problems.append(
                 f"scale rows '{name}s' shaped {tuple(sc.shape)} do not "
-                f"match value rows {tuple(val.shape[:-1])} — scale storage "
+                f"match value rows {tuple(val.shape)} (want {want}: a scale "
+                "a row and KV head) — scale storage "
                 "sheared off its pages/rows"
             )
     return problems

@@ -21,11 +21,10 @@ matmul itself). The HLO holds each piece once whatever the depth and the
 pattern.
 
 Serving state (paged layout only): the KV pool holds the attention layers
-alone, in the layout the page walk reads, ``[n_attention, pages, P, H_kv *
-d]`` (a page's row is its KV heads side by side: the walk's DMA source as it
-is, where a ``[.., H_kv, d]`` pool is relaid on the chip's tiling every time
-it is merged; int8 pages keep a scale a row and head, ``[.., P, H_kv]``), and
-beside it ``cache["state"]``:
+alone, in the one layout every family stores and the page walk reads,
+``[n_attention, pages, P, H_kv * d]`` (``ops/paged.py``'s module text says
+why; int8 pages keep a scale a row and head, ``[.., P, H_kv]``), and beside
+it ``cache["state"]``:
 
 - ``conv``  ``[n_conv, slots, taps-1, D]`` in the model's dtype: the last
   ``taps-1`` values of ``s`` of every slot and conv layer, what a decode
@@ -44,8 +43,11 @@ state; one that starts later reads ``conv[:, slot]``, which the engine has
 set (the previous chunk left it, or ``install_state`` copied it in).
 
 The decode walk reads the whole pool flattened to ``[L * pages, P, ...]``
-through block tables offset by the layer, so no layer of the pool is sliced
-out or relaid per step.
+through block tables offset by the layer, and every commit goes through the
+same flat view, so no layer of the pool is sliced out or relaid per step:
+``ops/paged.py``'s ``flat_pages``, ``layer_tables``, ``commit_whole_pages``,
+``commit_tokens`` and ``gather_pages``, the one copy ``models/llama.py``
+uses too.
 """
 
 from __future__ import annotations
@@ -59,7 +61,10 @@ import jax.numpy as jnp
 from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
 from ..ops.moe import COUNTS_HEAD, routed_experts
 from ..ops.norms import rms_norm
-from ..ops.quant import kv_dequantize, kv_quantize
+from ..ops.paged import (
+    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, layer_tables,
+    paged_decode_attention_reference_cache_plus_new,
+)
 from ..ops.rope import apply_rope
 
 PERIOD = ("attention", "conv", "conv", "conv")
@@ -385,14 +390,8 @@ def forward(params: dict, tokens: jax.Array, config: Lfm2Config) -> jax.Array:
 def init_paged_cache(config: Lfm2Config, num_pages: int, page_size: int, quantize_kv: bool = False,
                      max_slots: int = 1) -> dict:
     c = config
-    rows = (c.n_attention, num_pages, page_size)
-    width = c.n_kv_heads * c.head_dim
-    if quantize_kv:
-        cache = {"k": jnp.zeros(rows + (width,), jnp.int8), "v": jnp.zeros(rows + (width,), jnp.int8),
-                 "ks": jnp.zeros(rows + (c.n_kv_heads,), jnp.float32),
-                 "vs": jnp.zeros(rows + (c.n_kv_heads,), jnp.float32)}
-    else:
-        cache = {"k": jnp.zeros(rows + (width,), c.dtype), "v": jnp.zeros(rows + (width,), c.dtype)}
+    cache = init_kv_pages(c.n_attention, num_pages, page_size, c.n_kv_heads, c.head_dim, c.dtype,
+                          quantize=quantize_kv)
     shape = (c.n_conv, max_slots, c.conv_taps - 1, c.dim)
     cache["state"] = {
         "conv": jnp.zeros(shape, c.dtype),
@@ -404,35 +403,6 @@ def init_paged_cache(config: Lfm2Config, num_pages: int, page_size: int, quantiz
 
 def _kv(cache: dict) -> dict:
     return {k: v for k, v in cache.items() if k != "state"}
-
-
-def _kv_commit(pool: dict, new_k, new_v, setter) -> dict:
-    """Fresh K/V ``[L, ..., H_kv, d]`` into the pool through ``setter(array,
-    values)``: heads merged into the pool's row, int8 pools quantized here
-    a row and head, their scales through the same setter."""
-    merge = lambda t: t.reshape(t.shape[:-2] + (t.shape[-2] * t.shape[-1],))  # noqa: E731
-    if "ks" in pool:
-        (qk, sk), (qv, sv) = kv_quantize(new_k), kv_quantize(new_v)
-        return {"k": setter(pool["k"], merge(qk)), "v": setter(pool["v"], merge(qv)),
-                "ks": setter(pool["ks"], sk), "vs": setter(pool["vs"], sv)}
-    return {"k": setter(pool["k"], merge(new_k).astype(pool["k"].dtype)),
-            "v": setter(pool["v"], merge(new_v).astype(pool["v"].dtype))}
-
-
-def _commit_whole_pages(pool: dict, new_k, new_v, page_ids) -> dict:
-    """``new_k`` [L, B, T, H_kv, d] into pages ``page_ids`` [B, T // P]."""
-    L, B, T = new_k.shape[:3]
-    NP, P = pool["k"].shape[1:3]
-    # one scatter of whole pages into the pool flattened over its layers: a
-    # scatter windowed over the layer axis makes the compiler keep the pool
-    # layer-minor, and relay it for the walk inside every period of the scan
-    ids = (jnp.arange(L)[:, None] * NP + page_ids.reshape(-1)[None, :]).reshape(-1)
-
-    def setter(arr, val):
-        blocks = val.reshape((ids.shape[0], P) + val.shape[3:])
-        return arr.reshape((L * NP,) + arr.shape[2:]).at[ids].set(blocks).reshape(arr.shape)
-
-    return _kv_commit(pool, new_k, new_v, setter)
 
 
 def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, row):
@@ -476,21 +446,10 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     x, ends, snaps, new_k, new_v, counts = _run_layers(
         params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions), route)
-    pages = _commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, 1)
     x = rms_norm(x, params["norm"], c.norm_eps)
     return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, c)
-
-
-def _gather_rows(pool: dict, name: str, ids, dtype, n_kv_heads: int):
-    """Pages ``ids`` (any shape) of the flattened pool, heads apart again
-    ``[..., P, H_kv, d]``, int8 pages dequantized by their scale twins."""
-    flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])  # noqa: E731
-    rows = flat(pool[name])[ids]
-    rows = rows.reshape(rows.shape[:-1] + (n_kv_heads, rows.shape[-1] // n_kv_heads))
-    if name + "s" in pool:
-        return kv_dequantize(rows, flat(pool[name + "s"])[ids], dtype)
-    return rows.astype(dtype)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -511,9 +470,9 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
     def make_attn(a):
         def attn(q, k, v):
-            ids = block_tables + a * NP
-            k_rows = _gather_rows(pool, "k", ids, k.dtype, c.n_kv_heads).reshape(B, M * P, *k.shape[2:])
-            v_rows = _gather_rows(pool, "v", ids, v.dtype, c.n_kv_heads).reshape(B, M * P, *v.shape[2:])
+            ids = layer_tables(block_tables, a, NP)
+            k_rows = gather_pages(pool, "k", ids, k.dtype, c.n_kv_heads).reshape(B, M * P, *k.shape[2:])
+            v_rows = gather_pages(pool, "v", ids, v.dtype, c.n_kv_heads).reshape(B, M * P, *v.shape[2:])
             return continue_attention(q, jnp.concatenate([k_rows, k], axis=1),
                                       jnp.concatenate([v_rows, v], axis=1), positions, key_pos)
 
@@ -531,7 +490,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     B = tokens.shape[0]
     x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = _commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
     return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, config)
 
@@ -541,7 +500,7 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
     """The continuation's writes without the head (a mid chunk)."""
     _x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = _commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
 
 
@@ -550,26 +509,20 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     """One token for lanes 0..S-1 (lane b is slot b): attention layers walk
     the pages, conv layers read and shift their slot's state; an inactive
     lane's state and pages are left as they were."""
-    from ..ops.paged import TRASH_PAGE, paged_decode_attention_reference_cache_plus_new
-
     c = config
     S = tokens.shape[0]
     pool = _kv(cache)
-    L, NP, P = pool["k"].shape[:3]
-    flat = lambda a: a.reshape((L * NP,) + a.shape[2:])  # noqa: E731
-    heads = lambda a: a.reshape(a.shape[:-1] + (c.n_kv_heads, c.head_dim))  # noqa: E731
-    # the walk takes the merged pool as it is; the XLA reference wants the
-    # heads apart
-    k_flat, v_flat = flat(pool["k"]), flat(pool["v"])
-    if not use_pallas:
-        k_flat, v_flat = heads(k_flat), heads(v_flat)
-    scales = (flat(pool["ks"]), flat(pool["vs"])) if "ks" in pool else (None, None)
+    NP, P = pool["k"].shape[1:3]
+    # the walk takes the merged pool as it is; the XLA reference splits the
+    # heads on what it gathers
+    k_flat, v_flat = flat_pages(pool["k"]), flat_pages(pool["v"])
+    scales = (flat_pages(pool["ks"]), flat_pages(pool["vs"])) if "ks" in pool else (None, None)
     ctx = {"positions": seq_lens[:, None], "valid": active[:, None],
            "lengths": jnp.ones((S,), jnp.int32), "snap_rel": jnp.zeros((S,), jnp.int32)}
 
     def make_attn(a):
         def attn(q, k, v):
-            tables = block_tables + a * NP
+            tables = layer_tables(block_tables, a, NP)
             args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
             if use_pallas:
                 from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
@@ -586,13 +539,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     x, ends, _snaps, new_k, new_v, counts = _run_layers(
         params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route)
     target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-    # one scatter of token rows into the pool flattened to rows (see
-    # _commit_whole_pages): row (layer, page(slot), offset(slot))
-    rows = ((jnp.arange(L)[:, None] * NP + target[None, :]) * P + (seq_lens % P)[None, :]).reshape(-1)
-    pages = _kv_commit(
-        pool, new_k[:, :, 0], new_v[:, :, 0],
-        lambda arr, val: arr.reshape((L * NP * P,) + arr.shape[3:]).at[rows].set(
-            val.reshape((L * S,) + val.shape[2:])).reshape(arr.shape))
+    pages = commit_tokens(pool, new_k[:, :, 0], new_v[:, :, 0], target, seq_lens % P)
     conv = st["conv"].at[:, :S].set(
         jnp.where(active[None, :, None, None], ends.astype(st["conv"].dtype), st["conv"][:, :S]))
     cache = {**pages, "state": {"conv": conv, "snap": st["snap"], "moe": st["moe"].at[0].add(counts)}}

@@ -33,6 +33,17 @@ from ..ops.attention import (
     decode_attention_cache_plus_new,
 )
 from ..ops.norms import rms_norm
+from ..ops.paged import (
+    TRASH_PAGE,
+    commit_tokens,
+    commit_whole_pages,
+    flat_pages,
+    gather_pages,
+    init_kv_pages,
+    layer_tables,
+    paged_decode_attention_reference_cache_plus_new,
+    token_write_targets,
+)
 from ..ops.quant import kv_dequantize, kv_quantize
 from ..ops.rope import apply_rope
 
@@ -504,14 +515,16 @@ def forward(
 # ---------------------------------------------------------------------------
 #
 # A quantized cache is the SAME dict with int8 "k"/"v" plus per-row-per-head
-# f32 scale arrays "ks"/"vs" shaped like the value arrays minus head_dim
-# ([L, S, C, H_kv] slot / [L, NP, P, H_kv] paged). Presence of "ks" is the
-# trace-time switch: every model program below commits through _kv_commit
-# (quantize-on-commit, same single scatter) and reads through _kv_rows
-# (dequantize-after-gather), so all compiled shapes — prefill, continuation,
-# KV-only megastep chunks, decode, spec verify — serve quantized without a
-# second code path. Scale scatters reuse the value scatter's leading
-# indices, so scale storage is owned/freed with its pages by construction.
+# f32 scale arrays "ks"/"vs", one a row and KV head ([L, S, C, H_kv] slot /
+# [L, NP, P, H_kv] paged). Presence of "ks" is the trace-time switch: every
+# slot-layout program below commits through _kv_commit (quantize-on-commit,
+# same single scatter) and reads through _kv_rows (dequantize-after-gather);
+# the paged programs go through ops/paged.py's kv_commit and gather_pages,
+# the same discipline over the pool's merged rows. So all compiled shapes —
+# prefill, continuation, KV-only megastep chunks, decode, spec verify — serve
+# quantized without a second code path. Scale scatters reuse the value
+# scatter's leading indices, so scale storage is owned/freed with its pages
+# by construction.
 
 
 def _kv_scan_xs(cache: dict) -> tuple:
@@ -783,8 +796,9 @@ def verify_continue(
 def init_paged_cache(
     config: LlamaConfig, num_pages: int, page_size: int, quantize_kv: bool = False
 ) -> dict:
-    from ..ops.paged import init_kv_pages
-
+    """``{"k", "v": [L, num_pages, P, H_kv * d]}`` (+ int8 scale twins
+    ``[L, num_pages, P, H_kv]``): the layout the page walk reads, shared by
+    every family (``ops/paged.py``)."""
     return init_kv_pages(
         config.n_layers, num_pages, page_size, config.n_kv_heads, config.head_dim,
         config.dtype, quantize=quantize_kv,
@@ -793,7 +807,7 @@ def init_paged_cache(
 
 def prefill_paged_batch(
     params: dict,
-    pages: dict,  # {"k": [L, num_pages, P, H_kv, d], "v": ...}
+    pages: dict,  # {"k": [L, num_pages, P, H_kv * d], "v": ...}
     tokens: jax.Array,  # [B, T] int32 (rows padded to a multiple of page_size)
     lengths: jax.Array,  # [B] int32
     page_ids: jax.Array,  # [B, T // P] int32 (TRASH_PAGE beyond each prompt)
@@ -819,9 +833,10 @@ def prefill_paged_batch(
         return out, (k, v)
 
     # pages stay out of the scan (prompt attention never reads them); one
-    # scatter commits all layers' blocks — see prefill_batch/decode_step
+    # scatter of whole pages commits all layers' blocks through the pool
+    # flattened over its layers (ops/paged.py) — see prefill_batch/decode_step
     x, (new_k, new_v) = jax.lax.scan(body, x, params["layers"])
-    pages = _commit_whole_pages(pages, new_k, new_v, page_ids)
+    pages = commit_whole_pages(pages, new_k, new_v, page_ids)
     x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
     last = x[jnp.arange(B), lengths - 1]
     logits = _head_logits(last, params, c)
@@ -845,7 +860,7 @@ def prefill_paged(
 
 def _paged_continue_forward(
     params: dict,
-    pages: dict,  # {"k": [L, num_pages, P, H_kv, d], "v": ...}
+    pages: dict,  # {"k": [L, num_pages, P, H_kv * d], "v": ...}
     tokens: jax.Array,  # [B, T] int32 — new tokens (rows padded)
     lengths: jax.Array,  # [B] int32 — true token counts
     starts: jax.Array,  # [B] int32 — absolute position of each row's first token
@@ -866,7 +881,7 @@ def _paged_continue_forward(
     x = _embed(params, tokens, c)
     max_pages = block_tables.shape[1]
 
-    P = pages["k"].shape[2]
+    NP, P = pages["k"].shape[1:3]
     # keys = [gathered prefix pages (positions < start) ++ own suffix]; the
     # suffix pages referenced by the block table are not yet written, so
     # their gathered rows are stale — masked via key position -1.
@@ -885,13 +900,17 @@ def _paged_continue_forward(
 
     def body(carry, scanned):
         x = carry
-        layer, k_kv, v_kv = scanned  # read-only (value + optional scales)
+        layer, index = scanned
+        # the pool stays out of the scan's xs (a slice of a stacked operand
+        # is a copy of the layer's pool): the layer's pages are gathered from
+        # the whole pool by tables offset by the layer, and only the
+        # gathered rows have their heads split (and are dequantized)
+        tables = layer_tables(block_tables, index, NP)
 
         def attn(q, k, v):
-            # gather (+ dequantize) each row's pages, then transpose to the
-            # offset-major row order described above
-            k_gath = _kv_rows(k_kv, block_tables, k.dtype)  # [B, M, P, H, d]
-            v_gath = _kv_rows(v_kv, block_tables, v.dtype)
+            k_gath = gather_pages(pages, "k", tables, k.dtype, c.n_kv_heads)  # [B, M, P, H, d]
+            v_gath = gather_pages(pages, "v", tables, v.dtype, c.n_kv_heads)
+            # transpose to the offset-major row order described above
             k_rows = jnp.swapaxes(k_gath, 1, 2).reshape(
                 B, P * max_pages, *k_gath.shape[3:]
             )
@@ -911,7 +930,7 @@ def _paged_continue_forward(
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], *_kv_scan_xs(pages))
+        body, x, (params["layers"], jnp.arange(c.n_layers, dtype=jnp.int32))
     )
     x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
     return new_k, new_v, x
@@ -919,7 +938,7 @@ def _paged_continue_forward(
 
 def prefill_paged_continue(
     params: dict,
-    pages: dict,  # {"k": [L, num_pages, P, H_kv, d], "v": ...}
+    pages: dict,  # {"k": [L, num_pages, P, H_kv * d], "v": ...}
     tokens: jax.Array,  # [B, T] int32 — SUFFIX tokens (rows padded)
     lengths: jax.Array,  # [B] int32 — true suffix lengths
     starts: jax.Array,  # [B] int32 — absolute suffix start (page-aligned)
@@ -937,37 +956,15 @@ def prefill_paged_continue(
         params, pages, tokens, lengths, starts, block_tables, config
     )
     # one scatter commits the suffix blocks for every layer
-    pages = _commit_whole_pages(pages, new_k, new_v, page_ids)
+    pages = commit_whole_pages(pages, new_k, new_v, page_ids)
     last = x[jnp.arange(B), lengths - 1]
     logits = _head_logits(last, params, config)
     return pages, logits
 
 
-def _commit_whole_pages(
-    pages: dict,
-    new_k: jax.Array,  # [L, B, T, H_kv, d]
-    new_v: jax.Array,
-    page_ids: jax.Array,  # [B, T // P] int32
-) -> dict:
-    """Whole-page commit shared by the batch prefill, the split
-    continuation, and the fused megastep's mid-chunk phase — one copy of
-    the page-write discipline, so the paths' KV layout can never silently
-    diverge. The blocks reshape generalizes to the scale arrays (values
-    [L, B, T, H, d] and scales [L, B, T, H] both split T into pages)."""
-    L = new_k.shape[0]
-    B, T = new_k.shape[1], new_k.shape[2]
-    P = pages["k"].shape[2]
-    blocks = lambda t: t.reshape(L, B * (T // P), P, *t.shape[3:])
-    flat_ids = page_ids.reshape(-1)
-    return _kv_commit(
-        pages, new_k, new_v,
-        lambda arr, val: arr.at[:, flat_ids].set(blocks(val)),
-    )
-
-
 def prefill_paged_continue_kv(
     params: dict,
-    pages: dict,  # {"k": [L, num_pages, P, H_kv, d], "v": ...}
+    pages: dict,  # {"k": [L, num_pages, P, H_kv * d], "v": ...}
     tokens: jax.Array,  # [B, T] int32 — chunk tokens (rows padded)
     lengths: jax.Array,  # [B] int32 — true chunk lengths (0 = padding lane)
     starts: jax.Array,  # [B] int32 — absolute chunk start (page-aligned)
@@ -981,12 +978,12 @@ def prefill_paged_continue_kv(
     new_k, new_v, _x = _paged_continue_forward(
         params, pages, tokens, lengths, starts, block_tables, config
     )
-    return _commit_whole_pages(pages, new_k, new_v, page_ids)
+    return commit_whole_pages(pages, new_k, new_v, page_ids)
 
 
 def verify_paged_continue(
     params: dict,
-    pages: dict,  # {"k": [L, num_pages, P, H_kv, d], "v": ...}
+    pages: dict,  # {"k": [L, num_pages, P, H_kv * d], "v": ...}
     tokens: jax.Array,  # [B, T] int32 — last sampled token + draft (rows padded)
     lengths: jax.Array,  # [B] int32 — 1 + draft length per row
     starts: jax.Array,  # [B] int32 — seq_len per row (NOT page-aligned)
@@ -1002,18 +999,13 @@ def verify_paged_continue(
     Padded positions land on the trash page. Returns (pages, logits
     [B, T, V]); the rejected tail's KV needs no rollback (attention masks
     by seq_len, which the engine only advances over the accepted prefix)."""
-    from ..ops.paged import token_write_targets
-
     B, T = tokens.shape
     P = pages["k"].shape[2]
     new_k, new_v, x = _paged_continue_forward(
         params, pages, tokens, lengths, starts, block_tables, config
     )
     target, offset = token_write_targets(block_tables, starts, lengths, P, T)
-    pages = _kv_commit(
-        pages, new_k, new_v,
-        lambda arr, val: arr.at[:, target, offset].set(val),
-    )
+    pages = commit_tokens(pages, new_k, new_v, target, offset)
     return pages, _head_logits(x, params, config)
 
 
@@ -1030,66 +1022,55 @@ def decode_step_paged(
 ) -> tuple[dict, jax.Array]:
     """One decode step for all slots against the paged cache.
 
-    Same HBM discipline as :func:`decode_step`: pages ride the layer scan
-    READ-ONLY, the new token attends via a self term (folded outside the
-    Pallas kernel from its unnormalized (acc, m, l) output), and one
-    scatter after the scan commits every layer's new K/V to the pages."""
-    from ..ops.paged import (
-        TRASH_PAGE,
-        paged_decode_attention_reference_cache_plus_new,
-    )
-
+    Same HBM discipline as :func:`decode_step`: the pages are READ-ONLY
+    through the layer scan, the new token attends via a self term (folded
+    outside the Pallas kernel from its unnormalized (acc, m, l) output), and
+    one scatter after the scan commits every layer's new K/V to the pages.
+    The pool is not among the scan's xs: the scan carries the layer's index,
+    and every layer's walk is handed the WHOLE pool flattened over its
+    layers with block tables offset by the layer, so a step moves no page
+    it does not read (a slice of the stacked pool handed to an opaque kernel
+    is a copy of the layer's pool; ops/paged.py)."""
     c = config
     S = tokens.shape[0]
     positions = seq_lens[:, None]
     x = _embed(params, tokens[:, None], c)
     quantized = "ks" in pages
-    tp_size = sp_size = 1
-    if mesh is not None:
-        axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        tp_size = axes.get("tp", 1)
-        sp_size = axes.get("sp", 1)
+    NP, P = pages["k"].shape[1:3]
+    k_flat, v_flat = flat_pages(pages["k"]), flat_pages(pages["v"])
+    # int8 pages carry f32 scale twins; the Pallas path DMAs them with each
+    # page fetch and applies them in VMEM (the same f32 math as the
+    # reference up to rounding order; parity pinned at 1e-5). The kernel
+    # reads them head-major and lane-padded: laid out once here for the
+    # whole pool, not once a layer inside the scan (walk_scale_rows)
+    scales = {}
+    if quantized:
+        scales = {"k_scales": flat_pages(pages["ks"]), "v_scales": flat_pages(pages["vs"])}
+        if use_pallas:
+            from ..ops.pallas.paged_attention import walk_scale_rows
+
+            scales = {name: walk_scale_rows(a, mesh) for name, a in scales.items()}
+            scales["scales_laid"] = True
 
     def body(carry, scanned):
         x = carry
-        layer, k_kv, v_kv = scanned  # read-only (value + optional scales)
-        k_pages_l, v_pages_l = k_kv[0], v_kv[0]
-        # int8 pages carry f32 scale twins; the Pallas path DMAs them with
-        # each page fetch and applies them in VMEM (the same f32 math as
-        # the reference up to rounding order; parity pinned at 1e-5)
-        k_scales_l = k_kv[1] if quantized else None
-        v_scales_l = v_kv[1] if quantized else None
+        layer, index = scanned
+        tables = layer_tables(block_tables, index, NP)
 
         def attn(q, k, v):
-            if use_pallas and (tp_size > 1 or sp_size > 1):
-                # the sharded wrapper routes sp>1 meshes through the
-                # cross-rank (acc, m, l) flash merge
+            args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
+            if use_pallas:
+                # one chip runs the kernel as it is; tp>1 under shard_map
+                # over each chip's heads, sp>1 through the cross-rank
+                # (acc, m, l) flash merge
                 from ..ops.pallas.paged_attention import (
                     paged_decode_attention_cache_plus_new_sharded,
                 )
 
-                out = paged_decode_attention_cache_plus_new_sharded(
-                    mesh, q[:, 0], k_pages_l, v_pages_l, block_tables, seq_lens,
-                    k[:, 0], v[:, 0],
-                    k_scales=k_scales_l, v_scales=v_scales_l,
-                )
-            elif use_pallas:
-                from ..ops.pallas.paged_attention import (
-                    paged_decode_attention_cache_plus_new,
-                )
-
-                out = paged_decode_attention_cache_plus_new(
-                    q[:, 0], k_pages_l, v_pages_l, block_tables, seq_lens,
-                    k[:, 0], v[:, 0],
-                    k_scales=k_scales_l, v_scales=v_scales_l,
-                )
+                out = paged_decode_attention_cache_plus_new_sharded(mesh, *args, **scales)
             else:
-                out = paged_decode_attention_reference_cache_plus_new(
-                    q[:, 0], k_pages_l, v_pages_l, block_tables, seq_lens,
-                    k[:, 0], v[:, 0],
-                    k_scales=k_kv[1] if quantized else None,
-                    v_scales=v_kv[1] if quantized else None,
-                )
+                # the XLA reference splits the heads on what it gathers
+                out = paged_decode_attention_reference_cache_plus_new(*args, **scales)
             attn.new_kv = (k[:, 0], v[:, 0])
             return out[:, None]
 
@@ -1097,19 +1078,13 @@ def decode_step_paged(
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], *_kv_scan_xs(pages))
+        body, x, (params["layers"], jnp.arange(c.n_layers, dtype=jnp.int32))
     )
-    # one scatter commits all layers: (l, page(slot), offset(slot)); inactive
-    # slots land on the trash page
-    P = pages["k"].shape[2]
-    page_idx = seq_lens // P
-    offset = seq_lens % P
-    target = block_tables[jnp.arange(S), page_idx]
+    # one scatter of token rows commits all layers: (l, page(slot),
+    # offset(slot)); inactive slots land on the trash page
+    target = block_tables[jnp.arange(S), seq_lens // P]
     target = jnp.where(active, target, TRASH_PAGE)
-    pages = _kv_commit(
-        pages, new_k, new_v,
-        lambda arr, val: arr.at[:, target, offset].set(val),
-    )
+    pages = commit_tokens(pages, new_k, new_v, target, seq_lens % P)
     x = rms_norm(x[:, 0], _final_norm_w(params, c), c.norm_eps)
     logits = _head_logits(x, params, c)
     return pages, logits

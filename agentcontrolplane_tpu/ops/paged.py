@@ -1,10 +1,21 @@
 """Paged KV cache: page-table layout + pure-XLA reference ops.
 
-The north star calls for a paged KV cache: KV lives in fixed-size pages
-``[num_pages, page_size, H_kv, d]`` and each sequence owns a page list
-(block table), so HBM is allocated page-granular instead of
-max-context-granular — at 64 slots x 8k max context the slot layout wastes
-whatever contexts don't use, the paged layout doesn't.
+The north star calls for a paged KV cache: KV lives in fixed-size pages and
+each sequence owns a page list (block table), so HBM is allocated
+page-granular instead of max-context-granular — at 64 slots x 8k max context
+the slot layout wastes whatever contexts don't use, the paged layout doesn't.
+
+The pool is stored as the page walk reads it, for every model family:
+``[L, num_pages, page_size, H_kv * d]`` per k/v, a page's row its KV heads
+side by side (head-major). That is the walk's DMA source as it is; a pool
+with the heads on an axis of their own is relaid on the chip's tiling every
+time they are merged, a copy of a layer's whole pool a call (PERF.md, PR 31
+and PR 33). A program hands the walk the whole pool flattened over its
+layers (:func:`flat_pages`, a reshape of leading axes only) and block tables
+offset by the layer (:func:`layer_tables`), and commits through the same
+flat view (:func:`commit_whole_pages`, :func:`commit_tokens`): a scatter
+windowed over the layer axis makes the compiler keep the pool layer-minor
+and relay all of it.
 
 This module is the *reference* implementation (pure jnp gather/scatter,
 exact); ``ops.pallas.paged_attention`` is the TPU kernel that walks block
@@ -23,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .attention import NEG_INF
+from .quant import kv_dequantize, kv_quantize
 
 TRASH_PAGE = 0
 
@@ -31,26 +43,93 @@ def init_kv_pages(
     n_layers: int, num_pages: int, page_size: int, n_kv_heads: int, head_dim: int,
     dtype, quantize: bool = False,
 ) -> dict:
-    """Page pools [L, NP, P, H_kv, d] per k/v. With ``quantize`` the values
-    are int8 and per-row-per-head f32 scales ride page-shaped twins
-    ("ks"/"vs", [L, NP, P, H_kv]) indexed by the SAME page ids — scale
-    storage is allocated, shared, swapped, and freed with its pages."""
-    shape = (n_layers, num_pages, page_size, n_kv_heads, head_dim)
+    """Page pools [L, NP, P, H_kv * d] per k/v (the module's text). With
+    ``quantize`` the values are int8 and per-row-per-head f32 scales ride
+    page-shaped twins ("ks"/"vs", [L, NP, P, H_kv]) indexed by the SAME page
+    ids — scale storage is allocated, shared, swapped, and freed with its
+    pages."""
+    rows = (n_layers, num_pages, page_size)
+    width = n_kv_heads * head_dim
     if quantize:
         return {
-            "k": jnp.zeros(shape, dtype=jnp.int8),
-            "v": jnp.zeros(shape, dtype=jnp.int8),
-            "ks": jnp.zeros(shape[:-1], dtype=jnp.float32),
-            "vs": jnp.zeros(shape[:-1], dtype=jnp.float32),
+            "k": jnp.zeros(rows + (width,), dtype=jnp.int8),
+            "v": jnp.zeros(rows + (width,), dtype=jnp.int8),
+            "ks": jnp.zeros(rows + (n_kv_heads,), dtype=jnp.float32),
+            "vs": jnp.zeros(rows + (n_kv_heads,), dtype=jnp.float32),
         }
-    return {"k": jnp.zeros(shape, dtype=dtype), "v": jnp.zeros(shape, dtype=dtype)}
+    return {"k": jnp.zeros(rows + (width,), dtype=dtype), "v": jnp.zeros(rows + (width,), dtype=dtype)}
+
+
+def flat_pages(a: jax.Array) -> jax.Array:
+    """A pool leaf ``[L, NP, ...]`` as ``[L * NP, ...]``: page ``p`` of layer
+    ``l`` is page ``l * NP + p`` (:func:`layer_tables`)."""
+    return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+
+
+def layer_tables(page_ids: jax.Array, layer, num_pages: int) -> jax.Array:
+    """Page ids of one layer's pool as ids of the flattened pool."""
+    return page_ids + layer * num_pages
+
+
+def set_pages(arr: jax.Array, page_ids: jax.Array, blocks: jax.Array) -> jax.Array:
+    """``arr[:, page_ids] = blocks`` ([L, n, P, ...]) as one scatter of whole
+    pages into the pool flattened over its layers."""
+    L, NP = arr.shape[:2]
+    ids = layer_tables(page_ids.reshape(-1)[None, :], jnp.arange(L)[:, None], NP).reshape(-1)
+    flat = flat_pages(arr).at[ids].set(blocks.reshape((ids.shape[0],) + arr.shape[2:]))
+    return flat.reshape(arr.shape)
+
+
+def kv_commit(pool: dict, new_k: jax.Array, new_v: jax.Array, setter) -> dict:
+    """Fresh K/V ``[L, ..., H_kv, d]`` into the pool through ``setter(array,
+    values)``: heads merged into the pool's row, int8 pools quantized here
+    a row and head, their scales through the same setter."""
+    merge = lambda t: t.reshape(t.shape[:-2] + (t.shape[-2] * t.shape[-1],))  # noqa: E731
+    if "ks" in pool:
+        (qk, sk), (qv, sv) = kv_quantize(new_k), kv_quantize(new_v)
+        return {"k": setter(pool["k"], merge(qk)), "v": setter(pool["v"], merge(qv)),
+                "ks": setter(pool["ks"], sk), "vs": setter(pool["vs"], sv)}
+    return {"k": setter(pool["k"], merge(new_k).astype(pool["k"].dtype)),
+            "v": setter(pool["v"], merge(new_v).astype(pool["v"].dtype))}
+
+
+def commit_whole_pages(pool: dict, new_k: jax.Array, new_v: jax.Array, page_ids: jax.Array) -> dict:
+    """``new_k`` [L, B, T, H_kv, d] into pages ``page_ids`` [B, T // P]: the
+    one whole-page write of every prefill, continuation and mid chunk."""
+    return kv_commit(pool, new_k, new_v, lambda arr, val: set_pages(arr, page_ids, val))
+
+
+def commit_tokens(pool: dict, new_k: jax.Array, new_v: jax.Array, pages: jax.Array,
+                  offsets: jax.Array) -> dict:
+    """``new_k`` [L, ..., H_kv, d] a token at a time into row ``offsets`` of
+    page ``pages`` (each [...]: a decode step's lanes, a verify pass's
+    [B, T]): one scatter of token rows into the pool flattened over its
+    layers. The within-page axis stays an axis of its own, so a pool that
+    shards it (context-parallel serving) is not gathered to be written."""
+    L, NP = pool["k"].shape[:2]
+    layer = jnp.arange(L).reshape((L,) + (1,) * pages.ndim)
+    ids = layer_tables(pages[None], layer, NP)
+    rows = jnp.broadcast_to(offsets[None], ids.shape)
+    return kv_commit(pool, new_k, new_v,
+                     lambda arr, val: flat_pages(arr).at[ids, rows].set(val).reshape(arr.shape))
+
+
+def gather_pages(pool: dict, name: str, ids: jax.Array, dtype, n_kv_heads: int) -> jax.Array:
+    """Pages ``ids`` (any shape; ids of the flattened pool) of leaf ``name``
+    with the heads apart ``[..., P, H_kv, d]``: only what is gathered is
+    split, and int8 pages are dequantized by their scale twins."""
+    rows = flat_pages(pool[name])[ids]
+    rows = rows.reshape(rows.shape[:-1] + (n_kv_heads, rows.shape[-1] // n_kv_heads))
+    if name + "s" in pool:
+        return kv_dequantize(rows, flat_pages(pool[name + "s"])[ids], dtype)
+    return rows.astype(dtype)
 
 
 def write_prompt_to_pages(
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d] (one layer)
+    k_pages: jax.Array,  # [num_pages, P, ...] (one layer; any trailing axes)
     v_pages: jax.Array,
     page_ids: jax.Array,  # [max_prompt_pages] int32 — TRASH_PAGE beyond prompt
-    k_new: jax.Array,  # [T, H_kv, d], T = max_prompt_pages * P (padded)
+    k_new: jax.Array,  # [T, ...], T = max_prompt_pages * P (padded)
     v_new: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
     P = k_pages.shape[1]
@@ -61,12 +140,12 @@ def write_prompt_to_pages(
 
 
 def write_token_to_pages(
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d]
+    k_pages: jax.Array,  # [num_pages, P, ...]
     v_pages: jax.Array,
     block_tables: jax.Array,  # [S, max_pages] int32
     positions: jax.Array,  # [S] int32 — token position per slot
     active: jax.Array,  # [S] bool — inactive slots write to the trash page
-    k_new: jax.Array,  # [S, H_kv, d]
+    k_new: jax.Array,  # [S, ...]
     v_new: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
     P = k_pages.shape[1]
@@ -106,7 +185,7 @@ def token_write_targets(
 
 def paged_decode_attention_reference(
     q: jax.Array,  # [S, H, d] — one new token per slot
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d]
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d], or the heads apart [.., H_kv, d]
     v_pages: jax.Array,
     block_tables: jax.Array,  # [S, max_pages]
     seq_lens: jax.Array,  # [S] — valid tokens per slot (incl. the new one)
@@ -115,9 +194,11 @@ def paged_decode_attention_reference(
 ) -> jax.Array:
     """Exact paged attention by materializing each slot's pages (gather).
     O(S * max_pages * P) HBM traffic + a gathered copy — the thing the
-    Pallas kernel avoids. With ``k_scales``/``v_scales`` the pools are
-    int8 and dequantization happens AFTER the gather (only each slot's
-    gathered rows ever exist in float; the pool stays int8).
+    Pallas kernel avoids. The heads are split on what was gathered (a
+    pool's row holds them side by side), never on the pool. With
+    ``k_scales``/``v_scales`` the pools are int8 and dequantization happens
+    AFTER the gather (only each slot's gathered rows ever exist in float;
+    the pool stays int8).
 
     The (page, offset) axes stay UNMERGED through the whole reduction:
     under context-parallel serving the pools' within-page dim carries the
@@ -126,10 +207,11 @@ def paged_decode_attention_reference(
     softmax reductions compile to per-shard partials + tiny all-reduces,
     the same pattern as the slot layout's ctx-sharded cache."""
     S, H, d = q.shape
-    num_pages, P, H_kv, _ = k_pages.shape
+    P = k_pages.shape[1]
     max_pages = block_tables.shape[1]
-    k = k_pages[block_tables]  # [S, M, P, H_kv, d]
-    v = v_pages[block_tables]
+    k = k_pages[block_tables].reshape(S, max_pages, P, -1, d)  # [S, M, P, H_kv, d]
+    v = v_pages[block_tables].reshape(k.shape)
+    H_kv = k.shape[3]
     if k_scales is not None:
         k = k.astype(jnp.float32) * k_scales[block_tables][..., None]
         v = v.astype(jnp.float32) * v_scales[block_tables][..., None]
@@ -150,7 +232,7 @@ def paged_decode_attention_reference(
 
 def paged_decode_attention_reference_cache_plus_new(
     q: jax.Array,  # [S, H, d]
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d] — WITHOUT the new token
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] (or [.., H_kv, d]) — WITHOUT the new token
     v_pages: jax.Array,
     block_tables: jax.Array,  # [S, max_pages]
     seq_lens: jax.Array,  # [S] — tokens valid in the pages (excl. new)
@@ -170,11 +252,12 @@ def paged_decode_attention_reference_cache_plus_new(
     (page, offset) axes stay unmerged — see
     :func:`paged_decode_attention_reference` for why (sp sharding)."""
     S, H, d = q.shape
-    num_pages, P, H_kv, _ = k_pages.shape
+    P = k_pages.shape[1]
     max_pages = block_tables.shape[1]
+    H_kv = k_new.shape[1]
     r = H // H_kv
-    k = k_pages[block_tables]  # [S, M, P, H_kv, d]
-    v = v_pages[block_tables]
+    k = k_pages[block_tables].reshape(S, max_pages, P, H_kv, d)
+    v = v_pages[block_tables].reshape(k.shape)
     if k_scales is not None:
         k = k.astype(jnp.float32) * k_scales[block_tables][..., None]
         v = v.astype(jnp.float32) * v_scales[block_tables][..., None]
@@ -303,9 +386,12 @@ class PageAllocator:
 
 @dataclass
 class HostKVEntry:
-    """Swapped-out KV resident in host RAM: token-major rows (layout-
-    independent — the engine's extract/restore paths convert to and from
-    the slot rows or page blocks of whichever KV layout is serving).
+    """Swapped-out KV resident in host RAM: token-major rows with the
+    trailing axes of the cache they left (the engine's extract/restore
+    paths convert to and from the slot rows ``[.., H_kv, d]`` or the page
+    blocks ``[.., H_kv * d]`` of whichever KV layout is serving, generic
+    over what follows the token axis; an entry restores into the layout it
+    was taken from).
 
     ``tokens`` is the exact token sequence whose KV the rows hold (rows
     ``[0, cut)`` of a request's prefill row), so an entry can be matched
@@ -320,7 +406,7 @@ class HostKVEntry:
 
     rid: str
     tokens: tuple
-    k: np.ndarray  # [L, cut, H_kv, d] (bf16, or int8 with scales below)
+    k: np.ndarray  # [L, cut, H_kv * d] paged, [L, cut, H_kv, d] slot (bf16, or int8 with scales below)
     v: np.ndarray
     k_scale: Optional[np.ndarray] = None  # [L, cut, H_kv] f32
     v_scale: Optional[np.ndarray] = None

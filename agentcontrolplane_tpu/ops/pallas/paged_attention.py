@@ -14,6 +14,13 @@ cache pages a read-only operand: the engine's decode step commits all
 layers' new K/V with one scatter after the layer scan instead of writing
 pages before every attention call (see models/llama.py decode_step_paged).
 
+The kernel reads pages ``[num_pages, P, H_kv * d]``, a row its KV heads side
+by side, and that is how every family's pool is stored (``ops/paged.py``):
+a program hands the walk its whole pool flattened over the layers, with
+block tables offset by the layer, and nothing is relaid. A test or
+``chip_smoke.py`` may still hand the wrappers one layer's ``[num_pages, P,
+H_kv, d]``; the merge is then a copy of those pages on the chip's tiling.
+
 Grid: one program per slot. A turn of the walk covers G pages, G chosen
 so that a turn is one 128-lane tile of tokens (``pages_per_turn``: 8 at the
 engine's page 16, 1 at page 128): the turn's pages are DMA'd each into its
@@ -42,15 +49,19 @@ cross the kernel boundary grouped ``[S, H_kv, n_rep, .]`` for the same
 reason; the wrapper reshapes them outside.
 
 int8 page walk: with ``k_scales``/``v_scales`` (the allocator's per-row-
-per-head f32 scale twins, natural [num_pages, P, H_kv] layout) each page
+per-head f32 scale twins, [num_pages, P, H_kv] as the pool stores them, or
+already laid out for the kernel by :func:`walk_scale_rows`) each page
 fetch also DMAs its scale row on dedicated semaphore lanes. The per-row
 scale factors out of both products — ``q . (k_int8 * s) == (q . k_int8) *
 s`` — so the body scales the ``[n_rep, P]`` logits and softmax weights by
 the head's ``[1, P]`` scale row and never builds a dequantized page; the
 pool stays int8 in HBM and only int8 bytes cross to VMEM. The scale rows
 reach the kernel head-major and padded to whole 128-lane rows (built by
-XLA outside the kernel): Mosaic cannot slice an HBM operand whose minor
-dim is under a lane tile.
+XLA outside the kernel, :func:`scale_rows`): Mosaic cannot slice an HBM
+operand whose minor dim is under a lane tile. That transpose touches every
+scale it is given, so a program that calls the walk once a layer over its
+whole pool builds the rows once a step, outside its layer scan
+(:func:`walk_scale_rows`, ``scales_laid=True``).
 
 Tested in interpreter mode on CPU against the exact reference
 (tests/engine/test_paged*.py), compiled for a described v5e
@@ -111,6 +122,49 @@ def heads_per_window(d: int, H_kv: int, quantized: bool = False) -> int:
     if LANES % d == 0 and d >= 64 and H_kv % (LANES // d) == 0 and not quantized:
         return LANES // d
     return 0
+
+
+def scale_rows(scales: jax.Array) -> jax.Array:
+    """Scale twins ``[num_pages, P, H_kv]`` as the kernel reads them,
+    ``[num_pages, 1, SC]``: a page's scales head-major (``[H_kv, P]``
+    flattened) and padded to whole 128-lane rows. Mosaic cannot slice an HBM
+    operand whose minor dim is under a lane tile, and the body wants each
+    head's scales as a ``[1, P]`` row."""
+    num_pages, P, H_kv = scales.shape
+    SC = -(-H_kv * P // LANES) * LANES
+    rows = scales.astype(jnp.float32).transpose(0, 2, 1).reshape(num_pages, 1, H_kv * P)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, SC - H_kv * P)))
+
+
+def _mesh_axes(mesh) -> tuple[int, int]:
+    """(tp, sp) of a mesh; (1, 1) of none."""
+    if mesh is None:
+        return 1, 1
+    axes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    return axes.get("tp", 1), axes.get("sp", 1)
+
+
+def _laid_scale_spec(sp: int):
+    from jax.sharding import PartitionSpec as P
+
+    return P(None, None, ("sp", "tp") if sp > 1 else "tp")
+
+
+def walk_scale_rows(scales: jax.Array, mesh=None) -> jax.Array:
+    """:func:`scale_rows` of every chip's own scales (KV heads over ``tp``,
+    a page's rows over ``sp``), for the walk's ``scales_laid=True``: what a
+    decode step does once, outside its layer scan, over the pool's scales
+    flattened over the layers, where the walk called a layer at a time with
+    ``[L * num_pages, P, H_kv]`` would do it ``L`` times."""
+    tp, sp = _mesh_axes(mesh)
+    if tp == 1 and sp == 1:
+        return scale_rows(scales)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(
+        scale_rows, mesh=mesh, in_specs=P(None, "sp" if sp > 1 else None, "tp"),
+        out_specs=_laid_scale_spec(sp), check_vma=False,
+    )(scales)
 
 
 def _kernel(
@@ -300,7 +354,7 @@ def _kernel(
 
 def _paged_state(
     q: jax.Array,  # [S, H, d]
-    k_pages: jax.Array,  # [num_pages, P_local, H_kv, d]
+    k_pages: jax.Array,  # [num_pages, P_local, H_kv * d] (or [.., H_kv, d])
     v_pages: jax.Array,
     block_tables: jax.Array,  # [S, max_pages] int32
     seq_lens: jax.Array,  # [S] int32
@@ -310,17 +364,19 @@ def _paged_state(
     k_scales: jax.Array | None = None,  # [num_pages, P_local, H_kv] f32
     v_scales: jax.Array | None = None,  # (int8 pages: per-row-per-head)
     head_dim: int | None = None,  # softmax scale's width where it is not d
-    kv_heads: int | None = None,  # of pages given merged, [num_pages, P_local, H_kv * d]
+    kv_heads: int | None = None,  # of pages given merged
+    scales_laid: bool = False,  # the scales are scale_rows' output already
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the kernel -> unnormalized (acc [S,H,d] f32, m [S,H], l [S,H]).
 
-    With ``k_scales``/``v_scales`` (natural [num_pages, P, H_kv] layout)
+    With ``k_scales``/``v_scales`` ([num_pages, P, H_kv] as the pool stores
+    them, or ``scales_laid``: [num_pages, 1, SC] from :func:`scale_rows`)
     the pages are int8 and the kernel DMAs each page's scale row alongside
     the page fetch; applying them in VMEM keeps int8's HBM-bandwidth win.
     """
     S, H, d = q.shape
-    # pages come [num_pages, P, H_kv, d], or already merged as the kernel
-    # reads them (a pool stored in that layout is never relaid)
+    # pages come merged as the kernel reads them, the layout every pool is
+    # stored in (never relaid), or one layer's [num_pages, P, H_kv, d]
     num_pages, P = k_pages.shape[:2]
     H_kv = k_pages.shape[2] if k_pages.ndim == 4 else kv_heads
     pack = heads_per_window(d, H_kv, k_scales is not None)
@@ -389,23 +445,14 @@ def _paged_state(
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
-        # A page's scale rows reach the kernel head-major and padded to
-        # whole 128-lane rows, [num_pages, 1, SC]: Mosaic cannot slice an
-        # HBM operand whose minor dim is under a lane tile, and the body
-        # wants each head's scales as a [1, P] row.
-        SC = -(-H_kv * P // 128) * 128
-
-        def scale_rows(scales):
-            rows = scales.astype(jnp.float32).transpose(0, 2, 1).reshape(
-                num_pages, 1, H_kv * P
-            )
-            return jnp.pad(rows, ((0, 0), (0, 0), (0, SC - H_kv * P)))
-
+        if not scales_laid:
+            k_scales, v_scales = scale_rows(k_scales), scale_rows(v_scales)
+        SC = k_scales.shape[2]
         scratch_shapes += [
             pltpu.VMEM((NBUF, 1, SC), jnp.float32),
             pltpu.VMEM((NBUF, 1, SC), jnp.float32),
         ]
-        operands += [scale_rows(k_scales), scale_rows(v_scales)]
+        operands += [k_scales, v_scales]
     scratch_shapes.append(pltpu.SemaphoreType.DMA((NBUF, 4 if quantized else 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -430,7 +477,7 @@ def _paged_state(
 
 def paged_decode_attention(
     q: jax.Array,  # [S, H, d]
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d]
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] with ``kv_heads``, or [.., H_kv, d]
     v_pages: jax.Array,
     block_tables: jax.Array,  # [S, max_pages] int32
     seq_lens: jax.Array,  # [S] int32 — valid tokens per slot (already written)
@@ -438,11 +485,12 @@ def paged_decode_attention(
     *,
     k_scales: jax.Array | None = None,  # [num_pages, P, H_kv] f32 — int8 pages
     v_scales: jax.Array | None = None,
+    kv_heads: int | None = None,
 ) -> jax.Array:
     """Attention over written pages only (the classic form)."""
     acc, _m, l = _paged_state(
         q, k_pages, v_pages, block_tables, seq_lens, interpret,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, kv_heads=kv_heads,
     )
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.astype(q.dtype)
@@ -477,7 +525,7 @@ def _fold_self_term(q, k_new, v_new, acc, m, l) -> jax.Array:
 
 def paged_decode_attention_cache_plus_new(
     q: jax.Array,  # [S, H, d]
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d] — WITHOUT the new token
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] (or [.., H_kv, d]) — WITHOUT the new token
     v_pages: jax.Array,
     block_tables: jax.Array,
     seq_lens: jax.Array,  # [S] — tokens valid in the PAGES (excl. new)
@@ -487,6 +535,7 @@ def paged_decode_attention_cache_plus_new(
     *,
     k_scales: jax.Array | None = None,  # [num_pages, P, H_kv] f32 — int8 pages
     v_scales: jax.Array | None = None,
+    scales_laid: bool = False,  # the scales come from walk_scale_rows
 ) -> jax.Array:
     """Kernel over the read-only pages + the new token's self term, merged
     outside the kernel. The new token's k/v stay full-precision (they are
@@ -494,28 +543,32 @@ def paged_decode_attention_cache_plus_new(
     acc, m, l = _paged_state(
         q, k_pages, v_pages, block_tables, seq_lens, interpret,
         k_scales=k_scales, v_scales=v_scales, kv_heads=k_new.shape[1],
+        scales_laid=scales_laid,
     )
     return _fold_self_term(q, k_new, v_new, acc, m, l)
 
 
-def _shard_wrap(fn, mesh, interpret, extra_sharded=(), with_scales=False):
+def _shard_wrap(fn, mesh, interpret, extra_sharded=(), with_scales=False, **kw):
+    """``fn`` over each chip's own KV heads: the merged row splits over
+    ``tp`` as ``H_kv / tp`` heads of ``d`` contiguous lanes, and a chip's
+    scales are its heads' whether as stored or laid out for the kernel."""
     from jax.sharding import PartitionSpec as P
 
     q_spec = P(None, "tp", None)
-    pages_spec = P(None, None, "tp", None)
+    pages_spec = P(None, None, "tp")
     in_specs = (q_spec, pages_spec, pages_spec, P(None, None), P(None)) + extra_sharded
     if with_scales:
-        # scale twins shard with the pages' KV-head axis; ``interpret`` sits
-        # before the scale params in the wrapped signatures, so map the two
-        # trailing positionals back to keywords instead of partial()ing
+        # ``interpret`` sits before the scale params in the wrapped
+        # signatures, so map the two trailing positionals back to keywords
+        # instead of partial()ing
         scale_spec = P(None, None, "tp")
         in_specs = in_specs + (scale_spec, scale_spec)
         body = lambda q, kp, vp, bt, sl, *rest: fn(  # noqa: E731
             q, kp, vp, bt, sl, *rest[:-2],
-            interpret=interpret, k_scales=rest[-2], v_scales=rest[-1],
+            interpret=interpret, k_scales=rest[-2], v_scales=rest[-1], **kw,
         )
     else:
-        body = functools.partial(fn, interpret=interpret)
+        body = functools.partial(fn, interpret=interpret, **kw)
     return jax.shard_map(
         body,
         mesh=mesh,
@@ -528,23 +581,25 @@ def _shard_wrap(fn, mesh, interpret, extra_sharded=(), with_scales=False):
 def paged_decode_attention_sharded(
     mesh,
     q: jax.Array,  # [S, H, d] — heads sharded over 'tp'
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d] — KV heads sharded over 'tp'
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] — the row's KV heads over 'tp'
     v_pages: jax.Array,
     block_tables: jax.Array,  # replicated
     seq_lens: jax.Array,  # replicated
     interpret: bool = False,
     *,
+    kv_heads: int,  # H_kv, of the whole mesh
     k_scales: jax.Array | None = None,  # [num_pages, P, H_kv] — heads over 'tp'
     v_scales: jax.Array | None = None,
 ) -> jax.Array:
     """tp>1 wrapper: GSPMD treats pallas_call as opaque, so we shard_map it —
     each shard runs the kernel over its local head slice (attention is
     head-parallel; page tables are shared), no collectives needed."""
+    local = kv_heads // _mesh_axes(mesh)[0]
     if k_scales is not None:
-        return _shard_wrap(paged_decode_attention, mesh, interpret, with_scales=True)(
-            q, k_pages, v_pages, block_tables, seq_lens, k_scales, v_scales
-        )
-    return _shard_wrap(paged_decode_attention, mesh, interpret)(
+        return _shard_wrap(
+            paged_decode_attention, mesh, interpret, with_scales=True, kv_heads=local
+        )(q, k_pages, v_pages, block_tables, seq_lens, k_scales, v_scales)
+    return _shard_wrap(paged_decode_attention, mesh, interpret, kv_heads=local)(
         q, k_pages, v_pages, block_tables, seq_lens
     )
 
@@ -552,7 +607,7 @@ def paged_decode_attention_sharded(
 def paged_decode_attention_cache_plus_new_sp_sharded(
     mesh,
     q: jax.Array,  # [S, H, d] — heads over 'tp', replicated over 'sp'
-    k_pages: jax.Array,  # [num_pages, P, H_kv, d] — P over 'sp', heads 'tp'
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] — P over 'sp', the row's heads 'tp'
     v_pages: jax.Array,
     block_tables: jax.Array,  # replicated
     seq_lens: jax.Array,  # replicated
@@ -562,6 +617,7 @@ def paged_decode_attention_cache_plus_new_sp_sharded(
     *,
     k_scales: jax.Array | None = None,  # [num_pages, P, H_kv] — P over 'sp',
     v_scales: jax.Array | None = None,  # heads over 'tp'
+    scales_laid: bool = False,
 ) -> jax.Array:
     """Context-parallel kernel wrapper: each sp rank holds a 1/sp slice of
     every page and runs the kernel over it (pos_base = rank * P_local, so
@@ -574,8 +630,7 @@ def paged_decode_attention_cache_plus_new_sp_sharded(
     like the pages ('sp' on rows, 'tp' on KV heads)."""
     from jax.sharding import PartitionSpec as P
 
-    axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    sp = axes.get("sp", 1)
+    sp = _mesh_axes(mesh)[1]
     P_global = k_pages.shape[1]
     P_local = P_global // sp
     quantized = k_scales is not None
@@ -587,6 +642,7 @@ def paged_decode_attention_cache_plus_new_sp_sharded(
             pos_base=pos_base, global_page_size=P_global,
             k_scales=scales[0] if scales else None,
             v_scales=scales[1] if scales else None,
+            kv_heads=kn.shape[1], scales_laid=scales_laid,
         )
         m_g = jax.lax.pmax(m, "sp")
         corr = jnp.exp(m - m_g)
@@ -595,13 +651,13 @@ def paged_decode_attention_cache_plus_new_sp_sharded(
         return _fold_self_term(q, kn, vn, acc_g, m_g, l_g)
 
     q_spec = P(None, "tp", None)
-    pages_spec = P(None, "sp", "tp", None)
+    pages_spec = P(None, "sp", "tp")
     new_spec = P(None, "tp", None)
     in_specs = (q_spec, pages_spec, pages_spec, P(None, None), P(None),
                 new_spec, new_spec)
     operands = [q, k_pages, v_pages, block_tables, seq_lens, k_new, v_new]
     if quantized:
-        scale_spec = P(None, "sp", "tp")
+        scale_spec = _laid_scale_spec(sp) if scales_laid else pages_spec
         in_specs = in_specs + (scale_spec, scale_spec)
         operands += [k_scales, v_scales]
     return jax.shard_map(
@@ -616,7 +672,7 @@ def paged_decode_attention_cache_plus_new_sp_sharded(
 def paged_decode_attention_cache_plus_new_sharded(
     mesh,
     q: jax.Array,
-    k_pages: jax.Array,
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] — the row's KV heads over 'tp'
     v_pages: jax.Array,
     block_tables: jax.Array,
     seq_lens: jax.Array,
@@ -626,14 +682,20 @@ def paged_decode_attention_cache_plus_new_sharded(
     *,
     k_scales: jax.Array | None = None,  # [num_pages, P, H_kv] f32 — int8 pages
     v_scales: jax.Array | None = None,
+    scales_laid: bool = False,
 ) -> jax.Array:
     from jax.sharding import PartitionSpec as P
 
-    axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if axes.get("sp", 1) > 1:
+    tp, sp = _mesh_axes(mesh)
+    if tp == 1 and sp == 1:  # no mesh, or one chip: the kernel as it is
+        return paged_decode_attention_cache_plus_new(
+            q, k_pages, v_pages, block_tables, seq_lens, k_new, v_new, interpret,
+            k_scales=k_scales, v_scales=v_scales, scales_laid=scales_laid,
+        )
+    if sp > 1:
         return paged_decode_attention_cache_plus_new_sp_sharded(
             mesh, q, k_pages, v_pages, block_tables, seq_lens, k_new, v_new,
-            interpret, k_scales=k_scales, v_scales=v_scales,
+            interpret, k_scales=k_scales, v_scales=v_scales, scales_laid=scales_laid,
         )
     new_spec = P(None, "tp", None)
     if k_scales is not None:
@@ -643,6 +705,7 @@ def paged_decode_attention_cache_plus_new_sharded(
             interpret,
             extra_sharded=(new_spec, new_spec),
             with_scales=True,
+            scales_laid=scales_laid,
         )(q, k_pages, v_pages, block_tables, seq_lens, k_new, v_new,
           k_scales, v_scales)
     return _shard_wrap(

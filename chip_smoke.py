@@ -480,8 +480,8 @@ def phase_four_chips(devices, seed: int) -> None:
     for dev, held in four["kv"].items():
         check(abs(held / kv_total - 0.25) < 0.01, phase,
               f"{dev} holds {held / kv_total:.3f} of the KV pool, not a quarter")
-    check(four["kv_shard"][3] == 1, phase,
-          f"KV shard {four['kv_shard']} is not one head per chip")
+    check(four["kv_shard"][3] == config.head_dim, phase,
+          f"KV shard {four['kv_shard']} is not one head's {config.head_dim} lanes of the row per chip")
     check(four["all_reduce"], phase, "no all-reduce in the tp=4 decode program")
     check(not one["all_reduce"], phase, "all-reduce in the one-chip decode program")
 

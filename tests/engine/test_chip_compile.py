@@ -56,9 +56,11 @@ def _walk_args(sharding, H, H_kv, d, dtype, int8):
     def sds(shape, dt, *spec):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding(P(*spec)))
 
+    # pages as every pool stores them: a row its KV heads side by side,
+    # split over tp as H_kv / tp heads of d contiguous lanes
     pages = sds(
-        (NUM_PAGES, PAGE, H_kv, d), jnp.int8 if int8 else dtype,
-        None, None, "tp", None,
+        (NUM_PAGES, PAGE, H_kv * d), jnp.int8 if int8 else dtype,
+        None, None, "tp",
     )
     new = sds((SLOTS, H_kv, d), dtype, None, "tp", None)
     args = [
@@ -152,6 +154,109 @@ def test_compiles_for_described_v5e(v5e, case):
     _, H, H_kv, d, dtype, int8, tp = case
     text = _compile_walk(v5e, H, H_kv, d, dtype, int8, tp).as_text()
     assert "tpu_custom_call" in text, "the Pallas page walk is not in the program"
+
+
+# -- the llama decode step over the pool stored as the walk reads it -----------
+
+# (id, preset, widths the preset lacks, the cell's pages, slots, tp)
+_QWEN_32B = dict(dim=5120, n_heads=40, n_kv_heads=8, ffn_dim=27648)
+_DECODE_STEPS = [
+    ("qwen2.5-7b-v5e1", {}, 3585, 32, 1),
+    ("qwen2.5-32b-v5e4-tp4", _QWEN_32B, 2689, 24, 4),
+]
+_DEPTH = 4  # of 28 / 64 layers: the scan's body is compiled once whatever the depth
+
+
+def _compile_llama_decode_step(v5e, widths, pages, slots, tp, int8_pages):
+    """`models.llama.decode_step_paged` with the Pallas walk at a Qwen2.5
+    configuration's widths (int8 weights, depth cut to `_DEPTH`), over a
+    pool of the cell's pages, on one described chip or a tp mesh of them."""
+    import dataclasses
+
+    from agentcontrolplane_tpu.models.llama import decode_step_paged, init_paged_cache, init_params
+    from agentcontrolplane_tpu.ops.quant import QuantizedTensor, quantize_params
+    from agentcontrolplane_tpu.parallel.mesh import param_shardings
+
+    c = dataclasses.replace(PRESETS["qwen2.5-7b"], n_layers=_DEPTH, **widths)
+    mesh = Mesh(v5e[:tp], ("tp",))
+    named = lambda *spec: NamedSharding(mesh, P(*spec))  # noqa: E731
+    plain = jax.eval_shape(lambda: init_params(c, jax.random.key(0)))
+    params = jax.eval_shape(lambda: quantize_params(init_params(c, jax.random.key(0))))
+
+    def int8_leaf(sharding, leaf):  # values as the matrix; scales [.., 1, out] keep the output axis
+        if not isinstance(leaf, QuantizedTensor):
+            return sharding
+        spec = tuple(sharding.spec) + (None,) * (leaf.q.ndim - len(sharding.spec))
+        return QuantizedTensor(q=sharding, scale=named(*spec[:-2], None, spec[-1]))
+
+    shardings = jax.tree_util.tree_map(
+        int8_leaf, param_shardings(mesh, c, plain), params, is_leaf=lambda x: isinstance(x, NamedSharding))
+    place = lambda tree, sh: jax.tree_util.tree_map(  # noqa: E731
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree, sh)
+    cache = jax.eval_shape(lambda: init_paged_cache(c, pages, PAGE, quantize_kv=int8_pages))
+    # engine.py's page_spec: axis 3 (the row's heads, or a scale a head) over tp
+    cache = place(cache, {name: named(None, None, None, "tp") for name in cache})
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=named())  # noqa: E731
+    compiled = jax.jit(
+        lambda p, ca, tok, n, tables, active: decode_step_paged(
+            p, ca, tok, n, tables, active, c, use_pallas=True, mesh=mesh),
+        donate_argnums=(1,),
+    ).lower(place(params, shardings), cache, vec(slots), vec(slots), vec(slots, 1792 // PAGE),
+            vec(slots, dt=jnp.bool_)).compile()
+    return c, compiled
+
+
+def _computations(hlo: str) -> dict:
+    """An HLO module's text split into its computations, by name."""
+    import re
+
+    out, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = ("ENTRY " if head.group(1) else "") + head.group(2)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("int8_pages", [False, True], ids=["bf16-pages", "int8-pages"])
+@pytest.mark.parametrize("case", _DECODE_STEPS, ids=lambda c: c[0])
+def test_llama_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, case, int8_pages):
+    """The pool is stored `[L, pages, P, H_kv * d]` and handed to the walk
+    whole, flattened over its layers, with block tables offset by the
+    layer: the compiled step holds no value of one layer's pool (the old
+    layout's `reshape`, `dynamic-slice` and, at tp=4, `copy` of the stacked
+    pool: 42-46% of a decode step, PERF.md PR 33) and its temporaries are a
+    fraction of the pool. int8 pages: the scale rows are laid out for the
+    kernel once a step, outside the layer scan, not once a layer over the
+    whole pool's scales."""
+    import re
+
+    _, widths, pages, slots, tp = case
+    c, compiled = _compile_llama_decode_step(v5e, widths, pages, slots, tp, int8_pages)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1, "one walk, in the layer scan's body"
+    assert "paged_page_walk" in text, "the benchmark's readers find the kernel by this name"
+    heads, width = c.n_kv_heads // tp, c.n_kv_heads // tp * c.head_dim
+    kv = "s8" if int8_pages else "bf16"
+    for shape in (f"[{pages},{PAGE},{width}]", f"[1,{pages},{PAGE},{width}]", f"[{pages},{PAGE},{heads},{c.head_dim}]",
+                  f"[1,{pages},{PAGE},{heads},{c.head_dim}]", f"[{_DEPTH},{pages},{PAGE},{heads},{c.head_dim}]"):
+        assert f"{kv}{shape}" not in text, f"a value of a layer's pool or of the five-axis pool: {kv}{shape}"
+    pool = 2 * _DEPTH * pages * PAGE * width * (1 if int8_pages else 2)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if int8_pages:
+        # the token commit of the scale twins is made in a copy whose four
+        # (two at tp=4) scales a row are padded to a lane tile, as on the
+        # parent (PERF.md section 7): known, and not the pool's K or V
+        temp -= _DEPTH * pages * PAGE * 128 * 4
+        rows = re.compile(rf"= f32\[{_DEPTH * pages},1,\d+\]\S* (copy|transpose|pad|fusion|reshape|bitcast)\(")
+        inside = [name for name, lines in _computations(text).items()
+                  if not name.startswith("ENTRY") and any(rows.search(line) for line in lines)]
+        assert not inside, f"scale rows laid out inside a loop's body: {inside}"
+        assert re.search(rf"f32\[{_DEPTH * pages},1,\d+\]", text), "the kernel is handed no laid-out scale rows"
+    assert temp < pool // 4, f"temporaries {temp / 1e6:.0f} MB beside a {pool / 1e6:.0f} MB pool"
 
 
 # -- the grouped expert matmul and the model that runs it ---------------------

@@ -12,7 +12,7 @@ from agentcontrolplane_tpu.ops.paged import HostKVEntry, HostKVPool, PageAllocat
 
 
 def entry(rid: str, n_tokens: int, toks=None) -> HostKVEntry:
-    shape = (2, n_tokens, 2, 4)  # [L, T, H_kv, d]
+    shape = (2, n_tokens, 2 * 4)  # [L, T, H_kv * d]: rows as the paged pool holds them
     return HostKVEntry(
         rid=rid,
         tokens=tuple(toks if toks is not None else range(n_tokens)),

@@ -49,6 +49,12 @@ def _setup(seed=0, S=3, H=4, Hkv=2, d=8, P=4, max_pages=6, num_pages=32):
     )
 
 
+def _merged(pages):
+    """One layer's pages as a pool stores them: a row's KV heads side by
+    side, ``[num_pages, P, H_kv * d]`` (what the sharded wrappers take)."""
+    return pages.reshape(*pages.shape[:2], -1)
+
+
 def test_reference_paged_matches_slot_attention():
     q, k_pages, v_pages, tables, seq_lens, (k_slot, v_slot) = _setup()
     dense = decode_attention(q, k_slot, v_slot, seq_lens)
@@ -78,9 +84,11 @@ def test_write_token_and_prompt_roundtrip():
     k_pages, v_pages = pages["k"][0], pages["v"][0]
     rng = np.random.default_rng(0)
 
+    assert k_pages.shape == (16, P, Hkv * d)  # a row holds its KV heads side by side
+
     # prompt of 6 tokens -> pages [3, 5] (2 pages, second half-filled)
-    prompt_k = jnp.asarray(rng.normal(size=(8, Hkv, d)), dtype=jnp.float32)
-    prompt_v = jnp.asarray(rng.normal(size=(8, Hkv, d)), dtype=jnp.float32)
+    prompt_k = jnp.asarray(rng.normal(size=(8, Hkv * d)), dtype=jnp.float32)
+    prompt_v = jnp.asarray(rng.normal(size=(8, Hkv * d)), dtype=jnp.float32)
     page_ids = jnp.asarray([3, 5], dtype=jnp.int32)
     k_pages, v_pages = write_prompt_to_pages(k_pages, v_pages, page_ids, prompt_k, prompt_v)
     np.testing.assert_array_equal(np.asarray(k_pages[3]), np.asarray(prompt_k[:4]))
@@ -88,8 +96,8 @@ def test_write_token_and_prompt_roundtrip():
 
     # decode token at position 6 for slot with table [3,5] -> page 5 offset 2
     tables = jnp.asarray([[3, 5, 0]], dtype=jnp.int32)
-    tok_k = jnp.asarray(rng.normal(size=(1, Hkv, d)), dtype=jnp.float32)
-    tok_v = jnp.asarray(rng.normal(size=(1, Hkv, d)), dtype=jnp.float32)
+    tok_k = jnp.asarray(rng.normal(size=(1, Hkv * d)), dtype=jnp.float32)
+    tok_v = jnp.asarray(rng.normal(size=(1, Hkv * d)), dtype=jnp.float32)
     k_pages, v_pages = write_token_to_pages(
         k_pages, v_pages, tables, jnp.asarray([6]), jnp.asarray([True]), tok_k, tok_v
     )
@@ -187,7 +195,7 @@ def test_pallas_cache_plus_new_sharded_tp2_interpret():
         q, k_pages, v_pages, tables, seq_lens, k_new, v_new
     )
     out = paged_decode_attention_cache_plus_new_sharded(
-        mesh, q, k_pages, v_pages, tables, seq_lens, k_new, v_new, interpret=True
+        mesh, q, _merged(k_pages), _merged(v_pages), tables, seq_lens, k_new, v_new, interpret=True
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
@@ -207,7 +215,8 @@ def test_pallas_kernel_sharded_tp2_interpret():
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
     ref = paged_decode_attention_reference(q, k_pages, v_pages, tables, seq_lens)
     out = paged_decode_attention_sharded(
-        mesh, q, k_pages, v_pages, tables, seq_lens, interpret=True
+        mesh, q, _merged(k_pages), _merged(v_pages), tables, seq_lens, interpret=True,
+        kv_heads=k_pages.shape[2],
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
@@ -238,7 +247,7 @@ def test_pallas_cache_plus_new_sp_sharded_interpret():
         n = axes["sp"] * axes["tp"]
         mesh = make_mesh(axes, devices=jax.devices()[:n])
         out = paged_decode_attention_cache_plus_new_sharded(
-            mesh, q, k_pages, v_pages, tables, seq_lens, k_new, v_new,
+            mesh, q, _merged(k_pages), _merged(v_pages), tables, seq_lens, k_new, v_new,
             interpret=True,
         )
         np.testing.assert_allclose(
@@ -434,6 +443,125 @@ def test_sp_slices_walk_a_tile_a_turn_interpret():
     )
     mesh = make_mesh({"sp": 2, "tp": 1}, devices=jax.devices()[:2])
     out = paged_decode_attention_cache_plus_new_sharded(
-        mesh, q, k_pages, v_pages, tables, seq_lens, k_new, v_new, interpret=True
+        mesh, q, _merged(k_pages), _merged(v_pages), tables, seq_lens, k_new, v_new, interpret=True
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+# -- the programs over the pool stored as the walk reads it --------------------
+#
+# `[L, pages, P, H_kv * d]`, read and written through the pool flattened over
+# its layers with page ids offset by the layer (ops/paged.py). Every page no
+# block table names is NaN in every layer (int8 pages: its scales are), so a
+# read through a wrong layer offset or a wrong page fails loudly, and a write
+# that lands anywhere else is seen where the NaNs are counted afterwards. The
+# reference is the model's plain causal forward over the whole sequence: it
+# knows no pool.
+
+_P, _M, _LAYERS, _POOL_PAGES = 8, 4, 3, 24
+_MESHES = {"one-device": None, "tp2": {"tp": 2}, "sp2": {"sp": 2, "tp": 1}}
+
+
+def _pool_case(mesh_axes, int8_pages):
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as Spec
+
+    from agentcontrolplane_tpu.models import llama
+    from agentcontrolplane_tpu.parallel.mesh import make_mesh, param_shardings
+
+    c = dataclasses.replace(llama.PRESETS["tiny"], n_layers=_LAYERS)
+    params = llama.init_params(c, jax.random.key(7))
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(1, c.vocab_size, size=(2, _M * _P)).astype(np.int32)
+    # scattered pages in table order; the second sequence never needs its fourth
+    ids = rng.permutation(np.arange(1, _POOL_PAGES))[:7]
+    tables = np.asarray([ids[:4], list(ids[4:7]) + [TRASH_PAGE]], dtype=np.int32)
+    named = np.zeros(_POOL_PAGES, bool)
+    named[tables.reshape(-1)] = True
+    pool = llama.init_paged_cache(c, _POOL_PAGES, _P, quantize_kv=int8_pages)
+    assert pool["k"].shape == (_LAYERS, _POOL_PAGES, _P, c.n_kv_heads * c.head_dim)
+    poisoned = ("ks", "vs") if int8_pages else ("k", "v")
+    for name in poisoned:
+        pool[name] = pool[name].at[:, ~named].set(jnp.nan)
+    mesh = None
+    if mesh_axes is not None:
+        n = int(np.prod(list(mesh_axes.values())))
+        mesh = make_mesh(mesh_axes, devices=jax.devices()[:n])
+        page_sh = NamedSharding(mesh, Spec(None, None, "sp" if "sp" in mesh_axes else None, "tp"))
+        pool = {name: jax.device_put(a, page_sh) for name, a in pool.items()}
+        params = jax.device_put(params, param_shardings(mesh, c, params))
+    want = np.asarray(llama.forward(llama.init_params(c, jax.random.key(7)), jnp.asarray(tokens), c))
+    return c, params, pool, tokens, tables, named, poisoned, mesh, want
+
+
+def _rows(tokens, starts, lengths, T):
+    out = np.zeros((len(starts), T), np.int32)
+    for b, (s, n) in enumerate(zip(starts, lengths)):
+        out[b, :n] = tokens[b, s:s + n]
+    return jnp.asarray(out)
+
+
+@pytest.mark.parametrize("walk", ["xla-gather", "pallas-interpret"])
+@pytest.mark.parametrize("int8_pages", [False, True], ids=["f32-pages", "int8-pages"])
+@pytest.mark.parametrize("mesh_axes", list(_MESHES.values()), ids=list(_MESHES))
+def test_programs_through_the_merged_pool_match_the_plain_forward(mesh_axes, int8_pages, walk, monkeypatch):
+    """Prefill, continuation, verify and two decode steps of two sequences,
+    each program's logits against the plain forward at the same positions,
+    on one device, tp=2 and sp=2, the decode walk by the XLA gather and by
+    the kernel (interpret mode)."""
+    import functools
+
+    from agentcontrolplane_tpu.models import llama
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    name = "paged_decode_attention_cache_plus_new_sharded"  # the one entry the model calls, on any mesh
+    monkeypatch.setattr(pa, name, functools.partial(getattr(pa, name), interpret=True))
+    c, params, pool, tokens, tables, named, poisoned, mesh, want = _pool_case(mesh_axes, int8_pages)
+    tb = jnp.asarray(tables)
+    i32 = lambda *a: jnp.asarray(a, jnp.int32)  # noqa: E731
+    # int8 pages round every K and V a row and head: the gate's tolerance, not the exact one
+    close = functools.partial(np.testing.assert_allclose, rtol=0, atol=0.12 if int8_pages else 2e-4)
+
+    # whole prompts of 16 and 8 tokens (rows padded to 16), two pages and one
+    n0 = np.asarray([16, 8])
+    page_ids = np.where(np.arange(2)[None, :] * _P < n0[:, None], tables[:, :2], TRASH_PAGE)
+    pool, logits = jax.jit(lambda p, kv, *a: llama.prefill_paged_batch(p, kv, *a, c))(
+        params, pool, _rows(tokens, [0, 0], n0, 16), i32(*n0), jnp.asarray(page_ids))
+    close(np.asarray(logits), want[np.arange(2), n0 - 1], err_msg="prefill")
+
+    # continuations from the page-aligned ends: 7 and 5 tokens, one page each
+    n1 = np.asarray([7, 5])
+    pool, logits = jax.jit(lambda p, kv, *a: llama.prefill_paged_continue(p, kv, *a, c))(
+        params, pool, _rows(tokens, n0, n1, _P), i32(*n1), i32(*n0),
+        jnp.asarray(tables[np.arange(2), n0 // _P][:, None]), tb)
+    close(np.asarray(logits), want[np.arange(2), n0 + n1 - 1], err_msg="continuation")
+
+    # a verify pass from mid-page (23 and 13): 3 and 2 tokens, a token-row commit
+    s2, n2 = n0 + n1, np.asarray([3, 2])
+    pool, logits = jax.jit(lambda p, kv, *a: llama.verify_paged_continue(p, kv, *a, c))(
+        params, pool, _rows(tokens, s2, n2, 4), i32(*n2), i32(*s2), tb)
+    for b in range(2):
+        close(np.asarray(logits)[b, :n2[b]], want[b, s2[b]:s2[b] + n2[b]], err_msg=f"verify row {b}")
+
+    # two decode steps; seq 0 crosses into its fourth page at 26 -> 24..31 is page 3
+    seq = s2 + n2
+    step = jax.jit(lambda p, kv, t, n, a: llama.decode_step_paged(
+        p, kv, t, n, tb, a, c, use_pallas=walk == "pallas-interpret", mesh=mesh))
+    for j in range(2):
+        pool, logits = step(params, pool, jnp.asarray(tokens[np.arange(2), seq + j]), i32(*(seq + j)),
+                            jnp.ones((2,), bool))
+        close(np.asarray(logits), want[np.arange(2), seq + j], err_msg=f"decode step {j}")
+    # an inactive lane writes the trash page and nothing else
+    before = {name: np.asarray(a) for name, a in pool.items()}
+    pool, _ = step(params, pool, jnp.asarray(tokens[np.arange(2), seq + 2]), i32(*(seq + 2)),
+                   jnp.asarray([True, False]))
+    for name, a in pool.items():
+        a = np.asarray(a)
+        lane1 = tables[1][tables[1] != TRASH_PAGE]
+        np.testing.assert_array_equal(a[:, lane1], before[name][:, lane1], err_msg=f"{name}: an inactive lane's pages")
+    # nothing was written to a page no table names, in any layer
+    for name in poisoned:
+        a = np.asarray(pool[name])
+        assert np.isnan(a[:, ~named]).all(), f"{name}: a write landed on an unnamed page"
+        assert np.isfinite(a[:, named & (np.arange(_POOL_PAGES) != TRASH_PAGE)]).all(), name

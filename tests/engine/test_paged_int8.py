@@ -26,6 +26,7 @@ from agentcontrolplane_tpu.ops.pallas.paged_attention import (
     paged_decode_attention_cache_plus_new,
     paged_decode_attention_cache_plus_new_sharded,
     paged_decode_attention_sharded,
+    walk_scale_rows,
 )
 from agentcontrolplane_tpu.ops.quant import kv_quantize
 
@@ -38,6 +39,12 @@ def _quantize_pages(k_pages, v_pages):
     kq, ks = kv_quantize(k_pages)
     vq, vs = kv_quantize(v_pages)
     return kq, vq, ks, vs
+
+
+def merged(pages):
+    """One layer's pages as a pool stores them: a row its KV heads side by
+    side, ``[num_pages, P, H_kv * d]`` (what the sharded wrappers take)."""
+    return pages.reshape(*pages.shape[:2], -1)
 
 
 def _setup_int8(**kw):
@@ -158,8 +165,8 @@ def test_int8_walk_sharded_tp2_interpret():
         q, kq, vq, tables, seq_lens, k_scales=ks, v_scales=vs
     )
     out = paged_decode_attention_sharded(
-        mesh, q, kq, vq, tables, seq_lens, interpret=True,
-        k_scales=ks, v_scales=vs,
+        mesh, q, merged(kq), merged(vq), tables, seq_lens, interpret=True,
+        kv_heads=kq.shape[2], k_scales=ks, v_scales=vs,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
@@ -186,10 +193,17 @@ def test_int8_cache_plus_new_sharded_tp_and_sp_interpret():
             pytest.skip(f"needs {n} devices")
         mesh = make_mesh(axes, devices=jax.devices()[:n])
         out = paged_decode_attention_cache_plus_new_sharded(
-            mesh, q, kq, vq, tables, seq_lens, k_new, v_new, interpret=True,
+            mesh, q, merged(kq), merged(vq), tables, seq_lens, k_new, v_new, interpret=True,
             k_scales=ks, v_scales=vs,
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5,
             err_msg=str(axes),
         )
+        # the scales laid out for the kernel once, outside the caller's
+        # layer scan (what decode_step_paged does), read the same
+        laid = paged_decode_attention_cache_plus_new_sharded(
+            mesh, q, merged(kq), merged(vq), tables, seq_lens, k_new, v_new, interpret=True,
+            k_scales=walk_scale_rows(ks, mesh), v_scales=walk_scale_rows(vs, mesh), scales_laid=True,
+        )
+        np.testing.assert_array_equal(np.asarray(laid), np.asarray(out), err_msg=str(axes))

@@ -292,8 +292,12 @@ def test_off_knobs_carry_no_scale_storage():
 
 def test_quantized_cache_layout_pinned():
     """The quantized cache's dtypes/shapes are the documented contract:
-    int8 values + f32 scale twins shaped values-minus-head_dim, both
-    layouts."""
+    int8 values + f32 scale twins, one scale a row and KV head: the slot
+    cache keeps the heads apart ([L, S, C, H_kv, d]), the paged pool holds
+    a row's heads side by side ([L, pages, P, H_kv * d], as the page walk
+    reads it)."""
+    import math
+
     for layout in ("slot", "paged"):
         eng = make_engine(layout)
         try:
@@ -301,9 +305,9 @@ def test_quantized_cache_layout_pinned():
             for name in ("k", "v"):
                 assert eng.cache[name].dtype == jnp.int8
                 assert eng.cache[name + "s"].dtype == jnp.float32
-                assert (
-                    tuple(eng.cache[name + "s"].shape)
-                    == tuple(eng.cache[name].shape[:-1])
-                )
+                val, c = eng.cache[name], eng.config
+                assert tuple(eng.cache[name + "s"].shape) == tuple(val.shape[:3]) + (c.n_kv_heads,)
+                assert math.prod(val.shape[3:]) == c.n_kv_heads * c.head_dim
+                assert val.ndim == {"slot": 5, "paged": 4}[layout]
         finally:
             eng.stop()

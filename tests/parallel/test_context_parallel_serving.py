@@ -186,7 +186,8 @@ def test_decode_step_paged_sp_sharded_matches_replicated_and_no_allgather():
         params, pages, tokens, seq_lens_j, tables, active
     )
 
-    page_spec = NamedSharding(mesh, P(None, None, "sp", "tp", None))
+    assert len(shape) == 4  # [L, pages, P, H_kv * d]: the row's heads over tp, a page's rows over sp
+    page_spec = NamedSharding(mesh, P(None, None, "sp", "tp"))
     pg_shard = {"k": page_spec, "v": page_spec}
     p_shard = param_shardings(mesh, cfg, params)
     rep = NamedSharding(mesh, P())
