@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test test-unit test-e2e test-stress bench smoke run run-multi lint lint-acp \
+.PHONY: test test-unit test-e2e test-stress smoke run run-multi lint lint-acp \
 	chaos-smoke chaos-soak \
 	dryrun ci docker-build docker-run observability-up observability-down
 
@@ -37,11 +37,6 @@ test-stress:
 smoke:  ## does provider: tpu still start on the chip? (one TPU; exits non-zero without one)
 	$(PY) chip_smoke.py
 
-# requires the tpu backend: with no chip it exits non-zero and prints no
-# number. The cell matrix the driver records is ROADMAP A1, not this file.
-bench:
-	$(PY) bench.py
-
 chaos-smoke:  ## one seeded fault cocktail against a live 3-replica fleet, invariants gated (fast CI tier)
 	$(PY) -m agentcontrolplane_tpu.cli chaos --seed 3 --gate --replicas 3 --speed 20 \
 	  --set n=8 --tpu-preset tiny --tpu-slots 4 --tpu-ctx 64 --tpu-kv-layout paged --no-prewarm
@@ -64,10 +59,10 @@ run-multi:  ## two-replica dev control plane: owner serves the store, follower j
 
 lint:
 	@if command -v ruff >/dev/null 2>&1; then \
-		ruff check agentcontrolplane_tpu tests bench.py; \
+		ruff check agentcontrolplane_tpu tests; \
 	else \
 		echo "ruff not installed; falling back to compileall (syntax only)"; \
-		$(PY) -m compileall -q agentcontrolplane_tpu tests bench.py; \
+		$(PY) -m compileall -q agentcontrolplane_tpu tests; \
 	fi
 
 # pinned gates: ACP_LINT_SUPPRESSIONS is the live '# acp-lint: disable='
@@ -84,8 +79,6 @@ lint-acp:  ## repo-custom static analysis (acplint) — the engine's correctness
 		--timing --timing-budget $(ACP_LINT_BUDGET_S) \
 		--suppression-budget $(ACP_LINT_SUPPRESSIONS) \
 		--json acplint-findings.json \
-		agentcontrolplane_tpu tests bench.py
-	-$(PY) -m agentcontrolplane_tpu.analysis --bench-trend .  # advisory: perf-trajectory sentinel
-	-$(PY) -m agentcontrolplane_tpu.analysis --slo-envelopes .  # advisory: scenario SLO envelopes
+		agentcontrolplane_tpu tests
 
 ci: lint lint-acp test dryrun

@@ -126,40 +126,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         "switchboard consumption site in the package (both drift "
         "directions fail)",
     )
-    ap.add_argument(
-        "--bench-trend",
-        nargs="?",
-        const=str(_PACKAGE_ROOT.parent),
-        default=None,
-        metavar="DIR",
-        help="bench-trajectory sentinel: normalize every BENCH_PR*.json "
-        "under DIR (default: the repo root) into one trend table and exit "
-        "nonzero on a regression past a per-metric tolerance (advisory in "
-        "CI; see analysis/bench_trend.py)",
-    )
-    ap.add_argument(
-        "--slo-envelopes",
-        nargs="?",
-        const=str(_PACKAGE_ROOT.parent),
-        default=None,
-        metavar="DIR",
-        help="scenario SLO gate: judge the newest BENCH_PR*.json under DIR "
-        "(default: the repo root) that carries scenario blocks against the "
-        "per-scenario envelopes and exit nonzero on a violation (advisory "
-        "in CI, like --bench-trend; see analysis/slo_gate.py)",
-    )
     args = ap.parse_args(argv)
-    if args.bench_trend is not None:
-        # trend mode is exclusive: the lint gates run in their own step
-        from .bench_trend import main as trend_main
-
-        return trend_main(args.bench_trend)
-    if args.slo_envelopes is not None:
-        # envelope mode is exclusive for the same reason
-        from .slo_gate import main as slo_main
-
-        return slo_main(args.slo_envelopes)
-
     want_timing = args.timing or args.timing_budget is not None
     rules = tuple(args.rule) if args.rule else RULES
     paths = args.paths or [str(_PACKAGE_ROOT)]

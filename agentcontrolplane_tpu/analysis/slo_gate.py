@@ -1,17 +1,14 @@
-"""slo-gate: per-scenario SLO envelopes over the bench docs' scenario
-blocks.
+"""slo-gate: per-scenario SLO envelopes over a scenario run's summary.
 
-``bench.py``'s ``ACP_BENCH_SCENARIOS`` section replays the scenario
-library (scenarios/library.py) against a single engine and a fleet pool
-and writes each run's SLO summary (``ReplayReport.slo_doc()``) into the
-PR's ``BENCH_PR*.json`` under ``scenarios.<name>.<single|fleet>``. This
-gate judges the NEWEST doc carrying scenario blocks against per-scenario
-envelopes.
+``acp-tpu replay`` (cli.py) and the chaos drill (scenarios/chaos.py)
+replay the scenario library (scenarios/library.py) and hand each run's
+SLO summary (``ReplayReport.slo_doc()``) to ``check_block``, which judges
+it against the scenario's envelope.
 
 Envelope philosophy: CPU-fixture latency numbers are noise, so absolute
 latency ceilings are deliberately loose (they catch order-of-magnitude
-cliffs, not percent drift — ``--bench-trend`` owns the drift story). What
-the gate holds TIGHT is structure, which is platform-independent:
+cliffs, not percent drift). What the gate holds TIGHT is structure, which
+is platform-independent:
 
 - request conservation — every replayed request accounted for exactly once
   across completed/shed/cancelled/expired/error
@@ -23,19 +20,13 @@ the gate holds TIGHT is structure, which is platform-independent:
   actually cancelled and expired; a tool swarm surfaced tool calls; a
   fault cocktail still completed the healthy majority
 
-Advisory in CI and ``make lint-acp`` (same posture as ``--bench-trend``):
-a trip is a prompt to look at the scenario run, not a merge blocker.
-Stdlib-only, like the rest of ``analysis/`` — runs from a bare checkout
-via ``python -m agentcontrolplane_tpu.analysis --slo-envelopes [DIR]``.
+Stdlib-only, like the rest of ``analysis/``.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from pathlib import Path
 from typing import Any, Optional
-
-from .bench_trend import load_docs
 
 
 @dataclass(frozen=True)
@@ -175,66 +166,4 @@ def check_block(
     return out
 
 
-def check_doc(doc: dict[str, Any]) -> tuple[list[str], list[SLOViolation]]:
-    """(table lines, violations) for one bench doc's ``scenarios`` map."""
-    lines: list[str] = []
-    violations: list[SLOViolation] = []
-    scenarios = doc.get("scenarios")
-    if not isinstance(scenarios, dict) or not scenarios:
-        return ["slo-gate: doc has no scenario blocks"], []
-    header = (
-        f"{'scenario':<16}{'arm':<8}{'req':>5}{'done':>6}{'shed':>6}"
-        f"{'ttft p50':>10}{'ttft p99':>10}{'stall p99':>11}{'goodput':>9}"
-    )
-    lines.append(header)
-    for name in sorted(scenarios):
-        arms = scenarios[name]
-        if not isinstance(arms, dict):
-            continue
-        for arm in sorted(arms):
-            block = arms[arm]
-            if not isinstance(block, dict):
-                continue
-            goodput = block.get("goodput_ratio")
-            lines.append(
-                f"{name:<16}{arm:<8}"
-                f"{int(block.get('requests') or 0):>5}"
-                f"{int(block.get('completed') or 0):>6}"
-                f"{int(block.get('shed') or 0):>6}"
-                f"{float(block.get('ttft_p50_ms') or 0):>10.1f}"
-                f"{float(block.get('ttft_p99_ms') or 0):>10.1f}"
-                f"{float(block.get('decode_stall_p99_ms') or 0):>11.1f}"
-                + (f"{float(goodput):>9.3f}" if goodput is not None else f"{'-':>9}")
-            )
-            violations.extend(check_block(name, arm, block))
-    return lines, violations
-
-
-def main(root: str | Path) -> int:
-    """CLI body for ``--slo-envelopes``: judge the newest bench doc that
-    carries scenario blocks; exit 1 when any envelope tripped."""
-    docs = load_docs(root)
-    with_scenarios = [
-        (pr, name, doc) for pr, name, doc in docs
-        if isinstance(doc.get("scenarios"), dict) and doc["scenarios"]
-    ]
-    if not with_scenarios:
-        print("slo-gate: no bench doc with scenario blocks found (run "
-              "ACP_BENCH_SCENARIOS=1 python bench.py first)")
-        return 0
-    pr, name, doc = with_scenarios[-1]
-    lines, violations = check_doc(doc)
-    print(f"slo-gate: judging {name}")
-    for line in lines:
-        print(line)
-    if violations:
-        print(f"slo-gate: {len(violations)} envelope violation(s):")
-        for v in violations:
-            print(f"  {v}")
-        return 1
-    print("slo-gate: every scenario inside its envelope")
-    return 0
-
-
-__all__ = ["Envelope", "ENVELOPES", "SLOViolation", "check_block",
-           "check_doc", "main"]
+__all__ = ["Envelope", "ENVELOPES", "SLOViolation", "check_block"]

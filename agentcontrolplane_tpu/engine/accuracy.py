@@ -16,11 +16,9 @@ asserts
 - **logit MAE** — mean |quantized - bf16| over the fixture's logits —
   stays <= a pinned bound.
 
-Tests pin the thresholds (tests/engine/test_quant_kv.py); the bench
-fixture (``ACP_BENCH_QUANT=1``) records the measured numbers into the
-PR's bench doc so the accuracy trajectory is inspectable next to the
-capacity multiplier it buys. Both knobs off remains covered by the
-existing byte-identity matrix — this gate never relaxes that.
+Tests pin the thresholds (tests/engine/test_quant_kv.py). Both knobs off
+remains covered by the existing byte-identity matrix — this gate never
+relaxes that.
 """
 
 from __future__ import annotations
@@ -139,8 +137,8 @@ def check_accuracy_gate(
     report: dict, min_top1: float, max_logit_mae: float
 ) -> list[str]:
     """Evaluate a report against pinned thresholds; returns violations
-    (empty = the gate passes). Split from :func:`accuracy_report` so the
-    bench fixture can record the numbers AND the gate verdict."""
+    (empty = the gate passes). Split from :func:`accuracy_report` so a
+    caller can keep the numbers AND the gate verdict."""
     problems: list[str] = []
     if report["top1_agreement"] < min_top1:
         problems.append(

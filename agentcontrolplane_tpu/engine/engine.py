@@ -6071,24 +6071,6 @@ class Engine:
 
     # -- KV memory tiers: host-RAM offload + shared-prefix dedup ----------
 
-    def set_host_kv_bytes(self, n: int) -> None:
-        """Resize (0 = disable) the host KV tier. Idle-engine callers only
-        (benches/tests A/B the knob on one warmed engine); shrinking LRU-
-        evicts entries beyond the new budget."""
-        from ..ops.paged import HostKVPool
-
-        self.host_kv_bytes = max(0, int(n))
-        if not self.host_kv_bytes:
-            self._host_pool = None
-        elif self._host_pool is None:
-            self._host_pool = HostKVPool(self.host_kv_bytes)
-        else:
-            pool = self._host_pool
-            pool.max_bytes = self.host_kv_bytes
-            while pool.used_bytes > pool.max_bytes and len(pool):
-                pool.pop(next(iter(pool._entries)))
-        self._publish_memory_state()
-
     def inject_host_kv(self, entry) -> bool:
         """Land a :class:`HostKVEntry` in this engine's host-KV tier
         (thread-safe; the fleet router's prefill→decode handoff path).

@@ -188,13 +188,12 @@ class DispatchProfiler:
     events so serving-time compiles appear inline with the scheduler
     decisions that caused them."""
 
-    # A/B caveat: `enabled` is a plain mutable attribute (benches toggle it
+    # A/B caveat: `enabled` is a plain mutable attribute (tests toggle it
     # on a live engine). A program whose FIRST dispatch lands inside a
     # disabled window is never registered, so it would read as a cold
     # compile when re-enabled after mark_prewarmed() — toggle only on
-    # warmed engines whose program zoo is already registered (the shipped
-    # bench fixture runs its profiler-on warm-up leg first for exactly
-    # this reason), or re-baseline with a fresh profiler.
+    # warmed engines whose program zoo is already registered, or
+    # re-baseline with a fresh profiler.
 
     def __init__(
         self,

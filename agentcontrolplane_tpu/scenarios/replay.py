@@ -140,7 +140,7 @@ class ReplayRow:
 @dataclass
 class ReplayReport:
     """Everything a scenario run produced, plus the SLO summary the gate
-    and the bench doc consume."""
+    consumes."""
 
     scenario: str
     speed: float

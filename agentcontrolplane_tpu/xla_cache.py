@@ -4,7 +4,7 @@ TPU compiles are the dominant cold-start cost, and the serving engine has
 a bounded-but-real matrix of programs (prefill buckets x batch sizes,
 decode widths, constrained variants). The persistent cache makes every
 compile a once-per-machine cost instead of once-per-process: the second
-`acp-tpu run`, `chip_smoke.py`, `bench.py` and every test process reuse
+`acp-tpu run`, `chip_smoke.py`, `acpbench.run` and every test process reuse
 the same compiled artifacts.
 
 Where it lives is decided from outside: when ``JAX_COMPILATION_CACHE_DIR``

@@ -1,16 +1,12 @@
 """SLO envelope gate (analysis/slo_gate.py): structural per-scenario
-judgement of bench scenario blocks — request conservation, outcome floors,
-percentile sanity, and the ``--slo-envelopes`` CLI body. Stdlib-only."""
+judgement of a scenario run's SLO summary — request conservation, outcome
+floors, percentile sanity. Stdlib-only."""
 
 from __future__ import annotations
-
-import json
 
 from agentcontrolplane_tpu.analysis.slo_gate import (
     ENVELOPES,
     check_block,
-    check_doc,
-    main,
 )
 
 
@@ -90,48 +86,3 @@ def test_every_shipped_scenario_has_an_envelope():
     from agentcontrolplane_tpu.scenarios import SCENARIOS
 
     assert set(ENVELOPES) == set(SCENARIOS)
-
-
-def test_check_doc_renders_table_and_collects():
-    doc = {
-        "scenarios": {
-            "persona_storm": {
-                "single": good_block(),
-                "fleet": good_block(completed=9, shed=1),  # trips ratio
-            },
-        }
-    }
-    lines, violations = check_doc(doc)
-    assert any("scenario" in line for line in lines)  # header
-    assert sum("persona_storm" in line for line in lines) == 2
-    assert [v.arm for v in violations] == ["fleet"]
-
-
-def test_check_doc_without_scenarios_is_calm():
-    lines, violations = check_doc({"metric": "x"})
-    assert violations == []
-    assert "no scenario blocks" in lines[0]
-
-
-def test_main_judges_newest_scenario_doc(tmp_path, capsys):
-    (tmp_path / "BENCH_PR1.json").write_text(
-        json.dumps({"metric": "old", "value": 1})
-    )
-    assert main(tmp_path) == 0
-    assert "no bench doc with scenario blocks" in capsys.readouterr().out
-
-    (tmp_path / "BENCH_PR2.json").write_text(json.dumps({
-        "scenarios": {"persona_storm": {"single": good_block()}}
-    }))
-    assert main(tmp_path) == 0
-    assert "judging BENCH_PR2.json" in capsys.readouterr().out
-
-    (tmp_path / "BENCH_PR3.json").write_text(json.dumps({
-        "scenarios": {"persona_storm": {"single": good_block(
-            completed=3, shed=7
-        )}}
-    }))
-    assert main(tmp_path) == 1
-    out = capsys.readouterr().out
-    assert "judging BENCH_PR3.json" in out  # newest doc wins
-    assert "completed_ratio" in out

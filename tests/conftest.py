@@ -4,7 +4,7 @@
   without TPU hardware, per the driver contract).
 - Native asyncio test support (async def tests run via asyncio.run).
 - Shared builder fixtures live in agentcontrolplane_tpu.testing (shipped in
-  the package so bench.py runs without tests/); tests/fixtures.py re-exports.
+  the package so they import without tests/); tests/fixtures.py re-exports.
 """
 
 import asyncio

@@ -189,10 +189,12 @@ def test_inactive_lane_decode_write_clamps_to_unread_row(mega):
         # A takes slot 0 and decodes long enough for B to land in slot 1;
         # A then finishes, and C re-uses freed slot 0: mid-prefill BELOW
         # the active lane — the dispatch width now covers C's lane
-        a = eng.submit("short lived", SamplingParams(temperature=0.0, max_tokens=16))
-        assert a.admitted.result(timeout=120)
-        b = eng.submit(ATTRACTOR, SamplingParams(temperature=0.0, max_tokens=60))
-        assert b.admitted.result(timeout=120)
+        # (one admission group: on a loaded machine A could end before B
+        # was even submitted, and B took slot 0)
+        with eng.hold_admission():
+            a = eng.submit("short lived", SamplingParams(temperature=0.0, max_tokens=16))
+            b = eng.submit(ATTRACTOR, SamplingParams(temperature=0.0, max_tokens=60))
+        assert a.admitted.result(timeout=120) and b.admitted.result(timeout=120)
         a.result(timeout=120)
         deadline = time.monotonic() + 120
         while eng.decode_steps == 0 and time.monotonic() < deadline:

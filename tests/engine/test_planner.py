@@ -19,6 +19,7 @@ import pytest
 
 import jax
 
+from agentcontrolplane_tpu.engine import engine as engine_module
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.engine.planner import (
     Autopilot,
@@ -105,45 +106,74 @@ def test_cycle_clock_ewma_seeds_and_decays():
 # -- deadlines met by arithmetic ----------------------------------------------
 
 
-def test_planner_meets_deadline_flat_cadence_would_miss():
-    """One long prompt, chunk=8, ~20ms per cycle (stalled deterministically),
-    deadline 0.45s: the flat cadence needs ~25 cycles (~0.5s+) and expires
-    mid-prefill; the planner's quota-sized chunks finish in time. Same
-    engine, same stall — only the planner knob differs."""
+CYCLE_S = 0.02
+
+
+class _CycleTicks:
+    """``time`` as engine/engine.py sees it, with ``monotonic()`` a clock the
+    test advances: one CYCLE_S a scheduler cycle that runs prefill chunks.
+    A deadline then allows a COUNT of cycles, whatever the machine's load
+    (a wall-clock deadline judged the six test workers' contention, not the
+    plan). Everything else is the real module."""
+
+    def __init__(self):
+        self.now = time.monotonic()
+
+    def monotonic(self):
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_planner_meets_deadline_flat_cadence_would_miss(monkeypatch):
+    """One long prompt, chunk=8, 20 ms a cycle on the engine's clock,
+    deadline 0.45 s = 22 cycles: the flat cadence needs 25 cycles (200
+    tokens / 8) and expires mid-prefill; the planner's quota-sized chunks
+    (ceil(25 / (22 - 2 slack)) = 2 a cycle) finish in 13. Same engine, same
+    clock — only the planner knob differs."""
     prompt = [1 + (i % 250) for i in range(200)]
     sp = SamplingParams(temperature=0.0, max_tokens=4)
 
     def run(planner: bool):
+        clock = _CycleTicks()
+        monkeypatch.setattr(engine_module, "time", clock)
         eng = make_engine(prefill_chunk=8, rate_planner=planner)
         real = eng._prefill_chunks
+        cycles = []
 
-        def slow_chunks(budget):
-            time.sleep(0.02)
+        def ticking_chunks(budget):
+            clock.now += CYCLE_S
+            cycles.append(budget)
             return real(budget)
 
-        eng._prefill_chunks = slow_chunks
-        # seed the cycle clock so admission projects against the real
-        # (stalled) cadence instead of the cold-start default
-        eng._cycle_clock.observe(0.02)
+        eng._prefill_chunks = ticking_chunks
+        # seed the cycle clock so admission projects against the ticked
+        # cadence instead of the cold-start default
+        eng._cycle_clock.observe(CYCLE_S)
         try:
             fut = eng.submit(prompt, sp, timeout_s=0.45)
             try:
-                return ("ok", fut.result(timeout=120).tokens)
+                return ("ok", fut.result(timeout=120).tokens, len(cycles))
             except Exception as e:
-                return ("expired", type(e).__name__)
+                return ("expired", type(e).__name__, len(cycles))
         finally:
             eng.stop()
 
     flat = run(False)
     planned = run(True)
     assert flat[0] == "expired", flat
+    assert 22 <= flat[2] < 25, flat  # every cycle the deadline allows, not enough
     assert planned[0] == "ok", planned
+    assert planned[2] <= 22, planned
 
 
-def test_quota_projection_event_and_chunk_sizing():
+def test_quota_projection_event_and_chunk_sizing(monkeypatch):
     """Admission records a ``quota`` flight event and the scheduler sizes
     the slot's per-cycle chunk as quota x chunk (capped at the largest
-    bucket, page-aligned)."""
+    bucket, page-aligned). On a clock that stands still: the plan is the
+    subject, and a loaded machine must not expire the request under it."""
+    monkeypatch.setattr(engine_module, "time", _CycleTicks())
     eng = make_engine(prefill_chunk=8)
     try:
         eng._cycle_clock.observe(0.05)

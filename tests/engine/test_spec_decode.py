@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.engine.spec import (
@@ -29,7 +30,7 @@ from agentcontrolplane_tpu.engine.spec import (
     ngram_propose,
 )
 from agentcontrolplane_tpu.engine.tokenizer import ByteTokenizer
-from agentcontrolplane_tpu.models.llama import PRESETS
+from agentcontrolplane_tpu.models.llama import PRESETS, init_params
 from agentcontrolplane_tpu.observability.metrics import REGISTRY
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
 from agentcontrolplane_tpu.testing import FAULTS
@@ -38,8 +39,9 @@ TOK = ByteTokenizer()
 CFG = dataclasses.replace(PRESETS["tiny"], vocab_size=512, max_seq_len=256, n_kv_heads=2)
 
 # repeated tool-call JSON — the self-similar agent traffic shape the
-# drafter exploits (and which drives this random-weights model into a
-# repetition attractor, so the drafter predicts its greedy output too)
+# drafter exploits. The random weights below repeat themselves on it only
+# in short runs (enough for some drafts to land); the acceptance-rate
+# test serves it with weights that echo by construction (_echo_params)
 TOOL_ECHO = '{"tool": "search", "args": {"q": "x"}} {"tool": "search", "args": {"q": "x"}}'
 
 
@@ -237,25 +239,42 @@ def test_spec_composes_with_prefix_cache_hits(engines):
 # -- the acceptance-rate criterion -------------------------------------------
 
 
-def test_tool_echo_fixture_accepts_over_1_5_tokens_per_dispatch(engines):
+def _echo_params():
+    """Weights whose greedy continuation is periodic by construction: with
+    the attention output projection zeroed a position's logits depend on
+    its own token alone, so greedy decoding is a fixed map over 512 tokens
+    and must enter a cycle (here of five tokens, after one). The shared
+    engines' random weights do NOT echo: their greedy text on TOOL_ECHO
+    repeats only in short runs, and the drafter replayed over that text
+    with every draft verified perfectly lands 1.24 tokens a dispatch, so
+    on them the bar below would measure the weights' luck, not the engine."""
+    params = init_params(CFG, jax.random.key(0))
+    params["layers"]["wo"] = jnp.zeros_like(params["layers"]["wo"])
+    return params
+
+
+def test_tool_echo_fixture_accepts_over_1_5_tokens_per_dispatch():
     """On repetitive tool-echo traffic the engine must commit > 1.5 tokens
     per decode dispatch (the CPU-backend acceptance bar), and the decode-
     efficiency stats must say so."""
-    eng = engines[("slot", 6)]
-    before = counter("acp_engine_spec_accepted_total")
-    tok0, step0, acc0, prop0 = (
-        eng.tokens_generated, eng.decode_steps, eng.spec_accepted, eng.spec_proposed,
-    )
-    r = eng.generate(TOOL_ECHO, SamplingParams(temperature=0.0, max_tokens=120))
-    assert len(r.tokens) > 60  # long enough to be a real measurement
-    per_step = (eng.tokens_generated - tok0) / (eng.decode_steps - step0)
-    assert per_step > 1.5, per_step
-    accepted = eng.spec_accepted - acc0
-    assert 0 < accepted <= eng.spec_proposed - prop0
-    s = eng.stats()
-    assert s["tokens_per_decode_step"] > 0
-    assert 0.0 < s["spec"]["acceptance_rate"] <= 1.0
-    assert counter("acp_engine_spec_accepted_total") == before + accepted
+    eng = make_engine("slot", spec_len=6, params=_echo_params())
+    try:
+        before = counter("acp_engine_spec_accepted_total")
+        tok0, step0, acc0, prop0 = (
+            eng.tokens_generated, eng.decode_steps, eng.spec_accepted, eng.spec_proposed,
+        )
+        r = eng.generate(TOOL_ECHO, SamplingParams(temperature=0.0, max_tokens=120))
+        assert len(r.tokens) > 60  # long enough to be a real measurement
+        per_step = (eng.tokens_generated - tok0) / (eng.decode_steps - step0)
+        assert per_step > 1.5, per_step
+        accepted = eng.spec_accepted - acc0
+        assert 0 < accepted <= eng.spec_proposed - prop0
+        s = eng.stats()
+        assert s["tokens_per_decode_step"] > 0
+        assert 0.0 < s["spec"]["acceptance_rate"] <= 1.0
+        assert counter("acp_engine_spec_accepted_total") == before + accepted
+    finally:
+        eng.stop()
 
 
 # -- fault injection: forced worst case --------------------------------------
