@@ -831,7 +831,8 @@ def cmd_perf(args) -> int:
             # the engine loop's own phases: self time, so the rows partition
             # the loop's busy time (park = waiting on an empty queue)
             print(f"engine loop: {cycles} busy cycles, "
-                  f"{doc.get('blocks', 0)} decode blocks")
+                  f"{doc.get('blocks', 0)} decode blocks, "
+                  f"{doc.get('uploads', 0)} uploads")
             print(f"{'PHASE':<10}{'N':>9}{'ms/CYCLE':>11}{'SHARE':>8}")
             for name, p in sorted(phases.items(), key=lambda kv: -kv[1]["s"]):
                 share = f"{p['s'] / busy:>8.1%}" if name != "park" else f"{'-':>8}"

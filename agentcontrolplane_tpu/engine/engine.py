@@ -55,6 +55,7 @@ from ..parallel.mesh import (
     param_shardings,
     serving_mesh,
 )
+from .lanes import DECODE, PREFILL, VERIFY, dispatch_key
 from .tokenizer import ByteTokenizer, Tokenizer
 
 log = logging.getLogger("acp_tpu.engine")
@@ -771,11 +772,45 @@ class Engine:
                 )
         log.info("engine init: params+cache in %.1fs", time.monotonic() - t0)
 
+        # (both recorders are made before the first upload: _put counts)
+        # flight recorder (observability/flight.py): ring-buffer record of
+        # every scheduler decision, always on (ACP_FLIGHT=0 disables for
+        # bench A/B). Public attribute: the REST/CLI introspection surface
+        # reads it via its own cross-thread-safe methods.
+        from ..observability.flight import FlightRecorder
+
+        self.flight = FlightRecorder()
+        if getattr(self, "_kernel_fallback_reason", None):
+            # deferred from the _use_pallas gate (the recorder didn't exist
+            # yet); pairs with acp_engine_kernel_fallbacks_total
+            self.flight.record(
+                "kernel_fallback",
+                kernel="paged_decode",
+                reason=self._kernel_fallback_reason,
+            )
+        # compute efficiency observatory (observability/profiler.py): per-
+        # dispatch program telemetry, cold-compile tracking, goodput/waste
+        # ledger. Public attribute like the flight recorder: REST/CLI read
+        # it via its declared cross-thread methods. ACP_PROF=0 reduces every
+        # hook to one bool branch (bench A/B), and the hooks never touch
+        # dispatch inputs/outputs — profiler on/off is byte-identical.
+        from ..observability.profiler import DispatchProfiler
+
+        self.profiler = DispatchProfiler(flight=self.flight)
         # computed ON device (jit + out_shardings) rather than device_put so
-        # the replicated key is valid under multihost meshes too
-        self._rng = jax.jit(
+        # the replicated key is valid under multihost meshes too. It is never
+        # split on the host: every program mixes its dispatch's counter (a
+        # row of the packed lanes, engine/lanes.py) into it, so a dispatch
+        # draws from a key no other dispatch has and nothing but the model
+        # programs runs on the device. Followers replay the leader's
+        # dispatches and so count alike.
+        self._base_key = jax.jit(
             lambda: jax.random.key(seed), out_shardings=self._replicated
         )()
+        self._dispatch_n = 0
+        # the slot a fused phase's padding lanes name: out of range for a
+        # family with per-slot state, so their state writes are dropped
+        self._pad_slot = self.max_slots if self._has_state else 0
         self._queue: "queue.Queue[Optional[_Request]]" = queue.Queue()
         # admission order is strict FIFO: requests the pool can't fit yet
         # stay at the head of this deque (no starvation of large requests)
@@ -1015,30 +1050,6 @@ class Engine:
         from ..faults import FAULTS as _faults
 
         self._faults = _faults
-        # flight recorder (observability/flight.py): ring-buffer record of
-        # every scheduler decision, always on (ACP_FLIGHT=0 disables for
-        # bench A/B). Public attribute: the REST/CLI introspection surface
-        # reads it via its own cross-thread-safe methods.
-        from ..observability.flight import FlightRecorder
-
-        self.flight = FlightRecorder()
-        if getattr(self, "_kernel_fallback_reason", None):
-            # deferred from the _use_pallas gate (the recorder didn't exist
-            # yet); pairs with acp_engine_kernel_fallbacks_total
-            self.flight.record(
-                "kernel_fallback",
-                kernel="paged_decode",
-                reason=self._kernel_fallback_reason,
-            )
-        # compute efficiency observatory (observability/profiler.py): per-
-        # dispatch program telemetry, cold-compile tracking, goodput/waste
-        # ledger. Public attribute like the flight recorder: REST/CLI read
-        # it via its declared cross-thread methods. ACP_PROF=0 reduces every
-        # hook to one bool branch (bench A/B), and the hooks never touch
-        # dispatch inputs/outputs — profiler on/off is byte-identical.
-        from ..observability.profiler import DispatchProfiler
-
-        self.profiler = DispatchProfiler(flight=self.flight)
         self.check_invariants = (
             bool(check_invariants)
             if check_invariants is not None
@@ -1048,6 +1059,12 @@ class Engine:
         self._build_jitted()
 
     def _put(self, x) -> jax.Array:  # acp: megastep-seam — upload guard, not a model program
+        """One host array to the device, replicated: THE upload of the
+        engine thread, counted in ``stats()["perf"]["uploads"]``. A dispatch
+        makes few of them: its per-lane scalars go as one packed buffer
+        (engine/lanes.py), beside the token rows, the page ids and, when
+        they changed, the block tables."""
+        self.profiler.count_upload()
         if jax.process_count() > 1:
             # multihost: device_put cannot target non-addressable devices;
             # every process supplies its local shards of the same replicated
@@ -1067,6 +1084,14 @@ class Engine:
         if self._jit_upload_copy is not None:
             return self._jit_upload_copy(out)
         return out
+
+    def _next_key_n(self) -> int:
+        """The counter of the next dispatch that draws: it rides the
+        dispatch's lanes and the program mixes it into the base key
+        (lanes.dispatch_key)."""
+        n = self._dispatch_n
+        self._dispatch_n = (n + 1) % (1 << 31)
+        return n
 
     # -- jitted programs -------------------------------------------------
 
@@ -1116,12 +1141,16 @@ class Engine:
             nxt = jnp.where(toks < width, nxt, -1)  # beyond the table: illegal
             return jnp.where(constrained, nxt, con_state)
 
-        def sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
-            """Constrained sampling for a [B] batch of first tokens."""
-            logits = constrain_logits(logits, table, con_states, constrained, min_close, budgets)
-            toks = sample(logits, rng, temps, top_ks, top_ps)
-            new_states = advance_constraint(table, con_states, constrained, toks)
-            return toks, new_states
+        def sample_lanes(logits, key, ln, table, min_close):
+            """Constrained sampling for a [B] batch of first tokens, from a
+            prefill dispatch's unpacked lanes and the key of its counter."""
+            logits = constrain_logits(
+                logits, table, ln["con_states"], ln["constrained"], min_close, ln["budgets"]
+            )
+            toks = sample(
+                logits, dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"], ln["top_ps"]
+            )
+            return toks, advance_constraint(table, ln["con_states"], ln["constrained"], toks)
 
         def make_decode_block(step_fn):
             # trace-time constants: finish detection runs ON DEVICE so decode
@@ -1133,10 +1162,11 @@ class Engine:
             stop_toks = tuple(sorted({int(t) for t in self.tokenizer.stop_tokens}))
             max_ctx = self.max_ctx
 
-            def decode_block(
-                params, cache, tokens, seq_lens, active, rng, temps, top_ks, top_ps,
-                table, con_states, constrained, min_close, budgets, *extra,
-            ):
+            def decode_block(params, cache, lanes, key, table, min_close, *extra):
+                ln = DECODE.unpack(lanes)
+                temps, top_ks, top_ps = ln["temps"], ln["top_ks"], ln["top_ps"]
+                constrained = ln["constrained"]
+
                 def step(carry, _):
                     cache, tokens, seq_lens, con_states, budgets, active, rng = carry
                     rng, sub = jax.random.split(rng)
@@ -1155,11 +1185,20 @@ class Engine:
                     active = active & ~is_stop & (budgets > 0) & (seq_lens + 1 < max_ctx)
                     return (cache, next_toks, seq_lens, con_states, budgets, active, rng), next_toks
 
-                (cache, tokens, seq_lens, con_states, budgets, active, rng), toks = jax.lax.scan(
-                    step, (cache, tokens, seq_lens, con_states, budgets, active, rng), None,
-                    length=self.decode_block_size,
+                (cache, tokens, seq_lens, con_states, budgets, active, _), toks = jax.lax.scan(
+                    step,
+                    (cache, ln["tokens"], ln["seq_lens"], ln["con_states"], ln["budgets"],
+                     ln["active"], dispatch_key(key, ln["n"], ln["chain"])),
+                    None, length=self.decode_block_size,
                 )
-                return cache, toks, (tokens, seq_lens, con_states, budgets, active, rng)
+                # the carry is the lanes themselves, donated and handed back:
+                # a block nothing dirtied feeds them in again as they are, and
+                # draws from the next key of this dispatch's chain
+                lanes = DECODE.update(
+                    lanes, tokens=tokens, seq_lens=seq_lens, con_states=con_states,
+                    budgets=budgets, active=active, chain=ln["chain"] + 1,
+                )
+                return cache, toks, con_states, lanes
 
             # raw (unjitted): the split path jits it standalone; the fused
             # megastep composes the same body so both paths trace the same
@@ -1178,22 +1217,23 @@ class Engine:
 
             stop_toks = tuple(sorted({int(t) for t in self.tokenizer.stop_tokens}))
 
-            def verify_block(
-                params, cache, inputs, n_input, starts, active, rng, temps,
-                top_ks, top_ps, table, con_states, constrained, min_close,
-                budgets, force_reject, *extra,
-            ):
-                cache, logits = verify_fn(params, cache, inputs, n_input, starts, *extra)
+            def verify_block(params, cache, inputs, lanes, key, table, min_close, *extra):
+                ln = VERIFY.unpack(lanes)
+                constrained = ln["constrained"]
+                cache, logits = verify_fn(
+                    params, cache, inputs, ln["n_input"], ln["starts"], *extra
+                )
                 out_toks, n_emit, new_states = speculative_accept(
-                    logits, inputs, n_input, active, rng, temps, top_ks,
-                    top_ps, stop_toks, budgets, force_reject,
+                    logits, inputs, ln["n_input"], ln["active"],
+                    dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"],
+                    ln["top_ps"], stop_toks, ln["budgets"], ln["force_reject"][0],
                     constrain_fn=lambda l, s, b: constrain_logits(
                         l, table, s, constrained, min_close, b
                     ),
                     advance_fn=lambda s, t, take: jnp.where(
                         take, advance_constraint(table, s, constrained, t), s
                     ),
-                    con_states=con_states,
+                    con_states=ln["con_states"],
                 )
                 return cache, out_toks, n_emit, new_states
 
@@ -1218,15 +1258,18 @@ class Engine:
             tuple of (page_ids, blocks) pow2 scatter groups; the restored
             slots' pages are disjoint from every other phase's (page
             ownership is per-slot), so phase order among the prefill
-            phases cannot change bytes. Donation: the cache and the decode
-            carry arrays, matching the split decode block's in-place
-            reuse; dec_aux (temps/top_ks/table/...) is host-retained
-            across blocks and must NOT donate. plain_fn is None in the
+            phases cannot change bytes. Every phase takes its per-lane
+            scalars as one packed buffer (engine/lanes.py) and derives its
+            key from ``key``, the engine's base key, and the counter in its
+            own lanes. Donation: the cache and the decode lanes, matching
+            the split decode block's in-place reuse; dec_aux (the grammar
+            table, min_close, the block tables) is host-retained across
+            blocks and must NOT donate. plain_fn is None in the
             slot layout — plains/swaps only absorb under paged KV (their
             padding lanes need TRASH_PAGE routing to stay harmless)."""
 
-            def megastep(params, cache, swaps, mids, plains, finals,
-                         dec_carry, dec_aux, ver):
+            def megastep(params, cache, key, swaps, mids, plains, finals,
+                         dec_lanes, dec_aux, ver):
                 p_out = f_out = d_out = v_out = None
                 if swaps is not None:
                     for s_ids, s_blocks in swaps:
@@ -1237,67 +1280,72 @@ class Engine:
                 if mids is not None:
                     cache = mid_fn(params, cache, *mids)
                 if plains is not None:
-                    lanes, (p_rng, p_temps, p_top_ks, p_top_ps, p_table,
-                            p_con0, p_cst0, p_minc, p_budg) = plains
-                    cache, logits = plain_fn(params, cache, *lanes)
-                    p_out = sample_first(
-                        logits, p_rng, p_temps, p_top_ks, p_top_ps, p_table,
-                        p_con0, p_cst0, p_minc, p_budg,
-                    )
+                    lanes, tables = plains
+                    cache, *p_out = plain_fn(params, cache, *lanes, key, *tables)
                 if finals is not None:
-                    lanes, (f_rng, f_temps, f_top_ks, f_top_ps, f_table,
-                            f_con0, f_cst0, f_minc, f_budg) = finals
-                    cache, logits = final_fn(params, cache, *lanes)
-                    f_out = sample_first(
-                        logits, f_rng, f_temps, f_top_ks, f_top_ps, f_table,
-                        f_con0, f_cst0, f_minc, f_budg,
+                    lanes, tables = finals
+                    cache, *f_out = final_fn(params, cache, *lanes, key, *tables)
+                if dec_lanes is not None:
+                    table, min_close, extra = dec_aux
+                    cache, *d_out = decode_block(
+                        params, cache, dec_lanes, key, table, min_close, *extra
                     )
-                if dec_carry is not None:
-                    tokens, seq_lens, con_states, budgets, active, rng = dec_carry
-                    temps, top_ks, top_ps, table, constrained, min_close, extra = dec_aux
-                    cache, toks, carry = decode_block(
-                        params, cache, tokens, seq_lens, active, rng, temps,
-                        top_ks, top_ps, table, con_states, constrained,
-                        min_close, budgets, *extra,
-                    )
-                    d_out = (toks, carry)
                 if ver is not None:
-                    cache, out_toks, n_emit, new_states = verify_block(
-                        params, cache, *ver
-                    )
-                    v_out = (out_toks, n_emit, new_states)
+                    inputs, lanes, *rest = ver
+                    cache, *v_out = verify_block(params, cache, inputs, lanes, key, *rest)
                 return cache, p_out, f_out, d_out, v_out
 
             return self._with_counters(megastep)(
-                lambda f: jax.jit(f, donate_argnums=(1, 6))
+                lambda f: jax.jit(f, donate_argnums=(1, 7))
             )
+
+        has_state = self._has_state
 
         if self.kv_layout == "paged":
             decode_step_paged = self._model.decode_step_paged
             prefill_paged_batch = self._model.prefill_paged_batch
             prefill_paged_continue = self._model.prefill_paged_continue
+            prefill_paged_continue_kv = self._model.prefill_paged_continue_kv
 
             use_pallas = self._use_pallas
 
-            def prefill_and_sample(params, pages, tokens, lengths, page_ids, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
-                pages, logits = prefill_paged_batch(params, pages, tokens, lengths, page_ids, config)
-                toks, states = sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets)
+            def page_arg(page_ids, ln):
+                # a family with per-slot state reads which slot's state each
+                # row carries and where its snapshot is due beside the ids
+                return (page_ids, (ln["slots"], ln["snap_at"])) if has_state else page_ids
+
+            def prefill_and_sample(params, pages, tokens, lanes, page_ids, key, table, min_close):
+                ln = PREFILL.unpack(lanes)
+                pages, logits = prefill_paged_batch(
+                    params, pages, tokens, ln["lengths"], page_arg(page_ids, ln), config
+                )
+                toks, states = sample_lanes(logits, key, ln, table, min_close)
                 return pages, toks, states
 
             self._jit_prefill_paged = self._with_counters(prefill_and_sample)(
                 lambda f: jax.jit(f, donate_argnums=(1,))
             )
 
-            def paged_continue_and_sample(params, pages, tokens, lengths, starts, page_ids, block_tables, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
+            def paged_continue_and_sample(params, pages, tokens, lanes, page_ids, block_tables, key, table, min_close):
+                ln = PREFILL.unpack(lanes)
                 pages, logits = prefill_paged_continue(
-                    params, pages, tokens, lengths, starts, page_ids, block_tables, config
+                    params, pages, tokens, ln["lengths"], ln["starts"],
+                    page_arg(page_ids, ln), block_tables, config,
                 )
-                toks, states = sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets)
+                toks, states = sample_lanes(logits, key, ln, table, min_close)
                 return pages, toks, states
 
             self._jit_prefill_paged_continue = self._with_counters(
                 paged_continue_and_sample
             )(lambda f: jax.jit(f, donate_argnums=(1,)))
+
+            def paged_continue_kv(params, pages, tokens, lanes, page_ids, block_tables):
+                ln = PREFILL.unpack(lanes)
+                return prefill_paged_continue_kv(
+                    params, pages, tokens, ln["lengths"], ln["starts"],
+                    page_arg(page_ids, ln), block_tables, config,
+                )
+
             mesh = self.mesh
             decode_block = make_decode_block(
                 lambda params, pages, tokens, seq_lens, active, block_tables: decode_step_paged(
@@ -1306,7 +1354,7 @@ class Engine:
                 )
             )
             self._jit_decode_paged = self._with_counters(decode_block)(
-                lambda f: jax.jit(f, donate_argnums=(1, 2, 3, 4, 5, 10, 13))
+                lambda f: jax.jit(f, donate_argnums=(1, 2))
             )
             # spec_len > 0 is refused for a family without a verify program
             verify_paged_continue = getattr(self._model, "verify_paged_continue", None)
@@ -1316,54 +1364,48 @@ class Engine:
                 )
             )
             self._jit_verify = jax.jit(verify_block, donate_argnums=(1,))
-            prefill_paged_continue_kv = self._model.prefill_paged_continue_kv
-
             self._jit_megastep = make_megastep(
-                lambda params, pages, toks, lens, starts, page_ids, tables: (
-                    prefill_paged_continue_kv(
-                        params, pages, toks, lens, starts, page_ids, tables, config
-                    )
-                ),
-                lambda params, pages, toks, lens, starts, page_ids, tables: (
-                    prefill_paged_continue(
-                        params, pages, toks, lens, starts, page_ids, tables, config
-                    )
-                ),
-                decode_block,
-                verify_block,
-                plain_fn=lambda params, pages, toks, lens, page_ids: (
-                    prefill_paged_batch(params, pages, toks, lens, page_ids, config)
-                ),
+                paged_continue_kv, paged_continue_and_sample, decode_block,
+                verify_block, plain_fn=prefill_and_sample,
             )
         else:
             prefill_batch = self._model.prefill_batch
             decode_step = self._model.decode_step
+            prefill_continue = self._model.prefill_continue
+            prefill_continue_kv = self._model.prefill_continue_kv
 
-            def prefill_and_sample(params, cache, tokens, lengths, slots, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
-                cache, logits = prefill_batch(params, cache, tokens, lengths, slots, config)
-                toks, states = sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets)
+            def prefill_and_sample(params, cache, tokens, lanes, key, table, min_close):
+                ln = PREFILL.unpack(lanes)
+                cache, logits = prefill_batch(
+                    params, cache, tokens, ln["lengths"], ln["slots"], config
+                )
+                toks, states = sample_lanes(logits, key, ln, table, min_close)
                 return cache, toks, states
 
             self._jit_prefill = jax.jit(prefill_and_sample, donate_argnums=(1,))
 
-            prefill_continue = self._model.prefill_continue
-
-            def continue_and_sample(params, cache, tokens, lengths, starts, slots, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets):
+            def continue_and_sample(params, cache, tokens, lanes, key, table, min_close):
+                ln = PREFILL.unpack(lanes)
                 cache, logits = prefill_continue(
-                    params, cache, tokens, lengths, starts, slots, config
+                    params, cache, tokens, ln["lengths"], ln["starts"], ln["slots"], config
                 )
-                toks, states = sample_first(logits, rng, temps, top_ks, top_ps, table, con_states, constrained, min_close, budgets)
+                toks, states = sample_lanes(logits, key, ln, table, min_close)
                 return cache, toks, states
 
             self._jit_prefill_continue = jax.jit(continue_and_sample, donate_argnums=(1,))
+
+            def continue_kv(params, cache, tokens, lanes):
+                ln = PREFILL.unpack(lanes)
+                return prefill_continue_kv(
+                    params, cache, tokens, ln["lengths"], ln["starts"], ln["slots"], config
+                )
+
             decode_block = make_decode_block(
                 lambda params, cache, tokens, seq_lens, active: decode_step(
                     params, cache, tokens, seq_lens, config, active=active
                 )
             )
-            self._jit_decode = jax.jit(
-                decode_block, donate_argnums=(1, 2, 3, 4, 5, 10, 13)
-            )
+            self._jit_decode = jax.jit(decode_block, donate_argnums=(1, 2))
             verify_continue = self._model.verify_continue
             verify_block = make_verify(
                 lambda params, cache, inputs, n_input, starts: verify_continue(
@@ -1371,20 +1413,8 @@ class Engine:
                 )
             )
             self._jit_verify = jax.jit(verify_block, donate_argnums=(1,))
-            prefill_continue_kv = self._model.prefill_continue_kv
             self._jit_megastep = make_megastep(
-                lambda params, cache, toks, lens, starts, slots_: (
-                    prefill_continue_kv(
-                        params, cache, toks, lens, starts, slots_, config
-                    )
-                ),
-                lambda params, cache, toks, lens, starts, slots_: (
-                    prefill_continue(
-                        params, cache, toks, lens, starts, slots_, config
-                    )
-                ),
-                decode_block,
-                verify_block,
+                continue_kv, continue_and_sample, decode_block, verify_block
             )
 
     # -- public API ------------------------------------------------------
@@ -2797,48 +2827,18 @@ class Engine:
                         toks[i] = self._full_row(req)[start : start + CH]
                         starts[i] = start
                         slots[i] = slot
-                    self._rng, step_rng = jax.random.split(self._rng)
-                    tail = (
-                        step_rng,
-                        self._put(np.zeros(B, dtype=np.float32)),  # temps (unused sample)
-                        self._put(np.zeros(B, dtype=np.int32)),
-                        self._put(np.ones(B, dtype=np.float32)),
-                        self._dummy_table,
-                        self._put(np.zeros(B, dtype=np.int32)),
-                        self._put(np.zeros(B, dtype=bool)),  # unconstrained
-                        self._dummy_min_close,
-                        self._put(np.ones(B, dtype=np.int32)),
-                    )
                     prof_t0 = self.profiler.start()
+                    page_ids = None
                     if self.kv_layout == "paged":
                         P = self.page_size
                         page_ids = np.zeros((B, CH // P), dtype=np.int32)
                         for i, e in enumerate(batch):
                             slot, start = e[0][1], e[1]
                             page_ids[i] = self._slot_pages[slot][start // P : (start + CH) // P]
-                        block_tables = self._put(
-                            self._block_tables[[it[0][1] for it in batch]]
-                        )
-                        self.cache, _tok, _state = self._jit_prefill_paged_continue(
-                            self.params,
-                            self.cache,
-                            self._put(toks),
-                            self._put(np.full(B, CH, dtype=np.int32)),
-                            self._put(starts),
-                            self._page_arg(page_ids, slots, [e[0][0] for e in batch]),
-                            block_tables,
-                            *tail,
-                        )
-                    else:
-                        self.cache, _tok, _state = self._jit_prefill_continue(
-                            self.params,
-                            self.cache,
-                            self._put(toks),
-                            self._put(np.full(B, CH, dtype=np.int32)),
-                            self._put(starts),
-                            self._put(slots),
-                            *tail,
-                        )
+                    _tok = self._continue_kv_only(
+                        toks, np.full(B, CH, dtype=np.int32), starts, slots,
+                        [e[0][0] for e in batch], page_ids,
+                    )
                     if self.profiler.enabled:
                         # spill rounds run full CH-token rows: no bucket padding
                         self.profiler.record(
@@ -3601,19 +3601,8 @@ class Engine:
             lengths[i] = n
             starts[i] = st
             slots[i] = slot
-        self._rng, step_rng = jax.random.split(self._rng)
-        tail = (
-            step_rng,
-            self._put(np.zeros(B, dtype=np.float32)),  # temps (sample unused)
-            self._put(np.zeros(B, dtype=np.int32)),
-            self._put(np.ones(B, dtype=np.float32)),
-            self._dummy_table,
-            self._put(np.zeros(B, dtype=np.int32)),
-            self._put(np.zeros(B, dtype=bool)),  # unconstrained
-            self._dummy_min_close,
-            self._put(np.ones(B, dtype=np.int32)),
-        )
         prof_t0 = self.profiler.start()
+        page_ids = None
         if self.kv_layout == "paged":
             P = self.page_size
             page_ids = np.full((B, bucket // P), TRASH_PAGE, dtype=np.int32)
@@ -3623,29 +3612,9 @@ class Engine:
                 # fresh pages — never a page holding earlier KV
                 sub = self._slot_pages[slot][st // P : -(-(st + n) // P)]
                 page_ids[i, : len(sub)] = sub
-            block_tables = self._put(
-                self._block_tables[[slot for slot, _, _, _ in batch]]
-            )
-            self.cache, _tok, _state = self._jit_prefill_paged_continue(
-                self.params,
-                self.cache,
-                self._put(toks),
-                self._put(lengths),
-                self._put(starts),
-                self._page_arg(page_ids, slots, [sl.request for _, sl, _, _ in batch]),
-                block_tables,
-                *tail,
-            )
-        else:
-            self.cache, _tok, _state = self._jit_prefill_continue(
-                self.params,
-                self.cache,
-                self._put(toks),
-                self._put(lengths),
-                self._put(starts),
-                self._put(slots),
-                *tail,
-            )
+        _tok = self._continue_kv_only(
+            toks, lengths, starts, slots, [sl.request for _, sl, _, _ in batch], page_ids
+        )
         if self.profiler.enabled:
             real = int(lengths.sum())
             self.profiler.record(
@@ -3659,23 +3628,6 @@ class Engine:
             )
 
     # -- per-slot state beside the pages (models.programs().has_state) ----
-
-    def _page_arg(self, page_ids: np.ndarray, slot_ids, reqs: list):
-        # acp: dispatch-lanes slots,snap_at
-        """What a paged prefill program gets for its page ids: the ids
-        alone, or for a family with per-slot state the pair ``(ids, (slots,
-        snap_at))``: which slot's state each row reads and writes, and the
-        length at which its snapshot is due (-1: none). Rows past ``reqs``
-        are a fused dispatch's padding lanes: a slot out of range, whose
-        state writes are dropped."""
-        if not self._has_state:
-            return self._put(page_ids)
-        rows = page_ids.shape[0]
-        slots = np.full(rows, self.max_slots, dtype=np.int32)
-        slots[: len(reqs)] = np.asarray(slot_ids, dtype=np.int32)[: len(reqs)]
-        snap_at = np.full(rows, -1, dtype=np.int32)
-        snap_at[: len(reqs)] = [r.state_cut or -1 for r in reqs]
-        return self._put(page_ids), (self._put(slots), self._put(snap_at))
 
     def _state_cut_for(self, req: _Request, start: int) -> int:
         """The one length of this admission at which the state is saved:
@@ -4080,32 +4032,44 @@ class Engine:
         return self._token_table
 
     def _prefill_lanes(
-        self, chunk: list, starts: np.ndarray
+        self, chunk: list, starts: np.ndarray, width: Optional[int] = None
     ) -> dict:
-        # acp: dispatch-lanes tokens,lengths,slots,temps,top_ks,top_ps,con_states0,constrained0,budgets,full_lens
+        # acp: dispatch-lanes tokens,lengths,lane_starts,slots,snap_at,temps,top_ks,top_ps,con_states0,constrained0,budgets,full_lens
         # acp: budget-seam — the ONE admission-time budget computation (the
         # +1-for-the-first-token form); decode/verify recomputation goes
         # through _slot_budget
-        """Build the batched prefill/continuation lane arrays for B
+        """Build the batched prefill/continuation lanes for B
         already-reserved requests — shared by the split _prefill_group
-        dispatch and the megastep's fused final phase, so both upload the
-        same numbers (the budget seam must have exactly one home)."""
+        dispatch and the megastep's fused plain and final phases, so both
+        upload the same numbers (the budget seam must have exactly one
+        home). ``width`` pads the batch (a fused phase's power of two):
+        padding lanes sample garbage that is never committed, and their
+        writes land on the trash page (paged: length 0) or the clamped
+        never-readable row (slot layout: start max_ctx); a family with
+        per-slot state gets a slot out of range, whose state writes are
+        dropped, and no snapshot (-1). ``lanes`` is the one packed buffer
+        the program reads them from (engine/lanes.py PREFILL)."""
         B = len(chunk)
+        Bp = width or B
         # bucket over what actually runs through the model (full row on a
         # miss; suffix on a hit)
         bucket = max(
             _next_bucket(len(self._full_row(r)) - int(starts[i]), self.prefill_buckets)
             for i, (r, _, _, _) in enumerate(chunk)
         )
-        tokens = np.zeros((B, bucket), dtype=np.int32)
-        lengths = np.zeros(B, dtype=np.int32)
-        slots = np.zeros(B, dtype=np.int32)
-        temps = np.zeros(B, dtype=np.float32)
-        top_ks = np.zeros(B, dtype=np.int32)
-        top_ps = np.ones(B, dtype=np.float32)
-        con_states0 = np.zeros(B, dtype=np.int32)
-        constrained0 = np.zeros(B, dtype=bool)
-        budgets = np.zeros(B, dtype=np.int32)
+        tokens = np.zeros((Bp, bucket), dtype=np.int32)
+        lengths = np.zeros(Bp, dtype=np.int32)
+        lane_starts = np.full(
+            Bp, self.max_ctx if self.kv_layout == "slot" else 0, dtype=np.int32
+        )
+        slots = np.full(Bp, self._pad_slot, dtype=np.int32)
+        snap_at = np.full(Bp, -1, dtype=np.int32)
+        temps = np.zeros(Bp, dtype=np.float32)
+        top_ks = np.zeros(Bp, dtype=np.int32)
+        top_ps = np.ones(Bp, dtype=np.float32)
+        con_states0 = np.zeros(Bp, dtype=np.int32)
+        constrained0 = np.zeros(Bp, dtype=bool)
+        budgets = np.ones(Bp, dtype=np.int32)
         full_lens = np.zeros(B, dtype=np.int32)
         any_json = any(r.sampling.json_only for r, _, _, _ in chunk)
         if any_json:
@@ -4116,6 +4080,7 @@ class Engine:
             min_close = (
                 self._min_close if self._min_close is not None else self._dummy_min_close
             )
+        lane_starts[:B] = starts
         for i, (req, slot, _, _m) in enumerate(chunk):
             s = req.sampling
             row = self._full_row(req)
@@ -4125,6 +4090,7 @@ class Engine:
             tokens[i, : len(suffix)] = suffix
             lengths[i] = len(suffix)
             slots[i] = slot
+            snap_at[i] = req.state_cut or -1
             temps[i] = s.temperature
             top_ks[i] = s.top_k
             top_ps[i] = s.top_p
@@ -4141,11 +4107,47 @@ class Engine:
                 constrained0[i] = True
         return {
             "bucket": bucket, "tokens": tokens, "lengths": lengths,
-            "slots": slots, "temps": temps, "top_ks": top_ks,
-            "top_ps": top_ps, "con_states0": con_states0,
-            "constrained0": constrained0, "budgets": budgets,
             "full_lens": full_lens, "table": table, "min_close": min_close,
+            "lanes": PREFILL.pack(
+                Bp, n=self._next_key_n(), lengths=lengths, starts=lane_starts,
+                slots=slots, snap_at=snap_at, temps=temps, top_ks=top_ks,
+                top_ps=top_ps, con_states=con_states0, constrained=constrained0,
+                budgets=budgets,
+            ),
         }
+
+    def _kv_lanes(self, lengths, starts, slots, reqs: list) -> jax.Array:
+        # acp: dispatch-lanes snap_at
+        """The uploaded lanes of a dispatch that only writes KV (a spill
+        round, a chunk, the megastep's mid phase): its sampled token is
+        discarded or never drawn, so the sampling rows hold what samples
+        nothing special (greedy, unconstrained, a budget of 1). Rows past
+        ``reqs`` are a fused phase's padding: no snapshot."""
+        snap_at = np.full(len(lengths), -1, dtype=np.int32)
+        snap_at[: len(reqs)] = [r.state_cut or -1 for r in reqs]
+        return self._put(PREFILL.pack(
+            len(lengths), n=self._next_key_n(), lengths=lengths, starts=starts,
+            slots=slots, snap_at=snap_at, temps=0.0, top_ks=0, top_ps=1.0,
+            con_states=0, constrained=False, budgets=1,
+        ))
+
+    def _continue_kv_only(  # acp: megastep-seam — the split spill and chunk dispatches
+        self, toks, lengths, starts, slots, reqs: list, page_ids
+    ) -> jax.Array:
+        """One continuation dispatch whose sampled token is discarded (a
+        spill round, a split chunk): KV writes only, four uploads at most.
+        Returns the token array, for the profiler to wait on."""
+        args = [self._put(toks), self._kv_lanes(lengths, starts, slots, reqs)]
+        if self.kv_layout == "paged":
+            args += [self._put(page_ids), self._put(self._block_tables[slots])]
+            program = self._jit_prefill_paged_continue
+        else:
+            program = self._jit_prefill_continue
+        self.cache, tok, _state = program(
+            self.params, self.cache, *args, self._base_key,
+            self._dummy_table, self._dummy_min_close,
+        )
+        return tok
 
     def _prefill_group(  # acp: megastep-seam
         self,
@@ -4167,22 +4169,11 @@ class Engine:
             table, min_close = ln["table"], ln["min_close"]
             if starts_np is None:
                 self._full_batch_shapes.add((bucket, B))
-            self._rng, step_rng = jax.random.split(self._rng)
-            common = (
-                self._put(ln["tokens"]),
-                self._put(lengths),
-            )
-            tail = (
-                step_rng,
-                self._put(ln["temps"]),
-                self._put(ln["top_ks"]),
-                self._put(ln["top_ps"]),
-                table,
-                self._put(ln["con_states0"]),
-                self._put(ln["constrained0"]),
-                min_close,
-                self._put(ln["budgets"]),
-            )
+            else:
+                self._cont_batch_sizes.add(B)
+            # a plain dispatch uploads three arrays (token rows, lanes, page
+            # ids), a continuation its block tables besides
+            args = [self._put(ln["tokens"]), self._put(ln["lanes"])]
             prof_t0 = self.profiler.start()
             if self.kv_layout == "paged":
                 P = self.page_size
@@ -4195,32 +4186,21 @@ class Engine:
                     assert pages is not None
                     fresh = pages[int(starts[i]) // P :]
                     page_ids[i, : len(fresh)] = fresh
-                page_arg = self._page_arg(
-                    page_ids, ln["slots"], [r for r, _, _, _ in chunk]
-                )
+                args.append(self._put(page_ids))
+                program = self._jit_prefill_paged
                 if starts_np is not None:
-                    self._cont_batch_sizes.add(B)
-                    block_tables = self._put(
+                    args.append(self._put(
                         self._block_tables[[slot for _, slot, _, _ in chunk]]
-                    )
-                    cache, firsts, con_states = self._jit_prefill_paged_continue(
-                        self.params, self.cache, *common,
-                        self._put(starts), page_arg, block_tables, *tail,
-                    )
-                else:
-                    cache, firsts, con_states = self._jit_prefill_paged(
-                        self.params, self.cache, *common, page_arg, *tail
-                    )
-            elif starts_np is not None:
-                self._cont_batch_sizes.add(B)
-                cache, firsts, con_states = self._jit_prefill_continue(
-                    self.params, self.cache, *common,
-                    self._put(starts), self._put(ln["slots"]), *tail,
-                )
+                    ))
+                    program = self._jit_prefill_paged_continue
             else:
-                cache, firsts, con_states = self._jit_prefill(
-                    self.params, self.cache, *common, self._put(ln["slots"]), *tail
+                program = (
+                    self._jit_prefill if starts_np is None
+                    else self._jit_prefill_continue
                 )
+            cache, firsts, con_states = program(
+                self.params, self.cache, *args, self._base_key, table, min_close
+            )
             self.cache = cache
             if self.profiler.enabled:
                 # program key mirrors the jit cache keying: kind x bucket x
@@ -4639,13 +4619,15 @@ class Engine:
         self._tables_dirty = True
 
     def _ensure_dev_state(self) -> dict:
-        """Device-resident decode state: the per-slot arrays (tokens,
-        seq_lens, con_states, budgets, active, rng) round-trip through the
-        decode block's carry and are fed back DONATED on the next block.
-        Only a "dirty" block — admission, finish, cancel (anything that
-        changed host-side slot assignment) — re-uploads the host mirrors.
-        Steady-state blocks cost one dispatch + one result fetch instead
-        of eight uploads per block.
+        """Device-resident decode state: the per-slot lanes (tokens,
+        seq_lens, active, sampling parameters, con_states, budgets, and the
+        dispatch counter its key derives from: engine/lanes.py DECODE) are
+        ONE packed buffer that round-trips through the decode block's carry
+        and is fed back DONATED on the next block. Only a "dirty" block —
+        admission, finish, cancel (anything that changed host-side slot
+        assignment) — re-packs the host mirrors and uploads them, once.
+        Steady-state blocks cost one dispatch + one result fetch and upload
+        nothing; clean and dirty blocks run the same compiled program.
         Shared by the split decode block and the megastep's fused decode
         phase (both must upload the same lanes). Paged block tables ride
         the same dirty discipline: re-uploaded only when a page was
@@ -4665,7 +4647,6 @@ class Engine:
             for slot, sl in self._slots.items():
                 if not sl.parked and not sl.prefilling and slot < W:
                     active_mask[slot] = True
-            self._rng, step_rng = jax.random.split(self._rng)
             # once the token table exists it is passed unconditionally
             # (matching the prefill path): keying jit entries on "any slot
             # constrained" would DOUBLE the decode-width program matrix, and
@@ -4677,18 +4658,16 @@ class Engine:
                     self._budgets[slot] = self._slot_budget(slot, sl)
             self._dev = {
                 "W": W,
-                "tokens": self._put(self._last_tokens[:W]),
-                "seq_lens": self._put(self._seq_lens[:W]),
-                "active": self._put(active_mask),
-                "rng": step_rng,
-                "temps": self._put(self._temps[:W]),
-                "top_ks": self._put(self._top_ks[:W]),
-                "top_ps": self._put(self._top_ps[:W]),
+                "lanes": self._put(DECODE.pack(
+                    W, n=self._next_key_n(), chain=0,
+                    tokens=self._last_tokens[:W], seq_lens=self._seq_lens[:W],
+                    active=active_mask, temps=self._temps[:W],
+                    top_ks=self._top_ks[:W], top_ps=self._top_ps[:W],
+                    con_states=self._con_states[:W],
+                    constrained=self._constrained[:W], budgets=self._budgets[:W],
+                )),
                 "table": self._token_table if use_real else self._dummy_table,
-                "con_states": self._put(self._con_states[:W]),
-                "constrained": self._put(self._constrained[:W]),
                 "min_close": self._min_close if use_real else self._dummy_min_close,
-                "budgets": self._put(self._budgets[:W]),
             }
             self._state_dirty = False
         d = self._dev
@@ -4748,18 +4727,14 @@ class Engine:
                 d = self._ensure_dev_state()  # finals may have joined
                 W = d["W"]
                 n_act = self._n_active()
-            common = (
-                d["tokens"], d["seq_lens"], d["active"], d["rng"],
-                d["temps"], d["top_ks"], d["top_ps"], d["table"],
-                d["con_states"], d["constrained"], d["min_close"], d["budgets"],
-            )
+            common = (d["lanes"], self._base_key, d["table"], d["min_close"])
             prof_t0 = self.profiler.start()
             if self.kv_layout == "paged":
-                cache, tok_block, carry = self._jit_decode_paged(
+                cache, tok_block, con_states, carry = self._jit_decode_paged(
                     self.params, self.cache, *common, d["block_tables"]
                 )
             else:
-                cache, tok_block, carry = self._jit_decode(
+                cache, tok_block, con_states, carry = self._jit_decode(
                     self.params, self.cache, *common
                 )
             prog_key = (
@@ -4781,7 +4756,7 @@ class Engine:
             # sequential np.asarray fetches double the per-block latency floor.
             # con_states must stay mirrored so the next dirty upload (admission
             # into some other slot) doesn't clobber live automaton states.
-            con_states, tok_block = jax.device_get((carry[2], tok_block))
+            con_states, tok_block = jax.device_get((con_states, tok_block))
         self.cache = cache
         self._commit_decode_block(tok_block, con_states, carry, d, prog_key)
 
@@ -4790,15 +4765,16 @@ class Engine:
         self,
         tok_block: np.ndarray,
         con_states: np.ndarray,
-        carry: tuple,
+        carry: jax.Array,
         d: dict,
         prog_key: str,
     ) -> None:
         """Host-side commit of one decode-block dispatch (split or fused):
-        re-seat the device-resident carry, mirror constraint states, commit
+        re-seat the device-resident carry (the lanes the program handed
+        back), mirror constraint states, commit
         each lane's tokens, and attribute the block's compute."""
         W = d["W"]
-        d["tokens"], d["seq_lens"], d["con_states"], d["budgets"], d["active"], d["rng"] = carry
+        d["lanes"] = carry
         self._con_states[:W] = con_states
         # tok_block: [K, W]
         K = tok_block.shape[0]
@@ -4933,12 +4909,16 @@ class Engine:
         starts = np.full(
             Bp, self.max_ctx if self.kv_layout == "slot" else 0, dtype=np.int32
         )
-        slots = np.zeros(Bp, dtype=np.int32)
+        slots = np.full(Bp, self._pad_slot, dtype=np.int32)
         for i, (slot, sl, st, n) in enumerate(batch):
             toks[i, :n] = sl.prefill_row[st : st + n]
             lengths[i] = n
             starts[i] = st
             slots[i] = slot
+        lanes = (
+            self._put(toks),
+            self._kv_lanes(lengths, starts, slots, [sl.request for _, sl, _, _ in batch]),
+        )
         if self.kv_layout == "paged":
             P = self.page_size
             page_ids = np.full((Bp, bucket // P), TRASH_PAGE, dtype=np.int32)
@@ -4952,54 +4932,23 @@ class Engine:
                 (Bp, self.max_pages_per_seq), TRASH_PAGE, dtype=np.int32
             )
             tables[:B] = self._block_tables[[slot for slot, _, _, _ in batch]]
-            lanes = (
-                self._put(toks), self._put(lengths), self._put(starts),
-                self._page_arg(page_ids, slots[:B], [sl.request for _, sl, _, _ in batch]),
-                self._put(tables),
-            )
-        else:
-            lanes = (
-                self._put(toks), self._put(lengths), self._put(starts),
-                self._put(slots),
-            )
+            lanes += (self._put(page_ids), self._put(tables))
         return lanes, bucket, Bp
 
     def _fuse_final_lanes(self, batch: list) -> tuple:
+        # acp: dispatch-lanes page_ids,tables
         """Lane arrays for the megastep's final-chunk phase: the shared
-        _prefill_lanes builder (the budget seam must have one home) padded
-        to a power-of-two batch. Padding lanes sample garbage that is
-        never committed; their writes land on the trash page / clamped
+        _prefill_lanes builder (the budget seam must have one home) at a
+        power-of-two width. Padding lanes sample garbage that is never
+        committed; their writes land on the trash page / clamped
         never-readable row exactly like _fuse_mid_lanes padding."""
         chunk = self._chunk_items(batch)
         starts = np.asarray([st for _, _, st, _ in batch], dtype=np.int32)
-        ln = self._prefill_lanes(chunk, starts)
         B = len(batch)
         Bp = 1 << (B - 1).bit_length()
+        ln = self._prefill_lanes(chunk, starts, width=Bp)
         bucket = ln["bucket"]
-
-        def pad(a, fill):
-            if Bp == B:
-                return a
-            out = np.full((Bp, *a.shape[1:]), fill, dtype=a.dtype)
-            out[:B] = a
-            return out
-
-        pad_start = self.max_ctx if self.kv_layout == "slot" else 0
-        self._rng, step_rng = jax.random.split(self._rng)
-        sample = (
-            step_rng,
-            self._put(pad(ln["temps"], 0)),
-            self._put(pad(ln["top_ks"], 0)),
-            self._put(pad(ln["top_ps"], 1.0)),
-            ln["table"],
-            self._put(pad(ln["con_states0"], 0)),
-            self._put(pad(ln["constrained0"], False)),
-            ln["min_close"],
-            self._put(pad(ln["budgets"], 1)),
-        )
-        toks_d = self._put(pad(ln["tokens"], 0))
-        lens_d = self._put(pad(ln["lengths"], 0))
-        starts_d = self._put(pad(starts, pad_start))
+        lanes = (self._put(ln["tokens"]), self._put(ln["lanes"]))
         if self.kv_layout == "paged":
             P = self.page_size
             page_ids = np.full((Bp, bucket // P), TRASH_PAGE, dtype=np.int32)
@@ -5010,18 +4959,11 @@ class Engine:
                 (Bp, self.max_pages_per_seq), TRASH_PAGE, dtype=np.int32
             )
             tables[:B] = self._block_tables[[slot for slot, _, _, _ in batch]]
-            model_lanes = (
-                toks_d, lens_d, starts_d,
-                self._page_arg(page_ids, ln["slots"], [r for r, _, _, _ in chunk]),
-                self._put(tables),
-            )
-        else:
-            model_lanes = (
-                toks_d, lens_d, starts_d, self._put(pad(ln["slots"], 0))
-            )
-        return (model_lanes, sample), bucket, Bp, chunk, ln
+            lanes += (self._put(page_ids), self._put(tables))
+        return (lanes, (ln["table"], ln["min_close"])), bucket, Bp, chunk, ln
 
     def _fuse_plain_lanes(self, batch: list) -> tuple:
+        # acp: dispatch-lanes page_ids
         """Lane arrays for the megastep's plain-prefill phase (paged
         layout only): start-0 finals whose whole row fits one chunk run
         the plain causal program's raw body — byte-for-byte the
@@ -5029,42 +4971,17 @@ class Engine:
         lanes sample garbage that is never committed and route every page
         write to TRASH_PAGE, exactly like _fuse_mid_lanes padding."""
         chunk = self._chunk_items(batch)
-        starts = np.zeros(len(batch), dtype=np.int32)
-        ln = self._prefill_lanes(chunk, starts)
         B = len(batch)
         Bp = 1 << (B - 1).bit_length()
+        ln = self._prefill_lanes(chunk, np.zeros(B, dtype=np.int32), width=Bp)
         bucket = ln["bucket"]
-
-        def pad(a, fill):
-            if Bp == B:
-                return a
-            out = np.full((Bp, *a.shape[1:]), fill, dtype=a.dtype)
-            out[:B] = a
-            return out
-
-        self._rng, step_rng = jax.random.split(self._rng)
-        sample = (
-            step_rng,
-            self._put(pad(ln["temps"], 0)),
-            self._put(pad(ln["top_ks"], 0)),
-            self._put(pad(ln["top_ps"], 1.0)),
-            ln["table"],
-            self._put(pad(ln["con_states0"], 0)),
-            self._put(pad(ln["constrained0"], False)),
-            ln["min_close"],
-            self._put(pad(ln["budgets"], 1)),
-        )
         P = self.page_size
         page_ids = np.full((Bp, bucket // P), TRASH_PAGE, dtype=np.int32)
         for i, (_req, _slot, pages, _m) in enumerate(chunk):
             assert pages is not None
             page_ids[i, : len(pages)] = pages
-        model_lanes = (
-            self._put(pad(ln["tokens"], 0)),
-            self._put(pad(ln["lengths"], 0)),
-            self._page_arg(page_ids, ln["slots"], [r for r, _, _, _ in chunk]),
-        )
-        return (model_lanes, sample), bucket, Bp, chunk, ln
+        lanes = (self._put(ln["tokens"]), self._put(ln["lanes"]), self._put(page_ids))
+        return (lanes, (ln["table"], ln["min_close"])), bucket, Bp, chunk, ln
 
     def _megastep_dispatch(  # acp: megastep-seam
         self,
@@ -5177,24 +5094,18 @@ class Engine:
                 fin_lanes, fin_bucket, fin_Bp, fin_chunk, fin_ln = (
                     self._fuse_final_lanes(finals)
                 )
-            dec_carry = dec_aux = None
+            dec_lanes = dec_aux = None
             if d is not None:
-                dec_carry = (
-                    d["tokens"], d["seq_lens"], d["con_states"], d["budgets"],
-                    d["active"], d["rng"],
-                )
+                dec_lanes = d["lanes"]
                 extra = (d["block_tables"],) if self.kv_layout == "paged" else ()
-                dec_aux = (
-                    d["temps"], d["top_ks"], d["top_ps"], d["table"],
-                    d["constrained"], d["min_close"], extra,
-                )
+                dec_aux = (d["table"], d["min_close"], extra)
             key = f"megastep[{self.kv_layout},{'+'.join(parts)}{tbl}]"
             new_shape = shape not in self._megastep_shapes
             self._megastep_shapes.add(shape)
             prof_t0 = self.profiler.start()
             cache, p_out, f_out, d_out, v_out = self._jit_megastep(
-                self.params, self.cache, swap_arg, mid_lanes, pl_lanes,
-                fin_lanes, dec_carry, dec_aux, ver,
+                self.params, self.cache, self._base_key, swap_arg, mid_lanes,
+                pl_lanes, fin_lanes, dec_lanes, dec_aux, ver,
             )
             self.megastep_dispatches += 1
             if new_shape:
@@ -5280,12 +5191,9 @@ class Engine:
         with self.profiler.phase("fetch"):
             # ONE host round trip for every phase's results (None phases fetch
             # nothing — device_get maps over the pytree)
-            carry = d_out[1] if d_out is not None else None
+            tok_block, con_states, carry = d_out if d_out is not None else (None,) * 3
             f_np, p_np, dec_fetch, ver_np = jax.device_get((
-                f_out,
-                p_out,
-                (carry[2], d_out[0]) if d_out is not None else None,
-                v_out,
+                f_out, p_out, (con_states, tok_block), v_out,
             ))
         with self.profiler.phase("commit"):
             self.cache = cache
@@ -5497,9 +5405,9 @@ class Engine:
           already owns, exactly like decode-block lookahead pages).
         - Device-resident decode state: the spec path syncs with the host
           every dispatch by construction (the drafter needs the sampled
-          tokens), so it re-uploads the small per-slot arrays each time and
-          marks ``_state_dirty`` — a later fallback block re-uploads the
-          carried state like any other dirty block.
+          tokens), so it packs and uploads its lanes each time (VERIFY,
+          engine/lanes.py) and marks ``_state_dirty`` — a later fallback
+          block re-uploads the carried state like any other dirty block.
         - Preemption/prefix cache: drafts are host-only; page pressure in
           ``_ensure_pages_for_block`` preempts exactly as in the block path
           (preempted slots are dropped from this dispatch).
@@ -5578,24 +5486,21 @@ class Engine:
                 budgets[slot] = budgets_eff[slot]
                 proposed[slot] = len(d)
             use_real = self._token_table is not None
-            self._rng, step_rng = jax.random.split(self._rng)
+            # three uploads: the draft rows, the lanes, the block tables
             args = [
                 self.params,
                 self.cache,
                 self._put(inputs),
-                self._put(n_input),
-                self._put(starts),
-                self._put(active),
-                step_rng,
-                self._put(self._temps[:W]),
-                self._put(self._top_ks[:W]),
-                self._put(self._top_ps[:W]),
+                self._put(VERIFY.pack(
+                    W, n=self._next_key_n(), n_input=n_input, starts=starts,
+                    active=active, force_reject=force_reject,
+                    temps=self._temps[:W], top_ks=self._top_ks[:W],
+                    top_ps=self._top_ps[:W], con_states=self._con_states[:W],
+                    constrained=self._constrained[:W], budgets=budgets,
+                )),
+                self._base_key,
                 self._token_table if use_real else self._dummy_table,
-                self._put(self._con_states[:W]),
-                self._put(self._constrained[:W]),
                 self._min_close if use_real else self._dummy_min_close,
-                self._put(budgets),
-                self._put(np.asarray(force_reject)),
             ]
             if self.kv_layout == "paged":
                 args.append(self._put(self._block_tables[:W]))
@@ -5611,7 +5516,7 @@ class Engine:
                 # (finals activated by the fallback join the NEXT cycle's
                 # lanes — per-request greedy bytes are unaffected).
                 if self._megastep_dispatch(
-                    pending, ver=tuple(args[2:]), ver_meta=ver_meta
+                    pending, ver=(*args[2:4], *args[5:]), ver_meta=ver_meta
                 ):
                     return True
                 self._dispatch_pending_split(pending)

@@ -68,9 +68,12 @@ by the engine (``engine.profiler``):
   partition its wall time. :meth:`cycle` wraps one loop iteration in a
   ``StepTraceAnnotation`` (``acp.cycle``, ``step_num=<n>``) once it has
   work; its self time (loop glue no phase covers) is the ``cycle`` row.
+  Beside them ``stats()`` counts ``cycles``, ``blocks`` (decode-block
+  dispatches) and ``uploads`` (:meth:`count_upload`: every host-to-device
+  upload ``Engine._put`` makes; a dirty decode block costs two at most).
 
 Cross-thread contract: the write side (``record``/``account``/
-``reclassify``/``phase``/``cycle``) runs on the engine thread; the read side (``stats`` /
+``reclassify``/``phase``/``cycle``/``count_upload``) runs on the engine thread; the read side (``stats`` /
 ``ledger`` / ``publish``) runs on REST scrape threads and takes the same
 lock — enforced by the acplint thread-ownership pass (read methods are
 declared ``# acp: cross-thread``; server code must go through them, never
@@ -231,6 +234,7 @@ class DispatchProfiler:
         # reading of the latest phase boundary, and the cycle counter
         self._phases: dict[str, list] = {}
         self._blocks = 0
+        self._uploads = 0
         self._local = threading.local()
         self._stamp = 0.0
         self._cycle_phase: Optional[_Phase] = None  # the open acp.cycle
@@ -256,6 +260,12 @@ class DispatchProfiler:
         return _Phase(self, name, jax.profiler.TraceAnnotation(
             "acp." + name, cycle=self.cycle_n if self._cycle_phase else 0,
         ))
+
+    def count_upload(self) -> None:
+        """One host-to-device upload by the engine thread (``Engine._put``):
+        beside ``blocks`` it says how many a decode block costs. Counted
+        whether or not the profiler is enabled: one add, no clock."""
+        self._uploads += 1
 
     def cycle(self, busy: bool) -> None:
         """A new iteration of the engine loop begins. The cycle of the
@@ -551,6 +561,7 @@ class DispatchProfiler:
                 "phases": phases,
                 "cycles": self.cycle_n,
                 "blocks": self._blocks,
+                "uploads": self._uploads,
             }
         return doc
 
