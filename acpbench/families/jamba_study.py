@@ -1,0 +1,103 @@
+"""The readings the jamba configuration's `check` limits were set from, made
+again by one command on the chip (not run by the benchmark):
+
+    python -m acpbench.families.jamba_study --config jamba2-3b-bf16-v5e1 --seeds 6 --engine
+
+For each seed, one line a reading: the sound program (`program`: the
+family's cache check as every run makes it), the cache's controls (`h_bf16`:
+the stored `h` rounded to bfloat16, the precision below the stated one;
+`zero_state`: the state zeroed at the hand-over from prefill to decode;
+`state_swap`: every slot handed its neighbour's state there; `kv_int8`: int8
+pages), the reference's (`ref_int8`, `ref_bf16`, `ref_h_bf16`, `ref_nonorm`,
+`ref_nobias`), and with `--engine` the engine's own path beside check.py's
+structural control `page_swap` (one page of 16 tokens holds another
+request's). The last lines give each number's smallest and largest over the
+seeds, a reading a line. The cache's readings carry the family's own numbers
+on the stored state (`state_rel_rms`, `state_16bit_share`: `jamba.py`'s
+module text). Like `acpbench.run`, the command refuses a machine whose device is
+not one TPU chip. `lfm2_study.py` is the same command for its family; the
+seed loop and the release of a seed's weights are its.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+
+from .. import check, spec, study
+from .lfm2_study import released
+
+CACHE = {"program": {}, "h_bf16": {"h_bf16": True}, "zero_state": {"zero_state": True},
+         "state_swap": {"state_swap": True}, "kv_int8": {"quantize_kv": True}}
+REFERENCE = ("ref_int8", "ref_bf16", "ref_h_bf16", "ref_nonorm", "ref_nobias")
+NUMBERS = ("logit_rel_rms", "prefill_rel_rms", "cache_excess", "state_rel_rms", "state_16bit_share", "greedy_regret",
+           "stream_mismatch")
+
+
+def one_seed(config: dict, seed: int, names, engine: bool) -> dict:
+    family = spec.family(config)
+    system = None
+    if engine:
+        from ..systems.engine import System
+
+        system = System(config, seed)
+        program_config, mesh, params = system.program_config, system.mesh, system.params
+    else:
+        program_config, mesh, params = study._engine_free_system(config, seed)
+    reference = functools.partial(family.reference_logits, config, params)
+    s = check.sample(config["check"], config["vocab_size"], config["engine"]["page_size"], seed)
+    want = check.reference_logits(reference, s)
+    out = {}
+    for name in names:
+        if name in CACHE:
+            *got, state = family.cache_readings(config, program_config, params, mesh, s, True, **CACHE[name])
+            out[name] = {**check.compare(tuple(got), want), **state}
+        else:
+            out[name] = check.compare(check.reference_logits(reference, s, lower=name[4:]), want)
+    if system is not None:
+        path = check.engine_path(system, s, config["check"]["engine_tokens"])
+        out["engine"] = check.engine_numbers(reference, s, path)
+        out["page_swap"] = {"greedy_regret": check.engine_numbers(reference, s, path, control=True)["greedy_regret"]}
+        system.stop()
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", default="jamba2-3b-bf16-v5e1")
+    ap.add_argument("--seeds", type=int, default=6)
+    ap.add_argument("--first-seed", type=int, default=3_700_000_001)
+    ap.add_argument("--readings", default=",".join([*CACHE, *REFERENCE]))
+    ap.add_argument("--engine", action="store_true")
+    args = ap.parse_args(argv)
+    names = [n for n in args.readings.split(",") if n]
+    unknown = [n for n in names if n not in CACHE and n not in REFERENCE]
+    if unknown:
+        raise SystemExit(f"unknown readings {unknown}; known: {', '.join([*CACHE, *REFERENCE])}")
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(spec.ROOT, ".jax_cache"))
+    conf = next(c for c in spec.benchmark()["configs"] if c["name"] == args.config)
+    config = spec.load_json(os.path.join(spec.ROOT, conf["file"]))
+    from ..run import devices_or_exit
+
+    devices_or_exit(1)  # the limits are the chip's readings: elsewhere the programs run other code
+    seen: dict = {}
+    for i in range(args.seeds):
+        seed = args.first_seed + 104729 * i
+        for name, numbers in one_seed(config, seed, names, args.engine).items():
+            print(f"[jamba_study] seed={seed} {name} {json.dumps(numbers)}", flush=True)
+            for key in NUMBERS:
+                if key in numbers:
+                    seen.setdefault((name, key), []).append(numbers[key])
+        released()
+    for (name, key), vals in seen.items():
+        print(f"[jamba_study] {name} {key}: min {min(vals):.6g} max {max(vals):.6g} over {len(vals)} seeds")
+    return 0
+
+
+if __name__ == "__main__":
+    code = main()
+    sys.stdout.flush()
+    os._exit(code)

@@ -3640,30 +3640,36 @@ class Engine:
 
     @_in_phase("launch")
     def _install_state(self, slot: int, state) -> None:  # acp: megastep-seam — once an admission that resumes
-        """``conv[:, slot] = state``: what a continuation that starts past
-        0 resumes from. ``state`` is a device or host array
-        [n_conv, taps-1, D], or an int: the slot whose snapshot to copy."""
+        """The slot's live state = ``state``: what a continuation that
+        starts past 0 resumes from. ``state`` is the family's tree for one
+        slot as ``_saved_state`` gave it (device arrays, or a host entry's
+        numpy leaves: whatever arrays the family keeps, the engine reads
+        none of them), or an int: the slot whose snapshot to copy."""
         if self._jit_install_state is None:
             install, saved = self._model.install_state, self._model.saved_state
             self._jit_install_state = (
                 jax.jit(lambda c, s, st: install(c, s, st), donate_argnums=(0,)),
                 jax.jit(lambda c, s, src: install(c, s, saved(c, src)), donate_argnums=(0,)),
             )
-        by_array, by_slot = self._jit_install_state
+        by_tree, by_slot = self._jit_install_state
         if isinstance(state, int):
             self.cache = by_slot(self.cache, jnp.int32(slot), jnp.int32(state))
         else:
-            if not isinstance(state, jax.Array):  # a host entry's
-                state = self._put(np.asarray(state))
-            self.cache = by_array(self.cache, jnp.int32(slot), state)
+            state = jax.tree_util.tree_map(  # a host entry's leaves go up; a device entry's stay
+                lambda a: a if isinstance(a, jax.Array) else self._put(np.asarray(a)), state
+            )
+            self.cache = by_tree(self.cache, jnp.int32(slot), state)
         self.state_restores += 1
 
-    def _saved_state(self, slot: int):  # acp: megastep-seam — once a prefix entry, swap-out or handoff
-        """A device copy of the slot's snapshot (its state at state_cut)."""
+    def _saved_state(self, slot: int, host: bool = False):  # acp: megastep-seam — once a prefix entry, swap-out or handoff
+        """A copy of the slot's snapshot (its state at state_cut) as the
+        family's tree: on the device, or with ``host`` as numpy leaves for a
+        host entry (``HostKVEntry.state``)."""
         if self._jit_saved_state is None:
             self._jit_saved_state = jax.jit(self._model.saved_state)
         self.state_saves += 1
-        return self._jit_saved_state(self.cache, jnp.int32(slot))
+        saved = self._jit_saved_state(self.cache, jnp.int32(slot))
+        return jax.tree_util.tree_map(np.asarray, saved) if host else saved
 
     # -- prefix KV cache (slot layout) -----------------------------------
 
@@ -6043,7 +6049,7 @@ class Engine:
             rid=f"handoff-{req.rid}", tokens=tuple(row[:cut]),
             k=out["k"], v=out["v"],
             k_scale=out.get("ks"), v_scale=out.get("vs"),
-            state=np.asarray(self._saved_state(slot)) if self._has_state else None,
+            state=self._saved_state(slot, host=True) if self._has_state else None,
         )
         self.flight.record(
             "handoff_export", rid=req.rid, slot=slot, tokens=cut,
@@ -6139,7 +6145,7 @@ class Engine:
                 rid=req.rid, tokens=tuple(row[:cut]),
                 k=rows["k"], v=rows["v"],
                 k_scale=rows.get("ks"), v_scale=rows.get("vs"),
-                state=np.asarray(self._saved_state(slot)) if self._has_state else None,
+                state=self._saved_state(slot, host=True) if self._has_state else None,
             )
         if not pool.put(entry):
             return False  # bigger than the whole budget: recompute instead

@@ -3,23 +3,28 @@
 ``programs(config)`` gives the engine a family's serving programs under the
 names the dense path has always had (``prefill_paged_batch``,
 ``prefill_paged_continue``, ``decode_step_paged``, ...), chosen by the type
-of the config. The Llama family's entries are ``models.llama``'s functions
-themselves. A family that keeps per-slot state beside the pages
-(``has_state``: ``models.lfm2``) receives, where the dense programs take
-the page ids alone, the pair ``(page_ids, (slots, snap_at))``: which slot's
-state each row reads and writes, and where its snapshot is due. What the
-state is made of stays the family's: the engine shards whatever tree
-``init_paged_cache`` puts under ``"state"`` whole, and copies a slot's part
-through ``install_state`` / ``saved_state``. A family that counts on the
-device gives ``counters(cache)`` (the array to read, inside a program) and
-``describe_counters(config, total) -> (stats key, dict)`` for
+of the config (``_FAMILIES``: one row a family). The Llama family's entries
+are ``models.llama``'s functions themselves. A family that keeps per-slot
+state beside the pages (``has_state``: ``models.lfm2``, ``models.jamba``)
+receives, where the dense programs take the page ids alone, the pair
+``(page_ids, (slots, snap_at))``: which slot's state each row reads and
+writes, and where its snapshot is due. What the state is made of stays the
+family's: a tree of arrays of whatever types and sizes (``lfm2``: one array
+of conv columns; ``jamba``: the recurrence's float32 ``h`` and the conv
+columns, two leaves). The engine shards whatever tree ``init_paged_cache``
+puts under ``"state"`` whole, and moves a slot's part as a tree too:
+``saved_state(cache, slot)`` gives it, ``install_state(cache, slot, tree)``
+takes it back, and between the two the engine only keeps it (on the device
+in a prefix entry, as numpy leaves in a host entry). A family that counts on
+the device gives ``counters(cache)`` (the array to read, inside a program)
+and ``describe_counters(config, total) -> (stats key, dict)`` for
 ``Engine.stats()``; ``counters=None`` keeps none. The two capabilities are
 apart: state without counters and counters without state both serve.
 """
 
 from types import SimpleNamespace
 
-from . import llama, lfm2
+from . import jamba, lfm2, llama
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -29,20 +34,22 @@ from .llama import (
     init_params,
     prefill,
 )
+from .jamba import JambaConfig
 from .lfm2 import Lfm2Config
 
 __all__ = [
-    "PRESETS", "LlamaConfig", "Lfm2Config", "decode_step", "forward", "init_kv_cache",
+    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "decode_step", "forward", "init_kv_cache",
     "init_params", "prefill", "preset", "programs",
 ]
 
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    for table in (llama.PRESETS, lfm2.PRESETS):
+    tables = [module.PRESETS for module in (llama, lfm2, jamba)]
+    for table in tables:
         if name in table:
             return table[name]
-    known = sorted(llama.PRESETS) + sorted(lfm2.PRESETS)
+    known = [n for table in tables for n in sorted(table)]
     raise KeyError(f"unknown model preset {name!r}; known: {', '.join(known)}")
 
 
@@ -61,21 +68,36 @@ _LLAMA = SimpleNamespace(
     decode_step_paged=llama.decode_step_paged,
 )
 
-_LFM2 = SimpleNamespace(
-    family="lfm2", has_state=True,
-    init_params=lfm2.init_params,
-    init_paged_cache=lfm2.init_paged_cache,
-    prefill_paged_batch=lambda params, cache, tokens, lengths, ids, config: (
-        lfm2.prefill_paged_batch(params, cache, tokens, lengths, ids[0], ids[1], config)),
-    prefill_paged_continue=lambda params, cache, tokens, lengths, starts, ids, tables, config: (
-        lfm2.prefill_paged_continue(params, cache, tokens, lengths, starts, ids[0], tables, ids[1], config)),
-    prefill_paged_continue_kv=lambda params, cache, tokens, lengths, starts, ids, tables, config: (
-        lfm2.prefill_paged_continue_kv(params, cache, tokens, lengths, starts, ids[0], tables, ids[1], config)),
-    decode_step_paged=lfm2.decode_step_paged,
-    install_state=lfm2.install_state, saved_state=lfm2.saved_state,
-    counters=lfm2.counters, describe_counters=lfm2.describe_counters,
-)
+def _with_state(family: str, m) -> SimpleNamespace:
+    """A family with per-slot state: its module's programs take ``lanes``
+    after the page ids; the engine hands both as one pair."""
+    return SimpleNamespace(
+        family=family, has_state=True,
+        init_params=m.init_params,
+        init_paged_cache=m.init_paged_cache,
+        prefill_paged_batch=lambda params, cache, tokens, lengths, ids, config: (
+            m.prefill_paged_batch(params, cache, tokens, lengths, ids[0], ids[1], config)),
+        prefill_paged_continue=lambda params, cache, tokens, lengths, starts, ids, tables, config: (
+            m.prefill_paged_continue(params, cache, tokens, lengths, starts, ids[0], tables, ids[1], config)),
+        prefill_paged_continue_kv=lambda params, cache, tokens, lengths, starts, ids, tables, config: (
+            m.prefill_paged_continue_kv(params, cache, tokens, lengths, starts, ids[0], tables, ids[1], config)),
+        decode_step_paged=m.decode_step_paged,
+        install_state=m.install_state, saved_state=m.saved_state,
+        counters=m.counters, describe_counters=m.describe_counters,
+    )
+
+
+_LFM2 = _with_state("lfm2", lfm2)
+_JAMBA = _with_state("jamba", jamba)
+_FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA}
 
 
 def programs(config) -> SimpleNamespace:
-    return _LFM2 if isinstance(config, Lfm2Config) else _LLAMA
+    """The family of ``config``'s type, or of the nearest listed type it
+    derives from; a config of no listed type is an error, not the dense
+    family served without its state."""
+    for kind in type(config).__mro__:
+        if kind in _FAMILIES:
+            return _FAMILIES[kind]
+    raise TypeError(f"no model family serves a {type(config).__name__}; "
+                    f"known: {', '.join(k.__name__ for k in _FAMILIES)}")

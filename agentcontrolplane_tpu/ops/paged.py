@@ -27,7 +27,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -411,9 +411,10 @@ class HostKVEntry:
     k_scale: Optional[np.ndarray] = None  # [L, cut, H_kv] f32
     v_scale: Optional[np.ndarray] = None
     # families with per-slot state beside the pages: the state after
-    # exactly ``cut`` tokens ([n_conv, taps-1, D]); a restore resumes from
-    # it, and an entry without one is a miss for such a family
-    state: Optional[np.ndarray] = None
+    # exactly ``cut`` tokens, the family's tree for one slot with numpy
+    # leaves (one array or several, of whatever types); a restore resumes
+    # from it, and an entry without one is a miss for such a family
+    state: Optional[Any] = None
 
     @property
     def cut(self) -> int:
@@ -425,7 +426,7 @@ class HostKVEntry:
         if self.k_scale is not None:
             n += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
         if self.state is not None:
-            n += int(self.state.nbytes)
+            n += sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.state))
         return n
 
 

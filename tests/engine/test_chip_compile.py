@@ -312,3 +312,93 @@ def test_lfm2_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, monke
     assert mem.temp_size_in_bytes < pool // 4, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB beside a {pool / 1e6:.0f} MB pool"
     resident = mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert 0.25 * 16e9 < resident < 16e9, f"{resident / 1e9:.2f} GB"
+
+
+# -- the jamba family: the walk at one KV head, the recurrence's two kernels, the programs' memory -----
+
+_JAMBA_SLOTS, _JAMBA_PAGES = 128, 16385  # acpbench/configs/jamba2-3b-bf16-v5e1.json
+
+
+def _jamba(v5e, monkeypatch):
+    """The published config, abstract weights and cache placed on one
+    described chip, and the programs steered onto their kernels (here the
+    backend is the CPU, which they would serve by the XLA reference)."""
+    from agentcontrolplane_tpu.models import jamba
+
+    import functools
+
+    monkeypatch.setattr(jamba.ssm, "scan", functools.partial(jamba.ssm.scan, kernel=True))
+    monkeypatch.setattr(jamba.ssm, "update", functools.partial(jamba.ssm.update, kernel=True))
+    c = jamba.PRESETS["jamba2-3b"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: jamba.init_params(c, jax.random.key(0))))
+    cache = place(jax.eval_shape(lambda: jamba.init_paged_cache(c, _JAMBA_PAGES, PAGE, max_slots=_JAMBA_SLOTS)))
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return jamba, c, params, cache, vec
+
+
+def _resident(compiled) -> int:
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + mem.temp_size_in_bytes)
+
+
+def test_jamba_walk_compiles_at_one_kv_head_and_a_group_of_twenty(v5e):
+    text = _compile_walk(v5e, 20, 1, 128, jnp.bfloat16, False).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_jamba_decode_block_updates_the_state_in_place(v5e, monkeypatch):
+    """128 lanes of the published model, steps in a loop as the engine's
+    decode block nests them: the page walk and the update kernel are in the
+    one layer body, the whole state (2.40 GB with its snapshot) is aliased
+    from argument to result, no op copies the stack of `h` (a conditional
+    that handed it through unchanged did, once a layer: 6.6 ms a step on
+    the chip, PERF.md PR 37; one step alone compiled without it) and the
+    block's temporaries are a small fraction of the state."""
+    import re
+
+    jamba, c, params, cache, vec = _jamba(v5e, monkeypatch)
+    S = _JAMBA_SLOTS
+
+    def block(p, ca, tok, n, tables, active):
+        def step(carry, _):
+            ca, tok, n = carry
+            ca, logits = jamba.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (ca, tok, n + 1), tok
+
+        (ca, _, _), toks = jax.lax.scan(step, (ca, tok, n), None, length=4)
+        return ca, toks
+
+    compiled = jax.jit(block, donate_argnums=(1,)).lower(
+        params, cache, vec(S), vec(S), vec(S, 2048 // PAGE), vec(S, dt=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "ssm_update" in text and "paged_page_walk" in text
+    state = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache["state"]))
+    mem = compiled.memory_analysis()
+    assert state > 2.3e9 and mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 6, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB"
+    stack = rf"f32\[{c.n_mamba},{S + 1},{c.d_state},{c.d_inner}\]"
+    assert re.search(stack, text)
+    assert not re.search(rf"= {stack}\S* copy\(", text), "a copy of the whole stack of h"
+    assert f"f32[{S},{c.d_state},{c.d_inner}]" not in text, "a layer's lanes of the state as a value of their own"
+    assert _resident(compiled) < 10.5e9
+
+
+def test_jamba_prefill_fits_beside_the_resident_set(v5e, monkeypatch):
+    """The widest prefill the file admits (4 rows of 512) with the scan kernel: weights,
+    state and pool resident, the temporaries beside them, under the chip's
+    16 GB; and no [T, d_state, d_inner] array of the recurrence anywhere."""
+    jamba, c, params, cache, vec = _jamba(v5e, monkeypatch)
+    B, T = 4, 512
+    compiled = jax.jit(
+        lambda p, ca, tok, n, ids, slots, snap: jamba.prefill_paged_batch(p, ca, tok, n, ids, (slots, snap), c),
+        donate_argnums=(1,),
+    ).lower(params, cache, vec(B, T), vec(B), vec(B, T // PAGE), vec(B), vec(B)).compile()
+    text = compiled.as_text()
+    assert "ssm_scan" in text
+    assert f"{T},{c.d_state},{c.d_inner}]" not in text and f"{T},{c.d_inner},{c.d_state}]" not in text
+    assert _resident(compiled) < 11e9, f"{_resident(compiled) / 1e9:.1f} GB"

@@ -5,7 +5,7 @@ covers the live slots, the prefill bucket that covers the longest prompt of
 an admission group, the group's size, the decode block's step count. The
 benchmark's ``correct`` reaches one of each (prefill bucket 256, width 8,
 groups of four: PERF.md section 2, "What ``correct`` does not reach"), so
-here one prompt goes through every other one, in both model families, and
+here one prompt goes through every other one, in every model family, and
 its tokens are held to the model's own full forward (no cache, no state,
 no padding to a shape). Each case also reads back, from the profiler's
 program keys or the flight record, that the shape it names is the shape
@@ -13,7 +13,7 @@ that ran.
 
 CPU, float32, the paged layout, seeded weights. Identity of tokens is the
 bar, as in the byte-identity matrices of test_chunked_prefill.py and
-test_megastep.py: at these sizes both families give it (a near-tie in the
+test_megastep.py: at these sizes every family gives it (a near-tie in the
 logits could flip a token under another padding; none does for this probe).
 """
 
@@ -26,10 +26,10 @@ import numpy as np
 import pytest
 
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
-from agentcontrolplane_tpu.models import lfm2, llama, preset
+from agentcontrolplane_tpu.models import jamba, lfm2, llama, preset
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
-FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2)}
+FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2), "jamba": ("jamba-tiny", jamba)}
 N_TOKENS = 24
 GREEDY = SamplingParams(temperature=0.0, max_tokens=N_TOKENS)
 # outlive the probe, so the width it decodes at holds until it is done
