@@ -49,7 +49,7 @@ import numpy as np
 from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
 from ..observability.metrics import REGISTRY
 from ..ops.paged import TRASH_PAGE, set_pages
-from ..ops.sampling import sample
+from ..ops.sampling import NEG_INF, masks_wanted, sample
 from ..parallel.mesh import (
     kv_cache_shardings,
     param_shardings,
@@ -315,6 +315,114 @@ def _pow2_chunks(items: list, max_chunk: int) -> list[list]:
         out.append(items[i : i + b])
         i += b
     return out
+
+
+# -- what every sampling program nests around its model step ------------------
+
+def constrain_logits(logits, table, con_state, constrained, min_close, budget):
+    """Mask logits to grammar-legal tokens for constrained slots.
+    ``budget`` [S] = sampled tokens remaining INCLUDING this one:
+    tokens are additionally restricted to those whose next state can
+    still close the JSON within budget-1, so constrained generations
+    ALWAYS complete inside max_tokens (no truncated objects)."""
+    nxt = table[jnp.clip(con_state, 0, table.shape[0] - 1)]  # [S, W]
+    # the table is as wide as the TOKENIZER's vocab; logits are as
+    # wide as the model's. Tokens beyond the table are forbidden:
+    # pad the gathered rows, never the [states, V] table (at a
+    # 152k vocab the padded table alone is ~5 GB of HBM)
+    nxt = jnp.pad(
+        nxt, ((0, 0), (0, logits.shape[-1] - nxt.shape[-1])),
+        constant_values=-1,
+    )  # [S, V]
+    allowed = nxt >= 0
+    closable = (
+        min_close[jnp.clip(nxt, 0, min_close.shape[0] - 1)]
+        <= budget[:, None] - 1
+    )
+    budget_allowed = allowed & closable
+    # if the budget is already unsatisfiable, keep plain grammar
+    # legality rather than masking everything (never sample garbage)
+    feasible = budget_allowed.any(axis=-1, keepdims=True)
+    allowed = jnp.where(feasible, budget_allowed, allowed)
+    return jnp.where(constrained[:, None] & ~allowed, jnp.float32(NEG_INF), logits)
+
+
+def advance_constraint(table, con_state, constrained, toks):
+    width = table.shape[1]
+    nxt = table[
+        jnp.clip(con_state, 0, table.shape[0] - 1),
+        jnp.minimum(toks, width - 1),
+    ]
+    nxt = jnp.where(toks < width, nxt, -1)  # beyond the table: illegal
+    return jnp.where(constrained, nxt, con_state)
+
+
+def sample_lanes(logits, key, ln, table, min_close):
+    """Constrained sampling for a [B] batch of first tokens, from a
+    prefill dispatch's unpacked lanes and the key of its counter."""
+    logits = constrain_logits(
+        logits, table, ln["con_states"], ln["constrained"], min_close, ln["budgets"]
+    )
+    toks = sample(
+        logits, dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"], ln["top_ps"]
+    )
+    return toks, advance_constraint(table, ln["con_states"], ln["constrained"], toks)
+
+
+def make_decode_block(step_fn, stop_toks: tuple, max_ctx: int, block_size: int):
+    """The K-step decode block around a layout's ``step_fn``. ``stop_toks``
+    and ``max_ctx`` are trace-time constants: finish detection runs ON
+    DEVICE so decode blocks can chain device-resident state (see
+    _decode_once) — a slot that samples a stop token, exhausts its budget,
+    or hits the context edge deactivates itself mid-block and stops
+    advancing/writing, keeping the device state consistent with the host's
+    bookkeeping without a per-block re-upload."""
+
+    def decode_block(params, cache, lanes, key, table, min_close, *extra):
+        ln = DECODE.unpack(lanes)
+        temps, top_ks, top_ps = ln["temps"], ln["top_ks"], ln["top_ps"]
+        constrained = ln["constrained"]
+        # the rows a block samples by do not change inside it, and a lane
+        # only ever goes dead: asked once, of the lanes live at its start
+        wanted = masks_wanted(top_ks, top_ps, ln["active"])
+
+        def step(carry, _):
+            cache, tokens, seq_lens, con_states, budgets, active, rng = carry
+            rng, sub = jax.random.split(rng)
+            cache, logits = step_fn(params, cache, tokens, seq_lens, active, *extra)
+            logits = constrain_logits(
+                logits, table, con_states, constrained, min_close, budgets
+            )
+            next_toks = sample(logits, sub, temps, top_ks, top_ps, wanted)
+            next_toks = jnp.where(active, next_toks, tokens)
+            con_states = advance_constraint(table, con_states, constrained, next_toks)
+            seq_lens = seq_lens + active.astype(jnp.int32)
+            budgets = budgets - active.astype(jnp.int32)
+            is_stop = jnp.zeros_like(active)
+            for st in stop_toks:
+                is_stop = is_stop | (next_toks == st)
+            active = active & ~is_stop & (budgets > 0) & (seq_lens + 1 < max_ctx)
+            return (cache, next_toks, seq_lens, con_states, budgets, active, rng), next_toks
+
+        (cache, tokens, seq_lens, con_states, budgets, active, _), toks = jax.lax.scan(
+            step,
+            (cache, ln["tokens"], ln["seq_lens"], ln["con_states"], ln["budgets"],
+             ln["active"], dispatch_key(key, ln["n"], ln["chain"])),
+            None, length=block_size,
+        )
+        # the carry is the lanes themselves, donated and handed back:
+        # a block nothing dirtied feeds them in again as they are, and
+        # draws from the next key of this dispatch's chain
+        lanes = DECODE.update(
+            lanes, tokens=tokens, seq_lens=seq_lens, con_states=con_states,
+            budgets=budgets, active=active, chain=ln["chain"] + 1,
+        )
+        return cache, toks, con_states, lanes
+
+    # raw (unjitted): the split path jits it standalone; the fused
+    # megastep composes the same body so both paths trace the same
+    # graph per phase
+    return decode_block
 
 
 class Engine:
@@ -898,6 +1006,12 @@ class Engine:
         self.decode_steps = 0
         self.tokens_generated = 0
         self.table_uploads = 0  # paged: block-table host->device re-uploads
+        # dispatches that sample (decode blocks, verify passes, prefill
+        # groups), and those of them in which a live lane asked for top-k /
+        # top-p, so that the program ran that threshold search (ops/sampling.py)
+        self.sampling_dispatches = 0
+        self.sampling_topk_dispatches = 0
+        self.sampling_topp_dispatches = 0
         self.max_queue = max(0, max_queue)
         self.preemptions = 0  # pool-pressure preempt-and-resume events
         # chunked prefill + unified token-budget scheduler (see _dispatch_once
@@ -1085,6 +1199,13 @@ class Engine:
             return self._jit_upload_copy(out)
         return out
 
+    def _count_sampling(self, wanted: tuple) -> None:
+        """One sampling dispatch whose lanes' ``masks_wanted`` is ``wanted``:
+        the question the program asks of the same numbers on the chip."""
+        self.sampling_dispatches += 1
+        self.sampling_topk_dispatches += bool(wanted[0])
+        self.sampling_topp_dispatches += bool(wanted[1])
+
     def _next_key_n(self) -> int:
         """The counter of the next dispatch that draws: it rides the
         dispatch's lanes and the program mixes it into the base key
@@ -1103,107 +1224,7 @@ class Engine:
         stop token). The block builder is shared across layouts — only the
         per-step cache update differs."""
         config = self.config
-        NEG = jnp.float32(-1e30)
-
-        def constrain_logits(logits, table, con_state, constrained, min_close, budget):
-            """Mask logits to grammar-legal tokens for constrained slots.
-            ``budget`` [S] = sampled tokens remaining INCLUDING this one:
-            tokens are additionally restricted to those whose next state can
-            still close the JSON within budget-1, so constrained generations
-            ALWAYS complete inside max_tokens (no truncated objects)."""
-            nxt = table[jnp.clip(con_state, 0, table.shape[0] - 1)]  # [S, W]
-            # the table is as wide as the TOKENIZER's vocab; logits are as
-            # wide as the model's. Tokens beyond the table are forbidden:
-            # pad the gathered rows, never the [states, V] table (at a
-            # 152k vocab the padded table alone is ~5 GB of HBM)
-            nxt = jnp.pad(
-                nxt, ((0, 0), (0, logits.shape[-1] - nxt.shape[-1])),
-                constant_values=-1,
-            )  # [S, V]
-            allowed = nxt >= 0
-            closable = (
-                min_close[jnp.clip(nxt, 0, min_close.shape[0] - 1)]
-                <= budget[:, None] - 1
-            )
-            budget_allowed = allowed & closable
-            # if the budget is already unsatisfiable, keep plain grammar
-            # legality rather than masking everything (never sample garbage)
-            feasible = budget_allowed.any(axis=-1, keepdims=True)
-            allowed = jnp.where(feasible, budget_allowed, allowed)
-            return jnp.where(constrained[:, None] & ~allowed, NEG, logits)
-
-        def advance_constraint(table, con_state, constrained, toks):
-            width = table.shape[1]
-            nxt = table[
-                jnp.clip(con_state, 0, table.shape[0] - 1),
-                jnp.minimum(toks, width - 1),
-            ]
-            nxt = jnp.where(toks < width, nxt, -1)  # beyond the table: illegal
-            return jnp.where(constrained, nxt, con_state)
-
-        def sample_lanes(logits, key, ln, table, min_close):
-            """Constrained sampling for a [B] batch of first tokens, from a
-            prefill dispatch's unpacked lanes and the key of its counter."""
-            logits = constrain_logits(
-                logits, table, ln["con_states"], ln["constrained"], min_close, ln["budgets"]
-            )
-            toks = sample(
-                logits, dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"], ln["top_ps"]
-            )
-            return toks, advance_constraint(table, ln["con_states"], ln["constrained"], toks)
-
-        def make_decode_block(step_fn):
-            # trace-time constants: finish detection runs ON DEVICE so decode
-            # blocks can chain device-resident state (see _decode_once) —
-            # a slot that samples a stop token, exhausts its budget, or hits
-            # the context edge deactivates itself mid-block and stops
-            # advancing/writing, keeping the device state consistent with the
-            # host's bookkeeping without a per-block re-upload.
-            stop_toks = tuple(sorted({int(t) for t in self.tokenizer.stop_tokens}))
-            max_ctx = self.max_ctx
-
-            def decode_block(params, cache, lanes, key, table, min_close, *extra):
-                ln = DECODE.unpack(lanes)
-                temps, top_ks, top_ps = ln["temps"], ln["top_ks"], ln["top_ps"]
-                constrained = ln["constrained"]
-
-                def step(carry, _):
-                    cache, tokens, seq_lens, con_states, budgets, active, rng = carry
-                    rng, sub = jax.random.split(rng)
-                    cache, logits = step_fn(params, cache, tokens, seq_lens, active, *extra)
-                    logits = constrain_logits(
-                        logits, table, con_states, constrained, min_close, budgets
-                    )
-                    next_toks = sample(logits, sub, temps, top_ks, top_ps)
-                    next_toks = jnp.where(active, next_toks, tokens)
-                    con_states = advance_constraint(table, con_states, constrained, next_toks)
-                    seq_lens = seq_lens + active.astype(jnp.int32)
-                    budgets = budgets - active.astype(jnp.int32)
-                    is_stop = jnp.zeros_like(active)
-                    for st in stop_toks:
-                        is_stop = is_stop | (next_toks == st)
-                    active = active & ~is_stop & (budgets > 0) & (seq_lens + 1 < max_ctx)
-                    return (cache, next_toks, seq_lens, con_states, budgets, active, rng), next_toks
-
-                (cache, tokens, seq_lens, con_states, budgets, active, _), toks = jax.lax.scan(
-                    step,
-                    (cache, ln["tokens"], ln["seq_lens"], ln["con_states"], ln["budgets"],
-                     ln["active"], dispatch_key(key, ln["n"], ln["chain"])),
-                    None, length=self.decode_block_size,
-                )
-                # the carry is the lanes themselves, donated and handed back:
-                # a block nothing dirtied feeds them in again as they are, and
-                # draws from the next key of this dispatch's chain
-                lanes = DECODE.update(
-                    lanes, tokens=tokens, seq_lens=seq_lens, con_states=con_states,
-                    budgets=budgets, active=active, chain=ln["chain"] + 1,
-                )
-                return cache, toks, con_states, lanes
-
-            # raw (unjitted): the split path jits it standalone; the fused
-            # megastep composes the same body so both paths trace the same
-            # graph per phase
-            return decode_block
+        stop_toks = tuple(sorted({int(t) for t in self.tokenizer.stop_tokens}))
 
         def make_verify(verify_fn):
             """Speculative verify + on-device accept in one dispatch: the
@@ -1214,8 +1235,6 @@ class Engine:
             greedy output is byte-identical to spec-off. One fetch returns
             (tokens, emitted counts, constraint states)."""
             from ..ops.sampling import speculative_accept
-
-            stop_toks = tuple(sorted({int(t) for t in self.tokenizer.stop_tokens}))
 
             def verify_block(params, cache, inputs, lanes, key, table, min_close, *extra):
                 ln = VERIFY.unpack(lanes)
@@ -1351,7 +1370,8 @@ class Engine:
                 lambda params, pages, tokens, seq_lens, active, block_tables: decode_step_paged(
                     params, pages, tokens, seq_lens, block_tables, active, config,
                     use_pallas=use_pallas, mesh=mesh,
-                )
+                ),
+                stop_toks, self.max_ctx, self.decode_block_size,
             )
             self._jit_decode_paged = self._with_counters(decode_block)(
                 lambda f: jax.jit(f, donate_argnums=(1, 2))
@@ -1403,7 +1423,8 @@ class Engine:
             decode_block = make_decode_block(
                 lambda params, cache, tokens, seq_lens, active: decode_step(
                     params, cache, tokens, seq_lens, config, active=active
-                )
+                ),
+                stop_toks, self.max_ctx, self.decode_block_size,
             )
             self._jit_decode = jax.jit(decode_block, donate_argnums=(1, 2))
             verify_continue = self._model.verify_continue
@@ -2176,6 +2197,12 @@ class Engine:
             # cold-compile tracking, goodput/waste ledger (the profiler's
             # stats() is its declared cross-thread read surface)
             "perf": self.profiler.stats(),
+            # how often the sampler's threshold searches engage
+            "sampling": {
+                "dispatches": self.sampling_dispatches,
+                "topk_dispatches": self.sampling_topk_dispatches,
+                "topp_dispatches": self.sampling_topp_dispatches,
+            },
         }
         if self.kv_layout == "paged":
             out["kv_pages"] = {
@@ -4111,6 +4138,7 @@ class Engine:
                 seed = tuple(s.forced_prefix) + tuple(req.resume_tokens)
                 con_states0[i] = self._seed_con_state(seed) if seed else self._table_start
                 constrained0[i] = True
+        self._count_sampling(masks_wanted(top_ks, top_ps))
         return {
             "bucket": bucket, "tokens": tokens, "lengths": lengths,
             "full_lens": full_lens, "table": table, "min_close": min_close,
@@ -4674,6 +4702,8 @@ class Engine:
                 )),
                 "table": self._token_table if use_real else self._dummy_table,
                 "min_close": self._min_close if use_real else self._dummy_min_close,
+                # every block fed from these lanes is counted by them
+                "masks": masks_wanted(self._top_ks[:W], self._top_ps[:W], active_mask),
             }
             self._state_dirty = False
         d = self._dev
@@ -4780,6 +4810,7 @@ class Engine:
         back), mirror constraint states, commit
         each lane's tokens, and attribute the block's compute."""
         W = d["W"]
+        self._count_sampling(d["masks"])
         d["lanes"] = carry
         self._con_states[:W] = con_states
         # tok_block: [K, W]
@@ -5492,6 +5523,7 @@ class Engine:
                 budgets[slot] = budgets_eff[slot]
                 proposed[slot] = len(d)
             use_real = self._token_table is not None
+            self._count_sampling(masks_wanted(self._top_ks[:W], self._top_ps[:W], active))
             # three uploads: the draft rows, the lanes, the block tables
             args = [
                 self.params,

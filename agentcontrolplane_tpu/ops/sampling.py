@@ -11,6 +11,12 @@ stack). Both masks only need a *threshold*, so we binary-search the
 threshold value instead: ~32 fused compare+reduce passes, an order of
 magnitude cheaper, and exact up to float bisection (ties at the boundary
 are all kept — the sort-based variant kept an arbitrary subset of ties).
+
+Each search runs only when a live lane of the batch asks for it
+(``masks_wanted``): at a 152k vocabulary the 64 passes and their ~200 kernel
+launches cost a decode step more than the draw itself, and at tp > 1,
+where the logits are sharded over the vocabulary, each is an all-reduce
+that waits for the one before (PERF.md, PR 39).
 """
 
 from __future__ import annotations
@@ -22,10 +28,11 @@ NEG_INF = -1e30
 _BISECT_ITERS = 32
 
 
-def _topk_threshold(logits: jax.Array, k: jax.Array) -> jax.Array:
+def _topk_threshold(logits: jax.Array, k: jax.Array, turns=_BISECT_ITERS) -> jax.Array:
     """Per-row value t such that count(logits >= t) >= k and masking
     logits < t keeps the k largest (plus boundary ties). k >= V keeps all.
-    [S, V], [S] -> [S, 1]."""
+    [S, V], [S] -> [S, 1]. After no turn t is the row's minimum, which
+    masks nothing."""
     lo = jnp.min(logits, axis=-1)  # threshold below lowest keeps everything
     hi = jnp.max(logits, axis=-1)
 
@@ -36,7 +43,7 @@ def _topk_threshold(logits: jax.Array, k: jax.Array) -> jax.Array:
         ok = count >= k  # mid keeps enough -> can raise the floor
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
 
-    lo, hi = jax.lax.fori_loop(0, _BISECT_ITERS, body, (lo, hi))
+    lo, hi = jax.lax.fori_loop(0, turns, body, (lo, hi))
     return lo[:, None]
 
 
@@ -61,8 +68,20 @@ def _topp_threshold(
 
     lo, hi = jax.lax.fori_loop(0, _BISECT_ITERS, body, (lo, hi))
     # hi is the smallest valid prob threshold; the call site compares in
-    # prob space directly (no need to map back to logits)
+    # prob space and keeps the smallest logit that passes
     return hi[:, None], probs
+
+
+def masks_wanted(top_k, top_p, active=None) -> tuple:
+    """Which threshold searches a batch asks for: ``(top-k, top-p)``, true
+    when some LIVE lane set ``top_k > 0`` / ``top_p < 1.0``. ``active``
+    [S] bool says which lanes are live (``None``: all of them); a finished
+    request's values stay in its slot until the slot is reused, and a dead
+    lane's token is thrown away, so it must not turn a search on. Written
+    on array methods alone: the engine asks the same question of the same
+    numbers on the host (numpy) when it counts ``stats()["sampling"]``."""
+    live = True if active is None else active
+    return (live & (top_k > 0)).any(), (live & (top_p < 1.0)).any()
 
 
 def speculative_accept(
@@ -105,12 +124,14 @@ def speculative_accept(
         [inputs[:, 1:], jnp.zeros((S, 1), inputs.dtype)], axis=1
     )
 
+    wanted = masks_wanted(top_k, top_p, active)
+
     def step(carry, xs):
         emitting, state, budget, rng = carry
         logits_i, cand_i, has_draft = xs
         l = constrain_fn(logits_i, state, budget) if constrain_fn is not None else logits_i
         rng, sub = jax.random.split(rng)
-        tok = sample(l, sub, temperature, top_k, top_p)
+        tok = sample(l, sub, temperature, top_k, top_p, wanted)
         out_i = jnp.where(emitting, tok, -1)
         take = emitting
         budget = budget - take.astype(budget.dtype)
@@ -140,19 +161,47 @@ def sample(
     temperature: jax.Array,  # [S]
     top_k: jax.Array,  # [S] int32, 0 = disabled
     top_p: jax.Array,  # [S] float32, 1.0 = disabled
+    wanted: tuple | None = None,  # masks_wanted(...) of the batch's live lanes
 ) -> jax.Array:
-    """Returns sampled token ids [S]."""
+    """Returns sampled token ids [S].
+
+    Each threshold search runs only when the batch asks for it
+    (``wanted``: computed here over all lanes for a caller with no
+    ``active`` row, and by the caller once where it samples in a loop whose
+    rows do not change). A batch in which no live lane asks draws
+    ``categorical(logits / temperature)`` over the unmasked logits, which is
+    what 0 and 1.0 mean above; one in which some lane asks runs that search
+    for the whole batch, as it always did.
+
+    How each is switched off is what the chip's compiler made of it
+    (tests/engine/test_chip_compile.py holds both): the top-k search is its
+    own loop making no turn, because a conditional around a loop over the
+    logits moved them out of the fast memory every later pass reads them
+    from; the top-p search, which needs a softmax first, is one
+    ``lax.cond`` that hands back a LOGIT-space threshold a row, so what
+    crosses its edge is rank 1 and its skipping branch writes nothing."""
     logits = logits.astype(jnp.float32)
     S, V = logits.shape
+    want_k, want_p = masks_wanted(top_k, top_p) if wanted is None else wanted
 
     # top-k mask: keep the k largest (k==0 -> keep all)
     k = jnp.where(top_k > 0, top_k, V)
-    kth = _topk_threshold(logits, k)
-    logits = jnp.where(logits < kth, NEG_INF, logits)
+    kth = _topk_threshold(logits, k, jnp.where(want_k, _BISECT_ITERS, 0))[:, 0]
 
-    # top-p (nucleus) mask over the remaining distribution
-    p_thresh, probs = _topp_threshold(logits, top_p)
-    logits = jnp.where(probs < p_thresh, NEG_INF, logits)
+    # top-p (nucleus) mask over what top-k left. The search compares in
+    # probability space; the smallest kept logit selects the same set
+    # (softmax is monotone in the logit, ties included) and is at or above
+    # kth, so one compare applies both masks
+    def nucleus():
+        kept = jnp.where(logits < kth[:, None], NEG_INF, logits)
+        p_thresh, probs = _topp_threshold(kept, top_p)
+        return jnp.min(jnp.where(probs < p_thresh, jnp.inf, kept), axis=-1)
+
+    thresh = jax.lax.cond(want_p, nucleus, lambda: kth)
+    # without the barrier the compiler moves the broadcast below into the
+    # conditional, whose skipping branch then writes [S, V] of it
+    thresh = jax.lax.optimization_barrier(thresh)
+    logits = jnp.where(logits < thresh[:, None], NEG_INF, logits)
 
     greedy = jnp.argmax(logits, axis=-1)
     temp = jnp.maximum(temperature, 1e-6)[:, None]

@@ -167,17 +167,18 @@ _DECODE_STEPS = [
 _DEPTH = 4  # of 28 / 64 layers: the scan's body is compiled once whatever the depth
 
 
-def _compile_llama_decode_step(v5e, widths, pages, slots, tp, int8_pages):
-    """`models.llama.decode_step_paged` with the Pallas walk at a Qwen2.5
-    configuration's widths (int8 weights, depth cut to `_DEPTH`), over a
-    pool of the cell's pages, on one described chip or a tp mesh of them."""
+def _llama_operands(v5e, widths, pages, tp, depth, int8_pages=False):
+    """A Qwen2.5 configuration's widths at ``depth`` layers on one described
+    chip or a tp mesh of them: the config, the mesh, abstract int8 weights
+    and a pool of the cell's pages placed as the engine places them, and a
+    maker of replicated int32 (or other) operands."""
     import dataclasses
 
-    from agentcontrolplane_tpu.models.llama import decode_step_paged, init_paged_cache, init_params
+    from agentcontrolplane_tpu.models.llama import init_paged_cache, init_params
     from agentcontrolplane_tpu.ops.quant import QuantizedTensor, quantize_params
     from agentcontrolplane_tpu.parallel.mesh import param_shardings
 
-    c = dataclasses.replace(PRESETS["qwen2.5-7b"], n_layers=_DEPTH, **widths)
+    c = dataclasses.replace(PRESETS["qwen2.5-7b"], n_layers=depth, **widths)
     mesh = Mesh(v5e[:tp], ("tp",))
     named = lambda *spec: NamedSharding(mesh, P(*spec))  # noqa: E731
     plain = jax.eval_shape(lambda: init_params(c, jax.random.key(0)))
@@ -197,11 +198,21 @@ def _compile_llama_decode_step(v5e, widths, pages, slots, tp, int8_pages):
     # engine.py's page_spec: axis 3 (the row's heads, or a scale a head) over tp
     cache = place(cache, {name: named(None, None, None, "tp") for name in cache})
     vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=named())  # noqa: E731
+    return c, mesh, place(params, shardings), cache, vec
+
+
+def _compile_llama_decode_step(v5e, widths, pages, slots, tp, int8_pages):
+    """`models.llama.decode_step_paged` with the Pallas walk at a Qwen2.5
+    configuration's widths (int8 weights, depth cut to `_DEPTH`), over a
+    pool of the cell's pages, on one described chip or a tp mesh of them."""
+    from agentcontrolplane_tpu.models.llama import decode_step_paged
+
+    c, mesh, params, cache, vec = _llama_operands(v5e, widths, pages, tp, _DEPTH, int8_pages)
     compiled = jax.jit(
         lambda p, ca, tok, n, tables, active: decode_step_paged(
             p, ca, tok, n, tables, active, c, use_pallas=True, mesh=mesh),
         donate_argnums=(1,),
-    ).lower(place(params, shardings), cache, vec(slots), vec(slots), vec(slots, 1792 // PAGE),
+    ).lower(params, cache, vec(slots), vec(slots), vec(slots, 1792 // PAGE),
             vec(slots, dt=jnp.bool_)).compile()
     return c, compiled
 
@@ -257,6 +268,145 @@ def test_llama_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, case
         assert not inside, f"scale rows laid out inside a loop's body: {inside}"
         assert re.search(rf"f32\[{_DEPTH * pages},1,\d+\]", text), "the kernel is handed no laid-out scale rows"
     assert temp < pool // 4, f"temporaries {temp / 1e6:.0f} MB beside a {pool / 1e6:.0f} MB pool"
+
+
+# -- the decode block as the engine nests it: step, constraint, sampler, the scan ----------------
+
+_BLOCKS = [("qwen2.5-7b-v5e1", {}, 3585, 32, 1, 28), ("qwen2.5-32b-v5e4-tp4", _QWEN_32B, 2689, 24, 4, 64)]
+_COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter", "collective-permute")
+
+
+def _compile_llama_decode_block(v5e, widths, pages, slots, tp, depth, monkeypatch=None, sampler=None):
+    """`engine.make_decode_block` around `decode_step_paged` with the walk,
+    at a cell's shapes and full depth (int8 weights, bf16 pages, a block of
+    8, the lanes as one packed buffer): the program the ledger's op names
+    are read from. ``sampler`` stands in for `ops.sampling.sample`."""
+    from agentcontrolplane_tpu.engine import engine
+    from agentcontrolplane_tpu.engine.lanes import DECODE
+    from agentcontrolplane_tpu.models.llama import decode_step_paged
+
+    c, mesh, params, cache, vec = _llama_operands(v5e, widths, pages, tp, depth)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    if sampler is not None:
+        monkeypatch.setattr(engine, "sample", sampler)
+    block = engine.make_decode_block(
+        lambda p, pool, tok, n, active, tables: decode_step_paged(
+            p, pool, tok, n, tables, active, c, use_pallas=True, mesh=mesh),
+        (151643, 151645), 1792, 8)
+    return jax.jit(block, donate_argnums=(1, 2)).lower(
+        params, cache, vec(len(DECODE.kinds), slots), vec(*key.shape, dt=key.dtype),
+        vec(1, c.vocab_size), vec(1), vec(slots, 1792 // PAGE)).compile()
+
+
+def _ops(lines):
+    """(name, result type, opcode, the rest) of each instruction of a computation."""
+    import re
+
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)", line)
+        if m:
+            yield m.groups()
+
+
+def _called(rest: str) -> list:
+    import re
+
+    return re.findall(r"(?:calls|body|condition|true_computation|false_computation)=%?([\w.\-]+)", rest) + [
+        c.strip(" %") for group in re.findall(r"branch_computations=\{([^}]*)\}", rest) for c in group.split(",")]
+
+
+class _Step:
+    """The compiled block's one decode step: the body of the entry's scan."""
+
+    def __init__(self, compiled, wide: str):
+        self.comps = _computations(compiled.as_text())
+        entry = next(lines for name, lines in self.comps.items() if name.startswith("ENTRY"))
+        (scan,) = [rest for _, _, op, rest in _ops(entry) if op == "while"]
+        self.body = self.comps[_called(scan)[1]]  # condition=, body=
+        self.wide = wide  # the logits' shape a chip, as the text writes it
+        self.temp = compiled.memory_analysis().temp_size_in_bytes
+
+    def wide_values(self):
+        """Instructions of the step's own body whose result holds [S, V]."""
+        return [(name, shape, op) for name, shape, op, _ in _ops(self.body)
+                if self.wide in shape and op not in ("get-tuple-element", "tuple", "parameter")]
+
+    def loops_over_the_logits(self, lines=None):
+        """(condition's lines, where) of every loop that carries a float32
+        [S, V] operand: the two threshold searches."""
+        out = []
+        for _, shape, op, rest in _ops(self.body if lines is None else lines):
+            called = _called(rest)
+            if op == "while" and f"f32{self.wide}" in shape:
+                out.append((self.comps[called[0]], "step" if lines is None else "conditional"))
+            elif op == "conditional" and lines is None:
+                for branch in called:
+                    out += self.loops_over_the_logits(self.comps[branch])
+        return out
+
+    def collectives(self):
+        """Collectives of the step's own body: outside the layer loop, the
+        threshold loops and any conditional."""
+        return sorted(f"{op} {shape.split('{')[0]}" for _, shape, op, _ in _ops(self.body)
+                      if op.removesuffix("-start") in _COLLECTIVES)
+
+
+@pytest.mark.parametrize("case", _BLOCKS, ids=lambda c: c[0])
+def test_the_decode_block_runs_its_threshold_searches_only_when_asked(v5e, case, monkeypatch):
+    """The engine's decode block compiled for the described v5e at the
+    7B's widths on one chip and the 32B's at tp=4, beside the same block
+    with the sampler that ran both searches for every batch. The top-p
+    loop is inside a conditional whose result is a threshold a row; the
+    top-k loop turns by a count read from the lanes (a conditional around
+    it moved the float32 logits out of the fast memory, `S(1)`, that every
+    later pass reads them from); the step's body holds no [S, V] value the
+    parent's did not, every one of them in that memory, in no more
+    temporaries; at tp=4, where every reduction over the sharded vocabulary
+    is a collective, 9 are left in the step's body of the parent's 12, and
+    the two loops' chained ones run only when asked. It also keeps the
+    finding of ISSUE 39: the chip's op names (`PERF_LEDGER.jsonl`
+    `breakdown.device_ops`) can be looked up here, at no chip time."""
+    import re
+
+    from .test_sampling import always_both
+
+    _, widths, pages, slots, tp, depth = case
+    wide = f"[{slots},{152064 // tp}]"
+    change = _Step(_compile_llama_decode_block(v5e, widths, pages, slots, tp, depth), wide)
+    parent = _Step(_compile_llama_decode_block(v5e, widths, pages, slots, tp, depth, monkeypatch, always_both), wide)
+
+    # the ledger's names: the walk, the two loops' reductions on the parent
+    text = "\n".join(line for lines in parent.comps.values() for line in lines)
+    assert "paged_page_walk" in text and f"f32{wide}" in text
+
+    # both searches are loops of 32 on the parent; here one is gated by its count, one by a conditional
+    assert [where for _, where in parent.loops_over_the_logits()] == ["step", "step"]
+    assert all(any("constant(32)" in line for line in cond) for cond, _ in parent.loops_over_the_logits())
+    (topk, at_k), (topp, at_p) = change.loops_over_the_logits()
+    assert (at_k, at_p) == ("step", "conditional")
+    assert not any(" constant(" in line for line in topk), "the top-k loop's count of turns is a constant"
+    assert any("constant(32)" in line for line in topp)
+
+    # what crosses the conditional's edge is rank 1, and its skipping branch computes nothing
+    (cond,) = [(shape, _called(rest)) for _, shape, op, rest in _ops(change.body) if op == "conditional"]
+    assert re.fullmatch(rf"\(?f32\[{slots}\]\{{[^}}]*\}}\)?", cond[0]), cond[0]
+    skipping = min((change.comps[b] for b in cond[1]), key=len)
+    assert {op for _, _, op, _ in _ops(skipping)} <= {"parameter", "get-tuple-element", "tuple"}
+
+    # the step's body: no [S, V] value the parent's did not hold, all in the fast memory, no more temporaries
+    kinds = lambda step: sorted(f"{op} {shape.split('{')[0]}" for _, shape, op in step.wide_values())  # noqa: E731
+    assert not set(kinds(change)) - set(kinds(parent)), (kinds(change), kinds(parent))
+    assert len(change.wide_values()) < len(parent.wide_values())
+    assert not [v for v in change.wide_values() if v[2] == "copy"]
+    for name, shape, _ in change.wide_values() + parent.wide_values():
+        assert all("S(1)" in part for part in shape.split("], ") if wide in part), f"{name} leaves the fast memory: {shape}"
+    assert change.temp < parent.temp + (1 << 20), f"{change.temp / 1e6:.1f} MB against {parent.temp / 1e6:.1f} MB"
+
+    if tp > 1:
+        # the four all-gathers of the two argmaxes, the embedding's all-reduce, the constraint's two,
+        # and the top-k loop's first bounds (the row's min and max, which on one chip the head's fusion writes)
+        assert len(parent.collectives()) == 12 and len(change.collectives()) == 9, change.collectives()
+        assert not set(change.collectives()) - set(parent.collectives())
 
 
 # -- the grouped expert matmul and the model that runs it ---------------------
@@ -402,3 +552,44 @@ def test_jamba_prefill_fits_beside_the_resident_set(v5e, monkeypatch):
     assert "ssm_scan" in text
     assert f"{T},{c.d_state},{c.d_inner}]" not in text and f"{T},{c.d_inner},{c.d_state}]" not in text
     assert _resident(compiled) < 11e9, f"{_resident(compiled) / 1e9:.1f} GB"
+
+
+@pytest.mark.parametrize("family", ["jamba", "lfm2"])
+def test_a_family_with_state_keeps_its_logits_in_fast_memory_around_the_searches(v5e, family, monkeypatch):
+    """The other two cells' decode blocks (128 lanes x 65,536 on Jamba,
+    the widest logits of any cell; 32 x 65,536 on LFM2), nested by the
+    engine's own `make_decode_block`: the same placement as the Qwen
+    blocks above. The top-k loop in the step's body, the top-p loop under
+    the conditional, and every [S, V] value of the step in `S(1)`."""
+    import functools
+
+    from agentcontrolplane_tpu.engine import engine
+    from agentcontrolplane_tpu.engine.lanes import DECODE
+
+    if family == "jamba":
+        model, c, params, cache, vec = _jamba(v5e, monkeypatch)
+        slots, ctx, block = _JAMBA_SLOTS, 2048, 32
+    else:
+        from agentcontrolplane_tpu.models import lfm2 as model
+
+        monkeypatch.setattr(model, "routed_experts", functools.partial(model.routed_experts, kernel=True))
+        c, slots, ctx, block = model.PRESETS["lfm2-24b-a2b-ep8"], 32, 1024, 16
+        one_chip = SingleDeviceSharding(v5e[0])
+        place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+        params = place(jax.eval_shape(lambda: model.init_params(c, jax.random.key(0))))
+        cache = place(jax.eval_shape(lambda: model.init_paged_cache(c, 2049, PAGE, max_slots=slots)))
+        vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    fn = engine.make_decode_block(
+        lambda p, ca, tok, n, active, tables: model.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True),
+        (7,), ctx, block)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, cache, vec(len(DECODE.kinds), slots), vec(*key.shape, dt=key.dtype),
+        vec(1, c.vocab_size), vec(1), vec(slots, ctx // PAGE)).compile()
+    step = _Step(compiled, f"[{slots},{c.vocab_size}]")
+    assert [where for _, where in step.loops_over_the_logits()] == ["step", "conditional"]
+    assert step.wide_values(), "no [S, V] value found in the step's body"
+    for name, shape, _ in step.wide_values():
+        assert all("S(1)" in part for part in shape.split("], ") if step.wide in part), f"{name} leaves the fast memory: {shape}"
+
