@@ -48,7 +48,7 @@ import numpy as np
 
 from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
 from ..observability.metrics import REGISTRY
-from ..ops.paged import TRASH_PAGE, set_pages
+from ..ops.paged import TRASH_PAGE, ring_size, set_pages
 from ..ops.sampling import NEG_INF, masks_wanted, sample
 from ..parallel.mesh import (
     kv_cache_shardings,
@@ -627,6 +627,9 @@ class Engine:
         # the one seam to the model family's programs (models/__init__.py)
         self._model = programs(config)
         self._has_state = self._model.has_state
+        # a second cache a slot, the window layers' ring (models/mellum.py):
+        # fixed to the slot, written by the programs alone, copied nowhere
+        self._window_cache = self._model.window_cache
         # device counters a family keeps in its cache (None: it keeps none)
         self._counters = self._model.counters
         self.tokenizer = tokenizer or ByteTokenizer()
@@ -694,9 +697,11 @@ class Engine:
         if self.config.sliding_window and self.max_ctx > self.config.sliding_window:
             raise ValueError(
                 f"max_ctx={self.max_ctx} exceeds this model's sliding window "
-                f"({self.config.sliding_window}): gemma-2's alternating local "
-                "layers make serving exact only within one window — lower "
-                "--tpu-ctx to the window size"
+                f"({self.config.sliding_window}): the llama family keeps one "
+                "cache for every layer and no window cache, so gemma-2's "
+                "alternating local layers are exact only within one window — "
+                "lower --tpu-ctx to the window size (a family with a window "
+                "cache beside its pages, models/mellum.py, serves past it)"
             )
         if self._has_state:
             # per-slot state beside the pages: what the engine cannot yet do
@@ -709,6 +714,15 @@ class Engine:
                 (bool(quantize) or quantize_weights, "weight-only int8: its matrices are served in the dtype they were made in"),
                 (coordination is not None, "multi-host lockstep serving"),
             ]
+            if self._window_cache:
+                # the window layers' ring is rebuilt by a prefill and copied
+                # nowhere: whatever would restore a slot's pages without it
+                # is refused, so that no slot is ever served with its
+                # full-layer pages restored and its ring not
+                refused += [
+                    (host_kv_bytes > 0, "host_kv_bytes > 0: a swapped-out slot's window cache is not carried to the host and back"),
+                    (quantize_kv, "quantize_kv: its window cache and its pages are kept in the model's dtype"),
+                ]
             for hit, why in refused:
                 if hit:
                     raise ValueError(
@@ -947,6 +961,21 @@ class Engine:
         # O(new tokens) instead of O(whole conversation).
         import collections as _collections
 
+        # A family with a window cache keeps no prefix entry, shares no
+        # prompt page between live slots and parks no slot: each would hand
+        # a request full-layer pages whose window cache it has not (a copy
+        # is 45 MB a slot at Mellum2's widths, and a ring cut back to a
+        # page-aligned length has lost the rows before it). These three are
+        # leave to reuse where reuse is possible, as they are inert in the
+        # slot layout or under coordination; here it never is, so they are
+        # off, and stats() shows them off.
+        if self._window_cache and (prefix_cache_entries > 0 or prefix_dedup or park_max_s > 0):
+            log.info(
+                "the %s family keeps a window cache a slot: prefix entries, "
+                "prefix dedup and parked slots are off for it",
+                self._model.family,
+            )
+            prefix_cache_entries, prefix_dedup, park_max_s = 0, False, 0.0
         self._prefix_enabled = prefix_cache_entries > 0  # acp: mirror (immutable)
         self._prefix_cache_entries = prefix_cache_entries  # acp: mirror (immutable)
         # HBM accounting: per cached token one K+V row per layer
@@ -1500,6 +1529,11 @@ class Engine:
                 self.num_pages, track_scales=self.quantize_kv
             )
             self._slot_pages: dict[int, list[int]] = {}
+            # a family with a window cache: the slots whose ring is held,
+            # and by which request (taken at admission with the slot's first
+            # pages, given back where its pages are)
+            self._window_rings: dict[int, str] = {}
+            self._rings_held = 0  # acp: mirror — len(_window_rings), for stats()
             self._block_tables = np.full(
                 (self.max_slots, self.max_pages_per_seq), TRASH_PAGE, dtype=np.int32
             )
@@ -1621,6 +1655,11 @@ class Engine:
         replica restores via :meth:`inject_host_kv`. Export supersedes
         parking (the entry, not the slot, is the reuse unit)."""
         tokens = self.tokenizer.encode(prompt) if isinstance(prompt, str) else list(prompt)
+        if export_kv and self._window_cache:
+            raise ValueError(
+                f"the {self._model.family} family does not serve with export_kv: "
+                "a handoff entry carries a slot's pages and not its window cache"
+            )
         s = sampling or SamplingParams()
         prefix_len = len(s.forced_prefix)
         # keep the prompt's TAIL and reserve room to actually generate —
@@ -2227,10 +2266,21 @@ class Engine:
                     state_refused=self.state_refused,
                 )
             if model.counters is not None:
-                name, described = model.describe_counters(
+                # the family's own blocks of stats, by key
+                out.update(model.describe_counters(
                     self.config, self._read_counters()
+                ))
+            if model.window_cache:
+                # the window layers' ring: pages fixed to a slot, the rows
+                # a window layer holds of it at any context, and the slots
+                # that own theirs now (every admitted slot does, until its
+                # release: engine/invariants.py)
+                ring = ring_size(self.config.window, self.page_size)
+                out["window"].update(
+                    pages_per_slot=ring,
+                    rows_per_slot=ring * self.page_size,
+                    slots_holding=self._rings_held,
                 )
-                out[name] = described
         if self._prefix_enabled:
             with self._prefix_lock:
                 out["prefix_cache"] = {
@@ -2784,6 +2834,10 @@ class Engine:
                     _req, slot, pages, _m = item
                     assert pages is not None
                     self._slot_pages[slot] = pages
+                    if self._window_cache:
+                        # the slot's ring goes to this request with the slot
+                        self._window_rings[slot] = _req.rid
+                        self._rings_held = len(self._window_rings)
                     self._block_tables[slot, :] = TRASH_PAGE
                     self._block_tables[slot, : len(pages)] = pages
             if self.prefill_chunk:
@@ -3221,7 +3275,7 @@ class Engine:
         self._constrained[slot] = False
         heapq.heappush(self._free, slot)
         if self.kv_layout == "paged":
-            self._allocator.free(self._slot_pages.pop(slot, []))
+            self._release_slot_pages(slot)
             self._block_tables[slot, :] = TRASH_PAGE
             self._tables_dirty = True
         return sl
@@ -4579,7 +4633,7 @@ class Engine:
         self._constrained[slot] = False
         heapq.heappush(self._free, slot)
         if self.kv_layout == "paged":
-            self._allocator.free(self._slot_pages.pop(slot, []))
+            self._release_slot_pages(slot)
             self._block_tables[slot, :] = TRASH_PAGE
             self._tables_dirty = True
         REGISTRY.counter_add(
@@ -4644,6 +4698,14 @@ class Engine:
             req.future.set_result(result)
         REGISTRY.counter_add("acp_engine_requests_total", 1.0)
         REGISTRY.counter_add("acp_engine_tokens_total", float(len(gen)))
+
+    def _release_slot_pages(self, slot: int) -> None:
+        """A slot gives back what it held of both caches: its full-layer
+        pages to the allocator, and (a family with a window cache) its ring,
+        which is the slot's again for whoever takes the slot next."""
+        self._allocator.free(self._slot_pages.pop(slot, []))
+        self._window_rings.pop(slot, None)
+        self._rings_held = len(self._window_rings)
 
     def _append_pages(self, slot: int, new_pages: list[int]) -> None:
         table = self._slot_pages[slot]
@@ -5743,7 +5805,7 @@ class Engine:
         self._constrained[slot] = False
         heapq.heappush(self._free, slot)
         if self.kv_layout == "paged":
-            self._allocator.free(self._slot_pages.pop(slot, []))
+            self._release_slot_pages(slot)
             self._block_tables[slot, :] = TRASH_PAGE
         self._resolve_result(sl, reason, slot=slot, kv_entry=kv_entry)
 
@@ -5871,7 +5933,7 @@ class Engine:
         self._last_tokens[slot] = 0
         heapq.heappush(self._free, slot)
         if self.kv_layout == "paged":
-            self._allocator.free(self._slot_pages.pop(slot, []))
+            self._release_slot_pages(slot)
             self._block_tables[slot, :] = TRASH_PAGE
             self._tables_dirty = True
         self.park_releases += 1

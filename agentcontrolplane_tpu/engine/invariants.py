@@ -205,8 +205,12 @@ def _verify_quantized_cache(engine) -> list[str]:
     identical plain cache). Shape/dtype metadata only — no device
     transfer."""
     problems: list[str] = []
-    # a family's per-slot state beside the pages is no part of the KV pool
+    # a family's per-slot state beside the pages is no part of the KV pool,
+    # nor is the window layers' pool of a family that keeps one (its own
+    # audit: _verify_window_rings)
     keys = set(engine.cache) - {"state"}
+    if getattr(engine, "_window_cache", False):
+        keys -= {"wk", "wv"}
     if not engine.quantize_kv:
         if keys != {"k", "v"}:
             problems.append(
@@ -456,6 +460,41 @@ def _verify_pages(engine, slots: dict) -> list[str]:
                 "not TRASH_PAGE — a stale mapping could be read after the "
                 "page is reused"
             )
+    if getattr(engine, "_window_cache", False):
+        problems += _verify_window_rings(engine, slots)
+    return problems
+
+
+def _verify_window_rings(engine, slots: dict) -> list[str]:
+    """The second cache of a family with window layers (models/mellum.py):
+    a ring of ``window / P + 1`` pages a slot, fixed to the slot. Its
+    conservation is of slots: every slot that holds full-layer pages holds
+    its ring for the same request, and a ring held by no occupied slot is a
+    leak (its rows would be read as the next request's window)."""
+    problems: list[str] = []
+    rings = engine._window_rings
+    for slot, sl in slots.items():
+        if rings.get(slot) != sl.request.rid:
+            problems.append(
+                f"slot {slot}: its window ring is held by {rings.get(slot)!r}, "
+                f"not by its request {sl.request.rid!r} — the window layers "
+                "would read another request's rows"
+            )
+    leaked = sorted(set(rings) - set(slots))
+    if leaked:
+        problems.append(
+            f"window rings held by no occupied slot: {leaked[:8]} — a "
+            "released slot kept its ring"
+        )
+    if engine._rings_held != len(rings):
+        problems.append(
+            f"window-ring mirror {engine._rings_held} != {len(rings)} rings held"
+        )
+    if set(rings) != set(engine._slot_pages):
+        problems.append(
+            "window rings and full-layer page lists are held by different "
+            f"slots: rings {sorted(rings)[:8]}, pages {sorted(engine._slot_pages)[:8]}"
+        )
     return problems
 
 

@@ -17,14 +17,19 @@ puts under ``"state"`` whole, and moves a slot's part as a tree too:
 takes it back, and between the two the engine only keeps it (on the device
 in a prefix entry, as numpy leaves in a host entry). A family that counts on
 the device gives ``counters(cache)`` (the array to read, inside a program)
-and ``describe_counters(config, total) -> (stats key, dict)`` for
+and ``describe_counters(config, total) -> {stats key: dict}`` for
 ``Engine.stats()``; ``counters=None`` keeps none. The two capabilities are
 apart: state without counters and counters without state both serve.
+``window_cache`` (``models.mellum``) says that the family keeps a second
+cache a slot, the window layers' ring, fixed to the slot and written by the
+programs alone: it takes ``lanes`` as a family with state does, but nothing
+of it can be saved or installed, so the engine refuses what would need
+that (``engine.py``'s list).
 """
 
 from types import SimpleNamespace
 
-from . import jamba, lfm2, llama
+from . import jamba, lfm2, llama, mellum
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -36,16 +41,17 @@ from .llama import (
 )
 from .jamba import JambaConfig
 from .lfm2 import Lfm2Config
+from .mellum import MellumConfig
 
 __all__ = [
-    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "decode_step", "forward", "init_kv_cache",
+    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "decode_step", "forward", "init_kv_cache",
     "init_params", "prefill", "preset", "programs",
 ]
 
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum)]
     for table in tables:
         if name in table:
             return table[name]
@@ -54,7 +60,7 @@ def preset(name: str):
 
 
 _LLAMA = SimpleNamespace(
-    family="llama", has_state=False, counters=None,
+    family="llama", has_state=False, window_cache=False, counters=None,
     init_params=llama.init_params,
     init_kv_cache=llama.init_kv_cache, prefill_batch=llama.prefill_batch,
     prefill_continue=llama.prefill_continue, prefill_continue_kv=llama.prefill_continue_kv,
@@ -68,11 +74,11 @@ _LLAMA = SimpleNamespace(
     decode_step_paged=llama.decode_step_paged,
 )
 
-def _with_state(family: str, m) -> SimpleNamespace:
+def _with_state(family: str, m, window_cache: bool = False) -> SimpleNamespace:
     """A family with per-slot state: its module's programs take ``lanes``
     after the page ids; the engine hands both as one pair."""
     return SimpleNamespace(
-        family=family, has_state=True,
+        family=family, has_state=True, window_cache=window_cache,
         init_params=m.init_params,
         init_paged_cache=m.init_paged_cache,
         prefill_paged_batch=lambda params, cache, tokens, lengths, ids, config: (
@@ -89,7 +95,8 @@ def _with_state(family: str, m) -> SimpleNamespace:
 
 _LFM2 = _with_state("lfm2", lfm2)
 _JAMBA = _with_state("jamba", jamba)
-_FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA}
+_MELLUM = _with_state("mellum", mellum, window_cache=True)
+_FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM}
 
 
 def programs(config) -> SimpleNamespace:

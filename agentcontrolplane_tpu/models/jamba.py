@@ -544,7 +544,7 @@ def counters(cache: dict) -> jax.Array:
     return cache["state"]["counters"]
 
 
-def describe_counters(config: JambaConfig, total) -> tuple[str, dict]:
+def describe_counters(config: JambaConfig, total) -> dict:
     """``Engine.stats()["ssm"]`` from the counters summed by the engine
     (``total`` [2, 4], None before the first dispatch): decode steps and
     prefills apart, Mamba layers run, rows (lanes updated, or rows scanned),
@@ -556,5 +556,5 @@ def describe_counters(config: JambaConfig, total) -> tuple[str, dict]:
     def row(r):
         return {"mamba_layers": int(r[0]), "rows": int(r[1]), "tokens": int(r[2]), "chunks": int(r[3])}
 
-    return "ssm", {"state_bytes_per_slot": config.state_bytes_per_slot, "decode": row(total[0]),
-                   "prefill": row(total[1])}
+    return {"ssm": {"state_bytes_per_slot": config.state_bytes_per_slot, "decode": row(total[0]),
+                    "prefill": row(total[1])}}

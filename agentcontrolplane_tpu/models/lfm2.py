@@ -232,16 +232,18 @@ def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
         return out, state_at(lengths), state_at(snap_rel)
 
 
-def _attention_op(h, layer, c: Lfm2Config, positions, attn_fn):
-    """-> (Op output, k, v): k and v are the layer's new rows for the pool."""
+def _attention_op(h, layer, c, positions, attn_fn, yarn=None):
+    """-> (Op output, k, v): k and v are the layer's new rows for the pool.
+    ``yarn`` (``ops.rope.apply_rope``'s) turns q and k by YaRN's frequencies
+    (``models/mellum.py``'s full layers)."""
     B, T, _ = h.shape
     q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
     k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
     v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
     q = rms_norm(q, layer["q_norm"], c.norm_eps)
     k = rms_norm(k, layer["k_norm"], c.norm_eps)
-    q = apply_rope(q, positions, c.rope_theta)
-    k = apply_rope(k, positions, c.rope_theta)
+    q = apply_rope(q, positions, c.rope_theta, yarn=yarn)
+    k = apply_rope(k, positions, c.rope_theta, yarn=yarn)
     out = attn_fn(q, k, v)
     return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
 
@@ -567,7 +569,7 @@ def counters(cache: dict) -> jax.Array:
     return cache["state"]["moe"]
 
 
-def describe_counters(config: Lfm2Config, total) -> tuple[str, dict]:
+def describe_counters(config: Lfm2Config, total) -> dict:
     """``Engine.stats()["moe"]`` from the counters summed by the engine
     (``total`` [2, 1 + COUNTS_HEAD + held], None before the first dispatch):
     decode steps and prefills apart, expert layers run, (token, choice)
@@ -582,5 +584,5 @@ def describe_counters(config: Lfm2Config, total) -> tuple[str, dict]:
         return {"expert_layers": int(r[0]), "pairs_routed": int(r[1]), "pairs_held": int(r[2]),
                 "experts_read": int(r[3]), "tokens_per_held_expert": [int(n) for n in r[4:]]}
 
-    return "moe", {"experts": config.n_experts, "held": held, "experts_per_token": config.experts_per_token,
-                   "decode": row(total[0]), "prefill": row(total[1])}
+    return {"moe": {"experts": config.n_experts, "held": held, "experts_per_token": config.experts_per_token,
+                    "decode": row(total[0]), "prefill": row(total[1])}}

@@ -85,8 +85,10 @@ class LlamaConfig:
     query_pre_attn_scalar: float = 0.0  # q scale denominator; 0 = head_dim
     # Gemma-2 alternates local (sliding-window) and global layers. Within
     # one window sliding == full causal, so serving is EXACT for contexts
-    # <= window (4096) and the engine refuses longer (models this size
-    # rarely need it; a windowed KV path is future work).
+    # <= window (4096) and the engine refuses longer for this family: it
+    # keeps one cache for every layer. The windowed KV path is
+    # models/mellum.py's (a ring of window pages a slot beside the full
+    # layers' pages, the window walk); this family does not take it yet.
     sliding_window: int = 0
     # Mixture-of-Experts (Mixtral architecture): n_experts > 0 replaces the
     # dense FFN with top-k routed SwiGLU experts (ops/moe.py routed_experts:

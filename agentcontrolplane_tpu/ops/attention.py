@@ -46,6 +46,7 @@ def causal_attention(
     v: jax.Array,  # [B, T, H_kv, d]
     positions: jax.Array | None = None,  # [B, T] for padded/packed inputs
     softcap: float = 0.0,
+    window: int = 0,  # > 0: a query sees the last `window` positions, itself among them
 ) -> jax.Array:
     """Full causal self-attention. With ``positions`` given, tokens attend
     only to tokens with position <= their own AND valid (position >= 0)."""
@@ -57,6 +58,8 @@ def causal_attention(
     logits = _softcap(
         jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32) * scale, softcap
     )
+    if window and positions is None:
+        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     if positions is None:
         mask = jnp.tril(jnp.ones((T, T), dtype=bool))[None, None]
     else:
@@ -66,6 +69,8 @@ def causal_attention(
             & valid[:, None, :, None]
             & valid[:, None, None, :]
         )
+        if window:
+            mask = mask & (positions[:, None, None, :] > positions[:, None, :, None] - window)
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
@@ -169,6 +174,7 @@ def blocked_causal_attention(
     positions: jax.Array | None = None,  # [B, T] (-1 = padding)
     block_size: int = 512,
     softcap: float = 0.0,
+    window: int = 0,
 ) -> jax.Array:
     """Flash-style blocked causal attention (single device): query blocks
     attend only their causal KEY PREFIX (q-block i scans key blocks 0..i
@@ -178,18 +184,24 @@ def blocked_causal_attention(
     issued. Exact vs :func:`causal_attention` up to f32 accumulation order.
     Requires right-padded rows (valid positions equal their indices — true
     for prefill); falls back to the dense path when T doesn't split into
-    blocks (buckets are powers of two, so T > block implies divisibility)."""
+    blocks (buckets are powers of two, so T > block implies divisibility).
+    With ``window`` a query sees its last ``window`` positions only (a
+    banded mask), and q-block i scans the key blocks the band touches,
+    ``i - ceil(window / block) .. i``: the blocks wholly before the band are
+    never issued."""
     B, T, H, d = q.shape
     if T <= block_size or T % block_size:
-        return causal_attention(q, k, v, positions, softcap=softcap)
+        return causal_attention(q, k, v, positions, softcap=softcap, window=window)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     nb = T // block_size
     n_rep = H // k.shape[-2]
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
 
+    band = -(-window // block_size) if window else nb  # key blocks before a q-block's own that it sees
+
     def kv_prefix(arrs, qi):
-        return [a[:, : (qi + 1) * block_size] for a in arrs]
+        return [a[:, max(0, qi - band) * block_size: (qi + 1) * block_size] for a in arrs]
 
     outs = []
     for qi in range(nb):  # unrolled: nb is small (T/512), shapes static per qi
@@ -197,7 +209,7 @@ def blocked_causal_attention(
         qf = q[:, sl].astype(jnp.float32)
         q_pos = positions[:, sl]
         kp, vp, kvp = kv_prefix((k, v, positions), qi)
-        nkb = qi + 1
+        nkb = qi + 1 - max(0, qi - band)
         k_blocks = jnp.moveaxis(kp.reshape(B, nkb, block_size, *k.shape[2:]), 1, 0)
         v_blocks = jnp.moveaxis(vp.reshape(B, nkb, block_size, *v.shape[2:]), 1, 0)
         pos_blocks = jnp.moveaxis(kvp.reshape(B, nkb, block_size), 1, 0)
@@ -216,6 +228,8 @@ def blocked_causal_attention(
                 & (q_pos[:, None, :, None] >= 0)
                 & (kv_pos[:, None, None, :] >= 0)
             )
+            if window:
+                mask = mask & (kv_pos[:, None, None, :] > q_pos[:, None, :, None] - window)
             m, l, acc = online_softmax_step(
                 qf, kf, vf, mask, m, l, acc, scale, softcap=softcap
             )
@@ -233,6 +247,7 @@ def continue_attention(
     positions: jax.Array,  # [B, T] absolute query positions (-1 = padding)
     key_positions: jax.Array | None = None,  # [B, C]; -1 = invalid key
     softcap: float = 0.0,
+    window: int = 0,  # > 0: keys at positions > the query's - window only
 ) -> jax.Array:
     """Suffix-over-cache attention (prefix-cache continuation): each query
     attends to every key whose absolute position is <= its own — exactly
@@ -256,6 +271,8 @@ def continue_attention(
         & (key_positions >= 0)[:, None, None, :]
         & (positions >= 0)[:, None, :, None]
     )
+    if window:
+        mask = mask & (key_positions[:, None, None, :] > positions[:, None, :, None] - window)
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhtc,bchd->bthd", probs, v)

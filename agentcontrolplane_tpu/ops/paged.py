@@ -17,6 +17,15 @@ flat view (:func:`commit_whole_pages`, :func:`commit_tokens`): a scatter
 windowed over the layer axis makes the compiler keep the pool layer-minor
 and relay all of it.
 
+A layer that attends over a window keeps a **ring** a slot instead of a
+page list (``ring_*`` below, ``models/mellum.py``): ``ring`` pages of a pool
+of its own, fixed to the slot (slot ``s`` owns pages ``s * ring ..``, the
+slot after the last is where padding lanes write), position ``p`` in ring
+page ``(p // P) % ring``. With ``ring = window / P + 1`` the ``window`` rows
+a query sees never touch one ring page twice, however long the context: the
+cache of such a layer is ``window + P`` rows a slot, its table never changes
+and nothing is allocated or freed while a request runs.
+
 This module is the *reference* implementation (pure jnp gather/scatter,
 exact); ``ops.pallas.paged_attention`` is the TPU kernel that walks block
 tables with HBM->VMEM DMAs instead of materializing gathers. Page 0 is
@@ -123,6 +132,62 @@ def gather_pages(pool: dict, name: str, ids: jax.Array, dtype, n_kv_heads: int) 
     if name + "s" in pool:
         return kv_dequantize(rows, flat_pages(pool[name + "s"])[ids], dtype)
     return rows.astype(dtype)
+
+
+def ring_size(window: int, page_size: int) -> int:
+    """Pages of a slot's ring: the window's rows and one page of slack, so
+    that the page being written is never one the window still reads."""
+    if window % page_size:
+        raise ValueError(f"page_size {page_size} must divide the window {window}")
+    return window // page_size + 1
+
+
+def ring_tables(slots: jax.Array, ring: int) -> jax.Array:
+    """[B, ring] page ids of the window pool: slot ``s`` owns ``s * ring ..``."""
+    return slots[:, None] * ring + jnp.arange(ring, dtype=jnp.int32)[None, :]
+
+
+def ring_positions(written: jax.Array, ring: int, page_size: int) -> jax.Array:
+    """[B, ring * P]: the position whose K/V each row of a slot's ring holds
+    once rows ``0 .. written - 1`` of the sequence were committed (-1: none
+    yet; rows at and past ``written`` in the newest page are stale and read
+    as positions >= ``written``, which a caller masks as it masks any row
+    not written)."""
+    last = (written - 1) // page_size  # the newest page of the sequence, -1 for none
+    j = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    page = last[:, None] - jnp.mod(last[:, None] - j, ring)  # newest page <= last that sits at j
+    pos = page[:, :, None] * page_size + jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
+    pos = jnp.where((page >= 0)[:, :, None] & (written > 0)[:, None, None], pos, -1)
+    return pos.reshape(written.shape[0], ring * page_size)
+
+
+def ring_newest(slots: jax.Array, starts: jax.Array, lengths: jax.Array, T: int, page_size: int,
+                ring: int, pad_slot: int):
+    """What a prefill, a continuation or a mid chunk leaves of a window
+    layer: the newest ``ring`` whole pages of rows ``starts .. starts +
+    lengths`` (``starts`` page-aligned) of its ``T`` fresh rows. -> (``take``:
+    a function from fresh K or V ``[B, T, ...]`` to those pages' rows ``[B,
+    n * P, ...]``, to be applied inside the layer scan so that the scan
+    stacks a ring's worth of rows a layer and not the bucket's; ``ids`` [B,
+    n]: the pages of the window pool they go to, in the rows' slots' rings).
+    Pages of a row that are older, or that hold no token, go to slot
+    ``pad_slot``'s ring, which nothing reads."""
+    P = page_size
+    n = min(ring, T // P)
+    last = (lengths - 1) // P  # [B] the row's newest page, -1 for an empty row
+    local = last[:, None] - jnp.arange(n - 1, -1, -1, dtype=jnp.int32)[None, :]  # [B, n] oldest first
+    live = local >= 0
+    at = jnp.mod(starts[:, None] // P + local, ring)
+    ids = jnp.where(live, slots[:, None], pad_slot) * ring + at
+    chosen = jnp.clip(local, 0, T // P - 1)
+
+    def take(t):
+        B = t.shape[0]
+        pages = t.reshape(B, T // P, P, *t.shape[2:])
+        idx = chosen.reshape((B, n) + (1,) * (pages.ndim - 2))
+        return jnp.take_along_axis(pages, idx, axis=1).reshape(B, n * P, *t.shape[2:])
+
+    return take, ids
 
 
 def write_prompt_to_pages(
@@ -240,6 +305,8 @@ def paged_decode_attention_reference_cache_plus_new(
     v_new: jax.Array,
     k_scales: Optional[jax.Array] = None,  # [num_pages, P, H_kv] (int8 pools)
     v_scales: Optional[jax.Array] = None,
+    row_positions: Optional[jax.Array] = None,  # [S, max_pages * P]: a ring's (ring_positions)
+    starts: Optional[jax.Array] = None,  # [S]: with row_positions, the first position seen
 ) -> jax.Array:
     """Exact reference for the read-only-pages + self-term decode form (the
     hot-loop shape: pages stay a read-only operand, the new token attends
@@ -264,8 +331,12 @@ def paged_decode_attention_reference_cache_plus_new(
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     q4 = q.reshape(S, H_kv, r, d).astype(jnp.float32)
     logits = jnp.einsum("skrd,smpkd->smpkr", q4, k.astype(jnp.float32)) * scale
-    pos = jnp.arange(max_pages)[:, None] * P + jnp.arange(P)[None, :]  # [M, P]
-    mask = pos[None, :, :, None, None] < seq_lens[:, None, None, None, None]
+    if row_positions is None:
+        pos = jnp.arange(max_pages)[:, None] * P + jnp.arange(P)[None, :]  # [M, P]
+        mask = pos[None, :, :, None, None] < seq_lens[:, None, None, None, None]
+    else:  # the window walk: a ring's rows hold the positions given, seen from `starts` on
+        pos = row_positions.reshape(S, max_pages, P)
+        mask = ((pos >= starts[:, None, None]) & (pos < seq_lens[:, None, None]))[..., None, None]
     logits = jnp.where(mask, logits, NEG_INF)
     self_logit = (
         jnp.sum(q4 * k_new.astype(jnp.float32)[:, :, None, :], axis=-1) * scale

@@ -191,6 +191,8 @@ def _kernel(
     page_size: int,  # GLOBAL page size (pages hold this many tokens)
     quantized: bool = False,
     head_dim: int | None = None,  # the model's, where heads share a lane window
+    starts_ref=None,  # [S] int32 (SMEM): a slot's first valid row (the window walk)
+    ring: int = 0,  # > 0: the table is a ring, page a of the sequence at a % ring
 ):
     # int8 walk (quantized=True): pages hold int8 values plus f32 scale
     # twins (one scale per row per KV head). The fetch loop DMAs each
@@ -212,6 +214,17 @@ def _kernel(
     s = pl.program_id(0)
     seq_len = seq_lens_ref[s]
     n_pages = jax.lax.div(seq_len + page_size - 1, page_size)
+    # The window walk (``starts_ref``): rows before a slot's first valid row
+    # are not the query's to see. The walk begins at the page that holds that
+    # row, skips every page before it, and masks the rows of that first page
+    # that lie before the edge; ``first_page`` and ``n_pages`` are then of the
+    # walk and not of the sequence. With ``ring`` the table has ``ring``
+    # entries a slot and page ``a`` of the sequence sits at ``a % ring``: a
+    # window of at most ``(ring - 1)`` pages of rows touches no entry twice.
+    if starts_ref is not None:
+        first_row = starts_ref[s]
+        first_page = jax.lax.div(first_row, page_size)
+        n_pages = jnp.maximum(n_pages - first_page, 0)
     _, n_kv_heads, n_rep, d = q_ref.shape
     P = k_pages_ref.shape[1]  # local slice length
     pos_base = pos_base_ref[0]
@@ -237,7 +250,10 @@ def _kernel(
         # (G = 1: one unguarded page into the whole buffer).
         for g in range(G):
             def run(g=g):
-                page = block_tables_ref[s, t * G + g]
+                at = t * G + g
+                if starts_ref is not None:
+                    at = first_page + at
+                page = block_tables_ref[s, jax.lax.rem(at, ring) if ring else at]
                 rows = pl.ds(g * P, P)
                 copies = [
                     (k_pages_ref.at[page], k_buf.at[slot, rows]),
@@ -294,7 +310,11 @@ def _kernel(
 
         fetch(t, slot, "wait")
         pos = t * (G * page_size) + pos_base + col
+        if starts_ref is not None:
+            pos = pos + first_page * page_size
         valid = pos < seq_len  # [1, T]
+        if starts_ref is not None:
+            valid = valid & (pos >= first_row)
         if quantized:
             ks = ks_buf[slot]  # [1, >= H_kv * P], head-major
             vs = vs_buf[slot]
@@ -352,6 +372,12 @@ def _kernel(
         l_ref[0, h] = l
 
 
+def _window_kernel(block_tables_ref, seq_lens_ref, pos_base_ref, starts_ref, *rest, **kw):
+    """``_kernel`` with a fourth prefetched scalar row: each slot's first
+    valid row."""
+    _kernel(block_tables_ref, seq_lens_ref, pos_base_ref, *rest, starts_ref=starts_ref, **kw)
+
+
 def _paged_state(
     q: jax.Array,  # [S, H, d]
     k_pages: jax.Array,  # [num_pages, P_local, H_kv * d] (or [.., H_kv, d])
@@ -366,8 +392,14 @@ def _paged_state(
     head_dim: int | None = None,  # softmax scale's width where it is not d
     kv_heads: int | None = None,  # of pages given merged
     scales_laid: bool = False,  # the scales are scale_rows' output already
+    starts: jax.Array | None = None,  # [S] int32: the window walk's first valid row a slot
+    ring: int = 0,  # with `starts`: the table is a ring of this many pages a slot
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the kernel -> unnormalized (acc [S,H,d] f32, m [S,H], l [S,H]).
+
+    With ``starts`` it is the window walk (named ``paged_window_walk``): a
+    slot's rows ``starts[s] .. seq_lens[s] - 1`` and no others, the pages
+    before the first skipped and not read.
 
     With ``k_scales``/``v_scales`` ([num_pages, P, H_kv] as the pool stores
     them, or ``scales_laid``: [num_pages, 1, SC] from :func:`scale_rows`)
@@ -398,7 +430,7 @@ def _paged_state(
             k_pages.reshape(num_pages, P, W, pack * d),
             v_pages.reshape(num_pages, P, W, pack * d),
             block_tables, seq_lens, interpret, pos_base, global_page_size,
-            head_dim=d,
+            head_dim=d, starts=starts, ring=ring,
         )
         acc = jnp.einsum("swjrlc,jl->swjrc", acc.reshape(S, W, pack, r, pack, d),
                          jnp.eye(pack, dtype=acc.dtype))
@@ -408,11 +440,13 @@ def _paged_state(
         pos_base = jnp.zeros((1,), dtype=jnp.int32)
     quantized = k_scales is not None
 
+    windowed = starts is not None
     kernel = functools.partial(
-        _kernel,
+        _window_kernel if windowed else _kernel,
         page_size=global_page_size or P,
         quantized=quantized,
         head_dim=head_dim,
+        **({"ring": ring} if windowed else {}),
     )
 
     def per_slot(*tail):
@@ -436,6 +470,7 @@ def _paged_state(
         block_tables,
         seq_lens,
         pos_base.astype(jnp.int32),
+        *([starts.astype(jnp.int32)] if windowed else []),
         q.reshape(S, H_kv, n_rep, d),
         k_pages.reshape(num_pages, P, H_kv * d),
         v_pages.reshape(num_pages, P, H_kv * d),
@@ -455,7 +490,7 @@ def _paged_state(
         operands += [k_scales, v_scales]
     scratch_shapes.append(pltpu.SemaphoreType.DMA((NBUF, 4 if quantized else 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4 if windowed else 3,
         grid=(S,),
         in_specs=in_specs,
         out_specs=[per_slot(d), per_slot(1), per_slot(1)],
@@ -470,7 +505,8 @@ def _paged_state(
             jax.ShapeDtypeStruct((S, H_kv, n_rep, 1), jnp.float32),
         ],
         interpret=interpret,
-        name="paged_page_walk",
+        # the trace tells the two walks apart by name
+        name="paged_window_walk" if windowed else "paged_page_walk",
     )(*operands)
     return acc.reshape(S, H, d), m.reshape(S, H), l.reshape(S, H)
 
@@ -536,6 +572,8 @@ def paged_decode_attention_cache_plus_new(
     k_scales: jax.Array | None = None,  # [num_pages, P, H_kv] f32 — int8 pages
     v_scales: jax.Array | None = None,
     scales_laid: bool = False,  # the scales come from walk_scale_rows
+    starts: jax.Array | None = None,  # [S]: the window walk (`_paged_state`)
+    ring: int = 0,
 ) -> jax.Array:
     """Kernel over the read-only pages + the new token's self term, merged
     outside the kernel. The new token's k/v stay full-precision (they are
@@ -543,7 +581,7 @@ def paged_decode_attention_cache_plus_new(
     acc, m, l = _paged_state(
         q, k_pages, v_pages, block_tables, seq_lens, interpret,
         k_scales=k_scales, v_scales=v_scales, kv_heads=k_new.shape[1],
-        scales_laid=scales_laid,
+        scales_laid=scales_laid, starts=starts, ring=ring,
     )
     return _fold_self_term(q, k_new, v_new, acc, m, l)
 

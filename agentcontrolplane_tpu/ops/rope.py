@@ -10,6 +10,8 @@ cheap on the VPU, avoids carrying a [max_seq, d] table through jit.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -49,22 +51,65 @@ def llama3_scale_frequencies(
     )
 
 
+def yarn_correction_range(
+    head_dim: int, theta: float, original_max_seq: int, beta_fast: float, beta_slow: float
+) -> tuple[int, int]:
+    """(low, high): the pair indices between which YaRN's ramp runs. Pair
+    ``k`` turns ``r`` times over the original context where ``k =
+    head_dim ln(original / (2 pi r)) / (2 ln theta)``; ``low`` is the floor
+    of that at ``beta_fast`` turns, ``high`` the ceiling at ``beta_slow``,
+    both clipped to ``[0, head_dim - 1]``."""
+    def dim_at(r: float) -> float:
+        return head_dim * math.log(original_max_seq / (2.0 * math.pi * r)) / (2.0 * math.log(theta))
+
+    low = max(math.floor(dim_at(beta_fast)), 0)
+    high = min(math.ceil(dim_at(beta_slow)), head_dim - 1)
+    return low, high
+
+
+def yarn_scale_frequencies(
+    inv_freq: jax.Array,
+    factor: float,
+    original_max_seq: int,
+    beta_fast: float,
+    beta_slow: float,
+    theta: float,
+) -> jax.Array:
+    """YaRN's frequencies (HF ``rope_type == "yarn"``): pairs that turn
+    often over the original context keep their frequency, pairs that turn
+    less than once are slowed by ``factor``, a linear ramp over the pair
+    index between (:func:`yarn_correction_range`). The attention factor
+    that goes with them multiplies cos and sin (``apply_rope``'s
+    ``yarn``)."""
+    half = inv_freq.shape[0]
+    low, high = yarn_correction_range(2 * half, theta, original_max_seq, beta_fast, beta_slow)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return (1.0 - ramp) * inv_freq + ramp * inv_freq / factor
+
+
 def apply_rope(
     x: jax.Array,  # [..., T, H, d]
     positions: jax.Array,  # [..., T] int32
     theta: float = 500000.0,
     scaling: "tuple[float, float, float, int] | None" = None,
+    yarn: "tuple[float, int, float, float, float] | None" = None,
 ) -> jax.Array:
     """Rotate q or k by position. Computed in float32, cast back.
     ``scaling`` = (factor, low_freq_factor, high_freq_factor,
-    original_max_seq) applies the Llama-3.1 frequency rescale."""
+    original_max_seq) applies the Llama-3.1 frequency rescale; ``yarn`` =
+    (factor, original_max_seq, beta_fast, beta_slow, attention_factor)
+    YaRN's, cos and sin times its attention factor."""
     d = x.shape[-1]
     inv_freq = rope_frequencies(d, theta)  # [d/2]
     if scaling is not None:
         inv_freq = llama3_scale_frequencies(inv_freq, *scaling)
+    if yarn is not None:
+        inv_freq = yarn_scale_frequencies(inv_freq, *yarn[:4], theta)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., T, d/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, d/2]
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1 = x[..., : d // 2].astype(jnp.float32)
     x2 = x[..., d // 2 :].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

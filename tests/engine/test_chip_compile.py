@@ -593,3 +593,84 @@ def test_a_family_with_state_keeps_its_logits_in_fast_memory_around_the_searches
     for name, shape, _ in step.wide_values():
         assert all("S(1)" in part for part in shape.split("], ") if step.wide in part), f"{name} leaves the fast memory: {shape}"
 
+
+
+# -- the mellum family: two caches a slot, the window walk beside the page walk ----------------------------
+
+_MELLUM_SLOTS, _MELLUM_PAGES = 32, 16385  # acpbench/configs/mellum2-12b-a2.5b-bf16-v5e1-ep4.json
+
+
+def _mellum(v5e, monkeypatch):
+    """The chip's share of the published config (16 of 64 experts), abstract
+    weights and both caches placed on one described chip, the expert layer
+    steered onto its kernel."""
+    import functools
+
+    from agentcontrolplane_tpu.models import mellum
+
+    monkeypatch.setattr(mellum, "routed_experts", functools.partial(mellum.routed_experts, kernel=True))
+    c = mellum.PRESETS["mellum2-12b-a2.5b-ep4"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: mellum.init_params(c, jax.random.key(0))))
+    cache = place(jax.eval_shape(lambda: mellum.init_paged_cache(c, _MELLUM_PAGES, PAGE, max_slots=_MELLUM_SLOTS)))
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return mellum, c, params, cache, vec
+
+
+def test_mellum_decode_block_walks_two_caches_and_copies_neither(v5e, monkeypatch):
+    """32 lanes of the published model at full depth, steps in a loop as the
+    engine's decode block nests them: six kernels in all (the window layer's
+    body: its walk and two grouped matmuls; the full layer's: the page walk
+    and two), both pools aliased from argument to result (5.23 GB), no op
+    copies either pool, a layer of one or a slot's ring out of it, and the
+    block's temporaries are a fraction of the pools."""
+    import re
+
+    mellum, c, params, cache, vec = _mellum(v5e, monkeypatch)
+    S = _MELLUM_SLOTS
+
+    def block(p, ca, tok, n, tables, active):
+        def step(carry, _):
+            ca, tok, n = carry
+            ca, logits = mellum.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (ca, tok, n + 1), tok
+
+        (ca, _, _), toks = jax.lax.scan(step, (ca, tok, n), None, length=4)
+        return ca, toks
+
+    compiled = jax.jit(block, donate_argnums=(1,)).lower(
+        params, cache, vec(S), vec(S), vec(S, 8192 // PAGE), vec(S, dt=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "paged_window_walk" in text and "paged_page_walk" in text and text.count("tpu_custom_call") == 6
+    pools = sum(cache[name].size * 2 for name in ("k", "v", "wk", "wv"))
+    mem = compiled.memory_analysis()
+    assert 5.2e9 < pools < 5.3e9 and mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 6, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB"
+    ring = 1024 // PAGE + 1
+    for pool in (rf"bf16\[7,{_MELLUM_PAGES},{PAGE},512\]", rf"bf16\[21,{(S + 1) * ring},{PAGE},512\]"):
+        assert re.search(pool, text)
+        assert not re.search(rf"= {pool}\S* copy\(", text), f"a copy of the whole pool {pool}"
+    assert f"bf16[{_MELLUM_PAGES},{PAGE},512]" not in text and f"bf16[{(S + 1) * ring},{PAGE},512]" not in text, (
+        "one layer of a pool as a value of its own")
+    assert 0.75 * 16e9 < _resident(compiled) < 14e9, f"{_resident(compiled) / 1e9:.2f} GB"
+
+
+def test_mellum_prefill_of_2048_tokens_fits_beside_the_resident_set(v5e, monkeypatch):
+    """The check's bucket (one row of 2,048 tokens, as the engine prefills
+    it): weights and both caches resident, the temporaries beside them, under
+    the chip's 16 GB; the expert layer's kernels are there (a chunk of 2,048
+    tokens at a time) and the window layers stack a ring's worth of rows a
+    layer (1,040), not the bucket's."""
+    mellum, c, params, cache, vec = _mellum(v5e, monkeypatch)
+    B, T = 1, 2048
+    compiled = jax.jit(
+        lambda p, ca, tok, n, ids, slots, snap: mellum.prefill_paged_batch(p, ca, tok, n, ids, (slots, snap), c),
+        donate_argnums=(1,),
+    ).lower(params, cache, vec(B, T), vec(B), vec(B, T // PAGE), vec(B), vec(B)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "paged_window_walk" not in text  # a prefill attends over its own rows
+    assert f"bf16[7,3,{B},1040,4,128]" in text and f"bf16[7,3,{B},{T},4,128]" not in text
+    assert _resident(compiled) < 14.5e9, f"{_resident(compiled) / 1e9:.1f} GB"

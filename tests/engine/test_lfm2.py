@@ -392,7 +392,7 @@ def test_state_and_counters_are_capabilities_apart(capability, monkeypatch):
     seen = types.SimpleNamespace(**{
         **vars(models._LLAMA),
         "counters": lambda cache: jnp.sum(cache["k"] != 0, dtype=jnp.uint32)[None],
-        "describe_counters": lambda config, total: ("kv_nonzero", {"n": 0 if total is None else int(total[0])}),
+        "describe_counters": lambda config, total: {"kv_nonzero": {"n": 0 if total is None else int(total[0])}},
     })
     monkeypatch.setattr(engine_module, "programs", lambda config: seen)
     eng = Engine(config=tiny_llama, mesh=ONE_CHIP(), max_slots=2, max_ctx=64, kv_layout="paged", page_size=8,
