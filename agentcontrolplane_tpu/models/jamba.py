@@ -24,9 +24,15 @@ layers in order) and ``ff`` (norm and SwiGLU over all layers). ``conv_w`` is
 ``[taps, d_inner]`` and ``A_log`` ``[d_state, d_inner]``, the channels on the
 lanes (the published order is the transpose of both).
 
-Layout for XLA: ONE ``lax.scan`` over all layers whose body switches on the
-layer's kind, each kind reading its own row of its own stack, as
-``models/lfm2.py`` does.
+Layout for XLA, as ``models/lfm2.py``'s and for its reasons: the decode step
+runs ``layer_types`` through ``scan_layers`` (the published pattern: a scan
+over two periods of ``mamba x 7, attention, mamba x 6``, each run of Mamba
+layers an inner scan), so a layer's kind is fixed when the program is traced,
+every loop body has one kind and no loop is handed a stack it does not use;
+rows of tokens (prefill, continuation, ``forward``) run ONE ``lax.scan`` over
+all layers whose body switches on the layer's kind, each kind reading its own
+row of its own stack (a second Mamba body is a second trace of the
+``ssm_scan`` kernel in each of 15 prefill programs: PERF.md, PR 41).
 
 Serving state (paged layout only): the KV pool holds the attention layers
 alone, ``[n_attention, pages, P, H_kv * d]``, and beside it
@@ -69,9 +75,10 @@ from ..ops.paged import (
     paged_decode_attention_reference_cache_plus_new,
 )
 from ..ops.pallas import ssm_scan as ssm
-from .lfm2 import _embed, _head_logits, _kv, _rows_ctx  # the same for every family with state beside the pages
+from .lfm2 import _embed, _head_logits, _kv, _rows_ctx, scan_layers  # the same for every family with state beside the pages
 
 N_COUNTERS = 4  # mamba_layers, rows, tokens, chunks
+STACK = {"mamba": "mamba", "attention": "attn"}  # a kind of layer -> its stack of weights in the tree
 
 
 def _pattern(n_layers: int, period: int, offset: int) -> tuple[str, ...]:
@@ -450,28 +457,16 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     0)."""
     c = config
     S = tokens.shape[0]
-    pl_ = plan(c)
     pool = _kv(cache)
     NP, P = pool["k"].shape[1:3]
     k_flat, v_flat = flat_pages(pool["k"]), flat_pages(pool["v"])
     scales = (flat_pages(pool["ks"]), flat_pages(pool["vs"])) if "ks" in pool else (None, None)
     dt, f32 = c.dtype, jnp.float32
     n, di = c.d_conv - 1, c.d_inner
-    kv_shape = (S, c.n_kv_heads, c.head_dim)
 
-    # The stacked state never enters a conditional: the branch that would
-    # hand it through unchanged is answered with a copy of the whole stack
-    # (two attention layers a step copied 1.1 GB each: PERF.md, PR 37). So
-    # the switch on the kind is around the mixers' matmuls alone, before and
-    # after the update, and the update and the conv columns' write run in
-    # every layer, on an attention layer as a pass-through of one block.
-    zeros = lambda *shape: jnp.zeros(shape, f32)  # noqa: E731
-
-    def attention(x, old, a_row, m_row):
-        layer = _row(params["attn"], a_row)
-
+    def make_attn(a):
         def attn(q, k, v):
-            tables = layer_tables(block_tables, a_row, NP)
+            tables = layer_tables(block_tables, a, NP)
             args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
             if use_pallas:
                 from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
@@ -480,42 +475,39 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
             return paged_decode_attention_reference_cache_plus_new(
                 *args, k_scales=scales[0], v_scales=scales[1])[:, None]
 
-        op, k, v = _attention_op(rms_norm(x, layer["ln1"], c.norm_eps), layer, c, attn)
-        return (op, old, zeros(S, 1, di), zeros(S, 1, di), zeros(S, 1, di), zeros(S, 1, c.d_state),
-                zeros(S, 1, c.d_state), k[:, 0].astype(dt), v[:, 0].astype(dt))
+        return attn
 
-    def mamba(x, old, a_row, m_row):
-        layer = _row(params["mamba"], m_row)
-        u_act, z, delta, b, c_, u_ext = _mamba_pre(
-            rms_norm(x, layer["ln1"], c.norm_eps), layer, c, old.reshape(S, n, di), active[:, None])
-        with jax.named_scope("mamba_conv"):
-            new = jnp.where(active[:, None], u_ext[:, 1:].reshape(S, n * di), old)
-        zero = jnp.zeros(kv_shape, dt)
-        return jnp.zeros((S, 1, c.dim), dt), new, u_act, z, delta, b, c_, zero, zero
-
-    def body(carry, scanned):
+    # The stacked state is carried through the loops and each Mamba layer
+    # updates its own row where it lies; it never enters a conditional,
+    # whose branch that hands it through unchanged is answered with a copy
+    # of the whole stack (1.1 GB a layer: PERF.md, PR 37).
+    def layer(kind, carry, index, at):
         x, h_all, conv_all = carry
-        ff, is_attn, a_row, m_row = scanned
-        old = jax.lax.dynamic_slice(conv_all, (m_row, 0, 0), (1, S, n * di))[0]
-        op, new, u_act, z, delta, b, c_, k, v = jax.lax.cond(is_attn, attention, mamba, x, old, a_row, m_row)
-        conv_all = jax.lax.dynamic_update_slice(conv_all, new[None], (m_row, 0, 0))
-        with jax.named_scope("ssm_update"):
-            a = -jnp.exp(params["mamba"]["A_log"][m_row].astype(f32))
-            y, h_all = ssm.update(h_all, m_row, delta[:, 0], u_act[:, 0], b[:, 0], c_[:, 0], a, ~is_attn)
-
-        def mixed(op):
-            return _mamba_post(y[:, None], u_act, z, _row(params["mamba"], m_row), dt)
-
-        op = jax.lax.cond(is_attn, lambda op: op, mixed, op)
+        weights = _row(params[STACK[kind]], at)
+        h = rms_norm(x, weights["ln1"], c.norm_eps)
+        if kind == "attention":
+            op, k, v = _attention_op(h, weights, c, make_attn(at))
+            out = (k[:, 0].astype(dt), v[:, 0].astype(dt))
+        else:
+            old = jax.lax.dynamic_slice(conv_all, (at, 0, 0), (1, S, n * di))[0]
+            u_act, z, delta, b, c_, u_ext = _mamba_pre(h, weights, c, old.reshape(S, n, di), active[:, None])
+            with jax.named_scope("mamba_conv"):
+                new = jnp.where(active[:, None], u_ext[:, 1:].reshape(S, n * di), old)
+            conv_all = jax.lax.dynamic_update_slice(conv_all, new[None], (at, 0, 0))
+            with jax.named_scope("ssm_update"):
+                a = -jnp.exp(weights["A_log"].astype(f32))
+                y, h_all = ssm.update(h_all, at, delta[:, 0], u_act[:, 0], b[:, 0], c_[:, 0], a)
+            op = _mamba_post(y[:, None], u_act, z, weights, dt)
+            out = ()
         x = x + op
-        return (x + _swiglu(x, ff, c), h_all, conv_all), (k, v)
+        return (x + _swiglu(x, _row(params["ff"], index), c), h_all, conv_all), out
 
     st = cache["state"]
-    (x, h_all, conv_all), (ks, vs) = jax.lax.scan(
-        body, (_embed(params, tokens[:, None], c), st["ssm"], st["conv"]), _scanned(params, c))
-    attn_at = pl_["is_attn"].nonzero()[0]
+    plan(c)  # refuses a pattern of one kind or of a kind it does not know
+    (x, h_all, conv_all), outs = scan_layers(
+        c.layer_types, (_embed(params, tokens[:, None], c), st["ssm"], st["conv"]), layer)
     target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-    pages = commit_tokens(pool, ks[attn_at], vs[attn_at], target, seq_lens % P)
+    pages = commit_tokens(pool, *outs["attention"], target, seq_lens % P)
     counts = _counts(c, active, active, active)
     state = {"ssm": h_all, "conv": conv_all, "snap": st["snap"], "counters": st["counters"].at[0].add(counts)}
     x = rms_norm(x[:, 0], params["norm"], c.norm_eps)

@@ -12,13 +12,28 @@ Every layer is ``h = x + Op(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))`` with
   experts (``ops.moe.routed_experts``: sigmoid scores, a selection bias,
   top-k renormalised, of which this chip holds ``experts_held``).
 
-Layout for XLA (``plan``): the leading dense layers are written out; the
-expert layers are one ``lax.scan`` whose body is one layer, a switch on its
-kind between the two operators (weights stacked by kind, each layer reading
-its own row) and the expert FF (router stacked over the scan; the experts of
-all layers flattened to one axis and closed over, indexed by the grouped
-matmul itself). The HLO holds each piece once whatever the depth and the
-pattern.
+Layout for XLA: the leading dense layers are written out; weights are
+stacked by kind (each layer reading its own row) and the experts of all
+layers flattened to one axis, closed over and indexed by the grouped matmul
+itself. The expert layers run in one of two layouts (``_run_layers``):
+
+- the decode step, whose cost is the serving rate: a layer's kind is a fact
+  of the trace, never a value on the chip. ``segments`` reads
+  ``layer_types``: the longest stretch that repeats a period is a
+  ``lax.scan`` over periods whose body holds one layer body a run of one
+  kind (a run longer than one layer is an inner scan), what stands before
+  and after it likewise, and a layer that repeats nothing is written out
+  (the published pattern: ``(attention, conv x 3) x 9`` as a scan of nine,
+  then ``attention, conv``). No loop is handed a stack it reads one row of,
+  and a kind's layer is traced once however many places run it;
+- rows of tokens (prefill, continuation, ``forward``): ONE scan whose body is
+  one layer, a switch on its kind between the two operators and the expert
+  FF once, so the HLO holds each piece once whatever the pattern. The switch
+  makes every stack an operand of every layer, which the chip answers by
+  moving ``wk`` and the conv state once a layer: 1.9 ms of a 14.7 ms decode
+  step, a few percent of a prefill. A body a kind holds the expert FF once a
+  body, and the cell's 27 prefill programs with it took half as long again
+  to trace, load and compile (PERF.md, PR 41).
 
 Serving state (paged layout only): the KV pool holds the attention layers
 alone, in the one layout every family stores and the page walk reads,
@@ -52,6 +67,8 @@ uses too.
 
 from __future__ import annotations
 
+import functools
+import itertools
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -155,6 +172,90 @@ def plan(c: Lfm2Config) -> dict:
         "attn_row": np.where(is_attn, np.cumsum(is_attn) - 1, 0).astype(np.int32),
         "conv_row": np.where(~is_attn, np.cumsum(~is_attn) - 1, 0).astype(np.int32),
     }
+
+
+def segments(kinds: tuple[str, ...]) -> list[tuple[int, tuple[tuple[str, int], ...]]]:
+    """``kinds`` in order as stretches ``(periods, runs)``: ``runs`` is one
+    period as runs of one kind ``(kind, layers)``. The stretch that covers
+    most layers by repeating a period at least twice (the shortest such
+    period, the earliest such stretch) is taken first, then what stands
+    before and after it in the same way; a layer that repeats nothing is a
+    stretch of one period of one layer."""
+    n = len(kinds)
+    best = None  # (layers covered, -period, -start) the larger the better
+    for span in range(1, n // 2 + 1):
+        for start in range(n - 2 * span + 1):
+            reps = 1
+            while kinds[start + reps * span:start + (reps + 1) * span] == kinds[start:start + span]:
+                reps += 1
+            if reps > 1 and (best is None or (reps * span, -span, -start) > best[0]):
+                best = ((reps * span, -span, -start), start, span, reps)
+    if best is None:
+        return [(1, ((kind, 1),)) for kind in kinds]
+    _, start, span, reps = best
+    runs = tuple((kind, len(list(group))) for kind, group in itertools.groupby(kinds[start:start + span]))
+    return segments(kinds[:start]) + [(reps, runs)] + segments(kinds[start + reps * span:])
+
+
+def _stack(parts: list):
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, axis=0), *parts)
+
+
+def _run(layer, kind: str, n: int, carry, index, row):
+    """``n`` layers of ``kind`` from place ``index`` and row ``row`` on: one
+    written out, more as a scan -> (carry, the layers' ``out`` stacked)."""
+    i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    if n == 1:
+        carry, out = layer[kind](carry, i32(index), i32(row))
+        return carry, jax.tree_util.tree_map(lambda a: a[None], out)
+    return jax.lax.scan(lambda carry, j: layer[kind](carry, i32(index + j), i32(row + j)), carry,
+                        jnp.arange(n, dtype=jnp.int32))
+
+
+def _stretch(layer, reps: int, runs, carry, at: int, rows: dict):
+    """``reps`` periods of ``runs`` (``segments``) from place ``at`` on,
+    ``rows[kind]`` layers of each kind before them: one period written out,
+    more as a scan over periods -> (carry, {kind: ``out`` stacked})."""
+    span = sum(n for _, n in runs)
+    each = {kind: sum(n for k, n in runs if k == kind) for kind, _ in runs}
+
+    def period(carry, p):
+        index, row = at + p * span, {kind: rows[kind] + p * each[kind] for kind in each}
+        got: dict[str, list] = {}
+        for kind, n in runs:
+            carry, out = _run(layer, kind, n, carry, index, row[kind])
+            got.setdefault(kind, []).append(out)
+            index, row[kind] = index + n, row[kind] + n
+        return carry, {kind: _stack(parts) for kind, parts in got.items()}
+
+    if reps == 1:
+        return period(carry, 0)
+    carry, out = jax.lax.scan(period, carry, jnp.arange(reps, dtype=jnp.int32))
+    return carry, jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), out)
+
+
+def scan_layers(kinds: tuple[str, ...], carry, layer):
+    """Run ``kinds`` in order as ``segments`` lays them out. ``layer(kind,
+    carry, index, row) -> (carry, out)`` is one layer: ``index`` () int32
+    its place in ``kinds`` and ``row`` its place among the layers of its
+    kind, a loop's counters or constants. Every loop body has one kind. A
+    kind's layer is traced and lowered ONCE a program however many loops and
+    written-out places run it (a ``jax.jit`` a kind: the places call one
+    function, which the compiler inlines; the published ``lfm2`` pattern has
+    four places for two kinds, and a place costs its expert FF's trace). ->
+    (carry, {kind: ``out`` stacked over the kind's layers in order}; a kind
+    without layers is not there)."""
+    outs: dict[str, list] = {}
+    at, rows = 0, dict.fromkeys(kinds, 0)
+    bodies = {kind: jax.jit(functools.partial(layer, kind)) for kind in rows}
+    for reps, runs in segments(tuple(kinds)):
+        carry, out = _stretch(bodies, reps, runs, carry, at, rows)
+        for kind, n in runs:
+            rows[kind] += reps * n
+            at += reps * n
+        for kind, part in out.items():
+            outs.setdefault(kind, []).append(part)
+    return carry, {kind: _stack(parts) for kind, parts in outs.items()}
 
 
 def init_params(config: Lfm2Config, key: jax.Array) -> dict:
@@ -266,7 +367,7 @@ def _experts(x, ff, stacks, layer_index, c: Lfm2Config, valid, chosen=None):
     return y.reshape(B, T, D), jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
 
 
-def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None):
+def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None, by_kind=False):
     """The whole stack. ``conv_state`` [n_conv, B, taps-1, D] is each conv
     layer's state before the rows; ``make_attn(a)`` gives attention layer
     ``a``'s (traced index) attention function; ``route``
@@ -276,81 +377,96 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
     [n_conv, B, taps-1, D], conv snaps, new k [n_attention, B, T, H_kv, d],
     new v, expert counters).
 
-    The leading dense layers are written out. The expert layers are ONE
-    scan whose body is one layer: a switch on the layer's kind between the
-    two operators (each reading its own row of its own stack) and the
-    expert FF, so a program holds each piece once whatever the depth and
-    the pattern, and compiles in a third of the time of a body that is a
-    whole period with prologue and tail beside it (PERF.md, PR 31)."""
+    The leading dense layers are written out. The expert layers run in one
+    of two layouts, the same operations on the same values in the same
+    order (module text): ``by_kind`` (the decode step) as ``scan_layers``
+    lays the pattern out, each loop's body one kind of layer that reads its
+    own row of its kind's stack, of the state and of the router by the
+    loops' counters; otherwise (rows of tokens) as ONE scan whose body is
+    one layer, a switch on the kind between the two operators and the expert
+    FF once."""
     pl_ = plan(c)
     B, T, D = x.shape
     n = c.conv_taps - 1
     kv_shape = (B, T, c.n_kv_heads, c.head_dim)
     ends, snaps, ks, vs = [], [], [], []
-    ci = ai = 0
     # the residual stream in the model's dtype, as the source serves it:
     # 80 sublayers' sums rounded to bfloat16 each are the largest part of
     # what the program loses against its float32 reference (PERF.md, PR 31)
     dt = x.dtype
     norm = lambda x, w: rms_norm(x, w, c.norm_eps)  # noqa: E731
-    for kind, layer in zip(pl_["prologue"], params["pro"]):
+
+    def operator(kind, x, layer, at):
+        """``Op(RMSNorm(x))`` with ``layer`` the weights of the ``at``-th
+        layer of ``kind`` after those ``done`` -> (Op, (end, snap) or (k, v))."""
         h = norm(x, layer["ln1"])
+        at = done[kind] + at
         if kind == "conv":
-            op, end, snap = _conv_op(h, layer, c, conv_state[ci], ctx["lengths"], ctx["snap_rel"])
-            ends.append(end[None])
-            snaps.append(snap[None])
-            ci += 1
+            op, *out = _conv_op(h, layer, c, conv_state[at], ctx["lengths"], ctx["snap_rel"])
         else:
-            op, k, v = _attention_op(h, layer, c, ctx["positions"], make_attn(ai))
-            ks.append(k[None])
-            vs.append(v[None])
-            ai += 1
+            op, *out = _attention_op(h, layer, c, ctx["positions"], make_attn(at))
+        return op, tuple(o.astype(dt) for o in out)
+
+    done = {"conv": 0, "attention": 0}  # layers of each kind so far
+    kept = {"conv": (ends, snaps), "attention": (ks, vs)}
+    for kind, layer in zip(pl_["prologue"], params["pro"]):
+        op, out = operator(kind, x, layer, 0)
+        for part, o in zip(kept[kind], out):
+            part.append(o[None])
+        done[kind] += 1
         x = x + op
         h = norm(x, layer["ln2"])
         x = x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
 
     counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
-    n_body = len(pl_["body"])
-    if n_body:
-        ci0, ai0 = ci, ai
+    if pl_["body"]:
         ff = params["ff"]
         stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
-        row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
-
-        def attention(x, a_row, c_row):
-            layer = row(params["attn"], a_row)
-            op, k, v = _attention_op(norm(x, layer["ln1"]), layer, c, ctx["positions"], make_attn(ai0 + a_row))
-            zero = jnp.zeros((B, n, D), dt)
-            return op, zero, zero, k.astype(dt), v.astype(dt)
-
-        def conv(x, a_row, c_row):
-            layer = row(params["conv"], c_row)
-            op, end, snap = _conv_op(norm(x, layer["ln1"]), layer, c, conv_state[ci0 + c_row],
-                                     ctx["lengths"], ctx["snap_rel"])
-            zero = jnp.zeros(kv_shape, dt)
-            return op, end, snap, zero, zero
-
-        def body(carry, scanned):
-            x, counts = carry
-            small, index, is_attn, a_row, c_row, chosen = scanned
-            if len(set(pl_["body"])) == 1:  # one kind only: its stack alone has rows
-                op, end, snap, k, v = (attention if pl_["is_attn"][0] else conv)(x, a_row, c_row)
-            else:
-                op, end, snap, k, v = jax.lax.cond(is_attn, attention, conv, x, a_row, c_row)
-            x = x + op
-            y, m = _experts(norm(x, small["ln2"]), small, stacks, index, c, ctx["valid"], chosen)
-            return (x + y, counts + m), (end, snap, k, v)
-
         small = {name: ff[name] for name in ("ln2", "router", "router_bias")}
-        (x, counts), (e, s_, kk, vv) = jax.lax.scan(
-            body, (x, counts),
-            (small, jnp.arange(n_body, dtype=jnp.int32), jnp.asarray(pl_["is_attn"]),
-             jnp.asarray(pl_["attn_row"]), jnp.asarray(pl_["conv_row"]), route))
-        attn_at, conv_at = pl_["is_attn"].nonzero()[0], (~pl_["is_attn"]).nonzero()[0]
-        ends.append(e[conv_at])
-        snaps.append(s_[conv_at])
-        ks.append(kk[attn_at])
-        vs.append(vv[attn_at])
+        row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
+        stack = {"attention": params["attn"], "conv": params["conv"]}
+
+        def expert_layer(carry, op, mine, index, chosen):
+            """``mine``: the layer's norm and router."""
+            x, counts = carry
+            x = x + op
+            y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, ctx["valid"], chosen)
+            return x + y, counts + m
+
+        if by_kind:
+            def layer(kind, carry, index, at):
+                op, out = operator(kind, carry[0], row(stack[kind], at), at)
+                return expert_layer(carry, op, row(small, index), index, None if route is None else route[index]), out
+
+            (x, counts), outs = scan_layers(pl_["body"], (x, counts), layer)
+            for kind, out in outs.items():
+                for part, o in zip(kept[kind], out):
+                    part.append(o)
+        else:
+            def branch(kind):
+                def run(x, at):  # -> (Op, end, snap, k, v), zeros for the other kind's
+                    op, out = operator(kind, x, row(stack[kind], at[kind]), at[kind])
+                    zero = jnp.zeros(kv_shape if kind == "conv" else (B, n, D), dt)
+                    return (op, *out, zero, zero) if kind == "conv" else (op, zero, zero, *out)
+
+                return run
+
+            def body(carry, scanned):
+                mine, index, is_attn, at, chosen = scanned
+                if len(set(pl_["body"])) == 1:  # one kind only: its stack alone has rows
+                    op, *out = branch(pl_["body"][0])(carry[0], at)
+                else:
+                    op, *out = jax.lax.cond(is_attn, branch("attention"), branch("conv"), carry[0], at)
+                return expert_layer(carry, op, mine, index, chosen), tuple(out)
+
+            n_body = len(pl_["body"])
+            (x, counts), (e, s_, kk, vv) = jax.lax.scan(
+                body, (x, counts),
+                (small, jnp.arange(n_body, dtype=jnp.int32), jnp.asarray(pl_["is_attn"]),
+                 {"attention": jnp.asarray(pl_["attn_row"]), "conv": jnp.asarray(pl_["conv_row"])}, route))
+            attn_at, conv_at = pl_["is_attn"].nonzero()[0], (~pl_["is_attn"]).nonzero()[0]
+            for part, o in zip((ends, snaps, ks, vs), (e[conv_at], s_[conv_at], kk[attn_at], vv[attn_at])):
+                part.append(o)
 
     cat = lambda parts, shape, dtype: (  # noqa: E731
         jnp.concatenate(parts, axis=0) if parts else jnp.zeros((0,) + shape, dtype))
@@ -539,7 +655,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
 
     st = cache["state"]
     x, ends, _snaps, new_k, new_v, counts = _run_layers(
-        params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route)
+        params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route, by_kind=True)
     target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
     pages = commit_tokens(pool, new_k[:, :, 0], new_v[:, :, 0], target, seq_lens % P)
     conv = st["conv"].at[:, :S].set(

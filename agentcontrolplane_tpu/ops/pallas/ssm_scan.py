@@ -32,11 +32,10 @@ wholly past a row's length are skipped (``n_chunks``): they write zeros for
 ``[layers, slots, N, D]``, read and written IN PLACE (the state is aliased to
 the output; the layer comes as a prefetched scalar into the index maps), so a
 step moves each live lane's state once in and once out and nothing else of
-the stack. It is called once in every layer of a program's one layer body,
-whatever the layer's kind, with ``live`` false where the layer has no
-recurrence: the stack then never passes through a conditional, whose
-pass-through branch the compiler answered with a copy of the whole stack a
-layer (PERF.md, PR 37).
+the stack. The programs call it in their Mamba layers' loop bodies with the
+stack as the loops' carry; the stack never passes through a conditional,
+whose pass-through branch the compiler answered with a copy of the whole
+stack a layer (PERF.md, PR 37).
 
 ``*_reference`` are the same functions in plain XLA (a ``lax.scan`` over
 time), what the programs run where there is no TPU and what the tests hold
@@ -186,75 +185,60 @@ def scan(delta, u, b, c, a, h0, snap_rel, n_chunks, kernel: bool | None = None):
 # ---------------------------------------------------------------------------
 
 
-def _update_kernel(row_ref, live_ref, state_ref, delta_ref, u_ref, b_ref, c_ref, a_ref, y_ref, out_ref, *, lanes):
+def _update_kernel(row_ref, state_ref, delta_ref, u_ref, b_ref, c_ref, a_ref, y_ref, out_ref, *, lanes):
     del row_ref
-
-    @pl.when(live_ref[0] != 0)
-    def _():
-        a = a_ref[...]
-        for s in range(lanes):
-            delta_t = delta_ref[s:s + 1, :]
-            h, y = _step(state_ref[0, s], a, delta_t, delta_t * u_ref[s:s + 1, :], b_ref[s], c_ref[s])
-            out_ref[0, s] = h
-            y_ref[s:s + 1, :] = y
-
-    @pl.when(live_ref[0] == 0)
-    def _():
-        out_ref[...] = state_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+    a = a_ref[...]
+    for s in range(lanes):
+        delta_t = delta_ref[s:s + 1, :]
+        h, y = _step(state_ref[0, s], a, delta_t, delta_t * u_ref[s:s + 1, :], b_ref[s], c_ref[s])
+        out_ref[0, s] = h
+        y_ref[s:s + 1, :] = y
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "col_tile"))
-def ssm_update(state, row, delta, u, b, c, a, live=True, interpret: bool = False, col_tile: int = 2560):
+def ssm_update(state, row, delta, u, b, c, a, interpret: bool = False, col_tile: int = 2560):
     """state [layers, slots, N, D] float32; row () int32, the layer; delta,
     u [S, D]; b, c [S, N]; a [N, D] -> (y [S, D], state with
     ``state[row, :S]`` one step on). S <= slots, a multiple of LANES or S
-    itself. ``live`` () bool: where false (a layer of another kind passing
-    through the same loop body) nothing is stepped, ``y`` is zeros, and every
-    grid step names the layer's first block, which is fetched once and
-    written back as it was: the call costs one block."""
+    itself."""
     S, D = delta.shape
     N = a.shape[0]
     lanes = LANES if S % LANES == 0 else S
     tc = _col_tile(D, col_tile)
     f32 = jnp.float32
-    per_lane = pl.BlockSpec((lanes, tc), lambda i, j, row, live: (i * live[0], j * live[0]))
-    column = pl.BlockSpec((lanes, N, 1), lambda i, j, row, live: (i * live[0], 0, 0))
-    block = pl.BlockSpec((1, lanes, N, tc), lambda i, j, row, live: (row[0], i * live[0], 0, j * live[0]))
+    per_lane = pl.BlockSpec((lanes, tc), lambda i, j, row: (i, j))
+    column = pl.BlockSpec((lanes, N, 1), lambda i, j, row: (i, 0, 0))
+    block = pl.BlockSpec((1, lanes, N, tc), lambda i, j, row: (row[0], i, 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(S // lanes, D // tc),
-        in_specs=[block, per_lane, per_lane, column, column,
-                  pl.BlockSpec((N, tc), lambda i, j, row, live: (0, j * live[0]))],
+        in_specs=[block, per_lane, per_lane, column, column, pl.BlockSpec((N, tc), lambda i, j, row: (0, j))],
         out_specs=[per_lane, block],
     )
-    y, state = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_update_kernel, lanes=lanes),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((S, D), f32), jax.ShapeDtypeStruct(state.shape, f32)],
-        input_output_aliases={2: 1},  # operands 0 and 1 are the prefetched scalars
+        input_output_aliases={1: 1},  # operand 0 is the prefetched scalar
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ssm_update",
-    )(jnp.reshape(row, (1,)).astype(jnp.int32), jnp.reshape(live, (1,)).astype(jnp.int32), state,
+    )(jnp.reshape(row, (1,)).astype(jnp.int32), state,
       delta.astype(f32), u.astype(f32), b.astype(f32)[:, :, None], c.astype(f32)[:, :, None], a.astype(f32))
-    return jnp.where(live, y, 0.0), state
 
 
-def ssm_update_reference(state, row, delta, u, b, c, a, live=True):
-    """``ssm_update`` in plain XLA (``live`` false: ``delta`` taken as 0)."""
+def ssm_update_reference(state, row, delta, u, b, c, a):
+    """``ssm_update`` in plain XLA."""
     f32 = jnp.float32
     S = delta.shape[0]
     delta, u, b, c, a = (x.astype(f32) for x in (delta, u, b, c, a))
-    delta = jnp.where(live, delta, 0.0)
     h = jax.lax.dynamic_slice(state, (row, 0, 0, 0), (1, S) + state.shape[2:])[0]
     h = jnp.exp(delta[:, None, :] * a[None]) * h + (delta * u)[:, None, :] * b[:, :, None]
-    y = jnp.where(live, jnp.sum(h * c[:, :, None], axis=1), 0.0)
-    return y, jax.lax.dynamic_update_slice(state, h[None], (row, 0, 0, 0))
+    return jnp.sum(h * c[:, :, None], axis=1), jax.lax.dynamic_update_slice(state, h[None], (row, 0, 0, 0))
 
 
-def update(state, row, delta, u, b, c, a, live=True, kernel: bool | None = None):
+def update(state, row, delta, u, b, c, a, kernel: bool | None = None):
     """The decode step's recurrence, chosen as ``scan`` is."""
     if kernel is None:
         kernel = jax.default_backend() == "tpu"
-    return (ssm_update if kernel else ssm_update_reference)(state, row, delta, u, b, c, a, live)
+    return (ssm_update if kernel else ssm_update_reference)(state, row, delta, u, b, c, a)

@@ -434,12 +434,10 @@ def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, tok
     assert "bf16[8,2048,1536]" not in text, "a layer's experts were sliced out of the stack (a copy a call)"
 
 
-def test_lfm2_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, monkeypatch):
-    """`models.lfm2.decode_step_paged` at the published widths and full
-    depth, this chip's eighth of the experts, 32 slots over 2,049 pages: it
-    fits one chip, holds each piece once (three kernels: the walk and two
-    grouped matmuls), and its temporaries are a fraction of the pool: no
-    layer of the pool, and no layer's experts, is copied out for a kernel."""
+def _lfm2(v5e, monkeypatch):
+    """The chip's eighth of the published config, abstract weights and cache
+    (32 slots over 2,049 pages) placed on one described chip, the expert
+    layer steered onto its kernel."""
     import functools
 
     from agentcontrolplane_tpu.models import lfm2
@@ -452,13 +450,36 @@ def test_lfm2_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, monke
     params = place(jax.eval_shape(lambda: lfm2.init_params(c, jax.random.key(0))))
     cache = place(jax.eval_shape(lambda: lfm2.init_paged_cache(c, 2049, PAGE, max_slots=32)))
     vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return lfm2, c, params, cache, vec
+
+
+def _kernels_by_body(compiled) -> list:
+    """The kernels (`tpu_custom_call`) each computation of the program
+    holds itself, fusions and loops under it apart: sorted, zeros left out.
+    One entry a place where a layer body is traced."""
+    comps = _computations(compiled.as_text())
+    return sorted(n for n in (sum("tpu_custom_call" in rest for _, _, op, rest in _ops(lines) if op == "custom-call")
+                              for lines in comps.values()) if n)
+
+
+def test_lfm2_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, monkeypatch):
+    """`models.lfm2.decode_step_paged` at the published widths and full
+    depth, this chip's eighth of the experts, 32 slots over 2,049 pages: it
+    fits one chip and holds a layer body a kind a place (`segments`): the
+    loop over the nine periods has the attention layer's (the walk and two
+    grouped matmuls: 3 kernels), the loop over a period's three conv layers
+    theirs (2), and the entry the tail's two layers written out (3 + 2): four
+    layer bodies in three computations. Its temporaries are a fraction of
+    the pool: no layer of the pool, and no layer's experts, is copied out
+    for a kernel."""
+    lfm2, c, params, cache, vec = _lfm2(v5e, monkeypatch)
     compiled = jax.jit(
         lambda p, ca, tok, n, tables, active: lfm2.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True),
         donate_argnums=(1,),
     ).lower(params, cache, vec(32), vec(32), vec(32, 64), vec(32, dt=jnp.bool_)).compile()
     mem = compiled.memory_analysis()
     pool = 2 * 10 * 2049 * PAGE * 8 * 64 * 2
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert _kernels_by_body(compiled) == [2, 3, 5]
     assert mem.temp_size_in_bytes < pool // 4, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB beside a {pool / 1e6:.0f} MB pool"
     resident = mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert 0.25 * 16e9 < resident < 16e9, f"{resident / 1e9:.2f} GB"
@@ -502,12 +523,14 @@ def test_jamba_walk_compiles_at_one_kv_head_and_a_group_of_twenty(v5e):
 
 def test_jamba_decode_block_updates_the_state_in_place(v5e, monkeypatch):
     """128 lanes of the published model, steps in a loop as the engine's
-    decode block nests them: the page walk and the update kernel are in the
-    one layer body, the whole state (2.40 GB with its snapshot) is aliased
-    from argument to result, no op copies the stack of `h` (a conditional
-    that handed it through unchanged did, once a layer: 6.6 ms a step on
-    the chip, PERF.md PR 37; one step alone compiled without it) and the
-    block's temporaries are a small fraction of the state."""
+    decode block nests them: three kernels in three layer bodies (the loop
+    over the two periods holds the attention layer's page walk, each of a
+    period's two runs of Mamba layers, seven and six, the update kernel), the
+    whole state (2.40 GB with its snapshot) is aliased from argument to
+    result through all three loops, no op copies the stack of `h` (a
+    conditional that handed it through unchanged did, once a layer: 6.6 ms a
+    step on the chip, PERF.md PR 37; one step alone compiled without it) and
+    the block's temporaries are a small fraction of the state."""
     import re
 
     jamba, c, params, cache, vec = _jamba(v5e, monkeypatch)
@@ -526,7 +549,7 @@ def test_jamba_decode_block_updates_the_state_in_place(v5e, monkeypatch):
     compiled = jax.jit(block, donate_argnums=(1,)).lower(
         params, cache, vec(S), vec(S), vec(S, 2048 // PAGE), vec(S, dt=jnp.bool_)).compile()
     text = compiled.as_text()
-    assert "ssm_update" in text and "paged_page_walk" in text
+    assert "ssm_update" in text and "paged_page_walk" in text and _kernels_by_body(compiled) == [1, 1, 1]
     state = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache["state"]))
     mem = compiled.memory_analysis()
     assert state > 2.3e9 and mem.alias_size_in_bytes >= state
@@ -561,8 +584,6 @@ def test_a_family_with_state_keeps_its_logits_in_fast_memory_around_the_searches
     engine's own `make_decode_block`: the same placement as the Qwen
     blocks above. The top-k loop in the step's body, the top-p loop under
     the conditional, and every [S, V] value of the step in `S(1)`."""
-    import functools
-
     from agentcontrolplane_tpu.engine import engine
     from agentcontrolplane_tpu.engine.lanes import DECODE
 
@@ -570,16 +591,8 @@ def test_a_family_with_state_keeps_its_logits_in_fast_memory_around_the_searches
         model, c, params, cache, vec = _jamba(v5e, monkeypatch)
         slots, ctx, block = _JAMBA_SLOTS, 2048, 32
     else:
-        from agentcontrolplane_tpu.models import lfm2 as model
-
-        monkeypatch.setattr(model, "routed_experts", functools.partial(model.routed_experts, kernel=True))
-        c, slots, ctx, block = model.PRESETS["lfm2-24b-a2b-ep8"], 32, 1024, 16
-        one_chip = SingleDeviceSharding(v5e[0])
-        place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
-        params = place(jax.eval_shape(lambda: model.init_params(c, jax.random.key(0))))
-        cache = place(jax.eval_shape(lambda: model.init_paged_cache(c, 2049, PAGE, max_slots=slots)))
-        vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+        model, c, params, cache, vec = _lfm2(v5e, monkeypatch)
+        slots, ctx, block = 32, 1024, 16
     key = jax.eval_shape(lambda: jax.random.key(0))
     fn = engine.make_decode_block(
         lambda p, ca, tok, n, active, tables: model.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True),
@@ -593,6 +606,143 @@ def test_a_family_with_state_keeps_its_logits_in_fast_memory_around_the_searches
     for name, shape, _ in step.wide_values():
         assert all("S(1)" in part for part in shape.split("], ") if step.wide in part), f"{name} leaves the fast memory: {shape}"
 
+
+
+# -- a layer is handed its own row, not its kind's whole stack ----------------------------------------
+
+_MB = 1 << 20
+_PASSED_ON = ("parameter", "get-tuple-element", "tuple", "while", "call", "conditional", "bitcast")
+
+
+def _arrays(result: str):
+    """(text, leading dimension, bytes) of each array in an instruction's result type."""
+    import re
+
+    width = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4}
+    for dtype, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]+)\]", result):
+        dims = [int(d) for d in dims.split(",")]
+        size = width.get(dtype, 4)
+        for d in dims:
+            size *= d
+        yield f"{dtype}[{','.join(map(str, dims))}]", dims[0], size
+
+
+class _Loops:
+    """A compiled program's loops: what each `while` is handed and what the
+    computations under its body do."""
+
+    def __init__(self, compiled):
+        self.comps = {name.removeprefix("ENTRY "): lines for name, lines in _computations(compiled.as_text()).items()}
+        # (the operands' types, the body) of every loop, wherever it stands
+        self.loops = [(result, _called(rest)[1]) for lines in self.comps.values()
+                      for _, result, op, rest in _ops(lines) if op == "while"]
+
+    def under(self, body: str, fusions: bool) -> list:
+        """`body` and every computation it reaches; with `fusions`, the fused ones too."""
+        seen, todo = [], [body]
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in self.comps:
+                continue
+            seen.append(name)
+            todo += [c for _, _, op, rest in _ops(self.comps[name]) if fusions or op != "fusion" for c in _called(rest)]
+        return seen
+
+    def in_place(self, op: str, rest: str) -> bool:
+        """An update of a carried array where it lies: a `dynamic-update-slice`,
+        alone or as a fusion's root, or a kernel whose result is its operand."""
+        if op == "fusion":
+            return any(line.lstrip().startswith("ROOT") and " dynamic-update-slice(" in line
+                       for c in _called(rest) for line in self.comps.get(c, ()))
+        return op == "dynamic-update-slice" or (op == "custom-call" and "output_to_operand_aliasing" in rest)
+
+    def stacks_made(self, leads) -> list:
+        """Instructions under a loop's body that produce an array over 1 MB
+        whose leading dimension is one of `leads`, in-place updates apart. A
+        fusion counts by what it writes, not by what it holds inside."""
+        out = []
+        for name in sorted({c for _, body in self.loops for c in self.under(body, fusions=False)}):
+            for ins, result, op, rest in _ops(self.comps[name]):
+                if op in _PASSED_ON or self.in_place(op, rest):
+                    continue
+                out += [f"{ins} = {text} {op}" for text, lead, size in _arrays(result) if lead in leads and size > _MB]
+        return list(dict.fromkeys(out))
+
+    def marked(self, name: str, marks) -> bool:
+        """Whether anything under computation `name` carries one of `marks` (a scope or kernel name)."""
+        return any(mark in line for c in self.under(name, fusions=True) for line in self.comps[c] for mark in marks)
+
+    def handed(self, shapes, marks) -> list:
+        """Loops that are handed an array of one of `shapes` although nothing
+        under their body carries one of `marks`."""
+        return [f"{body}: {shape}" for result, body in self.loops if not self.marked(body, marks)
+                for shape in sorted(shapes) if shape in result]
+
+    def switches(self, marks_a, marks_b) -> list:
+        """Conditionals of which one branch carries `marks_a` and another `marks_b`."""
+        out = []
+        for lines in self.comps.values():
+            for ins, _, op, rest in _ops(lines):
+                branches = _called(rest) if op == "conditional" else []
+                if any(self.marked(b, marks_a) for b in branches) and any(self.marked(b, marks_b) for b in branches):
+                    out.append(ins)
+        return out
+
+
+def _stack_shapes(*trees) -> set:
+    names = {jnp.dtype(jnp.bfloat16): "bf16", jnp.dtype(jnp.float32): "f32"}
+    return {f"{names[jnp.dtype(x.dtype)]}[{','.join(map(str, x.shape))}]"
+            for tree in trees for x in jax.tree_util.tree_leaves(tree)}
+
+
+@pytest.mark.parametrize("family", ["lfm2", "jamba"])
+def test_a_decode_step_hands_a_layer_its_own_row_not_its_kinds_stack(v5e, family, monkeypatch):
+    """`lfm2` and `jamba` decode steps at the published widths and the cells'
+    slots, compiled for the described v5e. A layer's kind is fixed when the
+    step is traced, so each loop's body has one kind and reads its row of
+    the closed-over stacks by the loop's counter. (a) Over the computations a
+    `while` body reaches, no instruction produces an array over 1 MB whose
+    leading dimension is a stack's (the attention layers, the scanned conv or
+    Mamba layers, all of them), a carried state's update in place apart: a
+    fusion that takes a stack and reads one row is the form wanted. (`jamba`
+    has two attention layers: a stack of two rows, which the compiler may
+    fetch whole into fast memory beside the layer that reads one, twice a
+    step; it is held out of the Mamba loops by (b) and not counted here.) (b)
+    A loop under whose body no attention layer runs is handed nothing with
+    the shape of an attention stack, and one that runs no conv or Mamba layer
+    nothing of theirs or of their state. (c) No conditional has a branch of
+    each kind. One body that switched on the kind (`lax.cond`) made every
+    stack an operand of every layer: on `lfm2` the chip evicted and
+    refetched `bf16[10,2048,512]` (`wk`, 21 MB) and relaid
+    `bf16[30,32,2,2048]` (the conv state) 38 times a step, on `jamba` it cut
+    `bf16[1,2560,2560]` rows out of `wq` and `wo` in 28 layers for the 2 that
+    use them (PERF.md, PR 41). The programs over rows of tokens keep that
+    body, and why: `models/lfm2.py`'s module text."""
+    if family == "lfm2":
+        model, c, params, cache, vec = _lfm2(v5e, monkeypatch)
+        slots, ctx = 32, 1024
+        leads = {c.n_attention, c.n_conv, int(model.plan(c)["is_attn"].sum()), int((~model.plan(c)["is_attn"]).sum())}
+        other, other_marks = "conv", ("short_conv",)
+    else:
+        model, c, params, cache, vec = _jamba(v5e, monkeypatch)
+        slots, ctx = _JAMBA_SLOTS, 2048
+        leads = {c.n_mamba, c.n_layers}
+        other, other_marks = "mamba", ("mamba_in_proj",)
+    # the attention operator has no scope of its own: give it one here
+    op = model._attention_op
+    monkeypatch.setattr(model, "_attention_op", lambda *a, **k: jax.named_scope("attention_op")(op)(*a, **k))
+    compiled = jax.jit(
+        lambda p, ca, tok, n, tables, active: model.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True),
+        donate_argnums=(1,),
+    ).lower(params, cache, vec(slots), vec(slots), vec(slots, ctx // PAGE), vec(slots, dt=jnp.bool_)).compile()
+    loops = _Loops(compiled)
+    attention = ("attention_op",)
+    assert loops.loops and any(loops.marked(body, attention) and loops.marked(body, other_marks) for _, body in loops.loops)
+    assert not loops.stacks_made(leads), "a stack, or its like, is made inside a loop"
+    state = {k: v for k, v in cache["state"].items() if k in ("conv", "ssm")}
+    assert not loops.handed(_stack_shapes(params["attn"]), attention)
+    assert not loops.handed(_stack_shapes(params[other], state), other_marks)
+    assert not loops.switches(attention, other_marks), "a conditional chooses a layer's kind on the chip"
 
 
 # -- the mellum family: two caches a slot, the window walk beside the page walk ----------------------------

@@ -113,9 +113,6 @@ def test_the_update_kernel_steps_one_layers_lanes_in_place_and_no_other_row(S):
         np.testing.assert_allclose(out, want, atol=2e-5)
         assert np.array_equal(out[1, 1], state[1, 1]) and np.array_equal(out[0], state[0])
         assert np.array_equal(out[1, S:], state[1, S:])
-        # a layer of another kind passing through the same loop body: nothing is stepped
-        y, out = fn(jnp.asarray(state), jnp.int32(1), *f32, jnp.asarray(a, jnp.float32), jnp.asarray(False))
-        assert np.array_equal(out, state) and not np.asarray(y).any()
 
 
 # -- the program against the plain reference -----------------------------------
@@ -223,6 +220,31 @@ def test_a_continuation_from_an_installed_state_equals_one_prefill_of_the_whole_
     mid = jamba.prefill_paged_continue_kv(
         params, moved, tail, i32(T - cut), i32(cut), ids, pages, (i32(1), i32(-1)), pc)
     assert all(bool(jnp.array_equal(x, y)) for x, y in zip(jax.tree_util.tree_leaves(mid), jax.tree_util.tree_leaves(done)))
+
+
+m_, a_ = "mamba", "attention"
+# name -> (layer_types, the layers as `segments` lays them out)
+LAYOUTS = {
+    "published": (jamba.JambaConfig().layer_types, [(2, ((m_, 7), (a_, 1), (m_, 6)))]),
+    "no-period": ((m_, a_, a_, m_, m_), [(1, ((m_, 1),)), (2, ((a_, 1),)), (2, ((m_, 1),))]),
+    "attention-first": ((a_, m_, m_, a_, m_, m_, m_), [(2, ((a_, 1), (m_, 2))), (1, ((m_, 1),))]),
+    "period-of-two": ((m_, a_) * 3, [(3, ((m_, 1), (a_, 1)))]),
+}
+
+
+@pytest.mark.parametrize("name", list(LAYOUTS))
+def test_any_layer_pattern_serves_what_forward_computes(name):
+    """The pattern's layout for a decode step (`models/lfm2.py segments`);
+    prefill and continuation (through the one body that switches on the
+    kind) and decode steps through that layout give `forward`'s logits and
+    the K, V, `h` and conv columns of one prefill."""
+    from agentcontrolplane_tpu.models.lfm2 import segments
+
+    from .test_lfm2 import serves_what_forward_computes
+
+    kinds, layout = LAYOUTS[name]
+    assert segments(tuple(kinds)) == layout
+    serves_what_forward_computes(jamba, dataclasses.replace(CFG, layer_types=tuple(kinds)), ("ssm", "conv"))
 
 
 # -- the engine carries the state ------------------------------------------

@@ -62,6 +62,64 @@ def test_program_agrees_with_the_plain_reference_through_pages_and_state(name):
     assert numbers["prefill_rel_rms"] < 2e-5 and numbers["decode_rel_rms"] < 2e-5, numbers
 
 
+a, c_ = "attention", "conv"
+# name -> (layer_types, num_dense_layers, the expert layers as `segments` lays them out)
+LAYOUTS = {
+    "published": (lfm2.Lfm2Config().layer_types, 2, [(9, ((a, 1), (c_, 3))), (1, ((a, 1),)), (1, ((c_, 1),))]),
+    "no-period": ((c_, a, a, c_, c_), 0, [(1, ((c_, 1),)), (2, ((a, 1),)), (2, ((c_, 1),))]),
+    "one-kind": ((c_, c_, c_, c_), 1, [(3, ((c_, 1),))]),
+    "attention-in-the-prologue": ((a, c_, a, c_, c_, a, c_, c_, c_), 2, [(2, ((a, 1), (c_, 2))), (1, ((c_, 1),))]),
+}
+
+
+def serves_what_forward_computes(model, cfg, state_leaves):
+    """A prefill of 16 tokens, a continuation of 8 and four decode steps each
+    give `model.forward`'s logits of the same weights and tokens, and they
+    leave the K, V and `state_leaves` that one prefill of all 28 tokens
+    leaves (`tests/engine/test_jamba.py` runs its family through this too)."""
+    params = model.init_params(cfg, jax.random.key(3))
+    B, P, cut, mid, T = 2, 8, 16, 24, 28
+    tokens = np.random.default_rng(4).integers(0, cfg.vocab_size, (B, 32)).astype(np.int32)
+    want = model.forward(params, jnp.asarray(tokens[:, :T]), cfg)
+    close = functools.partial(np.testing.assert_allclose, rtol=2e-5, atol=2e-5)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    full = lambda v: jnp.full((B,), v, jnp.int32)  # noqa: E731
+    pages = i32([[1, 2, 3, 4], [5, 6, 7, 8]])
+    lanes = (i32([0, 1]), full(-1))
+    empty = model.init_paged_cache(cfg, 9, P, max_slots=B)
+    whole, _ = model.prefill_paged_batch(params, empty, tokens, full(T), pages, lanes, cfg)
+
+    padded = lambda rows: np.pad(rows, ((0, 0), (0, 32 - rows.shape[1])))  # noqa: E731
+    cache, got = model.prefill_paged_batch(
+        params, empty, padded(tokens[:, :cut]), full(cut), pages.at[:, cut // P:].set(0), lanes, cfg)
+    close(got, want[:, cut - 1])
+    ids = jnp.zeros((B, 4), jnp.int32).at[:, 0].set(pages[:, cut // P])
+    cache, got = model.prefill_paged_continue(
+        params, cache, padded(tokens[:, cut:mid]), full(mid - cut), full(cut), ids, pages, lanes, cfg)
+    close(got, want[:, mid - 1])
+    for t in range(mid, T):
+        cache, got = model.decode_step_paged(params, cache, i32(tokens[:, t]), full(t), pages, jnp.ones((B,), bool), cfg)
+        close(got, want[:, t])
+    for leaf in state_leaves:
+        close(cache["state"][leaf][:, :B], whole["state"][leaf][:, :B])
+    for leaf in ("k", "v"):  # the 28 rows of each slot, the last page's four unwritten rows apart
+        rows = lambda tree: np.asarray(tree[leaf])[:, np.asarray(pages)].reshape(cfg.n_attention, B, 32, cfg.n_kv_heads * cfg.head_dim)[:, :, :T]  # noqa: E731
+        close(rows(cache), rows(whole))
+
+
+@pytest.mark.parametrize("name", list(LAYOUTS))
+def test_any_layer_pattern_serves_what_forward_computes(name):
+    """The pattern's layout for a decode step (the longest repeated stretch
+    a loop over its periods, a run of one kind an inner loop or, alone,
+    written out, the rest likewise); prefill and continuation (through the
+    one body that switches on the kind) and decode steps through that layout
+    give `forward`'s logits and one prefill's cache."""
+    kinds, dense, layout = LAYOUTS[name]
+    assert lfm2.segments(tuple(kinds[dense:])) == layout
+    cfg = dataclasses.replace(preset("lfm2-tiny"), layer_types=tuple(kinds), num_dense_layers=dense)
+    serves_what_forward_computes(lfm2, cfg, ("conv",))
+
+
 @pytest.mark.parametrize("control,least", [("int8", 3e-3), ("nobias", 3e-3), ("nonorm", 3e-2), ("capacity", 1e-2)])
 def test_each_reference_control_moves_the_logits(control, least):
     config = tiny(*PATTERNS["whole-pattern"])
