@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
+from ..observability import scopes
 from ..observability.metrics import REGISTRY
 from ..ops.paged import TRASH_PAGE, ring_size, set_pages
 from ..ops.sampling import NEG_INF, masks_wanted, sample
@@ -360,13 +361,14 @@ def advance_constraint(table, con_state, constrained, toks):
 def sample_lanes(logits, key, ln, table, min_close):
     """Constrained sampling for a [B] batch of first tokens, from a
     prefill dispatch's unpacked lanes and the key of its counter."""
-    logits = constrain_logits(
-        logits, table, ln["con_states"], ln["constrained"], min_close, ln["budgets"]
-    )
-    toks = sample(
-        logits, dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"], ln["top_ps"]
-    )
-    return toks, advance_constraint(table, ln["con_states"], ln["constrained"], toks)
+    with scopes.layer("sample"):
+        logits = constrain_logits(
+            logits, table, ln["con_states"], ln["constrained"], min_close, ln["budgets"]
+        )
+        toks = sample(
+            logits, dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"], ln["top_ps"]
+        )
+        return toks, advance_constraint(table, ln["con_states"], ln["constrained"], toks)
 
 
 def make_decode_block(step_fn, stop_toks: tuple, max_ctx: int, block_size: int):
@@ -384,30 +386,36 @@ def make_decode_block(step_fn, stop_toks: tuple, max_ctx: int, block_size: int):
         constrained = ln["constrained"]
         # the rows a block samples by do not change inside it, and a lane
         # only ever goes dead: asked once, of the lanes live at its start
-        wanted = masks_wanted(top_ks, top_ps, ln["active"])
+        with scopes.layer("sample"):
+            wanted = masks_wanted(top_ks, top_ps, ln["active"])
 
+        # everything a step does around the model is the sampler's (acp.sample)
         def step(carry, _):
             cache, tokens, seq_lens, con_states, budgets, active, rng = carry
-            rng, sub = jax.random.split(rng)
+            with scopes.layer("sample"):
+                rng, sub = jax.random.split(rng)
             cache, logits = step_fn(params, cache, tokens, seq_lens, active, *extra)
-            logits = constrain_logits(
-                logits, table, con_states, constrained, min_close, budgets
-            )
-            next_toks = sample(logits, sub, temps, top_ks, top_ps, wanted)
-            next_toks = jnp.where(active, next_toks, tokens)
-            con_states = advance_constraint(table, con_states, constrained, next_toks)
-            seq_lens = seq_lens + active.astype(jnp.int32)
-            budgets = budgets - active.astype(jnp.int32)
-            is_stop = jnp.zeros_like(active)
-            for st in stop_toks:
-                is_stop = is_stop | (next_toks == st)
-            active = active & ~is_stop & (budgets > 0) & (seq_lens + 1 < max_ctx)
+            with scopes.layer("sample"):
+                logits = constrain_logits(
+                    logits, table, con_states, constrained, min_close, budgets
+                )
+                next_toks = sample(logits, sub, temps, top_ks, top_ps, wanted)
+                next_toks = jnp.where(active, next_toks, tokens)
+                con_states = advance_constraint(table, con_states, constrained, next_toks)
+                seq_lens = seq_lens + active.astype(jnp.int32)
+                budgets = budgets - active.astype(jnp.int32)
+                is_stop = jnp.zeros_like(active)
+                for st in stop_toks:
+                    is_stop = is_stop | (next_toks == st)
+                active = active & ~is_stop & (budgets > 0) & (seq_lens + 1 < max_ctx)
             return (cache, next_toks, seq_lens, con_states, budgets, active, rng), next_toks
 
+        with scopes.layer("sample"):
+            first_key = dispatch_key(key, ln["n"], ln["chain"])
         (cache, tokens, seq_lens, con_states, budgets, active, _), toks = jax.lax.scan(
             step,
             (cache, ln["tokens"], ln["seq_lens"], ln["con_states"], ln["budgets"],
-             ln["active"], dispatch_key(key, ln["n"], ln["chain"])),
+             ln["active"], first_key),
             None, length=block_size,
         )
         # the carry is the lanes themselves, donated and handed back:
@@ -1271,18 +1279,19 @@ class Engine:
                 cache, logits = verify_fn(
                     params, cache, inputs, ln["n_input"], ln["starts"], *extra
                 )
-                out_toks, n_emit, new_states = speculative_accept(
-                    logits, inputs, ln["n_input"], ln["active"],
-                    dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"],
-                    ln["top_ps"], stop_toks, ln["budgets"], ln["force_reject"][0],
-                    constrain_fn=lambda l, s, b: constrain_logits(
-                        l, table, s, constrained, min_close, b
-                    ),
-                    advance_fn=lambda s, t, take: jnp.where(
-                        take, advance_constraint(table, s, constrained, t), s
-                    ),
-                    con_states=ln["con_states"],
-                )
+                with scopes.layer("sample"):
+                    out_toks, n_emit, new_states = speculative_accept(
+                        logits, inputs, ln["n_input"], ln["active"],
+                        dispatch_key(key, ln["n"]), ln["temps"], ln["top_ks"],
+                        ln["top_ps"], stop_toks, ln["budgets"], ln["force_reject"][0],
+                        constrain_fn=lambda l, s, b: constrain_logits(
+                            l, table, s, constrained, min_close, b
+                        ),
+                        advance_fn=lambda s, t, take: jnp.where(
+                            take, advance_constraint(table, s, constrained, t), s
+                        ),
+                        con_states=ln["con_states"],
+                    )
                 return cache, out_toks, n_emit, new_states
 
             return verify_block  # raw; jitted standalone AND fused below
@@ -2310,7 +2319,8 @@ class Engine:
         @functools.wraps(program)
         def counted(*args):
             cache, *rest = program(*args)
-            return ((cache, counters(cache)), *rest)
+            with scopes.layer("commit"):
+                return ((cache, counters(cache)), *rest)
 
         def wrap(jit):
             jitted = jit(counted)

@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import scopes
 from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
 from ..ops.norms import rms_norm
 from ..ops.paged import (
@@ -75,7 +76,7 @@ from ..ops.paged import (
     paged_decode_attention_reference_cache_plus_new,
 )
 from ..ops.pallas import ssm_scan as ssm
-from .lfm2 import _embed, _head_logits, _kv, _rows_ctx, scan_layers  # the same for every family with state beside the pages
+from .lfm2 import _embed, _final_norm, _head_logits, _kv, _rows_ctx, scan_layers  # the same for every family with state beside the pages
 
 N_COUNTERS = 4  # mamba_layers, rows, tokens, chunks
 STACK = {"mamba": "mamba", "attention": "attn"}  # a kind of layer -> its stack of weights in the tree
@@ -240,18 +241,25 @@ def _conv_at(u_ext, rel, n: int):
     return got.reshape(got.shape[0], -1)
 
 
-def _attention_op(h, layer, c: JambaConfig, attn_fn):
-    """-> (Op output, k, v): k and v are the layer's new rows for the pool."""
+def _attention_op(h, layer, c: JambaConfig, attn_fn, walk="prefill_attention"):
+    """-> (Op output, k, v): k and v are the layer's new rows for the pool.
+    ``walk`` is the scope ``attn_fn`` runs under (a decode step's: ``page_walk``)."""
     B, T, _ = h.shape
-    q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
-    k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-    v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-    return _mm(attn_fn(q, k, v).reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
+    with jax.named_scope("attn_qkv"):
+        q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
+        k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+        v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+    with jax.named_scope(walk):
+        out = attn_fn(q, k, v)
+    with jax.named_scope("attn_out"):
+        return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
 
 
 def _swiglu(x, ff, c: JambaConfig):
-    h = rms_norm(x, ff["ln2"], c.norm_eps)
-    return _mm(jax.nn.silu(_mm(h, ff["w1"])) * _mm(h, ff["w3"]), ff["w2"])
+    """``x`` with the dense feed-forward's residual added."""
+    with scopes.layer("ffn"), jax.named_scope("ffn_dense"):
+        h = rms_norm(x, ff["ln2"], c.norm_eps)
+        return x + _mm(jax.nn.silu(_mm(h, ff["w1"])) * _mm(h, ff["w3"]), ff["w2"])
 
 
 def _row(tree, i):
@@ -279,33 +287,40 @@ def _run_rows(params, c: JambaConfig, x, ctx, ssm_in, conv_in, make_attn):
     n_chunks = -(-ctx["lengths"] // ssm.CHUNK)
 
     def attention(x, a_row, m_row):
-        layer = _row(params["attn"], a_row)
-        op, k, v = _attention_op(rms_norm(x, layer["ln1"], c.norm_eps), layer, c, make_attn(a_row))
-        zh, zc = jnp.zeros(h_shape, f32), jnp.zeros(cv_shape, dt)
-        return op, zh, zh, zc, zc, k.astype(dt), v.astype(dt)
+        with scopes.layer("attn"):
+            layer = _row(params["attn"], a_row)
+            op, k, v = _attention_op(rms_norm(x, layer["ln1"], c.norm_eps), layer, c, make_attn(a_row))
+            zh, zc = jnp.zeros(h_shape, f32), jnp.zeros(cv_shape, dt)
+            return op, zh, zh, zc, zc, k.astype(dt), v.astype(dt)
 
     def mamba(x, a_row, m_row):
-        layer = _row(params["mamba"], m_row)
-        u_act, z, delta, b, c_, u_ext = _mamba_pre(
-            rms_norm(x, layer["ln1"], c.norm_eps), layer, c, conv_in[m_row].reshape(B, n, c.d_inner), ctx["valid"])
-        with jax.named_scope("ssm_scan"):
-            a = -jnp.exp(layer["A_log"].astype(f32))
-            y, h_end, h_snap = ssm.scan(delta, u_act, b, c_, a, ssm_in[m_row], ctx["snap_rel"], n_chunks)
-        op = _mamba_post(y, u_act, z, layer, dt)
-        zero = jnp.zeros(kv_shape, dt)
-        return op, h_end, h_snap, _conv_at(u_ext, ctx["lengths"], n), _conv_at(u_ext, ctx["snap_rel"], n), zero, zero
+        with scopes.layer("mixer"):
+            layer = _row(params["mamba"], m_row)
+            u_act, z, delta, b, c_, u_ext = _mamba_pre(
+                rms_norm(x, layer["ln1"], c.norm_eps), layer, c, conv_in[m_row].reshape(B, n, c.d_inner),
+                ctx["valid"])
+            with jax.named_scope("ssm_scan"):
+                a = -jnp.exp(layer["A_log"].astype(f32))
+                y, h_end, h_snap = ssm.scan(delta, u_act, b, c_, a, ssm_in[m_row], ctx["snap_rel"], n_chunks)
+            op = _mamba_post(y, u_act, z, layer, dt)
+            zero = jnp.zeros(kv_shape, dt)
+            with jax.named_scope("mamba_conv"):
+                ends = _conv_at(u_ext, ctx["lengths"], n), _conv_at(u_ext, ctx["snap_rel"], n)
+            return op, h_end, h_snap, *ends, zero, zero
 
     def body(x, scanned):
         ff, is_attn, a_row, m_row = scanned
         out = jax.lax.cond(is_attn, attention, mamba, x, a_row, m_row)
-        x = x + out[0]
-        return x + _swiglu(x, ff, c), out[1:]
+        with scopes.layer("ffn"):  # the kind is the chip's to know here: its residual is filed with the FF
+            x = x + out[0]
+        return _swiglu(x, ff, c), out[1:]
 
     x, (h_end, h_snap, c_end, c_snap, ks, vs) = jax.lax.scan(body, x, _scanned(params, c))
     attn_at, mamba_at = pl_["is_attn"].nonzero()[0], (~pl_["is_attn"]).nonzero()[0]
-    ends = {"ssm": h_end[mamba_at], "conv": c_end[mamba_at]}
-    snaps = {"ssm": h_snap[mamba_at], "conv": c_snap[mamba_at]}
-    return x, ends, snaps, ks[attn_at], vs[attn_at]
+    with scopes.layer("commit"):  # each kind's rows out of what the one body stacked for both
+        ends = {"ssm": h_end[mamba_at], "conv": c_end[mamba_at]}
+        snaps = {"ssm": h_snap[mamba_at], "conv": c_snap[mamba_at]}
+        return x, ends, snaps, ks[attn_at], vs[attn_at]
 
 
 def _zero_state(c: JambaConfig, B: int):
@@ -322,7 +337,7 @@ def forward(params: dict, tokens: jax.Array, config: JambaConfig) -> jax.Array:
            "lengths": jnp.full((B,), T, jnp.int32), "snap_rel": jnp.full((B,), -1, jnp.int32)}
     x, *_ = _run_rows(params, c, _embed(params, tokens, c), ctx, *_zero_state(c, B),
                       lambda a: lambda q, k, v: causal_attention(q, k, v, positions))
-    return _head_logits(rms_norm(x, params["norm"], c.norm_eps), params, c)
+    return _head_logits(_final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -354,27 +369,30 @@ def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts):
     row's end state always, its snapshot where one fell inside the row. A
     padding row names the last slot, which nothing reads."""
     st = cache["state"]
-    slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
-    out = {"snap": {}, "counters": st["counters"].at[1].add(counts)}
-    for name in ("ssm", "conv"):
-        out[name] = st[name].at[:, slots].set(ends[name].astype(st[name].dtype))
-        old = st["snap"][name][:, slots]
-        ok = snap_ok.reshape((1, -1) + (1,) * (old.ndim - 2))
-        out["snap"][name] = st["snap"][name].at[:, slots].set(jnp.where(ok, snaps[name].astype(old.dtype), old))
-    return {**pages, "state": out}
+    with scopes.layer("commit"):
+        slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
+        out = {"snap": {}, "counters": st["counters"].at[1].add(counts)}
+        for name in ("ssm", "conv"):
+            out[name] = st[name].at[:, slots].set(ends[name].astype(st[name].dtype))
+            old = st["snap"][name][:, slots]
+            ok = snap_ok.reshape((1, -1) + (1,) * (old.ndim - 2))
+            out["snap"][name] = st["snap"][name].at[:, slots].set(jnp.where(ok, snaps[name].astype(old.dtype), old))
+        return {**pages, "state": out}
 
 
 def _state_in(cache, slots, starts):
     """Zeros for a row that starts the sequence, the slot's state otherwise."""
     st = cache["state"]
-    slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
-    began = starts > 0
-    return (jnp.where(began[None, :, None, None], st["ssm"][:, slots], 0),
-            jnp.where(began[None, :, None], st["conv"][:, slots], 0))
+    with scopes.layer("commit"):
+        slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
+        began = starts > 0
+        return (jnp.where(began[None, :, None, None], st["ssm"][:, slots], 0),
+                jnp.where(began[None, :, None], st["conv"][:, slots], 0))
 
 
 def _prefill_counts(c, lengths):
-    return _counts(c, lengths > 0, lengths, -(-lengths // ssm.CHUNK))
+    with scopes.layer("commit"):
+        return _counts(c, lengths > 0, lengths, -(-lengths // ssm.CHUNK))
 
 
 def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config: JambaConfig):
@@ -391,8 +409,8 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions))
     pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, _prefill_counts(c, lengths))
-    x = rms_norm(x, params["norm"], c.norm_eps)
-    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, c)
+    x = _final_norm(x, params, c)
+    return cache, _head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -423,19 +441,18 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
     x, ends, snaps, new_k, new_v = _run_rows(
         params, c, _embed(params, tokens, c), ctx, *_state_in(cache, slots, starts), make_attn)
-    return rms_norm(x, params["norm"], c.norm_eps), new_k, new_v, ends, snaps, snap_ok
+    return _final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
                            config: JambaConfig):
     """Continuation (a prefix hit's suffix, a later chunk of a long
     prompt): -> (cache, last-token logits [B, V])."""
-    B = tokens.shape[0]
     x, new_k, new_v, ends, snaps, snap_ok = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
     pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
-    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, config)
+    return cache, _head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -483,34 +500,39 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     # of the whole stack (1.1 GB a layer: PERF.md, PR 37).
     def layer(kind, carry, index, at):
         x, h_all, conv_all = carry
-        weights = _row(params[STACK[kind]], at)
-        h = rms_norm(x, weights["ln1"], c.norm_eps)
-        if kind == "attention":
-            op, k, v = _attention_op(h, weights, c, make_attn(at))
-            out = (k[:, 0].astype(dt), v[:, 0].astype(dt))
-        else:
-            old = jax.lax.dynamic_slice(conv_all, (at, 0, 0), (1, S, n * di))[0]
-            u_act, z, delta, b, c_, u_ext = _mamba_pre(h, weights, c, old.reshape(S, n, di), active[:, None])
-            with jax.named_scope("mamba_conv"):
-                new = jnp.where(active[:, None], u_ext[:, 1:].reshape(S, n * di), old)
-            conv_all = jax.lax.dynamic_update_slice(conv_all, new[None], (at, 0, 0))
-            with jax.named_scope("ssm_update"):
-                a = -jnp.exp(weights["A_log"].astype(f32))
-                y, h_all = ssm.update(h_all, at, delta[:, 0], u_act[:, 0], b[:, 0], c_[:, 0], a)
-            op = _mamba_post(y[:, None], u_act, z, weights, dt)
-            out = ()
-        x = x + op
-        return (x + _swiglu(x, _row(params["ff"], index), c), h_all, conv_all), out
+        with scopes.layer("attn" if kind == "attention" else "mixer"):
+            weights = _row(params[STACK[kind]], at)
+            h = rms_norm(x, weights["ln1"], c.norm_eps)
+            if kind == "attention":
+                op, k, v = _attention_op(h, weights, c, make_attn(at), walk="page_walk")
+                out = (k[:, 0].astype(dt), v[:, 0].astype(dt))
+            else:
+                with jax.named_scope("mamba_conv"):
+                    old = jax.lax.dynamic_slice(conv_all, (at, 0, 0), (1, S, n * di))[0]
+                u_act, z, delta, b, c_, u_ext = _mamba_pre(h, weights, c, old.reshape(S, n, di), active[:, None])
+                with jax.named_scope("mamba_conv"):
+                    new = jnp.where(active[:, None], u_ext[:, 1:].reshape(S, n * di), old)
+                    conv_all = jax.lax.dynamic_update_slice(conv_all, new[None], (at, 0, 0))
+                with jax.named_scope("ssm_update"):
+                    a = -jnp.exp(weights["A_log"].astype(f32))
+                    y, h_all = ssm.update(h_all, at, delta[:, 0], u_act[:, 0], b[:, 0], c_[:, 0], a)
+                op = _mamba_post(y[:, None], u_act, z, weights, dt)
+                out = ()
+            x = x + op
+        with scopes.layer("ffn"):
+            ff = _row(params["ff"], index)
+        return (_swiglu(x, ff, c), h_all, conv_all), out
 
     st = cache["state"]
     plan(c)  # refuses a pattern of one kind or of a kind it does not know
     (x, h_all, conv_all), outs = scan_layers(
         c.layer_types, (_embed(params, tokens[:, None], c), st["ssm"], st["conv"]), layer)
-    target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-    pages = commit_tokens(pool, *outs["attention"], target, seq_lens % P)
-    counts = _counts(c, active, active, active)
-    state = {"ssm": h_all, "conv": conv_all, "snap": st["snap"], "counters": st["counters"].at[0].add(counts)}
-    x = rms_norm(x[:, 0], params["norm"], c.norm_eps)
+    with scopes.layer("commit"):
+        target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
+        pages = commit_tokens(pool, *outs["attention"], target, seq_lens % P)
+        counts = _counts(c, active, active, active)
+        state = {"ssm": h_all, "conv": conv_all, "snap": st["snap"], "counters": st["counters"].at[0].add(counts)}
+    x = _final_norm(x[:, 0], params, c)
     return {**pages, "state": state}, _head_logits(x, params, c)
 
 

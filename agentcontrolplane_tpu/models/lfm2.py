@@ -67,6 +67,7 @@ uses too.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 from dataclasses import dataclass
@@ -75,6 +76,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from ..observability import scopes
 from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
 from ..ops.moe import COUNTS_HEAD, routed_experts
 from ..ops.norms import rms_norm
@@ -318,13 +320,15 @@ def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
         # from the projection's accumulator; `s` itself in the model's dtype,
         # the one the state keeps it in, so that a decode step that reads two
         # values back convolves what the prefill convolved
-        bcu = jnp.matmul(h, layer["conv_in"].astype(h.dtype), preferred_element_type=jnp.float32)
+        with jax.named_scope("conv_in_proj"):
+            bcu = jnp.matmul(h, layer["conv_in"].astype(h.dtype), preferred_element_type=jnp.float32)
         b_, c_, u_ = bcu[..., :D], bcu[..., D:2 * D], bcu[..., 2 * D:]
         s = (b_ * u_).astype(h.dtype)
         s_ext = jnp.concatenate([state_in.astype(h.dtype), s], axis=1).astype(jnp.float32)  # [B, n + T, D]
         taps = layer["conv_w"].astype(jnp.float32)  # [D, taps]
         conv = sum(s_ext[:, j:j + T] * taps[:, j] for j in range(c.conv_taps))
-        out = _mm((c_ * conv).astype(h.dtype), layer["conv_out"])
+        with jax.named_scope("conv_out_proj"):
+            out = _mm((c_ * conv).astype(h.dtype), layer["conv_out"])
 
         def state_at(rel):  # the n values of s before token `rel` of the row
             idx = jnp.clip(rel, 0, T)[:, None] + jnp.arange(n)[None, :]
@@ -333,20 +337,24 @@ def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
         return out, state_at(lengths), state_at(snap_rel)
 
 
-def _attention_op(h, layer, c, positions, attn_fn, yarn=None):
+def _attention_op(h, layer, c, positions, attn_fn, yarn=None, walk="prefill_attention"):
     """-> (Op output, k, v): k and v are the layer's new rows for the pool.
     ``yarn`` (``ops.rope.apply_rope``'s) turns q and k by YaRN's frequencies
-    (``models/mellum.py``'s full layers)."""
+    (``models/mellum.py``'s full layers). ``walk`` is the scope ``attn_fn``
+    runs under (a decode step's: ``page_walk``; None: it opens its own)."""
     B, T, _ = h.shape
-    q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
-    k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-    v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-    q = rms_norm(q, layer["q_norm"], c.norm_eps)
-    k = rms_norm(k, layer["k_norm"], c.norm_eps)
-    q = apply_rope(q, positions, c.rope_theta, yarn=yarn)
-    k = apply_rope(k, positions, c.rope_theta, yarn=yarn)
-    out = attn_fn(q, k, v)
-    return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
+    with jax.named_scope("attn_qkv"):
+        q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
+        k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+        v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+        q = rms_norm(q, layer["q_norm"], c.norm_eps)
+        k = rms_norm(k, layer["k_norm"], c.norm_eps)
+        q = apply_rope(q, positions, c.rope_theta, yarn=yarn)
+        k = apply_rope(k, positions, c.rope_theta, yarn=yarn)
+    with jax.named_scope(walk) if walk else contextlib.nullcontext():
+        out = attn_fn(q, k, v)
+    with jax.named_scope("attn_out"):
+        return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
 
 
 def _experts(x, ff, stacks, layer_index, c: Lfm2Config, valid, chosen=None):
@@ -399,13 +407,15 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
     def operator(kind, x, layer, at):
         """``Op(RMSNorm(x))`` with ``layer`` the weights of the ``at``-th
         layer of ``kind`` after those ``done`` -> (Op, (end, snap) or (k, v))."""
-        h = norm(x, layer["ln1"])
-        at = done[kind] + at
-        if kind == "conv":
-            op, *out = _conv_op(h, layer, c, conv_state[at], ctx["lengths"], ctx["snap_rel"])
-        else:
-            op, *out = _attention_op(h, layer, c, ctx["positions"], make_attn(at))
-        return op, tuple(o.astype(dt) for o in out)
+        with scopes.layer("mixer" if kind == "conv" else "attn"):
+            h = norm(x, layer["ln1"])
+            at = done[kind] + at
+            if kind == "conv":
+                op, *out = _conv_op(h, layer, c, conv_state[at], ctx["lengths"], ctx["snap_rel"])
+            else:
+                op, *out = _attention_op(h, layer, c, ctx["positions"], make_attn(at),
+                                         walk="page_walk" if by_kind else "prefill_attention")
+            return op, tuple(o.astype(dt) for o in out)
 
     done = {"conv": 0, "attention": 0}  # layers of each kind so far
     kept = {"conv": (ends, snaps), "attention": (ks, vs)}
@@ -414,9 +424,10 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
         for part, o in zip(kept[kind], out):
             part.append(o[None])
         done[kind] += 1
-        x = x + op
-        h = norm(x, layer["ln2"])
-        x = x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
+        with scopes.layer("ffn"), jax.named_scope("ffn_dense"):
+            x = x + op
+            h = norm(x, layer["ln2"])
+            x = x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
 
     counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
     if pl_["body"]:
@@ -429,14 +440,19 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
         def expert_layer(carry, op, mine, index, chosen):
             """``mine``: the layer's norm and router."""
             x, counts = carry
-            x = x + op
-            y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, ctx["valid"], chosen)
-            return x + y, counts + m
+            with scopes.layer("ffn"):
+                x = x + op
+                y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, ctx["valid"], chosen)
+                return x + y, counts + m
 
         if by_kind:
             def layer(kind, carry, index, at):
-                op, out = operator(kind, carry[0], row(stack[kind], at), at)
-                return expert_layer(carry, op, row(small, index), index, None if route is None else route[index]), out
+                with scopes.layer("mixer" if kind == "conv" else "attn"):
+                    weights = row(stack[kind], at)
+                op, out = operator(kind, carry[0], weights, at)
+                with scopes.layer("ffn"):
+                    mine, chosen = row(small, index), None if route is None else route[index]
+                return expert_layer(carry, op, mine, index, chosen), out
 
             (x, counts), outs = scan_layers(pl_["body"], (x, counts), layer)
             for kind, out in outs.items():
@@ -445,7 +461,9 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
         else:
             def branch(kind):
                 def run(x, at):  # -> (Op, end, snap, k, v), zeros for the other kind's
-                    op, out = operator(kind, x, row(stack[kind], at[kind]), at[kind])
+                    with scopes.layer("mixer" if kind == "conv" else "attn"):
+                        weights = row(stack[kind], at[kind])
+                    op, out = operator(kind, x, weights, at[kind])
                     zero = jnp.zeros(kv_shape if kind == "conv" else (B, n, D), dt)
                     return (op, *out, zero, zero) if kind == "conv" else (op, zero, zero, *out)
 
@@ -465,22 +483,35 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
                 (small, jnp.arange(n_body, dtype=jnp.int32), jnp.asarray(pl_["is_attn"]),
                  {"attention": jnp.asarray(pl_["attn_row"]), "conv": jnp.asarray(pl_["conv_row"])}, route))
             attn_at, conv_at = pl_["is_attn"].nonzero()[0], (~pl_["is_attn"]).nonzero()[0]
-            for part, o in zip((ends, snaps, ks, vs), (e[conv_at], s_[conv_at], kk[attn_at], vv[attn_at])):
-                part.append(o)
+            with scopes.layer("commit"):  # each kind's rows out of what the one body stacked for both
+                for part, o in zip((ends, snaps, ks, vs), (e[conv_at], s_[conv_at], kk[attn_at], vv[attn_at])):
+                    part.append(o)
 
     cat = lambda parts, shape, dtype: (  # noqa: E731
         jnp.concatenate(parts, axis=0) if parts else jnp.zeros((0,) + shape, dtype))
-    return (x, cat(ends, (B, n, D), dt), cat(snaps, (B, n, D), dt), cat(ks, kv_shape, dt),
-            cat(vs, kv_shape, dt), counts)
+    with scopes.layer("commit"):
+        return (x, cat(ends, (B, n, D), dt), cat(snaps, (B, n, D), dt), cat(ks, kv_shape, dt),
+                cat(vs, kv_shape, dt), counts)
 
 
-def _head_logits(x, params, c: Lfm2Config):
-    head = params["embed"].T if c.tie_embeddings else params["lm_head"]
-    return (x.astype(c.dtype) @ head.astype(c.dtype)).astype(jnp.float32)
+def _head_logits(x, params, c: Lfm2Config, last=None):
+    """The output head; ``last`` [B] (true lengths) picks each row's last real
+    token of ``x`` [B, T, D] first."""
+    with scopes.layer("head"):
+        if last is not None:
+            x = x[jnp.arange(x.shape[0]), last - 1]
+        head = params["embed"].T if c.tie_embeddings else params["lm_head"]
+        return (x.astype(c.dtype) @ head.astype(c.dtype)).astype(jnp.float32)
+
+
+def _final_norm(x, params, c):
+    with scopes.layer("head"):
+        return rms_norm(x, params["norm"], c.norm_eps)
 
 
 def _embed(params, tokens, c: Lfm2Config):
-    return params["embed"][tokens].astype(c.dtype)
+    with scopes.layer("embed"):
+        return params["embed"][tokens].astype(c.dtype)
 
 
 def _zero_state(c: Lfm2Config, B: int) -> jax.Array:
@@ -497,7 +528,7 @@ def forward(params: dict, tokens: jax.Array, config: Lfm2Config) -> jax.Array:
            "lengths": jnp.full((B,), T, jnp.int32), "snap_rel": jnp.zeros((B,), jnp.int32)}
     x, *_ = _run_layers(params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
                         lambda a: lambda q, k, v: causal_attention(q, k, v, positions))
-    return _head_logits(rms_norm(x, params["norm"], c.norm_eps), params, c)
+    return _head_logits(_final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -527,11 +558,12 @@ def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, row):
     """The cache with its pages replaced and the rows' state written: a
     row's end state always, its snapshot where one fell inside the row."""
     st = cache["state"]
-    conv = st["conv"].at[:, slots].set(ends.astype(st["conv"].dtype), mode="drop")
-    old = st["snap"][:, jnp.clip(slots, 0, st["snap"].shape[1] - 1)]
-    snap = st["snap"].at[:, slots].set(
-        jnp.where(snap_ok[None, :, None, None], snaps.astype(old.dtype), old), mode="drop")
-    return {**pages, "state": {"conv": conv, "snap": snap, "moe": st["moe"].at[row].add(counts)}}
+    with scopes.layer("commit"):
+        conv = st["conv"].at[:, slots].set(ends.astype(st["conv"].dtype), mode="drop")
+        old = st["snap"][:, jnp.clip(slots, 0, st["snap"].shape[1] - 1)]
+        snap = st["snap"].at[:, slots].set(
+            jnp.where(snap_ok[None, :, None, None], snaps.astype(old.dtype), old), mode="drop")
+        return {**pages, "state": {"conv": conv, "snap": snap, "moe": st["moe"].at[row].add(counts)}}
 
 
 def _rows_ctx(lengths, starts, snap_at, T):
@@ -548,8 +580,9 @@ def _state_in(cache, slots, starts):
     the slot's state otherwise. A padding lane's slot is out of range: it
     reads any slot's and writes nowhere (``mode="drop"``)."""
     conv = cache["state"]["conv"]
-    got = conv[:, jnp.clip(slots, 0, conv.shape[1] - 1)]
-    return jnp.where((starts > 0)[None, :, None, None], got, 0)
+    with scopes.layer("commit"):
+        got = conv[:, jnp.clip(slots, 0, conv.shape[1] - 1)]
+        return jnp.where((starts > 0)[None, :, None, None], got, 0)
 
 
 def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config: Lfm2Config, route=None):
@@ -566,8 +599,8 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions), route)
     pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, 1)
-    x = rms_norm(x, params["norm"], c.norm_eps)
-    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, c)
+    x = _final_norm(x, params, c)
+    return cache, _head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -598,19 +631,18 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
     x, ends, snaps, new_k, new_v, counts = _run_layers(
         params, c, _embed(params, tokens, c), ctx, _state_in(cache, slots, starts), make_attn)
-    return rms_norm(x, params["norm"], c.norm_eps), new_k, new_v, ends, snaps, snap_ok, counts
+    return _final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok, counts
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
                            config: Lfm2Config):
     """Continuation (a prefix hit's suffix, a later chunk of a long
     prompt): -> (cache, last-token logits [B, V])."""
-    B = tokens.shape[0]
     x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
     pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
     cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
-    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, config)
+    return cache, _head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -656,12 +688,13 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     st = cache["state"]
     x, ends, _snaps, new_k, new_v, counts = _run_layers(
         params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route, by_kind=True)
-    target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-    pages = commit_tokens(pool, new_k[:, :, 0], new_v[:, :, 0], target, seq_lens % P)
-    conv = st["conv"].at[:, :S].set(
-        jnp.where(active[None, :, None, None], ends.astype(st["conv"].dtype), st["conv"][:, :S]))
-    cache = {**pages, "state": {"conv": conv, "snap": st["snap"], "moe": st["moe"].at[0].add(counts)}}
-    x = rms_norm(x[:, 0], params["norm"], c.norm_eps)
+    with scopes.layer("commit"):
+        target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
+        pages = commit_tokens(pool, new_k[:, :, 0], new_v[:, :, 0], target, seq_lens % P)
+        conv = st["conv"].at[:, :S].set(
+            jnp.where(active[None, :, None, None], ends.astype(st["conv"].dtype), st["conv"][:, :S]))
+        cache = {**pages, "state": {"conv": conv, "snap": st["snap"], "moe": st["moe"].at[0].add(counts)}}
+    x = _final_norm(x[:, 0], params, c)
     return cache, _head_logits(x, params, c)
 
 

@@ -32,6 +32,7 @@ from ..ops.attention import (
     continue_attention,
     decode_attention_cache_plus_new,
 )
+from ..observability import scopes
 from ..ops.norms import rms_norm
 from ..ops.paged import (
     TRASH_PAGE,
@@ -363,25 +364,35 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
 
 
 def _embed(params: dict, tokens: jax.Array, c: LlamaConfig) -> jax.Array:
-    x = params["embed"][tokens].astype(c.dtype)
-    if c.embed_scale:  # gemma normalizes embeddings by sqrt(dim)
-        x = x * jnp.asarray(c.dim**0.5, dtype=c.dtype)
-    return x
+    with scopes.layer("embed"):
+        x = params["embed"][tokens].astype(c.dtype)
+        if c.embed_scale:  # gemma normalizes embeddings by sqrt(dim)
+            x = x * jnp.asarray(c.dim**0.5, dtype=c.dtype)
+        return x
 
 
 def _final_norm_w(params: dict, c: LlamaConfig) -> jax.Array:
     return params["norm"] + 1.0 if c.norm_plus_one else params["norm"]
 
 
-def _head_logits(x: jax.Array, params: dict, c: LlamaConfig) -> jax.Array:
+def _final_norm(x: jax.Array, params: dict, c: LlamaConfig) -> jax.Array:
+    with scopes.layer("head"):
+        return rms_norm(x, _final_norm_w(params, c), c.norm_eps)
+
+
+def _head_logits(x: jax.Array, params: dict, c: LlamaConfig, last: Optional[jax.Array] = None) -> jax.Array:
     """lm_head projection -> float32 logits; applies gemma-2's final logit
-    soft-capping when configured (cap * tanh(logits / cap))."""
-    head = params["embed"].T if c.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
-    if c.final_logit_softcap:
-        cap = jnp.float32(c.final_logit_softcap)
-        logits = cap * jnp.tanh(logits / cap)
-    return logits
+    soft-capping when configured (cap * tanh(logits / cap)). ``last`` [B]
+    (true lengths) picks each row's last real token of ``x`` [B, T, D] first."""
+    with scopes.layer("head"):
+        if last is not None:
+            x = x[jnp.arange(x.shape[0]), last - 1]
+        head = params["embed"].T if c.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(c.dtype)).astype(jnp.float32)
+        if c.final_logit_softcap:
+            cap = jnp.float32(c.final_logit_softcap)
+            logits = cap * jnp.tanh(logits / cap)
+        return logits
 
 
 def _attn_mlp(
@@ -390,9 +401,11 @@ def _attn_mlp(
     config: LlamaConfig,
     positions: jax.Array,  # [B, T]
     attn_fn,
+    walk: str = "prefill_attention",  # the attention operator's scope: a decode step's is "page_walk"
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Shared block body: returns (output, k, v) where k/v are this layer's
-    new key/value tensors (for cache writes)."""
+    new key/value tensors (for cache writes). The device scopes of every
+    llama program are opened here (observability/scopes.py)."""
     from ..ops.quant import matmul as mm  # transparent int8 dequant
 
     c = config
@@ -404,53 +417,60 @@ def _attn_mlp(
         act = partial(jax.nn.gelu, approximate=True)
     else:  # fail at trace time, not silently compute the wrong function
         raise ValueError(f"unsupported hidden_act {c.hidden_act!r} (silu|gelu_tanh)")
-    h = rms_norm(x, norm_w(layer["ln1"]), c.norm_eps)
-    q = mm(h, layer["wq"])
-    k = mm(h, layer["wk"])
-    v = mm(h, layer["wv"])
-    if c.qkv_bias:
-        q = q + layer["bq"]
-        k = k + layer["bk"]
-        v = v + layer["bv"]
-    q = q.reshape(B, T, c.n_heads, c.head_dim)
-    k = k.reshape(B, T, c.n_kv_heads, c.head_dim)
-    v = v.reshape(B, T, c.n_kv_heads, c.head_dim)
-    scaling = (
-        (c.rope_scaling_factor, c.rope_low_freq_factor,
-         c.rope_high_freq_factor, c.rope_original_max_seq)
-        if c.rope_scaling_factor != 1.0
-        else None
-    )
-    q = apply_rope(q, positions, c.rope_theta, scaling=scaling)
-    k = apply_rope(k, positions, c.rope_theta, scaling=scaling)
-    if c.query_pre_attn_scalar:
-        # gemma-2 scales attention by 1/sqrt(query_pre_attn_scalar) instead
-        # of 1/sqrt(head_dim); pre-scaling q here keeps every attention
-        # implementation's internal 1/sqrt(head_dim) untouched
-        q = q * jnp.asarray(
-            (c.head_dim ** 0.5) / (c.query_pre_attn_scalar ** 0.5), dtype=q.dtype
-        )
-    attn = attn_fn(q, k, v)
-    attn_out = mm(attn.reshape(B, T, c.n_heads * c.head_dim), layer["wo"])
-    if c.post_norms:  # gemma-2: norm the sublayer OUTPUT before residual
-        attn_out = rms_norm(attn_out, norm_w(layer["ln1_post"]), c.norm_eps)
-    x = x + attn_out
-    h = rms_norm(x, norm_w(layer["ln2"]), c.norm_eps)
-    if c.n_experts > 0:
-        from ..ops.moe import routed_experts
+    with scopes.layer("attn"):
+        with jax.named_scope("attn_qkv"):
+            h = rms_norm(x, norm_w(layer["ln1"]), c.norm_eps)
+            q = mm(h, layer["wq"])
+            k = mm(h, layer["wk"])
+            v = mm(h, layer["wv"])
+            if c.qkv_bias:
+                q = q + layer["bq"]
+                k = k + layer["bk"]
+                v = v + layer["bv"]
+            q = q.reshape(B, T, c.n_heads, c.head_dim)
+            k = k.reshape(B, T, c.n_kv_heads, c.head_dim)
+            v = v.reshape(B, T, c.n_kv_heads, c.head_dim)
+            scaling = (
+                (c.rope_scaling_factor, c.rope_low_freq_factor,
+                 c.rope_high_freq_factor, c.rope_original_max_seq)
+                if c.rope_scaling_factor != 1.0
+                else None
+            )
+            q = apply_rope(q, positions, c.rope_theta, scaling=scaling)
+            k = apply_rope(k, positions, c.rope_theta, scaling=scaling)
+            if c.query_pre_attn_scalar:
+                # gemma-2 scales attention by 1/sqrt(query_pre_attn_scalar) instead
+                # of 1/sqrt(head_dim); pre-scaling q here keeps every attention
+                # implementation's internal 1/sqrt(head_dim) untouched
+                q = q * jnp.asarray(
+                    (c.head_dim ** 0.5) / (c.query_pre_attn_scalar ** 0.5), dtype=q.dtype
+                )
+        with jax.named_scope(walk):
+            attn = attn_fn(q, k, v)
+        with jax.named_scope("attn_out"):
+            attn_out = mm(attn.reshape(B, T, c.n_heads * c.head_dim), layer["wo"])
+            if c.post_norms:  # gemma-2: norm the sublayer OUTPUT before residual
+                attn_out = rms_norm(attn_out, norm_w(layer["ln1_post"]), c.norm_eps)
+            x = x + attn_out
+    with scopes.layer("ffn"):
+        if c.n_experts > 0:
+            from ..ops.moe import routed_experts
 
-        # kernel=False: jax.lax.ragged_dot, which GSPMD partitions over the
-        # mesh's 'ep' and 'tp' axes (an opaque kernel it would replicate)
-        y, _counts = routed_experts(
-            h.reshape(B * T, D), layer["router"], layer["w1"], layer["w3"], layer["w2"],
-            c.experts_per_token, score="softmax", act=act, kernel=False,
-        )
-        x = x + y.reshape(B, T, D)
-    else:
-        y = mm(act(mm(h, layer["w1"])) * mm(h, layer["w3"]), layer["w2"])
-        if c.post_norms:
-            y = rms_norm(y, norm_w(layer["ln2_post"]), c.norm_eps)
-        x = x + y
+            h = rms_norm(x, norm_w(layer["ln2"]), c.norm_eps)
+            # kernel=False: jax.lax.ragged_dot, which GSPMD partitions over the
+            # mesh's 'ep' and 'tp' axes (an opaque kernel it would replicate)
+            y, _counts = routed_experts(
+                h.reshape(B * T, D), layer["router"], layer["w1"], layer["w3"], layer["w2"],
+                c.experts_per_token, score="softmax", act=act, kernel=False,
+            )
+            x = x + y.reshape(B, T, D)
+        else:
+            with jax.named_scope("ffn_dense"):
+                h = rms_norm(x, norm_w(layer["ln2"]), c.norm_eps)
+                y = mm(act(mm(h, layer["w1"])) * mm(h, layer["w3"]), layer["w2"])
+                if c.post_norms:
+                    y = rms_norm(y, norm_w(layer["ln2_post"]), c.norm_eps)
+                x = x + y
     return x, k, v
 
 
@@ -508,7 +528,7 @@ def forward(
     x = _embed(params, tokens, c)
 
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
+    x = _final_norm(x, params, c)
     return _head_logits(x, params, c)
 
 
@@ -551,19 +571,20 @@ def _kv_commit(cache: dict, new_k: jax.Array, new_v: jax.Array, setter) -> dict:
     scatter applied to the value arrays ([..., H_kv, d]) and, for a
     quantized cache, to the scale arrays ([..., H_kv]); quantization
     happens here, once per dispatch, on the already-stacked commit."""
-    if "ks" in cache:
-        qk, sk = kv_quantize(new_k)
-        qv, sv = kv_quantize(new_v)
+    with scopes.layer("commit"):
+        if "ks" in cache:
+            qk, sk = kv_quantize(new_k)
+            qv, sv = kv_quantize(new_v)
+            return {
+                "k": setter(cache["k"], qk),
+                "v": setter(cache["v"], qv),
+                "ks": setter(cache["ks"], sk),
+                "vs": setter(cache["vs"], sv),
+            }
         return {
-            "k": setter(cache["k"], qk),
-            "v": setter(cache["v"], qv),
-            "ks": setter(cache["ks"], sk),
-            "vs": setter(cache["vs"], sv),
+            "k": setter(cache["k"], new_k.astype(cache["k"].dtype)),
+            "v": setter(cache["v"], new_v.astype(cache["v"].dtype)),
         }
-    return {
-        "k": setter(cache["k"], new_k.astype(cache["k"].dtype)),
-        "v": setter(cache["v"], new_v.astype(cache["v"].dtype)),
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -605,7 +626,7 @@ def prefill_batch(
     splits admission groups into power-of-two B). Returns
     (cache, logits_at_last_token [B, V])."""
     c = config
-    B, T = tokens.shape
+    T = tokens.shape[1]
     ar = jnp.arange(T)
     positions = jnp.where(ar[None, :] < lengths[:, None], ar[None, :], -1)  # [B,T]
     x = _embed(params, tokens, c)  # [B, T, D]
@@ -632,9 +653,8 @@ def prefill_batch(
         cache, new_k, new_v, lambda arr, val: arr.at[:, slots, :T].set(val)
     )
     # (padded tail is garbage but never read: decode masks by seq_len)
-    x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
-    last = x[jnp.arange(B), lengths - 1]  # [B, D]
-    logits = _head_logits(last, params, c)
+    x = _final_norm(x, params, c)
+    logits = _head_logits(x, params, c, last=lengths)
     return cache, logits
 
 
@@ -718,7 +738,7 @@ def _continue_forward(
         cache, new_k, new_v,
         lambda arr, val: arr.at[:, slots[:, None], write_pos].set(val),
     )
-    x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
+    x = _final_norm(x, params, c)
     return cache, x
 
 
@@ -737,10 +757,8 @@ def prefill_continue(
     Costs O(suffix) model FLOPs instead of O(full prompt) — the win that
     makes multi-turn agent conversations cheap (each turn's prompt extends
     the previous one). Returns (cache, last-token logits [B, V])."""
-    B = tokens.shape[0]
     cache, x = _continue_forward(params, cache, tokens, lengths, starts, slots, config)
-    last = x[jnp.arange(B), lengths - 1]
-    logits = _head_logits(last, params, config)
+    logits = _head_logits(x, params, config, last=lengths)
     return cache, logits
 
 
@@ -819,7 +837,7 @@ def prefill_paged_batch(
     pages. Rows' trash-page writes may collide — unordered garbage into the
     never-read page 0."""
     c = config
-    B, T = tokens.shape
+    T = tokens.shape[1]
     ar = jnp.arange(T)
     positions = jnp.where(ar[None, :] < lengths[:, None], ar[None, :], -1)
     x = _embed(params, tokens, c)
@@ -839,9 +857,8 @@ def prefill_paged_batch(
     # flattened over its layers (ops/paged.py) — see prefill_batch/decode_step
     x, (new_k, new_v) = jax.lax.scan(body, x, params["layers"])
     pages = commit_whole_pages(pages, new_k, new_v, page_ids)
-    x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
-    last = x[jnp.arange(B), lengths - 1]
-    logits = _head_logits(last, params, c)
+    x = _final_norm(x, params, c)
+    logits = _head_logits(x, params, c, last=lengths)
     return pages, logits
 
 
@@ -907,7 +924,8 @@ def _paged_continue_forward(
         # is a copy of the layer's pool): the layer's pages are gathered from
         # the whole pool by tables offset by the layer, and only the
         # gathered rows have their heads split (and are dequantized)
-        tables = layer_tables(block_tables, index, NP)
+        with scopes.layer("attn"), jax.named_scope("prefill_attention"):
+            tables = layer_tables(block_tables, index, NP)
 
         def attn(q, k, v):
             k_gath = gather_pages(pages, "k", tables, k.dtype, c.n_kv_heads)  # [B, M, P, H, d]
@@ -934,7 +952,7 @@ def _paged_continue_forward(
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["layers"], jnp.arange(c.n_layers, dtype=jnp.int32))
     )
-    x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
+    x = _final_norm(x, params, c)
     return new_k, new_v, x
 
 
@@ -953,14 +971,12 @@ def prefill_paged_continue(
     never written here; starts are page-aligned so suffix writes only touch
     fresh pages). Runs the suffix through the model, attending over the
     gathered prefix+suffix pages. Returns (pages, last-token logits [B, V])."""
-    B = tokens.shape[0]
     new_k, new_v, x = _paged_continue_forward(
         params, pages, tokens, lengths, starts, block_tables, config
     )
     # one scatter commits the suffix blocks for every layer
     pages = commit_whole_pages(pages, new_k, new_v, page_ids)
-    last = x[jnp.arange(B), lengths - 1]
-    logits = _head_logits(last, params, config)
+    logits = _head_logits(x, params, config, last=lengths)
     return pages, logits
 
 
@@ -1006,8 +1022,9 @@ def verify_paged_continue(
     new_k, new_v, x = _paged_continue_forward(
         params, pages, tokens, lengths, starts, block_tables, config
     )
-    target, offset = token_write_targets(block_tables, starts, lengths, P, T)
-    pages = commit_tokens(pages, new_k, new_v, target, offset)
+    with scopes.layer("commit"):
+        target, offset = token_write_targets(block_tables, starts, lengths, P, T)
+        pages = commit_tokens(pages, new_k, new_v, target, offset)
     return pages, _head_logits(x, params, config)
 
 
@@ -1057,7 +1074,8 @@ def decode_step_paged(
     def body(carry, scanned):
         x = carry
         layer, index = scanned
-        tables = layer_tables(block_tables, index, NP)
+        with scopes.layer("attn"), jax.named_scope("page_walk"):
+            tables = layer_tables(block_tables, index, NP)
 
         def attn(q, k, v):
             args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
@@ -1076,7 +1094,7 @@ def decode_step_paged(
             attn.new_kv = (k[:, 0], v[:, 0])
             return out[:, None]
 
-        out, _, _ = _attn_mlp(x, layer, c, positions, attn)
+        out, _, _ = _attn_mlp(x, layer, c, positions, attn, walk="page_walk")
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -1084,10 +1102,11 @@ def decode_step_paged(
     )
     # one scatter of token rows commits all layers: (l, page(slot),
     # offset(slot)); inactive slots land on the trash page
-    target = block_tables[jnp.arange(S), seq_lens // P]
-    target = jnp.where(active, target, TRASH_PAGE)
-    pages = commit_tokens(pages, new_k, new_v, target, seq_lens % P)
-    x = rms_norm(x[:, 0], _final_norm_w(params, c), c.norm_eps)
+    with scopes.layer("commit"):
+        target = block_tables[jnp.arange(S), seq_lens // P]
+        target = jnp.where(active, target, TRASH_PAGE)
+        pages = commit_tokens(pages, new_k, new_v, target, seq_lens % P)
+    x = _final_norm(x[:, 0], params, c)
     logits = _head_logits(x, params, c)
     return pages, logits
 
@@ -1147,7 +1166,7 @@ def decode_step(
             attn.new_kv = (k[:, 0], v[:, 0])
             return out[:, None]
 
-        out, _, _ = _attn_mlp(x, layer, c, positions, attn)
+        out, _, _ = _attn_mlp(x, layer, c, positions, attn, walk="decode_attention")
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -1164,6 +1183,6 @@ def decode_step(
         cache, new_k, new_v,
         lambda arr, val: arr.at[:, slot_idx, write_rows].set(val),
     )
-    x = rms_norm(x[:, 0], _final_norm_w(params, c), c.norm_eps)  # [S, D]
+    x = _final_norm(x[:, 0], params, c)  # [S, D]
     logits = _head_logits(x, params, c)
     return cache, logits

@@ -62,6 +62,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from ..observability import scopes
 from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
 from ..ops.moe import COUNTS_HEAD, routed_experts
 from ..ops.norms import rms_norm
@@ -69,7 +70,7 @@ from ..ops.paged import (
     TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages,
     layer_tables, paged_decode_attention_reference_cache_plus_new, ring_newest, ring_positions, ring_size, ring_tables,
 )
-from .lfm2 import _attention_op, _embed, _head_logits  # GQA with q/k norms, embedding and head: as lfm2's
+from .lfm2 import _attention_op, _embed, _final_norm, _head_logits  # GQA with q/k norms, embedding and head: as lfm2's
 from .lfm2 import describe_counters as _describe_moe
 
 WINDOW_COUNTS = 4  # dispatches, rows read, rows with no window, lanes past the window
@@ -225,14 +226,17 @@ def _experts(x, ff, stacks, layer_index, c: MellumConfig, valid, chosen=None):
     return y.reshape(B, T, D), jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
 
 
-def _run_layers(params, c: MellumConfig, x, positions, valid, make_attn, route=None, keep=lambda t: t):
+def _run_layers(params, c: MellumConfig, x, positions, valid, make_attn, route=None, keep=lambda t: t,
+                walk="prefill_attention"):
     """The whole stack. ``make_attn(full, i)`` gives the attention function
     of window layer ``i`` or full layer ``i`` (a traced index among its own
     kind); ``route`` [n_layers, B, T, k] int32, where given, is every
     layer's choice of experts, taken as it is (an output check's
     teacher-forced routing; serving never gives one); ``keep`` is applied
     to a window layer's fresh K and V before the scan stacks them (a
-    prefill keeps a ring's worth of its rows: ``ring_newest``). -> (x, new
+    prefill keeps a ring's worth of its rows: ``ring_newest``); ``walk`` is
+    the scope the attention functions run under (None: a decode step's open
+    their own, ``page_walk`` and ``window_walk``). -> (x, new
     window k [n_window, B, kept rows, H_kv, d], new window v, new full k
     [n_full, B, T, H_kv, d], new full v, expert counters)."""
     span, periods = period(c)
@@ -244,13 +248,16 @@ def _run_layers(params, c: MellumConfig, x, positions, valid, make_attn, route=N
     small = {name: ff[name] for name in ("ln2", "router")}
     row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
 
-    def layer(x, counts, weights, full: bool, i, index, chosen):
-        op, k, v = _attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attn(full, i),
-                                 yarn=c.yarn if full else None)
-        x = x + op
-        mine = row(small, index)
-        y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, valid, chosen)
-        return x + y, counts + m, k.astype(dt), v.astype(dt)
+    def layer(x, counts, stack, full: bool, i, index, chosen):
+        with scopes.layer("attn"):
+            weights = row(stack, i)
+            op, k, v = _attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attn(full, i),
+                                     yarn=c.yarn if full else None, walk=walk)
+            x = x + op
+        with scopes.layer("ffn"):
+            mine = row(small, index)
+            y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, valid, chosen)
+            return x + y, counts + m, k.astype(dt), v.astype(dt)
 
     def one_period(carry, scanned):
         p, chosen = scanned  # chosen: [span, B, T, k] or None
@@ -258,13 +265,14 @@ def _run_layers(params, c: MellumConfig, x, positions, valid, make_attn, route=N
         def window_layer(carry, scanned):
             j, given = scanned
             i = p * (span - 1) + j
-            x, counts, k, v = layer(*carry, row(params["win"], i), False, i, p * span + j, given)
-            return (x, counts), (keep(k), keep(v))
+            x, counts, k, v = layer(*carry, params["win"], False, i, p * span + j, given)
+            with scopes.layer("commit"), jax.named_scope("window_commit"):
+                return (x, counts), (keep(k), keep(v))
 
         carry, (wk, wv) = jax.lax.scan(
             window_layer, carry,
             (jnp.arange(span - 1, dtype=jnp.int32), None if chosen is None else chosen[:span - 1]))
-        x, counts, fk, fv = layer(*carry, row(params["full"], p), True, p, p * span + span - 1,
+        x, counts, fk, fv = layer(*carry, params["full"], True, p, p * span + span - 1,
                                   None if chosen is None else chosen[span - 1])
         return (x, counts), (wk, wv, fk, fv)
 
@@ -286,7 +294,7 @@ def forward(params: dict, tokens: jax.Array, config: MellumConfig) -> jax.Array:
         return lambda q, k, v: causal_attention(q, k, v, positions, window=0 if full else c.window)
 
     x, *_ = _run_layers(params, c, _embed(params, tokens, c), positions, jnp.ones((B, T), bool), make_attn)
-    return _head_logits(rms_norm(x, params["norm"], c.norm_eps), params, c)
+    return _head_logits(_final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -363,12 +371,13 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     keep, ring_ids = ring_newest(slots, zero, lengths, T, win["k"].shape[2], ring, pad)
     x, wk, wv, fk, fv, counts = _run_layers(
         params, c, _embed(params, tokens, c), positions, valid, make_attn, route, keep)
-    full = commit_whole_pages(full, fk, fv, page_ids)
-    with jax.named_scope("window_commit"):
-        win = commit_whole_pages(win, wk, wv, ring_ids)
-    cache = _committed(cache, full, win, counts, _window_counts(c, positions, valid), 1)
-    x = rms_norm(x, params["norm"], c.norm_eps)
-    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, c)
+    with scopes.layer("commit"):
+        full = commit_whole_pages(full, fk, fv, page_ids)
+        with jax.named_scope("window_commit"):
+            win = commit_whole_pages(win, wk, wv, ring_ids)
+        cache = _committed(cache, full, win, counts, _window_counts(c, positions, valid), 1)
+    x = _final_norm(x, params, c)
+    return cache, _head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -415,27 +424,28 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     keep, ring_ids = ring_newest(jnp.minimum(slots, pad), starts, lengths, T, P, ring, pad)
     x, wk, wv, fk, fv, counts = _run_layers(
         params, c, _embed(params, tokens, c), positions, valid, make_attn, keep=keep)
-    return (rms_norm(x, params["norm"], c.norm_eps), wk, wv, ring_ids, fk, fv, counts,
-            _window_counts(c, positions, valid))
+    x = _final_norm(x, params, c)
+    with scopes.layer("commit"):
+        return x, wk, wv, ring_ids, fk, fv, counts, _window_counts(c, positions, valid)
 
 
 def _continue_commit(cache, new, page_ids):
     wk, wv, ring_ids, fk, fv, counts, window_counts = new
     full, win = _pools(cache)
-    full = commit_whole_pages(full, fk, fv, page_ids)
-    with jax.named_scope("window_commit"):
-        win = commit_whole_pages(win, wk, wv, ring_ids)
-    return _committed(cache, full, win, counts, window_counts, 1)
+    with scopes.layer("commit"):
+        full = commit_whole_pages(full, fk, fv, page_ids)
+        with jax.named_scope("window_commit"):
+            win = commit_whole_pages(win, wk, wv, ring_ids)
+        return _committed(cache, full, win, counts, window_counts, 1)
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
                            config: MellumConfig):
     """Continuation (a later chunk of a long prompt, a resumed request's
     tail): -> (cache, last-token logits [B, V])."""
-    B = tokens.shape[0]
     x, *new = _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, config)
     cache = _continue_commit(cache, new, page_ids)
-    return cache, _head_logits(x[jnp.arange(B), lengths - 1], params, config)
+    return cache, _head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -467,13 +477,13 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
 
     def make_attn(is_full, i):
         def attn(q, k, v):
-            if is_full:
-                args = (q[:, 0], k_flat, v_flat, layer_tables(block_tables, i, NP), seq_lens, k[:, 0], v[:, 0])
-                kw = {}
-            else:
-                args = (q[:, 0], wk_flat, wv_flat, layer_tables(rings, i, NW), seq_lens, k[:, 0], v[:, 0])
-                kw = {"starts": first}
             with jax.named_scope("page_walk" if is_full else "window_walk"):
+                if is_full:
+                    args = (q[:, 0], k_flat, v_flat, layer_tables(block_tables, i, NP), seq_lens, k[:, 0], v[:, 0])
+                    kw = {}
+                else:
+                    args = (q[:, 0], wk_flat, wv_flat, layer_tables(rings, i, NW), seq_lens, k[:, 0], v[:, 0])
+                    kw = {"starts": first}
                 if use_pallas:
                     from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
 
@@ -487,14 +497,15 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         return attn
 
     x, wk, wv, fk, fv, counts = _run_layers(
-        params, c, _embed(params, tokens[:, None], c), positions, active[:, None], make_attn, route)
-    target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-    full = commit_tokens(full, fk[:, :, 0], fv[:, :, 0], target, seq_lens % P)
-    with jax.named_scope("window_commit"):
-        at = jnp.where(active, jnp.arange(S), pad) * ring + jnp.mod(seq_lens // P, ring)
-        win = commit_tokens(win, wk[:, :, 0], wv[:, :, 0], at, seq_lens % P)
-    cache = _committed(cache, full, win, counts, _window_counts(c, positions, active[:, None]), 0)
-    x = rms_norm(x[:, 0], params["norm"], c.norm_eps)
+        params, c, _embed(params, tokens[:, None], c), positions, active[:, None], make_attn, route, walk=None)
+    with scopes.layer("commit"):
+        target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
+        full = commit_tokens(full, fk[:, :, 0], fv[:, :, 0], target, seq_lens % P)
+        with jax.named_scope("window_commit"):
+            at = jnp.where(active, jnp.arange(S), pad) * ring + jnp.mod(seq_lens // P, ring)
+            win = commit_tokens(win, wk[:, :, 0], wv[:, :, 0], at, seq_lens % P)
+        cache = _committed(cache, full, win, counts, _window_counts(c, positions, active[:, None]), 0)
+    x = _final_norm(x[:, 0], params, c)
     return cache, _head_logits(x, params, c)
 
 

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import scopes
 from .attention import NEG_INF
 from .quant import kv_dequantize, kv_quantize
 
@@ -84,9 +85,10 @@ def set_pages(arr: jax.Array, page_ids: jax.Array, blocks: jax.Array) -> jax.Arr
     """``arr[:, page_ids] = blocks`` ([L, n, P, ...]) as one scatter of whole
     pages into the pool flattened over its layers."""
     L, NP = arr.shape[:2]
-    ids = layer_tables(page_ids.reshape(-1)[None, :], jnp.arange(L)[:, None], NP).reshape(-1)
-    flat = flat_pages(arr).at[ids].set(blocks.reshape((ids.shape[0],) + arr.shape[2:]))
-    return flat.reshape(arr.shape)
+    with scopes.layer("commit"):
+        ids = layer_tables(page_ids.reshape(-1)[None, :], jnp.arange(L)[:, None], NP).reshape(-1)
+        flat = flat_pages(arr).at[ids].set(blocks.reshape((ids.shape[0],) + arr.shape[2:]))
+        return flat.reshape(arr.shape)
 
 
 def kv_commit(pool: dict, new_k: jax.Array, new_v: jax.Array, setter) -> dict:
@@ -105,7 +107,8 @@ def kv_commit(pool: dict, new_k: jax.Array, new_v: jax.Array, setter) -> dict:
 def commit_whole_pages(pool: dict, new_k: jax.Array, new_v: jax.Array, page_ids: jax.Array) -> dict:
     """``new_k`` [L, B, T, H_kv, d] into pages ``page_ids`` [B, T // P]: the
     one whole-page write of every prefill, continuation and mid chunk."""
-    return kv_commit(pool, new_k, new_v, lambda arr, val: set_pages(arr, page_ids, val))
+    with scopes.layer("commit"):
+        return kv_commit(pool, new_k, new_v, lambda arr, val: set_pages(arr, page_ids, val))
 
 
 def commit_tokens(pool: dict, new_k: jax.Array, new_v: jax.Array, pages: jax.Array,
@@ -116,11 +119,12 @@ def commit_tokens(pool: dict, new_k: jax.Array, new_v: jax.Array, pages: jax.Arr
     layers. The within-page axis stays an axis of its own, so a pool that
     shards it (context-parallel serving) is not gathered to be written."""
     L, NP = pool["k"].shape[:2]
-    layer = jnp.arange(L).reshape((L,) + (1,) * pages.ndim)
-    ids = layer_tables(pages[None], layer, NP)
-    rows = jnp.broadcast_to(offsets[None], ids.shape)
-    return kv_commit(pool, new_k, new_v,
-                     lambda arr, val: flat_pages(arr).at[ids, rows].set(val).reshape(arr.shape))
+    with scopes.layer("commit"):
+        layer = jnp.arange(L).reshape((L,) + (1,) * pages.ndim)
+        ids = layer_tables(pages[None], layer, NP)
+        rows = jnp.broadcast_to(offsets[None], ids.shape)
+        return kv_commit(pool, new_k, new_v,
+                         lambda arr, val: flat_pages(arr).at[ids, rows].set(val).reshape(arr.shape))
 
 
 def gather_pages(pool: dict, name: str, ids: jax.Array, dtype, n_kv_heads: int) -> jax.Array:

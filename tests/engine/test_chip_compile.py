@@ -728,15 +728,12 @@ def test_a_decode_step_hands_a_layer_its_own_row_not_its_kinds_stack(v5e, family
         slots, ctx = _JAMBA_SLOTS, 2048
         leads = {c.n_mamba, c.n_layers}
         other, other_marks = "mamba", ("mamba_in_proj",)
-    # the attention operator has no scope of its own: give it one here
-    op = model._attention_op
-    monkeypatch.setattr(model, "_attention_op", lambda *a, **k: jax.named_scope("attention_op")(op)(*a, **k))
     compiled = jax.jit(
         lambda p, ca, tok, n, tables, active: model.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True),
         donate_argnums=(1,),
     ).lower(params, cache, vec(slots), vec(slots), vec(slots, ctx // PAGE), vec(slots, dt=jnp.bool_)).compile()
     loops = _Loops(compiled)
-    attention = ("attention_op",)
+    attention = ("acp.attn",)  # the program's own scope around an attention layer's mixer (observability/scopes.py)
     assert loops.loops and any(loops.marked(body, attention) and loops.marked(body, other_marks) for _, body in loops.loops)
     assert not loops.stacks_made(leads), "a stack, or its like, is made inside a loop"
     state = {k: v for k, v in cache["state"].items() if k in ("conv", "ssm")}
