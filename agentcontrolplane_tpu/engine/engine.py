@@ -884,21 +884,26 @@ class Engine:
             # KV heads a chip): report the G this engine's walks compile
             # with, so a geometry that fell to one page a turn is seen in
             # stats() and the log, not guessed. 0: no kernel (reference).
-            self.pages_per_turn = 0
+            # Beside it what the walk keeps started ahead of the turn it
+            # folds, from the same rule that sizes its scratch.
+            self.pages_per_turn = self.turns_in_flight = self.bytes_in_flight = 0
             if self._use_pallas:
-                from ..ops.pallas.paged_attention import pages_per_turn
+                from ..ops.pallas.paged_attention import fetches_in_flight, pages_per_turn
 
                 axes = dict(self.mesh.shape)
                 k = self.cache["k"]  # [L, num_pages, page_size, ...]
                 rows = k.shape[2] // axes.get("sp", 1)  # of a page, a rank
-                self.pages_per_turn = pages_per_turn(
+                geometry = (
                     rows, k.dtype, config.n_kv_heads // axes.get("tp", 1),
                     config.head_dim, self.quantize_kv,
                 )
+                self.pages_per_turn = pages_per_turn(*geometry)
+                self.turns_in_flight, self.bytes_in_flight = fetches_in_flight(*geometry)
                 log.info(
                     "paged decode: Pallas page walk, pages_per_turn=%d "
-                    "(%d tokens a turn)",
+                    "(%d tokens a turn), turns_in_flight=%d, bytes_in_flight=%d",
                     self.pages_per_turn, self.pages_per_turn * rows,
+                    self.turns_in_flight, self.bytes_in_flight,
                 )
         log.info("engine init: params+cache in %.1fs", time.monotonic() - t0)
 
@@ -2262,6 +2267,9 @@ class Engine:
                 "page_size": self.page_size,
                 # pages one turn of the compiled walk folds (0: no kernel)
                 "pages_per_turn": self.pages_per_turn,
+                # turns and bytes of K and V its fetches run ahead of the fold
+                "turns_in_flight": self.turns_in_flight,
+                "bytes_in_flight": self.bytes_in_flight,
                 "table_uploads": self.table_uploads,
             }
             model = programs(self.config)  # not the engine thread's fields: any thread asks

@@ -2,7 +2,7 @@
 
 One new token per slot attends over its page list. The kernel walks each
 sequence's block table (scalar-prefetched so page indices are known before
-the body runs), DMAs K/V pages HBM -> VMEM with double buffering, and
+the body runs), DMAs K/V pages HBM -> VMEM through a ring of buffers, and
 accumulates a flash-style online softmax — the gathered
 ``[S, max_ctx, H, d]`` copy the pure-XLA reference materializes
 (``ops.paged.paged_decode_attention_reference``) never exists.
@@ -21,15 +21,30 @@ block tables offset by the layer, and nothing is relaid. A test or
 ``chip_smoke.py`` may still hand the wrappers one layer's ``[num_pages, P,
 H_kv, d]``; the merge is then a copy of those pages on the chip's tiling.
 
-Grid: one program per slot. A turn of the walk covers G pages, G chosen
-so that a turn is one 128-lane tile of tokens (``pages_per_turn``: 8 at the
-engine's page 16, 1 at page 128): the turn's pages are DMA'd each into its
-own row window of one ``[G * P, H_kv * d]`` buffer, and the body runs one
-pair of products per KV head over all of them. The walk is bound by what a
-turn costs (DMA waits, the chained softmax update, MXU fill and drain), not
-by bytes, so fewer, fuller turns are the lever. Per-program working set is
-NBUF x 2 (K+V) x [G * P, H_kv * d] — 1 MB in VMEM for Qwen2.5-7B geometry
-(page 16, 4 KV heads, d 128, bf16).
+One program walks every slot (or as many as fit VMEM with their q and
+outputs: ``slots_per_program``), as ONE stream of turns. A turn of the walk
+covers G pages, G chosen so that a turn is one 128-lane tile of tokens
+(``pages_per_turn``: 8 at the engine's page 16, 1 at page 128): the turn's
+pages are DMA'd each into its own row window of one ``[2, G * P, H_kv * d]``
+buffer (K and V) and the body runs one pair of products per KV head over all
+of them. The stream is every slot's turns in slot order, slots with nothing
+to walk stepped over; the fetches run ``RING - 1`` turns ahead of the fold
+from the kernel's first turn to its last, so a slot's last turns are folded
+while the next slots' first are in flight and the queue never drains between
+slots (``fetches_in_flight``). What a turn costs on a v5e, as measured with
+the kernel's parts taken out (PERF.md, PR 43): ~13 cycles of the scalar
+unit to issue each fetch, two a page, which may not be scheduled past a load
+of the buffers they write to; the latency of the body's two
+passes (product, reductions, product), which is the same for one KV head as
+for four; and ~27 ns a fetch in the DMA engine whatever its size, which is
+the floor under 16-row pages. A fetch's latency is NOT it (rings of 2 to 16
+buffers walk equally fast), nor are the bytes. So: a turn's 2 x G fetches
+signal one semaphore and are taken by ONE wait of the buffer's size (a turn
+always fetches G pages; past the walk's last page it fetches that page
+again); and the next fetches are issued unguarded, half after each of the
+body's two passes, where they cost least. Working set: the ring,
+RING x 2 (K+V) x [G * P, H_kv * d] — 1 MB for Qwen2.5-7B geometry (page 16,
+4 KV heads, d 128, bf16) — beside the program's q and three outputs.
 
 Geometry note: the walk takes head widths 64, 128 and 256
 (``heads_per_window``): a multiple of the 128-lane width whole (128 for
@@ -51,7 +66,7 @@ reason; the wrapper reshapes them outside.
 int8 page walk: with ``k_scales``/``v_scales`` (the allocator's per-row-
 per-head f32 scale twins, [num_pages, P, H_kv] as the pool stores them, or
 already laid out for the kernel by :func:`walk_scale_rows`) each page
-fetch also DMAs its scale row on dedicated semaphore lanes. The per-row
+fetch also DMAs its scale rows, on a semaphore of their own. The per-row
 scale factors out of both products — ``q . (k_int8 * s) == (q . k_int8) *
 s`` — so the body scales the ``[n_rep, P]`` logits and softmax weights by
 the head's ``[1, P]`` scale row and never builds a dequantized page; the
@@ -79,14 +94,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-NBUF = 4  # DMA pipeline depth: NBUF-1 turns' fetches kept in flight per walk
+RING = 4  # turn buffers of the walk: RING - 1 turns' fetches run ahead of the fold
 LANES = 128  # a turn of the walk covers one lane tile of tokens
 # scratch the walk may claim of the 16 MiB scoped VMEM a kernel gets by
-# default; the rest is the body's f32 windows and the pipelined q / outputs
+# default; the rest is the body's f32 windows and a program's q and outputs
 _SCRATCH_BUDGET = 8 << 20
+_SLOTS_BUDGET = 3 << 20  # of one program's q and outputs (each held twice)
 # In-kernel products run at f32 contract precision: the walk is the same
 # f32 math as the XLA reference, not a bf16-pass approximation of it. The
-# one exception is exact: q . k with both sides bf16 (see the KV-head loop).
+# one exception is exact: q . k with both sides bf16 (see `scores`).
 _F32 = jax.lax.Precision.HIGHEST
 
 
@@ -99,15 +115,39 @@ def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = Fal
     must be a multiple of the dtype's sublane tile: 8 for f32, 16 for bf16,
     32 for int8 — and for int8 pages at any size: their scale rows are laid
     out head-major per page and do not follow a G-page turn. G halves until
-    the K and V scratch fits ``_SCRATCH_BUDGET``.
+    the ring of K and V buffers fits ``_SCRATCH_BUDGET``.
     """
     itemsize = jnp.dtype(dtype).itemsize
     G = max(1, LANES // P_local)
     if quantized or P_local % (32 // itemsize):
         G = 1
-    while G > 1 and 2 * NBUF * G * P_local * H_kv * d * itemsize > _SCRATCH_BUDGET:
+    while G > 1 and 2 * RING * G * P_local * H_kv * d * itemsize > _SCRATCH_BUDGET:
         G //= 2
     return G
+
+
+def fetches_in_flight(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False) -> tuple[int, int]:
+    """(turns, bytes) of K and V the compiled walk keeps started ahead of
+    the turn it folds, from its first turn to its last and across slots:
+    ``RING - 1`` turns of ``pages_per_turn`` pages. Not scaled by the
+    turn's bytes: on a v5e the walk is bound by what a turn's fetches cost
+    the scalar unit to issue and by its two passes' latency, not by a
+    fetch's latency over the bytes in flight, and rings of 3, 6, 8 and 16
+    buffers all measured slower than 4 (PERF.md, PR 43)."""
+    G = pages_per_turn(P_local, dtype, H_kv, d, quantized)
+    return RING - 1, (RING - 1) * 2 * G * P_local * H_kv * d * jnp.dtype(dtype).itemsize
+
+
+def slots_per_program(S: int, H_kv: int, n_rep: int, d: int, q_dtype) -> int:
+    """Slots one program of the walk streams through: all ``S`` where their
+    q and three outputs fit ``_SLOTS_BUDGET`` of VMEM (held twice, pipelined
+    from program to program), else the largest divisor of ``S`` that does.
+    m and l pad their last axis to a lane tile, q's rows to its dtype's."""
+    itemsize = jnp.dtype(q_dtype).itemsize
+    tile = 32 // itemsize  # rows of q's sublane tile; float32's is 8
+    q_rows, rows = -(-n_rep // tile) * tile, -(-n_rep // 8) * 8
+    slot = H_kv * (q_rows * d * itemsize + rows * d * 4 + 2 * rows * LANES * 4)
+    return next(b for b in range(S, 0, -1) if S % b == 0 and (b * slot <= _SLOTS_BUDGET or b == 1))
 
 
 def heads_per_window(d: int, H_kv: int, quantized: bool = False) -> int:
@@ -173,20 +213,21 @@ def _kernel(
     seq_lens_ref,  # [S] int32 (SMEM)
     pos_base_ref,  # [1] int32 (SMEM) — this rank's within-page offset
     # inputs
-    q_ref,  # [1, H_kv, n_rep, d] (VMEM) — this program's slot, grouped by KV head
+    q_ref,  # [S, H_kv, n_rep, d] (VMEM) — the program's slots, grouped by KV head
     k_pages_ref,  # [num_pages, P_local, H_kv * d] (HBM/ANY)
     v_pages_ref,  # [num_pages, P_local, H_kv * d]
     # quantized=True only: ks_pages_ref / vs_pages_ref
     #   [num_pages, 1, SC] f32 (HBM/ANY) — a page's per-row-per-head
     #   scales, head-major ([H_kv, P_local] flattened), lane-padded to SC
     # outputs, grouped by KV head like q:
-    # acc_ref: [1, H_kv, n_rep, d] f32 — unnormalized weighted V sum
-    # m_ref:   [1, H_kv, n_rep, 1] f32 — running max
-    # l_ref:   [1, H_kv, n_rep, 1] f32 — running denominator
+    # acc_ref: [S, H_kv, n_rep, d] f32 — unnormalized weighted V sum
+    # m_ref:   [S, H_kv, n_rep, 1] f32 — running max
+    # l_ref:   [S, H_kv, n_rep, 1] f32 — running denominator
     # scratch
-    # k_buf / v_buf: [NBUF, G * P_local, H_kv * d] (VMEM) — a turn's G pages
-    # quantized=True only (G == 1): ks_buf / vs_buf [NBUF, 1, SC] f32 (VMEM)
-    # sems: DMA sems [NBUF, 4 if quantized else 2]
+    # kv_buf: [RING, 2, G * P_local, H_kv * d] (VMEM) — a turn's K and V
+    # quantized=True only (G == 1): sc_buf [RING, 2, 1, SC] f32 (VMEM)
+    # sems: DMA sems [RING, 2 if quantized else 1] — one a turn (and its scales)
+    # turns_ref, next_ref: [S] int32 (SMEM) — a slot's turns, the next slot that has any
     *rest,
     page_size: int,  # GLOBAL page size (pages hold this many tokens)
     quantized: bool = False,
@@ -195,101 +236,111 @@ def _kernel(
     ring: int = 0,  # > 0: the table is a ring, page a of the sequence at a % ring
 ):
     # int8 walk (quantized=True): pages hold int8 values plus f32 scale
-    # twins (one scale per row per KV head). The fetch loop DMAs each
-    # page's scale row alongside it on its own semaphore lanes and the body
-    # applies the scales in VMEM (see the KV-head loop), so int8 decode
-    # takes the kernel path with the same (acc, m, l) contract as the f32
-    # walk.
+    # twins (one scale per row per KV head). A turn's fetch DMAs its page's
+    # scale rows alongside it on a semaphore of their own and the body
+    # applies the scales in VMEM (see `scores`), so int8 decode takes the
+    # kernel path with the same (acc, m, l) contract as the f32 walk.
     if quantized:
         (ks_pages_ref, vs_pages_ref, acc_ref, m_ref, l_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sems) = rest
+         kv_buf, sc_buf, sems, turns_ref, next_ref) = rest
     else:
-        acc_ref, m_ref, l_ref, k_buf, v_buf, sems = rest
-        ks_pages_ref = vs_pages_ref = ks_buf = vs_buf = None
-    # Under context-parallel serving each rank holds a [P_local = P/sp]
-    # slice of every page (pos_base = rank * P_local); the walk length and
-    # token positions are computed with the GLOBAL page size so masking is
-    # exact, while DMAs and compute touch only the local slice. sp=1 runs
-    # with pos_base=0 and P_local == page_size (the original behavior).
-    s = pl.program_id(0)
-    seq_len = seq_lens_ref[s]
-    n_pages = jax.lax.div(seq_len + page_size - 1, page_size)
-    # The window walk (``starts_ref``): rows before a slot's first valid row
-    # are not the query's to see. The walk begins at the page that holds that
-    # row, skips every page before it, and masks the rows of that first page
-    # that lie before the edge; ``first_page`` and ``n_pages`` are then of the
-    # walk and not of the sequence. With ``ring`` the table has ``ring``
-    # entries a slot and page ``a`` of the sequence sits at ``a % ring``: a
-    # window of at most ``(ring - 1)`` pages of rows touches no entry twice.
-    if starts_ref is not None:
-        first_row = starts_ref[s]
-        first_page = jax.lax.div(first_row, page_size)
-        n_pages = jnp.maximum(n_pages - first_page, 0)
-    _, n_kv_heads, n_rep, d = q_ref.shape
+        acc_ref, m_ref, l_ref, kv_buf, sems, turns_ref, next_ref = rest
+        ks_pages_ref = vs_pages_ref = sc_buf = None
+    S, n_kv_heads, n_rep, d = q_ref.shape  # S: this program's slots
+    base = pl.program_id(0) * S  # its first slot among the batch's
     P = k_pages_ref.shape[1]  # local slice length
     pos_base = pos_base_ref[0]
-    NBUF, T = k_buf.shape[:2]  # T = G * P tokens a turn
+    D, _, T, _ = kv_buf.shape  # T = G * P tokens a turn
     G = T // P
-    n_turns = jax.lax.div(n_pages + G - 1, G)
+
+    def walk(s):
+        """(seq_len, first_row, first_page, n_pages) of slot ``s``'s walk.
+
+        Under context-parallel serving each rank holds a [P_local = P/sp]
+        slice of every page (pos_base = rank * P_local); the walk length and
+        token positions are computed with the GLOBAL page size so masking is
+        exact, while DMAs and compute touch only the local slice. sp=1 runs
+        with pos_base=0 and P_local == page_size.
+
+        The window walk (``starts_ref``): rows before a slot's first valid
+        row are not the query's to see. The walk begins at the page that
+        holds that row, skips every page before it, and masks the rows of
+        that first page that lie before the edge; ``first_page`` and
+        ``n_pages`` are then of the walk and not of the sequence. With
+        ``ring`` the table has ``ring`` entries a slot and page ``a`` of the
+        sequence sits at ``a % ring``: a window of at most ``(ring - 1)``
+        pages of rows touches no entry twice."""
+        seq_len = seq_lens_ref[base + s]
+        n_pages = jax.lax.div(seq_len + page_size - 1, page_size)
+        first_row = first_page = None
+        if starts_ref is not None:
+            first_row = starts_ref[base + s]
+            first_page = jax.lax.div(first_row, page_size)
+            n_pages = jnp.maximum(n_pages - first_page, 0)
+        return seq_len, first_row, first_page, n_pages
+
+    # The walk is ONE stream of (slot, turn) items over every slot (of this
+    # program: `slots_per_program`) that has a turn, in slot order: the
+    # fetches run D - 1 items ahead of the fold from the program's first
+    # item to its last, so the queue a slot's last turns leave is already
+    # refilled with the next slots' first. Slots with nothing to walk (a
+    # padding lane, a window not reached) are never visited: `next_ref`
+    # steps over them; their outputs are the start state written here.
+    def count(i, c):
+        nxt, total = c
+        s = S - 1 - i
+        n = jax.lax.div(walk(s)[3] + G - 1, G)
+        turns_ref[s] = n
+        next_ref[s] = nxt
+        return jnp.where(n > 0, s, nxt), total + n
+
+    first, total = jax.lax.fori_loop(0, S, count, (jnp.int32(S), jnp.int32(0)))
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    def advance(s, t):  # the item after (s, t); s == S past the last
+        last = t + 1 >= turns_ref[s]
+        return jnp.where(last, next_ref[s], s), jnp.where(last, 0, t + 1)
+
+    def start(s, t, buf, pages=range(G)):
+        # A turn's fetches (those of `pages`): page t*G+g of slot s's walk
+        # into rows [g*P, (g+1)*P) of the buffer's K and V windows, every
+        # one signalling the buffer's one semaphore. A turn always fetches G
+        # pages, with no branch among them: past the walk's last page it
+        # fetches that page again (a live page, read a moment before), so a
+        # turn's bytes are always the buffer's and ONE wait of the buffer's
+        # size takes them all, and no row of a buffer is ever left unwritten.
+        _, _, first_page, n_pages = walk(s)
+        for g in pages:
+            at = jnp.minimum(t * G + g, n_pages - 1)
+            if starts_ref is not None:
+                at = first_page + at
+            page = block_tables_ref[base + s, jax.lax.rem(at, ring) if ring else at]
+            rows = pl.ds(g * P, P)
+            pltpu.make_async_copy(k_pages_ref.at[page], kv_buf.at[buf, 0, rows], sems.at[buf, 0]).start()
+            pltpu.make_async_copy(v_pages_ref.at[page], kv_buf.at[buf, 1, rows], sems.at[buf, 0]).start()
+            if quantized:
+                pltpu.make_async_copy(ks_pages_ref.at[page], sc_buf.at[buf, 0], sems.at[buf, 1]).start()
+                pltpu.make_async_copy(vs_pages_ref.at[page], sc_buf.at[buf, 1], sems.at[buf, 1]).start()
+
+    def wait(buf):  # every fetch of the turn in `buf`: one wait of its size
+        pltpu.make_async_copy(kv_buf.at[buf], kv_buf.at[buf], sems.at[buf, 0]).wait()
+        if quantized:
+            pltpu.make_async_copy(sc_buf.at[buf], sc_buf.at[buf], sems.at[buf, 1]).wait()
+
+    def ramp(j, c):
+        start(*c, j)
+        return advance(*c)
+
+    ahead = jax.lax.fori_loop(0, jnp.minimum(D - 1, total), ramp, (first, jnp.int32(0)))
 
     scale = 1.0 / ((head_dim or d) ** 0.5)
     # q . k in one bf16 MXU pass where both sides are bf16 (int8 widens to
     # bf16 exactly): bf16 x bf16 products are exact in the f32 accumulator,
     # so with 1/sqrt(d) applied to the f32 logits this is the f32 product.
-    # f32 q or pages take the f32 contract with q pre-scaled once.
-    one_pass = q_ref.dtype == jnp.bfloat16 and k_buf.dtype in (jnp.bfloat16, jnp.int8)
-    if one_pass:
-        qs = [q_ref[0, h] for h in range(n_kv_heads)]
-    else:
-        qs = [q_ref[0, h].astype(jnp.float32) * scale for h in range(n_kv_heads)]
-
-    def fetch(t, slot, act):  # act: "start" or "wait", every DMA of turn t
-        # a turn's DMAs: page t*G+g of the block table into rows
-        # [g*P, (g+1)*P) of the turn's buffer. The caller holds t < n_turns,
-        # so the turn's first page is live; the rest are guarded one by one
-        # (G = 1: one unguarded page into the whole buffer).
-        for g in range(G):
-            def run(g=g):
-                at = t * G + g
-                if starts_ref is not None:
-                    at = first_page + at
-                page = block_tables_ref[s, jax.lax.rem(at, ring) if ring else at]
-                rows = pl.ds(g * P, P)
-                copies = [
-                    (k_pages_ref.at[page], k_buf.at[slot, rows]),
-                    (v_pages_ref.at[page], v_buf.at[slot, rows]),
-                ]
-                if quantized:
-                    copies += [
-                        (ks_pages_ref.at[page], ks_buf.at[slot]),
-                        (vs_pages_ref.at[page], vs_buf.at[slot]),
-                    ]
-                for i, (src, dst) in enumerate(copies):
-                    getattr(pltpu.make_async_copy(src, dst, sems.at[slot, i]), act)()
-
-            if g == 0:
-                run()
-            else:
-                pl.when(t * G + g < n_pages)(run)
-
-    if G > 1:
-        # Rows of a turn's buffer that no DMA wrote (the pages past n_pages
-        # in the walk's last turn) carry weight 0 into p . v, and 0 x NaN is
-        # NaN: start every walk from a zeroed V scratch. A buffer's later
-        # turns leave only fetched, finite rows behind. K needs none of
-        # this: its logits are replaced by the mask, not multiplied.
-        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
-
-    # page walks are small-transfer latency-bound: keep NBUF-1 turns'
-    # fetches in flight (ramp turns 0..NBUF-2 here, steady state issues
-    # t+NBUF-1)
-    def ramp(t, _):
-        @pl.when(t < n_turns)
-        def _():
-            fetch(t, t, "start")
-        return 0
-
-    jax.lax.fori_loop(0, NBUF - 1, ramp, 0)
+    # f32 q or pages take the f32 contract with q pre-scaled.
+    one_pass = q_ref.dtype == jnp.bfloat16 and kv_buf.dtype in (jnp.bfloat16, jnp.int8)
 
     # token position of a turn's column c, less the turn's first: row c % P
     # of the turn's page c // P, pages page_size tokens apart (sp=1: c)
@@ -299,16 +350,13 @@ def _kernel(
         for g in range(1, G):
             col = col + jnp.where(lane >= g * P, page_size - P, 0)
 
-    def body(t, carry):
-        slot = jax.lax.rem(t, NBUF)
-        # issue the deepest prefetch; its buffer was consumed at t-1
-        nxt = t + NBUF - 1
-
-        @pl.when(nxt < n_turns)
-        def _():
-            fetch(nxt, jax.lax.rem(nxt, NBUF), "start")
-
-        fetch(t, slot, "wait")
+    def turn(i, carry, issue):
+        """Fold item i, slot `s`'s turn `t`, out of buffer i % D; with
+        `issue`, start the item D - 1 ahead into the buffer item i - 1 left."""
+        (s, t), fetching, carried = carry
+        buf = jax.lax.rem(i, D)
+        seq_len, first_row, first_page, _ = walk(s)
+        wait(buf)
         pos = t * (G * page_size) + pos_base + col
         if starts_ref is not None:
             pos = pos + first_page * page_size
@@ -316,34 +364,37 @@ def _kernel(
         if starts_ref is not None:
             valid = valid & (pos >= first_row)
         if quantized:
-            ks = ks_buf[slot]  # [1, >= H_kv * P], head-major
-            vs = vs_buf[slot]
-        out = []
-        # Static loop over the KV heads. Each takes its lane-aligned [T, d]
-        # column window of the turn's buffer (d % 128 == 0) and two plain
-        # 2-D products with its [n_rep, d] query group — the only shapes in
-        # the body are 2-D, which is what Mosaic lays out (a 4-D grouped
-        # reshape of the logits is refused: "unsupported shape cast").
-        for h in range(n_kv_heads):
-            m, l, acc = carry[h]  # [n_rep,1], [n_rep,1], [n_rep,d]
-            k = k_buf[slot, :, h * d:(h + 1) * d]  # [T, d]
-            v = v_buf[slot, :, h * d:(h + 1) * d].astype(jnp.float32)
+            ks = sc_buf[buf, 0]  # [1, >= H_kv * P], head-major
+            vs = sc_buf[buf, 1]
+        fresh = t == 0  # a slot's first turn starts from the empty state
+
+        # Static loops over the KV heads, in two passes with the next
+        # fetches issued between them. Each head takes its lane-aligned
+        # [T, d] column window of the turn's buffer (d % 128 == 0) and two
+        # plain 2-D products with its [n_rep, d] query group — the only
+        # shapes in the body are 2-D, which is what Mosaic lays out (a 4-D
+        # grouped reshape of the logits is refused: "unsupported shape cast").
+        def scores(h):
+            m, l, _ = carried[h]  # [n_rep,1], [n_rep,1], [n_rep,d]
+            m = jnp.where(fresh, NEG_INF, m)
+            l = jnp.where(fresh, 0.0, l)
+            k = kv_buf[buf, 0, :, h * d:(h + 1) * d]  # [T, d]
             if one_pass:
                 logits = jax.lax.dot_general(
-                    qs[h], k.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                    q_ref[s, h], k.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 ) * scale  # [n_rep, T]
             else:
                 logits = jax.lax.dot_general(
-                    qs[h], k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                    q_ref[s, h].astype(jnp.float32) * scale, k.astype(jnp.float32),
+                    (((1,), (1,)), ((), ())),
                     precision=_F32, preferred_element_type=jnp.float32,
                 )
             if quantized:
                 # the per-row scale factors out of both products: scale the
                 # [n_rep, P] logits and weights by this head's [1, P] scale
-                # row, never the [P, d] page. Masked rows (stale scales
-                # incl. TRASH_PAGE) stay finite, so the pos mask zeroes
-                # their weight as in f32.
+                # row, never the [P, d] page. Masked rows (stale scales)
+                # stay finite, so the pos mask zeroes their weight as in f32.
                 logits = logits * ks[:, h * P:(h + 1) * P]
             logits = jnp.where(valid, logits, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
@@ -351,13 +402,44 @@ def _kernel(
             correction = jnp.exp(m - m_new)  # [n_rep, 1]
             l = l * correction + jnp.sum(p, axis=1, keepdims=True)
             pw = p * vs[:, h * P:(h + 1) * P] if quantized else p
+            return m_new, l, correction, pw
+
+        def values(h, m_new, l, correction, pw):
+            v = kv_buf[buf, 1, :, h * d:(h + 1) * d].astype(jnp.float32)
             pv = jnp.dot(
                 pw, v, precision=_F32, preferred_element_type=jnp.float32
             )  # [n_rep, d]
-            out.append((m_new, l, acc * correction + pv))
-        return tuple(out)
+            acc = jnp.where(fresh, 0.0, carried[h][2])
+            return m_new, l, acc * correction + pv
 
-    init = tuple(
+        # The fetch of the item D - 1 ahead, into the buffer item i - 1
+        # left, half after each pass and unguarded: a fetch is ~13 cycles of
+        # the scalar unit and may not be scheduled past a load of the
+        # buffers it writes to, so its place is after a pass's loads, in the
+        # shadow of that pass's products and reductions (the study in
+        # PERF.md, PR 43: all of them before the first pass cost a turn
+        # 0.23 us, so placed 0.06). Unguarded because a branch would end
+        # the block they are scheduled in: the `issue` loop runs only over
+        # items that have one D - 1 ahead.
+        ahead_buf = jax.lax.rem(i + D - 1, D)
+        half = [scores(h) for h in range(n_kv_heads)]
+        if issue:
+            start(*fetching, ahead_buf, range(0, G - G // 2))
+        state = tuple(values(h, *half[h]) for h in range(n_kv_heads))
+        if issue:
+            start(*fetching, ahead_buf, range(G - G // 2, G))
+            fetching = advance(*fetching)
+
+        @pl.when(t + 1 >= turns_ref[s])
+        def _():  # the slot's last turn: its state is the result
+            for h, (m, l, acc) in enumerate(state):
+                acc_ref[s, h] = acc
+                m_ref[s, h] = m
+                l_ref[s, h] = l
+
+        return advance(s, t), fetching, state
+
+    empty = tuple(
         (
             jnp.full((n_rep, 1), NEG_INF, dtype=jnp.float32),
             jnp.zeros((n_rep, 1), dtype=jnp.float32),
@@ -365,11 +447,10 @@ def _kernel(
         )
         for _ in range(n_kv_heads)
     )
-    state = jax.lax.fori_loop(0, n_turns, body, init)
-    for h, (m, l, acc) in enumerate(state):
-        acc_ref[0, h] = acc
-        m_ref[0, h] = m
-        l_ref[0, h] = l
+    carry = ((first, jnp.int32(0)), ahead, empty)
+    fed = jnp.maximum(total - (D - 1), 0)  # items with one D - 1 ahead of them
+    carry = jax.lax.fori_loop(0, fed, functools.partial(turn, issue=True), carry)
+    jax.lax.fori_loop(fed, total, functools.partial(turn, issue=False), carry)
 
 
 def _window_kernel(block_tables_ref, seq_lens_ref, pos_base_ref, starts_ref, *rest, **kw):
@@ -449,23 +530,24 @@ def _paged_state(
         **({"ring": ring} if windowed else {}),
     )
 
-    def per_slot(*tail):
-        # one slot per program; trailing dims are whole, so they tile
+    # A program walks as many slots as fit (all of them in every cell: the
+    # stream never drains between them), so their q and outputs sit in
+    # VMEM together, pipelined from program to program.
+    blk = slots_per_program(S, H_kv, n_rep, d, q.dtype)
+
+    def per_program(*tail):
         return pl.BlockSpec(
-            (1, H_kv, n_rep) + tail, lambda s, *_: (s, 0, 0, 0),
+            (blk, H_kv, n_rep) + tail, lambda c, *_: (c, 0, 0, 0),
             memory_space=pltpu.VMEM,
         )
 
     in_specs = [
-        per_slot(d),
+        per_program(d),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     G = pages_per_turn(P, k_pages.dtype, H_kv, d, quantized)
-    scratch_shapes = [
-        pltpu.VMEM((NBUF, G * P, H_kv * d), k_pages.dtype),
-        pltpu.VMEM((NBUF, G * P, H_kv * d), v_pages.dtype),
-    ]
+    scratch_shapes = [pltpu.VMEM((RING, 2, G * P, H_kv * d), k_pages.dtype)]
     operands = [
         block_tables,
         seq_lens,
@@ -483,17 +565,18 @@ def _paged_state(
         if not scales_laid:
             k_scales, v_scales = scale_rows(k_scales), scale_rows(v_scales)
         SC = k_scales.shape[2]
-        scratch_shapes += [
-            pltpu.VMEM((NBUF, 1, SC), jnp.float32),
-            pltpu.VMEM((NBUF, 1, SC), jnp.float32),
-        ]
+        scratch_shapes.append(pltpu.VMEM((RING, 2, 1, SC), jnp.float32))
         operands += [k_scales, v_scales]
-    scratch_shapes.append(pltpu.SemaphoreType.DMA((NBUF, 4 if quantized else 2)))
+    scratch_shapes += [
+        pltpu.SemaphoreType.DMA((RING, 2 if quantized else 1)),
+        pltpu.SMEM((blk,), jnp.int32),
+        pltpu.SMEM((blk,), jnp.int32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if windowed else 3,
-        grid=(S,),
+        grid=(S // blk,),
         in_specs=in_specs,
-        out_specs=[per_slot(d), per_slot(1), per_slot(1)],
+        out_specs=[per_program(d), per_program(1), per_program(1)],
         scratch_shapes=scratch_shapes,
     )
     acc, m, l = pl.pallas_call(

@@ -299,7 +299,8 @@ async def phase_serve(layout: str, device) -> dict:
         check(engine._use_pallas, phase,
               "the paged engine did not take the Pallas page walk on a TPU")
         say(phase, "engine._use_pallas=True: decode walks pages with the compiled kernel, "
-                   f"pages_per_turn={engine.stats()['kv_pages']['pages_per_turn']}")
+                   + ", ".join(f"{k}={engine.stats()['kv_pages'][k]}"
+                               for k in ("pages_per_turn", "turns_in_flight", "bytes_in_flight")))
     t0 = time.monotonic()
     prewarm = cli.EnginePrewarm(engine)
     prewarm.start()

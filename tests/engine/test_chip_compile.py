@@ -156,6 +156,68 @@ def test_compiles_for_described_v5e(v5e, case):
     assert "tpu_custom_call" in text, "the Pallas page walk is not in the program"
 
 
+# (id, slots, query heads, KV heads (a chip), head_dim, table entries a slot, the window's ring or 0)
+_CELL_WALKS = [
+    ("qwen2.5-7b", 32, 28, 4, 128, 1792 // PAGE, 0),
+    ("qwen2.5-32b-a-chip-of-tp4", 24, 10, 2, 128, 1792 // PAGE, 0),
+    ("lfm2-24b-a2b", 32, 32, 8, 64, 2048 // PAGE, 0),
+    ("jamba2-3b", 128, 20, 1, 128, 2048 // PAGE, 0),
+    ("mellum2-full-layers", 32, 32, 4, 128, 8192 // PAGE, 0),
+    ("mellum2-window-layers", 32, 32, 4, 128, 1024 // PAGE + 1, 1024 // PAGE + 1),
+    # no cell: a batch whose q and outputs do not fit one program's VMEM
+    ("llama3-8b-at-256-slots", 256, 32, 8, 128, 2048 // PAGE, 0),
+]
+_SLOTS_A_PROGRAM = {128: 64, 256: 16}  # every cell's slots in one program; jamba2's 128 in two
+
+
+def _padded(shape, dtype) -> int:
+    """Bytes of an array held whole in VMEM: its last two axes on the dtype's tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 8 * 4 // itemsize
+    n = -(-rows // sub) * sub * -(-lanes // 128) * 128 * itemsize
+    for x in lead:
+        n *= x
+    return n
+
+
+@pytest.mark.parametrize("case", _CELL_WALKS, ids=lambda c: c[0])
+def test_the_walk_compiles_at_each_cells_geometry(v5e, case):
+    """One program walks as many slots as fit, so their q and three outputs
+    sit in VMEM (twice: pipelined) beside the ring of turn buffers: at each
+    cell's slots and heads the chip's compiler takes it (it refuses a kernel
+    over its scoped VMEM), the ring holds to its budget, and the whole is
+    reckoned against the 16 MiB a kernel gets."""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    _, S, H, H_kv, d, entries, ring = case
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pages = sds((4096, PAGE, H_kv * d), jnp.bfloat16)
+    args = [sds((S, H, d), jnp.bfloat16), pages, pages, sds((S, entries), jnp.int32), sds((S,), jnp.int32)]
+    if ring:
+        walk = lambda q, k, v, t, n, first: pa._paged_state(q, k, v, t, n, kv_heads=H_kv, starts=first, ring=ring)  # noqa: E731
+        args.append(sds((S,), jnp.int32))
+    else:
+        walk = lambda q, k, v, t, n: pa._paged_state(q, k, v, t, n, kv_heads=H_kv)  # noqa: E731
+    text = jax.jit(walk).lower(*args).compile().as_text()
+    assert ("paged_window_walk" if ring else "paged_page_walk") in text and "tpu_custom_call" in text
+    pack = pa.heads_per_window(d, H_kv)  # narrow heads: `pack` to a lane window
+    W, rows, lanes = H_kv // pack, pack * (H // H_kv), pack * d
+    G = pa.pages_per_turn(PAGE, jnp.bfloat16, W, lanes)
+    turns, in_flight = pa.fetches_in_flight(PAGE, jnp.bfloat16, W, lanes)
+    turn = 2 * G * PAGE * W * lanes * 2
+    assert G == 8 and (turns, in_flight) == (pa.RING - 1, (pa.RING - 1) * turn)
+    scratch = pa.RING * turn
+    assert scratch <= pa._SCRATCH_BUDGET
+    blk = pa.slots_per_program(S, W, rows, lanes, jnp.bfloat16)
+    assert blk == _SLOTS_A_PROGRAM.get(S, S)
+    whole = (_padded((blk, W, rows, lanes), jnp.bfloat16) + _padded((blk, W, rows, lanes), jnp.float32)
+             + 2 * _padded((blk, W, rows, 1), jnp.float32))
+    assert whole <= pa._SLOTS_BUDGET and scratch + 2 * whole < 12 << 20, (
+        f"{(scratch + 2 * whole) / 2**20:.1f} MiB of the kernel's 16")
+
+
 # -- the llama decode step over the pool stored as the walk reads it -----------
 
 # (id, preset, widths the preset lacks, the cell's pages, slots, tp)

@@ -6,6 +6,7 @@ import dataclasses
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.engine.tokenizer import ByteTokenizer
@@ -176,3 +177,17 @@ def test_stats_name_the_walks_pages_per_turn(engines):
     assert not paged._use_pallas
     assert paged.stats()["kv_pages"]["pages_per_turn"] == 0
     assert "kv_pages" not in slot.stats()
+
+
+def test_stats_name_what_the_walk_keeps_in_flight(engines):
+    """`kv_pages.turns_in_flight` / `bytes_in_flight`: what the compiled walk
+    keeps started ahead of the turn it folds, from the rule that sizes its
+    scratch; an engine with no kernel reads 0 and 0. The rule itself at the
+    7B's geometry: three turns of eight 16 KB pages, K and V."""
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import fetches_in_flight
+
+    slot, paged = engines
+    pages = paged.stats()["kv_pages"]
+    assert (pages["turns_in_flight"], pages["bytes_in_flight"]) == (0, 0)
+    assert list(pages)[:6] == ["total", "free", "page_size", "pages_per_turn", "turns_in_flight", "bytes_in_flight"]
+    assert fetches_in_flight(16, jnp.bfloat16, 4, 128) == (3, 3 * 2 * 8 * 16 * 512 * 2)

@@ -368,6 +368,236 @@ def test_walk_parity_contexts_straddling_a_turn(dtype, plus_new):
         np.testing.assert_array_equal(out[~live], 0.0)
 
 
+# -- the walk as one stream of turns over every slot ---------------------------
+#
+# The fetches run RING - 1 turns ahead of the fold from the kernel's first turn
+# to its last, across slot boundaries: a slot's last turns are folded while the
+# next slots' first are in flight, and slots with nothing to walk are stepped
+# over. `_DEPTH` turns in flight; a turn is `_G` pages of 16 rows.
+
+_DEPTH, _G = 3, 8
+# pages a slot, by case: turn counts 0, 1, depth - 1, depth, depth + 1 and
+# 3 x depth + 1 with the empty slot first, last and between two long ones
+_TURNS = {
+    "empty-first": [0, 10, 1, 2, 3, 4],
+    "empty-last": [10, 4, 3, 2, 1, 0],
+    "empty-between-long": [10, 0, 10, 1, 0, 0, 4, 2, 0, 3],
+    "every-slot-empty": [0, 0, 0],
+    "one-slot-alone": [4],
+}
+_PAGES = {k: [t * _G for t in v] for k, v in _TURNS.items()} | {
+    "last-turns-of-one-page": [9 * _G + 1, 1, 0, 3 * _G + 1, _G + 1],
+    "last-turns-of-G-1-pages": [4 * _G - 1, _G - 1, 0, 10 * _G - 1],
+}
+
+
+def _stream_case(pages, dtype, seed=43, P=16, H=4, Hkv=2, d=8, int8=False):
+    """One batch whose slot ``s`` walks ``pages[s]`` pages (its last one
+    part-filled), scattered over a pool in which every page no block table
+    names is NaN (int8 pools: its scales are): a fetch of any page that is
+    not the walk's own fails loudly, and so does a row no fetch wrote
+    (uninitialised scratch is NaN in interpret mode). The tables' padding
+    names page 0, which is NaN too: nothing may read it."""
+    from agentcontrolplane_tpu.ops.quant import kv_quantize
+
+    rng = np.random.default_rng(seed)
+    S, max_pages = len(pages), max(max(pages), 1) + 3
+    seq_lens = np.asarray(
+        [0 if n == 0 else (n - 1) * P + 1 + (5 * s + 3) % P for s, n in enumerate(pages)], np.int32)
+    num_pages = sum(pages) + 7
+    k_pages = np.full((num_pages, P, Hkv, d), np.nan, np.float32)
+    v_pages = np.full((num_pages, P, Hkv, d), np.nan, np.float32)
+    named = np.zeros(num_pages, bool)
+    tables = np.full((S, max_pages), TRASH_PAGE, np.int32)
+    free = list(rng.permutation(np.arange(1, num_pages)))
+    order = [(s, j) for s in range(S) for j in range(pages[s])]
+    rng.shuffle(order)  # no walk reads a contiguous run
+    for s, j in order:
+        page = int(free.pop())
+        tables[s, j], named[page] = page, True
+        # whole pages are written: rows past seq_len hold finite stale data
+        k_pages[page] = rng.normal(size=(P, Hkv, d))
+        v_pages[page] = rng.normal(size=(P, Hkv, d))
+    as_dt = lambda x: jnp.asarray(x, dtype=dtype)  # noqa: E731
+    case = dict(
+        q=as_dt(rng.normal(size=(S, H, d))), k_pages=as_dt(k_pages), v_pages=as_dt(v_pages),
+        tables=jnp.asarray(tables), seq_lens=jnp.asarray(seq_lens),
+        k_new=as_dt(rng.normal(size=(S, Hkv, d))), v_new=as_dt(rng.normal(size=(S, Hkv, d))),
+        scales={}, clean_scales={},
+    )
+    # the reference gathers whole tables: it gets the pool with the unnamed
+    # pages zeroed (its mask then drops them exactly)
+    clean = lambda x: jnp.nan_to_num(x.astype(jnp.float32)).astype(x.dtype)  # noqa: E731
+    case["clean_k"], case["clean_v"] = clean(case["k_pages"]), clean(case["v_pages"])
+    if int8:
+        poison = jnp.where(jnp.asarray(named)[:, None, None], 1.0, jnp.nan)
+        case["k_pages"], ks = kv_quantize(case["clean_k"])
+        case["v_pages"], vs = kv_quantize(case["clean_v"])
+        case["clean_k"], case["clean_v"] = case["k_pages"], case["v_pages"]
+        case["scales"] = {"k_scales": ks * poison, "v_scales": vs * poison}
+        case["clean_scales"] = {"k_scales": ks, "v_scales": vs}
+    return case
+
+
+def _stream_parity(c, plus_new, atol, interpret=True, **kernel_kw):
+    from agentcontrolplane_tpu.ops.paged import paged_decode_attention_reference_cache_plus_new
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
+
+    args = [c["q"], c["k_pages"], c["v_pages"], c["tables"], c["seq_lens"]]
+    ref_args = [c["q"], c["clean_k"], c["clean_v"], c["tables"], c["seq_lens"]]
+    if plus_new:
+        kernel, reference = paged_decode_attention_cache_plus_new, paged_decode_attention_reference_cache_plus_new
+        args += [c["k_new"], c["v_new"]]
+        ref_args += [c["k_new"], c["v_new"]]
+    else:
+        kernel, reference = paged_decode_attention, paged_decode_attention_reference
+    out = np.asarray(kernel(*args, interpret=interpret, **c["scales"], **kernel_kw).astype(jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(reference(*ref_args, **c["clean_scales"]).astype(jnp.float32))
+    live = np.asarray(c["seq_lens"]) > 0
+    if plus_new:
+        live[:] = True  # the self term gives an empty slot its one token
+    assert np.isfinite(out[live]).all(), "a walk read a page that is not its own, or a row no fetch wrote"
+    np.testing.assert_allclose(out[live], ref[live], rtol=0, atol=atol)
+    if not plus_new:
+        # a slot with nothing to walk keeps the start state: acc 0 over the floor of l
+        np.testing.assert_array_equal(out[~live], 0.0)
+
+
+@pytest.mark.parametrize("plus_new", [False, True], ids=["plain", "cache-plus-new"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("order", list(_PAGES))
+def test_the_stream_of_turns_across_slots_matches_the_reference(order, dtype, plus_new):
+    from agentcontrolplane_tpu.engine.kernel_parity import TOLERANCE
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    assert (pa.RING - 1, pa.pages_per_turn(16, jnp.dtype(dtype), 2, 8)) == (_DEPTH, _G)
+    c = _stream_case(_PAGES[order], jnp.dtype(dtype))
+    _stream_parity(c, plus_new, {"float32": 1e-5, "bfloat16": TOLERANCE["bfloat16"]}[dtype])
+
+
+@pytest.mark.parametrize("order", ["empty-between-long", "empty-first", "every-slot-empty"])
+def test_a_batch_too_large_for_one_program_streams_in_several(order, monkeypatch):
+    """Where the slots' q and outputs do not fit VMEM together a program
+    takes a divisor of them (`slots_per_program`) and the next program the
+    next: each drains its own stream, tables and lengths read at its offset."""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    c = _stream_case(_PAGES[order], jnp.float32)
+    S = len(_PAGES[order])
+    assert pa.slots_per_program(S, 2, 2, 8, jnp.float32) == S
+    monkeypatch.setattr(pa, "_SLOTS_BUDGET", 40 << 10)
+    assert pa.slots_per_program(S, 2, 2, 8, jnp.float32) == {10: 2, 6: 2, 3: 1}[S]
+    _stream_parity(c, True, 1e-5)
+    _stream_parity(c, False, 1e-5)
+
+
+def test_the_stream_walks_int8_pages_a_page_a_turn():
+    """`G = 1`: every page a turn of its own, its scale rows fetched beside
+    it; the turn counts of the empty-between-long order in pages."""
+    c = _stream_case([10, 0, 13, 1, 0, 0, 4, 2, 0, 3], jnp.bfloat16, int8=True)
+    _stream_parity(c, True, 2e-2)
+
+
+def test_the_stream_walks_packed_heads_at_width_64():
+    """Two KV heads to a lane window (the wrapper's layout): the stream
+    underneath is the same."""
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import heads_per_window
+
+    assert heads_per_window(64, 2) == 2
+    c = _stream_case(_PAGES["empty-between-long"], jnp.float32, H=4, Hkv=2, d=64)
+    _stream_parity(c, True, 1e-5)
+    _stream_parity(c, False, 1e-5)
+
+
+def test_the_stream_walks_sp2_slices():
+    """Each rank walks its half of every page (f32, 8 rows a rank: 16 pages
+    a turn) and the ranks' states merge; empty slots first, between, last."""
+    from agentcontrolplane_tpu.ops.paged import paged_decode_attention_reference_cache_plus_new
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import paged_decode_attention_cache_plus_new_sharded
+    from agentcontrolplane_tpu.parallel.mesh import make_mesh
+
+    c = _stream_case([0, 65, 1, 0, 16, 17, 33, 0], jnp.float32)
+    mesh = make_mesh({"sp": 2, "tp": 1}, devices=jax.devices()[:2])
+    out = paged_decode_attention_cache_plus_new_sharded(
+        mesh, c["q"], _merged(c["k_pages"]), _merged(c["v_pages"]), c["tables"], c["seq_lens"],
+        c["k_new"], c["v_new"], interpret=True)
+    ref = paged_decode_attention_reference_cache_plus_new(
+        c["q"], c["clean_k"], c["clean_v"], c["tables"], c["seq_lens"], c["k_new"], c["v_new"])
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+def test_the_stream_walks_windows_whose_ring_wraps_inside_a_turn():
+    """The window walk (`starts`, `ring`): a ring of 17 pages a slot, walks
+    of 0 to 3 turns that begin anywhere in the ring and wrap inside a turn;
+    slots that have not reached a row yet walk nothing. Every page of the
+    pool that is not a slot's ring is NaN."""
+    from agentcontrolplane_tpu.ops import paged
+    from agentcontrolplane_tpu.ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
+
+    S, H, H_kv, d, P, W = 8, 4, 2, 8, 16, 256
+    ring = paged.ring_size(W, P)
+    lens = np.asarray([0, 1000, 15, 0, 256 + 130, 17 * 16 * 3 + 5, 0, 255], np.int32)
+    rng = np.random.default_rng(7)
+    NW = (S + 2) * ring
+    kp, vp = (rng.normal(size=(NW, P, H_kv * d)).astype(np.float32) for _ in range(2))
+    kp[S * ring:] = vp[S * ring:] = np.nan  # the pad slot's ring and beyond: nobody's
+    q = jnp.asarray(rng.normal(size=(S, H, d)), jnp.float32)
+    kn, vn = (jnp.asarray(rng.normal(size=(S, H_kv, d)), jnp.float32) for _ in range(2))
+    n = jnp.asarray(lens)
+    first = jnp.maximum(n + 1 - W, 0)
+    tables = paged.ring_tables(jnp.arange(S, dtype=jnp.int32), ring)
+    want = paged.paged_decode_attention_reference_cache_plus_new(
+        q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), tables, n, kn, vn,
+        row_positions=paged.ring_positions(n, ring, P), starts=first)
+    got = paged_decode_attention_cache_plus_new(
+        q, jnp.asarray(kp), jnp.asarray(vp), tables, n, kn, vn, interpret=True, starts=first, ring=ring)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+@pytest.mark.parametrize("order", ["empty-first", "empty-between-long", "last-turns-of-G-1-pages", "every-slot-empty"])
+def test_every_fetch_is_started_once_and_waited_for_once(order, monkeypatch):
+    """A wait on a fetch never started hangs the chip, and the interpreter
+    does not hang: so the starts and waits are recorded as they run (a
+    callback beside each) and held to the ring's discipline, semaphore by
+    semaphore: a turn's 2 x G starts, then its one wait, then the buffer's
+    next turn; as many waits as the batch has turns; nothing left started.
+    (Callbacks of one loop turn may run in any order, those of different
+    turns run in theirs: a buffer's events are never of one turn.)"""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    events = []
+    real = pa.pltpu.make_async_copy
+
+    class Spy:
+        def __init__(self, src, dst, sem):
+            self.copy, self.at = real(src, dst, sem), sem.transforms[-1].indices
+
+        def _note(self, kind):
+            jax.debug.callback(lambda k, b, i: events.append((int(k), int(b), int(i))), kind, *self.at)
+
+        def start(self):
+            self._note(0)
+            self.copy.start()
+
+        def wait(self):
+            self._note(1)
+            self.copy.wait()
+
+    monkeypatch.setattr(pa.pltpu, "make_async_copy", Spy)
+    c = _stream_case(_PAGES[order], jnp.float32)
+    out = paged_decode_attention(c["q"], c["k_pages"], c["v_pages"], c["tables"], c["seq_lens"], interpret=True)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    turns = sum(-(-n // _G) for n in _PAGES[order])
+    assert sum(k for k, _, _ in events) == turns
+    for buf in range(pa.RING):
+        mine = [k for k, b, _ in events if b == buf]
+        assert mine == ([0] * (2 * _G) + [1]) * (len(mine) // (2 * _G + 1)), (buf, mine)
+    assert len(events) == turns * (2 * _G + 1)
+
+
 @pytest.mark.parametrize(
     "P_local,dtype,H_kv,d,quantized,want",
     [
@@ -379,12 +609,25 @@ def test_walk_parity_contexts_straddling_a_turn(dtype, plus_new):
         (16, "int8", 4, 128, True, 1),        # int8 tile is 32 rows; scale rows per page
         (32, "int8", 4, 128, True, 1),
         (16, "float32", 32, 128, False, 4),   # MHA 32 heads f32: scratch over budget, halved
+        (16, "float32", 64, 128, False, 2),   # and halved again
+        # the cells: the 7B and mellum2, a chip of the 32B at tp=4, lfm2's
+        # four lane windows of two heads of 64, jamba2's one KV head
+        (16, "bfloat16", 2, 128, False, 8),
+        (16, "bfloat16", 4, 128, False, 8),   # lfm2: 8 KV heads of 64 walk as 4 windows of 128
+        (16, "bfloat16", 1, 128, False, 8),
     ],
 )
 def test_pages_per_turn_rule(P_local, dtype, H_kv, d, quantized, want):
-    from agentcontrolplane_tpu.ops.pallas.paged_attention import pages_per_turn
+    """G by geometry, and beside it what the walk keeps in flight: RING - 1
+    turns of G pages, K and V, whatever a turn's bytes (deeper rings
+    measured slower on the chip: PERF.md, PR 43), in a ring that holds to
+    the scratch budget."""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
 
-    assert pages_per_turn(P_local, jnp.dtype(dtype), H_kv, d, quantized) == want
+    assert pa.pages_per_turn(P_local, jnp.dtype(dtype), H_kv, d, quantized) == want
+    turn = 2 * want * P_local * H_kv * d * jnp.dtype(dtype).itemsize
+    assert pa.fetches_in_flight(P_local, jnp.dtype(dtype), H_kv, d, quantized) == (pa.RING - 1, (pa.RING - 1) * turn)
+    assert pa.RING * turn <= pa._SCRATCH_BUDGET
 
 
 def test_excluded_geometry_walks_one_page_a_turn():
@@ -404,8 +647,9 @@ def test_excluded_geometry_walks_one_page_a_turn():
         )
     )(case)
     (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    k_buf = call.params["jaxpr"].invars[-5].aval  # k_buf, v_buf, ks_buf, vs_buf, sems
-    assert k_buf.shape == (pa.NBUF, 16, 2 * 8), k_buf.shape
+    kv_buf = call.params["jaxpr"].invars[-5].aval  # kv_buf, sc_buf, sems, turns, next
+    assert kv_buf.shape == (pa.RING, 2, 16, 2 * 8), kv_buf.shape
+    assert pa.fetches_in_flight(16, jnp.int8, 2, 8, True) == (pa.RING - 1, (pa.RING - 1) * 2 * 16 * 16)
     assert page_walk_parity(case, plus_new=True, interpret=True)["ok"]
 
 

@@ -67,6 +67,22 @@ def test_compiled_page_walk_matches_reference(tpu, case):
     assert got["ok"], got
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16-pages", "int8-pages"])
+@pytest.mark.parametrize("order", ["empty-first", "empty-between-long", "every-slot-empty", "last-turns-of-G-1-pages"])
+def test_compiled_stream_across_slots_matches_reference(tpu, order, int8):
+    """The walk's one stream of turns COMPILED: slots of 0 to 3 x depth + 1
+    turns with empty ones first, between and last, every page no table
+    names NaN. A wait on a fetch that was never started hangs here, where
+    the interpreter (`tests/engine/test_paged.py`) cannot."""
+    import jax.numpy as jnp
+
+    from .test_paged import _PAGES, _stream_case, _stream_parity
+
+    c = _stream_case(_PAGES[order], jnp.bfloat16, H=8, Hkv=2, d=128, int8=int8)
+    for plus_new in (False, True):
+        _stream_parity(c, plus_new, 2e-2, interpret=False)
+
+
 def test_engine_slot_and_paged_agree_on_tpu(tpu):
     """Greedy decode through BOTH kv layouts on hardware must produce the
     same tokens (the paged path uses the compiled Pallas kernel: engine
