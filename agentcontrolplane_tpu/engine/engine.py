@@ -49,11 +49,10 @@ import numpy as np
 from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
 from ..observability import scopes
 from ..observability.metrics import REGISTRY
-from ..ops.paged import TRASH_PAGE, ring_size, set_pages
+from ..ops.paged import TRASH_PAGE, pool_leaves, ring_size, set_pages
 from ..ops.sampling import NEG_INF, masks_wanted, sample
 from ..parallel.mesh import (
     kv_cache_shardings,
-    param_shardings,
     serving_mesh,
 )
 from .lanes import DECODE, PREFILL, VERIFY, dispatch_key
@@ -285,6 +284,12 @@ def _next_bucket(n: int, buckets: Sequence[int]) -> int:
         if n <= b:
             return b
     return buckets[-1]
+
+
+def _a_leaf(tree: dict):
+    """One page-shaped leaf of a cache or of rows taken from it, whatever
+    the family names its leaves: what a profiler record blocks on."""
+    return next(iter(pool_leaves(tree).values()))
 
 
 def _pow2_sizes(n: int) -> list[int]:
@@ -672,6 +677,18 @@ class Engine:
         )
         tp = dict(self.mesh.shape).get("tp", 1)
         sp = dict(self.mesh.shape).get("sp", 1)
+        # what the family does not serve it says itself (models.programs
+        # `refusals`): refused here, in words, and never served wrongly
+        asked = {
+            "kv_layout": kv_layout, "spec_len": spec_len, "tp": tp, "sp": sp,
+            "quantize_weights": bool(quantize) or quantize_weights, "quantize_kv": bool(quantize_kv),
+            "coordination": coordination is not None, "host_kv_bytes": host_kv_bytes,
+        }
+        for hit, why in self._model.refusals(asked):
+            if hit:
+                raise ValueError(
+                    f"the {self._model.family} family does not serve with {why}"
+                )
         if tp > 1 and self.config.n_kv_heads % tp:
             raise ValueError(
                 f"n_kv_heads={self.config.n_kv_heads} cannot shard over tp={tp} "
@@ -711,31 +728,6 @@ class Engine:
                 "lower --tpu-ctx to the window size (a family with a window "
                 "cache beside its pages, models/mellum.py, serves past it)"
             )
-        if self._has_state:
-            # per-slot state beside the pages: what the engine cannot yet do
-            # for this family it refuses here, in words, and never serves
-            # wrongly
-            refused = [
-                (kv_layout != "paged", "kv_layout='slot': its state lives beside the paged pool; serve it with kv_layout='paged'"),
-                (spec_len > 0, "spec_len > 0: speculation needs the per-slot state rolled back on a rejected draft"),
-                (tp > 1 or sp > 1, "tensor or context parallelism: its weights and state have no sharding here; serve it on a tp=1 mesh"),
-                (bool(quantize) or quantize_weights, "weight-only int8: its matrices are served in the dtype they were made in"),
-                (coordination is not None, "multi-host lockstep serving"),
-            ]
-            if self._window_cache:
-                # the window layers' ring is rebuilt by a prefill and copied
-                # nowhere: whatever would restore a slot's pages without it
-                # is refused, so that no slot is ever served with its
-                # full-layer pages restored and its ring not
-                refused += [
-                    (host_kv_bytes > 0, "host_kv_bytes > 0: a swapped-out slot's window cache is not carried to the host and back"),
-                    (quantize_kv, "quantize_kv: its window cache and its pages are kept in the model's dtype"),
-                ]
-            for hit, why in refused:
-                if hit:
-                    raise ValueError(
-                        f"the {self._model.family} family does not serve with {why}"
-                    )
         self.prefill_batch_max = max(1, prefill_batch_max)
         # decode dispatch widths: smallest bucket covering the active slots
         # (each width is its own jit cache entry; keep the set small so cold
@@ -761,9 +753,11 @@ class Engine:
             _init = self._model.init_params
             abstract = jax.eval_shape(lambda k: _init(config, k), jax.random.key(0))
             shardings = (
+                # a family that gives no layout of its own over a mesh is
+                # held whole (tp=1 only: it refuses the rest above)
                 jax.tree_util.tree_map(lambda _: self._replicated, abstract)
-                if self._has_state  # tp=1 only (refused above): all whole
-                else param_shardings(self.mesh, config, abstract)
+                if self._model.shardings is None
+                else self._model.shardings.params(self.mesh, config, abstract)
             )
             params = jax.jit(
                 lambda k: _init(config, k), out_shardings=shardings
@@ -846,13 +840,21 @@ class Engine:
             # f32 scale rows with each fetch and applies them in VMEM
             # (paged_attention.py), so the pool stays int8 in HBM and decode
             # keeps the no-gather path.
-            from ..ops.pallas.paged_attention import heads_per_window
-
-            self._use_pallas = jax.default_backend() == "tpu" and bool(
-                heads_per_window(
-                    config.head_dim, config.n_kv_heads // tp, self.quantize_kv
-                )
-            )
+            # The walk is the family's (models.programs `walk`): the page
+            # walk over K and V pages at a head geometry, or the latent
+            # walk over a row a token. Its width is static, chosen by the
+            # kernel from what it sees on one device (rows of a page a rank
+            # holds, page dtype, KV heads a chip): report the G this
+            # engine's walks compile with, so a geometry that fell to one
+            # page a turn is seen in stats() and the log, not guessed, and
+            # beside it what the walk keeps started ahead of the turn it
+            # folds, from the same rule that sizes its scratch. None: no
+            # kernel takes this geometry (0 in stats(): the reference).
+            axes = dict(self.mesh.shape)
+            leaf = self.cache[self._model.page_leaf]  # the walk's own: [L, num_pages, page_size, ...]
+            rows = leaf.shape[2] // axes.get("sp", 1)  # of a page, a rank
+            walk = self._model.walk(config, rows, leaf.dtype, tp, self.quantize_kv)
+            self._use_pallas = jax.default_backend() == "tpu" and walk is not None
             if jax.default_backend() == "tpu" and not self._use_pallas:
                 reason = "head_dim"
                 log.warning(
@@ -862,7 +864,8 @@ class Engine:
                     f"head_dim {config.head_dim} with {config.n_kv_heads // tp} "
                     f"KV heads a chip{' and int8 pages' if self.quantize_kv else ''} "
                     "is none of the walk's geometries (128 or 256 wide, or 64 "
-                    "wide with bf16/f32 pages and an even number of KV heads)",
+                    "wide with bf16/f32 pages and an even number of KV heads; "
+                    "a latent row: whole pages of rows whose value is whole lane tiles)",
                 )
                 # a silent perf cliff deserves a first-class signal: count
                 # it and drop a flight breadcrumb so dashboards and dumps
@@ -879,26 +882,9 @@ class Engine:
                     "paged-decode path dispatches the int8 Pallas walk",
                 )
                 self._kernel_fallback_reason = reason
-            # The walk's width is static, chosen by the kernel from what it
-            # sees on one device (rows of a page a rank holds, page dtype,
-            # KV heads a chip): report the G this engine's walks compile
-            # with, so a geometry that fell to one page a turn is seen in
-            # stats() and the log, not guessed. 0: no kernel (reference).
-            # Beside it what the walk keeps started ahead of the turn it
-            # folds, from the same rule that sizes its scratch.
             self.pages_per_turn = self.turns_in_flight = self.bytes_in_flight = 0
             if self._use_pallas:
-                from ..ops.pallas.paged_attention import fetches_in_flight, pages_per_turn
-
-                axes = dict(self.mesh.shape)
-                k = self.cache["k"]  # [L, num_pages, page_size, ...]
-                rows = k.shape[2] // axes.get("sp", 1)  # of a page, a rank
-                geometry = (
-                    rows, k.dtype, config.n_kv_heads // axes.get("tp", 1),
-                    config.head_dim, self.quantize_kv,
-                )
-                self.pages_per_turn = pages_per_turn(*geometry)
-                self.turns_in_flight, self.bytes_in_flight = fetches_in_flight(*geometry)
+                self.pages_per_turn, self.turns_in_flight, self.bytes_in_flight = walk
                 log.info(
                     "paged decode: Pallas page walk, pages_per_turn=%d "
                     "(%d tokens a turn), turns_in_flight=%d, bytes_in_flight=%d",
@@ -1499,39 +1485,21 @@ class Engine:
                 out_shardings=kv_cache_shardings(self.mesh, self.quantize_kv),
             )()
         else:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
             from ..ops.paged import PageAllocator
 
-            sp_axis = (
-                "sp"
-                if "sp" in self.mesh.axis_names and dict(self.mesh.shape)["sp"] > 1
-                else None
-            )
-            # [L, num_pages, page_size, H_kv * d]: the row's KV heads over
-            # tp (H_kv / tp heads of d contiguous lanes a chip); within-page
-            # over sp (context-parallel paged serving — page ids stay
-            # rank-local, each rank holds a slice of every page)
-            page_spec = P(None, None, sp_axis, "tp")
-            page_shardings = {
-                "k": NamedSharding(self.mesh, page_spec),
-                "v": NamedSharding(self.mesh, page_spec),
-            }
-            if self.quantize_kv:
-                # scale twins [L, NP, P, H_kv]: a chip's heads, like the row
-                page_shardings["ks"] = page_shardings["k"]
-                page_shardings["vs"] = page_shardings["v"]
             init_cache = lambda: self._model.init_paged_cache(  # noqa: E731
                 self.config, self.num_pages, self.page_size,
                 quantize_kv=self.quantize_kv, max_slots=self.max_slots,
             )
-            if self._has_state:
+            if self._model.shardings is None:
                 # the family's own pool layout and whatever tree it keeps
                 # beside the pages under "state", whole on every device
                 # (tp=1, refused otherwise at construction)
                 page_shardings = jax.tree_util.tree_map(
                     lambda _: self._replicated, jax.eval_shape(init_cache)
                 )
+            else:
+                page_shardings = self._model.shardings.paged_pool(self.mesh, self.quantize_kv)
             # a family's device counters start from 0 again (and wrap at
             # 2**32): stats() sums their differences into _counters_total
             self._counters_seen = None
@@ -3442,7 +3410,7 @@ class Engine:
             prof_t0 = self.profiler.start()
             self.cache = fn(self.cache, ids, blocks)
             self.profiler.record(
-                f"swap_scatter[{m}]", prof_t0, out=self.cache["k"],
+                f"swap_scatter[{m}]", prof_t0, out=_a_leaf(self.cache),
                 real_tokens=m * P,
             )
         REGISTRY.counter_add(
@@ -3472,10 +3440,7 @@ class Engine:
         if n <= 0:
             sl.swap_staged = None
             return
-        rows = {"k": entry.k, "v": entry.v}
-        if "ks" in self.cache:
-            rows["ks"] = entry.k_scale
-            rows["vs"] = entry.v_scale
+        rows = entry.rows
         P = self.page_size
         pages = self._slot_pages[slot][start // P : (start + n) // P]
         groups: list = []
@@ -3826,7 +3791,7 @@ class Engine:
             {name: entry[name] for name in self.cache},
         )
         self.profiler.record(
-            f"prefix_copy[{cut}]", prof_t0, out=self.cache["k"],
+            f"prefix_copy[{cut}]", prof_t0, out=_a_leaf(self.cache),
             real_tokens=cut, real_slots=1,
         )
 
@@ -3892,7 +3857,7 @@ class Engine:
                 prof_t0 = self.profiler.start()
                 rows = fn(self.cache, jnp.int32(slot))
                 self.profiler.record(
-                    f"prefix_extract[{cut}]", prof_t0, out=rows["k"],
+                    f"prefix_extract[{cut}]", prof_t0, out=_a_leaf(rows),
                     real_tokens=cut, real_slots=1,
                 )
             entry = {"cut": cut, **rows}
@@ -5265,7 +5230,7 @@ class Engine:
                     else v_out[0] if v_out is not None
                     else f_out[0] if f_out is not None
                     else p_out[0] if p_out is not None
-                    else cache["k"]  # chunks-only: block on the committed KV
+                    else _a_leaf(cache)  # chunks-only: block on the committed KV
                 )
                 self.profiler.record(
                     key, prof_t0, out=out_probe, real_tokens=real,
@@ -6159,8 +6124,7 @@ class Engine:
             out = self._extract_rows(slot, cut)
         entry = HostKVEntry(
             rid=f"handoff-{req.rid}", tokens=tuple(row[:cut]),
-            k=out["k"], v=out["v"],
-            k_scale=out.get("ks"), v_scale=out.get("vs"),
+            rows=out,
             state=self._saved_state(slot, host=True) if self._has_state else None,
         )
         self.flight.record(
@@ -6240,8 +6204,7 @@ class Engine:
             entry = sl.swap_entry
             if entry.rid != req.rid:
                 entry = HostKVEntry(
-                    rid=req.rid, tokens=entry.tokens, k=entry.k, v=entry.v,
-                    k_scale=entry.k_scale, v_scale=entry.v_scale,
+                    rid=req.rid, tokens=entry.tokens, rows=entry.rows,
                     state=entry.state,
                 )
             cut = entry.cut
@@ -6255,8 +6218,7 @@ class Engine:
                 rows = self._extract_rows(slot, cut)
             entry = HostKVEntry(
                 rid=req.rid, tokens=tuple(row[:cut]),
-                k=rows["k"], v=rows["v"],
-                k_scale=rows.get("ks"), v_scale=rows.get("vs"),
+                rows=rows,
                 state=self._saved_state(slot, host=True) if self._has_state else None,
             )
         if not pool.put(entry):
@@ -6279,11 +6241,12 @@ class Engine:
 
     @_in_phase("launch")
     def _extract_pages(self, pages: list[int]) -> dict[str, np.ndarray]:  # acp: megastep-seam # acp: kv-seam
-        """Gather paged KV pages to host numpy, token-major
-        ``{"k"/"v": [L, tokens, H_kv * d]}`` (the pool's rows) plus ``"ks"/"vs":
-        [L, tokens, H_kv]`` scale
-        rows when the pool is quantized (the host tier carries the int8
-        bytes verbatim — no requantization round trip). Dispatches
+        """Gather paged KV pages to host numpy, token-major, every leaf of
+        the pool under its own name: ``{"k"/"v": [L, tokens, H_kv * d]}``
+        (the pool's rows) plus ``"ks"/"vs": [L, tokens, H_kv]`` scale rows
+        when the pool is quantized (the host tier carries the int8 bytes
+        verbatim — no requantization round trip), ``{"kv": [L, tokens,
+        width]}`` of a latent pool. Dispatches
         decompose into pow2 page counts (bounded jit entries); the
         device->host copies are issued async and joined at the end so the
         DMA overlaps the remaining gathers."""
@@ -6295,16 +6258,14 @@ class Engine:
             fn = self._jit_swap_gather.get(n)
             if fn is None:
                 fn = jax.jit(
-                    lambda c, ids: {
-                        name: a[:, ids] for name, a in c.items() if name != "state"
-                    }
+                    lambda c, ids: {name: a[:, ids] for name, a in pool_leaves(c).items()}
                 )
                 self._jit_swap_gather[n] = fn
             ids = np.asarray(pages[i : i + n], dtype=np.int32)
             prof_t0 = self.profiler.start()
             out = fn(self.cache, self._put(ids))
             self.profiler.record(
-                f"swap_gather[{n}]", prof_t0, out=out["k"], real_tokens=n * P
+                f"swap_gather[{n}]", prof_t0, out=_a_leaf(out), real_tokens=n * P
             )
             chunks.append(out)
             i += n
@@ -6350,7 +6311,7 @@ class Engine:
             prof_t0 = self.profiler.start()
             out = fn(self.cache, jnp.int32(slot), jnp.int32(start))
             self.profiler.record(
-                f"swap_extract[{n}]", prof_t0, out=out["k"], real_tokens=n
+                f"swap_extract[{n}]", prof_t0, out=_a_leaf(out), real_tokens=n
             )
             chunks.append(out)
             start += n
@@ -6371,13 +6332,10 @@ class Engine:
         chunks). Returns the engine-thread seconds spent blocked in the
         host->device copies (the host_stall phase input)."""
         t0 = time.monotonic()
-        rows = {"k": entry.k, "v": entry.v}
-        if "ks" in self.cache:
-            # quantized cache: the entry MUST carry matching scale rows (a
-            # bf16 entry cannot restore into an int8 pool) — _swap_out on a
-            # quantized engine always records them
-            rows["ks"] = entry.k_scale
-            rows["vs"] = entry.v_scale
+        # the entry's leaves are the cache's own (a quantized cache's entry
+        # carries its scale rows: a bf16 entry cannot restore into an int8
+        # pool) — _swap_out records whatever leaves the cache has
+        rows = entry.rows
         if self.kv_layout == "paged":
             P = self.page_size
             pages = self._slot_pages[slot][start // P : (start + n) // P]
@@ -6407,7 +6365,7 @@ class Engine:
                     {name: self._put(b) for name, b in blocks.items()},
                 )
                 self.profiler.record(
-                    f"swap_scatter[{m}]", prof_t0, out=self.cache["k"],
+                    f"swap_scatter[{m}]", prof_t0, out=_a_leaf(self.cache),
                     real_tokens=m * P,
                 )
                 i += m
@@ -6438,7 +6396,7 @@ class Engine:
                     },
                 )
                 self.profiler.record(
-                    f"swap_restore[{m}]", prof_t0, out=self.cache["k"],
+                    f"swap_restore[{m}]", prof_t0, out=_a_leaf(self.cache),
                     real_tokens=m,
                 )
                 pos += m

@@ -211,21 +211,25 @@ def _verify_quantized_cache(engine) -> list[str]:
     keys = set(engine.cache) - {"state"}
     if getattr(engine, "_window_cache", False):
         keys -= {"wk", "wv"}
+    # the pool's leaves as the family names them (k and v; a latent pool's
+    # one leaf): a scale twin is a leaf named after a value leaf plus "s"
+    twins = {name for name in keys if name.endswith("s") and name[:-1] in keys}
+    values = keys - twins
     if not engine.quantize_kv:
-        if keys != {"k", "v"}:
+        if twins or not values:
             problems.append(
                 f"quantize_kv off but the cache carries keys {sorted(keys)} "
                 "— scale storage must not exist on the bit-identical path"
             )
         return problems
-    if keys != {"k", "v", "ks", "vs"}:
+    if {name + "s" for name in values} != twins:
         problems.append(
             f"quantize_kv on but the cache carries keys {sorted(keys)} "
-            "(want k/v int8 values + ks/vs scale rows)"
+            "(want int8 value leaves, each with its scale rows: k/v + ks/vs)"
         )
         return problems
     c = engine.config
-    for name in ("k", "v"):
+    for name in sorted(values):
         val, sc = engine.cache[name], engine.cache[name + "s"]
         if str(val.dtype) != "int8":
             problems.append(

@@ -24,12 +24,28 @@ apart: state without counters and counters without state both serve.
 cache a slot, the window layers' ring, fixed to the slot and written by the
 programs alone: it takes ``lanes`` as a family with state does, but nothing
 of it can be saved or installed, so the engine refuses what would need
-that (``engine.py``'s list).
+that.
+
+What the engine needs to know of a family beyond its programs it asks here
+too, and branches on no family's name or cache kind: ``refusals(asked)``
+gives the options a family does not serve, each with its reason in words
+(``asked``: what the engine was constructed with; the engine raises on the
+first that hits), and ``shardings`` is the family's own layout over a mesh
+(``params(mesh, config, abstract)``, ``paged_pool(mesh, quantize_kv)``) or
+None: a family that gives none has its weights and its whole cache tree held
+whole on every device (``models.kanana`` is such a family with no state a
+slot: a latent row a token in the pool, counters beside it). ``walk(config,
+page_rows, dtype, tp, quantize_kv)`` names the compiled walk a decode step
+takes on a TPU as ``(pages_per_turn, turns_in_flight, bytes_in_flight)``, or
+None where the geometry falls to the XLA reference; ``page_leaf`` names the
+leaf of the cache whose pages that walk fetches (the engine reads a page's
+rows and dtype off it; a family may keep other leaves beside it, as
+``mellum``'s rings, that are not shaped so).
 """
 
 from types import SimpleNamespace
 
-from . import jamba, lfm2, llama, mellum
+from . import jamba, kanana, lfm2, llama, mellum
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -40,18 +56,19 @@ from .llama import (
     prefill,
 )
 from .jamba import JambaConfig
+from .kanana import KananaConfig
 from .lfm2 import Lfm2Config
 from .mellum import MellumConfig
 
 __all__ = [
-    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "decode_step", "forward", "init_kv_cache",
+    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "decode_step", "forward", "init_kv_cache",
     "init_params", "prefill", "preset", "programs",
 ]
 
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana)]
     for table in tables:
         if name in table:
             return table[name]
@@ -59,8 +76,79 @@ def preset(name: str):
     raise KeyError(f"unknown model preset {name!r}; known: {', '.join(known)}")
 
 
+def _kv_walk(config, page_rows: int, dtype, tp: int, quantize_kv: bool):
+    """The page walk over K and V pages at the config's head geometry."""
+    from ..ops.pallas.paged_attention import fetches_in_flight, heads_per_window, pages_per_turn
+
+    heads = config.n_kv_heads // tp
+    if not heads_per_window(config.head_dim, heads, quantize_kv):
+        return None
+    geometry = (page_rows, dtype, heads, config.head_dim, quantize_kv)
+    return (pages_per_turn(*geometry), *fetches_in_flight(*geometry))
+
+
+def _latent_walk(config, page_rows: int, dtype, tp: int, quantize_kv: bool):
+    """The latent walk: one leaf, one fetch a page, the row both key and value."""
+    from ..ops.pallas.paged_attention import fetches_in_flight, latent_walk_serves, pages_per_turn
+
+    if not latent_walk_serves(config.head_dim, config.kv_lora_rank, page_rows, dtype):
+        return None
+    geometry = (page_rows, dtype, 1, config.head_dim, False, 1)
+    return (pages_per_turn(*geometry), *fetches_in_flight(*geometry))
+
+
+def _llama_shardings() -> SimpleNamespace:
+    """The dense family's own layout over a mesh (``parallel/mesh.py``,
+    which imports this package for ``LlamaConfig``: so it is imported when
+    a layout is asked for, not while this module is being made)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def params(mesh, config, abstract) -> dict:
+        from ..parallel.mesh import param_shardings
+
+        return param_shardings(mesh, config, abstract)
+
+    def paged_pool(mesh, quantize_kv: bool) -> dict:
+        # [L, num_pages, page_size, H_kv * d]: the row's KV heads over tp
+        # (H_kv / tp heads of d contiguous lanes a chip); within-page over
+        # sp (context-parallel paged serving: page ids stay rank-local, each
+        # rank holds a slice of every page); int8 scale twins [L, NP, P,
+        # H_kv] a chip's heads, like the row
+        sp = "sp" if "sp" in mesh.axis_names and dict(mesh.shape)["sp"] > 1 else None
+        spec = NamedSharding(mesh, P(None, None, sp, "tp"))
+        return {name: spec for name in (("k", "v", "ks", "vs") if quantize_kv else ("k", "v"))}
+
+    return SimpleNamespace(params=params, paged_pool=paged_pool)
+
+
+def _stateful_refusals(window_cache: bool):
+    """What a family with state a slot does not serve; a window cache adds
+    what would restore a slot's pages without its ring."""
+    def refusals(asked: dict) -> list:
+        refused = [
+            (asked["kv_layout"] != "paged", "kv_layout='slot': its state lives beside the paged pool; serve it with kv_layout='paged'"),
+            (asked["spec_len"] > 0, "spec_len > 0: speculation needs the per-slot state rolled back on a rejected draft"),
+            (asked["tp"] > 1 or asked["sp"] > 1, "tensor or context parallelism: its weights and state have no sharding here; serve it on a tp=1 mesh"),
+            (asked["quantize_weights"], "weight-only int8: its matrices are served in the dtype they were made in"),
+            (asked["coordination"], "multi-host lockstep serving"),
+        ]
+        if window_cache:
+            # the window layers' ring is rebuilt by a prefill and copied
+            # nowhere: whatever would restore a slot's pages without it is
+            # refused, so that no slot is ever served with its full-layer
+            # pages restored and its ring not
+            refused += [
+                (asked["host_kv_bytes"] > 0, "host_kv_bytes > 0: a swapped-out slot's window cache is not carried to the host and back"),
+                (asked["quantize_kv"], "quantize_kv: its window cache and its pages are kept in the model's dtype"),
+            ]
+        return refused
+
+    return refusals
+
+
 _LLAMA = SimpleNamespace(
     family="llama", has_state=False, window_cache=False, counters=None,
+    refusals=lambda asked: [], shardings=_llama_shardings(), walk=_kv_walk, page_leaf="k",
     init_params=llama.init_params,
     init_kv_cache=llama.init_kv_cache, prefill_batch=llama.prefill_batch,
     prefill_continue=llama.prefill_continue, prefill_continue_kv=llama.prefill_continue_kv,
@@ -79,6 +167,7 @@ def _with_state(family: str, m, window_cache: bool = False) -> SimpleNamespace:
     after the page ids; the engine hands both as one pair."""
     return SimpleNamespace(
         family=family, has_state=True, window_cache=window_cache,
+        refusals=_stateful_refusals(window_cache), shardings=None, walk=_kv_walk, page_leaf="k",
         init_params=m.init_params,
         init_paged_cache=m.init_paged_cache,
         prefill_paged_batch=lambda params, cache, tokens, lengths, ids, config: (
@@ -96,7 +185,20 @@ def _with_state(family: str, m, window_cache: bool = False) -> SimpleNamespace:
 _LFM2 = _with_state("lfm2", lfm2)
 _JAMBA = _with_state("jamba", jamba)
 _MELLUM = _with_state("mellum", mellum, window_cache=True)
-_FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM}
+# no state a slot (its programs take the page ids alone, as the dense
+# family's), counters on the device, its own pool: a latent row a token
+_KANANA = SimpleNamespace(
+    family="kanana", has_state=False, window_cache=False,
+    refusals=kanana.refusals, shardings=None, walk=_latent_walk, page_leaf="kv",
+    init_params=kanana.init_params, init_paged_cache=kanana.init_paged_cache,
+    prefill_paged_batch=kanana.prefill_paged_batch,
+    prefill_paged_continue=kanana.prefill_paged_continue,
+    prefill_paged_continue_kv=kanana.prefill_paged_continue_kv,
+    decode_step_paged=kanana.decode_step_paged,
+    counters=kanana.counters, describe_counters=kanana.describe_counters,
+)
+_FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM,
+             KananaConfig: _KANANA}
 
 
 def programs(config) -> SimpleNamespace:

@@ -407,7 +407,7 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     x, ends, snaps, new_k, new_v = _run_rows(
         params, c, _embed(params, tokens, c), ctx, *_zero_state(c, B),
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions))
-    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
     cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, _prefill_counts(c, lengths))
     x = _final_norm(x, params, c)
     return cache, _head_logits(x, params, c, last=lengths)
@@ -450,7 +450,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     prompt): -> (cache, last-token logits [B, V])."""
     x, new_k, new_v, ends, snaps, snap_ok = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
     cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
     return cache, _head_logits(x, params, config, last=lengths)
 
@@ -460,7 +460,7 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
     """The continuation's writes without the head (a mid chunk)."""
     _x, new_k, new_v, ends, snaps, snap_ok = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
     return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
 
 
@@ -529,7 +529,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         c.layer_types, (_embed(params, tokens[:, None], c), st["ssm"], st["conv"]), layer)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-        pages = commit_tokens(pool, *outs["attention"], target, seq_lens % P)
+        pages = commit_tokens(pool, dict(zip(("k", "v"), outs["attention"])), target, seq_lens % P)
         counts = _counts(c, active, active, active)
         state = {"ssm": h_all, "conv": conv_all, "snap": st["snap"], "counters": st["counters"].at[0].add(counts)}
     x = _final_norm(x[:, 0], params, c)

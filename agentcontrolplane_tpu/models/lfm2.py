@@ -597,7 +597,7 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     x, ends, snaps, new_k, new_v, counts = _run_layers(
         params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions), route)
-    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
     cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, 1)
     x = _final_norm(x, params, c)
     return cache, _head_logits(x, params, c, last=lengths)
@@ -640,7 +640,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     prompt): -> (cache, last-token logits [B, V])."""
     x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
     cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
     return cache, _head_logits(x, params, config, last=lengths)
 
@@ -650,7 +650,7 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
     """The continuation's writes without the head (a mid chunk)."""
     _x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), new_k, new_v, page_ids)
+    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
     return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
 
 
@@ -690,7 +690,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route, by_kind=True)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-        pages = commit_tokens(pool, new_k[:, :, 0], new_v[:, :, 0], target, seq_lens % P)
+        pages = commit_tokens(pool, {"k": new_k[:, :, 0], "v": new_v[:, :, 0]}, target, seq_lens % P)
         conv = st["conv"].at[:, :S].set(
             jnp.where(active[None, :, None, None], ends.astype(st["conv"].dtype), st["conv"][:, :S]))
         cache = {**pages, "state": {"conv": conv, "snap": st["snap"], "moe": st["moe"].at[0].add(counts)}}

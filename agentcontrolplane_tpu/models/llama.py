@@ -856,7 +856,7 @@ def prefill_paged_batch(
     # scatter of whole pages commits all layers' blocks through the pool
     # flattened over its layers (ops/paged.py) — see prefill_batch/decode_step
     x, (new_k, new_v) = jax.lax.scan(body, x, params["layers"])
-    pages = commit_whole_pages(pages, new_k, new_v, page_ids)
+    pages = commit_whole_pages(pages, {"k": new_k, "v": new_v}, page_ids)
     x = _final_norm(x, params, c)
     logits = _head_logits(x, params, c, last=lengths)
     return pages, logits
@@ -975,7 +975,7 @@ def prefill_paged_continue(
         params, pages, tokens, lengths, starts, block_tables, config
     )
     # one scatter commits the suffix blocks for every layer
-    pages = commit_whole_pages(pages, new_k, new_v, page_ids)
+    pages = commit_whole_pages(pages, {"k": new_k, "v": new_v}, page_ids)
     logits = _head_logits(x, params, config, last=lengths)
     return pages, logits
 
@@ -996,7 +996,7 @@ def prefill_paged_continue_kv(
     new_k, new_v, _x = _paged_continue_forward(
         params, pages, tokens, lengths, starts, block_tables, config
     )
-    return commit_whole_pages(pages, new_k, new_v, page_ids)
+    return commit_whole_pages(pages, {"k": new_k, "v": new_v}, page_ids)
 
 
 def verify_paged_continue(
@@ -1024,7 +1024,7 @@ def verify_paged_continue(
     )
     with scopes.layer("commit"):
         target, offset = token_write_targets(block_tables, starts, lengths, P, T)
-        pages = commit_tokens(pages, new_k, new_v, target, offset)
+        pages = commit_tokens(pages, {"k": new_k, "v": new_v}, target, offset)
     return pages, _head_logits(x, params, config)
 
 
@@ -1105,7 +1105,7 @@ def decode_step_paged(
     with scopes.layer("commit"):
         target = block_tables[jnp.arange(S), seq_lens // P]
         target = jnp.where(active, target, TRASH_PAGE)
-        pages = commit_tokens(pages, new_k, new_v, target, seq_lens % P)
+        pages = commit_tokens(pages, {"k": new_k, "v": new_v}, target, seq_lens % P)
     x = _final_norm(x[:, 0], params, c)
     logits = _head_logits(x, params, c)
     return pages, logits

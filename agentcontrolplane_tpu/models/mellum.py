@@ -51,7 +51,7 @@ the rows its first queries need before it overwrites them.
 The programs take ``lanes = (slots, snap_at)`` as every family with state a
 slot does; ``snap_at`` is not used (nothing of the ring is snapshot: a
 prefix entry, a park and a host swap are refused by the engine for this
-family, ``engine.py``'s list).
+family: ``models.programs``' ``refusals``).
 """
 
 from __future__ import annotations
@@ -372,9 +372,9 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     x, wk, wv, fk, fv, counts = _run_layers(
         params, c, _embed(params, tokens, c), positions, valid, make_attn, route, keep)
     with scopes.layer("commit"):
-        full = commit_whole_pages(full, fk, fv, page_ids)
+        full = commit_whole_pages(full, {"k": fk, "v": fv}, page_ids)
         with jax.named_scope("window_commit"):
-            win = commit_whole_pages(win, wk, wv, ring_ids)
+            win = commit_whole_pages(win, {"k": wk, "v": wv}, ring_ids)
         cache = _committed(cache, full, win, counts, _window_counts(c, positions, valid), 1)
     x = _final_norm(x, params, c)
     return cache, _head_logits(x, params, c, last=lengths)
@@ -433,9 +433,9 @@ def _continue_commit(cache, new, page_ids):
     wk, wv, ring_ids, fk, fv, counts, window_counts = new
     full, win = _pools(cache)
     with scopes.layer("commit"):
-        full = commit_whole_pages(full, fk, fv, page_ids)
+        full = commit_whole_pages(full, {"k": fk, "v": fv}, page_ids)
         with jax.named_scope("window_commit"):
-            win = commit_whole_pages(win, wk, wv, ring_ids)
+            win = commit_whole_pages(win, {"k": wk, "v": wv}, ring_ids)
         return _committed(cache, full, win, counts, window_counts, 1)
 
 
@@ -500,10 +500,10 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         params, c, _embed(params, tokens[:, None], c), positions, active[:, None], make_attn, route, walk=None)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
-        full = commit_tokens(full, fk[:, :, 0], fv[:, :, 0], target, seq_lens % P)
+        full = commit_tokens(full, {"k": fk[:, :, 0], "v": fv[:, :, 0]}, target, seq_lens % P)
         with jax.named_scope("window_commit"):
             at = jnp.where(active, jnp.arange(S), pad) * ring + jnp.mod(seq_lens // P, ring)
-            win = commit_tokens(win, wk[:, :, 0], wv[:, :, 0], at, seq_lens % P)
+            win = commit_tokens(win, {"k": wk[:, :, 0], "v": wv[:, :, 0]}, at, seq_lens % P)
         cache = _committed(cache, full, win, counts, _window_counts(c, positions, active[:, None]), 0)
     x = _final_norm(x[:, 0], params, c)
     return cache, _head_logits(x, params, c)

@@ -188,7 +188,8 @@ def blocked_causal_attention(
     With ``window`` a query sees its last ``window`` positions only (a
     banded mask), and q-block i scans the key blocks the band touches,
     ``i - ceil(window / block) .. i``: the blocks wholly before the band are
-    never issued."""
+    never issued. Keys and values may differ in width (latent attention
+    expanded: keys of 192, values of 128); the scale is the keys'."""
     B, T, H, d = q.shape
     if T <= block_size or T % block_size:
         return causal_attention(q, k, v, positions, softcap=softcap, window=window)
@@ -216,7 +217,7 @@ def blocked_causal_attention(
 
         m = jnp.full((B, H, block_size), -jnp.inf, dtype=jnp.float32)
         l = jnp.zeros((B, H, block_size), dtype=jnp.float32)
-        acc = jnp.zeros((B, H, block_size, d), dtype=jnp.float32)
+        acc = jnp.zeros((B, H, block_size, v.shape[-1]), dtype=jnp.float32)  # v's width, which need not be k's
 
         def step(carry, blk, qf=qf, q_pos=q_pos):
             m, l, acc = carry

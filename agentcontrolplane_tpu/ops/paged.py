@@ -35,12 +35,11 @@ reserved as the trash page: padded writes land there, nothing reads it.
 from __future__ import annotations
 
 from collections import OrderedDict
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..observability import scopes
 from .attention import NEG_INF
@@ -91,39 +90,60 @@ def set_pages(arr: jax.Array, page_ids: jax.Array, blocks: jax.Array) -> jax.Arr
         return flat.reshape(arr.shape)
 
 
-def kv_commit(pool: dict, new_k: jax.Array, new_v: jax.Array, setter) -> dict:
-    """Fresh K/V ``[L, ..., H_kv, d]`` into the pool through ``setter(array,
-    values)``: heads merged into the pool's row, int8 pools quantized here
-    a row and head, their scales through the same setter."""
+def init_latent_pages(n_layers: int, num_pages: int, page_size: int, width: int, dtype) -> dict:
+    """A pool of ONE leaf ``[L, NP, P, width]``: a row a token that is key
+    and value at once (latent attention, ``models/kanana.py``: the normed
+    latent and the roped shared key side by side, one head of ``width``).
+    There is no ``"v"`` and no scale twin; every helper below takes a pool's
+    leaves as they come, so this one is committed, gathered, shared, swapped
+    and restored as the ``k`` / ``v`` pools are."""
+    return {"kv": jnp.zeros((n_layers, num_pages, page_size, width), dtype=dtype)}
+
+
+def pool_leaves(cache: dict) -> dict:
+    """The page-shaped leaves of a cache: all but what a family keeps beside
+    them under ``"state"``."""
+    return {name: a for name, a in cache.items() if name != "state"}
+
+
+def kv_commit(pool: dict, new: dict, setter) -> dict:
+    """Fresh rows ``{leaf: [L, ..., heads, d]}`` into the pool through
+    ``setter(array, values)``, leaf by leaf: heads merged into the pool's
+    row, a leaf with a scale twin (``name + "s"``: int8 pools) quantized
+    here a row and head, its scales through the same setter."""
     merge = lambda t: t.reshape(t.shape[:-2] + (t.shape[-2] * t.shape[-1],))  # noqa: E731
-    if "ks" in pool:
-        (qk, sk), (qv, sv) = kv_quantize(new_k), kv_quantize(new_v)
-        return {"k": setter(pool["k"], merge(qk)), "v": setter(pool["v"], merge(qv)),
-                "ks": setter(pool["ks"], sk), "vs": setter(pool["vs"], sv)}
-    return {"k": setter(pool["k"], merge(new_k).astype(pool["k"].dtype)),
-            "v": setter(pool["v"], merge(new_v).astype(pool["v"].dtype))}
+    out = {}
+    for name, rows in new.items():
+        if name + "s" in pool:
+            q, scales = kv_quantize(rows)
+            out[name] = setter(pool[name], merge(q))
+            out[name + "s"] = setter(pool[name + "s"], scales)
+        else:
+            out[name] = setter(pool[name], merge(rows).astype(pool[name].dtype))
+    return out
 
 
-def commit_whole_pages(pool: dict, new_k: jax.Array, new_v: jax.Array, page_ids: jax.Array) -> dict:
-    """``new_k`` [L, B, T, H_kv, d] into pages ``page_ids`` [B, T // P]: the
-    one whole-page write of every prefill, continuation and mid chunk."""
+def commit_whole_pages(pool: dict, new: dict, page_ids: jax.Array) -> dict:
+    """``new`` ``{leaf: [L, B, T, heads, d]}`` into pages ``page_ids`` [B, T
+    // P]: the one whole-page write of every prefill, continuation and mid
+    chunk."""
     with scopes.layer("commit"):
-        return kv_commit(pool, new_k, new_v, lambda arr, val: set_pages(arr, page_ids, val))
+        return kv_commit(pool, new, lambda arr, val: set_pages(arr, page_ids, val))
 
 
-def commit_tokens(pool: dict, new_k: jax.Array, new_v: jax.Array, pages: jax.Array,
-                  offsets: jax.Array) -> dict:
-    """``new_k`` [L, ..., H_kv, d] a token at a time into row ``offsets`` of
-    page ``pages`` (each [...]: a decode step's lanes, a verify pass's
-    [B, T]): one scatter of token rows into the pool flattened over its
-    layers. The within-page axis stays an axis of its own, so a pool that
-    shards it (context-parallel serving) is not gathered to be written."""
-    L, NP = pool["k"].shape[:2]
+def commit_tokens(pool: dict, new: dict, pages: jax.Array, offsets: jax.Array) -> dict:
+    """``new`` ``{leaf: [L, ..., heads, d]}`` a token at a time into row
+    ``offsets`` of page ``pages`` (each [...]: a decode step's lanes, a
+    verify pass's [B, T]): one scatter of token rows into the pool flattened
+    over its layers. The within-page axis stays an axis of its own, so a
+    pool that shards it (context-parallel serving) is not gathered to be
+    written."""
+    L, NP = next(iter(pool.values())).shape[:2]
     with scopes.layer("commit"):
         layer = jnp.arange(L).reshape((L,) + (1,) * pages.ndim)
         ids = layer_tables(pages[None], layer, NP)
         rows = jnp.broadcast_to(offsets[None], ids.shape)
-        return kv_commit(pool, new_k, new_v,
+        return kv_commit(pool, new,
                          lambda arr, val: flat_pages(arr).at[ids, rows].set(val).reshape(arr.shape))
 
 
@@ -355,6 +375,36 @@ def paged_decode_attention_reference_cache_plus_new(
     return out.reshape(S, H, d).astype(q.dtype)
 
 
+def latent_decode_attention_reference_cache_plus_new(
+    q: jax.Array,  # [S, H, width]: every head's query against the whole row
+    pages: jax.Array,  # [num_pages, P, width] — a latent pool's one leaf, WITHOUT the new token
+    block_tables: jax.Array,  # [S, max_pages]
+    seq_lens: jax.Array,  # [S] — tokens valid in the pages (excl. new)
+    row_new: jax.Array,  # [S, width]: the new token's row
+    value_width: int,  # the row's first columns are the value
+    score_dim: int,  # softmax scale: score_dim ** -0.5
+) -> jax.Array:
+    """Exact reference of the latent walk (``ops.pallas.paged_attention``'s
+    ``paged_latent_walk``) in the same absorbed form: a row a token shared
+    by all heads, key as it stands and value in its first ``value_width``
+    columns; the gathered rows are never expanded a head. -> [S, H,
+    value_width] in q's dtype."""
+    S, H, width = q.shape
+    rows = pages[block_tables].reshape(S, -1, width).astype(jnp.float32)  # [S, M * P, width]
+    qf, new = q.astype(jnp.float32), row_new.astype(jnp.float32)
+    scale = score_dim ** -0.5
+    prec = jax.lax.Precision.HIGHEST
+    logits = jnp.einsum("shw,stw->sht", qf, rows, precision=prec) * scale
+    mask = jnp.arange(rows.shape[1])[None, None, :] < seq_lens[:, None, None]
+    logits = jnp.where(mask, logits, NEG_INF)
+    self_logit = jnp.einsum("shw,sw->sh", qf, new, precision=prec) * scale
+    m = jnp.maximum(jnp.max(logits, axis=-1), self_logit)
+    p, p_self = jnp.exp(logits - m[..., None]), jnp.exp(self_logit - m)
+    out = jnp.einsum("sht,stv->shv", p, rows[..., :value_width], precision=prec)
+    out = out + p_self[..., None] * new[:, None, :value_width]
+    return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]).astype(q.dtype)
+
+
 class PageAllocator:
     """Host-side page free list with reference counts (the engine thread
     owns it; no locking). Page 0 is the reserved trash page and is never
@@ -461,12 +511,13 @@ class PageAllocator:
 
 @dataclass
 class HostKVEntry:
-    """Swapped-out KV resident in host RAM: token-major rows with the
-    trailing axes of the cache they left (the engine's extract/restore
-    paths convert to and from the slot rows ``[.., H_kv, d]`` or the page
-    blocks ``[.., H_kv * d]`` of whichever KV layout is serving, generic
-    over what follows the token axis; an entry restores into the layout it
-    was taken from).
+    """Swapped-out KV resident in host RAM: the cache's leaves by the names
+    the cache gives them, token-major, with the trailing axes of the cache
+    they left (the engine's extract/restore paths convert to and from the
+    slot rows ``[.., H_kv, d]`` or the page blocks ``[.., H_kv * d]`` of
+    whichever KV layout is serving, generic over the leaves and over what
+    follows the token axis; an entry restores into the layout and the
+    family it was taken from).
 
     ``tokens`` is the exact token sequence whose KV the rows hold (rows
     ``[0, cut)`` of a request's prefill row), so an entry can be matched
@@ -474,17 +525,16 @@ class HostKVEntry:
     -prefix equality (park expiry / mid-prefill deadline -> a later request
     re-sending the same conversation or persona prompt).
 
-    Quantized-KV engines swap the int8 bytes VERBATIM plus their per-row
-    scale rows (``k_scale``/``v_scale``, [L, cut, H_kv] f32) — the host
-    tier holds ~2x the tokens per byte, and a restore is bit-exact by
-    construction (no requantization round trip)."""
+    ``rows`` holds whatever the pool holds: ``k`` and ``v`` ([L, cut, H_kv *
+    d] paged, [L, cut, H_kv, d] slot); for a quantized-KV engine the int8
+    bytes VERBATIM plus their per-row scale rows (``ks`` / ``vs``, [L, cut,
+    H_kv] f32) — the host tier holds ~2x the tokens per byte, and a restore
+    is bit-exact by construction (no requantization round trip); for a
+    latent pool the one leaf ``kv`` [L, cut, width]."""
 
     rid: str
     tokens: tuple
-    k: np.ndarray  # [L, cut, H_kv * d] paged, [L, cut, H_kv, d] slot (bf16, or int8 with scales below)
-    v: np.ndarray
-    k_scale: Optional[np.ndarray] = None  # [L, cut, H_kv] f32
-    v_scale: Optional[np.ndarray] = None
+    rows: dict  # {leaf name: np.ndarray [L, cut, ...]}
     # families with per-slot state beside the pages: the state after
     # exactly ``cut`` tokens, the family's tree for one slot with numpy
     # leaves (one array or several, of whatever types); a restore resumes
@@ -497,9 +547,7 @@ class HostKVEntry:
 
     @property
     def nbytes(self) -> int:
-        n = int(self.k.nbytes) + int(self.v.nbytes)
-        if self.k_scale is not None:
-            n += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
+        n = sum(int(a.nbytes) for a in self.rows.values())
         if self.state is not None:
             n += sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.state))
         return n

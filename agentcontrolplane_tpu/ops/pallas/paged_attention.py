@@ -78,6 +78,26 @@ scale it is given, so a program that calls the walk once a layer over its
 whole pool builds the rows once a step, outside its layer scan
 (:func:`walk_scale_rows`, ``scales_laid=True``).
 
+Latent walk (``value_width``, the call named ``paged_latent_walk``): the
+pool has ONE leaf, a row a token that is key and value at once (latent
+attention, ``models/kanana.py``: 512 values of normed latent and 64 of the
+roped shared key). A page is ONE fetch into a ``[G * P, row]`` buffer, every
+query head scores against the whole row (one KV "head" of the row's width, a
+query group of all the heads) and the value is the row's first
+``value_width`` columns, read from the same buffer: ``acc += p R[:, :512]``.
+A row is 1,152 B and 69.6 kFLOP for 32 heads, 60 FLOP a byte where the GQA
+walks have 4 to 20, so the second product is not given the six passes of
+the f32 contract: with bf16 pages ``p`` goes in as a bf16 head and a bf16
+tail (two passes, exact in the pages' values, 2**-17 in ``p``). Same stream
+of turns, same (acc, m, l) contract; the absorbed projections on either side
+of it are the model's (``mla_absorb``). What a turn costs on a v5e (16 slots
+of 3,450 rows, 48 layers, cold pages; PERF.md, PR 44): 0.455 us, 2.5 times
+its bytes' time. The second product is not what holds it: one pass of ``p``
+for two takes 7% off, head and tail stacked into one 64-row product adds
+1%. Nor is the transposed key tile of ``q . K^T``: with the queries
+transposed outside and the keys streamed through them (``K . q^T``, one
+square transpose of the scores a turn) a turn took 25% longer.
+
 Tested in interpreter mode on CPU against the exact reference
 (tests/engine/test_paged*.py), compiled for a described v5e
 (tests/engine/test_chip_compile.py), and run compiled on the chip against
@@ -106,7 +126,7 @@ _SLOTS_BUDGET = 3 << 20  # of one program's q and outputs (each held twice)
 _F32 = jax.lax.Precision.HIGHEST
 
 
-def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False) -> int:
+def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False, leaves: int = 2) -> int:
     """G, the pages one turn of the walk fetches and folds: as many as make
     a turn one lane tile of tokens, from what the kernel can see alone.
 
@@ -115,27 +135,29 @@ def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = Fal
     must be a multiple of the dtype's sublane tile: 8 for f32, 16 for bf16,
     32 for int8 — and for int8 pages at any size: their scale rows are laid
     out head-major per page and do not follow a G-page turn. G halves until
-    the ring of K and V buffers fits ``_SCRATCH_BUDGET``.
+    the ring of K and V buffers (``leaves``: 2; 1 for a latent row, which is
+    both) fits ``_SCRATCH_BUDGET``.
     """
     itemsize = jnp.dtype(dtype).itemsize
     G = max(1, LANES // P_local)
     if quantized or P_local % (32 // itemsize):
         G = 1
-    while G > 1 and 2 * RING * G * P_local * H_kv * d * itemsize > _SCRATCH_BUDGET:
+    while G > 1 and leaves * RING * G * P_local * H_kv * d * itemsize > _SCRATCH_BUDGET:
         G //= 2
     return G
 
 
-def fetches_in_flight(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False) -> tuple[int, int]:
-    """(turns, bytes) of K and V the compiled walk keeps started ahead of
+def fetches_in_flight(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False,
+                      leaves: int = 2) -> tuple[int, int]:
+    """(turns, bytes) of K and V (``leaves``: 1 for a latent row) the compiled walk keeps started ahead of
     the turn it folds, from its first turn to its last and across slots:
     ``RING - 1`` turns of ``pages_per_turn`` pages. Not scaled by the
     turn's bytes: on a v5e the walk is bound by what a turn's fetches cost
     the scalar unit to issue and by its two passes' latency, not by a
     fetch's latency over the bytes in flight, and rings of 3, 6, 8 and 16
     buffers all measured slower than 4 (PERF.md, PR 43)."""
-    G = pages_per_turn(P_local, dtype, H_kv, d, quantized)
-    return RING - 1, (RING - 1) * 2 * G * P_local * H_kv * d * jnp.dtype(dtype).itemsize
+    G = pages_per_turn(P_local, dtype, H_kv, d, quantized, leaves)
+    return RING - 1, (RING - 1) * leaves * G * P_local * H_kv * d * jnp.dtype(dtype).itemsize
 
 
 def slots_per_program(S: int, H_kv: int, n_rep: int, d: int, q_dtype) -> int:
@@ -234,6 +256,7 @@ def _kernel(
     head_dim: int | None = None,  # the model's, where heads share a lane window
     starts_ref=None,  # [S] int32 (SMEM): a slot's first valid row (the window walk)
     ring: int = 0,  # > 0: the table is a ring, page a of the sequence at a % ring
+    value_width: int = 0,  # > 0: the latent walk; no v_pages_ref, V is the row's first columns
 ):
     # int8 walk (quantized=True): pages hold int8 values plus f32 scale
     # twins (one scale per row per KV head). A turn's fetch DMAs its page's
@@ -319,7 +342,8 @@ def _kernel(
             page = block_tables_ref[base + s, jax.lax.rem(at, ring) if ring else at]
             rows = pl.ds(g * P, P)
             pltpu.make_async_copy(k_pages_ref.at[page], kv_buf.at[buf, 0, rows], sems.at[buf, 0]).start()
-            pltpu.make_async_copy(v_pages_ref.at[page], kv_buf.at[buf, 1, rows], sems.at[buf, 0]).start()
+            if not value_width:
+                pltpu.make_async_copy(v_pages_ref.at[page], kv_buf.at[buf, 1, rows], sems.at[buf, 0]).start()
             if quantized:
                 pltpu.make_async_copy(ks_pages_ref.at[page], sc_buf.at[buf, 0], sems.at[buf, 1]).start()
                 pltpu.make_async_copy(vs_pages_ref.at[page], sc_buf.at[buf, 1], sems.at[buf, 1]).start()
@@ -405,10 +429,20 @@ def _kernel(
             return m_new, l, correction, pw
 
         def values(h, m_new, l, correction, pw):
-            v = kv_buf[buf, 1, :, h * d:(h + 1) * d].astype(jnp.float32)
-            pv = jnp.dot(
-                pw, v, precision=_F32, preferred_element_type=jnp.float32
-            )  # [n_rep, d]
+            if value_width and kv_buf.dtype == jnp.bfloat16:
+                # the latent walk (module text): p as a bf16 head and tail,
+                # two passes against the row's own bf16 values
+                v = kv_buf[buf, 0, :, :value_width]
+                head = pw.astype(jnp.bfloat16)
+                tail = (pw - head.astype(jnp.float32)).astype(jnp.bfloat16)
+                pv = (jnp.dot(head, v, preferred_element_type=jnp.float32)
+                      + jnp.dot(tail, v, preferred_element_type=jnp.float32))
+            else:
+                v = (kv_buf[buf, 0, :, :value_width] if value_width
+                     else kv_buf[buf, 1, :, h * d:(h + 1) * d]).astype(jnp.float32)
+                pv = jnp.dot(
+                    pw, v, precision=_F32, preferred_element_type=jnp.float32
+                )  # [n_rep, d]
             acc = jnp.where(fresh, 0.0, carried[h][2])
             return m_new, l, acc * correction + pv
 
@@ -443,7 +477,7 @@ def _kernel(
         (
             jnp.full((n_rep, 1), NEG_INF, dtype=jnp.float32),
             jnp.zeros((n_rep, 1), dtype=jnp.float32),
-            jnp.zeros((n_rep, d), dtype=jnp.float32),
+            jnp.zeros((n_rep, value_width or d), dtype=jnp.float32),
         )
         for _ in range(n_kv_heads)
     )
@@ -457,6 +491,11 @@ def _window_kernel(block_tables_ref, seq_lens_ref, pos_base_ref, starts_ref, *re
     """``_kernel`` with a fourth prefetched scalar row: each slot's first
     valid row."""
     _kernel(block_tables_ref, seq_lens_ref, pos_base_ref, *rest, starts_ref=starts_ref, **kw)
+
+
+def _latent_kernel(block_tables_ref, seq_lens_ref, pos_base_ref, q_ref, pages_ref, *rest, **kw):
+    """``_kernel`` over a pool of one leaf: there are no V pages."""
+    _kernel(block_tables_ref, seq_lens_ref, pos_base_ref, q_ref, pages_ref, None, *rest, **kw)
 
 
 def _paged_state(
@@ -667,6 +706,82 @@ def paged_decode_attention_cache_plus_new(
         scales_laid=scales_laid, starts=starts, ring=ring,
     )
     return _fold_self_term(q, k_new, v_new, acc, m, l)
+
+
+def latent_walk_serves(width: int, value_width: int, P: int, dtype) -> bool:
+    """Whether the compiled latent walk takes this geometry: a page's rows a
+    whole sublane tile of its dtype (a turn's pages share one buffer), the
+    value a whole number of lane tiles at the row's start, and what is left
+    of the row (the shared roped key) narrower than a tile or whole tiles."""
+    return (P % (32 // jnp.dtype(dtype).itemsize) == 0 and value_width % LANES == 0
+            and 0 < value_width <= width)
+
+
+def paged_latent_state(
+    q: jax.Array,  # [S, H, width]: every head's query against the whole row
+    pages: jax.Array,  # [num_pages, P, width]: the pool's one leaf
+    block_tables: jax.Array,  # [S, max_pages] int32
+    seq_lens: jax.Array,  # [S] int32
+    value_width: int,  # the row's first columns are the value
+    score_dim: int,  # softmax scale: score_dim ** -0.5
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The latent walk -> unnormalized (acc [S, H, value_width] f32, m [S,
+    H], l [S, H]): one fetch a page, ``s = Q R^T``, the online softmax,
+    ``acc += p R[:, :value_width]``."""
+    S, H, width = q.shape
+    num_pages, P = pages.shape[:2]
+    G = pages_per_turn(P, pages.dtype, 1, width, leaves=1)
+    blk = slots_per_program(S, 1, H, width, q.dtype)
+
+    def per_program(*tail):
+        return pl.BlockSpec((blk, 1, H) + tail, lambda c, *_: (c, 0, 0, 0), memory_space=pltpu.VMEM)
+
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=P, head_dim=score_dim, value_width=value_width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S // blk,),
+            in_specs=[per_program(width), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[per_program(value_width), per_program(1), per_program(1)],
+            scratch_shapes=[
+                pltpu.VMEM((RING, 1, G * P, width), pages.dtype),
+                pltpu.SemaphoreType.DMA((RING, 1)),
+                pltpu.SMEM((blk,), jnp.int32),
+                pltpu.SMEM((blk,), jnp.int32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((S, 1, H, value_width), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1, H, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        name="paged_latent_walk",  # no reader of page_walk or paged_window_walk matches it
+    )(block_tables, seq_lens, jnp.zeros((1,), jnp.int32), q.reshape(S, 1, H, width), pages)
+    return acc.reshape(S, H, value_width), m.reshape(S, H), l.reshape(S, H)
+
+
+def paged_latent_attention_cache_plus_new(
+    q: jax.Array,  # [S, H, width]
+    pages: jax.Array,  # [num_pages, P, width] — WITHOUT the new token
+    block_tables: jax.Array,
+    seq_lens: jax.Array,  # [S] — tokens valid in the PAGES (excl. new)
+    row_new: jax.Array,  # [S, width]: the new token's row, not yet written
+    value_width: int,
+    score_dim: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """The latent walk over the read-only pages plus the new token's own
+    term, merged outside the kernel (:func:`_fold_self_term`'s fold, with
+    the one row every head shares) -> [S, H, value_width] in q's dtype."""
+    acc, m, l = paged_latent_state(q, pages, block_tables, seq_lens, value_width, score_dim, interpret)
+    row = row_new.astype(jnp.float32)
+    self_logit = jnp.einsum("shw,sw->sh", q.astype(jnp.float32), row) * score_dim ** -0.5
+    m2 = jnp.maximum(m, self_logit)
+    corr, p_self = jnp.exp(m - m2), jnp.exp(self_logit - m2)
+    out = acc * corr[..., None] + p_self[..., None] * row[:, None, :value_width]
+    return (out / jnp.maximum(l * corr + p_self, 1e-30)[..., None]).astype(q.dtype)
 
 
 def _shard_wrap(fn, mesh, interpret, extra_sharded=(), with_scales=False, **kw):

@@ -114,3 +114,17 @@ def apply_rope(
     x2 = x[..., d // 2 :].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def deinterleave_pairs(w: jax.Array) -> jax.Array:
+    """The last axis' values in the order ``apply_rope`` pairs them: a
+    source that rotates neighbours ``(2k, 2k + 1)`` (``rope_interleave``)
+    becomes one that rotates halves ``(k, k + d/2)``, by moving the even
+    values to the first half and the odd ones to the second. Applied once,
+    where weights are made or loaded, to the output columns of the
+    projections whose values are rotated: a rotation over a PART of a head
+    (latent attention's 64 rope values beside 128 that are not turned) is
+    ``apply_rope`` over that part alone, and a key all heads share is one
+    head. A dot product of two vectors permuted alike is unchanged, so
+    ``q_pe . k_pe`` is the source's."""
+    return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)

@@ -883,3 +883,100 @@ def test_mellum_prefill_of_2048_tokens_fits_beside_the_resident_set(v5e, monkeyp
     assert "moe_gmm" in text and "paged_window_walk" not in text  # a prefill attends over its own rows
     assert f"bf16[7,3,{B},1040,4,128]" in text and f"bf16[7,3,{B},{T},4,128]" not in text
     assert _resident(compiled) < 14.5e9, f"{_resident(compiled) / 1e9:.1f} GB"
+
+
+# -- the kanana family: a latent row a token in the pool, the latent walk ---------------------------------
+
+_KANANA_SLOTS, _KANANA_PAGES = 16, 5121  # acpbench/configs/kanana2-30b-a3b-bf16-v5e1-ep16.json
+
+
+def _kanana(v5e, monkeypatch):
+    """The chip's share of the published config (8 of 128 experts), abstract
+    weights and the latent pool placed on one described chip, the expert
+    layer steered onto its kernel."""
+    import functools
+
+    from agentcontrolplane_tpu.models import kanana
+
+    monkeypatch.setattr(kanana, "routed_experts", functools.partial(kanana.routed_experts, kernel=True))
+    c = kanana.PRESETS["kanana-2-30b-a3b-ep16"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: kanana.init_params(c, jax.random.key(0))))
+    cache = place(jax.eval_shape(lambda: kanana.init_paged_cache(c, _KANANA_PAGES, PAGE, max_slots=_KANANA_SLOTS)))
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return kanana, c, params, cache, vec
+
+
+def test_kanana_decode_block_walks_latent_rows_and_copies_no_pool(v5e, monkeypatch):
+    """16 lanes of the published model at full depth, steps in a loop as the
+    engine's decode block nests them: four kernels (the dense layer's walk,
+    the expert layers' walk and two grouped matmuls), the pool aliased from
+    argument to result (5.03 GB: a row of 576 values is stored on 640 lanes),
+    no op copies the pool or a layer of it, and NO weight is relaid before
+    the first step: with `q_proj`, `kv_a_proj` and `kv_b_proj` each one matrix
+    the block copied 1.73 GB of them, with `q_proj`'s halves inputs first
+    still 1.18 GB (PERF.md, PR 44)."""
+    import re
+
+    kanana, c, params, cache, vec = _kanana(v5e, monkeypatch)
+    S = _KANANA_SLOTS
+
+    def block(p, ca, tok, n, tables, active):
+        def step(carry, _):
+            ca, tok, n = carry
+            ca, logits = kanana.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (ca, tok, n + 1), tok
+
+        (ca, _, _), toks = jax.lax.scan(step, (ca, tok, n), None, length=4)
+        return ca, toks
+
+    compiled = jax.jit(block, donate_argnums=(1,)).lower(
+        params, cache, vec(S), vec(S), vec(S, 5120 // PAGE), vec(S, dt=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "paged_latent_walk" in text and "paged_page_walk" not in text and text.count("tpu_custom_call") == 4
+    pool = cache["kv"].size * 2
+    mem = compiled.memory_analysis()
+    assert cache["kv"].shape == (48, _KANANA_PAGES, PAGE, 640) and 5.0e9 < pool < 5.1e9 and mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 100e6, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB: a weight is relaid"
+    shape = rf"bf16\[48,{_KANANA_PAGES},{PAGE},640\]"
+    assert re.search(shape, text) and not re.search(rf"= {shape}\S* copy\(", text), "a copy of the whole pool"
+    assert f"bf16[{_KANANA_PAGES},{PAGE},640]" not in text, "one layer of the pool as a value of its own"
+    assert 0.8 * 16e9 < _resident(compiled) < 13.5e9, f"{_resident(compiled) / 1e9:.2f} GB"
+
+
+def test_kanana_prefill_of_4096_tokens_fits_beside_the_resident_set(v5e, monkeypatch):
+    """The mix's widest prompt (one row of 4,096 tokens, as the engine
+    prefills it), expanded: weights and the pool resident, the temporaries
+    beside them, under 14.5 GB; the expert layer's kernels are there (a chunk
+    of 2,048 tokens at a time) and no walk is."""
+    kanana, c, params, cache, vec = _kanana(v5e, monkeypatch)
+    B, T = 1, 4096
+    fn = lambda p, ca, t, n, ids: kanana.prefill_paged_batch(p, ca, t, n, ids, c)  # noqa: E731
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, vec(B, T), vec(B), vec(B, T // PAGE)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "paged_latent_walk" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache["kv"].size * 2
+    assert mem.temp_size_in_bytes < 1.2e9, f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB"
+    assert _resident(compiled) < 14.5e9, f"{_resident(compiled) / 1e9:.2f} GB"
+
+
+def test_the_latent_walk_refuses_a_row_that_is_not_whole_lane_tiles(v5e):
+    """Why the pool's row is 640 wide and not 576: Mosaic slices no HBM
+    operand whose minor axis is not whole lane tiles."""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def walk(width):
+        args = [sds((16, 32, width), jnp.bfloat16), sds((4096, PAGE, width), jnp.bfloat16),
+                sds((16, 320), jnp.int32), sds((16,), jnp.int32)]
+        return jax.jit(lambda q, p, t, n: pa.paged_latent_state(q, p, t, n, 512, 192)).lower(*args).compile()
+
+    assert "paged_latent_walk" in walk(640).as_text()
+    with pytest.raises(Exception, match="aligned to tiling"):
+        walk(576)

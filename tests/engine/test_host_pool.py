@@ -11,13 +11,29 @@ import pytest
 from agentcontrolplane_tpu.ops.paged import HostKVEntry, HostKVPool, PageAllocator
 
 
+# the leaves an entry of each kind of pool carries, with the width of a row
+# of each: a `k` and a `v` of 8 (H_kv * d), or the one leaf of a latent pool
+# (16: key and value at once), the same bytes a token
+POOLS = {"k_and_v": {"k": 8, "v": 8}, "one_leaf": {"kv": 16}}
+_leaves = POOLS["k_and_v"]
+
+
+@pytest.fixture(autouse=True, params=sorted(POOLS))
+def pool_kind(request):
+    """Every case runs over an entry of each kind of pool: the host tier
+    takes an entry's leaves as they come."""
+    global _leaves
+    _leaves = POOLS[request.param]
+    yield request.param
+    _leaves = POOLS["k_and_v"]
+
+
 def entry(rid: str, n_tokens: int, toks=None) -> HostKVEntry:
-    shape = (2, n_tokens, 2 * 4)  # [L, T, H_kv * d]: rows as the paged pool holds them
+    # [L, T, width]: rows as the paged pool holds them
     return HostKVEntry(
         rid=rid,
         tokens=tuple(toks if toks is not None else range(n_tokens)),
-        k=np.zeros(shape, dtype=np.float32),
-        v=np.zeros(shape, dtype=np.float32),
+        rows={name: np.zeros((2, n_tokens, width), dtype=np.float32) for name, width in _leaves.items()},
     )
 
 

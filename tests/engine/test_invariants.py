@@ -210,8 +210,8 @@ def test_host_resident_page_leak_is_detected():
 
             e._host_pool.put(HostKVEntry(
                 rid="seed", tokens=tuple(range(16)),
-                k=np.zeros((2, 16, 2, 8), dtype=np.float32),
-                v=np.zeros((2, 16, 2, 8), dtype=np.float32),
+                rows={"k": np.zeros((2, 16, 2, 8), dtype=np.float32),
+                      "v": np.zeros((2, 16, 2, 8), dtype=np.float32)},
             ))
             e._publish_memory_state()
         assert verify_engine(e) == []

@@ -402,7 +402,7 @@ def test_a_host_entry_without_a_state_is_a_miss():
         p = prompts(44)[0]
         L, HD = CFG.n_attention, CFG.n_kv_heads * CFG.head_dim
         rows = np.ones((L, 32, HD), np.float32)  # wrong K/V: it must never be restored
-        assert eng.inject_host_kv(HostKVEntry(rid="x", tokens=tuple(p[:32]), k=rows, v=rows))
+        assert eng.inject_host_kv(HostKVEntry(rid="x", tokens=tuple(p[:32]), rows={"k": rows, "v": rows}))
         assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 10)
         assert eng.state_refused >= 1 and eng.kv_swap_ins == 0
     finally:

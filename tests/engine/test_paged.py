@@ -556,15 +556,9 @@ def test_the_stream_walks_windows_whose_ring_wraps_inside_a_turn():
     np.testing.assert_allclose(got, want, atol=5e-6)
 
 
-@pytest.mark.parametrize("order", ["empty-first", "empty-between-long", "last-turns-of-G-1-pages", "every-slot-empty"])
-def test_every_fetch_is_started_once_and_waited_for_once(order, monkeypatch):
-    """A wait on a fetch never started hangs the chip, and the interpreter
-    does not hang: so the starts and waits are recorded as they run (a
-    callback beside each) and held to the ring's discipline, semaphore by
-    semaphore: a turn's 2 x G starts, then its one wait, then the buffer's
-    next turn; as many waits as the batch has turns; nothing left started.
-    (Callbacks of one loop turn may run in any order, those of different
-    turns run in theirs: a buffer's events are never of one turn.)"""
+def _spy_on_fetches(monkeypatch):
+    """Every start (0) and wait (1) of the walk's async copies as they run,
+    `(kind, buffer, semaphore column)`: a `jax.debug.callback` beside each."""
     from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
 
     events = []
@@ -586,6 +580,21 @@ def test_every_fetch_is_started_once_and_waited_for_once(order, monkeypatch):
             self.copy.wait()
 
     monkeypatch.setattr(pa.pltpu, "make_async_copy", Spy)
+    return events
+
+
+@pytest.mark.parametrize("order", ["empty-first", "empty-between-long", "last-turns-of-G-1-pages", "every-slot-empty"])
+def test_every_fetch_is_started_once_and_waited_for_once(order, monkeypatch):
+    """A wait on a fetch never started hangs the chip, and the interpreter
+    does not hang: so the starts and waits are recorded as they run (a
+    callback beside each) and held to the ring's discipline, semaphore by
+    semaphore: a turn's 2 x G starts, then its one wait, then the buffer's
+    next turn; as many waits as the batch has turns; nothing left started.
+    (Callbacks of one loop turn may run in any order, those of different
+    turns run in theirs: a buffer's events are never of one turn.)"""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    events = _spy_on_fetches(monkeypatch)
     c = _stream_case(_PAGES[order], jnp.float32)
     out = paged_decode_attention(c["q"], c["k_pages"], c["v_pages"], c["tables"], c["seq_lens"], interpret=True)
     jax.block_until_ready(out)
@@ -809,3 +818,84 @@ def test_programs_through_the_merged_pool_match_the_plain_forward(mesh_axes, int
         a = np.asarray(pool[name])
         assert np.isnan(a[:, ~named]).all(), f"{name}: a write landed on an unnamed page"
         assert np.isfinite(a[:, named & (np.arange(_POOL_PAGES) != TRASH_PAGE)]).all(), name
+
+
+# -- the pool as a tree of page-shaped leaves: every helper takes them as they come ----------
+
+
+def _pools():
+    """A `k` / `v` pool, an int8 one with its scale twins, and a latent
+    pool's one leaf: fresh rows for each, [L, B, T, heads, d]."""
+    from agentcontrolplane_tpu.ops import paged
+
+    L, NP, P, B, T = 3, 9, 4, 2, 8
+    key = jax.random.key(5)
+    rows = lambda i, heads, d: jax.random.normal(jax.random.fold_in(key, i), (L, B, T, heads, d), jnp.float32)  # noqa: E731
+    return {
+        "k_and_v": (paged.init_kv_pages(L, NP, P, 2, 8, jnp.float32), {"k": rows(1, 2, 8), "v": rows(2, 2, 8)}),
+        "int8": (paged.init_kv_pages(L, NP, P, 2, 8, jnp.float32, quantize=True), {"k": rows(1, 2, 8), "v": rows(2, 2, 8)}),
+        "one_leaf": (paged.init_latent_pages(L, NP, P, 24, jnp.float32), {"kv": rows(3, 1, 24)}),
+    }, (L, NP, P, B, T)
+
+
+@pytest.mark.parametrize("kind", ["k_and_v", "int8", "one_leaf"])
+def test_the_pool_helpers_take_a_pools_leaves_as_they_come(kind):
+    """`commit_whole_pages`, `commit_tokens`, `gather_pages` and `set_pages`
+    over each kind of pool: what is committed is what is gathered (int8: to
+    its rounding), no leaf is named by the helpers, and pages not written
+    stay as they were."""
+    from agentcontrolplane_tpu.ops import paged
+
+    pools, (L, NP, P, B, T) = _pools()
+    pool, new = pools[kind]
+    ids = jnp.asarray([[1, 2], [5, 6]], jnp.int32)
+    done = paged.commit_whole_pages(pool, new, ids)
+    assert set(done) == set(pool) and all(done[name].shape == pool[name].shape for name in pool)
+    tol = 0.05 if kind == "int8" else 0.0
+    for name, rows in new.items():
+        heads = rows.shape[-2]
+        for layer in range(L):
+            got = paged.gather_pages(done, name, paged.layer_tables(ids, layer, NP), jnp.float32, heads)
+            np.testing.assert_allclose(got.reshape(B, T, heads, -1), rows[layer], atol=tol)
+        untouched = np.asarray(done[name])[:, [0, 3, 4, 7, 8]]
+        assert float(np.abs(untouched).max()) == 0.0
+    # a token at a time: row 1 of pages 3 and 7, and nothing else moves
+    one = {name: rows[:, :, 0] for name, rows in new.items()}
+    after = paged.commit_tokens(done, one, jnp.asarray([3, 7], jnp.int32), jnp.asarray([1, 1], jnp.int32))
+    for name, rows in one.items():
+        heads = rows.shape[-2]
+        got = paged.gather_pages(after, name, paged.layer_tables(jnp.asarray([[3], [7]]), 2, NP), jnp.float32, heads)
+        np.testing.assert_allclose(got[:, 0, 1], rows[2], atol=tol)
+        assert float(np.abs(np.asarray(got)[:, 0, [0, 2, 3]]).max()) == 0.0
+        np.testing.assert_array_equal(np.asarray(after[name])[:, [1, 2, 5, 6]], np.asarray(done[name])[:, [1, 2, 5, 6]])
+    # whole pages set leaf by leaf, as the engine's swap-in scatters a host entry's blocks
+    blocks = {name: np.asarray(after[name])[:, [1, 2]] for name in after}
+    moved = {name: paged.set_pages(after[name], jnp.asarray([7, 8]), jnp.asarray(blocks[name])) for name in after}
+    for name in after:
+        np.testing.assert_array_equal(np.asarray(moved[name])[:, [7, 8]], blocks[name])
+    assert set(paged.pool_leaves({**pool, "state": {"x": 1}})) == set(pool)
+
+
+def test_every_fetch_of_the_latent_walk_is_started_once_and_waited_for_once(monkeypatch):
+    """The latent walk on the same stream of turns: ONE fetch a page (a
+    turn's G starts, then its one wait), as many waits as the batch has
+    turns, nothing left started."""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    events = _spy_on_fetches(monkeypatch)
+    pages_of = [0, 3 * _G, 1, 0, 2 * _G - 1, _G]
+    S, H, W, V, P, M = len(pages_of), 4, 256, 128, 16, 3 * _G
+    key = jax.random.key(2)
+    pool = jax.random.normal(key, (1 + S * M, P, W), jnp.float32)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (S, H, W), jnp.float32)
+    tables = 1 + jnp.arange(S * M, dtype=jnp.int32).reshape(S, M)
+    lens = jnp.asarray([max(0, n * P - 3) for n in pages_of], jnp.int32)
+    out = pa.paged_latent_state(q, pool, tables, lens, V, 192, interpret=True)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    turns = sum(-(-n // _G) for n in pages_of)
+    assert sum(k for k, _, _ in events) == turns
+    for buf in range(pa.RING):
+        mine = [k for k, b, _ in events if b == buf]
+        assert mine == ([0] * _G + [1]) * (len(mine) // (_G + 1)), (buf, mine)
+    assert len(events) == turns * (_G + 1)

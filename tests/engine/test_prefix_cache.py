@@ -13,6 +13,7 @@ import jax
 
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.engine.tokenizer import ByteTokenizer
+from agentcontrolplane_tpu.models import preset
 from agentcontrolplane_tpu.models.llama import PRESETS
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
@@ -159,11 +160,22 @@ def test_chunked_prefill_long_prompt():
         big_buckets.stop()
 
 
-def _paged_engine(prefix_entries: int) -> Engine:
+# the pool whose pages an entry shares: a `k` and a `v` (the dense family on a
+# tp=2 mesh), or the one leaf of a latent pool (models/kanana.py, held whole):
+# an entry is page ids and refcounts, and names no leaf
+POOLS = {
+    "k_and_v": lambda: (CFG, make_mesh({"tp": 2}, devices=jax.devices()[:2])),
+    "one_leaf": lambda: (preset("kanana-tiny"), make_mesh({"tp": 1}, devices=jax.devices()[:1])),
+}
+pools = pytest.mark.parametrize("pool", list(POOLS))
+
+
+def _paged_engine(prefix_entries: int, pool: str = "k_and_v") -> Engine:
+    config, mesh = POOLS[pool]()
     eng = Engine(
-        config=CFG,
+        config=config,
         tokenizer=ByteTokenizer(),
-        mesh=make_mesh({"tp": 2}, devices=jax.devices()[:2]),
+        mesh=mesh,
         max_slots=4,
         max_ctx=256,
         prefill_buckets=(64, 128, 256),
@@ -177,12 +189,13 @@ def _paged_engine(prefix_entries: int) -> Engine:
     return eng
 
 
-def test_paged_hit_results_match_cold_engine():
+@pools
+def test_paged_hit_results_match_cold_engine(pool):
     """Paged layout shares prefix PAGES zero-copy (refcounted block-table
     references); hits must still be bit-identical to cold prefills."""
     greedy = SamplingParams(temperature=0.0, max_tokens=10)
-    cached = _paged_engine(prefix_entries=4)
-    cold = _paged_engine(prefix_entries=0)
+    cached = _paged_engine(prefix_entries=4, pool=pool)
+    cold = _paged_engine(prefix_entries=0, pool=pool)
     try:
         prompts = [SYSTEM + "turn one", SYSTEM + "turn one and then some"]
         out_cached = [cached.generate(p, greedy).tokens for p in prompts]
@@ -195,12 +208,13 @@ def test_paged_hit_results_match_cold_engine():
         cold.stop()
 
 
-def test_paged_prefix_page_refcounts_conserved():
+@pools
+def test_paged_prefix_page_refcounts_conserved(pool):
     """Page accounting: after all requests drain, the only pages still out
     are exactly the cached entries' shared pages; disabling the cache (0
     entries) returns the pool to full."""
     greedy = SamplingParams(temperature=0.0, max_tokens=6)
-    eng = _paged_engine(prefix_entries=2)
+    eng = _paged_engine(prefix_entries=2, pool=pool)
     initial_free = eng._allocator.free_count
     try:
         for i in range(5):  # several prompts; entries capped at 2 (LRU evicts)
@@ -225,12 +239,13 @@ def test_paged_prefix_page_refcounts_conserved():
         eng.stop()
 
 
-def test_paged_entry_eviction_while_borrower_active_is_safe():
+@pools
+def test_paged_entry_eviction_while_borrower_active_is_safe(pool):
     """An entry evicted while a sequence still references its pages must not
     free them out from under the borrower (refcounts): the borrower's
     output is unaffected and pages return only when it finishes."""
-    eng = _paged_engine(prefix_entries=1)  # capacity 1: next save evicts
-    cold = _paged_engine(prefix_entries=0)
+    eng = _paged_engine(prefix_entries=1, pool=pool)  # capacity 1: next save evicts
+    cold = _paged_engine(prefix_entries=0, pool=pool)
     try:
         seed_prompt = SYSTEM + "base"
         eng.generate(seed_prompt, SamplingParams(temperature=0.0, max_tokens=4))

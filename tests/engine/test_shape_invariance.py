@@ -26,12 +26,14 @@ import numpy as np
 import pytest
 
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
-from agentcontrolplane_tpu.models import jamba, lfm2, llama, mellum, preset
+from agentcontrolplane_tpu.models import jamba, kanana, lfm2, llama, mellum, preset
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
 FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2), "jamba": ("jamba-tiny", jamba),
             # the probe's 44 tokens cross mellum-tiny's window of 32 inside its decode blocks
-            "mellum": ("mellum-tiny", mellum)}
+            "mellum": ("mellum-tiny", mellum),
+            # expanded prefill and absorbed decode through a one-leaf pool
+            "kanana": ("kanana-tiny", kanana)}
 N_TOKENS = 24
 GREEDY = SamplingParams(temperature=0.0, max_tokens=N_TOKENS)
 # outlive the probe, so the width it decodes at holds until it is done
