@@ -4,7 +4,9 @@ One definition shared by ``chip_smoke.py`` and
 ``tests/engine/test_tpu_hardware.py`` (and usable in interpret mode on the
 CPU): seeded ragged pages at a named geometry, the kernel and
 ``ops.paged``'s reference run on the default device, the largest absolute
-difference judged against a tolerance set from the page dtype.
+difference judged against a tolerance set from the page dtype. The K/V walk
+(:func:`page_walk_parity`) and the latent walk over a pool of one leaf
+(:func:`latent_walk_parity`).
 """
 
 from __future__ import annotations
@@ -16,12 +18,15 @@ import numpy as np
 from ..ops.paged import (
     TRASH_PAGE,
     PageAllocator,
+    latent_decode_attention_reference_cache_plus_new,
     paged_decode_attention_reference,
     paged_decode_attention_reference_cache_plus_new,
 )
 from ..ops.pallas.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_cache_plus_new,
+    paged_latent_attention_cache_plus_new,
+    pages_per_turn,
 )
 from ..ops.quant import kv_quantize
 
@@ -96,6 +101,12 @@ def page_walk_parity(
     )(*args, **scales)
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(reference)(*args, **scales)
+    return _verdict(case, out, ref)
+
+
+def _verdict(case: dict, out: jax.Array, ref: jax.Array) -> dict:
+    """The kernel's output judged against the reference's: a NaN anywhere
+    (a page read that is not the walk's own) is not ok whatever the rest."""
     out = np.asarray(out.astype(jnp.float32))
     ref = np.asarray(ref.astype(jnp.float32))
     err = float(np.max(np.abs(out - ref)))
@@ -109,3 +120,50 @@ def page_walk_parity(
         "seq_lens": [int(n) for n in np.asarray(case["seq_lens"])],
         "ok": finite and out.shape == ref.shape and err <= tol,
     }
+
+
+# slots of 0 to 10 turns, empty ones first, between and last, a slot of one
+# turn after the longest, last turns that hold one page and all but one
+LATENT_TURNS = (0, 10, 1, 0, 0, 4, 2, 0, 3, 0)
+
+
+def make_latent_case(seed: int, *, H: int = 32, width: int = 640, value_width: int = 512, P: int = 16,
+                     turns: tuple = LATENT_TURNS, dtype=jnp.bfloat16) -> dict:
+    """A pool of one leaf in which slot ``s`` holds ``turns[s]`` turns of the
+    latent walk's own geometry (:func:`pages_per_turn` with ``leaves=1``),
+    its last turn one page (odd slots) or a page short of whole (even), its
+    last page part-filled; the pages scattered, and every page no slot's
+    rows reach NaN (the table's padding names them): a walk that reads a
+    page not its own, or scores a row no fetch wrote, fails loudly."""
+    rng = np.random.default_rng(seed)
+    G = pages_per_turn(P, dtype, 1, width, leaves=1)
+    held = [0 if n == 0 else (n - 1) * G + 1 if s % 2 else n * G - 1 for s, n in enumerate(turns)]
+    S, M = len(turns), max(1, max(held))
+    order = 1 + rng.permutation(S * M).astype(np.int32).reshape(S, M)
+    clean = rng.normal(size=(1 + S * M, P, width)).astype(np.float32)
+    named = np.zeros((1 + S * M,), bool)
+    for s, n in enumerate(held):
+        named[order[s, :n]] = True
+    return {
+        "q": jnp.asarray(rng.normal(size=(S, H, width)) * 0.3, dtype=dtype),
+        "pages": jnp.asarray(np.where(named[:, None, None], clean, np.nan), dtype=dtype),
+        "clean": jnp.asarray(clean, dtype=dtype),
+        "block_tables": jnp.asarray(order),
+        "seq_lens": jnp.asarray([max(0, n * P - 3) for n in held], jnp.int32),
+        "row_new": jnp.asarray(rng.normal(size=(S, width)), dtype=dtype),
+        "value_width": value_width,
+        "pages_per_turn": G,
+    }
+
+
+def latent_walk_parity(case: dict, *, score_dim: int = 192, interpret: bool = False) -> dict:
+    """The latent walk (compiled unless ``interpret``) over the case's pool
+    against the reference over the same pool without its NaN pages, in the
+    serving form (read-only pages plus the new token's own term)."""
+    tail = (case["block_tables"], case["seq_lens"], case["row_new"], case["value_width"], score_dim)
+    out = jax.jit(lambda q, pages: paged_latent_attention_cache_plus_new(q, pages, *tail, interpret=interpret))(
+        case["q"], case["pages"])
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda q, pages: latent_decode_attention_reference_cache_plus_new(q, pages, *tail))(
+            case["q"], case["clean"])
+    return {**_verdict(case, out, ref), "pages_per_turn": case["pages_per_turn"]}

@@ -104,7 +104,7 @@ from ..ops.rope import apply_rope, deinterleave_pairs
 from .lfm2 import _embed, _final_norm, _head_logits, _mm
 from .lfm2 import describe_counters as _describe_moe
 
-LATENT_COUNTS = 3  # dispatches, rows covered, rows expanded
+LATENT_COUNTS = 4  # dispatches, rows covered, rows expanded, rows fetched
 MOE_CHUNK = 2048  # tokens the routed FF takes at a time (models/mellum.py says why)
 CONTINUE_BLOCK = 512  # query rows a continuation attends at a time
 
@@ -394,10 +394,10 @@ def init_paged_cache(config: KananaConfig, num_pages: int, page_size: int, quant
     return cache
 
 
-def _committed(cache, pool, counts, covered, expanded, row):
+def _committed(cache, pool, counts, covered, expanded, row, fetched=0):
     """The cache with its pages replaced and the dispatch counted."""
     u32 = lambda v: jnp.asarray(v).astype(jnp.uint32)  # noqa: E731
-    added = jnp.concatenate([counts, jnp.stack([jnp.ones((), jnp.uint32), u32(covered), u32(expanded)])])
+    added = jnp.concatenate([counts, jnp.stack([jnp.ones((), jnp.uint32), u32(covered), u32(expanded), u32(fetched)])])
     return {**pool, "state": {"counts": cache["state"]["counts"].at[row].add(added)}}
 
 
@@ -428,7 +428,7 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     gathered prefix pages plus themselves: the latent rows gathered (the
     whole table's, whatever the start) are expanded with the rows' own and
     attended densely. Nothing is written here. -> (x normed, new rows,
-    counts, rows covered, rows expanded)."""
+    counts, rows covered, rows expanded, rows fetched)."""
     B, T = tokens.shape
     positions, valid = _rows(lengths, starts, T)
     pool = pool_leaves(cache)
@@ -460,14 +460,14 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
     x, rows, counts, expanded = _run_layers(params, c, _embed(params, tokens, c), positions, valid, make_attend)
     live = jnp.where(lengths > 0, starts, 0)
-    return _final_norm(x, params, c), rows, counts, jnp.sum(lengths + live), expanded
+    return _final_norm(x, params, c), rows, counts, jnp.sum(lengths + live), expanded, B * M * P
 
 
 def _continue_commit(cache, new, page_ids):
-    rows, counts, covered, expanded = new
+    rows, counts, covered, expanded, fetched = new
     with scopes.layer("commit"):
         pool = commit_whole_pages(pool_leaves(cache), {"kv": rows[..., None, :]}, page_ids)
-        return _committed(cache, pool, counts, covered, expanded, 1)
+        return _committed(cache, pool, counts, covered, expanded, 1, fetched)
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, config: KananaConfig):
@@ -494,6 +494,14 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     NP, P = pool["kv"].shape[1:3]
     flat = flat_pages(pool["kv"])
     r, H = c.kv_lora_rank, c.n_heads
+    if use_pallas or interpret:
+        from ..ops.pallas.paged_attention import paged_latent_attention_cache_plus_new, pages_per_turn
+
+        # the walk fetches whole turns: a slot's last turn reads its last page again
+        turn = P * pages_per_turn(P, flat.dtype, 1, c.row_stored, leaves=1)
+        fetched = jnp.sum((seq_lens + turn - 1) // turn * turn)
+    else:
+        fetched = S * block_tables.shape[1] * P  # the reference gathers a lane's whole table
 
     def make_attend(i):
         def attend(q_nope, q_pe, rows, w):
@@ -506,8 +514,6 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
             with jax.named_scope("latent_walk"):
                 args = (q_row, flat, layer_tables(block_tables, i, NP), seq_lens, rows[:, 0], r, c.qk_head_dim)
                 if use_pallas or interpret:
-                    from ..ops.pallas.paged_attention import paged_latent_attention_cache_plus_new
-
                     o_lat = paged_latent_attention_cache_plus_new(*args, interpret=interpret)
                 else:
                     o_lat = latent_decode_attention_reference_cache_plus_new(*args)
@@ -523,7 +529,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         pool = commit_tokens(pool, {"kv": rows[:, :, 0, None, :]}, target, seq_lens % P)
-        cache = _committed(cache, pool, counts, jnp.sum(jnp.where(active, seq_lens + 1, 0)), expanded, 0)
+        cache = _committed(cache, pool, counts, jnp.sum(jnp.where(active, seq_lens + 1, 0)), expanded, 0, fetched)
     x = _final_norm(x[:, 0], params, c)
     return cache, _head_logits(x, params, c)
 
@@ -543,14 +549,19 @@ def describe_counters(config: KananaConfig, total) -> dict:
     ``rows_expanded`` the rows one layer put through ``_expand`` into
     per-head K and V, as the attention path that ran reports them (a
     bucket's padding rows and a continuation's whole gathered table among
-    them): 0 in decode, or the absorbed path is not what runs."""
+    them): 0 in decode, or the absorbed path is not what runs;
+    ``rows_fetched`` the cached rows one layer's attention fetched to cover
+    them (a decode step: the walk's whole turns, so the tail a wider turn
+    costs is ``rows_fetched`` over ``rows_read``; the XLA reference and a
+    continuation gather a lane's whole table; a whole prompt fetches none)."""
     c = config
     cut = 1 + COUNTS_HEAD + len(c.held)
     if total is None:
         total = [[0] * (cut + LATENT_COUNTS)] * 2
 
     def latent(r):
-        return {"steps": int(r[cut]), "rows_read": int(r[cut + 1]), "rows_expanded": int(r[cut + 2])}
+        return {"steps": int(r[cut]), "rows_read": int(r[cut + 1]), "rows_expanded": int(r[cut + 2]),
+                "rows_fetched": int(r[cut + 3])}
 
     moe = _describe_moe(c, [r[:cut] for r in total])["moe"]
     return {

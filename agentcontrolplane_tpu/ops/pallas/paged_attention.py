@@ -24,7 +24,7 @@ H_kv, d]``; the merge is then a copy of those pages on the chip's tiling.
 One program walks every slot (or as many as fit VMEM with their q and
 outputs: ``slots_per_program``), as ONE stream of turns. A turn of the walk
 covers G pages, G chosen so that a turn is one 128-lane tile of tokens
-(``pages_per_turn``: 8 at the engine's page 16, 1 at page 128): the turn's
+(``pages_per_turn``: 8 at page 16, 1 at page 128; a pool of one leaf: 32): the turn's
 pages are DMA'd each into its own row window of one ``[2, G * P, H_kv * d]``
 buffer (K and V) and the body runs one pair of products per KV head over all
 of them. The stream is every slot's turns in slot order, slots with nothing
@@ -84,19 +84,19 @@ attention, ``models/kanana.py``: 512 values of normed latent and 64 of the
 roped shared key). A page is ONE fetch into a ``[G * P, row]`` buffer, every
 query head scores against the whole row (one KV "head" of the row's width, a
 query group of all the heads) and the value is the row's first
-``value_width`` columns, read from the same buffer: ``acc += p R[:, :512]``.
-A row is 1,152 B and 69.6 kFLOP for 32 heads, 60 FLOP a byte where the GQA
-walks have 4 to 20, so the second product is not given the six passes of
-the f32 contract: with bf16 pages ``p`` goes in as a bf16 head and a bf16
-tail (two passes, exact in the pages' values, 2**-17 in ``p``). Same stream
-of turns, same (acc, m, l) contract; the absorbed projections on either side
-of it are the model's (``mla_absorb``). What a turn costs on a v5e (16 slots
-of 3,450 rows, 48 layers, cold pages; PERF.md, PR 44): 0.455 us, 2.5 times
-its bytes' time. The second product is not what holds it: one pass of ``p``
-for two takes 7% off, head and tail stacked into one 64-row product adds
-1%. Nor is the transposed key tile of ``q . K^T``: with the queries
-transposed outside and the keys streamed through them (``K . q^T``, one
-square transpose of the scores a turn) a turn took 25% longer.
+``value_width`` columns of the same buffer: ``acc += p R[:, :512]``, ``p`` a
+bf16 head and a bf16 tail over bf16 pages (two passes, exact in the pages'
+values, 2**-17 in ``p``; 60 FLOP a byte, so not the f32 contract's six).
+Same stream of turns, same (acc, m, l) contract. What a turn costs on a v5e
+(16 slots of ~3,600 rows, 48 layers, cold pages; PERF.md, PR 45): at ONE
+lane tile of rows 0.45 us, 2.5 times its bytes' time, the body alone 0.43
+and the fetches alone 0.26. The body is one chain (product, max, exp, sum,
+two products, rescale) where a K/V turn runs one a KV head side by side;
+neither the MXU's passes nor the key tile's transpose hold it (PR 44). So a
+turn is ``LATENT_TILES`` lane tiles (32 pages): four independent score
+products, one max, exp, sum and rescale for all, 0.26 us a 128 rows: the
+fetches' own time (8 at ~27 ns in the DMA engine, and their issue). The tail
+(a slot's last turn fetches its last page again) shows under 128 rows a slot.
 
 Tested in interpreter mode on CPU against the exact reference
 (tests/engine/test_paged*.py), compiled for a described v5e
@@ -115,7 +115,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 RING = 4  # turn buffers of the walk: RING - 1 turns' fetches run ahead of the fold
-LANES = 128  # a turn of the walk covers one lane tile of tokens
+LANES = 128  # a turn of the walk over K and V pages covers one lane tile of tokens
+LATENT_TILES = 4  # and over a pool of one leaf this many: its turn is one chain (module text)
 # scratch the walk may claim of the 16 MiB scoped VMEM a kernel gets by
 # default; the rest is the body's f32 windows and a program's q and outputs
 _SCRATCH_BUDGET = 8 << 20
@@ -127,19 +128,19 @@ _F32 = jax.lax.Precision.HIGHEST
 
 
 def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False, leaves: int = 2) -> int:
-    """G, the pages one turn of the walk fetches and folds: as many as make
-    a turn one lane tile of tokens, from what the kernel can see alone.
+    """G, the pages one turn of the walk fetches and folds, from what the
+    kernel can see alone: as many as make a turn one lane tile of tokens, or
+    ``LATENT_TILES`` tiles over a pool of ONE leaf (``leaves=1``: a latent row).
 
     G = 1 (a page a turn, each page DMA'd into a whole buffer) where a page
     cannot land on a whole-tile row window of a shared buffer — its rows
     must be a multiple of the dtype's sublane tile: 8 for f32, 16 for bf16,
     32 for int8 — and for int8 pages at any size: their scale rows are laid
     out head-major per page and do not follow a G-page turn. G halves until
-    the ring of K and V buffers (``leaves``: 2; 1 for a latent row, which is
-    both) fits ``_SCRATCH_BUDGET``.
+    the ring of buffers (K and V, or the one leaf) fits ``_SCRATCH_BUDGET``.
     """
     itemsize = jnp.dtype(dtype).itemsize
-    G = max(1, LANES // P_local)
+    G = max(1, (LATENT_TILES if leaves == 1 else 1) * LANES // P_local)
     if quantized or P_local % (32 // itemsize):
         G = 1
     while G > 1 and leaves * RING * G * P_local * H_kv * d * itemsize > _SCRATCH_BUDGET:
@@ -149,13 +150,12 @@ def pages_per_turn(P_local: int, dtype, H_kv: int, d: int, quantized: bool = Fal
 
 def fetches_in_flight(P_local: int, dtype, H_kv: int, d: int, quantized: bool = False,
                       leaves: int = 2) -> tuple[int, int]:
-    """(turns, bytes) of K and V (``leaves``: 1 for a latent row) the compiled walk keeps started ahead of
-    the turn it folds, from its first turn to its last and across slots:
-    ``RING - 1`` turns of ``pages_per_turn`` pages. Not scaled by the
-    turn's bytes: on a v5e the walk is bound by what a turn's fetches cost
-    the scalar unit to issue and by its two passes' latency, not by a
-    fetch's latency over the bytes in flight, and rings of 3, 6, 8 and 16
-    buffers all measured slower than 4 (PERF.md, PR 43)."""
+    """(turns, bytes) of K and V (``leaves``: 1 for a latent row) the compiled walk keeps started
+    ahead of the turn it folds, from its first turn to its last and across slots: ``RING - 1`` turns
+    of ``pages_per_turn`` pages. Not scaled by the turn's bytes: on a v5e the walk is bound by what
+    a turn's fetches cost the scalar unit to issue and by its two passes' latency, not by a fetch's
+    latency over the bytes in flight, and rings of 3, 6, 8 and 16 buffers all measured slower than
+    4 (PERF.md, PR 43; over one leaf 3, 5 and 6 no faster: PR 45)."""
     G = pages_per_turn(P_local, dtype, H_kv, d, quantized, leaves)
     return RING - 1, (RING - 1) * leaves * G * P_local * H_kv * d * jnp.dtype(dtype).itemsize
 

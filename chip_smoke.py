@@ -112,7 +112,8 @@ def phase_kernel(seed: int) -> None:
     import jax.numpy as jnp
 
     from agentcontrolplane_tpu.models.llama import PRESETS
-    from agentcontrolplane_tpu.engine.kernel_parity import make_paged_case, page_walk_parity
+    from agentcontrolplane_tpu.engine.kernel_parity import (
+        latent_walk_parity, make_latent_case, make_paged_case, page_walk_parity)
 
     c = PRESETS[PRESET]
     geometry = dict(S=16, H=c.n_heads, H_kv=c.n_kv_heads, d=c.head_dim,
@@ -130,6 +131,15 @@ def phase_kernel(seed: int) -> None:
                       f"ragged seq_lens {min(lens)}..{max(lens)}, "
                       f"{time.monotonic() - t0:.1f}s compile+run")
         check(got["ok"], "kernel", f"{name}: parity failed: {got}")
+    t0 = time.monotonic()
+    got = latent_walk_parity(make_latent_case(seed))
+    lens = got["seq_lens"]
+    say("kernel", f"compiled latent walk vs XLA reference (a pool of one leaf: 32 heads on a row of 640, value 512, "
+                  f"page 16, {got['pages_per_turn']} pages a turn), bf16 pages, unnamed pages NaN: "
+                  f"out {got['shape']} finite={got['finite']} max|err| {got['max_abs_err']:.2e} "
+                  f"(tolerance {got['tolerance']:.0e}), slots of {min(lens)}..{max(lens)} rows, "
+                  f"{time.monotonic() - t0:.1f}s compile+run")
+    check(got["ok"], "kernel", f"latent walk: parity failed: {got}")
 
 
 # -- serve -------------------------------------------------------------------

@@ -218,6 +218,25 @@ def test_the_walk_compiles_at_each_cells_geometry(v5e, case):
         f"{(scratch + 2 * whole) / 2**20:.1f} MiB of the kernel's 16")
 
 
+# (pages a turn, turns in flight, bytes in flight) of every K/V walk a cell runs, as PR 43 left them: PR 45 widened
+# the turn of a pool of ONE leaf alone, so these walks' kernels are the parent's text (same shapes, same body)
+_KV_WALKS_AS_PR43_LEFT_THEM = {
+    "qwen2.5-7b": (8, 3, 786_432), "qwen2.5-32b-a-chip-of-tp4": (8, 3, 393_216), "lfm2-24b-a2b": (8, 3, 786_432),
+    "jamba2-3b": (8, 3, 196_608), "mellum2-full-layers": (8, 3, 786_432), "mellum2-window-layers": (8, 3, 786_432),
+}
+
+
+@pytest.mark.parametrize("case", _CELL_WALKS[:6], ids=lambda c: c[0])
+def test_the_kv_walks_geometry_is_what_it_was_before_the_latent_turn_widened(case):
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    name, _, H, H_kv, d, _, _ = case
+    pack = pa.heads_per_window(d, H_kv)
+    geometry = (PAGE, jnp.bfloat16, H_kv // pack, pack * d)
+    assert (pa.pages_per_turn(*geometry), *pa.fetches_in_flight(*geometry)) == _KV_WALKS_AS_PR43_LEFT_THEM[name]
+    assert (pa.RING, pa.LANES, pa._SCRATCH_BUDGET) == (4, 128, 8 << 20)
+
+
 # -- the llama decode step over the pool stored as the walk reads it -----------
 
 # (id, preset, widths the preset lacks, the cell's pages, slots, tp)
@@ -937,6 +956,10 @@ def test_kanana_decode_block_walks_latent_rows_and_copies_no_pool(v5e, monkeypat
         params, cache, vec(S), vec(S), vec(S, 5120 // PAGE), vec(S, dt=jnp.bool_)).compile()
     text = compiled.as_text()
     assert "paged_latent_walk" in text and "paged_page_walk" not in text and text.count("tpu_custom_call") == 4
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    G = pa.pages_per_turn(PAGE, jnp.bfloat16, 1, c.row_stored, leaves=1)  # four lane tiles of rows a turn
+    assert G == 32 and pa.RING * G * PAGE * c.row_stored * 2 == 2_621_440 <= pa._SCRATCH_BUDGET
     pool = cache["kv"].size * 2
     mem = compiled.memory_analysis()
     assert cache["kv"].shape == (48, _KANANA_PAGES, PAGE, 640) and 5.0e9 < pool < 5.1e9 and mem.alias_size_in_bytes >= pool

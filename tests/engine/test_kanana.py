@@ -53,31 +53,45 @@ def built(config, seed=5):
 # -- the latent walk -----------------------------------------------------------------
 
 
+_TURN = 512  # rows a turn of the committed latent walk covers at page 16 (`pages_per_turn(..., leaves=1)`: 32 pages)
+
+
 @pytest.mark.parametrize("lens", [
-    (0, 1, 15, 16, 17, 127),  # nothing to walk, inside a page, across the first page boundaries, a turn less one
-    (128, 129, 255, 256, 257, 1000),  # across turn boundaries (8 pages of 16 a turn), many turns
-], ids=["pages", "turns"])
+    (0, 1, 15, 16, 17, 127),  # nothing to walk, inside a page, across the first page boundaries, a lane tile less one
+    (128, 129, 255, 256, 257, 1000),  # across lane tiles inside a turn, a second turn
+    # every boundary of the turn: 1, T - 1, T, T + 1, 2T, 2T + 1 rows, empty slots first, between and last,
+    # a slot of one turn after a long one
+    (0, 1, _TURN - 1, 0, _TURN, _TURN + 1, 2 * _TURN, 2 * _TURN + 1, 100, 0),
+], ids=["pages", "tiles", "turns"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 def test_the_latent_walk_agrees_with_its_reference(lens, dtype):
     """One fetch a page, the row both key and value: the interpreted kernel
     against `ops/paged.py`'s reference in the same absorbed form, at the
     published row (640 stored, value 512, scores over sqrt(192)), in float32
-    (exact) and in bfloat16 (the two-pass second product)."""
-    S, H, W, V, P, L = 6, 8, 640, 512, 16, 2
-    NP = 1 + S * 64
+    (exact) and in bfloat16 (the two-pass second product). Every page no
+    slot's rows reach is NaN: a turn always fetches its 32 pages, past the
+    walk's end the last live page again and never the table's next."""
+    S, H, W, V, P, L, M = len(lens), 8, 640, 512, 16, 2, 2 * _TURN // 16 + 1
+    assert pa.pages_per_turn(P, dtype, 1, W, leaves=1) * P == _TURN
+    NP = 1 + S * M
     key = jax.random.key(0)
-    pages = jax.random.normal(jax.random.fold_in(key, 1), (L * NP, P, W), jnp.float32).astype(dtype)
+    clean = jax.random.normal(jax.random.fold_in(key, 1), (L * NP, P, W), jnp.float32).astype(dtype)
     q = (jax.random.normal(jax.random.fold_in(key, 2), (S, H, W), jnp.float32) * 0.3).astype(dtype)
     new = jax.random.normal(jax.random.fold_in(key, 3), (S, W), jnp.float32).astype(dtype)
     n = jnp.asarray(lens, jnp.int32)
-    tables = paged.layer_tables(1 + jnp.arange(S * 64, dtype=jnp.int32).reshape(S, 64), 1, NP)
-    want = paged.latent_decode_attention_reference_cache_plus_new(q, pages, tables, n, new, V, 192)
+    tables = paged.layer_tables(1 + jnp.arange(S * M, dtype=jnp.int32).reshape(S, M), 1, NP)
+    named = np.zeros((L * NP,), bool)
+    for s, rows_held in enumerate(lens):
+        named[np.asarray(tables)[s, : -(-rows_held // P)]] = True
+    pages = jnp.where(jnp.asarray(named)[:, None, None], clean, jnp.nan)
+    want = paged.latent_decode_attention_reference_cache_plus_new(q, clean, tables, n, new, V, 192)
     got = pa.paged_latent_attention_cache_plus_new(q, pages, tables, n, new, V, 192, interpret=True)
     assert got.shape == (S, H, V)
+    assert np.isfinite(np.asarray(got, np.float32)).all(), "a walk read a page that is not its own"
     tol = 5e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=tol)
     # and against plain attention over the rows laid out by position
-    rows = np.asarray(pages.astype(jnp.float32))[np.asarray(tables)].reshape(S, 64 * P, W)
+    rows = np.asarray(clean.astype(jnp.float32))[np.asarray(tables)].reshape(S, M * P, W)
     for s in range(S):
         ctx = np.concatenate([rows[s, : lens[s]], np.asarray(new.astype(jnp.float32))[s][None]])
         logits = np.asarray(q.astype(jnp.float32))[s] @ ctx.T * 192 ** -0.5
@@ -88,15 +102,25 @@ def test_the_latent_walk_agrees_with_its_reference(lens, dtype):
 
 def test_the_walk_reports_one_fetch_a_page_and_serves_the_published_row():
     """The engine asks `models.programs(...).walk` for the geometry: a latent
-    row is one leaf, so a turn in flight is half the bytes of K and V pages
-    of the same width; a row whose value is not whole lane tiles has no
-    kernel (the tiny config: the reference serves)."""
+    row is one leaf and its turn is four lane tiles of rows (32 pages of 16:
+    one chain a turn, so the turn is wide; `paged_attention.py`, "Latent
+    walk"), which keeps twice the bytes in flight that K and V pages of the
+    same width keep at a tile a turn; a row whose value is not whole lane
+    tiles has no kernel (the tiny config: the reference serves)."""
     full, small = preset("kanana-2-30b-a3b-ep16"), preset("kanana-tiny")
     assert (full.row_width, full.row_stored, full.head_dim, full.n_kv_heads) == (576, 640, 640, 1)
     G, turns, in_flight = programs(full).walk(full, 16, jnp.bfloat16, 1, False)
-    assert (G, turns) == (8, pa.RING - 1) and in_flight == turns * 8 * 16 * 640 * 2
-    assert pa.fetches_in_flight(16, jnp.bfloat16, 1, 640)[1] == 2 * in_flight
+    assert (G, turns) == (32, pa.RING - 1) and G * 16 == _TURN and in_flight == turns * 32 * 16 * 640 * 2 == 1_966_080
+    assert pa.RING * 32 * 16 * 640 * 2 <= pa._SCRATCH_BUDGET
+    assert pa.fetches_in_flight(16, jnp.bfloat16, 1, 640) == (turns, in_flight // 2)  # K and V pages: 8 a turn, two leaves
     assert programs(small).walk(small, 8, jnp.float32, 1, False) is None
+    # every other family's walk is the parent's: a lane tile a turn
+    for name, page_rows, want in [("qwen2.5-7b", 16, (8, 3, 786_432)), ("lfm2-24b-a2b-ep8", 16, (8, 3, 786_432)),
+                                  ("jamba2-3b", 16, (8, 3, 196_608)), ("mellum2-12b-a2.5b-ep4", 16, (8, 3, 786_432))]:
+        cfg = preset(name)
+        assert programs(cfg).walk(cfg, page_rows, jnp.bfloat16, 1, False) == want, name
+    big = preset("qwen2.5-7b")
+    assert programs(big).walk(big, 16, jnp.bfloat16, 2, False) == (8, 3, 393_216)  # two KV heads a chip
 
 
 # -- the program against the plain reference ------------------------------------------
@@ -159,10 +183,14 @@ def test_absorbed_and_expanded_agree_in_float32_and_decode_expands_no_row():
     close(rows(cache), rows(whole))
     assert float(np.abs(rows(cache)[..., cfg.row_width:]).max()) == 0.0  # the padding columns stay zero
     latent = kanana.describe_counters(cfg, np.asarray(cache["state"]["counts"]))["latent"]
-    assert latent["decode"] == {"steps": T - mid, "rows_read": B * sum(range(mid + 1, T + 1)), "rows_expanded": 0}
+    # whole turns where the kernel walked (the even steps; a turn here is 64 pages of 8), the table of 4 pages where the reference gathered
+    fetched = B * sum(64 * P if t % 2 == 0 else 4 * P for t in range(mid, T))
+    assert latent["decode"] == {"steps": T - mid, "rows_read": B * sum(range(mid + 1, T + 1)), "rows_expanded": 0,
+                                "rows_fetched": fetched}
     # rows as `_expand` took them: the prefill's 32 with its padding, the continuation's whole table of 4 pages and its 32
     assert latent["prefill"]["rows_read"] == B * (cut + mid) and latent["row_values"] == cfg.row_width
     assert latent["prefill"]["rows_expanded"] == B * (32 + 4 * P + 32)
+    assert latent["prefill"]["rows_fetched"] == B * 4 * P  # the continuation's gathered table; a whole prompt fetches none
 
 
 @pytest.mark.parametrize("program,expanded", [("prefill", 2 * 32), ("continuation", 2 * (4 * 8 + 16)), ("decode", 0)])

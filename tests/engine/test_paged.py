@@ -608,35 +608,58 @@ def test_every_fetch_is_started_once_and_waited_for_once(order, monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "P_local,dtype,H_kv,d,quantized,want",
+    "P_local,dtype,H_kv,d,quantized,leaves,want",
     [
-        (16, "bfloat16", 4, 128, False, 8),   # the engine's page: a lane tile a turn
-        (16, "float32", 8, 128, False, 8),
-        (128, "bfloat16", 4, 128, False, 1),  # a page is a tile already
-        (8, "bfloat16", 4, 128, False, 1),    # sp=2 slice of page 16: under a bf16 tile
-        (8, "float32", 4, 128, False, 16),    # the same slice in f32: on its tile
-        (16, "int8", 4, 128, True, 1),        # int8 tile is 32 rows; scale rows per page
-        (32, "int8", 4, 128, True, 1),
-        (16, "float32", 32, 128, False, 4),   # MHA 32 heads f32: scratch over budget, halved
-        (16, "float32", 64, 128, False, 2),   # and halved again
+        (16, "bfloat16", 4, 128, False, 2, 8),   # the engine's page: a lane tile a turn
+        (16, "float32", 8, 128, False, 2, 8),
+        (128, "bfloat16", 4, 128, False, 2, 1),  # a page is a tile already
+        (8, "bfloat16", 4, 128, False, 2, 1),    # sp=2 slice of page 16: under a bf16 tile
+        (8, "float32", 4, 128, False, 2, 16),    # the same slice in f32: on its tile
+        (16, "int8", 4, 128, True, 2, 1),        # int8 tile is 32 rows; scale rows per page
+        (32, "int8", 4, 128, True, 2, 1),
+        (16, "float32", 32, 128, False, 2, 4),   # MHA 32 heads f32: scratch over budget, halved
+        (16, "float32", 64, 128, False, 2, 2),   # and halved again
         # the cells: the 7B and mellum2, a chip of the 32B at tp=4, lfm2's
         # four lane windows of two heads of 64, jamba2's one KV head
-        (16, "bfloat16", 2, 128, False, 8),
-        (16, "bfloat16", 4, 128, False, 8),   # lfm2: 8 KV heads of 64 walk as 4 windows of 128
-        (16, "bfloat16", 1, 128, False, 8),
+        (16, "bfloat16", 2, 128, False, 2, 8),
+        (16, "bfloat16", 4, 128, False, 2, 8),   # lfm2: 8 KV heads of 64 walk as 4 windows of 128
+        (16, "bfloat16", 1, 128, False, 2, 8),
+        # a pool of one leaf (a latent row, key and value at once): four lane tiles a turn
+        (16, "bfloat16", 1, 640, False, 1, 32),  # kanana2: 512 rows a turn
+        (16, "float32", 1, 640, False, 1, 32),
+        (128, "bfloat16", 1, 640, False, 1, 4),
+        (8, "bfloat16", 1, 640, False, 1, 1),    # under a bf16 tile: a page a turn, as K and V pages
+        (8, "float32", 1, 640, False, 1, 64),
+        (16, "float32", 1, 8192, False, 1, 4),   # a row of 32 KB: halved to the scratch budget
     ],
 )
-def test_pages_per_turn_rule(P_local, dtype, H_kv, d, quantized, want):
+def test_pages_per_turn_rule(P_local, dtype, H_kv, d, quantized, leaves, want):
     """G by geometry, and beside it what the walk keeps in flight: RING - 1
-    turns of G pages, K and V, whatever a turn's bytes (deeper rings
-    measured slower on the chip: PERF.md, PR 43), in a ring that holds to
-    the scratch budget."""
+    turns of G pages, K and V or a pool's one leaf, whatever a turn's bytes
+    (deeper rings measured slower on the chip: PERF.md, PR 43; no faster
+    over one leaf: PR 45), in a ring that holds to the scratch budget."""
     from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
 
-    assert pa.pages_per_turn(P_local, jnp.dtype(dtype), H_kv, d, quantized) == want
-    turn = 2 * want * P_local * H_kv * d * jnp.dtype(dtype).itemsize
-    assert pa.fetches_in_flight(P_local, jnp.dtype(dtype), H_kv, d, quantized) == (pa.RING - 1, (pa.RING - 1) * turn)
+    assert pa.pages_per_turn(P_local, jnp.dtype(dtype), H_kv, d, quantized, leaves) == want
+    turn = leaves * want * P_local * H_kv * d * jnp.dtype(dtype).itemsize
+    assert pa.fetches_in_flight(P_local, jnp.dtype(dtype), H_kv, d, quantized, leaves) == (pa.RING - 1, (pa.RING - 1) * turn)
     assert pa.RING * turn <= pa._SCRATCH_BUDGET
+    if leaves == 2:  # the default is K and V pages
+        assert pa.pages_per_turn(P_local, jnp.dtype(dtype), H_kv, d, quantized) == want
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("turns", [(0, 3, 1, 0, 2, 0), (2, 0), (0, 0)], ids=["empty-around", "empty-last", "every-slot-empty"])
+def test_the_latent_parity_case_the_chip_runs_holds_interpreted(turns, dtype):
+    """`kernel_parity.latent_walk_parity` is what `chip_smoke.py` and
+    `test_tpu_hardware.py` run compiled: the same case (fewer turns, 8
+    heads) through the interpreter, unnamed pages NaN."""
+    from agentcontrolplane_tpu.engine.kernel_parity import latent_walk_parity, make_latent_case
+
+    case = make_latent_case(3, H=8, turns=turns, dtype=jnp.dtype(dtype))
+    assert case["pages_per_turn"] == 32 and bool(jnp.isnan(case["pages"]).any())
+    got = latent_walk_parity(case, interpret=True)
+    assert got["ok"] and got["shape"] == (len(turns), 8, 512), got
 
 
 def test_excluded_geometry_walks_one_page_a_turn():
@@ -876,26 +899,27 @@ def test_the_pool_helpers_take_a_pools_leaves_as_they_come(kind):
     assert set(paged.pool_leaves({**pool, "state": {"x": 1}})) == set(pool)
 
 
-def test_every_fetch_of_the_latent_walk_is_started_once_and_waited_for_once(monkeypatch):
-    """The latent walk on the same stream of turns: ONE fetch a page (a
-    turn's G starts, then its one wait), as many waits as the batch has
-    turns, nothing left started."""
+@pytest.mark.parametrize("turns", [[0, 3, 1, 0, 2, 1], [0, 0, 5], [1, 0, 0], [0, 0]],
+                         ids=["empty-between", "empty-first", "empty-last", "every-slot-empty"])
+def test_every_fetch_of_the_latent_walk_is_started_once_and_waited_for_once(turns, monkeypatch):
+    """The latent walk on the same stream of turns at its own geometry, four
+    lane tiles of rows a turn: ONE fetch a page (a turn's G = 32 starts,
+    then its one wait), as many waits as the batch has turns, nothing left
+    started; a slot's last turn ends a page short of whole or on one page."""
+    from agentcontrolplane_tpu.engine.kernel_parity import make_latent_case
     from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
 
     events = _spy_on_fetches(monkeypatch)
-    pages_of = [0, 3 * _G, 1, 0, 2 * _G - 1, _G]
-    S, H, W, V, P, M = len(pages_of), 4, 256, 128, 16, 3 * _G
-    key = jax.random.key(2)
-    pool = jax.random.normal(key, (1 + S * M, P, W), jnp.float32)
-    q = jax.random.normal(jax.random.fold_in(key, 1), (S, H, W), jnp.float32)
-    tables = 1 + jnp.arange(S * M, dtype=jnp.int32).reshape(S, M)
-    lens = jnp.asarray([max(0, n * P - 3) for n in pages_of], jnp.int32)
-    out = pa.paged_latent_state(q, pool, tables, lens, V, 192, interpret=True)
+    c = make_latent_case(2, H=4, width=256, value_width=128, turns=tuple(turns), dtype=jnp.float32)
+    G = c["pages_per_turn"]
+    assert G == pa.LATENT_TILES * pa.LANES // 16 == 32
+    out = pa.paged_latent_state(c["q"], c["pages"], c["block_tables"], c["seq_lens"], 128, 192, interpret=True)
     jax.block_until_ready(out)
     jax.effects_barrier()
-    turns = sum(-(-n // _G) for n in pages_of)
-    assert sum(k for k, _, _ in events) == turns
+    pages_of = [-(-int(n) // 16) for n in c["seq_lens"]]
+    total = sum(-(-n // G) for n in pages_of)
+    assert total == sum(turns) and sum(k for k, _, _ in events) == total
     for buf in range(pa.RING):
         mine = [k for k, b, _ in events if b == buf]
-        assert mine == ([0] * _G + [1]) * (len(mine) // (_G + 1)), (buf, mine)
-    assert len(events) == turns * (_G + 1)
+        assert mine == ([0] * G + [1]) * (len(mine) // (G + 1)), (buf, mine)
+    assert len(events) == total * (G + 1)

@@ -83,6 +83,26 @@ def test_compiled_stream_across_slots_matches_reference(tpu, order, int8):
         _stream_parity(c, plus_new, 2e-2, interpret=False)
 
 
+_LATENT_TURNS = {  # turns a slot; the first is `kernel_parity.LATENT_TURNS`, what `chip_smoke.py` runs
+    "empty-first-between-and-last": {},
+    "empty-last": {"turns": (10, 4, 3, 2, 1, 0)},
+    "every-slot-empty": {"turns": (0, 0, 0)},
+    "one-slot-alone": {"turns": (4,)},
+}
+
+
+@pytest.mark.parametrize("order", list(_LATENT_TURNS))
+def test_compiled_latent_walk_matches_reference(tpu, order):
+    """The latent walk COMPILED at its own geometry (a pool of one leaf: 32
+    pages a turn at page 16, 32 heads on a row of 640): slots of 0 to 10
+    turns, empty ones first, between and last, every page no slot's rows
+    reach NaN. A wait on a fetch never started hangs here."""
+    from agentcontrolplane_tpu.engine.kernel_parity import latent_walk_parity, make_latent_case
+
+    got = latent_walk_parity(make_latent_case(9, **_LATENT_TURNS[order]))
+    assert got["pages_per_turn"] == 32 and got["ok"], got
+
+
 def test_engine_slot_and_paged_agree_on_tpu(tpu):
     """Greedy decode through BOTH kv layouts on hardware must produce the
     same tokens (the paged path uses the compiled Pallas kernel: engine
