@@ -180,6 +180,30 @@ def _add_tpu_flags(p) -> None:
     )
 
 
+def _kv_pages_from_memory(args) -> int:
+    """A preset's paged pool sized from the device's memory where the device
+    reports it (0: it does not, as the CPU; the engine's own default stands):
+    a page's bytes come from the family's pool itself (``models.page_bytes``),
+    so a family whose cache is deeper than its weights is not handed a pool
+    several times the chip."""
+    import jax
+
+    from .models import kv_pages_that_fit, preset, programs
+
+    limit = (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit", 0)
+    if not limit:
+        return 0
+    config = preset(args.tpu_preset)
+    weights = jax.eval_shape(lambda: programs(config).init_params(config, jax.random.key(0)))
+    weight_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(weights))
+    if args.tpu_quantize_weights or args.tpu_quantize:
+        weight_bytes //= 2
+    chips = max(1, args.tpu_tp)  # the llama family splits weights and pool alike over tp
+    ctx = min(args.tpu_ctx, config.max_seq_len)
+    return kv_pages_that_fit(config, args.tpu_slots, ctx, 16, limit * chips, weight_bytes,
+                             quantize_kv=args.tpu_quantize_kv)
+
+
 def _build_engine(args, coordination=None, **engine_kw):
     """Engine construction shared by `run` (leader/single-host) and
     `engine-follower` — multi-host lockstep requires every rank to build
@@ -211,6 +235,10 @@ def _build_engine(args, coordination=None, **engine_kw):
         coordination=coordination,
     )
     kw.update(engine_kw)
+    if args.tpu_kv_layout == "paged" and args.tpu_preset and "kv_pages" not in kw:
+        pages = _kv_pages_from_memory(args)
+        if pages:
+            kw["kv_pages"] = pages
     if args.tpu_tp or args.tpu_sp > 1 or args.tpu_ep > 1:
         from .parallel.mesh import serving_mesh
 

@@ -49,7 +49,7 @@ import numpy as np
 from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-exported names)
 from ..observability import scopes
 from ..observability.metrics import REGISTRY
-from ..ops.paged import TRASH_PAGE, pool_leaves, ring_size, set_pages
+from ..ops.paged import TRASH_PAGE, page_bytes, pool_leaves, ring_size, set_pages
 from ..ops.sampling import NEG_INF, masks_wanted, sample
 from ..parallel.mesh import (
     kv_cache_shardings,
@@ -683,6 +683,11 @@ class Engine:
             "kv_layout": kv_layout, "spec_len": spec_len, "tp": tp, "sp": sp,
             "quantize_weights": bool(quantize) or quantize_weights, "quantize_kv": bool(quantize_kv),
             "coordination": coordination is not None, "host_kv_bytes": host_kv_bytes,
+            # the most rows one prefill dispatch stacks before its commit,
+            # beside the rows the paged pool holds (a family whose cache is
+            # deeper than its weights bounds the one by the other)
+            "prefill_rows": max(1, prefill_batch_max) * max(self.prefill_buckets),
+            "pool_rows": (kv_pages * page_size) or (max_slots * self.max_ctx + page_size),
         }
         for hit, why in self._model.refusals(asked):
             if hit:
@@ -713,9 +718,9 @@ class Engine:
                     f"max_ctx={self.max_ctx} must be divisible by the mesh's "
                     f"sp={sp} for context-parallel serving"
                 )
-        if (self.config.attn_logit_softcap or self.config.post_norms) and kv_layout == "paged":
+        if self.config.attn_logit_softcap and kv_layout == "paged":
             raise ValueError(
-                "gemma-2-style models (attention soft-cap / post-norms) serve "
+                "gemma-2-style models (attention soft-cap) serve "
                 "with kv_layout='slot' — the paged attention kernel has no "
                 "soft-cap path"
             )
@@ -1507,6 +1512,9 @@ class Engine:
             self.cache = jax.jit(  # acp: donated
                 init_cache, out_shardings=page_shardings
             )()
+            # what one page costs over every cache layer and leaf (scale
+            # twins among them), read off the pool and not off the config
+            self.page_bytes = page_bytes(self.cache, self.num_pages)  # acp: mirror (immutable)
             self._allocator = PageAllocator(
                 self.num_pages, track_scales=self.quantize_kv
             )
@@ -1519,6 +1527,9 @@ class Engine:
             self._block_tables = np.full(
                 (self.max_slots, self.max_pages_per_seq), TRASH_PAGE, dtype=np.int32
             )
+        # the cache's depth, off the leaf (a family's pool may be deeper than its weights)
+        leaf = self.cache[self._model.page_leaf] if self.kv_layout == "paged" else _a_leaf(self.cache)
+        self.cache_layers = int(leaf.shape[0])  # acp: mirror (immutable)
 
     def start(self) -> None:
         if self._thread is not None:
@@ -2073,6 +2084,9 @@ class Engine:
             "model": {
                 "dim": self.config.dim,
                 "layers": self.config.n_layers,
+                # the cache's depth, off the leaf: a family may keep more
+                # cache layers than it has layers of weights (models/ouro.py)
+                "cache_layers": self.cache_layers,
                 "vocab": self.config.vocab_size,
                 "quantize": self.quantize,
                 "quantize_kv": self.quantize_kv,
@@ -2239,6 +2253,8 @@ class Engine:
                 "turns_in_flight": self.turns_in_flight,
                 "bytes_in_flight": self.bytes_in_flight,
                 "table_uploads": self.table_uploads,
+                # what one page costs over every cache layer and leaf
+                "page_bytes": self.page_bytes,
             }
             model = programs(self.config)  # not the engine thread's fields: any thread asks
             if model.has_state:

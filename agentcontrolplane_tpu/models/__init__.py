@@ -40,12 +40,18 @@ takes on a TPU as ``(pages_per_turn, turns_in_flight, bytes_in_flight)``, or
 None where the geometry falls to the XLA reference; ``page_leaf`` names the
 leaf of the cache whose pages that walk fetches (the engine reads a page's
 rows and dtype off it; a family may keep other leaves beside it, as
-``mellum``'s rings, that are not shaped so).
+``mellum``'s rings, that are not shaped so). The pool's DEPTH is the
+family's too, and the leaf's axis 0 is the only place it is read from:
+``models.ouro`` runs one stack of layers ``loops`` times over the same
+weights and keeps a cache layer a loop and layer (192 over 48 layers of
+weights), so ``config.n_layers`` counts its weights and
+``Engine.stats()["model"]["cache_layers"]``, the leaf's, its cache;
+:func:`page_bytes` gives what a page costs from the pool's own shapes.
 """
 
 from types import SimpleNamespace
 
-from . import jamba, kanana, lfm2, llama, mellum
+from . import jamba, kanana, lfm2, llama, mellum, ouro
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -59,21 +65,50 @@ from .jamba import JambaConfig
 from .kanana import KananaConfig
 from .lfm2 import Lfm2Config
 from .mellum import MellumConfig
+from .ouro import OuroConfig
 
 __all__ = [
-    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "decode_step", "forward", "init_kv_cache",
-    "init_params", "prefill", "preset", "programs",
+    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "OuroConfig", "decode_step", "forward", "init_kv_cache",
+    "init_params", "kv_pages_that_fit", "page_bytes", "prefill", "preset", "programs",
 ]
 
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro)]
     for table in tables:
         if name in table:
             return table[name]
     known = [n for table in tables for n in sorted(table)]
     raise KeyError(f"unknown model preset {name!r}; known: {', '.join(known)}")
+
+
+def page_bytes(config, page_size: int, quantize_kv: bool = False) -> int:
+    """Bytes one page of ``config``'s paged pool costs over every cache layer
+    and leaf (scale twins among them), read off the pool the family's own
+    ``init_paged_cache`` makes and not off the config's layer count: a family
+    may keep more cache layers than layers of weights (``models/ouro.py``),
+    or fewer (``lfm2``, ``jamba``: the attention layers alone)."""
+    import jax
+
+    from ..ops import paged
+
+    probe = 3  # pages: what tells the page list's leaves from a ring's
+    cache = jax.eval_shape(lambda: programs(config).init_paged_cache(
+        config, probe, page_size, quantize_kv=quantize_kv, max_slots=1))
+    return paged.page_bytes(cache, probe)
+
+
+def kv_pages_that_fit(config, max_slots: int, max_ctx: int, page_size: int, memory_bytes: int, weight_bytes: int,
+                      quantize_kv: bool = False, headroom: float = 0.9) -> int:
+    """The pool a paged engine is given where none is asked for: what the
+    slots could fill (``max_slots x max_ctx`` tokens and the trash page), cut
+    to what ``headroom`` of the device's memory holds beside the weights, at
+    :func:`page_bytes` a page. At 1.5 MiB a token the CLI's 64 slots of 2,048
+    tokens would ask a 16 GB chip for 206 GB."""
+    wanted = max_slots * (max_ctx // page_size) + 1
+    fits = int(headroom * memory_bytes - weight_bytes) // page_bytes(config, page_size, quantize_kv)
+    return max(2, min(wanted, fits))
 
 
 def _kv_walk(config, page_rows: int, dtype, tp: int, quantize_kv: bool):
@@ -197,8 +232,22 @@ _KANANA = SimpleNamespace(
     decode_step_paged=kanana.decode_step_paged,
     counters=kanana.counters, describe_counters=kanana.describe_counters,
 )
+# the dense family's layer run `loops` times: a pool `loops` times as deep as
+# the weights (its leaf's axis 0, `stats()["model"]["cache_layers"]`), the
+# dense family's walk at its own head geometry, counters beside the pages.
+# Its config derives from LlamaConfig: the MRO finds this row first
+_OURO = SimpleNamespace(
+    family="ouro", has_state=False, window_cache=False,
+    refusals=ouro.refusals, shardings=None, walk=_kv_walk, page_leaf="k",
+    init_params=ouro.init_params, init_paged_cache=ouro.init_paged_cache,
+    prefill_paged_batch=ouro.prefill_paged_batch,
+    prefill_paged_continue=ouro.prefill_paged_continue,
+    prefill_paged_continue_kv=ouro.prefill_paged_continue_kv,
+    decode_step_paged=ouro.decode_step_paged,
+    counters=ouro.counters, describe_counters=ouro.describe_counters,
+)
 _FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM,
-             KananaConfig: _KANANA}
+             KananaConfig: _KANANA, OuroConfig: _OURO}
 
 
 def programs(config) -> SimpleNamespace:

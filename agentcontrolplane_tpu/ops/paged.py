@@ -106,6 +106,15 @@ def pool_leaves(cache: dict) -> dict:
     return {name: a for name, a in cache.items() if name != "state"}
 
 
+def page_bytes(cache: dict, num_pages: int) -> int:
+    """Bytes one page costs over every cache layer and page-shaped leaf of
+    ``cache`` (arrays or their shapes; scale twins among them): read off the
+    leaves, whose axis 0 is the pool's depth whatever the config's layer
+    count is. A leaf with another page count (a window ring's pool) is not
+    the page list's."""
+    return sum(a.size * a.dtype.itemsize for a in pool_leaves(cache).values() if a.shape[1] == num_pages) // num_pages
+
+
 def kv_commit(pool: dict, new: dict, setter) -> dict:
     """Fresh rows ``{leaf: [L, ..., heads, d]}`` into the pool through
     ``setter(array, values)``, leaf by leaf: heads merged into the pool's

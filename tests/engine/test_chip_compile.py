@@ -166,6 +166,8 @@ _CELL_WALKS = [
     ("mellum2-window-layers", 32, 32, 4, 128, 1024 // PAGE + 1, 1024 // PAGE + 1),
     # no cell: a batch whose q and outputs do not fit one program's VMEM
     ("llama3-8b-at-256-slots", 256, 32, 8, 128, 2048 // PAGE, 0),
+    # plain multi-head attention: sixteen KV heads a chip, a query group of ONE (sixteen chains of one query row a turn)
+    ("ouro-2.6b", 8, 16, 16, 128, 640 // PAGE, 0),
 ]
 _SLOTS_A_PROGRAM = {128: 64, 256: 16}  # every cell's slots in one program; jamba2's 128 in two
 
@@ -1003,3 +1005,83 @@ def test_the_latent_walk_refuses_a_row_that_is_not_whole_lane_tiles(v5e):
     assert "paged_latent_walk" in walk(640).as_text()
     with pytest.raises(Exception, match="aligned to tiling"):
         walk(576)
+
+
+# -- the ouro family: a stack run four times over shared weights, a pool four times as deep ---------------
+
+_OURO_SLOTS, _OURO_PAGES = 8, 321  # acpbench/configs/ouro-2.6b-bf16-v5e1.json
+
+
+def _ouro(v5e):
+    """The published config whole, abstract weights and the pool of 192 cache
+    layers placed on one described chip."""
+    from agentcontrolplane_tpu.models import ouro
+
+    c = ouro.PRESETS["ouro-2.6b"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: ouro.init_params(c, jax.random.key(0))))
+    cache = place(jax.eval_shape(lambda: ouro.init_paged_cache(c, _OURO_PAGES, PAGE, max_slots=_OURO_SLOTS)))
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return ouro, c, params, cache, vec
+
+
+def test_ouro_decode_block_walks_192_cache_layers_and_copies_neither_pool_nor_weight(v5e):
+    """8 lanes of the published model, steps in a loop as the engine's decode
+    block nests them (steps, loops, layers: three scans deep): ONE kernel,
+    the page walk at 16 KV heads and a query group of one, which the chip's
+    compiler takes as it stands; the pool (8.08 GB) aliased from argument to
+    result, no op copies it or a layer of it; and no weight is relaid before
+    the first step: with `wq` and `wk` inputs first the block copied both
+    stacks transposed, 0.81 GB of temporaries (PERF.md, PR 46)."""
+    import re
+
+    ouro, c, params, cache, vec = _ouro(v5e)
+    S = _OURO_SLOTS
+
+    def block(p, ca, tok, n, tables, active):
+        def step(carry, _):
+            ca, tok, n = carry
+            ca, logits = ouro.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (ca, tok, n + 1), tok
+
+        (ca, _, _), toks = jax.lax.scan(step, (ca, tok, n), None, length=4)
+        return ca, toks
+
+    compiled = jax.jit(block, donate_argnums=(1,)).lower(
+        params, cache, vec(S), vec(S), vec(S, 640 // PAGE), vec(S, dt=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "paged_page_walk" in text and text.count("tpu_custom_call") == 1
+    pool = 2 * cache["k"].size * 2
+    mem = compiled.memory_analysis()
+    assert cache["k"].shape == (192, _OURO_PAGES, PAGE, 2048) and 8.07e9 < pool < 8.09e9 and mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 50e6, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB: a weight is relaid"
+    shape = rf"bf16\[192,{_OURO_PAGES},{PAGE},2048\]"
+    assert re.search(shape, text) and not re.search(rf"= {shape}\S* copy\(", text), "a copy of the whole pool"
+    assert f"bf16[{_OURO_PAGES},{PAGE},2048]" not in text, "one cache layer of the pool as a value of its own"
+    assert not re.search(r"= bf16\[48,\d+,\d+\]\S* copy\(", text), "a stack of weights copied"
+    assert 0.83 * 16e9 < _resident(compiled) < 13.6e9, f"{_resident(compiled) / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("program", ["prefill", "continuation"])
+def test_ouro_prefill_of_two_rows_of_256_fits_beside_the_resident_set(v5e, program):
+    """The widest dispatch the configuration allows (`prefill_batch_max` 2 x
+    the 256 bucket): weights and the pool resident, the stacked new rows of
+    192 cache layers (0.81 GB) and their way into whole pages beside them,
+    under 15.2 GB; no walk is in it."""
+    ouro, c, params, cache, vec = _ouro(v5e)
+    B, T = 2, 256
+    if program == "prefill":
+        fn = lambda p, ca, t, n, ids: ouro.prefill_paged_batch(p, ca, t, n, ids, c)  # noqa: E731
+        args = (vec(B, T), vec(B), vec(B, T // PAGE))
+    else:
+        fn = lambda p, ca, t, n, st, ids, tb: ouro.prefill_paged_continue(p, ca, t, n, st, ids, tb, c)  # noqa: E731
+        args = (vec(B, T), vec(B), vec(B), vec(B, T // PAGE), vec(B, 640 // PAGE))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cache["k"].size * 2
+    assert mem.temp_size_in_bytes < 1.7e9, f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB"
+    assert _resident(compiled) < 15.2e9, f"{_resident(compiled) / 1e9:.2f} GB"
