@@ -427,6 +427,17 @@ def _attn_mlp(
                 q = q + layer["bq"]
                 k = k + layer["bk"]
                 v = v + layer["bv"]
+            # q and k exist as [B, T, heads * head_dim] before they are split to
+            # heads. Without the barrier the chip's compiler folds the reshape
+            # below into the two products, wants each weight heads-major, and so
+            # in every decode program cuts the layer's wq / wk out of its stack
+            # into fast memory as an op of its own, relays it there, only then
+            # multiplies, and copies the whole stacks once a block into the
+            # layout the cut wants (`constant_dynamic-slice_fusion.9
+            # s8[1,3584,3584]`, `.8 s8[1,3584,512]`, `copy.44 s8[28,3584,3584]`,
+            # `copy.43` on the 7B: 0.80 ms of a 13.27 ms step; PERF.md, PR 48).
+            # The identity on values; v is not roped and was never staged.
+            q, k = jax.lax.optimization_barrier((q, k))
             q = q.reshape(B, T, c.n_heads, c.head_dim)
             k = k.reshape(B, T, c.n_kv_heads, c.head_dim)
             v = v.reshape(B, T, c.n_kv_heads, c.head_dim)

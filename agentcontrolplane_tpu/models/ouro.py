@@ -145,9 +145,9 @@ def _run_loops(params, c: OuroConfig, x, positions, make_attn, pick, scope: str 
     L = c.n_layers
     norm_w = _final_norm_w(params, c)
     # the query and key projections are indexed where they are used and not
-    # by the scan: the compiler reads a layer's slice of each into fast
-    # memory as an op of its own (a sixth of a decode step's weight bytes),
-    # which so carries the scope of the product it is for
+    # by the scan: the read of a layer's row (a sixth of a decode step's
+    # weight bytes) so carries the scope of the product it is for, where the
+    # scan's own slicing carries none
     indexed = {name: params["layers"][name] for name in OUT_FIRST}
     scanned_layers = {name: a for name, a in params["layers"].items() if name not in OUT_FIRST}
 

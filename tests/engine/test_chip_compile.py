@@ -492,6 +492,66 @@ def test_the_decode_block_runs_its_threshold_searches_only_when_asked(v5e, case,
         assert not set(change.collectives()) - set(parent.collectives())
 
 
+# -- the q and k projections: plain matmuls, their weights read where they lie -------------------
+
+
+def _staged_projections(text: str, stacks) -> list:
+    """Instructions of a compiled program that stage a projection's weight:
+    a `fusion` or `copy` whose result is ONE layer's slice of a stack
+    (`[1, in, out]`) in fast memory (memory space 1, `S(1)`), or a `copy`
+    of a whole stack. ``stacks``: the `[L, in, out]` shapes a chip holds."""
+    import re
+
+    out = []
+    for L, rows, cols in set(stacks):
+        one, whole = rf"(?:s8|bf16)\[1,{rows},{cols}\]", rf"(?:s8|bf16)\[{L},{rows},{cols}\]"
+        out += re.findall(rf"%?([\w.\-]+ = {one}\{{[^}}]*S\(1\)\}}) (?:fusion|copy)\(", text)
+        out += re.findall(rf"%?([\w.\-]+ = {whole}\S*) copy\(", text)
+    return sorted(out)
+
+
+def _projection_stacks(c, tp: int = 1) -> list:
+    """`wq`'s and `wk`'s stacks as one chip of ``tp`` holds them, either way round."""
+    q, kv = c.n_heads * c.head_dim // tp, c.n_kv_heads * c.head_dim // tp
+    return [(c.n_layers, c.dim, q), (c.n_layers, q, c.dim), (c.n_layers, c.dim, kv), (c.n_layers, kv, c.dim)]
+
+
+@pytest.mark.parametrize("case", _BLOCKS + [("ouro-2.6b-v5e1",)], ids=lambda c: c[0])
+def test_no_decode_block_stages_a_layers_wq_or_wk_in_fast_memory(v5e, case):
+    """The q and k products are plain matmuls: `_attn_mlp` keeps q and k as
+    `[B, T, heads * head_dim]` behind one `optimization_barrier` before the
+    split to heads, so the compiler cannot fold the split into the product
+    and has no reason to want the weight heads-major. Held here for the
+    7B's block, the 32B's at tp=4 and `ouro`'s, each at its cell's shapes
+    and full depth: no `fusion` or `copy` whose result is one layer's slice
+    of a projection's stack in fast memory (`S(1)`), no `copy` of a whole
+    stack, temporaries under 50 MB. The parent
+    (PR 46's tree) fails in all three blocks, by the ledger's own op names: on the 7B
+    `constant_dynamic-slice_fusion.9 s8[1,3584,3584]{..S(1)}` (0.5075 ms a
+    step) and `.8 s8[1,3584,512]` (0.1139), `copy.44 s8[28,3584,3584]`
+    (0.1608) and `copy.43 s8[28,3584,512]`, temporaries 422.4 MB; at tp=4
+    `.12 s8[1,5120,1280]` (0.627), `.11 s8[1,5120,256]`, `copy.41
+    s8[64,5120,1280]`, `copy.42`, 429.7 MB; on `ouro`
+    `constant_dynamic-slice_fusion.4` / `.5 bf16[1,2048,2048]{..S(1)}`
+    (2.339 + 2.297 ms of a 38.3 ms step). With the barrier the blocks hold
+    11.2, 3.5 and 1.3 MB of temporaries and the weights are read inside the
+    matmul fusions, from HBM, as `w1` / `w3` / `w2` are (PERF.md, PR 48)."""
+    if case[0].startswith("ouro"):
+        (c, _, compiled), tp = _ouro_decode_block(v5e), 1
+    else:
+        import dataclasses
+
+        _, widths, pages, slots, tp, depth = case
+        compiled = _compile_llama_decode_block(v5e, widths, pages, slots, tp, depth)
+        c = dataclasses.replace(PRESETS["qwen2.5-7b"], n_layers=depth, **widths)
+    text = compiled.as_text()
+    assert "paged_page_walk" in text
+    staged = _staged_projections(text, _projection_stacks(c, tp))
+    assert not staged, f"a projection's weight staged in fast memory or its stack copied: {staged}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 50e6, f"temporaries {temp / 1e6:.1f} MB: a stack of weights is copied"
+
+
 # -- the grouped expert matmul and the model that runs it ---------------------
 
 
@@ -1027,16 +1087,9 @@ def _ouro(v5e):
     return ouro, c, params, cache, vec
 
 
-def test_ouro_decode_block_walks_192_cache_layers_and_copies_neither_pool_nor_weight(v5e):
+def _ouro_decode_block(v5e):
     """8 lanes of the published model, steps in a loop as the engine's decode
-    block nests them (steps, loops, layers: three scans deep): ONE kernel,
-    the page walk at 16 KV heads and a query group of one, which the chip's
-    compiler takes as it stands; the pool (8.08 GB) aliased from argument to
-    result, no op copies it or a layer of it; and no weight is relaid before
-    the first step: with `wq` and `wk` inputs first the block copied both
-    stacks transposed, 0.81 GB of temporaries (PERF.md, PR 46)."""
-    import re
-
+    block nests them (steps, loops, layers: three scans deep)."""
     ouro, c, params, cache, vec = _ouro(v5e)
     S = _OURO_SLOTS
 
@@ -1052,6 +1105,22 @@ def test_ouro_decode_block_walks_192_cache_layers_and_copies_neither_pool_nor_we
 
     compiled = jax.jit(block, donate_argnums=(1,)).lower(
         params, cache, vec(S), vec(S), vec(S, 640 // PAGE), vec(S, dt=jnp.bool_)).compile()
+    return c, cache, compiled
+
+
+def test_ouro_decode_block_walks_192_cache_layers_and_copies_neither_pool_nor_weight(v5e):
+    """8 lanes of the published model, steps in a loop as the engine's decode
+    block nests them (steps, loops, layers: three scans deep): ONE kernel,
+    the page walk at 16 KV heads and a query group of one, which the chip's
+    compiler takes as it stands; the pool (8.08 GB) aliased from argument to
+    result, no op copies it or a layer of it; and no weight is relaid before
+    the first step: with `wq` and `wk` inputs first the block copied both
+    stacks transposed, 0.81 GB of temporaries (PERF.md, PR 46); and no layer's
+    `wq` or `wk` slice is read into fast memory as an op of its own (4.64 ms
+    of the step before the products were kept plain: PERF.md, PR 48)."""
+    import re
+
+    c, cache, compiled = _ouro_decode_block(v5e)
     text = compiled.as_text()
     assert "paged_page_walk" in text and text.count("tpu_custom_call") == 1
     pool = 2 * cache["k"].size * 2
@@ -1062,6 +1131,7 @@ def test_ouro_decode_block_walks_192_cache_layers_and_copies_neither_pool_nor_we
     assert re.search(shape, text) and not re.search(rf"= {shape}\S* copy\(", text), "a copy of the whole pool"
     assert f"bf16[{_OURO_PAGES},{PAGE},2048]" not in text, "one cache layer of the pool as a value of its own"
     assert not re.search(r"= bf16\[48,\d+,\d+\]\S* copy\(", text), "a stack of weights copied"
+    assert not _staged_projections(text, _projection_stacks(c)), "a layer's wq or wk slice staged in fast memory"
     assert 0.83 * 16e9 < _resident(compiled) < 13.6e9, f"{_resident(compiled) / 1e9:.2f} GB"
 
 
