@@ -1,4 +1,4 @@
-"""Mixture-of-Experts FFN: one expert layer, sorted and grouped.
+"""Mixture-of-Experts FFN: one expert layer, its rows grouped by expert.
 
 The MoE leg of the ``provider: tpu`` data plane: per-layer top-k routed
 SwiGLU experts in the dense FFN's place (Mixtral: softmax over the chosen;
@@ -13,14 +13,22 @@ that holds an eighth of a layer's experts computes its part of the sum and
 nothing stands in for the rest. ``held=None`` holds all (Mixtral, the uncut
 reference). There is no capacity and no token is dropped.
 
-Compute: the (token, choice) pairs are sorted by held expert, each expert's
-group padded to whole row tiles, and one grouped matmul per projection runs
-over the rows that landed here (``ops/pallas/moe_gmm.py`` on the chip for
-one device's experts; ``jax.lax.ragged_dot`` elsewhere, and wherever GSPMD
-has to partition the layer: an 'ep' axis over the expert axis, 'tp' over
-each expert's hidden width). Static shapes: the row bound is tokens x k
-plus a tile of padding per held expert. An expert no token chose has no
-tile and costs no weight read.
+Compute: the (token, choice) pairs are laid out by held expert, each
+expert's group padded to whole row tiles, and one grouped matmul per
+projection runs over the rows that landed here (``ops/pallas/moe_gmm.py``
+on the chip for one device's experts; ``jax.lax.ragged_dot`` elsewhere, and
+wherever GSPMD has to partition the layer: an 'ep' axis over the expert
+axis, 'tp' over each expert's hidden width). Static shapes: the row bound is
+tokens x k plus a tile of padding per held expert. An expert no token chose
+has no tile and costs no weight read.
+
+The layout is the one a stable sort of the pairs by expert gives, and it is
+COUNTED, not sorted (``group_rows``, PR 49): within an expert the pairs keep
+their own order, so a pair's row is its expert's first row plus the number
+of earlier pairs with its key, and sums over a one-hot of the keys give
+every integer of the plan. An argsort, two scatters and a ``searchsorted``
+loop gave the same integers in 38 ops a layer, which the chip runs an
+element at a time: 14-26 us of a layer's 97-338 for a few hundred integers.
 
 The one-hot dispatch/combine formulation with its capacity and dropped
 tokens (GShard's; ``moe_ffn``, ``expert_capacity``) went with PR 31: at
@@ -123,10 +131,63 @@ def route_scores(
     return idx, w * scale
 
 
+PREFILL_PAIRS = 2048  # from here on a dispatch's (token, choice) pairs are a prefill's, under it a decode step's
+
+
 def row_tile(pairs: int) -> int:
     """Rows of a tile of the grouped matmul: a bf16 sublane tile for decode
     batches, an MXU-height tile once a prefill brings rows by the thousand."""
-    return 128 if pairs >= 2048 else 16
+    return 128 if pairs >= PREFILL_PAIRS else 16
+
+
+def group_rows(
+    key: jax.Array,  # [pairs] int32: a pair's held expert, Eh = its expert is not here
+    Eh: int,
+    tm: int,
+    M: int,  # rows of the grouped matmul: pairs rounded up to tiles, and a tile a held expert
+    k: int,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The grouped matmul's plan, by counting -> (dest [pairs] the row of
+    each pair, M = none; row_token [M] the token of each row, 0 where no
+    pair landed; tile_expert [M // tm] the held expert of each tile, a dead
+    tile's the last live tile's; n_live [1] the tiles that hold a row;
+    counts [Eh] the pairs of each held expert).
+
+    The rows are those a stable sort of the pairs by `key` would give, each
+    expert's group then padded to whole tiles: within an expert the pairs
+    keep their own order, so a pair's place in its group is the number of
+    pairs before it with its key. No sort, no search loop, and no scatter
+    at a decode step's rows: the chip runs each of those an element at a
+    time, and there are a few hundred integers and eight or sixteen values
+    to group them by. Sums over a one-hot of the keys fuse to a dozen ops."""
+    pairs = key.shape[0]
+    experts = jnp.arange(Eh, dtype=jnp.int32)
+    pair = jnp.arange(pairs, dtype=jnp.int32)
+    hot = (key[:, None] == experts).astype(jnp.int32)  # [pairs, Eh]; all zeros: not here
+    counts = jnp.sum(hot, axis=0)
+    tiles_of = jax.lax.div(counts + (tm - 1), tm)  # no count is negative: this is the floor
+    padded = tiles_of * tm
+    # each expert's end row: an inclusive prefix sum over Eh values, as one masked sum
+    ends = jnp.sum(jnp.where(experts[:, None] <= experts, padded[:, None], 0), axis=0)
+    first = jnp.sum(hot * (ends - padded), axis=1)  # the first row of a pair's expert
+    pair_token = jax.lax.div(pair, k)
+    if pairs < PREFILL_PAIRS:
+        # a decode step's rows: every pair against every pair, every row against every pair,
+        # each ONE fused op of under a microsecond
+        same_before = (key == key[:, None]) & (pair < pair[:, None])
+        dest = jnp.where(key < Eh, first + jnp.sum(same_before.astype(jnp.int32), axis=1), M)
+        lands = dest == jnp.arange(M, dtype=jnp.int32)[:, None]
+        row_token = jnp.sum(jnp.where(lands, pair_token, 0), axis=1)
+    else:
+        # a prefill's: those compares are quadratic (the inverse alone 104-208 us a layer for a
+        # scatter's 57-76: PERF.md, PR 49), so a prefix sum down the one-hot and ONE scatter
+        rank = jnp.sum(hot * (jnp.cumsum(hot, axis=0) - hot), axis=1)
+        dest = jnp.where(key < Eh, first + rank, M)
+        row_token = jnp.zeros((M,), jnp.int32).at[dest].set(pair_token, mode="drop")
+    n_live = jnp.sum(tiles_of, keepdims=True)
+    at = jnp.minimum(jnp.arange(M // tm, dtype=jnp.int32), jnp.maximum(n_live - 1, 0)) * tm
+    tile_expert = jnp.minimum(jnp.sum((ends <= at[:, None]).astype(jnp.int32), axis=1), Eh - 1)
+    return dest, row_token, tile_expert, n_live, counts
 
 
 def routed_experts(
@@ -179,22 +240,8 @@ def routed_experts(
 
     with jax.named_scope("moe_sort"):
         n_valid = pairs if valid is None else jnp.sum(valid.astype(jnp.uint32)) * k
-        counts = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
-        padded = (counts + tm - 1) // tm * tm
-        ends = jnp.cumsum(padded)
-        starts = jnp.concatenate([ends - padded, jnp.zeros((1,), jnp.int32)])
-        firsts = jnp.concatenate([jnp.cumsum(counts) - counts, jnp.zeros((1,), jnp.int32)])
-        order = jnp.argsort(key, stable=True)  # pairs sorted by held expert, absent last
-        key_sorted = key[order]
-        row_sorted = jnp.where(
-            key_sorted < Eh, starts[key_sorted] + jnp.arange(pairs) - firsts[key_sorted], M)
-        dest = jnp.zeros((pairs,), jnp.int32).at[order].set(row_sorted)  # a pair's row, M = none
-        row_token = jnp.zeros((M,), jnp.int32).at[row_sorted].set(order // k, mode="drop")
-        n_live = ends[-1:] // tm
-        tiles = jnp.arange(M // tm, dtype=jnp.int32)
-        tile_expert = jnp.searchsorted(ends, jnp.minimum(tiles, jnp.maximum(n_live - 1, 0)) * tm,
-                                       side="right").astype(jnp.int32)
-        tile_expert = jnp.minimum(tile_expert, Eh - 1) + jnp.asarray(expert_base, jnp.int32)
+        dest, row_token, tile_expert, n_live, counts = group_rows(key, Eh, tm, M, k)
+        tile_expert = tile_expert + jnp.asarray(expert_base, jnp.int32)
         xs = x[row_token]
 
     with jax.named_scope("moe_gmm"):
@@ -208,11 +255,12 @@ def routed_experts(
             if w1d.shape[0] != Eh:
                 w1d, w3d, w2d = (jax.lax.dynamic_slice_in_dim(w, expert_base, Eh) for w in (w1d, w3d, w2d))
             prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+            padded = -(-counts // tm) * tm  # the groups' rows, as `group_rows` padded them
             dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
                 a, w, padded, precision=prec, preferred_element_type=jnp.float32)
             h = (act(dot(xs, w1d)) * dot(xs, w3d)).astype(x.dtype)
             ys = dot(h, w2d).astype(x.dtype)
-            ys = jnp.where((jnp.arange(M) < ends[-1])[:, None], ys, 0)
+            ys = jnp.where((jnp.arange(M) < n_live * tm)[:, None], ys, 0)
 
     with jax.named_scope("moe_combine"):
         ys = jnp.concatenate([ys, jnp.zeros((1, D), ys.dtype)])

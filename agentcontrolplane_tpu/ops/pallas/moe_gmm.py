@@ -1,8 +1,8 @@
-"""Pallas TPU kernel: grouped matmul over rows sorted by expert (``moe_gmm``).
+"""Pallas TPU kernel: grouped matmul over rows laid out by expert (``moe_gmm``).
 
-The routed expert layer (``ops.moe.routed_experts``) sorts its (token,
-choice) pairs by expert and pads each expert's group to whole row tiles, so
-a row tile belongs to exactly one expert. The kernel walks the row tiles
+The routed expert layer (``ops.moe.routed_experts``) counts each (token,
+choice) pair's row by expert, groups padded to whole row tiles (no sort:
+``ops.moe.group_rows``): a tile has one expert. The kernel walks the row tiles
 with that tile -> expert map scalar-prefetched: the weight block of grid
 step ``(j, i)`` is expert ``tile_expert[i]``'s column tile ``j``, and
 Pallas re-fetches a block only when its index changes, so consecutive tiles

@@ -555,26 +555,68 @@ def test_no_decode_block_stages_a_layers_wq_or_wk_in_fast_memory(v5e, case):
 # -- the grouped expert matmul and the model that runs it ---------------------
 
 
-@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode-32-lanes", "prefill-8x512"])
-def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, tokens):
-    """`ops.moe.routed_experts` at LFM2-24B-A2B's widths, 8 of 64 experts
-    held out of a stack of 38 layers' experts: route, sort and the
-    `moe_gmm` kernels, at a decode step's rows and at a prefill's."""
+def _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers) -> str:
+    """`ops.moe.routed_experts` with its kernels, `Eh` of `E` experts held out
+    of a stack of `layers` layers' experts, compiled for one described chip."""
     from agentcontrolplane_tpu.ops.moe import routed_experts
 
     one_chip = SingleDeviceSharding(v5e[0])
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    bf16, D, F = jnp.bfloat16, 2048, 1536
+    bf16 = jnp.bfloat16
 
     def layer(x, router, bias, w1, w3, w2, index):
-        return routed_experts(x, router, w1, w3, w2, 4, held=tuple(range(8)), score="sigmoid", bias=bias,
-                              kernel=True, expert_base=index * 8)
+        return routed_experts(x, router, w1, w3, w2, k, held=tuple(range(Eh)), score="sigmoid", bias=bias,
+                              kernel=True, expert_base=index * Eh)
 
-    args = [sds((tokens, D), bf16), sds((D, 64), bf16), sds((64,), jnp.float32), sds((38 * 8, D, F), bf16),
-            sds((38 * 8, D, F), bf16), sds((38 * 8, F, D), bf16), sds((), jnp.int32)]
-    text = jax.jit(layer).lower(*args).compile().as_text()
+    args = [sds((tokens, D), bf16), sds((D, E), bf16), sds((E,), jnp.float32), sds((layers * Eh, D, F), bf16),
+            sds((layers * Eh, D, F), bf16), sds((layers * Eh, F, D), bf16), sds((), jnp.int32)]
+    return jax.jit(layer).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode-32-lanes", "prefill-8x512"])
+def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, tokens):
+    """`ops.moe.routed_experts` at LFM2-24B-A2B's widths, 8 of 64 experts
+    held out of a stack of 38 layers' experts: route, the rows' plan and the
+    `moe_gmm` kernels, at a decode step's rows and at a prefill's."""
+    text = _compiled_expert_layer(v5e, tokens, 4, 8, 64, 2048, 1536, 38)
     assert text.count("tpu_custom_call") == 2, "gate-and-up and down: two grouped matmuls"
     assert "bf16[8,2048,1536]" not in text, "a layer's experts were sliced out of the stack (a copy a call)"
+
+
+# (name, tokens, k, experts held, E, D, F, layers, entry ops that run at most): a decode step's rows in the three
+# expert cells and a prefill's in `lfm2`; the parent of PR 49 compiled to 85 / 96 / 78 / 100 such ops
+_EXPERT_ROWS = [
+    ("lfm2-32x4-8-of-64", 32, 4, 8, 64, 2048, 1536, 38, 56),
+    ("mellum2-32x8-16-of-64", 32, 8, 16, 64, 2304, 896, 28, 61),
+    ("kanana2-16x6-8-of-128", 16, 6, 8, 128, 2048, 768, 47, 49),
+    ("lfm2-prefill-4096x4", 4096, 4, 8, 64, 2048, 1536, 38, 71),
+]
+_NOT_RUN = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
+
+
+@pytest.mark.parametrize("case", _EXPERT_ROWS, ids=lambda c: c[0])
+def test_the_expert_layers_rows_are_grouped_without_sort_loop_or_scatter(v5e, case):
+    """Between the router and the grouped matmul the compiled layer counts
+    (`ops.moe.group_rows`): no `sort` and no `while` under `moe_sort`, no
+    scatter at a decode step's rows (a prefill's keep ONE, the inverse),
+    no chain of one-element ops for a floor-divide, and no more ops in the
+    entry computation than stated. The chip runs a sort, a scatter and a
+    loop an element at a time, and an op of a few hundred integers costs
+    what its launch costs: the count is the cost (PERF.md, PR 49)."""
+    *rows, most = case[1:]
+    comps = _computations(_compiled_expert_layer(v5e, *rows))
+    entry = next(lines for name, lines in comps.items() if name.startswith("ENTRY"))
+    ran = [(name, shape.split("{")[0], op, rest) for name, shape, op, rest in _ops(entry) if op not in _NOT_RUN]
+    plan = [(name, shape, op) for name, shape, op, rest in ran if "/moe_sort/" in rest]
+    assert sum("tpu_custom_call" in rest for _, _, _, rest in ran) == 2, "gate-and-up and down: two grouped matmuls"
+    serial = [o for o in plan if o[2] in ("sort", "while")]
+    assert not serial, f"the plan of the grouped matmul sorts or loops: {serial}"
+    scatters = sorted(name for name, lines in comps.items() for _, _, op, _ in _ops(lines) if op == "scatter")
+    assert len(scatters) <= (rows[0] * rows[1] >= 2048), f"scatters {scatters}: a decode step's rows take none, a prefill's one"
+    single = [o for o in plan if o[1].endswith("[1]") or o[1].endswith("[]")]
+    assert len(single) <= 2 and not any(op in ("sign", "negate", "select", "divide") for _, _, op in single), (
+        f"one-element ops under moe_sort (a floor-divide written out is eighteen): {single}")
+    assert len(ran) <= most, f"{len(ran)} entry ops for {most}: {sorted(op for _, _, op, _ in ran)}"
 
 
 def _lfm2(v5e, monkeypatch):

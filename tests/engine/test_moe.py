@@ -1,6 +1,6 @@
 """Mixture-of-Experts FFN (ops/moe.py) + the Mixtral-architecture family.
 
-The sorted, grouped expert layer must match the exact per-token reference,
+The grouped expert layer (its rows counted by expert, PR 49) must match the exact per-token reference,
 drop no token however uneven the routing, and expert parallelism ('ep' mesh
 axis) must be numerically transparent and must not all-gather the expert
 weights.
@@ -327,3 +327,99 @@ def test_moe_grouped_matches_reference_across_tile_boundaries(n):
     ref = moe_ffn_reference(x, router, w1, w3, w2, experts_per_token=2)
     out = _grouped(x, router, w1, w3, w2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+# -- the grouped matmul's plan, by counting (PR 49) ----------------------------
+
+
+def _group_rows_double(key, Eh, tm, M, k):
+    """The plan as a stable sort and a loop write it: each held expert's
+    pairs in their own order, its group padded to whole tiles, a dead
+    tile given the last live tile's expert (the last held expert where no
+    pair landed at all)."""
+    key = np.asarray(key)
+    order = np.argsort(key, kind="stable")
+    dest, row_token, live = np.full(len(key), M), np.zeros(M, np.int64), np.zeros(M, bool)
+    tile_expert, counts, row = [], [], 0
+    for e in range(Eh):
+        members = [p for p in order if key[p] == e]
+        for p in members:
+            dest[p], row_token[row], live[row] = row, p // k, True
+            row += 1
+        row = -(-row // tm) * tm
+        tile_expert += [e] * (-(-len(members) // tm))
+        counts.append(len(members))
+    n_live = len(tile_expert)
+    tile_expert += [tile_expert[-1] if tile_expert else Eh - 1] * (M // tm - n_live)
+    return dest, row_token, np.asarray(tile_expert), n_live, np.asarray(counts), live
+
+
+def _keys(case: str, pairs: int, Eh: int):
+    rng = np.random.default_rng(len(case) * 1000 + pairs)
+    if case == "held-out-of-the-middle":  # 64 experts, those held are 24..24+Eh: most pairs are not here
+        chosen = rng.integers(0, 64, pairs)
+        return np.where((chosen >= 24) & (chosen < 24 + Eh), chosen - 24, Eh)
+    if case == "an-expert-no-pair-chose":
+        return rng.choice([e for e in range(Eh + 1) if e not in (1, Eh - 1)], pairs)
+    if case == "every-pair-absent":  # and every row `valid` False: `routed_experts` writes Eh for both
+        return np.full(pairs, Eh)
+    if case == "one-expert-takes-all":
+        return np.full(pairs, Eh - 2)
+    return rng.integers(0, Eh + 1, pairs)  # "even"
+
+
+@pytest.mark.parametrize("pairs,Eh,k,tm", [
+    (6, 8, 2, 16),  # under one tile
+    (128, 8, 4, 16), (96, 8, 6, 16), (256, 16, 8, 16),  # the three cells' decode rows
+    (2046, 8, 2, 16), (2048, 8, 2, 128),  # either side of the inverse's rule, at `row_tile`'s tiles
+    (2046, 3, 2, 128), (2048, 3, 2, 16), (512, 16, 8, 128),  # and each form at the other tile
+])
+@pytest.mark.parametrize("case", ["even", "held-out-of-the-middle", "an-expert-no-pair-chose", "every-pair-absent",
+                                  "one-expert-takes-all"])
+def test_group_rows_counts_what_a_stable_sort_would_lay_out(case, pairs, Eh, k, tm):
+    from agentcontrolplane_tpu.ops.moe import group_rows
+
+    M = -(-pairs // tm) * tm + Eh * tm
+    key = _keys(case, pairs, Eh)
+    dest, row_token, tile_expert, n_live, counts = (
+        np.asarray(a) for a in jax.jit(group_rows, static_argnums=(1, 2, 3, 4))(jnp.asarray(key, jnp.int32), Eh, tm, M, k))
+    want_dest, want_token, want_expert, want_live, want_counts, live = _group_rows_double(key, Eh, tm, M, k)
+    np.testing.assert_array_equal(dest, want_dest)
+    np.testing.assert_array_equal(tile_expert, want_expert)
+    assert n_live.tolist() == [want_live]
+    np.testing.assert_array_equal(counts, want_counts)
+    np.testing.assert_array_equal(row_token[live], want_token[live])
+    assert not row_token[~live].any(), "a row no pair landed on reads token 0"
+
+
+# sha256 (16 hex digits) of y's and the counts' bytes as the PARENT of PR 49 (58bc506: argsort, two scatters, a
+# searchsorted) computed them on these operands. Every operand is a small integer or a binary fraction and `act` is
+# the identity, so each product and sum of the matmuls is exact in float32 in any order: the XLA path and the
+# interpreted kernel agree to the bit, and nothing but a changed plan (or router) moves a digest.
+_FROZEN = {  # tokens, k, E, held, score, rows valid -> digest
+    "32x4-8-of-64": ((32, 4, 64, tuple(range(8)), "sigmoid", "four-fifths"), "aa536b2dc7faa79a"),  # 63 of 100 landed
+    "16x6-8-of-128-middle": ((16, 6, 128, tuple(range(56, 64)), "sigmoid", "four-fifths"), "75a015bde5626d09"),  # 20 of 72
+    "32x8-16-of-64": ((32, 8, 64, tuple(range(16, 32)), "sigmoid", "four-fifths"), "ec0ecb04db8bdbec"),  # 151 of 200
+    "600x2-all-held": ((600, 2, 8, None, "softmax", "four-fifths"), "078d67cf78858c59"),  # 960 of 960
+    "2048x4-8-of-64": ((2048, 4, 64, tuple(range(8)), "sigmoid", "four-fifths"), "ef082103353c3034"),  # 4,153 of 6,552
+    "32x4-no-row-valid": ((32, 4, 64, tuple(range(8)), "sigmoid", "none"), "e94801d91141191e"),  # 0 of 0
+}
+
+
+@pytest.mark.parametrize("path", ["xla", "interpreted-kernel"])
+@pytest.mark.parametrize("name", list(_FROZEN))
+def test_routed_experts_is_bit_for_bit_what_the_sorted_plan_gave(name, path):
+    import hashlib
+
+    (N, k, E, held, score, valid), digest = _FROZEN[name]
+    Eh, D, F = E if held is None else len(held), 64, 128
+    rng = np.random.default_rng(49)
+    ints = lambda lo, hi, *shape: jnp.asarray(rng.integers(lo, hi + 1, shape), jnp.float32)  # noqa: E731
+    x, router, bias = ints(-2, 2, N, D), ints(-8, 8, D, E) / 64, ints(-4, 4, E) / 256
+    w1, w3, w2 = ints(-1, 1, 2 * Eh, D, F), ints(-1, 1, 2 * Eh, D, F), ints(-1, 1, 2 * Eh, F, D)  # two layers' experts
+    if held is not None:
+        bias = bias.at[jnp.asarray(held)].add(0.25)  # the choice leans to the held: about half the pairs land
+    ok = jnp.arange(N) % 5 != 0 if valid == "four-fifths" else jnp.zeros((N,), bool)
+    y, stats = routed_experts(x, router, w1, w3, w2, k, held=held, score=score, bias=bias if score == "sigmoid" else None,
+                              valid=ok, act=lambda v: v, expert_base=Eh, kernel=False, interpret=path != "xla")
+    assert hashlib.sha256(np.asarray(y).tobytes() + np.asarray(stats).tobytes()).hexdigest()[:16] == digest
