@@ -64,6 +64,32 @@ log = logging.getLogger("acp_tpu.engine")
 # engine stops being restartable (ensure_running returns False)
 _CRASH_LOOP_LIMIT = 2
 
+# Fixed values: no flag, CRD field, benchmark cell or deployment sets one. An
+# engine copies those a test varies into attributes of the same name.
+
+# HBM bound of the prefix cache, in total cached KV tokens: per cached token
+# one K+V row per layer (L * H_kv * d * 2 * dtype bytes); the token bound
+# keeps worst-case cache HBM explicit instead of silently scaling with
+# bucket sizes
+PREFIX_CACHE_MAX_TOKENS = 4096
+# paged: how many decode blocks of pages to reserve per slot ahead of need,
+# so the block table isn't dirtied (re-uploaded) every dispatch
+PAGE_LOOKAHEAD_BLOCKS = 8
+# bound on distinct fused program shapes: a NEW (chunk bucket x batch x
+# decode width x phase-set) combination past this many falls back to the
+# split dispatches for that cycle (which reuse already-compiled programs)
+# instead of compiling yet another megastep variant — fusion must not turn
+# the jit cache into a combinatorial zoo. 0 = never fuse.
+MEGASTEP_MAX_PROGRAMS = 32
+# dispatch-cycle stall watchdog: a busy cycle (fault throttles included)
+# whose wall time exceeds BOTH STALL_MULT x the fastest cycle seen (the
+# cadence floor) and STALL_MIN_S records a `stall` flight event +
+# acp_engine_stalls_total — the cheap gray-failure signal the fleet health
+# state machine (fleet/health.py) consumes. Observation-only: a stall never
+# changes what is sampled.
+STALL_MULT = 8.0
+STALL_MIN_S = 0.25
+
 
 def _in_phase(name: str):
     """Run an engine-thread method inside the profiler's ``name`` phase
@@ -451,14 +477,10 @@ class Engine:
         prefill_batch_max: int = 8,  # burst admissions batch up to this many prompts
         width_buckets: Sequence[int] = (1, 2, 4, 8, 16, 32),  # low-occupancy decode widths
         prefix_cache_entries: int = 4,  # 0 disables (slot: KV copies; paged: shared pages)
-        prefix_cache_max_tokens: int = 4096,  # HBM bound: total cached KV tokens
         decode_block_size: int = 8,
         kv_layout: str = "slot",  # "slot" | "paged"
         page_size: int = 16,
         kv_pages: int = 0,  # paged: total pages (0 = slot-equivalent capacity)
-        # paged: how many decode blocks of pages to reserve per slot ahead of
-        # need, so the block table isn't dirtied (re-uploaded) every dispatch
-        page_lookahead_blocks: int = 8,
         # admission-queue cap: a submission arriving with max_queue requests
         # already waiting (submit queue + admission deque) is SHED
         # (EngineOverloadedError -> REST 503 + Retry-After) instead of
@@ -505,13 +527,6 @@ class Engine:
         # for A/B. Inert while nothing is mid-prefill (the plain decode /
         # verify iteration is already one dispatch).
         megastep: bool = True,
-        # bound on distinct fused program shapes: a NEW (chunk bucket x
-        # batch x decode width x phase-set) combination past this many
-        # falls back to the split dispatches for that cycle (which reuse
-        # already-compiled programs) instead of compiling yet another
-        # megastep variant — fusion must not turn the jit cache into a
-        # combinatorial zoo.
-        megastep_max_programs: int = 32,
         # admission-time chunk-rate planner (engine/planner.py): deadline
         # requests get a per-cycle chunk quota (tokens remaining / cycles
         # until deadline) instead of the flat one-chunk-per-cycle cadence.
@@ -519,31 +534,13 @@ class Engine:
         # deadlines and under multi-host coordination (leader-local wall
         # clock, same rule as EDF ordering).
         rate_planner: bool = True,
-        planner_max_quota: int = 8,  # per-slot per-cycle chunk cap
         # scheduler autopilot (engine/planner.py): every
-        # autopilot_interval busy cycles, steer prefill_chunk /
+        # planner.AUTOPILOT_INTERVAL busy cycles, steer prefill_chunk /
         # token_budget / spec_len one bounded step from the flight
         # recorder's phase attribution + budget utilization + spec
         # acceptance. Off by default; constructor-disabled under
         # coordination (host-local wall-clock inputs would fork lockstep).
         autopilot: bool = False,
-        autopilot_interval: int = 128,
-        # dispatch-cycle stall watchdog: a busy cycle (fault throttles
-        # included) whose wall time exceeds BOTH stall_mult x the fastest
-        # cycle seen (the cadence floor) and stall_min_s records a `stall` flight
-        # event + acp_engine_stalls_total — the cheap gray-failure signal
-        # the fleet health state machine (fleet/health.py) consumes.
-        # Observation-only: a stall never changes what is sampled.
-        stall_mult: float = 8.0,
-        stall_min_s: float = 0.25,
-        # degradation ladder (engine/brownout.py): under sustained
-        # pressure (admission sheds + watchdog stalls) step optional
-        # features down in the pinned order spec_len -> park acceptance ->
-        # chunk quota, one bounded rung per interval, restoring fully on
-        # recovery. Off by default; constructor-disabled under
-        # coordination (host-local pressure counters would fork lockstep).
-        brownout: bool = False,
-        brownout_interval: int = 64,
         # parked-slot lifetime: a slot parked at generation end (see
         # _Request.park) that no follow-up turn adopts within this window
         # is released. 0 disables parking entirely. Parking is also
@@ -633,7 +630,7 @@ class Engine:
             raise ValueError(f"kv_layout must be 'slot' or 'paged', got {kv_layout!r}")
         self.kv_layout = kv_layout
         self.page_size = page_size
-        self.page_lookahead_blocks = max(1, page_lookahead_blocks)
+        self.page_lookahead_blocks = PAGE_LOOKAHEAD_BLOCKS
         if isinstance(config, str):
             config = preset(config)
         self.config = config
@@ -982,10 +979,6 @@ class Engine:
             prefix_cache_entries, prefix_dedup, park_max_s = 0, False, 0.0
         self._prefix_enabled = prefix_cache_entries > 0  # acp: mirror (immutable)
         self._prefix_cache_entries = prefix_cache_entries  # acp: mirror (immutable)
-        # HBM accounting: per cached token one K+V row per layer
-        # (L * H_kv * d * 2 * dtype bytes); the token bound keeps worst-case
-        # cache HBM explicit instead of silently scaling with bucket sizes
-        self._prefix_cache_max_tokens = prefix_cache_max_tokens
         self._prefix_cache: "_collections.OrderedDict[tuple, dict]" = (
             _collections.OrderedDict()
         )
@@ -1077,7 +1070,7 @@ class Engine:
         # _prefill_chunks to the decode/verify dispatch site; it never
         # survives a cycle (every _decode_once entry consumes it).
         self.megastep = bool(megastep)
-        self.megastep_max_programs = max(0, int(megastep_max_programs))  # 0 = never fuse
+        self.megastep_max_programs = MEGASTEP_MAX_PROGRAMS
         self._fuse_pending: Optional[dict] = None
         self._megastep_shapes: set[tuple] = set()  # fused shapes dispatched
         self.megastep_dispatches = 0  # fused program dispatches issued
@@ -1086,7 +1079,6 @@ class Engine:
         from .planner import Autopilot, AutopilotLimits, CycleClock
 
         self.rate_planner = bool(rate_planner)
-        self.planner_max_quota = max(1, int(planner_max_quota))
         self._cycle_clock = CycleClock()
         self.quota_projections = 0  # rate plans issued (admit + reproject)
         self.quota_reprojections = 0  # reprojections (resume/adopt)
@@ -1100,15 +1092,13 @@ class Engine:
                     + 4 * self.prefill_buckets[-1],
                     spec_len_max=16,
                 ),
-                interval=autopilot_interval,
             )
             if self.autopilot_enabled
             else None
         )
-        # gray-failure instrumentation: the dispatch watchdog + the
-        # degradation ladder (see _stall_check / _brownout_tick)
-        self.stall_mult = float(stall_mult)
-        self.stall_min_s = float(stall_min_s)
+        # gray-failure instrumentation: the dispatch watchdog (see _stall_check)
+        self.stall_mult = STALL_MULT
+        self.stall_min_s = STALL_MIN_S
         self.stalls = 0  # dispatch cycles the watchdog judged stalled
         self.sheds = 0  # admission sheds (bounded queue / fault site)
         self._cycle_s = 0.0  # acp: mirror — cycle EWMA snapshot for stats()
@@ -1118,16 +1108,6 @@ class Engine:
         # cycles after start; the min converges to honest cadence after a
         # single fast cycle and a slow cycle can never inflate it.
         self._cycle_floor = 0.0
-        from .brownout import BrownoutController, BrownoutPolicy
-
-        self.brownout_enabled = bool(brownout) and coordination is None
-        self._brownout = (  # acp: mirror (immutable; stats reads plain ints off it)
-            BrownoutController(BrownoutPolicy(interval=max(1, int(brownout_interval))))
-            if self.brownout_enabled
-            else None
-        )
-        self._brownout_level = 0  # acp: mirror — applied ladder rung
-        self._brownout_saved: dict = {}  # knob -> pre-brownout value
         # overlapped tool execution (see _stream / _park). _parked_count is
         # a plain int mirror of "slots in _slots with parked=True" so
         # cross-thread readers (stats()) never iterate the engine-mutated
@@ -2110,17 +2090,6 @@ class Engine:
             "cycle_s": round(self._cycle_s, 6),
             "stalls": self.stalls,
             "sheds": self.sheds,
-            # degradation ladder posture (engine/brownout.py)
-            "brownout": {
-                "enabled": self.brownout_enabled,
-                "level": self._brownout_level,
-                "steps_down": (
-                    self._brownout.steps_down if self._brownout is not None else 0
-                ),
-                "steps_up": (
-                    self._brownout.steps_up if self._brownout is not None else 0
-                ),
-            },
             # decode efficiency: tokens committed per model step. Without
             # speculation this is <= 1 (finished lanes pad blocks); with it,
             # each verify dispatch counts ONE step however many tokens land,
@@ -2445,8 +2414,6 @@ class Engine:
                     self.profiler.publish()
                     if self._autopilot is not None:
                         self._autopilot_tick()
-                    if self._brownout is not None:
-                        self._brownout_tick()
                     if self.check_invariants:
                         if self._faults.enabled and self._faults.pop(
                             "engine.invariant_break"
@@ -3038,7 +3005,6 @@ class Engine:
             self._chunk_tokens(),
             seconds_left,
             self._cycle_clock.cycle_s or 0.05,
-            max_quota=self.planner_max_quota,
         )
         self.quota_projections += 1
         if reason != "admit":
@@ -3120,46 +3086,6 @@ class Engine:
             "stall_min_s) — the gray-failure signal the fleet health "
             "state machine consumes",
         )
-
-    def _brownout_tick(self) -> None:
-        """Degradation ladder (engine/brownout.py): on interval
-        boundaries, judge shed/stall pressure and move at most one rung.
-        Stepping DOWN saves and sheds the next optional knob in the
-        pinned order (spec_len -> park acceptance -> chunk quota);
-        stepping UP restores the most recent one. Mirrors the autopilot's
-        apply-seam: the controller decides, the engine applies the knob
-        and flight-records it, and the gauge tracks the level."""
-        bo = self._brownout
-        if bo is None or not bo.due():
-            return
-        from .brownout import LADDER
-
-        target = bo.step(self.sheds, self.stalls)
-        if target == self._brownout_level:
-            return
-        if target > self._brownout_level:
-            knob, downed = LADDER[self._brownout_level]
-            self._brownout_saved[knob] = getattr(self, knob)
-            setattr(self, knob, downed)
-            self._brownout_level += 1
-            self.flight.record(
-                "brownout", level=self._brownout_level, **{f"set_{knob}": downed}
-            )
-        else:
-            knob, _ = LADDER[self._brownout_level - 1]
-            restored = self._brownout_saved.pop(knob, getattr(self, knob))
-            setattr(self, knob, restored)
-            self._brownout_level -= 1
-            self.flight.record(
-                "brownout", level=self._brownout_level, **{f"set_{knob}": restored}
-            )
-        REGISTRY.gauge_set(
-            "acp_engine_brownout_level", float(self._brownout_level),
-            help="current rung of the degradation ladder (0 = full "
-            "service; 1 = speculation off; 2 = + park acceptance off; "
-            "3 = + chunk quota floored) — engine/brownout.py",
-        )
-        log.info("brownout level -> %d", self._brownout_level)
 
     def _has_work(self) -> bool:
         """Anything the dispatch loop must advance: decoding or mid-prefill
@@ -3881,7 +3807,7 @@ class Engine:
             self._prefix_cache[key] = entry
             while len(self._prefix_cache) > self._prefix_cache_entries or (
                 len(self._prefix_cache) > 1
-                and self._cached_tokens_locked() > self._prefix_cache_max_tokens
+                and self._cached_tokens_locked() > PREFIX_CACHE_MAX_TOKENS
             ):
                 _, old = self._prefix_cache.popitem(last=False)  # evict LRU
                 if "pages" in old:

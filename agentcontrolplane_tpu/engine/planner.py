@@ -48,13 +48,18 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+# per-slot per-cycle chunk cap of a rate plan
+MAX_QUOTA = 8
+# busy cycles between two looks of the autopilot
+AUTOPILOT_INTERVAL = 128
+
 
 def project_quota(
     tokens_left: int,
     chunk: int,
     seconds_left: Optional[float],
     cycle_s: float,
-    max_quota: int = 8,
+    max_quota: int = MAX_QUOTA,
     slack_cycles: int = 2,
 ) -> int:
     """Per-cycle chunk quota for one mid-prefill slot (>= 1).
@@ -166,7 +171,7 @@ class Autopilot:
     flight-records them) — the autopilot itself never touches engine
     state, so it stays trivially unit-testable."""
 
-    def __init__(self, limits: AutopilotLimits, interval: int = 128):
+    def __init__(self, limits: AutopilotLimits, interval: int = AUTOPILOT_INTERVAL):
         self.limits = limits
         self.interval = max(1, int(interval))
         self.cycles = 0

@@ -12,9 +12,28 @@ Fault injection
 module so the engine can import it without this fixture surface) and is
 re-exported here for test convenience — see that module's docstring for
 the site catalogue and determinism contract.
+
+A model family's test file
+--------------------------
+
+Its reference is :func:`greedy_reference` (the family's own ``forward``, no
+cache, over one row padded to the file's ``max_ctx``: compiled once a file,
+whatever the prompts' lengths), and ``tests/engine/test_reference_padding.py``
+holds every family's forward to the property that rests on: what lies to
+the right of a position never reaches it. A model program a case calls
+itself goes through :func:`compiled`, as the engine compiles it. Its engines
+are built a case: nearly every engine case of the five family files sets
+options of its own, counts from zero, preempts or parks (6 to 16 s a case
+once the reference is compiled: PR 50); cases that share one set of options
+and only serve and read share a ``scope="module"`` engine, stopped at the
+end (``tests/engine/test_shape_invariance.py``'s ``Family``). A file stays
+under 300 s of the driver's junit file (``ROADMAP.md``, "a family
+file's budget").
 """
 
 from __future__ import annotations
+
+import functools
 
 from agentcontrolplane_tpu.api import ObjectMeta
 from agentcontrolplane_tpu.api.resources import (
@@ -207,6 +226,45 @@ def make_contactchannel(store: Store, name="approval-channel", ready=True) -> Co
         ),
         mark_ready if ready else None,
     )
+
+
+# ---------------------------------------------------------------------------
+# The tests' greedy reference (jax is imported when it is first asked for)
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def compiled(fn, config):
+    """``jax.jit`` of a model program with its ``config`` bound, made once a
+    (program, config): the calls of one test file share what it compiles."""
+    import jax
+
+    return jax.jit(functools.partial(fn, config=config))
+
+
+def padded_logits(forward, params, config, tokens, width: int):
+    """``forward``'s logits ``[len(tokens), vocab]`` for one row of tokens,
+    run at ``width`` (the tail is token 0) and cut back: every length shares
+    the one program ``jax.jit`` makes for (``forward``, ``config``, ``width``).
+    Right for a forward whose row ``i`` reads rows ``<= i`` alone."""
+    import numpy as np
+
+    if len(tokens) > width:
+        raise ValueError(f"{len(tokens)} tokens do not fit a row of {width}")
+    row = np.zeros((1, width), np.int32)
+    row[0, : len(tokens)] = tokens
+    return np.asarray(compiled(forward, config)(params, row))[0, : len(tokens)]
+
+
+def greedy_reference(forward, params, config, prompt, n: int, width: int) -> list[int]:
+    """The ``n`` tokens greedy decoding gives after ``prompt`` by the family's
+    plain ``forward(params, tokens, config)``: no cache, no state, nothing of
+    the engine. Each token is the argmax at the last real row of
+    :func:`padded_logits`."""
+    toks = [int(t) for t in prompt]
+    for _ in range(n):
+        toks.append(int(padded_logits(forward, params, config, toks, width)[-1].argmax()))
+    return toks[len(prompt):]
 
 
 # ---------------------------------------------------------------------------

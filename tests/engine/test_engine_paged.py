@@ -101,7 +101,8 @@ def test_lookahead_reservation_bounds_table_uploads():
     host->device RTT in the decode hot loop). With block K == 4 and
     lookahead 8, a 48-token generation must dirty the table ~ once per 8
     blocks, not once per block."""
-    eng = make_engine("paged", page_lookahead_blocks=8)
+    eng = make_engine("paged")
+    assert eng.page_lookahead_blocks == 8
     try:
         r = eng.generate("q" * 16, SamplingParams(temperature=0.0, max_tokens=48))
         assert len(r.tokens) >= 1
@@ -118,8 +119,8 @@ def test_lookahead_reservation_bounds_table_uploads():
 def test_lookahead_one_matches_legacy_per_block_behavior():
     """page_lookahead_blocks=1 degenerates to the strict per-block
     allocation; output must be identical to the default lookahead."""
-    a = make_engine("paged", page_lookahead_blocks=1)
-    b = make_engine("paged", page_lookahead_blocks=8)
+    a, b = make_engine("paged"), make_engine("paged")
+    a.page_lookahead_blocks, b.page_lookahead_blocks = 1, 8
     try:
         ra = a.generate("lookahead", SamplingParams(temperature=0.0, max_tokens=24))
         rb = b.generate("lookahead", SamplingParams(temperature=0.0, max_tokens=24))

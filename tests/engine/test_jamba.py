@@ -22,6 +22,7 @@ from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.models import jamba, preset, programs
 from agentcontrolplane_tpu.ops.pallas import ssm_scan as ssm
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
+from agentcontrolplane_tpu.testing import greedy_reference
 
 FILE = spec.load_json(spec.os.path.join(spec.ROOT, "acpbench/configs/jamba2-3b-bf16-v5e1.json"))
 M, A = "mamba", "full_attention"
@@ -250,6 +251,7 @@ def test_any_layer_pattern_serves_what_forward_computes(name):
 # -- the engine carries the state ------------------------------------------
 
 CFG = preset("jamba-tiny")
+MAX_CTX = 128  # the engines' and the padded reference's
 PARAMS = None
 ONE_CHIP = lambda: make_mesh({"tp": 1}, devices=jax.devices()[:1])  # noqa: E731
 
@@ -258,7 +260,7 @@ def make_engine(**kw):
     global PARAMS
     if PARAMS is None:
         PARAMS = jamba.init_params(CFG, jax.random.key(0))
-    opts = dict(max_slots=4, max_ctx=128, kv_layout="paged", page_size=8, kv_pages=80,
+    opts = dict(max_slots=4, max_ctx=MAX_CTX, kv_layout="paged", page_size=8, kv_pages=80,
                 prefill_buckets=(16, 32, 64), width_buckets=(2, 4), decode_block_size=4, check_invariants=True)
     eng = Engine(config=CFG, params=PARAMS, mesh=ONE_CHIP(), **{**opts, **kw})
     eng.start()
@@ -270,13 +272,6 @@ def prompts(*lengths, seed=0):
     return [[int(t) for t in rng.integers(0, 256, n)] for n in lengths]
 
 
-def reference_greedy(prompt, n):
-    """The model's own full forward, no cache and no state, token by token."""
-    toks = list(prompt)
-    for _ in range(n):
-        logits = jamba.forward(PARAMS, jnp.asarray([toks]), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 GREEDY = SamplingParams(temperature=0.0, max_tokens=10)
@@ -288,7 +283,7 @@ def test_engine_serves_it_as_the_other_models_and_counts_its_recurrence():
         ps = prompts(20, 37, 50)
         futures = [eng.submit(p, GREEDY) for p in ps]
         for p, f in zip(ps, futures):
-            assert f.result(300).tokens == reference_greedy(p, 10)
+            assert f.result(300).tokens == greedy_reference(jamba.forward, PARAMS, CFG, p, 10, MAX_CTX)
         st = eng.stats()
         ssm_, m = st["ssm"], CFG.n_mamba
         assert st["model"]["layers"] == 4 and m == 3
@@ -309,7 +304,7 @@ def test_chunked_prefill_carries_the_state_across_chunk_boundaries():
     eng = make_engine(prefill_buckets=(16, 32), prefill_chunk=16)
     try:
         for p in prompts(70, 41, seed=3):
-            assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 10)
+            assert eng.generate(p, GREEDY).tokens == greedy_reference(jamba.forward, PARAMS, CFG, p, 10, MAX_CTX)
     finally:
         eng.stop()
 
@@ -347,7 +342,7 @@ def test_a_parked_turn_resumes_from_the_saved_state():
     try:
         turn1 = prompts(29)[0]
         turn2 = turn1 + prompts(15, seed=9)[0]
-        cold = reference_greedy(turn2, 8)
+        cold = greedy_reference(jamba.forward, PARAMS, CFG, turn2, 8, MAX_CTX)
         sp = SamplingParams(temperature=0.0, max_tokens=8)
         eng.submit(turn1, sp, park=True).result(120)
         assert eng.stats()["parked_slots"] == 1
@@ -366,7 +361,7 @@ def test_a_prefix_hit_is_taken_where_the_state_was_saved():
         eng.generate(base, sp)
         longer = base + prompts(9, seed=4)[0]
         hits = eng.stats()["prefix_cache"]["hits"]
-        assert eng.generate(longer, sp).tokens == reference_greedy(longer, 6)
+        assert eng.generate(longer, sp).tokens == greedy_reference(jamba.forward, PARAMS, CFG, longer, 6, MAX_CTX)
         assert eng.stats()["prefix_cache"]["hits"] == hits + 1 and eng.state_restores >= 1
         with eng._prefix_lock:
             assert all(set(e["state"]) == {"ssm", "conv"} for e in eng._prefix_cache.values())

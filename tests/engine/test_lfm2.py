@@ -1,9 +1,10 @@
 """LFM2-MoE through the normal path: the program against the plain reference
 (acpbench/families/lfm2_reference.py, which imports nothing of the
 program) for each kind of layer and the whole pattern, prefill and then
-decode through pages and per-slot state; the eight shares of an expert
-layer summing to the uncut layer; and the engine carrying the state
-through preempt, host swap, park and the prefix cache.
+decode through pages and per-slot state; any layer pattern through the
+decode step's layout; the eight shares of an expert layer summing to the
+uncut layer; the page walk at head width 64. The engine serving it:
+`test_lfm2_engine.py`.
 
 CPU, tiny sizes, float32 (so that agreement is to rounding, not to
 bfloat16), seeded weights.
@@ -19,10 +20,10 @@ import pytest
 
 from acpbench import check, spec
 from acpbench.families import lfm2_reference, lfm2_weights
-from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
-from agentcontrolplane_tpu.models import lfm2, preset, programs
+from agentcontrolplane_tpu.models import lfm2, preset
 from agentcontrolplane_tpu.ops.moe import moe_ffn_reference, route_scores, route_topk, routed_experts
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
+from agentcontrolplane_tpu.testing import compiled
 
 FILE = spec.load_json(spec.os.path.join(spec.ROOT, "acpbench/configs/lfm2-24b-a2b-bf16-v5e1-ep8.json"))
 A, C = "full_attention", "conv"
@@ -78,27 +79,28 @@ def serves_what_forward_computes(model, cfg, state_leaves):
     leave the K, V and `state_leaves` that one prefill of all 28 tokens
     leaves (`tests/engine/test_jamba.py` runs its family through this too)."""
     params = model.init_params(cfg, jax.random.key(3))
+    # compiled, as the engine runs them (eagerly, every op of every layer is a program of its own)
+    forward, prefill, continuation, step = (
+        compiled(f, cfg) for f in (model.forward, model.prefill_paged_batch, model.prefill_paged_continue, model.decode_step_paged))
     B, P, cut, mid, T = 2, 8, 16, 24, 28
     tokens = np.random.default_rng(4).integers(0, cfg.vocab_size, (B, 32)).astype(np.int32)
-    want = model.forward(params, jnp.asarray(tokens[:, :T]), cfg)
+    want = forward(params, jnp.asarray(tokens[:, :T]))
     close = functools.partial(np.testing.assert_allclose, rtol=2e-5, atol=2e-5)
     i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
     full = lambda v: jnp.full((B,), v, jnp.int32)  # noqa: E731
     pages = i32([[1, 2, 3, 4], [5, 6, 7, 8]])
     lanes = (i32([0, 1]), full(-1))
     empty = model.init_paged_cache(cfg, 9, P, max_slots=B)
-    whole, _ = model.prefill_paged_batch(params, empty, tokens, full(T), pages, lanes, cfg)
+    whole, _ = prefill(params, empty, tokens, full(T), pages, lanes)
 
     padded = lambda rows: np.pad(rows, ((0, 0), (0, 32 - rows.shape[1])))  # noqa: E731
-    cache, got = model.prefill_paged_batch(
-        params, empty, padded(tokens[:, :cut]), full(cut), pages.at[:, cut // P:].set(0), lanes, cfg)
+    cache, got = prefill(params, empty, padded(tokens[:, :cut]), full(cut), pages.at[:, cut // P:].set(0), lanes)
     close(got, want[:, cut - 1])
     ids = jnp.zeros((B, 4), jnp.int32).at[:, 0].set(pages[:, cut // P])
-    cache, got = model.prefill_paged_continue(
-        params, cache, padded(tokens[:, cut:mid]), full(mid - cut), full(cut), ids, pages, lanes, cfg)
+    cache, got = continuation(params, cache, padded(tokens[:, cut:mid]), full(mid - cut), full(cut), ids, pages, lanes)
     close(got, want[:, mid - 1])
     for t in range(mid, T):
-        cache, got = model.decode_step_paged(params, cache, i32(tokens[:, t]), full(t), pages, jnp.ones((B,), bool), cfg)
+        cache, got = step(params, cache, i32(tokens[:, t]), full(t), pages, jnp.ones((B,), bool))
         close(got, want[:, t])
     for leaf in state_leaves:
         close(cache["state"][leaf][:, :B], whole["state"][leaf][:, :B])
@@ -266,215 +268,6 @@ def test_the_selection_bias_changes_the_choice_for_a_stated_share_of_tokens():
     assert 0.25 < share < 0.75, share
     s = jnp.take_along_axis(jax.nn.sigmoid(logits), with_b, axis=-1)
     np.testing.assert_allclose(w, s / (s.sum(-1, keepdims=True) + 1e-6), atol=1e-6)
-
-
-# -- the engine carries the state ------------------------------------------
-
-CFG = preset("lfm2-tiny")
-PARAMS = None
-ONE_CHIP = lambda: make_mesh({"tp": 1}, devices=jax.devices()[:1])  # noqa: E731
-
-
-def make_engine(**kw):
-    global PARAMS
-    if PARAMS is None:
-        PARAMS = lfm2.init_params(CFG, jax.random.key(0))
-    opts = dict(max_slots=4, max_ctx=128, kv_layout="paged", page_size=8, kv_pages=80,
-                prefill_buckets=(16, 32, 64), width_buckets=(2, 4), decode_block_size=4, check_invariants=True)
-    eng = Engine(config=CFG, params=PARAMS, mesh=ONE_CHIP(), **{**opts, **kw})
-    eng.start()
-    return eng
-
-
-def prompts(*lengths, seed=0):
-    rng = np.random.default_rng(seed)
-    return [[int(t) for t in rng.integers(0, 256, n)] for n in lengths]
-
-
-def reference_greedy(prompt, n):
-    """The model's own full forward, no cache and no state, token by token."""
-    toks = list(prompt)
-    for _ in range(n):
-        logits = lfm2.forward(PARAMS, jnp.asarray([toks]), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
-GREEDY = SamplingParams(temperature=0.0, max_tokens=10)
-
-
-def test_engine_serves_it_as_the_other_models_and_counts_its_experts():
-    eng = make_engine()
-    try:
-        ps = prompts(20, 37, 50)
-        futures = [eng.submit(p, GREEDY) for p in ps]
-        for p, f in zip(ps, futures):
-            assert f.result(300).tokens == reference_greedy(p, 10)
-        st = eng.stats()
-        moe, layers = st["moe"], CFG.n_layers - CFG.num_dense_layers
-        assert moe["held"] == 8 and st["model"]["layers"] == 12
-        for part, tokens in (("prefill", sum(map(len, ps))),):
-            assert moe[part]["pairs_routed"] == tokens * CFG.experts_per_token * layers
-            assert moe[part]["pairs_held"] == sum(moe[part]["tokens_per_held_expert"]) == moe[part]["pairs_routed"]
-        assert moe["decode"]["expert_layers"] == eng.decode_steps * layers
-        # the programs keep the names the trace readers match on, counters or not
-        assert eng._jit_decode_paged.__wrapped__.__name__ == "decode_block"
-        assert eng._jit_prefill_paged.__wrapped__.__name__ == "prefill_and_sample"
-        assert 0 < moe["decode"]["experts_read"] <= moe["decode"]["expert_layers"] * 8
-        assert st["kv_pages"]["state_refused"] == 0
-    finally:
-        eng.stop()
-
-
-def test_chunked_prefill_carries_the_state_across_chunk_boundaries():
-    eng = make_engine(prefill_buckets=(16, 32), prefill_chunk=16)
-    try:
-        for p in prompts(70, 41, seed=3):
-            assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 10)
-    finally:
-        eng.stop()
-
-
-@pytest.mark.parametrize("host_kv_bytes", [0, 1 << 22], ids=["recompute", "host-swap"])
-def test_preempt_and_resume_reproduce_the_uninterrupted_tokens(host_kv_bytes):
-    """An oversubscribed pool preempts; the resume recomputes the state (no
-    host tier) or restores pages and state from the host entry saved at the
-    one length whose state was kept."""
-    eng = make_engine(kv_pages=14, host_kv_bytes=host_kv_bytes)
-    try:
-        sp = SamplingParams(temperature=0.0, max_tokens=12)
-        ps = prompts(*[20] * 6, seed=1)
-        solo = [eng.generate(p, sp).tokens for p in ps]
-        with eng.hold_admission():
-            futures = [eng.submit(p, sp) for p in ps]
-        assert [f.result(300).tokens for f in futures] == solo
-        assert eng.preemptions >= 1
-        if host_kv_bytes:
-            assert eng.kv_swap_outs >= 1 and eng.kv_swap_ins >= 1 and eng.state_restores >= 1
-    finally:
-        eng.stop()
-
-
-def test_a_parked_turn_resumes_from_the_saved_state():
-    eng = make_engine()
-    try:
-        turn1 = prompts(29)[0]
-        turn2 = turn1 + prompts(15, seed=9)[0]
-        cold = reference_greedy(turn2, 8)
-        sp = SamplingParams(temperature=0.0, max_tokens=8)
-        eng.submit(turn1, sp, park=True).result(120)
-        assert eng.stats()["parked_slots"] == 1
-        before = eng.state_restores
-        assert eng.generate(turn2, sp).tokens == cold
-        assert eng.park_adoptions == 1 and eng.state_restores == before + 1
-    finally:
-        eng.stop()
-
-
-def test_a_prefix_hit_is_taken_where_the_state_was_saved_and_only_there():
-    eng = make_engine(prefix_dedup=True)
-    try:
-        base = prompts(45)[0]  # saved at its last page boundary: 40 tokens
-        sp = SamplingParams(temperature=0.0, max_tokens=6)
-        eng.generate(base, sp)
-        longer = base + prompts(9, seed=4)[0]
-        hits = eng.stats()["prefix_cache"]["hits"]
-        assert eng.generate(longer, sp).tokens == reference_greedy(longer, 6)
-        assert eng.stats()["prefix_cache"]["hits"] == hits + 1 and eng.state_restores >= 1
-        with eng._prefix_lock:
-            assert {e["cut"] for e in eng._prefix_cache.values()} <= {40, 48} and all(
-                "state" in e for e in eng._prefix_cache.values())
-        # live leaders' pages are never shared (no state at the common cut): dedup is a miss
-        with eng.hold_admission():
-            futures = [eng.submit(base + [7, i], sp) for i in range(3)]
-        for i, f in enumerate(futures):
-            assert f.result(120).tokens == reference_greedy(base + [7, i], 6)
-        assert eng.prefix_shares == 0
-    finally:
-        eng.stop()
-
-
-def test_a_host_entry_without_a_state_is_a_miss():
-    from agentcontrolplane_tpu.ops.paged import HostKVEntry
-
-    eng = make_engine(host_kv_bytes=1 << 22, prefix_cache_entries=0)
-    try:
-        p = prompts(44)[0]
-        L, HD = CFG.n_attention, CFG.n_kv_heads * CFG.head_dim
-        rows = np.ones((L, 32, HD), np.float32)  # wrong K/V: it must never be restored
-        assert eng.inject_host_kv(HostKVEntry(rid="x", tokens=tuple(p[:32]), rows={"k": rows, "v": rows}))
-        assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 10)
-        assert eng.state_refused >= 1 and eng.kv_swap_ins == 0
-    finally:
-        eng.stop()
-
-
-@pytest.mark.parametrize("kw,words", [
-    ({"spec_len": 4}, "rolled back"), ({"kv_layout": "slot"}, "paged"), ({"quantize": "int8"}, "int8"),
-])
-def test_what_the_engine_cannot_do_for_it_is_refused_in_words(kw, words):
-    with pytest.raises(ValueError, match=words):
-        Engine(config=CFG, mesh=ONE_CHIP(), max_slots=2, max_ctx=64, **{"kv_layout": "paged", "page_size": 8, **kw})
-
-
-def test_the_seam_gives_each_family_its_programs():
-    assert programs(CFG).has_state and not programs(preset("tiny")).has_state
-    assert programs(preset("tiny")).prefill_paged_batch.__module__.endswith("models.llama")
-    with pytest.raises(KeyError, match="lfm2-24b-a2b-ep8"):
-        preset("no-such-model")
-    full = preset("lfm2-24b-a2b-ep8")
-    assert (full.n_layers, full.n_attention, full.n_conv, len(full.held)) == (40, 10, 30, 8)
-
-
-@pytest.mark.parametrize("capability", ["state-without-counters", "counters-without-state"])
-def test_state_and_counters_are_capabilities_apart(capability, monkeypatch):
-    """The engine asks a family for its per-slot state and for its device
-    counters separately: a family with one and not the other serves."""
-    import types
-
-    from agentcontrolplane_tpu import models
-    from agentcontrolplane_tpu.engine import engine as engine_module
-
-    if capability == "state-without-counters":
-        family = types.SimpleNamespace(**{**vars(models._LFM2), "counters": None})
-        monkeypatch.setattr(engine_module, "programs", lambda config: family)
-        eng, p = make_engine(), prompts(20)[0]
-        try:
-            assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 10)
-            st = eng.stats()
-            assert "moe" not in st and st["kv_pages"]["state_saves"] >= 0
-        finally:
-            eng.stop()
-        return
-    tiny_llama = preset("tiny")
-    seen = types.SimpleNamespace(**{
-        **vars(models._LLAMA),
-        "counters": lambda cache: jnp.sum(cache["k"] != 0, dtype=jnp.uint32)[None],
-        "describe_counters": lambda config, total: {"kv_nonzero": {"n": 0 if total is None else int(total[0])}},
-    })
-    monkeypatch.setattr(engine_module, "programs", lambda config: seen)
-    eng = Engine(config=tiny_llama, mesh=ONE_CHIP(), max_slots=2, max_ctx=64, kv_layout="paged", page_size=8,
-                 prefill_buckets=(16, 32), width_buckets=(2,), decode_block_size=4)
-    eng.start()
-    try:
-        assert eng.stats()["kv_nonzero"] == {"n": 0}
-        eng.generate(prompts(12)[0], SamplingParams(temperature=0.0, max_tokens=4))
-        assert eng.stats()["kv_nonzero"]["n"] > 0
-    finally:
-        eng.stop()
-
-
-def test_prewarm_freezes_the_heap_and_stop_gives_it_back():
-    import gc
-
-    eng = make_engine(prefill_buckets=(16,), width_buckets=(2,), max_slots=2, prefix_cache_entries=0)
-    before = gc.get_freeze_count()  # what a test plugin may have frozen already
-    try:
-        eng.prewarm()
-        assert gc.get_freeze_count() > before + 1000
-    finally:
-        eng.stop()
-    assert gc.get_freeze_count() == 0
 
 
 # -- the page walk at head width 64 -----------------------------------------

@@ -299,7 +299,8 @@ def test_shape_bound_falls_back_to_split_programs(spec_len):
     finally:
         ref.stop()
     eng = make_engine("slot", megastep=True, prefill_chunk=16,
-                      spec_len=spec_len, megastep_max_programs=0)
+                      spec_len=spec_len)
+    eng.megastep_max_programs = 0
     try:
         fb0 = counter("acp_engine_megastep_fallbacks_total")
         got = _busy_run(eng)

@@ -31,6 +31,7 @@ from agentcontrolplane_tpu.ops import paged
 from agentcontrolplane_tpu.ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
 from agentcontrolplane_tpu.ops.rope import apply_rope, rope_frequencies, yarn_correction_range, yarn_scale_frequencies
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
+from agentcontrolplane_tpu.testing import compiled, greedy_reference
 
 FILE = spec.load_json(spec.os.path.join(spec.ROOT, "tests/acpbench/data/tiny-config-mellum.json"))
 PUBLISHED = spec.load_json(spec.os.path.join(spec.ROOT, "acpbench/configs/mellum2-12b-a2.5b-bf16-v5e1-ep4.json"))
@@ -197,9 +198,9 @@ def _rows(params, pc, tokens, starts, lengths, cache, tables, slots, T, head=Tru
         n = -(-lengths[b] // PAGE)
         ids[b, :n] = tables[b, starts[b] // PAGE: starts[b] // PAGE + n]
     lanes = (jnp.asarray(slots, jnp.int32), jnp.full((B,), -1, jnp.int32))
-    fn = mellum.prefill_paged_continue if head else mellum.prefill_paged_continue_kv
-    return jax.jit(lambda c: fn(params, c, jnp.asarray(toks), jnp.asarray(lengths, jnp.int32),
-                                jnp.asarray(starts, jnp.int32), jnp.asarray(ids), jnp.asarray(tables), lanes, pc))(cache)
+    fn = mellum.prefill_paged_continue if head else mellum.prefill_paged_continue_kv  # one program, however many chunks
+    return compiled(fn, pc)(params, cache, jnp.asarray(toks), jnp.asarray(lengths, jnp.int32),
+                            jnp.asarray(starts, jnp.int32), jnp.asarray(ids), jnp.asarray(tables), lanes)
 
 
 @pytest.mark.parametrize("chunk", [16, 64], ids=["chunk-under-the-ring", "chunk-over-the-ring"])
@@ -295,12 +296,13 @@ class NoStop(ByteTokenizer):
 
 
 CFG = preset("mellum-tiny")
+MAX_CTX = 256  # the engines' and the padded reference's
 PARAMS = mellum.init_params(CFG, jax.random.key(0))
 GREEDY = SamplingParams(temperature=0.0, max_tokens=24)
 
 
 def make_engine(**over):
-    opts = dict(kv_layout="paged", page_size=PAGE, max_slots=4, max_ctx=256, prefill_buckets=(32, 64, 128),
+    opts = dict(kv_layout="paged", page_size=PAGE, max_slots=4, max_ctx=MAX_CTX, prefill_buckets=(32, 64, 128),
                 width_buckets=(2,), decode_block_size=4, tokenizer=NoStop(), check_invariants=True)
     eng = Engine(config=CFG, params=PARAMS, mesh=make_mesh({"tp": 1}, devices=jax.devices()[:1]), **{**opts, **over})
     eng.start()
@@ -310,18 +312,6 @@ def make_engine(**over):
 def prompts(*lengths, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(0, 256, size=n).tolist() for n in lengths]
-
-
-_forward = jax.jit(lambda t: mellum.forward(PARAMS, t, CFG))
-
-
-def reference_greedy(prompt, n):
-    toks = list(prompt)
-    for _ in range(n):
-        row = np.zeros((1, 256), np.int32)
-        row[0, : len(toks)] = toks
-        toks.append(int(np.asarray(_forward(jnp.asarray(row)))[0, len(toks) - 1].argmax()))
-    return toks[len(prompt):]
 
 
 def test_engine_serves_short_and_long_slots_in_one_batch_and_counts_its_window():
@@ -334,7 +324,7 @@ def test_engine_serves_short_and_long_slots_in_one_batch_and_counts_its_window()
         budgets = (10, 30, 50, 40, 20, 60)
         futures = [eng.submit(p, SamplingParams(temperature=0.0, max_tokens=m)) for p, m in zip(ps, budgets)]
         for p, m, f in zip(ps, budgets, futures):
-            assert f.result(300).tokens == reference_greedy(p, m)
+            assert f.result(300).tokens == greedy_reference(mellum.forward, PARAMS, CFG, p, m, MAX_CTX)
         st = eng.stats()
         w = st["window"]
         assert (w["window"], w["window_layers"], w["full_layers"]) == (WINDOW, 6, 2)
@@ -380,7 +370,7 @@ def test_prompts_over_the_widest_bucket_go_through_continuations(chunked):
     eng = make_engine(prefill_buckets=(32, 64), **({"prefill_chunk": 32} if chunked else {}))
     try:
         for p in prompts(150, 70, seed=3):
-            assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 24)
+            assert eng.generate(p, GREEDY).tokens == greedy_reference(mellum.forward, PARAMS, CFG, p, 24, MAX_CTX)
         assert verify_engine(eng) == []
     finally:
         eng.stop()
@@ -432,9 +422,9 @@ def test_a_handoff_is_refused_and_a_park_is_not_taken():
         with pytest.raises(ValueError, match="does not serve with export_kv"):
             eng.submit(prompts(20)[0], GREEDY, export_kv=True)
         p = prompts(40, seed=7)[0]
-        assert eng.submit(p, GREEDY, park=True).result(300).tokens == reference_greedy(p, 24)
+        assert eng.submit(p, GREEDY, park=True).result(300).tokens == greedy_reference(mellum.forward, PARAMS, CFG, p, 24, MAX_CTX)
         # the same prompt again: no prefix entry, no parked slot, no shared page served it
-        assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 24)
+        assert eng.generate(p, GREEDY).tokens == greedy_reference(mellum.forward, PARAMS, CFG, p, 24, MAX_CTX)
         st = eng.stats()
         assert st["parked_slots"] == 0 and st["tool_overlap"]["parks"] == 0 and "prefix_cache" not in st
         with pytest.raises(NotImplementedError, match="saves no state"):

@@ -25,6 +25,7 @@ from acpbench.families import ouro_reference, ouro_weights
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.models import llama, ouro, preset, programs
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
+from agentcontrolplane_tpu.testing import greedy_reference
 
 FILE = spec.load_json(spec.os.path.join(spec.ROOT, "tests/acpbench/data/tiny-config-ouro.json"))
 PAGE = FILE["engine"]["page_size"]
@@ -267,6 +268,7 @@ def test_the_value_policy_keeps_the_state_in_hand_through_every_loop():
 # -- the engine ------------------------------------------------------------------------------
 
 CFG = preset("ouro-tiny")
+MAX_CTX = 128  # the engines' and the padded reference's
 PARAMS = None
 
 
@@ -275,7 +277,7 @@ def make_engine(**kw):
     if PARAMS is None:
         PARAMS = ouro.init_params(CFG, jax.random.key(0))
     # armed: the engine audits its own books (pages, refcounts, host entries, the cache's leaves) after every cycle
-    opts = dict(max_slots=4, max_ctx=128, kv_layout="paged", page_size=8, kv_pages=80, prefill_batch_max=1,
+    opts = dict(max_slots=4, max_ctx=MAX_CTX, kv_layout="paged", page_size=8, kv_pages=80, prefill_batch_max=1,
                 prefill_buckets=(16, 32, 64), width_buckets=(2, 4), decode_block_size=4, check_invariants=True)
     eng = Engine(config=CFG, params=PARAMS, mesh=ONE_CHIP(), **{**opts, **kw})
     eng.start()
@@ -287,17 +289,6 @@ def prompts(*lengths, seed=0):
     return [[int(t) for t in rng.integers(0, 256, n)] for n in lengths]
 
 
-_FORWARD = jax.jit(lambda params, tokens: ouro.forward(params, tokens, CFG))
-
-
-def reference_greedy(prompt, n):
-    """The model's own full forward, no cache, token by token (one compiled
-    length: what lies to the right of a row never reaches it)."""
-    toks = list(prompt)
-    for _ in range(n):
-        logits = _FORWARD(PARAMS, jnp.asarray([toks + [0] * (128 - len(toks))]))
-        toks.append(int(jnp.argmax(logits[0, len(toks) - 1])))
-    return toks[len(prompt):]
 
 
 GREEDY = SamplingParams(temperature=0.0, max_tokens=10)
@@ -310,7 +301,7 @@ def test_engine_serves_short_and_long_slots_in_one_batch_and_counts():
         with eng.hold_admission():
             futures = [eng.submit(p, GREEDY) for p in ps]
         for p, f in zip(ps, futures):
-            assert f.result(300).tokens == reference_greedy(p, 10)
+            assert f.result(300).tokens == greedy_reference(ouro.forward, PARAMS, CFG, p, 10, MAX_CTX)
         st = eng.stats()
         assert set(eng.cache) == {"k", "v", "state"} and eng.cache["k"].shape[0] == CFG.loops * CFG.n_layers
         assert (st["model"]["layers"], st["model"]["cache_layers"]) == (3, 6)
@@ -330,19 +321,19 @@ def test_chunked_prefill_and_a_prefix_hit_read_each_loops_own_rows():
     eng = make_engine(prefill_buckets=(16, 32), prefill_chunk=16, prefix_dedup=True)
     try:
         for p in prompts(70, 41, seed=3):
-            assert eng.generate(p, GREEDY).tokens == reference_greedy(p, 10)
+            assert eng.generate(p, GREEDY).tokens == greedy_reference(ouro.forward, PARAMS, CFG, p, 10, MAX_CTX)
         base = prompts(45)[0]
         sp = SamplingParams(temperature=0.0, max_tokens=6)
         eng.generate(base, sp)
         longer = base + prompts(9, seed=4)[0]
         hits = eng.stats()["prefix_cache"]["hits"]
-        assert eng.generate(longer, sp).tokens == reference_greedy(longer, 6)
+        assert eng.generate(longer, sp).tokens == greedy_reference(ouro.forward, PARAMS, CFG, longer, 6, MAX_CTX)
         assert eng.stats()["prefix_cache"]["hits"] == hits + 1
         fresh = prompts(41, seed=8)[0]
         with eng.hold_admission():
             futures = [eng.submit(fresh + [7, i], sp) for i in range(3)]
         for i, f in enumerate(futures):
-            assert f.result(120).tokens == reference_greedy(fresh + [7, i], 6)
+            assert f.result(120).tokens == greedy_reference(ouro.forward, PARAMS, CFG, fresh + [7, i], 6, MAX_CTX)
         assert eng.prefix_shares >= 1
     finally:
         eng.stop()
@@ -376,7 +367,7 @@ def test_a_parked_turn_is_adopted_and_an_export_carries_every_cache_layer():
         sp = SamplingParams(temperature=0.0, max_tokens=8)
         eng.submit(turn1, sp, park=True).result(120)
         assert eng.stats()["parked_slots"] == 1
-        assert eng.generate(turn2, sp).tokens == reference_greedy(turn2, 8)
+        assert eng.generate(turn2, sp).tokens == greedy_reference(ouro.forward, PARAMS, CFG, turn2, 8, MAX_CTX)
         assert eng.park_adoptions == 1
         out = eng.submit(turn2, sp, export_kv=True).result(120)
         entry = out.kv_handoff

@@ -332,8 +332,8 @@ def test_autopilot_engine_applies_and_flight_records():
     """Engine integration: with the autopilot armed at a tiny interval and
     spec acceptance forced low, the engine applies a spec_len step and
     flight-records it."""
-    eng = make_engine(prefill_chunk=8, spec_len=6, autopilot=True,
-                      autopilot_interval=2)
+    eng = make_engine(prefill_chunk=8, spec_len=6, autopilot=True)
+    eng._autopilot.interval = 2
     try:
         a0 = counter("acp_engine_autopilot_adjustments_total")
         # force terrible acceptance so the policy must shrink spec_len

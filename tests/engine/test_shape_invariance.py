@@ -21,13 +21,13 @@ import functools
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.models import jamba, kanana, lfm2, llama, mellum, preset
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
+from agentcontrolplane_tpu.testing import greedy_reference
 
 FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2), "jamba": ("jamba-tiny", jamba),
             # the probe's 44 tokens cross mellum-tiny's window of 32 inside its decode blocks
@@ -35,6 +35,7 @@ FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2), "jamba": ("ja
             # expanded prefill and absorbed decode through a one-leaf pool
             "kanana": ("kanana-tiny", kanana)}
 N_TOKENS = 24
+MAX_CTX = 128
 GREEDY = SamplingParams(temperature=0.0, max_tokens=N_TOKENS)
 # outlive the probe, so the width it decodes at holds until it is done
 NEIGHBOUR = SamplingParams(temperature=0.0, max_tokens=2 * N_TOKENS + 12)
@@ -62,7 +63,7 @@ class Family:
             eng = Engine(
                 config=self.config, params=self.params,
                 mesh=make_mesh({"tp": 1}, devices=jax.devices()[:1]),
-                max_ctx=128, kv_layout="paged", page_size=8, prefix_cache_entries=0,
+                max_ctx=MAX_CTX, kv_layout="paged", page_size=8, prefix_cache_entries=0,
                 check_invariants=True, **options,
             )
             eng.start()
@@ -71,14 +72,7 @@ class Family:
 
     @functools.cached_property
     def reference(self):
-        """Greedy tokens by the model's full forward over one padded row
-        (causal: what follows a position cannot reach it)."""
-        forward = jax.jit(functools.partial(self.model.forward, config=self.config))
-        row = np.zeros((1, len(PROBE) + N_TOKENS), np.int32)
-        row[0, : len(PROBE)] = PROBE
-        for n in range(len(PROBE), row.shape[1]):
-            row[0, n] = int(jnp.argmax(forward(self.params, jnp.asarray(row))[0, n - 1]))
-        return [int(t) for t in row[0, len(PROBE):]]
+        return greedy_reference(self.model.forward, self.params, self.config, PROBE, N_TOKENS, MAX_CTX)
 
     def close(self):
         for eng in self.engines.values():

@@ -73,14 +73,14 @@ def test_compiled_stream_across_slots_matches_reference(tpu, order, int8):
     """The walk's one stream of turns COMPILED: slots of 0 to 3 x depth + 1
     turns with empty ones first, between and last, every page no table
     names NaN. A wait on a fetch that was never started hangs here, where
-    the interpreter (`tests/engine/test_paged.py`) cannot."""
+    the interpreter (`tests/engine/test_paged_stream.py`) cannot."""
     import jax.numpy as jnp
 
-    from .test_paged import _PAGES, _stream_case, _stream_parity
+    from ._paged_cases import PAGES, stream_case, stream_parity
 
-    c = _stream_case(_PAGES[order], jnp.bfloat16, H=8, Hkv=2, d=128, int8=int8)
+    c = stream_case(PAGES[order], jnp.bfloat16, H=8, Hkv=2, d=128, int8=int8)
     for plus_new in (False, True):
-        _stream_parity(c, plus_new, 2e-2, interpret=False)
+        stream_parity(c, plus_new, 2e-2, interpret=False)
 
 
 _LATENT_TURNS = {  # turns a slot; the first is `kernel_parity.LATENT_TURNS`, what `chip_smoke.py` runs

@@ -173,9 +173,9 @@ def test_stalling_replica_degrades_sheds_affinity_and_recovers():
         watchdog_interval_s=0.1,
         health_policy=HealthPolicy(degrade_after=2, recover_after=3),
     )
-    engines = [make_engine(stall_mult=2.0, stall_min_s=0.02)
-               for _ in range(2)]
+    engines = [make_engine() for _ in range(2)]
     for i, eng in enumerate(engines):
+        eng.stall_mult, eng.stall_min_s = 2.0, 0.02
         router.add_replica(f"r{i}", eng)
     try:
         sp = SamplingParams(temperature=0.0, max_tokens=2)

@@ -65,9 +65,9 @@ def make_hedging_pool(n=2, **router_kw):
     router_kw.setdefault("health_policy", HealthPolicy(degrade_after=1))
     router_kw.setdefault("heartbeat_interval", 60.0)
     router = FleetRouter(store=Store(), **router_kw)
-    engines = [make_engine(stall_mult=2.0, stall_min_s=0.02)
-               for _ in range(n)]
+    engines = [make_engine() for _ in range(n)]
     for i, eng in enumerate(engines):
+        eng.stall_mult, eng.stall_min_s = 2.0, 0.02
         router.add_replica(f"r{i}", eng)
     return router, engines
 

@@ -112,18 +112,25 @@ def test_live_trace_replays_byte_identical(kv_layout):
     eng = make_engine(kv_layout, spec_len=6, prefill_chunk=16)
     try:
         eng.prewarm(constrained=True)
+        # The trace starts at the live traffic. The prewarm's bursts go
+        # through submit() and are recorded too (eight one-token requests,
+        # one, two and four at a time), and each 1x replay then slept
+        # through the compiles between them and the live requests: most of
+        # this case's time. Four live requests fill the four slots as the
+        # widest burst did.
+        with eng.flight._lock:
+            eng.flight._done.clear()
         sp = SamplingParams(temperature=0.0, max_tokens=8)
         live = [
             "persona alpha shares this long prefix // req one",
             "persona alpha shares this long prefix // req two",
             "persona beta is its own prompt shape",
+            "persona gamma asks for something else again",
         ]
         for f in [eng.submit(p, sp) for p in live]:
             f.result(timeout=120)
         trace = export_trace(eng.flight)
         assert validate_trace(trace) == []
-        # >= because prewarm's warmup bursts go through submit() and are
-        # recorded too — they replay like any other traffic
         assert len(trace["requests"]) >= 3
         a = replay(trace, eng, speed=1.0, seed=5, record_metrics=False)
         b = replay(trace, eng, speed=1.0, seed=5, record_metrics=False)
