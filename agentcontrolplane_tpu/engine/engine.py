@@ -50,7 +50,7 @@ from ..models import LlamaConfig, PRESETS, preset, programs  # noqa: F401 (re-ex
 from ..observability import scopes
 from ..observability.metrics import REGISTRY
 from ..ops.paged import TRASH_PAGE, page_bytes, pool_leaves, ring_size, set_pages
-from ..ops.sampling import NEG_INF, masks_wanted, sample
+from ..ops.sampling import NEG_INF, masked_logits, masks_wanted, sample, speculative_sample
 from ..parallel.mesh import (
     kv_cache_shardings,
     serving_mesh,
@@ -464,6 +464,106 @@ def make_decode_block(step_fn, stop_toks: tuple, max_ctx: int, block_size: int):
     return decode_block
 
 
+class _DraftSampler:
+    """What a drafting family's step asks of the engine (``models.programs``
+    ``draft_step``): the draft drawn from the drafted logits, and the
+    accept, both under the lanes' own constraint masks, sampling parameters,
+    stops, budgets and the context's edge, as the decode block's one-token
+    step applies them. ``after`` holds the lanes as the step leaves them."""
+
+    def __init__(self, key, tokens, seq_lens, con_states, budgets, active, ln, wanted,
+                 table, min_close, stop_toks, max_ctx):
+        self.key_draft, self.key_accept = jax.random.split(key)
+        self.lanes = (tokens, seq_lens, con_states, budgets, active)
+        self.ln, self.wanted = ln, wanted
+        self.table, self.min_close, self.stop_toks, self.max_ctx = table, min_close, stop_toks, max_ctx
+        self.after = None
+
+    def _masked(self, logits, con_states, budgets):
+        ln = self.ln
+        logits = constrain_logits(logits, self.table, con_states, ln["constrained"], self.min_close, budgets)
+        return masked_logits(logits, ln["top_ks"], ln["top_ps"], self.wanted)
+
+    def _stops(self, toks):
+        hit = jnp.zeros(toks.shape, bool)
+        for st in self.stop_toks:
+            hit = hit | (toks == st)
+        return hit
+
+    def propose(self, q_logits):
+        _tokens, _seq_lens, con_states, budgets, _active = self.lanes
+        with scopes.layer("sample"), jax.named_scope("spec_accept"):
+            q_logits = self._masked(q_logits, con_states, budgets)
+            temps = self.ln["temps"]
+            drawn = jax.random.categorical(self.key_draft, q_logits / jnp.maximum(temps, 1e-6)[:, None], axis=-1)
+            draft = jnp.where(temps <= 0.0, jnp.argmax(q_logits, axis=-1), drawn).astype(jnp.int32)
+            return draft, q_logits
+
+    def accept(self, logits, draft, q_logits):
+        tokens, seq_lens, con_states, budgets, active = self.lanes
+        constrained = self.ln["constrained"]
+        with scopes.layer("sample"), jax.named_scope("spec_accept"):
+            # the second row is judged in the state the draft would leave
+            drafted_state = advance_constraint(self.table, con_states, constrained, draft)
+            p_logits = jnp.stack([self._masked(logits[:, 0], con_states, budgets),
+                                  self._masked(logits[:, 1], drafted_state, budgets - 1)], axis=1)
+            kept, first, second = speculative_sample(p_logits, q_logits, draft, self.key_accept, self.ln["temps"])
+            # the second token lands only where the lane would have lived on after the first
+            both = active & kept & ~self._stops(first) & (budgets > 1) & (seq_lens + 2 < self.max_ctx)
+            emitted = jnp.where(active, 1 + both.astype(jnp.int32), 0)
+            out = jnp.stack([jnp.where(active, first, -1), jnp.where(both, second, -1)], axis=1)
+            last = jnp.where(both, second, first)
+            con_states = advance_constraint(self.table, con_states, constrained & active, first)
+            con_states = advance_constraint(self.table, con_states, constrained & both, second)
+            seq_lens, budgets = seq_lens + emitted, budgets - emitted
+            live = active & ~self._stops(last) & (budgets > 0) & (seq_lens + 1 < self.max_ctx)
+            self.after = (jnp.where(active, last, tokens), seq_lens, con_states, budgets, live)
+            return out, emitted, kept
+
+
+def make_draft_block(step_fn, stop_toks: tuple, max_ctx: int, block_size: int):
+    """The K-step decode block of a family that drafts by itself
+    (``models.programs`` ``draft_step``): :func:`make_decode_block`'s carry,
+    lanes and hand-back, around a verify-and-draft step that commits up to
+    ``draft_rows`` tokens a lane. Lengths, budgets, constraint states, stops and
+    the context's edge advance by the emitted count on the device; the
+    block's tokens are ``[block, lanes, draft_rows]`` with -1 where a step
+    emitted none, so one fetch hands the host each lane's tokens and their
+    counts. What the drafter carries from step to step (and from block to
+    block) is in the family's cache, which is donated and handed back with
+    the lanes."""
+
+    def decode_block(params, cache, lanes, key, table, min_close, *extra):
+        ln = DECODE.unpack(lanes)
+        with scopes.layer("sample"):
+            wanted = masks_wanted(ln["top_ks"], ln["top_ps"], ln["active"])
+
+        def step(carry, _):
+            cache, tokens, seq_lens, con_states, budgets, active, rng = carry
+            with scopes.layer("sample"):
+                rng, sub = jax.random.split(rng)
+            sampler = _DraftSampler(sub, tokens, seq_lens, con_states, budgets, active, ln, wanted,
+                                    table, min_close, stop_toks, max_ctx)
+            cache, out, _emitted, *_ = step_fn(params, cache, tokens, seq_lens, active, sampler, *extra)
+            return (cache, *sampler.after, rng), out
+
+        with scopes.layer("sample"):
+            first_key = dispatch_key(key, ln["n"], ln["chain"])
+        (cache, tokens, seq_lens, con_states, budgets, active, _), toks = jax.lax.scan(
+            step,
+            (cache, ln["tokens"], ln["seq_lens"], ln["con_states"], ln["budgets"],
+             ln["active"], first_key),
+            None, length=block_size,
+        )
+        lanes = DECODE.update(
+            lanes, tokens=tokens, seq_lens=seq_lens, con_states=con_states,
+            budgets=budgets, active=active, chain=ln["chain"] + 1,
+        )
+        return cache, toks, con_states, lanes
+
+    return decode_block
+
+
 class Engine:
     def __init__(
         self,
@@ -642,6 +742,9 @@ class Engine:
         self._window_cache = self._model.window_cache
         # device counters a family keeps in its cache (None: it keeps none)
         self._counters = self._model.counters
+        # tokens a lane may commit a decode step: 1, or what a family that
+        # drafts by itself verifies a step (models.programs `draft_step`)
+        self._step_rows = self._model.draft_rows if self._model.draft_step is not None else 1
         self.tokenizer = tokenizer or ByteTokenizer()
         self.max_slots = max_slots
         self.max_ctx = min(max_ctx, config.max_seq_len)
@@ -1380,13 +1483,25 @@ class Engine:
                 )
 
             mesh = self.mesh
-            decode_block = make_decode_block(
-                lambda params, pages, tokens, seq_lens, active, block_tables: decode_step_paged(
-                    params, pages, tokens, seq_lens, block_tables, active, config,
-                    use_pallas=use_pallas, mesh=mesh,
-                ),
-                stop_toks, self.max_ctx, self.decode_block_size,
-            )
+            draft_step = self._model.draft_step
+            if draft_step is not None:
+                # the family's own verify-and-draft step in the block's place
+                # for the one-token step: same lanes, same carry, same name
+                decode_block = make_draft_block(
+                    lambda params, pages, tokens, seq_lens, active, sampler, block_tables: draft_step(
+                        params, pages, tokens, seq_lens, block_tables, active, sampler, config,
+                        use_pallas=use_pallas, mesh=mesh,
+                    ),
+                    stop_toks, self.max_ctx, self.decode_block_size,
+                )
+            else:
+                decode_block = make_decode_block(
+                    lambda params, pages, tokens, seq_lens, active, block_tables: decode_step_paged(
+                        params, pages, tokens, seq_lens, block_tables, active, config,
+                        use_pallas=use_pallas, mesh=mesh,
+                    ),
+                    stop_toks, self.max_ctx, self.decode_block_size,
+                )
             self._jit_decode_paged = self._with_counters(decode_block)(
                 lambda f: jax.jit(f, donate_argnums=(1, 2))
             )
@@ -4344,6 +4459,14 @@ class Engine:
                     slot, "stop" if first_tok in self.tokenizer.stop_tokens else "length"
                 )
 
+    _step_rows = 1  # until the constructor has read the family's seam
+
+    @property
+    def _block_rows(self) -> int:
+        """Rows a decode block may write a slot: a step's committed tokens
+        and, for a family that drafts, the refused row past the last."""
+        return self.decode_block_size * self._step_rows + (self._step_rows > 1)
+
     def _ensure_pages_for_block(self, need_tokens: Optional[dict] = None) -> None:
         """Paged mode: every active slot's table must cover the next K
         tokens before dispatch (or, per slot, ``need_tokens[slot]`` —
@@ -4356,7 +4479,7 @@ class Engine:
         later via a prompt+partial prefill."""
         if self._faults.enabled:
             self._faults.apply_page_pressure(self._allocator)
-        K = self.decode_block_size
+        K = self._block_rows
         # Pass 1 — strict coverage: every slot gets exactly the pages this
         # block needs; lookahead can never starve a slot that strictly fits.
         crossed: list[int] = []
@@ -4431,7 +4554,7 @@ class Engine:
             return self._allocator.alloc(n)
         except MemoryError:
             pass
-        K = self.decode_block_size
+        K = self._block_rows
         reclaimed = False
         for slot in self._slots:
             table = self._slot_pages.get(slot)
@@ -4577,7 +4700,7 @@ class Engine:
         # length (this is real memory exhaustion, not contention; the old
         # force-finish behavior, now reserved for the impossible case).
         if self.kv_layout == "paged":
-            K = self.decode_block_size
+            K = self._block_rows
             ever_needed = min(
                 -(-(len(self._full_row(req)) + K) // self.page_size),
                 self.max_pages_per_seq,
@@ -4816,7 +4939,10 @@ class Engine:
             if slot >= W:
                 continue  # joined after the lanes were built (fused finals)
             n0 = len(sl.generated)
-            self._consume_tokens(slot, sl, (int(tok_block[k, slot]) for k in range(K)))
+            # [K] tokens a lane, or [K, rows] of a family that drafts: -1
+            # where a step emitted none (after a lane's finish too, where
+            # the one-token block repeats its last token)
+            self._consume_tokens(slot, sl, (int(t) for t in tok_block[:, slot].reshape(-1) if t >= 0))
             # sl stays valid after a _finish pops the slot — the delta is
             # this dispatch's committed tokens (stop tokens included: the
             # termination signal is useful compute)
@@ -4830,7 +4956,7 @@ class Engine:
             # inactive lanes and post-finish steps — is width padding
             self.profiler.account(
                 goodput=emitted, prewarm=pre_emitted,
-                pad_width=W * K - emitted - pre_emitted,
+                pad_width=W * K * self._step_rows - emitted - pre_emitted,
             )
         self._publish_decode_gauges()
 

@@ -26,6 +26,16 @@ programs alone: it takes ``lanes`` as a family with state does, but nothing
 of it can be saved or installed, so the engine refuses what would need
 that.
 
+A family that drafts by itself (``models.exaone``: the model's own
+multi-token-prediction module) gives ``draft_step`` in place of
+``decode_step_paged`` in the engine's decode block: ``draft_step(params,
+cache, tokens, seq_lens, block_tables, active, sampler, config, ...) ->
+(cache, tokens [S, draft_rows], emitted [S], ...)``, a verify-and-draft step
+that commits up to ``draft_rows`` tokens a lane, the draft drawn and judged
+by the engine's ``sampler`` (``propose`` / ``accept``). What the drafter
+carries between steps is the family's, under the cache's ``"state"``.
+``draft_step`` None, ``draft_rows`` 1: a step yields one token a lane.
+
 What the engine needs to know of a family beyond its programs it asks here
 too, and branches on no family's name or cache kind: ``refusals(asked)``
 gives the options a family does not serve, each with its reason in words
@@ -51,7 +61,7 @@ weights), so ``config.n_layers`` counts its weights and
 
 from types import SimpleNamespace
 
-from . import jamba, kanana, lfm2, llama, mellum, ouro
+from . import exaone, jamba, kanana, lfm2, llama, mellum, ouro
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -61,6 +71,7 @@ from .llama import (
     init_params,
     prefill,
 )
+from .exaone import ExaoneConfig
 from .jamba import JambaConfig
 from .kanana import KananaConfig
 from .lfm2 import Lfm2Config
@@ -68,14 +79,15 @@ from .mellum import MellumConfig
 from .ouro import OuroConfig
 
 __all__ = [
-    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "OuroConfig", "decode_step", "forward", "init_kv_cache",
+    "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "OuroConfig", "ExaoneConfig",
+    "decode_step", "forward", "init_kv_cache",
     "init_params", "kv_pages_that_fit", "page_bytes", "prefill", "preset", "programs",
 ]
 
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone)]
     for table in tables:
         if name in table:
             return table[name]
@@ -156,13 +168,19 @@ def _llama_shardings() -> SimpleNamespace:
     return SimpleNamespace(params=params, paged_pool=paged_pool)
 
 
-def _stateful_refusals(window_cache: bool):
+def _stateful_refusals(window_cache: bool, drafts: bool = False):
     """What a family with state a slot does not serve; a window cache adds
-    what would restore a slot's pages without its ring."""
+    what would restore a slot's pages without its ring. A family that
+    ``drafts`` speculates over its ring by itself, one row a step; the
+    n-gram drafter (``spec_len``) stays refused there."""
+    spec_why = ("spec_len > 0: the n-gram drafter's rows (up to spec_len a step) do not fit the ring's one page of "
+                "slack; the family drafts one row a step by itself, with no option"
+                if drafts else "spec_len > 0: speculation needs the per-slot state rolled back on a rejected draft")
+
     def refusals(asked: dict) -> list:
         refused = [
             (asked["kv_layout"] != "paged", "kv_layout='slot': its state lives beside the paged pool; serve it with kv_layout='paged'"),
-            (asked["spec_len"] > 0, "spec_len > 0: speculation needs the per-slot state rolled back on a rejected draft"),
+            (asked["spec_len"] > 0, spec_why),
             (asked["tp"] > 1 or asked["sp"] > 1, "tensor or context parallelism: its weights and state have no sharding here; serve it on a tp=1 mesh"),
             (asked["quantize_weights"], "weight-only int8: its matrices are served in the dtype they were made in"),
             (asked["coordination"], "multi-host lockstep serving"),
@@ -182,7 +200,7 @@ def _stateful_refusals(window_cache: bool):
 
 
 _LLAMA = SimpleNamespace(
-    family="llama", has_state=False, window_cache=False, counters=None,
+    family="llama", has_state=False, window_cache=False, counters=None, draft_step=None, draft_rows=1,
     refusals=lambda asked: [], shardings=_llama_shardings(), walk=_kv_walk, page_leaf="k",
     init_params=llama.init_params,
     init_kv_cache=llama.init_kv_cache, prefill_batch=llama.prefill_batch,
@@ -200,9 +218,11 @@ _LLAMA = SimpleNamespace(
 def _with_state(family: str, m, window_cache: bool = False) -> SimpleNamespace:
     """A family with per-slot state: its module's programs take ``lanes``
     after the page ids; the engine hands both as one pair."""
+    draft_step = getattr(m, "verify_step_paged", None)
     return SimpleNamespace(
         family=family, has_state=True, window_cache=window_cache,
-        refusals=_stateful_refusals(window_cache), shardings=None, walk=_kv_walk, page_leaf="k",
+        refusals=_stateful_refusals(window_cache, draft_step is not None), shardings=None, walk=_kv_walk,
+        page_leaf="k", draft_step=draft_step, draft_rows=getattr(m, "ROWS", 1),
         init_params=m.init_params,
         init_paged_cache=m.init_paged_cache,
         prefill_paged_batch=lambda params, cache, tokens, lengths, ids, config: (
@@ -220,10 +240,13 @@ def _with_state(family: str, m, window_cache: bool = False) -> SimpleNamespace:
 _LFM2 = _with_state("lfm2", lfm2)
 _JAMBA = _with_state("jamba", jamba)
 _MELLUM = _with_state("mellum", mellum, window_cache=True)
+# mellum's two caches over kanana's expert layer, and the model's own MTP
+# module as the drafter: its decode program is a verify-and-draft step
+_EXAONE = _with_state("exaone", exaone, window_cache=True)
 # no state a slot (its programs take the page ids alone, as the dense
 # family's), counters on the device, its own pool: a latent row a token
 _KANANA = SimpleNamespace(
-    family="kanana", has_state=False, window_cache=False,
+    family="kanana", has_state=False, window_cache=False, draft_step=None, draft_rows=1,
     refusals=kanana.refusals, shardings=None, walk=_latent_walk, page_leaf="kv",
     init_params=kanana.init_params, init_paged_cache=kanana.init_paged_cache,
     prefill_paged_batch=kanana.prefill_paged_batch,
@@ -237,7 +260,7 @@ _KANANA = SimpleNamespace(
 # dense family's walk at its own head geometry, counters beside the pages.
 # Its config derives from LlamaConfig: the MRO finds this row first
 _OURO = SimpleNamespace(
-    family="ouro", has_state=False, window_cache=False,
+    family="ouro", has_state=False, window_cache=False, draft_step=None, draft_rows=1,
     refusals=ouro.refusals, shardings=None, walk=_kv_walk, page_leaf="k",
     init_params=ouro.init_params, init_paged_cache=ouro.init_paged_cache,
     prefill_paged_batch=ouro.prefill_paged_batch,
@@ -247,7 +270,7 @@ _OURO = SimpleNamespace(
     counters=ouro.counters, describe_counters=ouro.describe_counters,
 )
 _FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM,
-             KananaConfig: _KANANA, OuroConfig: _OURO}
+             KananaConfig: _KANANA, OuroConfig: _OURO, ExaoneConfig: _EXAONE}
 
 
 def programs(config) -> SimpleNamespace:

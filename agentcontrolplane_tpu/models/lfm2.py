@@ -337,11 +337,13 @@ def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
         return out, state_at(lengths), state_at(snap_rel)
 
 
-def _attention_op(h, layer, c, positions, attn_fn, yarn=None, walk="prefill_attention"):
+def _attention_op(h, layer, c, positions, attn_fn, yarn=None, walk="prefill_attention", rope=True):
     """-> (Op output, k, v): k and v are the layer's new rows for the pool.
     ``yarn`` (``ops.rope.apply_rope``'s) turns q and k by YaRN's frequencies
     (``models/mellum.py``'s full layers). ``walk`` is the scope ``attn_fn``
-    runs under (a decode step's: ``page_walk``; None: it opens its own)."""
+    runs under (a decode step's: ``page_walk``; None: it opens its own).
+    ``rope`` False leaves q and k unturned (``models/exaone.py``'s full
+    layers carry no position)."""
     B, T, _ = h.shape
     with jax.named_scope("attn_qkv"):
         q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
@@ -349,8 +351,9 @@ def _attention_op(h, layer, c, positions, attn_fn, yarn=None, walk="prefill_atte
         v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
         q = rms_norm(q, layer["q_norm"], c.norm_eps)
         k = rms_norm(k, layer["k_norm"], c.norm_eps)
-        q = apply_rope(q, positions, c.rope_theta, yarn=yarn)
-        k = apply_rope(k, positions, c.rope_theta, yarn=yarn)
+        if rope:
+            q = apply_rope(q, positions, c.rope_theta, yarn=yarn)
+            k = apply_rope(k, positions, c.rope_theta, yarn=yarn)
     with jax.named_scope(walk) if walk else contextlib.nullcontext():
         out = attn_fn(q, k, v)
     with jax.named_scope("attn_out"):

@@ -384,6 +384,54 @@ def paged_decode_attention_reference_cache_plus_new(
     return out.reshape(S, H, d).astype(q.dtype)
 
 
+def paged_verify_attention_reference(
+    q: jax.Array,  # [S, R, H, d]: R query rows a lane, row r at position seq_lens + r
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] (or [.., H_kv, d]) — WITHOUT the new rows
+    v_pages: jax.Array,
+    block_tables: jax.Array,  # [S, max_pages]: one table for all of a lane's rows
+    seq_lens: jax.Array,  # [S] — tokens valid in the pages (excl. the new rows)
+    k_new: jax.Array,  # [S, R, H_kv, d]
+    v_new: jax.Array,
+    new_valid: Optional[jax.Array] = None,  # [S, R] bool: a new row that is no key (None: all are)
+    row_positions: Optional[jax.Array] = None,  # [S, max_pages * P]: a ring's (ring_positions)
+    starts: Optional[jax.Array] = None,  # [S, R]: with row_positions, the first position row r sees
+) -> jax.Array:
+    """Exact reference for a verify step's attention: the pages read-only,
+    row ``r`` of a lane over the cached rows and the new rows ``0 .. r`` (the
+    second row sees the first's K/V, which no page holds yet). One gather of
+    a lane's pages serves all its rows. -> [S, R, H, d]."""
+    S, R, H, d = q.shape
+    P = k_pages.shape[1]
+    max_pages = block_tables.shape[1]
+    H_kv = k_new.shape[2]
+    r = H // H_kv
+    k = k_pages[block_tables].reshape(S, max_pages, P, H_kv, d)
+    v = v_pages[block_tables].reshape(k.shape)
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    q5 = q.reshape(S, R, H_kv, r, d).astype(jnp.float32)
+    logits = jnp.einsum("sjkrd,smpkd->sjmpkr", q5, k.astype(jnp.float32)) * scale
+    if row_positions is None:
+        pos = jnp.arange(max_pages)[:, None] * P + jnp.arange(P)[None, :]  # [M, P]
+        mask = jnp.broadcast_to((pos[None] < seq_lens[:, None, None])[:, None], (S, R, max_pages, P))
+    else:
+        pos = row_positions.reshape(S, 1, max_pages, P)
+        mask = (pos >= starts[:, :, None, None]) & (pos < seq_lens[:, None, None, None])
+    logits = jnp.where(mask[..., None, None], logits, NEG_INF)
+    fresh = jnp.einsum("sjkrd,sikd->sjikr", q5, k_new.astype(jnp.float32)) * scale  # row j over new row i
+    seen = jnp.arange(R)[None, :, None] >= jnp.arange(R)[None, None, :]  # [1, j, i]
+    if new_valid is not None:
+        seen = seen & new_valid[:, None, :]
+    fresh = jnp.where(seen[..., None, None], fresh, NEG_INF)
+    m = jnp.maximum(jnp.max(logits, axis=(2, 3)), jnp.max(fresh, axis=2))  # [S, R, H_kv, r]
+    p = jnp.where(mask[..., None, None], jnp.exp(logits - m[:, :, None, None]), 0.0)
+    p_new = jnp.where(seen[..., None, None], jnp.exp(fresh - m[:, :, None]), 0.0)
+    denom = jnp.sum(p, axis=(2, 3)) + jnp.sum(p_new, axis=2)
+    out = jnp.einsum("sjmpkr,smpkd->sjkrd", p, v.astype(jnp.float32))
+    out = out + jnp.einsum("sjikr,sikd->sjkrd", p_new, v_new.astype(jnp.float32))
+    out = out / jnp.maximum(denom, 1e-30)[..., None]
+    return out.reshape(S, R, H, d).astype(q.dtype)
+
+
 def latent_decode_attention_reference_cache_plus_new(
     q: jax.Array,  # [S, H, width]: every head's query against the whole row
     pages: jax.Array,  # [num_pages, P, width] — a latent pool's one leaf, WITHOUT the new token

@@ -67,10 +67,19 @@ def _col_tile(n: int, want: int) -> int:
     return n
 
 
+# what a grid step's weight blocks may take of the 16 MiB a kernel gets, each
+# block twice (pipelined): at a hidden width of 6,144 two blocks of 512
+# columns are 24 MB and the chip's compiler refuses the kernel
+# (tests/engine/test_chip_compile.py, the exaone cases); every narrower
+# hidden width the benchmark has keeps its 512 columns
+_WEIGHT_BLOCKS_BYTES = 10 << 20
+
+
 def _call(kernel, x, weights, tile_expert, n_live, tm, tn, interpret):
     M, K = x.shape
     N = weights[0].shape[2]
-    tn = _col_tile(N, tn)
+    fits = _WEIGHT_BLOCKS_BYTES // (2 * len(weights) * K * weights[0].dtype.itemsize)
+    tn = _col_tile(N, min(tn, max(128, fits // 128 * 128)))
     assert M % tm == 0 and tile_expert.shape == (M // tm,), (M, tm, tile_expert.shape)
 
     def row(i, nl):  # dead tiles re-use the last live tile's rows: no fetch

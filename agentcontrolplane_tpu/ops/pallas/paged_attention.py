@@ -708,6 +708,52 @@ def paged_decode_attention_cache_plus_new(
     return _fold_self_term(q, k_new, v_new, acc, m, l)
 
 
+def paged_verify_attention_cache_plus_new(
+    q: jax.Array,  # [S, R, H, d]: R query rows a lane, row r at position seq_lens + r
+    k_pages: jax.Array,  # [num_pages, P, H_kv * d] — WITHOUT the new rows
+    v_pages: jax.Array,
+    block_tables: jax.Array,  # [S, max_pages]: one table for all of a lane's rows
+    seq_lens: jax.Array,  # [S] — tokens valid in the PAGES (excl. the new rows)
+    k_new: jax.Array,  # [S, R, H_kv, d]
+    v_new: jax.Array,
+    interpret: bool = False,
+    *,
+    new_valid: jax.Array | None = None,  # [S, R] bool: a new row that is no key
+    starts: jax.Array | None = None,  # [S, R]: the window walk, each row's own edge
+    ring: int = 0,
+) -> jax.Array:
+    """A verify step's attention: the rows of a lane as lanes of the walk
+    over the one table (``S * R`` lanes, each fetching the lane's pages: a
+    kernel that fetches a page once for all of a lane's rows is ROADMAP's),
+    then the new rows folded in outside the kernel, row ``r`` over new rows
+    ``0 .. r``: the second row sees the first's K/V, which no page holds
+    yet. -> [S, R, H, d]."""
+    S, R, H, d = q.shape
+    H_kv = k_new.shape[2]
+    rep = lambda a: jnp.repeat(a, R, axis=0)  # noqa: E731
+    acc, m, l = _paged_state(
+        q.reshape(S * R, H, d), k_pages, v_pages, rep(block_tables), rep(seq_lens), interpret,
+        kv_heads=H_kv, starts=None if starts is None else starts.reshape(S * R), ring=ring,
+    )
+    r = H // H_kv
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    q5 = q.reshape(S, R, H_kv, r, d).astype(jnp.float32)
+    fresh = jnp.einsum("sjkrd,sikd->sjikr", q5, k_new.astype(jnp.float32)) * scale  # row j over new row i
+    seen = jnp.arange(R)[None, :, None] >= jnp.arange(R)[None, None, :]
+    if new_valid is not None:
+        seen = seen & new_valid[:, None, :]
+    fresh = jnp.where(seen[..., None, None], fresh, NEG_INF)
+    m = m.reshape(S, R, H_kv, r)
+    m2 = jnp.maximum(m, jnp.max(fresh, axis=2))
+    corr = jnp.exp(m - m2)
+    p_new = jnp.where(seen[..., None, None], jnp.exp(fresh - m2[:, :, None]), 0.0)
+    l2 = l.reshape(S, R, H_kv, r) * corr + jnp.sum(p_new, axis=2)
+    out = acc.reshape(S, R, H_kv, r, d) * corr[..., None] + jnp.einsum(
+        "sjikr,sikd->sjkrd", p_new, v_new.astype(jnp.float32))
+    out = out / jnp.maximum(l2, 1e-30)[..., None]
+    return out.reshape(S, R, H, d).astype(q.dtype)
+
+
 def latent_walk_serves(width: int, value_width: int, P: int, dtype) -> bool:
     """Whether the compiled latent walk takes this geometry: a page's rows a
     whole sublane tile of its dtype (a turn's pages share one buffer), the

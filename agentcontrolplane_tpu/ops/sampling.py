@@ -155,23 +155,22 @@ def speculative_accept(
     return out_tokens, n_emit, state
 
 
-def sample(
+def masked_logits(
     logits: jax.Array,  # [S, V] float32
-    rng: jax.Array,
-    temperature: jax.Array,  # [S]
     top_k: jax.Array,  # [S] int32, 0 = disabled
     top_p: jax.Array,  # [S] float32, 1.0 = disabled
     wanted: tuple | None = None,  # masks_wanted(...) of the batch's live lanes
 ) -> jax.Array:
-    """Returns sampled token ids [S].
+    """``logits`` with what top-k and top-p leave out at ``NEG_INF``: the
+    support :func:`sample` draws from, and what a speculative accept holds
+    a drafted distribution against.
 
     Each threshold search runs only when the batch asks for it
     (``wanted``: computed here over all lanes for a caller with no
     ``active`` row, and by the caller once where it samples in a loop whose
-    rows do not change). A batch in which no live lane asks draws
-    ``categorical(logits / temperature)`` over the unmasked logits, which is
-    what 0 and 1.0 mean above; one in which some lane asks runs that search
-    for the whole batch, as it always did.
+    rows do not change). A batch in which no live lane asks keeps the
+    logits unmasked, which is what 0 and 1.0 mean above; one in which some
+    lane asks runs that search for the whole batch, as it always did.
 
     How each is switched off is what the chip's compiler made of it
     (tests/engine/test_chip_compile.py holds both): the top-k search is its
@@ -201,9 +200,59 @@ def sample(
     # without the barrier the compiler moves the broadcast below into the
     # conditional, whose skipping branch then writes [S, V] of it
     thresh = jax.lax.optimization_barrier(thresh)
-    logits = jnp.where(logits < thresh[:, None], NEG_INF, logits)
+    return jnp.where(logits < thresh[:, None], NEG_INF, logits)
 
+
+def sample(
+    logits: jax.Array,  # [S, V] float32
+    rng: jax.Array,
+    temperature: jax.Array,  # [S]
+    top_k: jax.Array,  # [S] int32, 0 = disabled
+    top_p: jax.Array,  # [S] float32, 1.0 = disabled
+    wanted: tuple | None = None,  # masks_wanted(...) of the batch's live lanes
+) -> jax.Array:
+    """Returns sampled token ids [S]: greedy at temperature 0, else a draw
+    from ``categorical(masked_logits / temperature)``."""
+    logits = masked_logits(logits, top_k, top_p, wanted)
     greedy = jnp.argmax(logits, axis=-1)
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     sampled = jax.random.categorical(rng, logits / temp, axis=-1)
     return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+
+
+def speculative_sample(
+    p_logits: jax.Array,  # [S, 2, V] float32, masked: row 0 scores the draft, row 1 the token after it
+    q_logits: jax.Array,  # [S, V] float32, masked: the drafter's, which ``draft`` was drawn from
+    draft: jax.Array,  # [S] int32
+    rng: jax.Array,
+    temperature: jax.Array,  # [S]
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Speculative sampling proper, for a drafted DISTRIBUTION ``q`` (a
+    model's own drafter) where :func:`speculative_accept` tests a point
+    draft for equality: the draft is kept with probability ``min(1, p(d) /
+    q(d))``; a refused draft is replaced by a draw from ``norm(max(p - q,
+    0))``; after a kept draft the next token is drawn from row 1's ``p``.
+    The first token is then distributed exactly as ``p`` (Leviathan et al.,
+    Chen et al. 2023), whatever ``q`` is. At temperature 0 both
+    distributions are points: the draft is kept when it is ``p``'s argmax
+    and replaced by that argmax when not, which is the equality test.
+    -> (kept [S] bool, first [S]: the draft where kept, else its
+    replacement, next [S]: row 1's draw, meaningful where kept)."""
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    greedy = temperature <= 0.0
+    k_accept, k_resid, k_next = jax.random.split(rng, 3)
+    p0 = jax.nn.softmax(p_logits[:, 0] / temp, axis=-1)
+    q = jax.nn.softmax(q_logits / temp, axis=-1)
+    at = lambda a: jnp.take_along_axis(a, draft[:, None], axis=-1)[:, 0]  # noqa: E731
+    u = jax.random.uniform(k_accept, draft.shape, jnp.float32)
+    kept = u * at(q) <= at(p0)  # u <= p/q without the division; q(d) > 0 for a token drawn from q
+    resid = jnp.maximum(p0 - q, 0.0)
+    # p == q to the last bit leaves no residual, and is never refused
+    resid_logits = jnp.where(jnp.sum(resid, axis=-1, keepdims=True) > 0, jnp.log(resid), p_logits[:, 0] / temp)
+    replaced = jax.random.categorical(k_resid, resid_logits, axis=-1)
+    nxt = jax.random.categorical(k_next, p_logits[:, 1] / temp, axis=-1)
+    top = jnp.argmax(p_logits, axis=-1)  # [S, 2]
+    kept = jnp.where(greedy, draft == top[:, 0], kept)
+    first = jnp.where(greedy, top[:, 0], jnp.where(kept, draft, replaced))
+    nxt = jnp.where(greedy, top[:, 1], nxt)
+    return kept, first.astype(jnp.int32), nxt.astype(jnp.int32)

@@ -1309,6 +1309,16 @@ class RestServer:
                     help="mean tokens committed per decode model step "
                     "(> 1 means speculative decoding is paying)",
                 )
+                drafter = s.get("drafter")  # a family that drafts by itself (models/exaone.py)
+                if drafter is not None:
+                    REGISTRY.gauge_set(
+                        "acp_engine_spec_self_proposed", float(drafter["proposed"]),
+                        help="drafts the model's own drafter put to live lanes (device counter)",
+                    )
+                    REGISTRY.gauge_set(
+                        "acp_engine_spec_self_accepted", float(drafter["accepted"]),
+                        help="drafts of the model's own drafter the verify step kept (device counter)",
+                    )
                 REGISTRY.gauge_set(
                     "acp_engine_prefilling_slots",
                     float(s.get("prefilling_slots", 0)),
