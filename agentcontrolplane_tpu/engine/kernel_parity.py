@@ -5,8 +5,9 @@ One definition shared by ``chip_smoke.py`` and
 CPU): seeded ragged pages at a named geometry, the kernel and
 ``ops.paged``'s reference run on the default device, the largest absolute
 difference judged against a tolerance set from the page dtype. The K/V walk
-(:func:`page_walk_parity`) and the latent walk over a pool of one leaf
-(:func:`latent_walk_parity`).
+(:func:`page_walk_parity`), the latent walk over a pool of one leaf
+(:func:`latent_walk_parity`) and a verify step's walks, several rows a lane
+in one query group over pages or a ring (:func:`verify_walk_parity`).
 """
 
 from __future__ import annotations
@@ -21,11 +22,16 @@ from ..ops.paged import (
     latent_decode_attention_reference_cache_plus_new,
     paged_decode_attention_reference,
     paged_decode_attention_reference_cache_plus_new,
+    paged_verify_attention_reference,
+    ring_positions,
+    ring_size,
+    ring_tables,
 )
 from ..ops.pallas.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_cache_plus_new,
     paged_latent_attention_cache_plus_new,
+    paged_verify_attention_cache_plus_new,
     pages_per_turn,
 )
 from ..ops.quant import kv_quantize
@@ -167,3 +173,65 @@ def latent_walk_parity(case: dict, *, score_dim: int = 192, interpret: bool = Fa
         ref = jax.jit(lambda q, pages: latent_decode_attention_reference_cache_plus_new(q, pages, *tail))(
             case["q"], case["clean"])
     return {**_verdict(case, out, ref), "pages_per_turn": case["pages_per_turn"]}
+
+
+# rows in a lane's pages: none, under a page, a window's worth, the two rows'
+# edges 15 and 16 (row 0's on a page's last row, row 1's in the next page),
+# and contexts of several turns whose rings have wrapped
+VERIFY_LENS = (0, 9, 128, 142, 1000, 2001, 3800, 6000)
+
+
+def make_verify_case(seed: int, *, R: int = 2, H: int = 64, H_kv: int = 8, d: int = 128, P: int = 16,
+                     window: int = 128, lens: tuple = VERIFY_LENS, dtype=jnp.bfloat16) -> dict:
+    """A verify step's attention at the geometry that runs it (``exaone``:
+    64 / 8 heads of 128, two rows a lane, a window of 128 over pages of 16):
+    a pool in which every lane's pages are scattered, and a ring a lane.
+    Every page no lane's rows reach, and every page of a ring that lies
+    outside its lane's window, is NaN where the kernel reads (``pages``,
+    ``rings``) and seeded where the reference does: a walk that fetches a
+    page not its own fails loudly."""
+    rng = np.random.default_rng(seed)
+    S, ring = len(lens), ring_size(window, P)
+    held = [-(-n // P) for n in lens]
+    M = max(1, max(held))
+    order = 1 + rng.permutation(S * M).astype(np.int32).reshape(S, M)
+    named = np.zeros((1 + S * M,), bool)
+    in_window = np.zeros((S * ring,), bool)
+    for s, n in enumerate(lens):
+        named[order[s, :held[s]]] = True
+        if n:
+            in_window[s * ring + np.arange(max(n + 1 - window, 0) // P, (n - 1) // P + 1) % ring] = True
+    draw = lambda *shape: rng.normal(size=shape).astype(np.float32)  # noqa: E731
+    marked = lambda clean, live: jnp.asarray(np.where(live[:, None, None], clean, np.nan), dtype)  # noqa: E731
+    pool, rings = draw(2, 1 + S * M, P, H_kv * d), draw(2, S * ring, P, H_kv * d)
+    lens = jnp.asarray(lens, jnp.int32)
+    return {
+        "q": jnp.asarray(draw(S, R, H, d), dtype),
+        "k_new": jnp.asarray(draw(S, R, H_kv, d), dtype), "v_new": jnp.asarray(draw(S, R, H_kv, d), dtype),
+        # every fourth lane's first row is no key, and the lane's before it its second
+        "new_valid": jnp.asarray((np.arange(S)[:, None] + np.arange(R)[None, :]) % 4 != 3),
+        "seq_lens": lens, "ring": ring,
+        "starts": jnp.maximum(lens[:, None] + jnp.arange(R)[None, :] + 1 - window, 0),
+        "full": {"tables": jnp.asarray(order), "pages": [marked(x, named) for x in pool],
+                 "clean": [jnp.asarray(x, dtype) for x in pool]},
+        "win": {"tables": ring_tables(jnp.arange(S, dtype=jnp.int32), ring), "pages": [marked(x, in_window) for x in rings],
+                "clean": [jnp.asarray(x, dtype) for x in rings]},
+    }
+
+
+def verify_walk_parity(case: dict, *, window: bool, interpret: bool = False) -> dict:
+    """A verify step's walk (compiled unless ``interpret``) over the lanes'
+    pages, or with ``window`` over their rings from each row's own edge on,
+    against the gather over the same pool without its NaN pages."""
+    pool = case["win" if window else "full"]
+    n, new = case["seq_lens"], (case["k_new"], case["v_new"])
+    kw = {"starts": case["starts"], "ring": case["ring"]} if window else {}
+    out = jax.jit(lambda q, k, v: paged_verify_attention_cache_plus_new(
+        q, k, v, pool["tables"], n, *new, interpret=interpret, new_valid=case["new_valid"], **kw))(
+        case["q"], *pool["pages"])
+    if window:
+        kw = {"starts": case["starts"], "row_positions": ring_positions(n, case["ring"], pool["clean"][0].shape[1])}
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda q, k, v: paged_verify_attention_reference(
+            q, k, v, pool["tables"], n, *new, new_valid=case["new_valid"], **kw))(case["q"], *pool["clean"])
+    return _verdict(case, out, ref)

@@ -98,10 +98,28 @@ products, one max, exp, sum and rescale for all, 0.26 us a 128 rows: the
 fetches' own time (8 at ~27 ns in the DMA engine, and their issue). The tail
 (a slot's last turn fetches its last page again) shows under 128 rows a slot.
 
+A verify step (:func:`paged_verify_attention_cache_plus_new`, ``R`` query
+rows a lane at positions ``seq_lens + r``; ``models/exaone.py``): the rows
+are rows of the walk's query group, not lanes of the walk. q crosses the
+boundary as ``[S, H_kv, R * n_rep, d]`` (a KV head's queries of row 0, then
+of row 1) beside the lane's own table and length, so a page is fetched
+ONCE for all of a lane's rows and a turn's two products serve them all (16
+rows a KV head at 64 / 8 heads and two rows: one whole bf16 sublane tile,
+where a group of 8 was padded to it). Over the full-attention pages every
+row of a lane sees the same page rows (the new rows are folded in outside
+the kernel) and the body is the one-row body. Over a ring each row has its
+own edge, one position after the row's before it: ``starts_ref`` holds
+``rows`` edges a slot, the walk begins at the page of the first, and
+``valid`` is a ``[R * n_rep, T]`` select between the edges over a row iota; a
+query row whose edge lies past a turn's rows adds nothing to its ``l``.
+``rows`` is static and the branch a Python one: at ``rows == 1`` the body
+traces what it traced before there was one.
+
 Tested in interpreter mode on CPU against the exact reference
-(tests/engine/test_paged*.py), compiled for a described v5e
-(tests/engine/test_chip_compile.py), and run compiled on the chip against
-the reference (chip_smoke.py, tests/engine/test_tpu_hardware.py).
+(tests/engine/test_paged*.py, tests/engine/test_exaone.py), compiled for a
+described v5e (tests/engine/test_chip_compile.py, test_exaone_compile.py),
+and run compiled on the chip against the reference (chip_smoke.py,
+tests/engine/test_tpu_hardware.py).
 """
 
 from __future__ import annotations
@@ -254,7 +272,8 @@ def _kernel(
     page_size: int,  # GLOBAL page size (pages hold this many tokens)
     quantized: bool = False,
     head_dim: int | None = None,  # the model's, where heads share a lane window
-    starts_ref=None,  # [S] int32 (SMEM): a slot's first valid row (the window walk)
+    starts_ref=None,  # [S * rows] int32 (SMEM): a slot's first valid row (the window walk)
+    rows: int = 1,  # > 1: a verify step's window walk, the group `rows` blocks of query rows, an edge each
     ring: int = 0,  # > 0: the table is a ring, page a of the sequence at a % ring
     value_width: int = 0,  # > 0: the latent walk; no v_pages_ref, V is the row's first columns
 ):
@@ -292,12 +311,14 @@ def _kernel(
         ``n_pages`` are then of the walk and not of the sequence. With
         ``ring`` the table has ``ring`` entries a slot and page ``a`` of the
         sequence sits at ``a % ring``: a window of at most ``(ring - 1)``
-        pages of rows touches no entry twice."""
+        pages of rows touches no entry twice. With ``rows`` edges a slot the
+        walk begins at the first of them, which is the lowest (a verify step's
+        rows lie one position apart); ``turn`` masks each query row by its own."""
         seq_len = seq_lens_ref[base + s]
         n_pages = jax.lax.div(seq_len + page_size - 1, page_size)
         first_row = first_page = None
         if starts_ref is not None:
-            first_row = starts_ref[base + s]
+            first_row = starts_ref[(base + s) * rows if rows > 1 else base + s]
             first_page = jax.lax.div(first_row, page_size)
             n_pages = jnp.maximum(n_pages - first_page, 0)
         return seq_len, first_row, first_page, n_pages
@@ -373,6 +394,12 @@ def _kernel(
     if page_size != P:
         for g in range(1, G):
             col = col + jnp.where(lane >= g * P, page_size - P, 0)
+    if rows > 1:
+        # the group's rows in `rows` blocks a KV head of the window (a head's
+        # `per` queries of verify row 0, then of row 1, ...): block b is verify
+        # row b % rows and sees from that row's own edge on
+        per = n_rep // (rows * (d // (head_dim or d)))
+        group_row = jax.lax.broadcasted_iota(jnp.int32, (n_rep, 1), 0)
 
     def turn(i, carry, issue):
         """Fold item i, slot `s`'s turn `t`, out of buffer i % D; with
@@ -385,7 +412,12 @@ def _kernel(
         if starts_ref is not None:
             pos = pos + first_page * page_size
         valid = pos < seq_len  # [1, T]
-        if starts_ref is not None:
+        if rows > 1:
+            edge = first_row
+            for b in range(1, n_rep // per):
+                edge = jnp.where(group_row >= b * per, starts_ref[(base + s) * rows + b % rows], edge)
+            valid = valid & (pos >= edge)  # [n_rep, T]
+        elif starts_ref is not None:
             valid = valid & (pos >= first_row)
         if quantized:
             ks = sc_buf[buf, 0]  # [1, >= H_kv * P], head-major
@@ -423,6 +455,8 @@ def _kernel(
             logits = jnp.where(valid, logits, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
             p = jnp.exp(logits - m_new)  # [n_rep, T]
+            if rows > 1:  # a query row that has seen no row yet (its edge past this turn's) adds nothing
+                p = jnp.where(valid, p, 0.0)
             correction = jnp.exp(m - m_new)  # [n_rep, 1]
             l = l * correction + jnp.sum(p, axis=1, keepdims=True)
             pw = p * vs[:, h * P:(h + 1) * P] if quantized else p
@@ -512,14 +546,18 @@ def _paged_state(
     head_dim: int | None = None,  # softmax scale's width where it is not d
     kv_heads: int | None = None,  # of pages given merged
     scales_laid: bool = False,  # the scales are scale_rows' output already
-    starts: jax.Array | None = None,  # [S] int32: the window walk's first valid row a slot
+    starts: jax.Array | None = None,  # [S * rows] int32: the window walk's first valid row a slot (and row)
     ring: int = 0,  # with `starts`: the table is a ring of this many pages a slot
+    rows: int = 1,  # a KV head's queries are `rows` blocks (a verify step's rows), each with its own edge
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the kernel -> unnormalized (acc [S,H,d] f32, m [S,H], l [S,H]).
 
     With ``starts`` it is the window walk (named ``paged_window_walk``): a
     slot's rows ``starts[s] .. seq_lens[s] - 1`` and no others, the pages
-    before the first skipped and not read.
+    before the first skipped and not read. With ``rows`` > 1 a KV head's
+    ``H / H_kv`` queries are ``rows`` blocks of as many (the rows of a verify
+    step, one query group over one fetch of the slot's pages) and block ``j``
+    sees from ``starts[s * rows + j]`` on, ``starts[s * rows]`` the lowest.
 
     With ``k_scales``/``v_scales`` ([num_pages, P, H_kv] as the pool stores
     them, or ``scales_laid``: [num_pages, 1, SC] from :func:`scale_rows`)
@@ -550,7 +588,7 @@ def _paged_state(
             k_pages.reshape(num_pages, P, W, pack * d),
             v_pages.reshape(num_pages, P, W, pack * d),
             block_tables, seq_lens, interpret, pos_base, global_page_size,
-            head_dim=d, starts=starts, ring=ring,
+            head_dim=d, starts=starts, ring=ring, rows=rows,
         )
         acc = jnp.einsum("swjrlc,jl->swjrc", acc.reshape(S, W, pack, r, pack, d),
                          jnp.eye(pack, dtype=acc.dtype))
@@ -567,6 +605,7 @@ def _paged_state(
         quantized=quantized,
         head_dim=head_dim,
         **({"ring": ring} if windowed else {}),
+        **({"rows": rows} if windowed and rows > 1 else {}),
     )
 
     # A program walks as many slots as fit (all of them in every cell: the
@@ -719,23 +758,25 @@ def paged_verify_attention_cache_plus_new(
     interpret: bool = False,
     *,
     new_valid: jax.Array | None = None,  # [S, R] bool: a new row that is no key
-    starts: jax.Array | None = None,  # [S, R]: the window walk, each row's own edge
+    starts: jax.Array | None = None,  # [S, R]: the window walk, each row's own edge (row 0's the lowest)
     ring: int = 0,
 ) -> jax.Array:
-    """A verify step's attention: the rows of a lane as lanes of the walk
-    over the one table (``S * R`` lanes, each fetching the lane's pages: a
-    kernel that fetches a page once for all of a lane's rows is ROADMAP's),
-    then the new rows folded in outside the kernel, row ``r`` over new rows
-    ``0 .. r``: the second row sees the first's K/V, which no page holds
-    yet. -> [S, R, H, d]."""
+    """A verify step's attention: a lane's ``R`` rows ride ONE query group of
+    the walk over the lane's one table, ``R * H / H_kv`` rows a KV head, so a
+    page is fetched once for all of them (over a ring each row is masked by
+    its own edge, ``starts[s, 0]`` the lowest); then the new rows folded in
+    outside the kernel, row ``r`` over new rows ``0 .. r``: the second row
+    sees the first's K/V, which no page holds yet. -> [S, R, H, d]."""
     S, R, H, d = q.shape
     H_kv = k_new.shape[2]
-    rep = lambda a: jnp.repeat(a, R, axis=0)  # noqa: E731
-    acc, m, l = _paged_state(
-        q.reshape(S * R, H, d), k_pages, v_pages, rep(block_tables), rep(seq_lens), interpret,
-        kv_heads=H_kv, starts=None if starts is None else starts.reshape(S * R), ring=ring,
-    )
     r = H // H_kv
+    acc, m, l = _paged_state(
+        q.reshape(S, R, H_kv, r, d).swapaxes(1, 2).reshape(S, R * H, d),  # [S, H_kv x (R, r), d]
+        k_pages, v_pages, block_tables, seq_lens, interpret,
+        kv_heads=H_kv, starts=None if starts is None else starts.reshape(S * R), ring=ring, rows=R,
+    )
+    rowwise = lambda a: a.reshape(S, H_kv, R, r, *a.shape[2:]).swapaxes(1, 2)  # noqa: E731 -> [S, R, H_kv, r, .]
+    acc, m, l = rowwise(acc), rowwise(m), rowwise(l)
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     q5 = q.reshape(S, R, H_kv, r, d).astype(jnp.float32)
     fresh = jnp.einsum("sjkrd,sikd->sjikr", q5, k_new.astype(jnp.float32)) * scale  # row j over new row i
@@ -743,12 +784,11 @@ def paged_verify_attention_cache_plus_new(
     if new_valid is not None:
         seen = seen & new_valid[:, None, :]
     fresh = jnp.where(seen[..., None, None], fresh, NEG_INF)
-    m = m.reshape(S, R, H_kv, r)
     m2 = jnp.maximum(m, jnp.max(fresh, axis=2))
     corr = jnp.exp(m - m2)
     p_new = jnp.where(seen[..., None, None], jnp.exp(fresh - m2[:, :, None]), 0.0)
-    l2 = l.reshape(S, R, H_kv, r) * corr + jnp.sum(p_new, axis=2)
-    out = acc.reshape(S, R, H_kv, r, d) * corr[..., None] + jnp.einsum(
+    l2 = l * corr + jnp.sum(p_new, axis=2)
+    out = acc * corr[..., None] + jnp.einsum(
         "sjikr,sikd->sjkrd", p_new, v_new.astype(jnp.float32))
     out = out / jnp.maximum(l2, 1e-30)[..., None]
     return out.reshape(S, R, H, d).astype(q.dtype)

@@ -8,7 +8,8 @@ from a seed. One process; no argument = one chip:
 
   device   jax.devices() must be a TPU (a CPU fallback is a failure)
   kernel   the COMPILED Pallas page walk vs the XLA gather reference on the
-           device, Qwen2.5-7B geometry, bf16 pages and int8 pages + scales
+           device, Qwen2.5-7B geometry, bf16 pages and int8 pages + scales;
+           the latent walk; a verify step's two rows a lane over pages and ring
   serve    slot layout (the CLI default), then paged: the engine built the
            way `acp-tpu run --tpu-preset qwen2.5-7b --tpu-quantize-weights`
            builds it, prewarmed, behind the real Operator + REST server on
@@ -113,7 +114,7 @@ def phase_kernel(seed: int) -> None:
 
     from agentcontrolplane_tpu.models.llama import PRESETS
     from agentcontrolplane_tpu.engine.kernel_parity import (
-        latent_walk_parity, make_latent_case, make_paged_case, page_walk_parity)
+        latent_walk_parity, make_latent_case, make_paged_case, make_verify_case, page_walk_parity, verify_walk_parity)
 
     c = PRESETS[PRESET]
     geometry = dict(S=16, H=c.n_heads, H_kv=c.n_kv_heads, d=c.head_dim,
@@ -140,6 +141,17 @@ def phase_kernel(seed: int) -> None:
                   f"(tolerance {got['tolerance']:.0e}), slots of {min(lens)}..{max(lens)} rows, "
                   f"{time.monotonic() - t0:.1f}s compile+run")
     check(got["ok"], "kernel", f"latent walk: parity failed: {got}")
+    case = make_verify_case(seed)
+    for name, window in (("the lanes' pages", False), ("their rings, each row from its own edge", True)):
+        t0 = time.monotonic()
+        got = verify_walk_parity(case, window=window)
+        lens = got["seq_lens"]
+        say("kernel", f"compiled verify walk vs XLA reference (two rows a lane in one query group: 64/8 heads, d 128, "
+                      f"page 16, a window of 128) over {name}, bf16 pages, pages outside the walk NaN: "
+                      f"out {got['shape']} finite={got['finite']} max|err| {got['max_abs_err']:.2e} "
+                      f"(tolerance {got['tolerance']:.0e}), lanes of {min(lens)}..{max(lens)} rows, "
+                      f"{time.monotonic() - t0:.1f}s compile+run")
+        check(got["ok"], "kernel", f"verify walk over {name}: parity failed: {got}")
 
 
 # -- serve -------------------------------------------------------------------

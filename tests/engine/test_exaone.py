@@ -4,7 +4,8 @@ which imports nothing of the program); prefill then verify-and-draft steps
 through both caches under every pattern of kept and refused drafts, main
 logits and drafted logits; the ring after a refused row; a chunked row; the
 accept's distribution; the eight shares of a layer's experts; what the seam
-says of a family that drafts.
+says of a family that drafts. (The verify step's walks, kernel against
+gather: `test_exaone_walks.py`, a file of its own for the file's budget.)
 
 CPU, tiny sizes (a dense window layer, then window, window, full, window; a
 window of 16, pages of 8, 16 experts top-2, the MTP module), float32, seeded
@@ -24,7 +25,6 @@ from acpbench.families import exaone_reference
 from acpbench.families.exaone import forced_sampler
 from agentcontrolplane_tpu.models import exaone, preset, programs
 from agentcontrolplane_tpu.ops import paged
-from agentcontrolplane_tpu.ops.pallas.paged_attention import paged_verify_attention_cache_plus_new
 from agentcontrolplane_tpu.ops.sampling import masked_logits, speculative_sample
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
@@ -183,31 +183,6 @@ def test_a_chunked_row_leaves_the_drafter_what_a_whole_prefill_leaves():
     for slot in range(B):
         flat = lambda c: np.asarray(c["k"])[pc.n_full, np.asarray(tables)[slot]].reshape(-1, c["k"].shape[-1])  # noqa: E731
         np.testing.assert_allclose(flat(chunked)[: lengths[slot] - 1], flat(whole)[: lengths[slot] - 1], atol=2e-5)
-
-
-def test_the_interpreted_walks_of_a_verify_step_equal_the_xla_reference():
-    """Two rows a lane over one table, full layer and ring: the kernels in
-    interpret mode and the new rows folded outside them against the gather
-    (`ops/paged.py`), a row that is no key left out."""
-    rng = np.random.default_rng(0)
-    S, R, H, Hkv, d, P, NP, M = 3, 2, 4, 2, 16, 8, 40, 6
-    q = jnp.asarray(rng.normal(size=(S, R, H, d)), jnp.float32)
-    kn, vn = (jnp.asarray(rng.normal(size=(S, R, Hkv, d)), jnp.float32) for _ in range(2))
-    kp, vp = (jnp.asarray(rng.normal(size=(NP, P, Hkv * d)), jnp.float32) for _ in range(2))
-    tables = jnp.asarray(1 + np.arange(S * M).reshape(S, M), jnp.int32)
-    lens = jnp.asarray([0, 13, 41], jnp.int32)
-    valid = jnp.asarray([[True, True], [True, False], [True, True]])
-    want = paged.paged_verify_attention_reference(q, kp, vp, tables, lens, kn, vn, new_valid=valid)
-    got = paged_verify_attention_cache_plus_new(q, kp, vp, tables, lens, kn, vn, interpret=True, new_valid=valid)
-    np.testing.assert_allclose(got, want, atol=2e-5)
-    ring = 3
-    rings = paged.ring_tables(jnp.arange(S, dtype=jnp.int32), ring)
-    lens = jnp.asarray([5, 22, 70], jnp.int32)
-    starts = jnp.maximum(lens[:, None] + jnp.arange(R)[None] + 1 - 16, 0)
-    want = paged.paged_verify_attention_reference(
-        q, kp, vp, rings, lens, kn, vn, row_positions=paged.ring_positions(lens, ring, P), starts=starts)
-    got = paged_verify_attention_cache_plus_new(q, kp, vp, rings, lens, kn, vn, interpret=True, starts=starts, ring=ring)
-    np.testing.assert_allclose(got, want, atol=2e-5)
 
 
 # -- the accept ------------------------------------------------------------------------------------------

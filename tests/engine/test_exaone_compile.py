@@ -2,7 +2,9 @@
 has the other families' cases and the described-v5e fixture these use; a file
 of their own so that neither file runs over its budget): the verify-and-draft
 decode block at the benchmark's 64 lanes and its widest prefills compile for a
-described v5e, copy no pool and fit beside the resident set."""
+described v5e, copy no pool and fit beside the resident set; the one-row window
+walk's kernel is op for op what it was before a verify step's rows rode one
+query group."""
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +62,10 @@ def test_exaone_decode_block_verifies_two_rows_and_copies_no_pool(v5e, monkeypat
         params, cache, vec(len(DECODE.kinds), S), key, vec(1, 256), vec(1), vec(S, 6144 // PAGE)).compile()
     text = compiled.as_text()
     assert "paged_window_walk" in text and "paged_page_walk" in text and "moe_gmm" in text
+    # a lane's two rows in ONE query group of each walk: 64 lanes of 8 KV heads x 16 rows, and no walk of 128 lanes
+    for walk in ("paged_page_walk", "paged_window_walk"):
+        assert re.search(rf"%{walk}\S* = \(f32\[{S},8,16,128\]", text), f"{walk} is not {S} lanes of 16 rows a KV head"
+    assert f"f32[{2 * S},8,8,128]" not in text
     pools = sum(cache[name].size * 2 for name in ("k", "v", "wk", "wv"))
     mem = compiled.memory_analysis()
     assert 3.3e9 < pools < 3.5e9 and mem.alias_size_in_bytes >= pools
@@ -83,3 +89,43 @@ def test_exaone_prefill_fits_beside_the_resident_set(v5e, monkeypatch, tokens):
     ).lower(params, cache, vec(B, T), vec(B), vec(B, T // PAGE), vec(B), vec(B)).compile()
     assert "moe_gmm" in compiled.as_text()
     assert _resident(compiled) < 15e9, f"{_resident(compiled) / 1e9:.1f} GB"
+
+
+def _kernel_ops(jaxpr) -> int:
+    """Equations of a jaxpr, those of every loop and branch inside it among them."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    n += _kernel_ops(inner)
+    return n
+
+
+# (id, slots, query heads, KV heads, table entries = the ring, rows a lane, the kernel's equations)
+_WINDOW_KERNELS = [
+    ("mellum2-one-row", 32, 32, 4, 1024 // PAGE + 1, 1, 667),  # PR 51's count: `rows == 1` traces what it traced
+    ("kexaone-one-row", 64, 64, 8, 128 // PAGE + 1, 1, 999),  # `decode_step_paged`'s, the same
+    ("kexaone-two-rows", 64, 64, 8, 128 // PAGE + 1, 2, 1072),  # an edge a row: its select and compare a turn, a guard a KV head
+]
+
+
+@pytest.mark.parametrize("case", _WINDOW_KERNELS, ids=lambda c: c[0])
+def test_the_window_walks_kernel_has_the_ops_it_had_at_one_row_a_lane(case):
+    """The window branch of the walk's body takes a static case at more than
+    one row a lane and no other: the one-row kernels' equation counts are the
+    parent's (PR 51's tree, counted there), so `mellum2`'s and the drafter-less
+    decode step's programs hold the ops they held."""
+    from agentcontrolplane_tpu.ops.pallas import paged_attention as pa
+
+    _, S, H, H_kv, ring, rows, ops = case
+    sds = jax.ShapeDtypeStruct
+    pages = sds((4096, PAGE, H_kv * 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, t, n, first: pa._paged_state(q, k, v, t, n, kv_heads=H_kv, starts=first, ring=ring, rows=rows))(
+        sds((S, rows * H, 128), jnp.bfloat16), pages, pages, sds((S, ring), jnp.int32), sds((S,), jnp.int32),
+        sds((S * rows,), jnp.int32))
+    (call,) = (eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "pallas_call")
+    assert call.params["name"] == "paged_window_walk" and _kernel_ops(call.params["jaxpr"]) == ops

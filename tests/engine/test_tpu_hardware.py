@@ -103,6 +103,18 @@ def test_compiled_latent_walk_matches_reference(tpu, order):
     assert got["pages_per_turn"] == 32 and got["ok"], got
 
 
+@pytest.mark.parametrize("window", [False, True], ids=["pages", "ring"])
+def test_compiled_verify_walk_matches_reference(tpu, window):
+    """A verify step's two rows a lane in ONE query group of the walk,
+    COMPILED at the geometry that runs it (64 / 8 heads of 128, a window of
+    128 over pages of 16): lanes of 0 to 6,000 rows, the two rows' edges in
+    one page and in two, every page outside the walk NaN."""
+    from agentcontrolplane_tpu.engine.kernel_parity import make_verify_case, verify_walk_parity
+
+    got = verify_walk_parity(make_verify_case(9), window=window)
+    assert got["ok"] and got["shape"] == (8, 2, 64, 128), got
+
+
 def test_engine_slot_and_paged_agree_on_tpu(tpu):
     """Greedy decode through BOTH kv layouts on hardware must produce the
     same tokens (the paged path uses the compiled Pallas kernel: engine
