@@ -7,7 +7,9 @@ CPU): seeded ragged pages at a named geometry, the kernel and
 difference judged against a tolerance set from the page dtype. The K/V walk
 (:func:`page_walk_parity`), the latent walk over a pool of one leaf
 (:func:`latent_walk_parity`) and a verify step's walks, several rows a lane
-in one query group over pages or a ring (:func:`verify_walk_parity`).
+in one query group over pages or a ring (:func:`verify_walk_parity`); and
+the routed experts' grouped matmul against ``jax.lax.ragged_dot`` over the
+same plan (:func:`expert_matmul_parity`).
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.moe import routed_experts
 from ..ops.paged import (
     TRASH_PAGE,
     PageAllocator,
@@ -235,3 +238,36 @@ def verify_walk_parity(case: dict, *, window: bool, interpret: bool = False) -> 
         ref = jax.jit(lambda q, k, v: paged_verify_attention_reference(
             q, k, v, pool["tables"], n, *new, new_valid=case["new_valid"], **kw))(case["q"], *pool["clean"])
     return _verdict(case, out, ref)
+
+
+def expert_matmul_parity(seed: int, *, tokens: int = 128, k: int = 8, experts: int = 128, held: int = 16,
+                         hidden: int = 6144, width: int = 2048, layers: int = 2, interpret: bool = False) -> dict:
+    """``ops.moe.routed_experts`` through the ``moe_gmm`` kernels (compiled
+    unless ``interpret``) against the same layer through ``ragged_dot``, at
+    the geometry of the widest expert layer served (``exaone``'s decode
+    step: 64 lanes x 2 rows x 8 choices over 16 of 128 experts, 6,144 and
+    2,048 wide: four column tiles a kernel, gate and up under a stated
+    VMEM limit), the second layer of a stack. The selection leans to the held experts so that most
+    row tiles hold a row; held expert 1 is chosen by no token and every
+    fifth token routes nowhere, so the plan has an idle expert and dead
+    tiles. The largest difference is judged against the largest output."""
+    keys = jax.random.split(jax.random.key(seed), 5)
+    draw = lambda i, *shape, scale=1.0: jax.random.normal(keys[i], shape, jnp.bfloat16) * scale  # noqa: E731
+    x, router = draw(0, tokens, hidden), draw(1, hidden, experts, scale=hidden ** -0.5)
+    w1, w3 = (draw(i, layers * held, hidden, width, scale=hidden ** -0.5) for i in (2, 3))
+    w2 = draw(4, layers * held, width, hidden, scale=width ** -0.5)
+    bias = jnp.zeros((experts,), jnp.float32).at[:held].set(2.0).at[1].set(-10.0)
+    valid = jnp.arange(tokens) % 5 != 4
+    run = lambda kernel: jax.jit(lambda *a: routed_experts(  # noqa: E731
+        *a, k, held=tuple(range(held)), score="sigmoid", bias=bias, valid=valid, expert_base=(layers - 1) * held,
+        kernel=kernel, interpret=interpret and kernel))(x, router, w1, w3, w2)
+    (out, counts), (ref, _) = run(True), run(False)
+    out, ref = np.asarray(out.astype(jnp.float32)), np.asarray(ref.astype(jnp.float32))
+    err, top = float(np.max(np.abs(out - ref))), float(np.max(np.abs(ref)))
+    finite, tol = bool(np.isfinite(out).all()), TOLERANCE["bfloat16"] * top
+    landed = np.asarray(counts)[-held:]
+    return {
+        "max_abs_err": err, "largest": top, "tolerance": tol, "finite": finite,
+        "shape": tuple(out.shape), "pairs_by_expert": [int(n) for n in landed],
+        "ok": finite and top > 0.1 and err <= tol and landed[1] == 0 and int(landed.sum()) > tokens * k // 2,
+    }

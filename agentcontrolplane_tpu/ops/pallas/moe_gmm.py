@@ -11,11 +11,24 @@ all. Tiles past ``n_live`` (the static row bound is ``tokens x k``; what
 landed here is usually an eighth of it) repeat the last live tile's block
 indices, fetch nothing, skip the product and store zeros.
 
+What the grid reads again: the column tile is the outer axis, so the
+weights are read once an expert and column tile (each expert's matrix once
+a call, in runs of ``tn`` columns), but the ROWS' block changes at every
+step and every live row is read once a column tile, ``N / tn`` times a
+call; and every step, dead or live, costs its issue and its output block's
+write (~0.15 us), and every sweep over the row tiles ends in its dead tiles
+with no fetch in flight (at 128 columns of a 6,144-wide contraction a
+decode step's 80 row tiles, 17 of them live, were 1,280 steps in 16 sweeps;
+a prefill's live rows were read sixteen times). So the column tile is as
+wide as the VMEM a kernel gets unasked holds, and wider under a stated
+limit only where that is under 512 columns (``tile_plan``): one tile or
+two in three cells, four and four at a hidden width of 6,144.
+
 Two entry points, one kernel each: ``gmm_swiglu`` (``act(x W1_e) * (x
-W3_e)``, both weights walked together so the rows are read once) and
-``gmm`` (``x W_e``). Products accumulate in float32 on the MXU's native
-bf16 pass; float32 operands (the CPU tests) take the full-precision
-contract.
+W3_e)``, both weights walked together so the rows are read once a column
+tile for both) and ``gmm`` (``x W_e``). Products accumulate in float32 on
+the MXU's native bf16 pass; float32 operands (the CPU tests) take the
+full-precision contract.
 """
 
 from __future__ import annotations
@@ -67,19 +80,44 @@ def _col_tile(n: int, want: int) -> int:
     return n
 
 
-# what a grid step's weight blocks may take of the 16 MiB a kernel gets, each
-# block twice (pipelined): at a hidden width of 6,144 two blocks of 512
-# columns are 24 MB and the chip's compiler refuses the kernel
-# (tests/engine/test_chip_compile.py, the exaone cases); every narrower
-# hidden width the benchmark has keeps its 512 columns
-_WEIGHT_BLOCKS_BYTES = 10 << 20
+# the VMEM a kernel gets unasked. A call that claims no more leaves the compiler the memory plan it always had
+_DEFAULT_VMEM_BYTES = 16 << 20
+# the most one call may claim of a v5e core's 128 MiB: a quarter. A claim over the default is taken from the
+# compiler's own budget for as long as the kernel runs: at 55 MB it sent a decode step's staged q weights (96 MiB at
+# a hidden width of 6,144) back to HBM, and at ANY size over the default the compiler's repacker crashed on one
+# prefill program of `lfm2` (PERF.md, PR 53). So a call claims more only where the default forces a tile under
+# `_MIN_COLS` columns (a weight fetch's runs under 1 KB, sixteen sweeps over the row tiles at a hidden width of 6,144)
+_VMEM_LIMIT_BYTES = 32 << 20
+_MIN_COLS = 512
+
+
+def tile_plan(K: int, N: int, weights: int, itemsize: int, tm: int, tn: int | None = None) -> tuple[int, int]:
+    """-> (the column tile, the VMEM the call asks for). The tile is the
+    widest whole-lane-tile divisor of ``N`` (at most ``tn`` where one is
+    given) whose ``weights`` blocks of ``K`` rows, each held twice (the next
+    expert's is fetched under this one's products), fit three quarters of
+    the VMEM a kernel gets unasked; only where that tile is under
+    ``_MIN_COLS`` columns, three quarters of ``_VMEM_LIMIT_BYTES``. The
+    limit is what the call then holds: every block twice (Pallas pipelines
+    them), a float32 product a weight and one more for their combination,
+    and 4 MiB for the compiler's own scratch (it took 0.1-2.2 MB over the
+    blocks at every geometry rehearsed), capped at the VMEM it was sized
+    for."""
+    widest = min(N, tn or N)
+    for limit in (_DEFAULT_VMEM_BYTES, _VMEM_LIMIT_BYTES):
+        fits = limit * 3 // 4 // (2 * weights * K * itemsize)
+        tile = _col_tile(N, max(128, min(fits, widest) // 128 * 128))
+        if tile >= min(_MIN_COLS, widest):
+            break
+    blocks = (weights * K * tile + tm * K + tm * tile) * itemsize
+    products = (weights + 1) * tm * tile * 4
+    return tile, min(limit, 2 * blocks + products + (4 << 20))
 
 
 def _call(kernel, x, weights, tile_expert, n_live, tm, tn, interpret):
     M, K = x.shape
     N = weights[0].shape[2]
-    fits = _WEIGHT_BLOCKS_BYTES // (2 * len(weights) * K * weights[0].dtype.itemsize)
-    tn = _col_tile(N, min(tn, max(128, fits // 128 * 128)))
+    tn, vmem_limit = tile_plan(K, N, len(weights), weights[0].dtype.itemsize, tm, tn)
     assert M % tm == 0 and tile_expert.shape == (M // tm,), (M, tm, tile_expert.shape)
 
     def row(i, nl):  # dead tiles re-use the last live tile's rows: no fetch
@@ -96,6 +134,7 @@ def _call(kernel, x, weights, tile_expert, n_live, tm, tn, interpret):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="moe_gmm",
     )(tile_expert, n_live, x, *weights)
@@ -107,7 +146,7 @@ def gmm(
     tile_expert: jax.Array,  # [M // tm] int32 — the expert of each row tile
     n_live: jax.Array,  # [1] int32 — row tiles that hold any row
     tm: int,
-    tn: int = 512,
+    tn: int | None = None,  # at most this wide a column tile; None: as wide as VMEM holds
     interpret: bool = False,
 ) -> jax.Array:
     """``x[rows of tile i] @ w[tile_expert[i]]`` -> [M, N]; zeros past n_live."""
@@ -121,7 +160,7 @@ def gmm_swiglu(
     tile_expert: jax.Array,
     n_live: jax.Array,
     tm: int,
-    tn: int = 512,
+    tn: int | None = None,  # at most this wide a column tile; None: as wide as VMEM holds
     act=jax.nn.silu,
     interpret: bool = False,
 ) -> jax.Array:

@@ -573,14 +573,66 @@ def _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers) -> str:
     return jax.jit(layer).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode-32-lanes", "prefill-8x512"])
-def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, tokens):
-    """`ops.moe.routed_experts` at LFM2-24B-A2B's widths, 8 of 64 experts
-    held out of a stack of 38 layers' experts: route, the rows' plan and the
-    `moe_gmm` kernels, at a decode step's rows and at a prefill's."""
-    text = _compiled_expert_layer(v5e, tokens, 4, 8, 64, 2048, 1536, 38)
+# (id, tokens, k, experts held, E, D, F, layers): the four expert cells' layer at a decode step's rows (`kexaone`'s
+# two rows a lane) and at a prefill's (`models/mellum.py MOE_CHUNK` tokens a call; `kexaone`'s 3,072-token bucket whole)
+_EXPERT_LAYERS = [
+    ("lfm2-decode-32-lanes", 32, 4, 8, 64, 2048, 1536, 38), ("lfm2-prefill-8x512", 4096, 4, 8, 64, 2048, 1536, 38),
+    ("mellum2-decode-32-lanes", 32, 8, 16, 64, 2304, 896, 28), ("mellum2-prefill-2048", 2048, 8, 16, 64, 2304, 896, 28),
+    ("kanana2-decode-16-lanes", 16, 6, 8, 128, 2048, 768, 47), ("kanana2-prefill-2048", 2048, 6, 8, 128, 2048, 768, 47),
+    ("kexaone-decode-64-lanes-2-rows", 128, 8, 16, 128, 6144, 2048, 4), ("kexaone-prefill-3072", 3072, 8, 16, 128, 6144, 2048, 4),
+]
+
+
+def _kernel_vmem(text: str, kernel: str = "moe_gmm") -> list:
+    """(asked, used) scoped VMEM bytes of each `tpu_custom_call` of a compiled
+    program whose name begins with `kernel`, in the program's order; `used`
+    less what the compiler itself keeps live under the kernel (the offset it
+    places the kernel's share at)."""
+    import re
+
+    sized = r'\[\{"memory_space":"1","offset":"(\d+)","size":"(\d+)"\}\]'
+    found = re.findall(rf'%{kernel}\S* = [^\n]*?custom_call_target="tpu_custom_call"[^\n]*?"scoped_memory_configs":{sized}'
+                       rf'[^\n]*?"used_scoped_memory_configs":{sized}', text)
+    return [(int(asked), int(used) - int(base)) for base, asked, _, used in found]
+
+
+@pytest.mark.parametrize("case", _EXPERT_LAYERS, ids=lambda c: c[0])
+def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, case):
+    """`ops.moe.routed_experts` at each expert cell's widths, its share of
+    the experts held out of a stack of the layers' experts: route, the rows'
+    plan and the `moe_gmm` kernels, at a decode step's rows and at a
+    prefill's. Each kernel asks for the VMEM `tile_plan` reckons for its
+    column tile (no more than a kernel gets unasked, except the gate and up
+    of `kexaone` and `mellum2`, whose tile the default would hold under 512
+    columns) and the chip's compiler places what it needs inside it."""
+    from agentcontrolplane_tpu.ops.moe import row_tile
+    from agentcontrolplane_tpu.ops.pallas.moe_gmm import tile_plan
+
+    _, tokens, k, Eh, E, D, F, layers = case
+    text = _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers)
     assert text.count("tpu_custom_call") == 2, "gate-and-up and down: two grouped matmuls"
-    assert "bf16[8,2048,1536]" not in text, "a layer's experts were sliced out of the stack (a copy a call)"
+    assert f"bf16[{Eh},{D},{F}]" not in text, "a layer's experts were sliced out of the stack (a copy a call)"
+    tm = row_tile(tokens * k)
+    (up, up_limit), (down, down_limit) = tile_plan(D, F, 2, 2, tm), tile_plan(F, D, 1, 2, tm)
+    assert (F // up, D // down) == {6144: (4, 4), 2304: (1, 1), 2048: (2 if F == 1536 else 1, 1)}[D]
+    assert (up_limit > 16 << 20) == (D in (6144, 2304)) and down_limit <= 16 << 20, "who claims over the default"
+    vmem = _kernel_vmem(text)
+    assert [asked for asked, _ in vmem] == [up_limit, down_limit], "each kernel states the limit its plan reckons"
+    assert all(2 * weights * K * tn * 2 <= used <= asked for (asked, used), (weights, K, tn) in zip(
+        vmem, ((2, D, up), (1, F, down)))), f"the weight blocks twice over, inside the limit: {vmem}"
+
+
+def test_the_widest_column_tile_is_refused_under_the_vmem_a_kernel_gets_unasked(v5e, monkeypatch):
+    """Why the call states its limit: `kexaone`'s gate and up at a tile of
+    512 columns holds 25 MB of weight blocks, and under the 16 MiB of scoped
+    VMEM a kernel gets by default the chip's compiler refuses it (PR 51 met
+    this and narrowed the tile to 128)."""
+    from agentcontrolplane_tpu.ops.pallas import moe_gmm
+
+    plan = moe_gmm.tile_plan
+    monkeypatch.setattr(moe_gmm, "tile_plan", lambda *a: (plan(*a)[0], 16 << 20))
+    with pytest.raises(Exception, match="vmem"):
+        _compiled_expert_layer(v5e, 128, 8, 16, 128, 6144, 2048, 4)
 
 
 # (name, tokens, k, experts held, E, D, F, layers, entry ops that run at most): a decode step's rows in the three
@@ -589,7 +641,9 @@ _EXPERT_ROWS = [
     ("lfm2-32x4-8-of-64", 32, 4, 8, 64, 2048, 1536, 38, 56),
     ("mellum2-32x8-16-of-64", 32, 8, 16, 64, 2304, 896, 28, 61),
     ("kanana2-16x6-8-of-128", 16, 6, 8, 128, 2048, 768, 47, 49),
-    ("lfm2-prefill-4096x4", 4096, 4, 8, 64, 2048, 1536, 38, 71),
+    # 78 since PR 53 (71 before): beside the kernels' wider tiles the compiler prefetches the combine's weights
+    # `f32[4096,4]` as four slices of 16 KB where it made one copy (four `slice-start` / `slice-done` and their join)
+    ("lfm2-prefill-4096x4", 4096, 4, 8, 64, 2048, 1536, 38, 78),
 ]
 _NOT_RUN = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
 

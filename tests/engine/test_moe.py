@@ -423,3 +423,91 @@ def test_routed_experts_is_bit_for_bit_what_the_sorted_plan_gave(name, path):
     y, stats = routed_experts(x, router, w1, w3, w2, k, held=held, score=score, bias=bias if score == "sigmoid" else None,
                               valid=ok, act=lambda v: v, expert_base=Eh, kernel=False, interpret=path != "xla")
     assert hashlib.sha256(np.asarray(y).tobytes() + np.asarray(stats).tobytes()).hexdigest()[:16] == digest
+
+
+# -- the grouped matmul's column tile (PR 53) ----------------------------------
+
+# (id, hidden, expert width, row tile, gate-and-up's tile, down's tile): the four expert cells' two kernels at a
+# decode step's rows and a prefill's, and CPU-test widths with no whole-lane-tile divisor (the width itself)
+_TILES = [
+    ("kexaone-decode", 6144, 2048, 16, 512, 1536), ("kexaone-prefill", 6144, 2048, 128, 512, 1536),
+    ("mellum2-decode", 2304, 896, 16, 896, 2304), ("mellum2-prefill", 2304, 896, 128, 896, 2304),
+    ("lfm2-decode", 2048, 1536, 16, 768, 2048), ("lfm2-prefill", 2048, 1536, 128, 768, 2048),
+    ("kanana2-decode", 2048, 768, 16, 768, 2048), ("kanana2-prefill", 2048, 768, 128, 768, 2048),
+    ("cpu-tiny", 64, 96, 16, 96, 64), ("cpu-odd", 200, 320, 16, 320, 200),
+]
+
+
+@pytest.mark.parametrize("case", _TILES, ids=lambda c: c[0])
+def test_the_column_tile_is_as_wide_as_the_stated_vmem_holds(case):
+    """`tile_plan`, the rule alone: the widest whole-lane-tile divisor of
+    the width whose weight blocks fit twice over in three quarters of the
+    VMEM a kernel gets unasked, the call then claiming no more than that
+    default; and only where such a tile is under 512 columns (`kexaone`'s
+    and `mellum2`'s gate and up) the same under a stated limit of at most a
+    quarter of the core. The limit covers every block twice."""
+    from agentcontrolplane_tpu.ops.pallas import moe_gmm
+
+    _, D, F, tm, up, down = case
+    default, most = moe_gmm._DEFAULT_VMEM_BYTES, moe_gmm._VMEM_LIMIT_BYTES
+    assert (default, most, moe_gmm._MIN_COLS) == (16 << 20, 32 << 20, 512)
+    for K, N, weights, want in ((D, F, 2, up), (F, D, 1, down)):
+        tn, limit = moe_gmm.tile_plan(K, N, weights, 2, tm)
+        assert tn == want and N % tn == 0 and (tn % 128 == 0 or tn == N)
+        held = lambda t: 2 * weights * K * t * 2  # noqa: E731 (a tile's weight blocks, twice)
+        claims = held(tn) > default * 3 // 4
+        assert 2 * (weights * K * tn + tm * K + tm * tn) * 2 < limit <= (most if claims else default)
+        assert held(tn) <= (most if claims else default) * 3 // 4
+        wider = [t for t in range(tn + 128, N + 1, 128) if N % t == 0]
+        if claims:  # only because the default held under 512 columns, and then as wide as the quarter holds
+            assert all(held(t) > default * 3 // 4 for t in range(512, tn + 1, 128) if N % t == 0)
+            assert all(held(t) > most * 3 // 4 for t in wider), "a wider tile fits the quarter"
+        else:
+            assert all(held(t) > default * 3 // 4 for t in wider), "a wider tile fits the default"
+        assert moe_gmm.tile_plan(K, N, weights, 2, tm, 128)[0] == (128 if N % 128 == 0 else N), "a caller's cap holds"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("entry", ["gmm", "gmm_swiglu"])
+def test_a_narrow_and_the_widest_column_tile_give_the_same_bits(entry, dtype):
+    """The contraction is whole in one block at any column tile: three
+    tiles of 128 and one of 384, dead tiles and an expert no row chose among
+    them, agree bit for bit, and with `ragged_dot` over the same groups.
+    The operands are small integers, so each sum is exact in any order: the
+    CPU's matmul blocks a 384-wide product otherwise than a 128-wide one
+    and what this holds is the tiles' indexing, not the host's rounding."""
+    from agentcontrolplane_tpu.ops.moe import group_rows
+    from agentcontrolplane_tpu.ops.pallas import moe_gmm
+
+    E, K, N, tm, k, pairs = 4, 128, 384, 16, 2, 40
+    rng = np.random.default_rng(53)
+    key = jnp.asarray(rng.choice([0, 2, 3, E], pairs), jnp.int32)  # expert 1 is chosen by no pair; E: not held here
+    M = -(-pairs // tm) * tm + E * tm
+    _, row_token, tile_expert, n_live, counts = group_rows(key, E, tm, M, k)
+    live = int(n_live[0]) * tm
+    assert int(counts[1]) == 0 and live < M, "the case holds an idle expert and dead tiles"
+    x = jnp.asarray(rng.integers(-2, 3, (pairs // k, K)), dtype)[row_token]
+    ws = [jnp.asarray(rng.integers(-1, 2, (E, K, N)), dtype) for _ in range(1 + (entry == "gmm_swiglu"))]
+    act = lambda v: jnp.maximum(v, 0)  # noqa: E731 (exact, where silu is the host's exp)
+    run = lambda tn: np.asarray(jax.jit(lambda *a: getattr(moe_gmm, entry)(  # noqa: E731
+        *a, tile_expert, n_live, tm, tn=tn, interpret=True, **({"act": act} if len(ws) == 2 else {})))(x, *ws))
+    narrow, widest = run(128), run(None)
+    assert moe_gmm.tile_plan(K, N, len(ws), x.dtype.itemsize, tm)[0] == N
+    assert narrow.tobytes() == widest.tobytes()
+    padded = -(-counts // tm) * tm
+    dot = lambda w: jax.lax.ragged_dot(x, w, padded, preferred_element_type=jnp.float32)  # noqa: E731
+    want = dot(ws[0]) if entry == "gmm" else act(dot(ws[0])) * dot(ws[1])
+    assert not widest[live:].any() and np.abs(widest[:live].astype(np.float32)).max() > 8
+    np.testing.assert_array_equal(widest[:live], np.asarray(want.astype(dtype))[:live])
+
+
+def test_the_chips_parity_case_runs_interpreted_with_an_idle_expert_and_dead_tiles():
+    """`engine.kernel_parity.expert_matmul_parity` (what `chip_smoke.py` and
+    the hardware test compile at `exaone`'s widths) at a tiny size through
+    the interpreted kernel: column tiles of 256 and 128, the second layer of
+    a stack, held expert 1 idle, every fifth token routed nowhere."""
+    from agentcontrolplane_tpu.engine.kernel_parity import expert_matmul_parity
+
+    got = expert_matmul_parity(3, tokens=20, k=2, experts=8, held=4, hidden=128, width=256, layers=2, interpret=True)
+    assert got["ok"] and got["shape"] == (20, 128) and got["pairs_by_expert"][1] == 0, got
+    assert sum(got["pairs_by_expert"]) == 32, "16 of 20 tokens route, two choices each, all to held experts"

@@ -115,6 +115,18 @@ def test_compiled_verify_walk_matches_reference(tpu, window):
     assert got["ok"] and got["shape"] == (8, 2, 64, 128), got
 
 
+def test_compiled_grouped_matmul_matches_ragged_dot(tpu):
+    """The routed experts' layer through the `moe_gmm` kernels, COMPILED at
+    the widest geometry served (`exaone`'s decode step: 1,280 rows in tiles
+    of 16, 6,144 and 2,048 wide, so four column tiles a kernel, gate and
+    up under the VMEM limit the call states), against `ragged_dot`
+    over the same plan: an idle expert and dead tiles among it."""
+    from agentcontrolplane_tpu.engine.kernel_parity import expert_matmul_parity
+
+    got = expert_matmul_parity(9)
+    assert got["ok"] and got["shape"] == (128, 6144), got
+
+
 def test_engine_slot_and_paged_agree_on_tpu(tpu):
     """Greedy decode through BOTH kv layouts on hardware must produce the
     same tokens (the paged path uses the compiled Pallas kernel: engine
