@@ -5,13 +5,15 @@ names the dense path has always had (``prefill_paged_batch``,
 ``prefill_paged_continue``, ``decode_step_paged``, ...), chosen by the type
 of the config (``_FAMILIES``: one row a family). The Llama family's entries
 are ``models.llama``'s functions themselves. A family that keeps per-slot
-state beside the pages (``has_state``: ``models.lfm2``, ``models.jamba``)
+state beside the pages (``has_state``: ``models.lfm2``, ``models.jamba``,
+``models.nemotron_h``)
 receives, where the dense programs take the page ids alone, the pair
 ``(page_ids, (slots, snap_at))``: which slot's state each row reads and
 writes, and where its snapshot is due. What the state is made of stays the
 family's: a tree of arrays of whatever types and sizes (``lfm2``: one array
 of conv columns; ``jamba``: the recurrence's float32 ``h`` and the conv
-columns, two leaves). The engine shards whatever tree ``init_paged_cache``
+columns, two leaves; ``nemotron_h``: the same two, ``S`` of heads in the order
+its kernels read). The engine shards whatever tree ``init_paged_cache``
 puts under ``"state"`` whole, and moves a slot's part as a tree too:
 ``saved_state(cache, slot)`` gives it, ``install_state(cache, slot, tree)``
 takes it back, and between the two the engine only keeps it (on the device
@@ -61,7 +63,7 @@ weights), so ``config.n_layers`` counts its weights and
 
 from types import SimpleNamespace
 
-from . import exaone, jamba, kanana, lfm2, llama, mellum, ouro
+from . import exaone, jamba, kanana, lfm2, llama, mellum, nemotron_h, ouro
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -76,10 +78,12 @@ from .jamba import JambaConfig
 from .kanana import KananaConfig
 from .lfm2 import Lfm2Config
 from .mellum import MellumConfig
+from .nemotron_h import NemotronHConfig
 from .ouro import OuroConfig
 
 __all__ = [
     "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "OuroConfig", "ExaoneConfig",
+    "NemotronHConfig",
     "decode_step", "forward", "init_kv_cache",
     "init_params", "kv_pages_that_fit", "page_bytes", "prefill", "preset", "programs",
 ]
@@ -87,7 +91,7 @@ __all__ = [
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone, nemotron_h)]
     for table in tables:
         if name in table:
             return table[name]
@@ -243,6 +247,8 @@ _MELLUM = _with_state("mellum", mellum, window_cache=True)
 # mellum's two caches over kanana's expert layer, and the model's own MTP
 # module as the drafter: its decode program is a verify-and-draft step
 _EXAONE = _with_state("exaone", exaone, window_cache=True)
+# jamba's seam over a state of heads (Mamba-2: 4 MiB a slot and layer) and latent experts; blocks of one mixer
+_NEMOTRON_H = _with_state("nemotron_h", nemotron_h)
 # no state a slot (its programs take the page ids alone, as the dense
 # family's), counters on the device, its own pool: a latent row a token
 _KANANA = SimpleNamespace(
@@ -270,7 +276,7 @@ _OURO = SimpleNamespace(
     counters=ouro.counters, describe_counters=ouro.describe_counters,
 )
 _FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM,
-             KananaConfig: _KANANA, OuroConfig: _OURO, ExaoneConfig: _EXAONE}
+             KananaConfig: _KANANA, OuroConfig: _OURO, ExaoneConfig: _EXAONE, NemotronHConfig: _NEMOTRON_H}
 
 
 def programs(config) -> SimpleNamespace:

@@ -131,13 +131,20 @@ def route_scores(
     return idx, w * scale
 
 
-PREFILL_PAIRS = 2048  # from here on a dispatch's (token, choice) pairs are a prefill's, under it a decode step's
+PREFILL_PAIRS = 2048  # from here on the plan's compares, quadratic in the pairs, cost more than a prefix sum and a scatter
+DECODE_TILE = 16  # rows of a decode step's tile: a bf16 sublane tile
 
 
-def row_tile(pairs: int) -> int:
-    """Rows of a tile of the grouped matmul: a bf16 sublane tile for decode
-    batches, an MXU-height tile once a prefill brings rows by the thousand."""
-    return 128 if pairs >= PREFILL_PAIRS else 16
+def row_tile(pairs: int, experts: int | None = None) -> int:
+    """Rows of a tile of the grouped matmul, by what an expert can expect: a
+    bf16 sublane tile while a dispatch's pairs over the ``experts`` the
+    router knows leave an expert under a tile of rows (a decode step), an
+    MXU-height tile once a prefill brings rows by the thousand and a decode
+    tile's worth to each. The pairs alone do not say: 128 lanes at top-22 of
+    512 are 2,816 pairs and 5.5 rows an expert, a decode step, where 512
+    tokens at top-4 of 64 are 2,048 pairs and 32 rows an expert. With 128
+    experts or fewer (``experts`` None: not given) the rule is the pairs'."""
+    return 128 if pairs >= max(PREFILL_PAIRS, DECODE_TILE * (experts or 0)) else DECODE_TILE
 
 
 def group_rows(
@@ -159,7 +166,11 @@ def group_rows(
     pairs before it with its key. No sort, no search loop, and no scatter
     at a decode step's rows: the chip runs each of those an element at a
     time, and there are a few hundred integers and eight or sixteen values
-    to group them by. Sums over a one-hot of the keys fuse to a dozen ops."""
+    to group them by. Sums over a one-hot of the keys fuse to a dozen ops.
+    The two ways to a pair's rank are chosen by what bounds THEM, the pairs
+    (compares quadratic in them against a prefix sum and one scatter),
+    whatever tile ``row_tile`` gave: a decode step of 2,816 pairs takes the
+    16-row tile and the prefix sum (7.9 M compares a layer otherwise)."""
     pairs = key.shape[0]
     experts = jnp.arange(Eh, dtype=jnp.int32)
     pair = jnp.arange(pairs, dtype=jnp.int32)
@@ -193,8 +204,8 @@ def group_rows(
 def routed_experts(
     x: jax.Array,  # [N, D]
     router_w: jax.Array,  # [D, E]
-    w1: jax.Array,  # [E_held, D, F] gate
-    w3: jax.Array,  # [E_held, D, F] up
+    w1: jax.Array,  # [E_held, D, F] gate (of an expert with no gate: its one matrix in)
+    w3: jax.Array | None,  # [E_held, D, F] up; None: the expert is ``w2 act(w1 .)``, not gated
     w2: jax.Array,  # [E_held, F, D] down
     experts_per_token: int,
     held: tuple[int, ...] | None = None,
@@ -208,8 +219,14 @@ def routed_experts(
     interpret: bool = False,
     expert_base: jax.Array | int = 0,
     chosen: jax.Array | None = None,  # [N, k]: route_scores' `chosen`
+    u: jax.Array | None = None,  # [N, D_u]: what the experts read, where it is not what the router reads
 ) -> tuple[jax.Array, jax.Array]:
     """-> (y [N, D] in x.dtype, counts [COUNTS_HEAD + E_held] uint32).
+
+    The router always reads ``x``. The experts read ``x`` too, or ``u``
+    where one is given (latent experts: ``u = x W_down``, narrower than
+    ``x``; ``y`` is then ``[N, w2's columns]`` and the caller projects it
+    back up). ``w3`` None: experts of two matrices with ``act`` between.
 
     ``w1``/``w3``/``w2`` may hold several layers' experts on one leading
     axis (``[layers * E_held, ...]``); ``expert_base`` (traced or not) is
@@ -226,7 +243,7 @@ def routed_experts(
     if kernel is None:
         kernel = jax.default_backend() == "tpu"
     pairs = N * k
-    tm = row_tile(pairs)
+    tm = row_tile(pairs, E)
     M = -(-pairs // tm) * tm + Eh * tm
 
     with jax.named_scope("moe_route"):
@@ -242,28 +259,32 @@ def routed_experts(
         n_valid = pairs if valid is None else jnp.sum(valid.astype(jnp.uint32)) * k
         dest, row_token, tile_expert, n_live, counts = group_rows(key, Eh, tm, M, k)
         tile_expert = tile_expert + jnp.asarray(expert_base, jnp.int32)
-        xs = x[row_token]
+        xs = (x if u is None else u)[row_token]
 
     with jax.named_scope("moe_gmm"):
-        w1d, w3d, w2d = (_maybe_dequant(w, x.dtype) for w in (w1, w3, w2))
+        w1d, w3d, w2d = (None if w is None else _maybe_dequant(w, x.dtype) for w in (w1, w3, w2))
         if kernel or interpret:
-            from .pallas.moe_gmm import gmm, gmm_swiglu
+            from .pallas.moe_gmm import gmm, gmm_act, gmm_swiglu
 
-            h = gmm_swiglu(xs, w1d, w3d, tile_expert, n_live, tm, act=act, interpret=interpret)
+            if w3d is None:
+                h = gmm_act(xs, w1d, tile_expert, n_live, tm, act=act, interpret=interpret)
+            else:
+                h = gmm_swiglu(xs, w1d, w3d, tile_expert, n_live, tm, act=act, interpret=interpret)
             ys = gmm(h, w2d, tile_expert, n_live, tm, interpret=interpret)
         else:
             if w1d.shape[0] != Eh:
-                w1d, w3d, w2d = (jax.lax.dynamic_slice_in_dim(w, expert_base, Eh) for w in (w1d, w3d, w2d))
+                w1d, w3d, w2d = (None if w is None else jax.lax.dynamic_slice_in_dim(w, expert_base, Eh)
+                                 for w in (w1d, w3d, w2d))
             prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
             padded = -(-counts // tm) * tm  # the groups' rows, as `group_rows` padded them
             dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
                 a, w, padded, precision=prec, preferred_element_type=jnp.float32)
-            h = (act(dot(xs, w1d)) * dot(xs, w3d)).astype(x.dtype)
+            h = (act(dot(xs, w1d)) if w3d is None else act(dot(xs, w1d)) * dot(xs, w3d)).astype(x.dtype)
             ys = dot(h, w2d).astype(x.dtype)
             ys = jnp.where((jnp.arange(M) < n_live * tm)[:, None], ys, 0)
 
     with jax.named_scope("moe_combine"):
-        ys = jnp.concatenate([ys, jnp.zeros((1, D), ys.dtype)])
+        ys = jnp.concatenate([ys, jnp.zeros((1, w2.shape[-1]), ys.dtype)])
         dest = dest.reshape(N, k)
         w_here = jnp.where(dest < M, wts, 0.0)
         y = jnp.sum(ys[dest].astype(jnp.float32) * w_here[..., None], axis=1)

@@ -167,3 +167,34 @@ def gmm_swiglu(
     """``act(x @ w1[e]) * (x @ w3[e])`` per row tile -> [M, F]."""
     kernel = functools.partial(_gmm_swiglu_kernel, act=act)
     return _call(kernel, x, (w1, w3), tile_expert, n_live, tm, tn, interpret)
+
+
+# -- an expert with no gate (`w2 act(w1 x)`): added below what was here, whose kernels' lines stay where they were
+# (a Pallas kernel's program text carries the line of every op: PERF.md, PR 45) --
+
+
+def _gmm_act_kernel(tile_expert_ref, n_live_ref, x_ref, w_ref, o_ref, *, act):
+    live = pl.program_id(1) < n_live_ref[0]
+
+    @pl.when(live)
+    def _():
+        o_ref[...] = act(_dot(x_ref[...], w_ref[0])).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+def gmm_act(
+    x: jax.Array,  # [M, K]
+    w: jax.Array,  # [E, K, F]
+    tile_expert: jax.Array,
+    n_live: jax.Array,
+    tm: int,
+    tn: int | None = None,
+    act=jax.nn.silu,
+    interpret: bool = False,
+) -> jax.Array:
+    """``act(x @ w[e])`` per row tile -> [M, F]: the first matrix of an expert
+    that has no gate."""
+    return _call(functools.partial(_gmm_act_kernel, act=act), x, (w,), tile_expert, n_live, tm, tn, interpret)

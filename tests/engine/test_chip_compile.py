@@ -1251,3 +1251,98 @@ def test_ouro_prefill_of_two_rows_of_256_fits_beside_the_resident_set(v5e, progr
     assert mem.alias_size_in_bytes >= 2 * cache["k"].size * 2
     assert mem.temp_size_in_bytes < 1.7e9, f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB"
     assert _resident(compiled) < 15.2e9, f"{_resident(compiled) / 1e9:.2f} GB"
+
+
+# -- the nemotron_h family: the Mamba-2 kernels, the latent experts, the programs' memory (PR 54) ------------------
+
+_NEMOTRON_SLOTS, _NEMOTRON_PAGES = 128, 32769  # acpbench/configs/nemotron3-super-120b-a12b-bf16-v5e1-ep8.json
+
+
+def _nemotron(v5e, monkeypatch):
+    """The benchmark's cut of the published config (its first 11 blocks, 64
+    of 512 experts, an eighth of the vocabulary), abstract weights and cache
+    placed on one described chip, the programs steered onto their kernels."""
+    import dataclasses
+    import functools
+
+    from agentcontrolplane_tpu.models import nemotron_h as nh
+
+    monkeypatch.setattr(nh.ssd, "scan", functools.partial(nh.ssd.scan, kernel=True))
+    monkeypatch.setattr(nh.ssd, "update", functools.partial(nh.ssd.update, kernel=True))
+    monkeypatch.setattr(nh, "routed_experts", functools.partial(nh.routed_experts, kernel=True))
+    c = dataclasses.replace(nh.PRESETS["nemotron-3-super-120b-a12b"], vocab_size=16384,
+                            layer_types=nh.pattern(nh.PUBLISHED[:11]), experts_held=tuple(range(64)))
+    one_chip = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: nh.init_params(c, jax.random.key(0))))
+    cache = place(jax.eval_shape(lambda: nh.init_paged_cache(c, _NEMOTRON_PAGES, PAGE, max_slots=_NEMOTRON_SLOTS)))
+    vec = lambda *shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return nh, c, params, cache, vec
+
+
+def test_nemotron_walk_compiles_at_two_kv_heads_and_a_group_of_sixteen(v5e):
+    text = _compile_walk(v5e, 32, 2, 128, jnp.bfloat16, False).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_nemotron_decode_block_updates_the_state_in_place(v5e, monkeypatch):
+    """128 lanes of the benchmark's cut, steps in a loop as the engine's
+    decode block nests them: the resident set is the issue's arithmetic
+    (5.50 GB of weights, 5.49 GB of state with its snapshot), the whole state
+    is DONATED and aliased from argument to result through every layer loop,
+    no op copies the stack of `S` (4 MiB a slot and layer: 2.7 GB a copy) or
+    makes a layer's lanes of it a value of their own, and the block's
+    temporaries are a small fraction of the state."""
+    import re
+
+    nh, c, params, cache, vec = _nemotron(v5e, monkeypatch)
+    S = _NEMOTRON_SLOTS
+
+    def block(p, ca, tok, n, tables, active):
+        def step(carry, _):
+            ca, tok, n = carry
+            ca, logits = nh.decode_step_paged(p, ca, tok, n, tables, active, c, use_pallas=True)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (ca, tok, n + 1), tok
+
+        (ca, _, _), toks = jax.lax.scan(step, (ca, tok, n), None, length=4)
+        return ca, toks
+
+    compiled = jax.jit(block, donate_argnums=(1,)).lower(
+        params, cache, vec(S), vec(S), vec(S, 4096 // PAGE), vec(S, dt=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert all(name in text for name in ("ssm_update", "moe_gmm", "paged_page_walk"))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    state = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache["state"]))
+    assert abs(weights - 5.50e9) < 0.01e9 and abs(state - 5.49e9) < 0.01e9
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 20, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB"
+    stack = rf"f32\[{c.n_mamba},{S + 1},64,128,128\]"
+    assert re.search(stack, text)
+    assert not re.search(rf"= {stack}\S* copy\(", text), "a copy of the whole stack of S"
+    assert f"f32[{S},64,128,128]" not in text, "a layer's lanes of the state as a value of their own"
+    assert 0.6 * 16e9 < _resident(compiled) < 12.5e9, f"{_resident(compiled) / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("program", ["prefill", "continuation"])
+def test_nemotron_prefill_of_2048_tokens_fits_beside_the_resident_set(v5e, monkeypatch, program):
+    """The widest prefill the file admits (one row of 2,048) with the chunked
+    scan, and its continuation over 4,096 tokens of pages: weights, state and
+    pool resident, the temporaries beside them, under the chip's 16 GB; no
+    per-token array of the state anywhere."""
+    nh, c, params, cache, vec = _nemotron(v5e, monkeypatch)
+    B, T = 1, 2048
+    if program == "prefill":
+        fn = lambda p, ca, tok, n, ids, slots, snap: nh.prefill_paged_batch(p, ca, tok, n, ids, (slots, snap), c)  # noqa: E731
+        args = (params, cache, vec(B, T), vec(B), vec(B, T // PAGE), vec(B), vec(B))
+    else:
+        fn = lambda p, ca, tok, n, st, ids, tb, slots, snap: nh.prefill_paged_continue(  # noqa: E731
+            p, ca, tok, n, st, ids, tb, (slots, snap), c)
+        args = (params, cache, vec(B, T), vec(B), vec(B), vec(B, T // PAGE), vec(B, 4096 // PAGE), vec(B), vec(B))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ssm_scan" in text and "moe_gmm" in text
+    assert f"f32[{B},{T},64,128,128]" not in text and f"f32[{T},64,128,128]" not in text
+    assert _resident(compiled) < 13e9, f"{_resident(compiled) / 1e9:.1f} GB"

@@ -4,7 +4,7 @@
 `forward` over one row padded to a fixed width and reads the last real row,
 so that every prompt length shares one compiled program. That is right only
 while a family's forward is blind to what lies to the right of a position:
-attention, the short conv and the Mamba scan are causal, and the routed
+attention, the short conv and the Mamba scans (both) are causal, and the routed
 layers choose a token's experts from that token alone (a capacity rule
 would break it). Here each family's padded rows are held to its forward at
 the exact length. A family that fails keeps exact lengths in its own file.
@@ -16,11 +16,12 @@ import jax
 import numpy as np
 import pytest
 
-from agentcontrolplane_tpu.models import jamba, kanana, lfm2, llama, mellum, ouro, preset
+from agentcontrolplane_tpu.models import jamba, kanana, lfm2, llama, mellum, nemotron_h, ouro, preset
 from agentcontrolplane_tpu.testing import compiled, greedy_reference, padded_logits
 
 FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2), "jamba": ("jamba-tiny", jamba),
-            "mellum": ("mellum-tiny", mellum), "kanana": ("kanana-tiny", kanana), "ouro": ("ouro-tiny", ouro)}
+            "mellum": ("mellum-tiny", mellum), "kanana": ("kanana-tiny", kanana), "ouro": ("ouro-tiny", ouro),
+            "nemotron_h": ("nemotron-h-tiny", nemotron_h)}
 WIDTH = 128
 
 

@@ -21,7 +21,7 @@ from agentcontrolplane_tpu.engine.lanes import DECODE, PREFILL
 from agentcontrolplane_tpu.observability import scopes
 
 SLOTS, PAGE, PAGES, CTX, BLOCK = 4, 16, 33, 128, 4
-FAMILIES = ["tiny", "moe-tiny", "lfm2-tiny", "jamba-tiny", "mellum-tiny", "kanana-tiny"]
+FAMILIES = ["tiny", "moe-tiny", "lfm2-tiny", "jamba-tiny", "mellum-tiny", "kanana-tiny", "nemotron-h-tiny"]
 
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([a-z][\w\-]*)\(")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -146,7 +146,7 @@ def test_every_op_of_a_layer_names_its_layer(preset, program):
         assert len(levels) <= 1, op_name
         seen |= levels
     assert seen <= set(scopes.LAYERS)
-    mixer = {"lfm2-tiny", "jamba-tiny"}
+    mixer = {"lfm2-tiny", "jamba-tiny", "nemotron-h-tiny"}
     assert seen == set(scopes.LAYERS) - (set() if preset in mixer else {"mixer"})
 
 
