@@ -240,34 +240,55 @@ def verify_walk_parity(case: dict, *, window: bool, interpret: bool = False) -> 
     return _verdict(case, out, ref)
 
 
+# the compiled kernels' cases: the widest layer served with most row tiles live (the selection leans to the held
+# experts), then streams whose bound is mostly dead tiles at the two cells with the longest bounds (an unsteered router:
+# the chip's share of the pairs lands) and one prefill (tiles of 128 rows, runs of several)
+EXPERT_CASES = {
+    "widest": dict(tokens=128, k=8, experts=128, held=16, hidden=6144, width=2048),
+    "kexaone-decode": dict(tokens=128, k=8, experts=128, held=16, hidden=6144, width=2048, lean=0.0),  # ~17 of 80 tiles live
+    "nemotron3s-decode": dict(tokens=128, k=22, experts=512, held=64, hidden=1024, width=2688, lean=0.0, gated=False),  # ~64 of 240
+    "mellum2-prefill": dict(tokens=2048, k=8, experts=64, held=16, hidden=2304, width=896, lean=0.0),  # ~40 of 144 tiles of 128
+}
+
+
 def expert_matmul_parity(seed: int, *, tokens: int = 128, k: int = 8, experts: int = 128, held: int = 16,
-                         hidden: int = 6144, width: int = 2048, layers: int = 2, interpret: bool = False) -> dict:
+                         hidden: int = 6144, width: int = 2048, layers: int = 2, lean: float = 2.0, gated: bool = True,
+                         interpret: bool = False) -> dict:
     """``ops.moe.routed_experts`` through the ``moe_gmm`` kernels (compiled
-    unless ``interpret``) against the same layer through ``ragged_dot``, at
-    the geometry of the widest expert layer served (``exaone``'s decode
-    step: 64 lanes x 2 rows x 8 choices over 16 of 128 experts, 6,144 and
-    2,048 wide: four column tiles a kernel, gate and up under a stated
-    VMEM limit), the second layer of a stack. The selection leans to the held experts so that most
-    row tiles hold a row; held expert 1 is chosen by no token and every
-    fifth token routes nowhere, so the plan has an idle expert and dead
-    tiles. The largest difference is judged against the largest output."""
+    unless ``interpret``) against the same layer through ``ragged_dot``, by
+    default at the geometry of the widest expert layer served (``exaone``'s
+    decode step: 64 lanes x 2 rows x 8 choices over 16 of 128 experts, 6,144
+    and 2,048 wide: sixteen column chunks an expert's gate and up), the
+    second layer of a stack. ``lean`` is the selection's bias to the held
+    experts: 2.0 so that most row tiles hold a row, 0.0 for the share an
+    unsteered router lands here (``EXPERT_CASES``: a stream under a long
+    dead bound). Held expert 1 is chosen by no token and every fifth token
+    routes nowhere, so the plan has an idle expert and dead tiles; ``gated``
+    False: experts of two matrices. The largest difference is judged against
+    the largest output."""
+    from ..ops.moe import row_tile
+
     keys = jax.random.split(jax.random.key(seed), 5)
     draw = lambda i, *shape, scale=1.0: jax.random.normal(keys[i], shape, jnp.bfloat16) * scale  # noqa: E731
     x, router = draw(0, tokens, hidden), draw(1, hidden, experts, scale=hidden ** -0.5)
     w1, w3 = (draw(i, layers * held, hidden, width, scale=hidden ** -0.5) for i in (2, 3))
     w2 = draw(4, layers * held, width, hidden, scale=width ** -0.5)
-    bias = jnp.zeros((experts,), jnp.float32).at[:held].set(2.0).at[1].set(-10.0)
+    bias = jnp.zeros((experts,), jnp.float32).at[:held].set(lean).at[1].set(-10.0)
     valid = jnp.arange(tokens) % 5 != 4
-    run = lambda kernel: jax.jit(lambda *a: routed_experts(  # noqa: E731
-        *a, k, held=tuple(range(held)), score="sigmoid", bias=bias, valid=valid, expert_base=(layers - 1) * held,
-        kernel=kernel, interpret=interpret and kernel))(x, router, w1, w3, w2)
+    run = lambda kernel: jax.jit(lambda x, router, w1, w3, w2: routed_experts(  # noqa: E731
+        x, router, w1, w3 if gated else None, w2, k, held=tuple(range(held)), score="sigmoid", bias=bias, valid=valid,
+        expert_base=(layers - 1) * held, kernel=kernel, interpret=interpret and kernel))(x, router, w1, w3, w2)
     (out, counts), (ref, _) = run(True), run(False)
     out, ref = np.asarray(out.astype(jnp.float32)), np.asarray(ref.astype(jnp.float32))
     err, top = float(np.max(np.abs(out - ref))), float(np.max(np.abs(ref)))
     finite, tol = bool(np.isfinite(out).all()), TOLERANCE["bfloat16"] * top
-    landed = np.asarray(counts)[-held:]
+    landed = np.asarray(counts)[-held:].astype(np.int64)
+    tm = row_tile(tokens * k, experts)
+    routed = int(np.asarray(valid).sum()) * k
     return {
         "max_abs_err": err, "largest": top, "tolerance": tol, "finite": finite,
         "shape": tuple(out.shape), "pairs_by_expert": [int(n) for n in landed],
-        "ok": finite and top > 0.1 and err <= tol and landed[1] == 0 and int(landed.sum()) > tokens * k // 2,
+        "live_tiles": int(np.sum(-(-landed // tm))), "tiles": -(-tokens * k // tm) + held,
+        "ok": finite and top > 0.1 and err <= tol and landed[1] == 0
+              and int(landed.sum()) > (routed // 2 if lean else routed * held // experts // 2),
     }

@@ -270,6 +270,8 @@ def routed_experts(
                 h = gmm_act(xs, w1d, tile_expert, n_live, tm, act=act, interpret=interpret)
             else:
                 h = gmm_swiglu(xs, w1d, w3d, tile_expert, n_live, tm, act=act, interpret=interpret)
+            # rows past the live tiles are not written by either kernel: the second walks the same live tiles and
+            # `dest` below is a live row or the appended zero row
             ys = gmm(h, w2d, tile_expert, n_live, tm, interpret=interpret)
         else:
             if w1d.shape[0] != Eh:

@@ -10,7 +10,7 @@ from a seed. One process; no argument = one chip:
   kernel   the COMPILED Pallas page walk vs the XLA gather reference on the
            device, Qwen2.5-7B geometry, bf16 pages and int8 pages + scales;
            the latent walk; a verify step's two rows a lane over pages and ring;
-           the routed experts' grouped matmul vs ragged_dot at its widest tiles
+           the routed experts' grouped matmul vs ragged_dot at its widest layer and under long dead bounds
   serve    slot layout (the CLI default), then paged: the engine built the
            way `acp-tpu run --tpu-preset qwen2.5-7b --tpu-quantize-weights`
            builds it, prewarmed, behind the real Operator + REST server on
@@ -115,7 +115,7 @@ def phase_kernel(seed: int) -> None:
 
     from agentcontrolplane_tpu.models.llama import PRESETS
     from agentcontrolplane_tpu.engine.kernel_parity import (
-        expert_matmul_parity, latent_walk_parity, make_latent_case, make_paged_case, make_verify_case, page_walk_parity,
+        EXPERT_CASES, expert_matmul_parity, latent_walk_parity, make_latent_case, make_paged_case, make_verify_case, page_walk_parity,
         verify_walk_parity)
 
     c = PRESETS[PRESET]
@@ -154,14 +154,15 @@ def phase_kernel(seed: int) -> None:
                       f"(tolerance {got['tolerance']:.0e}), lanes of {min(lens)}..{max(lens)} rows, "
                       f"{time.monotonic() - t0:.1f}s compile+run")
         check(got["ok"], "kernel", f"verify walk over {name}: parity failed: {got}")
-    t0 = time.monotonic()
-    got = expert_matmul_parity(seed)
-    say("kernel", f"compiled grouped matmul vs ragged_dot (a decode step's 128 rows x 8 choices over 16 of 128 experts, "
-                  f"6144 x 2048: more than one column tile a kernel), an idle expert and dead tiles: "
-                  f"out {got['shape']} finite={got['finite']} max|err| {got['max_abs_err']:.2e} of a largest "
-                  f"{got['largest']:.2f} (tolerance {got['tolerance']:.2e}), pairs by expert {got['pairs_by_expert']}, "
-                  f"{time.monotonic() - t0:.1f}s compile+run")
-    check(got["ok"], "kernel", f"grouped matmul: parity failed: {got}")
+    for name, kw in EXPERT_CASES.items():
+        t0 = time.monotonic()
+        got = expert_matmul_parity(seed, **kw)
+        say("kernel", f"compiled grouped matmul vs ragged_dot ({name}: {kw['tokens']} rows x {kw['k']} choices over "
+                      f"{kw['held']} of {kw['experts']} experts, {kw['hidden']} x {kw['width']}), "
+                      f"{got['live_tiles']} of {got['tiles']} row tiles live, an idle expert among them: "
+                      f"out {got['shape']} finite={got['finite']} max|err| {got['max_abs_err']:.2e} of a largest "
+                      f"{got['largest']:.2f} (tolerance {got['tolerance']:.2e}), {time.monotonic() - t0:.1f}s compile+run")
+        check(got["ok"], "kernel", f"grouped matmul, {name}: parity failed: {got}")
 
 
 # -- serve -------------------------------------------------------------------

@@ -555,9 +555,10 @@ def test_no_decode_block_stages_a_layers_wq_or_wk_in_fast_memory(v5e, case):
 # -- the grouped expert matmul and the model that runs it ---------------------
 
 
-def _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers) -> str:
+def _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers, gated=True) -> str:
     """`ops.moe.routed_experts` with its kernels, `Eh` of `E` experts held out
-    of a stack of `layers` layers' experts, compiled for one described chip."""
+    of a stack of `layers` layers' experts, compiled for one described chip
+    (`gated` False: experts of two matrices, `w2 act(w1 .)`)."""
     from agentcontrolplane_tpu.ops.moe import routed_experts
 
     one_chip = SingleDeviceSharding(v5e[0])
@@ -565,74 +566,83 @@ def _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers) -> str:
     bf16 = jnp.bfloat16
 
     def layer(x, router, bias, w1, w3, w2, index):
-        return routed_experts(x, router, w1, w3, w2, k, held=tuple(range(Eh)), score="sigmoid", bias=bias,
-                              kernel=True, expert_base=index * Eh)
+        return routed_experts(x, router, w1, w3 if gated else None, w2, k, held=tuple(range(Eh)), score="sigmoid",
+                              bias=bias, kernel=True, expert_base=index * Eh)
 
     args = [sds((tokens, D), bf16), sds((D, E), bf16), sds((E,), jnp.float32), sds((layers * Eh, D, F), bf16),
             sds((layers * Eh, D, F), bf16), sds((layers * Eh, F, D), bf16), sds((), jnp.int32)]
     return jax.jit(layer).lower(*args).compile().as_text()
 
 
-# (id, tokens, k, experts held, E, D, F, layers): the four expert cells' layer at a decode step's rows (`kexaone`'s
-# two rows a lane) and at a prefill's (`models/mellum.py MOE_CHUNK` tokens a call; `kexaone`'s 3,072-token bucket whole)
+# (id, tokens, k, experts held, E, D, F, layers): the five expert cells' layer at a decode step's rows (`kexaone`'s
+# two rows a lane) and at a prefill's (`models/mellum.py MOE_CHUNK` tokens a call; `kexaone`'s 3,072-token bucket whole);
+# `nemotron3s`'s experts are latent and ungated (D the latent width)
 _EXPERT_LAYERS = [
     ("lfm2-decode-32-lanes", 32, 4, 8, 64, 2048, 1536, 38), ("lfm2-prefill-8x512", 4096, 4, 8, 64, 2048, 1536, 38),
     ("mellum2-decode-32-lanes", 32, 8, 16, 64, 2304, 896, 28), ("mellum2-prefill-2048", 2048, 8, 16, 64, 2304, 896, 28),
     ("kanana2-decode-16-lanes", 16, 6, 8, 128, 2048, 768, 47), ("kanana2-prefill-2048", 2048, 6, 8, 128, 2048, 768, 47),
     ("kexaone-decode-64-lanes-2-rows", 128, 8, 16, 128, 6144, 2048, 4), ("kexaone-prefill-3072", 3072, 8, 16, 128, 6144, 2048, 4),
+    ("nemotron3s-decode-128-lanes", 128, 22, 64, 512, 1024, 2688, 5), ("nemotron3s-prefill-2048", 2048, 22, 64, 512, 1024, 2688, 5),
 ]
 
 
 def _kernel_vmem(text: str, kernel: str = "moe_gmm") -> list:
     """(asked, used) scoped VMEM bytes of each `tpu_custom_call` of a compiled
-    program whose name begins with `kernel`, in the program's order; `used`
-    less what the compiler itself keeps live under the kernel (the offset it
-    places the kernel's share at)."""
+    program whose name begins with `kernel`, in the program's order (`asked`
+    None where the call states no limit); `used` less what the compiler itself
+    keeps live under the kernel (the offset it places the kernel's share at)."""
     import re
 
-    sized = r'\[\{"memory_space":"1","offset":"(\d+)","size":"(\d+)"\}\]'
-    found = re.findall(rf'%{kernel}\S* = [^\n]*?custom_call_target="tpu_custom_call"[^\n]*?"scoped_memory_configs":{sized}'
-                       rf'[^\n]*?"used_scoped_memory_configs":{sized}', text)
-    return [(int(asked), int(used) - int(base)) for base, asked, _, used in found]
+    sized = r'\{"memory_space":"1","offset":"(\d+)","size":"(\d+)"\}'
+    found = re.findall(rf'%{kernel}\S* = [^\n]*?custom_call_target="tpu_custom_call"[^\n]*?"scoped_memory_configs":\[(?:{sized})?\]'
+                       rf'[^\n]*?"used_scoped_memory_configs":\[{sized}\]', text)
+    return [(int(asked) if asked else None, int(used) - int(base or 0)) for base, asked, _, used in found]
 
 
 @pytest.mark.parametrize("case", _EXPERT_LAYERS, ids=lambda c: c[0])
 def test_routed_expert_layer_compiles_with_its_kernel_for_described_v5e(v5e, case):
     """`ops.moe.routed_experts` at each expert cell's widths, its share of
     the experts held out of a stack of the layers' experts: route, the rows'
-    plan and the `moe_gmm` kernels, at a decode step's rows and at a
-    prefill's. Each kernel asks for the VMEM `tile_plan` reckons for its
-    column tile (no more than a kernel gets unasked, except the gate and up
-    of `kexaone` and `mellum2`, whose tile the default would hold under 512
-    columns) and the chip's compiler places what it needs inside it."""
+    plan and the two `moe_gmm` kernels, at a decode step's rows and at a
+    prefill's. Each kernel states what it holds and 2 MiB, never over the
+    16 MiB a kernel gets unasked (a claim over that is taken from the
+    compiler's own plan: PR 53), and the chip's compiler places the ring
+    `chunk_plan` reckons, a run's rows and its own scratch inside it."""
+    import re
+
     from agentcontrolplane_tpu.ops.moe import row_tile
-    from agentcontrolplane_tpu.ops.pallas.moe_gmm import tile_plan
+    from agentcontrolplane_tpu.ops.pallas.moe_gmm import _DEFAULT_VMEM_BYTES, chunk_plan, vmem_bytes
 
     _, tokens, k, Eh, E, D, F, layers = case
-    text = _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers)
-    assert text.count("tpu_custom_call") == 2, "gate-and-up and down: two grouped matmuls"
+    gated = E != 512
+    text = _compiled_expert_layer(v5e, tokens, k, Eh, E, D, F, layers, gated)
+    assert text.count("tpu_custom_call") == len(re.findall(r"%moe_gmm\S* = ", text)) == 2, "gate-and-up (or the one matrix in) and down"
     assert f"bf16[{Eh},{D},{F}]" not in text, "a layer's experts were sliced out of the stack (a copy a call)"
-    tm = row_tile(tokens * k)
-    (up, up_limit), (down, down_limit) = tile_plan(D, F, 2, 2, tm), tile_plan(F, D, 1, 2, tm)
-    assert (F // up, D // down) == {6144: (4, 4), 2304: (1, 1), 2048: (2 if F == 1536 else 1, 1)}[D]
-    assert (up_limit > 16 << 20) == (D in (6144, 2304)) and down_limit <= 16 << 20, "who claims over the default"
+    tm = row_tile(tokens * k, E)
+    kernels = ((1 + gated, D, *chunk_plan(D, F, 1 + gated, 2, tm)), (1, F, *chunk_plan(F, D, 1, 2, tm)))
     vmem = _kernel_vmem(text)
-    assert [asked for asked, _ in vmem] == [up_limit, down_limit], "each kernel states the limit its plan reckons"
-    assert all(2 * weights * K * tn * 2 <= used <= asked for (asked, used), (weights, K, tn) in zip(
-        vmem, ((2, D, up), (1, F, down)))), f"the weight blocks twice over, inside the limit: {vmem}"
+    assert len(vmem) == 2
+    for (asked, used), (weights, K, chunk, depth) in zip(vmem, kernels):
+        ring = depth * weights * K * chunk * 2
+        assert asked == vmem_bytes(K, weights, 2, tm, chunk, depth) <= _DEFAULT_VMEM_BYTES, "what the call holds and 2 MiB"
+        assert ring < used <= asked, f"{used} bytes of scoped VMEM for a ring of {ring}, {asked} asked"
 
 
-def test_the_widest_column_tile_is_refused_under_the_vmem_a_kernel_gets_unasked(v5e, monkeypatch):
-    """Why the call states its limit: `kexaone`'s gate and up at a tile of
-    512 columns holds 25 MB of weight blocks, and under the 16 MiB of scoped
-    VMEM a kernel gets by default the chip's compiler refuses it (PR 51 met
-    this and narrowed the tile to 128)."""
+def test_a_ring_over_the_vmem_a_kernel_gets_unasked_is_refused_by_the_compiler(v5e, monkeypatch):
+    """Why the plan stops where it does: `kexaone`'s gate and up in units of
+    512 columns is a ring of 25 MB, and under the 16 MiB of scoped VMEM a
+    kernel gets by default the chip's compiler refuses it (PR 51 met this
+    with the grid's blocks; PR 53 claimed a limit for them; the stream's
+    units are 128 columns there and claim nothing)."""
     from agentcontrolplane_tpu.ops.pallas import moe_gmm
 
-    plan = moe_gmm.tile_plan
-    monkeypatch.setattr(moe_gmm, "tile_plan", lambda *a: (plan(*a)[0], 16 << 20))
-    with pytest.raises(Exception, match="vmem"):
-        _compiled_expert_layer(v5e, 128, 8, 16, 128, 6144, 2048, 4)
+    monkeypatch.setattr(moe_gmm, "chunk_plan", lambda *a: (512, 2))
+    moe_gmm._stream.cache_clear()  # a geometry's call is built once a process
+    try:
+        with pytest.raises(Exception, match="vmem"):
+            _compiled_expert_layer(v5e, 128, 8, 16, 128, 6144, 2048, 4)
+    finally:
+        moe_gmm._stream.cache_clear()
 
 
 # (name, tokens, k, experts held, E, D, F, layers, entry ops that run at most): a decode step's rows in the three
@@ -641,8 +651,9 @@ _EXPERT_ROWS = [
     ("lfm2-32x4-8-of-64", 32, 4, 8, 64, 2048, 1536, 38, 56),
     ("mellum2-32x8-16-of-64", 32, 8, 16, 64, 2304, 896, 28, 61),
     ("kanana2-16x6-8-of-128", 16, 6, 8, 128, 2048, 768, 47, 49),
-    # 78 since PR 53 (71 before): beside the kernels' wider tiles the compiler prefetches the combine's weights
-    # `f32[4096,4]` as four slices of 16 KB where it made one copy (four `slice-start` / `slice-done` and their join)
+    # 78 since PR 53 (71 before): beside kernels that state their VMEM the compiler prefetches the combine's weights
+    # `f32[4096,4]` as four slices of 16 KB where it made one copy (four `slice-start` / `slice-done` and their join); PR
+    # 55's stream states what it holds (12.3 and 12.9 MB here) and the count stays (71 under kernels that state nothing)
     ("lfm2-prefill-4096x4", 4096, 4, 8, 64, 2048, 1536, 38, 78),
 ]
 _NOT_RUN = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
@@ -699,6 +710,37 @@ def _kernels_by_body(compiled) -> list:
     comps = _computations(compiled.as_text())
     return sorted(n for n in (sum("tpu_custom_call" in rest for _, _, op, rest in _ops(lines) if op == "custom-call")
                               for lines in comps.values()) if n)
+
+
+@pytest.mark.parametrize("batch,tokens", [(1, 256), (8, 512)])
+def test_lfm2_prefill_compiles_under_the_engines_own_sampler(v5e, monkeypatch, batch, tokens):
+    """The ENGINE's prefill program (`prefill_and_sample`: the model's
+    prefill through `models.programs`, then `sample_lanes`) at the cell's
+    one row of 256 tokens and at its widest batch. Under `moe_gmm` kernels
+    that claimed any VMEM over the default the chip's compiler itself died
+    on the first of them (SIGSEGV in its memory-space repacker, PR 53),
+    while the model's prefill alone compiled at every shape: a kernel's
+    rehearsal is every program that holds it, wrappers included. No kernel
+    of the program states a limit over the default."""
+    from agentcontrolplane_tpu import models
+    from agentcontrolplane_tpu.engine import engine
+    from agentcontrolplane_tpu.engine.lanes import PREFILL
+
+    lfm2, c, params, cache, vec = _lfm2(v5e, monkeypatch)
+    prog = models.programs(c)
+
+    def prefill_and_sample(params, pages, tokens, lanes, page_ids, key, table, min_close):
+        ln = PREFILL.unpack(lanes)
+        pages, logits = prog.prefill_paged_batch(params, pages, tokens, ln["lengths"], (page_ids, (ln["slots"], ln["snap_at"])), c)
+        toks, states = engine.sample_lanes(logits, key, ln, table, min_close)
+        return pages, toks, states
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    text = jax.jit(prefill_and_sample, donate_argnums=(1,)).lower(
+        params, cache, vec(batch, tokens), vec(len(PREFILL.kinds), batch), vec(batch, tokens // PAGE),
+        vec(*key.shape, dt=key.dtype), vec(1, 256), vec(1)).compile().as_text()
+    vmem = _kernel_vmem(text)
+    assert vmem and all(used <= asked <= 16 << 20 for asked, used in vmem), vmem
 
 
 def test_lfm2_decode_step_compiles_and_moves_no_pool_it_does_not_read(v5e, monkeypatch):
@@ -1041,6 +1083,10 @@ def test_mellum_decode_block_walks_two_caches_and_copies_neither(v5e, monkeypatc
         assert not re.search(rf"= {pool}\S* copy\(", text), f"a copy of the whole pool {pool}"
     assert f"bf16[{_MELLUM_PAGES},{PAGE},512]" not in text and f"bf16[{(S + 1) * ring},{PAGE},512]" not in text, (
         "one layer of a pool as a value of its own")
+    # what the compiler stages in VMEM under the kernels stays there: the window layers' stack of k (or v) weights,
+    # 49.5 MB, went to HBM under grouped matmuls that stated no VMEM limit and so were given the whole default (PR 55)
+    staged = re.findall(r"= (bf16\[21,2304,512\]\S*) copy\(", text)
+    assert all("S(1)" in layout for layout in staged), f"the window layers' k/v weights staged outside VMEM: {staged}"
     assert 0.75 * 16e9 < _resident(compiled) < 14e9, f"{_resident(compiled) / 1e9:.2f} GB"
 
 

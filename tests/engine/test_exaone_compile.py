@@ -66,15 +66,12 @@ def test_exaone_decode_block_verifies_two_rows_and_copies_no_pool(v5e, monkeypat
     for walk in ("paged_page_walk", "paged_window_walk"):
         assert re.search(rf"%{walk}\S* = \(f32\[{S},8,16,128\]", text), f"{walk} is not {S} lanes of 16 rows a KV head"
     assert f"f32[{2 * S},8,8,128]" not in text
-    # the grouped matmuls: gate and up under the limit its plan states (four tiles of 512 columns, 25 MB of weight
-    # blocks, which the 16 MiB a kernel gets unasked would refuse), down within that default (four tiles of 1,536); and
-    # what the compiler stages in VMEM under them stays there: a layer's q weights `bf16[1,6144,8192]`, 96 MiB, went
-    # back to HBM under kernels that claimed 55 MB, and took `attn_qkv` and `attn_out` 0.6 ms a step with them (PR 53)
-    from agentcontrolplane_tpu.ops.pallas.moe_gmm import tile_plan
-
-    asked = {limit for limit, _ in _kernel_vmem(text)}
-    assert asked == {tile_plan(6144, 2048, 2, 2, 16)[1], tile_plan(2048, 6144, 1, 2, 16)[1]}, asked
-    assert min(asked) == 16 << 20 < max(asked) <= 32 << 20
+    # the grouped matmuls: none states a VMEM limit over the 16 MiB a kernel gets unasked (each what it holds and 2
+    # MiB); and what the compiler stages in VMEM under them stays there: a layer's q weights `bf16[1,6144,8192]`,
+    # 96 MiB, went back to HBM under kernels that claimed 55 MB, and took `attn_qkv` and `attn_out` 0.6 ms a step with
+    # them (PR 53)
+    vmem = _kernel_vmem(text)
+    assert vmem and all(used <= asked <= 16 << 20 for asked, used in vmem), vmem
     staged = re.findall(r"= (bf16\[1,6144,8192\]\S*) fusion\(", text)
     assert all("S(1)" in layout for layout in staged), f"a layer's q weights staged outside VMEM: {staged}"
     pools = sum(cache[name].size * 2 for name in ("k", "v", "wk", "wv"))

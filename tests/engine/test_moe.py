@@ -425,57 +425,106 @@ def test_routed_experts_is_bit_for_bit_what_the_sorted_plan_gave(name, path):
     assert hashlib.sha256(np.asarray(y).tobytes() + np.asarray(stats).tobytes()).hexdigest()[:16] == digest
 
 
-# -- the grouped matmul's column tile (PR 53) ----------------------------------
+# -- the grouped matmul as a stream (PR 55) -------------------------------------
 
-# (id, hidden, expert width, row tile, gate-and-up's tile, down's tile): the four expert cells' two kernels at a
-# decode step's rows and a prefill's, and CPU-test widths with no whole-lane-tile divisor (the width itself)
-_TILES = [
-    ("kexaone-decode", 6144, 2048, 16, 512, 1536), ("kexaone-prefill", 6144, 2048, 128, 512, 1536),
-    ("mellum2-decode", 2304, 896, 16, 896, 2304), ("mellum2-prefill", 2304, 896, 128, 896, 2304),
-    ("lfm2-decode", 2048, 1536, 16, 768, 2048), ("lfm2-prefill", 2048, 1536, 128, 768, 2048),
-    ("kanana2-decode", 2048, 768, 16, 768, 2048), ("kanana2-prefill", 2048, 768, 128, 768, 2048),
-    ("cpu-tiny", 64, 96, 16, 96, 64), ("cpu-odd", 200, 320, 16, 320, 200),
+# (id, K, N, weights, row tile, (chunk, depth)): the five expert cells' two kernels at a decode step's rows and a
+# prefill's, and CPU-test widths with no whole-lane-tile divisor (the width itself, one chunk)
+_PLANS = [
+    ("kexaone-up-decode", 6144, 2048, 2, 16, (128, 3)), ("kexaone-down-decode", 2048, 6144, 1, 16, (1536, 2)),
+    ("kexaone-up-prefill", 6144, 2048, 2, 128, (128, 3)), ("kexaone-down-prefill", 2048, 6144, 1, 128, (1024, 2)),
+    ("mellum2-up-decode", 2304, 896, 2, 16, (128, 3)), ("mellum2-down-decode", 896, 2304, 1, 16, (2304, 3)),
+    ("mellum2-up-prefill", 2304, 896, 2, 128, (128, 3)), ("mellum2-down-prefill", 896, 2304, 1, 128, (2304, 2)),
+    ("lfm2-up-decode", 2048, 1536, 2, 16, (768, 2)), ("lfm2-down-decode", 1536, 2048, 1, 16, (2048, 2)),
+    ("lfm2-up-prefill", 2048, 1536, 2, 128, (512, 3)), ("lfm2-down-prefill", 1536, 2048, 1, 128, (1024, 3)),
+    ("kanana2-up-decode", 2048, 768, 2, 16, (768, 2)), ("kanana2-down-decode", 768, 2048, 1, 16, (2048, 3)),
+    ("kanana2-up-prefill", 2048, 768, 2, 128, (384, 3)), ("kanana2-down-prefill", 768, 2048, 1, 128, (2048, 3)),
+    ("nemotron3s-up-decode", 1024, 2688, 1, 16, (2688, 2)), ("nemotron3s-down-decode", 2688, 1024, 1, 16, (1024, 2)),
+    ("nemotron3s-up-prefill", 1024, 2688, 1, 128, (896, 3)), ("nemotron3s-down-prefill", 2688, 1024, 1, 128, (1024, 2)),
+    ("cpu-tiny", 64, 96, 2, 16, (96, 3)), ("cpu-odd", 200, 320, 1, 16, (320, 3)),
 ]
 
 
-@pytest.mark.parametrize("case", _TILES, ids=lambda c: c[0])
-def test_the_column_tile_is_as_wide_as_the_stated_vmem_holds(case):
-    """`tile_plan`, the rule alone: the widest whole-lane-tile divisor of
-    the width whose weight blocks fit twice over in three quarters of the
-    VMEM a kernel gets unasked, the call then claiming no more than that
-    default; and only where such a tile is under 512 columns (`kexaone`'s
-    and `mellum2`'s gate and up) the same under a stated limit of at most a
-    quarter of the core. The limit covers every block twice."""
+@pytest.mark.parametrize("case", _PLANS, ids=lambda c: c[0])
+def test_the_chunk_is_as_wide_as_two_units_fit_beside_a_runs_rows(case):
+    """`chunk_plan`, the rule alone: the widest whole-lane-tile divisor of
+    the width of which two units (a chunk of every weight, `K` whole) fit
+    the VMEM a kernel gets unasked beside a run's rows, two output tiles,
+    the float32 products and 2 MiB for the compiler; a third unit where
+    that fits too. What a call states (`vmem_bytes`) is never over that
+    default."""
     from agentcontrolplane_tpu.ops.pallas import moe_gmm
 
-    _, D, F, tm, up, down = case
-    default, most = moe_gmm._DEFAULT_VMEM_BYTES, moe_gmm._VMEM_LIMIT_BYTES
-    assert (default, most, moe_gmm._MIN_COLS) == (16 << 20, 32 << 20, 512)
-    for K, N, weights, want in ((D, F, 2, up), (F, D, 1, down)):
-        tn, limit = moe_gmm.tile_plan(K, N, weights, 2, tm)
-        assert tn == want and N % tn == 0 and (tn % 128 == 0 or tn == N)
-        held = lambda t: 2 * weights * K * t * 2  # noqa: E731 (a tile's weight blocks, twice)
-        claims = held(tn) > default * 3 // 4
-        assert 2 * (weights * K * tn + tm * K + tm * tn) * 2 < limit <= (most if claims else default)
-        assert held(tn) <= (most if claims else default) * 3 // 4
-        wider = [t for t in range(tn + 128, N + 1, 128) if N % t == 0]
-        if claims:  # only because the default held under 512 columns, and then as wide as the quarter holds
-            assert all(held(t) > default * 3 // 4 for t in range(512, tn + 1, 128) if N % t == 0)
-            assert all(held(t) > most * 3 // 4 for t in wider), "a wider tile fits the quarter"
-        else:
-            assert all(held(t) > default * 3 // 4 for t in wider), "a wider tile fits the default"
-        assert moe_gmm.tile_plan(K, N, weights, 2, tm, 128)[0] == (128 if N % 128 == 0 else N), "a caller's cap holds"
+    _, K, N, weights, tm, want = case
+    default = moe_gmm._DEFAULT_VMEM_BYTES
+    assert (default, moe_gmm._COMPILER_BYTES, moe_gmm._RUN_ROWS) == (16 << 20, 2 << 20, 256)
+    chunk, depth = moe_gmm.chunk_plan(K, N, weights, 2, tm)
+    assert (chunk, depth) == want and N % chunk == 0 and (chunk % 128 == 0 or chunk == N) and 2 <= depth <= 3
+    stated = lambda c, d: moe_gmm.vmem_bytes(K, weights, 2, tm, c, d)  # noqa: E731
+    assert stated(chunk, depth) <= default and (depth == 3 or stated(chunk, 3) > default)
+    rows = moe_gmm.held_tiles(tm)
+    assert rows == {16: 16, 128: 2}[tm], "a run's rows: 256 of them"
+    assert stated(chunk, depth) == depth * weights * K * chunk * 2 + rows * tm * K * 2 + (4 + 4 * (weights + 1)) * tm * chunk + (2 << 20)
+    assert all(stated(c, 2) > default for c in range(chunk + 128, N + 1, 128) if N % c == 0), "a wider chunk fits"
+    assert moe_gmm.chunk_plan(K, N, weights, 2, tm, 128)[0] == (128 if N % 128 == 0 else N), "a caller's cap holds"
+
+
+# (id, K, N, row tile, tiles of each held expert in turn (0: idle), dead tiles after them, the cap on the chunk): the
+# five cells' two shapes cut small, and what a stream can get wrong
+_STREAMS = [
+    ("kexaone-one-tile-runs-under-a-long-dead-bound", 384, 256, 16, (1, 1, 2, 1), 15, 128),
+    ("mellum2-seven-chunks", 96, 896, 16, (1, 2, 1, 1), 4, 128),
+    ("lfm2-three-chunks-every-tile-live", 128, 384, 16, (2, 1, 1, 3), 0, 128),
+    ("kanana2-one-chunk", 128, 128, 16, (1, 1, 0, 1), 3, None),
+    ("nemotron3s-one-chunk-many-experts", 64, 256, 16, (1, 0, 1, 1, 0, 0, 1, 1), 9, None),
+    ("no-tile-live", 128, 256, 16, (0, 0, 0), 5, 128),
+    ("one-tile-live", 128, 256, 16, (0, 1, 0), 5, 128),
+    ("an-idle-expert-between-two-live", 128, 256, 16, (2, 0, 1), 2, 128),
+    ("a-run-of-three-tiles-of-128-rows", 64, 256, 128, (3, 1), 2, 128),
+    ("a-run-longer-than-the-rows-held", 32, 256, 16, (1, 19, 2), 1, 128),
+    ("a-width-with-no-lane-tile-divisor", 40, 96, 16, (1, 2), 1, None),
+]
+
+
+@pytest.mark.parametrize("entry", ["gmm", "gmm_swiglu", "gmm_act"])
+@pytest.mark.parametrize("case", _STREAMS, ids=lambda c: c[0])
+def test_the_stream_gives_ragged_dots_bits_on_every_live_row(case, entry):
+    """The kernel walks the live tiles as one stream of (run, column chunk)
+    units, the second layer of a stack of two (`tile_expert` carries the
+    layer's base): against `ragged_dot` over the same groups, bit for bit
+    on every row of a live tile. The operands are small integers, so each
+    sum is exact in any order: what this holds is the stream's indexing
+    (runs, chunks, the ring, the rows kept and refilled), not the host's
+    rounding. Rows past the live tiles are not written and are compared
+    with nothing."""
+    from agentcontrolplane_tpu.ops.pallas import moe_gmm
+
+    _, K, N, tm, tiles, dead, tn = case
+    E, live = len(tiles), sum(tiles)
+    rng = np.random.default_rng(55)
+    M = (live + dead) * tm
+    experts = np.repeat(np.arange(E), tiles)
+    tile_expert = jnp.asarray(np.concatenate([experts, np.full(dead, experts[-1] if live else E - 1)]) + E, jnp.int32)
+    n_live = jnp.asarray([live], jnp.int32)
+    x = jnp.asarray(rng.integers(-2, 3, (M, K)), jnp.bfloat16)
+    ws = [jnp.asarray(rng.integers(-1, 2, (2 * E, K, N)), jnp.bfloat16) for _ in range(1 + (entry == "gmm_swiglu"))]
+    act = lambda v: jnp.maximum(v, 0)  # noqa: E731 (exact, where silu is the host's exp)
+    kw = {} if entry == "gmm" else {"act": act}
+    got = np.asarray(jax.jit(lambda *a: getattr(moe_gmm, entry)(*a, tile_expert, n_live, tm, tn=tn, interpret=True, **kw))(x, *ws))
+    chunk, _ = moe_gmm.chunk_plan(K, N, len(ws), 2, tm, tn)
+    assert got.shape == (M, N) and N // chunk == {384: 3, 896: 7}.get(N, 2 if tn else 1)
+    groups = jnp.asarray(np.concatenate([np.asarray(tiles) * tm, [dead * tm]]), jnp.int32)  # the dead rows: a group of their own
+    dot = lambda w: jax.lax.ragged_dot(  # noqa: E731
+        x, jnp.concatenate([w[E:], w[:1]]), groups, preferred_element_type=jnp.float32)
+    want = {"gmm": lambda: dot(ws[0]), "gmm_act": lambda: act(dot(ws[0])), "gmm_swiglu": lambda: act(dot(ws[0])) * dot(ws[1])}[entry]()
+    np.testing.assert_array_equal(got[: live * tm], np.asarray(want.astype(jnp.bfloat16))[: live * tm])
+    assert not live or np.abs(got[: live * tm].astype(np.float32)).max() > 4
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("entry", ["gmm", "gmm_swiglu"])
-def test_a_narrow_and_the_widest_column_tile_give_the_same_bits(entry, dtype):
-    """The contraction is whole in one block at any column tile: three
-    tiles of 128 and one of 384, dead tiles and an expert no row chose among
-    them, agree bit for bit, and with `ragged_dot` over the same groups.
-    The operands are small integers, so each sum is exact in any order: the
-    CPU's matmul blocks a 384-wide product otherwise than a 128-wide one
-    and what this holds is the tiles' indexing, not the host's rounding."""
+def test_the_stream_through_the_rows_plan_with_a_narrow_and_the_widest_chunk(dtype):
+    """Through `group_rows`' own plan, dead tiles and an expert no row chose
+    among them: three chunks of 128 and one of 384 agree bit for bit on the
+    live rows, in float32 (the full-precision contract) and bfloat16."""
     from agentcontrolplane_tpu.ops.moe import group_rows
     from agentcontrolplane_tpu.ops.pallas import moe_gmm
 
@@ -487,17 +536,12 @@ def test_a_narrow_and_the_widest_column_tile_give_the_same_bits(entry, dtype):
     live = int(n_live[0]) * tm
     assert int(counts[1]) == 0 and live < M, "the case holds an idle expert and dead tiles"
     x = jnp.asarray(rng.integers(-2, 3, (pairs // k, K)), dtype)[row_token]
-    ws = [jnp.asarray(rng.integers(-1, 2, (E, K, N)), dtype) for _ in range(1 + (entry == "gmm_swiglu"))]
-    act = lambda v: jnp.maximum(v, 0)  # noqa: E731 (exact, where silu is the host's exp)
-    run = lambda tn: np.asarray(jax.jit(lambda *a: getattr(moe_gmm, entry)(  # noqa: E731
-        *a, tile_expert, n_live, tm, tn=tn, interpret=True, **({"act": act} if len(ws) == 2 else {})))(x, *ws))
+    w = jnp.asarray(rng.integers(-1, 2, (E, K, N)), dtype)
+    run = lambda tn: np.asarray(jax.jit(lambda *a: moe_gmm.gmm(*a, tile_expert, n_live, tm, tn=tn, interpret=True))(x, w))  # noqa: E731
     narrow, widest = run(128), run(None)
-    assert moe_gmm.tile_plan(K, N, len(ws), x.dtype.itemsize, tm)[0] == N
-    assert narrow.tobytes() == widest.tobytes()
-    padded = -(-counts // tm) * tm
-    dot = lambda w: jax.lax.ragged_dot(x, w, padded, preferred_element_type=jnp.float32)  # noqa: E731
-    want = dot(ws[0]) if entry == "gmm" else act(dot(ws[0])) * dot(ws[1])
-    assert not widest[live:].any() and np.abs(widest[:live].astype(np.float32)).max() > 8
+    assert moe_gmm.chunk_plan(K, N, 1, x.dtype.itemsize, tm)[0] == N
+    assert narrow[:live].tobytes() == widest[:live].tobytes()
+    want = jax.lax.ragged_dot(x, w, -(-counts // tm) * tm, preferred_element_type=jnp.float32)
     np.testing.assert_array_equal(widest[:live], np.asarray(want.astype(dtype))[:live])
 
 

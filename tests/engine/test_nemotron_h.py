@@ -181,13 +181,14 @@ def test_the_plan_is_chosen_by_the_rows_an_expert_can_expect():
     assert moe.row_tile(373 * 22, 512) == 128 and moe.row_tile(512 * 22, 512) == 128
 
 
-def test_the_expert_matrices_take_one_column_tile_under_the_vmem_a_kernel_gets_unasked():
-    from agentcontrolplane_tpu.ops.pallas.moe_gmm import _DEFAULT_VMEM_BYTES, tile_plan
+def test_a_decode_steps_expert_matrices_are_one_unit_each_under_the_vmem_a_kernel_gets_unasked():
+    from agentcontrolplane_tpu.ops.pallas.moe_gmm import _DEFAULT_VMEM_BYTES, chunk_plan, vmem_bytes
 
     for K, Ncols in ((1024, 2688), (2688, 1024)):
+        chunk, depth = chunk_plan(K, Ncols, 1, 2, 16)
+        assert chunk == Ncols and depth == 2, "a whole expert's matrix a fetch, the next one's under its product"
         for tm in (16, 128):
-            tile, limit = tile_plan(K, Ncols, 1, 2, tm)
-            assert tile == Ncols and limit <= _DEFAULT_VMEM_BYTES, (K, Ncols, tm, tile, limit)
+            assert vmem_bytes(K, 1, 2, tm, *chunk_plan(K, Ncols, 1, 2, tm)) <= _DEFAULT_VMEM_BYTES
 
 
 # -- the program against the plain reference ------------------------------------------

@@ -115,16 +115,22 @@ def test_compiled_verify_walk_matches_reference(tpu, window):
     assert got["ok"] and got["shape"] == (8, 2, 64, 128), got
 
 
-def test_compiled_grouped_matmul_matches_ragged_dot(tpu):
-    """The routed experts' layer through the `moe_gmm` kernels, COMPILED at
-    the widest geometry served (`exaone`'s decode step: 1,280 rows in tiles
-    of 16, 6,144 and 2,048 wide, so four column tiles a kernel, gate and
-    up under the VMEM limit the call states), against `ragged_dot`
-    over the same plan: an idle expert and dead tiles among it."""
-    from agentcontrolplane_tpu.engine.kernel_parity import expert_matmul_parity
+@pytest.mark.parametrize("case", ["widest", "kexaone-decode", "nemotron3s-decode", "mellum2-prefill"])  # kernel_parity.EXPERT_CASES
+def test_compiled_grouped_matmul_matches_ragged_dot(tpu, case):
+    """The routed experts' layer through the `moe_gmm` kernels, COMPILED,
+    against `ragged_dot` over the same plan, an idle expert and dead tiles
+    among it: at the widest geometry served with most tiles live (`exaone`'s
+    decode step: 1,280 rows in tiles of 16, 6,144 and 2,048 wide, sixteen
+    chunks an expert's gate and up), and as a stream under a long dead bound:
+    `kexaone`'s and `nemotron3s`'s decode steps with the share of the pairs
+    an unsteered router lands here (about 17 of 80 and 64 of 240 tiles
+    live), and a prefill's runs of 128-row tiles."""
+    from agentcontrolplane_tpu.engine.kernel_parity import EXPERT_CASES, expert_matmul_parity
 
-    got = expert_matmul_parity(9)
-    assert got["ok"] and got["shape"] == (128, 6144), got
+    kw = EXPERT_CASES[case]
+    got = expert_matmul_parity(9, **kw)
+    assert got["ok"] and got["shape"] == (kw["tokens"], kw["hidden"]), got
+    assert case == "widest" or got["live_tiles"] < got["tiles"] // 2, got
 
 
 def test_engine_slot_and_paged_agree_on_tpu(tpu):
