@@ -821,8 +821,10 @@ def cmd_perf(args) -> int:
     (where device time goes, how much of each dispatch is padding), the
     cold-compile observatory (compiles real traffic paid for after
     prewarm), the goodput/waste ledger (tokens computed vs emitted,
-    waste attributed by cause), and the engine loop's phase table (host
-    ms per cycle by phase)."""
+    waste attributed by cause), the engine loop's phase table (host
+    ms per cycle by phase), and the engine's start: its set-up phases and
+    the costliest first dispatches, each split into trace, lowering,
+    compile or cache load, and first run."""
     with _client(args) as http:
         resp = http.get("/v1/engine/perf")
         if resp.status_code != 200:
@@ -851,7 +853,14 @@ def cmd_perf(args) -> int:
             print(f"SERVING-TIME COLD COMPILES: {cold['serving']} "
                   "(each was a latency stall — widen prewarm coverage)")
             for ev in cold.get("events", []):
-                print(f"  {ev['program']:<34}{ev['wall_s'] * 1e3:>10.1f}ms")
+                if not ev.get("retrace"):
+                    print(f"  {ev['program']:<34}{ev['wall_s'] * 1e3:>10.1f}ms")
+        if cold.get("retraces"):
+            print(f"RETRACES: {cold['retraces']} (a program key dispatched again "
+                  "compiled again: the key holds less than jit's cache keys on)")
+            for ev in cold.get("events", []):
+                if ev.get("retrace"):
+                    print(f"  {ev['program']:<34}{ev['wall_s'] * 1e3:>10.1f}ms")
         phases = doc.get("phases", {})
         cycles = doc.get("cycles", 0)
         busy = sum(p["s"] for name, p in phases.items() if name != "park")
@@ -877,6 +886,25 @@ def cmd_perf(args) -> int:
                     f"{dev if dev is not None else float('nan'):>10.3f}"
                     f"{p['padding_pct']:>7.1f}  {p['real_tokens']}"
                 )
+        setup = doc.get("setup")
+        if setup:
+            # the engine's start: wall seconds by phase (a phase opened in
+            # another is inside it), then the costliest first dispatches
+            print(f"set-up: {setup['programs']} programs first dispatched, "
+                  f"{setup['cache_misses']} missed the compile cache; prewarm "
+                  f"less its first dispatches {setup['prewarm_rest_s']:.2f}s")
+            print(f"{'SET-UP PHASE':<20}{'N':>4}{'s':>10}{'JAX s':>9}{'FIRST DISPATCHES s':>20}")
+            for name, p in setup["phases"].items():
+                print(f"{name:<20}{p['n']:>4}{p['s']:>10.3f}{p['jax_s']:>9.3f}"
+                      f"{p['first_wall_s']:>20.3f}")
+            print(f"{'FIRST DISPATCH ms':<34}{'WALL':>9}{'TRACE':>9}{'LOWER':>9}"
+                  f"{'COMPILE':>9}{'LOAD':>9}{'RUN':>9}  CACHE")
+            costliest = sorted(programs.items(), key=lambda kv: -kv[1]["first_wall_ms"])
+            for key, p in costliest[:5]:
+                cache = {True: "hit", False: "miss", None: "-"}[p["cache_hit"]]
+                print(f"{key:<34}{p['first_wall_ms']:>9.1f}{p['trace_ms']:>9.1f}"
+                      f"{p['lower_ms']:>9.1f}{p['compile_ms']:>9.1f}{p['load_ms']:>9.1f}"
+                      f"{p['run_ms']:>9.1f}  {cache}")
         return 0
 
 

@@ -91,19 +91,54 @@ STALL_MULT = 8.0
 STALL_MIN_S = 0.25
 
 
-def _in_phase(name: str):
+def _in_phase(name: str, opener: str = "phase"):
     """Run an engine-thread method inside the profiler's ``name`` phase
     (observability/profiler.py): for methods that are one phase whole."""
 
     def wrap(fn):
         @functools.wraps(fn)
         def inner(self, *args, **kwargs):
-            with self.profiler.phase(name):
+            with getattr(self.profiler, opener)(name):
                 return fn(self, *args, **kwargs)
 
         return inner
 
     return wrap
+
+
+def _in_setup(name: str):
+    """The same for a method of the engine's start, inside the ``name``
+    set-up phase (``DispatchProfiler.setup``: the caller's thread, not the
+    engine's)."""
+    return _in_phase(name, opener="setup")
+
+
+def _init_phase(init):
+    """``Engine.__init__`` whole inside the ``init`` set-up phase. The two
+    recorders are made first, for that and because the constructor's first
+    upload counts on them (``_put``):
+
+    - the flight recorder (observability/flight.py): ring-buffer record of
+      every scheduler decision, always on (ACP_FLIGHT=0 disables for bench
+      A/B). Public attribute: the REST/CLI introspection surface reads it
+      via its own cross-thread-safe methods.
+    - the compute efficiency observatory (observability/profiler.py): per-
+      dispatch program telemetry, cold-compile tracking, goodput/waste
+      ledger, set-up phases. Public attribute likewise. ACP_PROF=0 reduces
+      every hook to one bool branch (bench A/B), and the hooks never touch
+      dispatch inputs/outputs — profiler on/off is byte-identical."""
+
+    @functools.wraps(init)
+    def inner(self, *args, **kwargs):
+        from ..observability.flight import FlightRecorder
+        from ..observability.profiler import DispatchProfiler
+
+        self.flight = FlightRecorder()
+        self.profiler = DispatchProfiler(flight=self.flight)
+        with self.profiler.setup("init"):
+            init(self, *args, **kwargs)
+
+    return inner
 
 
 class EngineOverloadedError(RuntimeError):
@@ -565,6 +600,7 @@ def make_draft_block(step_fn, stop_toks: tuple, max_ctx: int, block_size: int):
 
 
 class Engine:
+    @_init_phase
     def __init__(
         self,
         config: LlamaConfig | str = "bench-1b",
@@ -847,39 +883,42 @@ class Engine:
         if quantize_weights:
             quantize = "int8"
         self.quantize_kv = bool(quantize_kv)
-        if params is None and quantize == "int8" and tp == 1:
-            # host-side quantized random init: the device-init path below
-            # peaks at the FULL bf16 model + one tensor (16GB for 8B — by
-            # itself a whole v5e chip); this one only ever places int8+scales
-            from .weights import random_quantized_init
+        if params is None or quantize == "int8":
+            # the engine makes or quantizes weights itself: a phase of its own
+            with self.profiler.setup("init.params"):
+                if params is None and quantize == "int8" and tp == 1:
+                    # host-side quantized random init: the device-init path below
+                    # peaks at the FULL bf16 model + one tensor (16GB for 8B — by
+                    # itself a whole v5e chip); this one only ever places int8+scales
+                    from .weights import random_quantized_init
 
-            params = random_quantized_init(config, seed=seed)
-        elif params is None:
-            _init = self._model.init_params
-            abstract = jax.eval_shape(lambda k: _init(config, k), jax.random.key(0))
-            shardings = (
-                # a family that gives no layout of its own over a mesh is
-                # held whole (tp=1 only: it refuses the rest above)
-                jax.tree_util.tree_map(lambda _: self._replicated, abstract)
-                if self._model.shardings is None
-                else self._model.shardings.params(self.mesh, config, abstract)
-            )
-            params = jax.jit(
-                lambda k: _init(config, k), out_shardings=shardings
-            )(jax.random.key(seed))
-        if quantize == "int8":
-            # Quantize per-matrix, dropping each bf16 original as its int8
-            # replacement lands (in-place layer-dict mutation) so peak device
-            # memory is the bf16 params + ONE extra tensor. For big
-            # checkpoints prefer load-time quantization (weights.py
-            # quantize="int8"), which never materializes bf16 at all; already
-            # -quantized leaves are skipped here.
-            from ..ops.quant import QUANTIZABLE, QuantizedTensor, quantize as _q
+                    params = random_quantized_init(config, seed=seed)
+                elif params is None:
+                    _init = self._model.init_params
+                    abstract = jax.eval_shape(lambda k: _init(config, k), jax.random.key(0))
+                    shardings = (
+                        # a family that gives no layout of its own over a mesh is
+                        # held whole (tp=1 only: it refuses the rest above)
+                        jax.tree_util.tree_map(lambda _: self._replicated, abstract)
+                        if self._model.shardings is None
+                        else self._model.shardings.params(self.mesh, config, abstract)
+                    )
+                    params = jax.jit(
+                        lambda k: _init(config, k), out_shardings=shardings
+                    )(jax.random.key(seed))
+                if quantize == "int8":
+                    # Quantize per-matrix, dropping each bf16 original as its int8
+                    # replacement lands (in-place layer-dict mutation) so peak device
+                    # memory is the bf16 params + ONE extra tensor. For big
+                    # checkpoints prefer load-time quantization (weights.py
+                    # quantize="int8"), which never materializes bf16 at all; already
+                    # -quantized leaves are skipped here.
+                    from ..ops.quant import QUANTIZABLE, QuantizedTensor, quantize as _q
 
-            layers = params["layers"]
-            for key in QUANTIZABLE:
-                if not isinstance(layers[key], QuantizedTensor):
-                    layers[key] = jax.jit(_q)(layers[key])
+                    layers = params["layers"]
+                    for key in QUANTIZABLE:
+                        if not isinstance(layers[key], QuantizedTensor):
+                            layers[key] = jax.jit(_q)(layers[key])
         self.quantize = quantize
         self.params = params
         # per-device bytes held by weights (QuantizedTensor leaves flatten
@@ -975,8 +1014,6 @@ class Engine:
                 # a silent perf cliff deserves a first-class signal: count
                 # it and drop a flight breadcrumb so dashboards and dumps
                 # show WHY decode is on the slow path (docs/observability.md).
-                # The flight recorder doesn't exist yet this early in init,
-                # so the event is emitted right after it is constructed.
                 REGISTRY.counter_add(
                     "acp_engine_kernel_fallbacks_total",
                     1.0,
@@ -986,7 +1023,7 @@ class Engine:
                     "why); 0 on a healthy TPU deployment — the quantized "
                     "paged-decode path dispatches the int8 Pallas walk",
                 )
-                self._kernel_fallback_reason = reason
+                self.flight.record("kernel_fallback", kernel="paged_decode", reason=reason)
             self.pages_per_turn = self.turns_in_flight = self.bytes_in_flight = 0
             if self._use_pallas:
                 self.pages_per_turn, self.turns_in_flight, self.bytes_in_flight = walk
@@ -998,31 +1035,6 @@ class Engine:
                 )
         log.info("engine init: params+cache in %.1fs", time.monotonic() - t0)
 
-        # (both recorders are made before the first upload: _put counts)
-        # flight recorder (observability/flight.py): ring-buffer record of
-        # every scheduler decision, always on (ACP_FLIGHT=0 disables for
-        # bench A/B). Public attribute: the REST/CLI introspection surface
-        # reads it via its own cross-thread-safe methods.
-        from ..observability.flight import FlightRecorder
-
-        self.flight = FlightRecorder()
-        if getattr(self, "_kernel_fallback_reason", None):
-            # deferred from the _use_pallas gate (the recorder didn't exist
-            # yet); pairs with acp_engine_kernel_fallbacks_total
-            self.flight.record(
-                "kernel_fallback",
-                kernel="paged_decode",
-                reason=self._kernel_fallback_reason,
-            )
-        # compute efficiency observatory (observability/profiler.py): per-
-        # dispatch program telemetry, cold-compile tracking, goodput/waste
-        # ledger. Public attribute like the flight recorder: REST/CLI read
-        # it via its declared cross-thread methods. ACP_PROF=0 reduces every
-        # hook to one bool branch (bench A/B), and the hooks never touch
-        # dispatch inputs/outputs — profiler on/off is byte-identical.
-        from ..observability.profiler import DispatchProfiler
-
-        self.profiler = DispatchProfiler(flight=self.flight)
         # computed ON device (jit + out_shardings) rather than device_put so
         # the replicated key is valid under multihost meshes too. It is never
         # split on the host: every program mixes its dispatch's counter (a
@@ -1332,6 +1344,7 @@ class Engine:
 
     # -- jitted programs -------------------------------------------------
 
+    @_in_setup("init.programs")
     def _build_jitted(self):
         """Two jitted programs per layout: prefill+first-sample, and the
         K-step decode block (one dispatch advances all slots K tokens,
@@ -1569,10 +1582,14 @@ class Engine:
 
     # -- public API ------------------------------------------------------
 
+    @_in_setup("init.pool")
     def _init_kv_state(self) -> None:
         """(Re)build the device KV cache and host allocator state — shared
         by __init__ and crash recovery (ensure_running) so the restart path
-        can never diverge from fresh construction."""
+        can never diverge from fresh construction. With the profiler on it
+        waits for the pool, so that the ``init.pool`` phase is the pool's
+        whole cost (its init program, then the device filling it) and not
+        its enqueue."""
         self._dev = None
         self._state_dirty = True
         self._tables_dirty = True
@@ -1625,6 +1642,8 @@ class Engine:
         # the cache's depth, off the leaf (a family's pool may be deeper than its weights)
         leaf = self.cache[self._model.page_leaf] if self.kv_layout == "paged" else _a_leaf(self.cache)
         self.cache_layers = int(leaf.shape[0])  # acp: mirror (immutable)
+        if self.profiler.enabled:
+            jax.block_until_ready(self.cache)
 
     def start(self) -> None:
         if self._thread is not None:
@@ -1834,6 +1853,7 @@ class Engine:
         self._queue.put(req)
         return req.future
 
+    @_in_setup("prewarm")
     def prewarm(self, constrained: bool = False) -> None:
         """Compile the jit entries real traffic will hit — a full-width
         burst of short generations with largest-bucket prompts covers the
@@ -1871,8 +1891,9 @@ class Engine:
         # heap is a block-long pause the device sits through (one or two a
         # minute; PERF.md, PR 31). Process-wide: docs/serving-engine.md, "The
         # host's collector".
-        gc.collect()
-        gc.freeze()
+        with self.profiler.setup("prewarm.freeze"):
+            gc.collect()
+            gc.freeze()
         self._gc_frozen = True
         log.info("engine prewarm complete (constrained=%s)", constrained)
 
@@ -1893,6 +1914,7 @@ class Engine:
             "at serving time (pair with acp_engine_cold_compiles_total)",
         )
 
+    @_in_setup("prewarm.chunked")
     def _prewarm_chunked(self, constrained: bool) -> None:
         """Warm the SPLIT chunk loop's shapes: multi-chunk prompts at
         every power-of-two batch size compile the KV-only chunk dispatch
@@ -1927,6 +1949,7 @@ class Engine:
         finally:
             self.megastep = ms
 
+    @_in_setup("prewarm.megastep")
     def _prewarm_megastep(self, constrained: bool) -> None:
         """Warm the fused megastep's core (bucket, batch, width) shapes:
         one long-running decoder keeps a decode phase live while b long
@@ -1990,6 +2013,7 @@ class Engine:
                 self._prewarm_gap("megastep", bucket=mid_bucket, B=b)
             b *= 2
 
+    @_in_setup("prewarm.phases")
     def _prewarm_phases(self, constrained: bool = False) -> None:
         # coverage (documented, not aspirational): per mode —
         #   (a) a full-width staggered burst at the largest bucket that

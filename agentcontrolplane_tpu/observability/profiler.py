@@ -30,7 +30,27 @@ by the engine (``engine.profiler``):
   a compile REAL TRAFFIC paid for — a serving-time latency bug. It records
   a ``cold_compile`` flight event and increments
   ``acp_engine_cold_compiles_total``, turning the silent "prewarm: batch
-  never formed" log line into an alertable signal.
+  never formed" log line into an alertable signal. That wall time is
+  PARTITIONED by what jax itself reports (``jax.monitoring``, one
+  process-wide listener set routed by thread): ``trace_ms`` (the Python of
+  the program traced to a jaxpr, a jitted function traced inside another
+  counted once), ``lower_ms`` (jaxpr to MLIR), ``compile_ms`` (the backend's
+  compile where the persistent cache missed or is off), ``load_ms`` (the
+  cache's retrieval where it hit) and the remainder ``run_ms`` (the first
+  run, and what jit does around the four). ``compiles`` counts how often
+  the key went through the backend: a key that compiles on a LATER dispatch
+  holds less than jit's cache keys on, and is counted in
+  ``cold_compiles.retraces`` with an event that names it.
+
+- **set-up phases** — :meth:`setup` is a context manager for the CALLER's
+  thread (the constructor and ``prewarm()`` do not run on the engine
+  thread): an ``acp.setup.<name>`` trace annotation plus wall seconds into
+  ``stats()["setup"]["phases"]``. What jax compiles on a thread with no
+  dispatch open goes to the phase open there (``jax_s``, inside the phase's
+  wall seconds), with no phase open to the profiler's ``outside`` row (the
+  engine thread's helpers between programs), and on a thread that never
+  opened either to the process-wide ``unattributed`` count, so that the
+  compiles of all tables sum to what jax's backend was asked for.
 
 - **goodput/waste accounting** — dispatch sites classify every computed
   token position into exactly one cause via :meth:`account`: ``goodput``
@@ -73,7 +93,9 @@ by the engine (``engine.profiler``):
   upload ``Engine._put`` makes; a dirty decode block costs two at most).
 
 Cross-thread contract: the write side (``record``/``account``/
-``reclassify``/``phase``/``cycle``/``count_upload``) runs on the engine thread; the read side (``stats`` /
+``reclassify``/``phase``/``cycle``/``count_upload``) runs on the engine thread (``setup`` on
+its caller's, and jax's listeners on whichever thread compiled: both add
+their rows under the lock); the read side (``stats`` /
 ``ledger`` / ``publish``) runs on REST scrape threads and takes the same
 lock — enforced by the acplint thread-ownership pass (read methods are
 declared ``# acp: cross-thread``; server code must go through them, never
@@ -107,9 +129,167 @@ WASTE_CAUSES = (
 
 COLD_EVENTS_KEPT = 32  # recent serving-time cold compiles kept for /perf
 
+# jax.monitoring's names (jax/_src/dispatch.py, compiler.py): the three stages
+# of a jitted call's first use, each a scalar at its start and a duration at its
+# end, and the persistent cache's verdict on a backend compile, fired inside it
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_STAGES = {_TRACE: "trace_s", _LOWER: "lower_s", _BACKEND: "compile_s"}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
 
-class _Program:
-    """Mutable per-program aggregate (guarded by the profiler lock)."""
+
+class _Split:
+    """What jax reported of one dispatch, set-up phase or table row: seconds
+    by stage (outermost stage only: what is traced, lowered or compiled
+    inside another stage is that stage's time), backend compiles, and of
+    those the persistent cache's hits and misses."""
+
+    __slots__ = (
+        "trace_s", "lower_s", "compile_s", "load_s", "saved_s", "compiles", "hits", "misses",
+    )
+
+    def __init__(self) -> None:
+        self.trace_s = self.lower_s = self.compile_s = self.load_s = self.saved_s = 0.0
+        self.compiles = self.hits = self.misses = 0
+
+    def add(self, other: "_Split") -> None:
+        for name in _Split.__slots__:
+            setattr(self, name, getattr(self, name) + getattr(other, name))
+
+    def jax_s(self) -> float:
+        return self.trace_s + self.lower_s + self.compile_s + self.load_s
+
+    def row(self) -> dict[str, Any]:
+        return {
+            "trace_ms": round(self.trace_s * 1e3, 3),
+            "lower_ms": round(self.lower_s * 1e3, 3),
+            "compile_ms": round(self.compile_s * 1e3, 3),
+            "load_ms": round(self.load_s * 1e3, 3),
+            # null: the persistent cache was not asked (it is off, or
+            # nothing went through the backend)
+            "cache_hit": (self.misses == 0) if (self.hits or self.misses) else None,
+            "compiles": self.compiles,
+        }
+
+
+class _Thread:
+    """One thread's routing state for jax's compile events: the profiler
+    whose dispatch or set-up phase this thread opened last, whether that
+    dispatch is still open and what arrived inside it, the open set-up
+    phases, how many stages are open (events of a nested one are the
+    outermost's), and the cache's verdict on the backend compile in flight."""
+
+    __slots__ = ("owner", "open", "split", "phases", "depth", "asked", "hit", "load_s", "saved_s")
+
+    def __init__(self) -> None:
+        self.owner: Optional["DispatchProfiler"] = None
+        self.open = False
+        self.split: Optional[_Split] = None
+        self.phases: list = []
+        self.depth = 0
+        self.asked = self.hit = False
+        self.load_s = self.saved_s = 0.0
+
+
+_TLS = threading.local()
+# compiles on threads that never opened a dispatch or a phase (a benchmark's
+# weights, another library): process-wide, in no engine's table
+_UNATTRIBUTED = _Split()
+_MODULE_LOCK = threading.Lock()  # the count above, and the one registration
+_listening = False
+
+
+def _thread() -> _Thread:
+    st = getattr(_TLS, "st", None)
+    if st is None:
+        st = _TLS.st = _Thread()
+    return st
+
+
+def _sink(st: _Thread):
+    """Where an event on this thread is counted, and the lock to hold."""
+    if st.open:
+        if st.split is None:
+            st.split = _Split()
+        return st.split, None  # the thread's own until record() takes it
+    if st.phases:
+        return st.phases[-1].split, None  # likewise until the phase closes
+    if st.owner is not None:
+        return st.owner._outside, st.owner._lock
+    return _UNATTRIBUTED, _MODULE_LOCK
+
+
+def _on_scalar(event: str, value, **kw) -> None:
+    if event in _STAGES:  # a stage begins (log_elapsed_time.__enter__)
+        _thread().depth += 1
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_ASKED:
+        import jax
+
+        # jax asks its cache whenever caching is enabled, directory or none:
+        # only with a directory can the answer be a miss
+        _thread().asked = jax.config.jax_compilation_cache_dir is not None
+    elif event == _CACHE_HIT:
+        _thread().hit = True
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    field = _STAGES.get(event)
+    if field is None:
+        if event == _CACHE_LOAD:
+            _thread().load_s += seconds
+        elif event == _CACHE_SAVED:
+            _thread().saved_s += seconds
+        return
+    st = _thread()
+    st.depth = depth = max(0, st.depth - 1)
+    split, lock = _sink(st)
+    with lock or _NULL_PHASE:
+        if event == _BACKEND:
+            split.compiles += 1
+            if st.hit:
+                split.hits += 1
+                split.saved_s += st.saved_s
+                field, seconds = "load_s", st.load_s
+            elif st.asked:
+                split.misses += 1
+            st.asked = st.hit = False
+            st.load_s = st.saved_s = 0.0
+        if depth == 0:
+            setattr(split, field, getattr(split, field) + seconds)
+
+
+def _listen() -> None:
+    """Register the three listeners, once a process: jax keeps them for good
+    and calls each on every event, so one per engine would pile up."""
+    global _listening
+    with _MODULE_LOCK:
+        if _listening:
+            return
+        import jax.monitoring as monitoring
+
+        monitoring.register_scalar_listener(_on_scalar)
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
+def unattributed() -> dict[str, Any]:
+    """Backend compiles (and jax's seconds around them) on threads that
+    never opened a dispatch or a set-up phase of any profiler."""
+    with _MODULE_LOCK:
+        return {"compiles": _UNATTRIBUTED.compiles, "s": round(_UNATTRIBUTED.jax_s(), 6)}
+
+
+class _Program(_Split):
+    """Mutable per-program aggregate (guarded by the profiler lock); the
+    split is its first dispatch's, the compiles every dispatch's."""
 
     __slots__ = (
         "dispatches", "host_s", "blocked_s", "blocked_samples",
@@ -118,6 +298,7 @@ class _Program:
     )
 
     def __init__(self) -> None:
+        super().__init__()
         self.dispatches = 0
         self.host_s = 0.0
         self.blocked_s = 0.0      # sampled dispatch-to-ready wall time
@@ -184,6 +365,49 @@ class _Phase:
         )
 
 
+class _Setup:
+    """One open set-up phase on its caller's thread: a trace annotation,
+    wall seconds, what jax compiled on this thread while it was the
+    innermost phase open there, and the first-dispatch wall time of the
+    programs first dispatched (on any thread) while it was open."""
+
+    __slots__ = ("_prof", "name", "_ann", "_t0", "split", "first_wall_s")
+
+    def __init__(self, prof: "DispatchProfiler", name: str, ann):
+        self._prof = prof
+        self.name = name
+        self._ann = ann
+        self._t0 = 0.0
+        self.split = _Split()
+        self.first_wall_s = 0.0  # under the profiler's lock: record() adds to it
+
+    def __enter__(self):
+        self._ann.__enter__()
+        prof = self._prof
+        st = _thread()
+        st.owner = prof
+        st.phases.append(self)
+        with prof._lock:
+            prof._setup_open.append(self)
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        wall = time.monotonic() - self._t0
+        prof = self._prof
+        _thread().phases.remove(self)
+        with prof._lock:
+            prof._setup_open.remove(self)
+            row = prof._setup.get(self.name)
+            if row is None:
+                row = prof._setup[self.name] = [0.0, 0, 0.0, _Split()]
+            row[0] += wall
+            row[1] += 1
+            row[2] += self.first_wall_s
+            row[3].add(self.split)
+        self._ann.__exit__(*exc)
+
+
 class DispatchProfiler:
     """Per-dispatch program telemetry + cold-compile tracking + goodput
     ledger. One per :class:`~agentcontrolplane_tpu.engine.engine.Engine`
@@ -215,6 +439,16 @@ class DispatchProfiler:
         self._programs: dict[str, _Program] = {}
         self._warm = False
         self._cold_serving = 0
+        self._retraces = 0  # later dispatches of a key that compiled again
+        # set-up: per phase [wall s, count, first-dispatch wall s of the
+        # programs first dispatched inside it, what jax compiled on its own
+        # thread], the phases open now (on any thread), and jax's seconds on
+        # this profiler's threads outside every dispatch and phase
+        self._setup: dict[str, list] = {}
+        self._setup_open: list[_Setup] = []
+        self._outside = _Split()
+        if self.enabled:
+            _listen()
         self._cold_events: "collections.deque[dict]" = collections.deque(
             maxlen=COLD_EVENTS_KEPT
         )
@@ -260,6 +494,20 @@ class DispatchProfiler:
         return _Phase(self, name, jax.profiler.TraceAnnotation(
             "acp." + name, cycle=self.cycle_n if self._cycle_phase else 0,
         ))
+
+    def setup(self, name: str):
+        """Open set-up phase ``name`` on the caller's thread (the
+        constructor's and ``prewarm()``'s, not the engine's: no self time,
+        no stamp, nothing the cycle clock reads): an ``acp.setup.<name>``
+        trace annotation, and wall seconds into
+        ``stats()["setup"]["phases"]``. A phase opened inside another is
+        inside its wall seconds too (``init.pool`` in ``init``)."""
+        if not self.enabled:
+            return _NULL_PHASE
+        import jax
+
+        _listen()  # a profiler made disabled and enabled since
+        return _Setup(self, name, jax.profiler.TraceAnnotation("acp.setup." + name))
 
     def count_upload(self) -> None:
         """One host-to-device upload by the engine thread (``Engine._put``):
@@ -307,8 +555,15 @@ class DispatchProfiler:
 
     def start(self) -> float:
         """Stamp a dispatch about to be issued (0.0 when disabled — the
-        matching ``record`` is then skipped by its own guard)."""
-        return time.monotonic() if self.enabled else 0.0
+        matching ``record`` is then skipped by its own guard). What jax
+        traces, lowers, compiles or loads on this thread from here to
+        ``record`` is that dispatch's."""
+        if not self.enabled:
+            return 0.0
+        st = _thread()
+        st.owner = self
+        st.open = True
+        return time.monotonic()
 
     def record(
         self,
@@ -338,6 +593,9 @@ class DispatchProfiler:
             # zero stamp would corrupt the program's stats
             return
         host_s = time.monotonic() - t0
+        st = _thread()
+        st.open = False
+        split, st.split = st.split, None  # None unless jax compiled in here
         stack = self._stack()
         if stack and stack[-1].name == "launch":
             stack[-1].program(key, t0)
@@ -361,6 +619,8 @@ class DispatchProfiler:
         cold = False
         wall = blocked_s if blocked_s is not None else host_s
         with self._lock:
+            if split is not None:
+                self._record_split(key, p, split, first, wall, t0)
             p.dispatches += 1
             p.host_s += host_s
             p.real_tokens += int(real_tokens)
@@ -372,12 +632,14 @@ class DispatchProfiler:
                 p.blocked_samples += 1
             if first:
                 p.first_wall_s = wall
+                for ph in self._setup_open:
+                    ph.first_wall_s += wall
                 if self._warm:
                     p.cold = True
                     self._cold_serving += 1
                     self._cold_events.append(
                         {"program": key, "wall_s": round(wall, 6),
-                         "t": round(t0, 6)}
+                         "t": round(t0, 6), **p.row()}
                     )
                     cold = True
         REGISTRY.observe(
@@ -397,6 +659,28 @@ class DispatchProfiler:
                 self._flight.record(
                     "cold_compile", program=key, wall_s=round(wall, 6)
                 )
+
+    def _record_split(self, key: str, p: _Program, split: _Split, first: bool,
+                      wall: float, t0: float) -> None:
+        """Under the lock: what jax did inside one dispatch of ``key``. A
+        first dispatch's split partitions its wall time. A later one's
+        seconds go to ``outside`` (the row's four parts stay under its
+        ``first_wall_ms``); its compiles stay with the key, and each such
+        dispatch that went through the backend is a retrace."""
+        if first:
+            p.add(split)
+            return
+        if split.compiles:
+            self._retraces += 1
+            self._cold_events.append(
+                {"program": key, "wall_s": round(wall, 6), "t": round(t0, 6),
+                 "retrace": True, **split.row()}
+            )
+        p.compiles += split.compiles
+        p.hits += split.hits
+        p.misses += split.misses
+        split.compiles = split.hits = split.misses = 0
+        self._outside.add(split)
 
     def account(self, goodput: int = 0, **waste: int) -> None:
         """Classify one dispatch's computed token positions: ``goodput``
@@ -534,6 +818,10 @@ class DispatchProfiler:
                     "padded_slots": p.padded_slots,
                     "first_wall_ms": round(p.first_wall_s * 1e3, 3),
                     "cold": p.cold,
+                    # the first dispatch's wall time, partitioned: jax's four
+                    # stages, then what is left (the first run itself)
+                    **p.row(),
+                    "run_ms": round(max(0.0, p.first_wall_s - p.jax_s()) * 1e3, 3),
                 }
             waste = dict(self._waste)
             computed, goodput = self._computed, self._goodput
@@ -548,6 +836,7 @@ class DispatchProfiler:
                 "programs": programs,
                 "cold_compiles": {
                     "serving": self._cold_serving,
+                    "retraces": self._retraces,
                     "events": list(self._cold_events),
                 },
                 "goodput": {
@@ -563,7 +852,40 @@ class DispatchProfiler:
                 "blocks": self._blocks,
                 "uploads": self._uploads,
             }
+            if self.enabled:
+                doc["setup"] = self._setup_stats(programs)
         return doc
+
+    def _setup_stats(self, programs: dict[str, dict]) -> dict[str, Any]:
+        """Under the lock: the engine's start. ``prewarm_rest_s`` is the
+        ``prewarm`` phase's wall seconds less the first dispatches inside
+        it: the bursting, the waiting for batches to form and the freeze."""
+        phases = {}
+        total = _Split()  # programs' + outside's + phases'
+        total.add(self._outside)
+        for name, (wall, n, first_wall_s, split) in self._setup.items():
+            phases[name] = {
+                "s": round(wall, 6), "n": n, "jax_s": round(split.jax_s(), 6),
+                "compiles": split.compiles, "first_wall_s": round(first_wall_s, 6),
+            }
+            total.add(split)
+        for p in self._programs.values():
+            total.add(p)
+        prewarm = phases.get("prewarm")
+        return {
+            "phases": phases,
+            "programs": len(programs),
+            "compiles": total.compiles,  # with unattributed's, all the backend saw
+            "cache_misses": sum(1 for p in self._programs.values() if p.misses),
+            "after_prewarm": self._cold_serving,
+            "retraces": self._retraces,
+            "prewarm_rest_s": (
+                round(max(0.0, prewarm["s"] - prewarm["first_wall_s"]), 6) if prewarm else 0.0
+            ),
+            "saved_s": round(total.saved_s, 6),  # backend seconds the cache's hits stood for
+            "outside": self._outside.row(),
+            "unattributed": unattributed(),
+        }
 
 
 __all__ = ["DispatchProfiler", "WASTE_CAUSES"]

@@ -398,8 +398,11 @@ def test_disabled_profiler_serves_the_same_tokens_and_records_no_phase(monkeypat
             st = eng.stats()
         finally:
             eng.stop()
+        if flag == "1":
+            assert {"init", "init.pool"} <= set(st["perf"]["setup"]["phases"])
         if flag == "0":
             assert st["perf"]["phases"] == {} and st["perf"]["cycles"] == 0
+            assert "setup" not in st["perf"]  # no set-up phase, no split, no wait on the pool
             # the counter of the scheduler does not hang on the profiler
             assert st["scheduler"]["queue_wait"]["n"] == 3
             assert st["cycle_s"] > 0  # the planner's clock falls back to its own reads
