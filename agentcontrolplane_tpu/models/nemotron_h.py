@@ -291,15 +291,14 @@ def _mamba_pre(h, w, c: NemotronHConfig, conv_in, valid):
 
 def _mamba_post(y, x, z, w, c: NemotronHConfig, dtype):
     """y, x [B, T, H, P] float32; z [B, T, d_inner] -> the mixer's output."""
-    f32 = jnp.float32
-    B, T = y.shape[:2]
     with jax.named_scope("ssd_gate_norm"):
-        y = (y + w["D"].astype(f32)[:, None] * x).reshape(B, T, c.d_inner) * jax.nn.silu(z)
-        groups = y.reshape(B, T, c.n_groups, -1)
-        groups = groups * jax.lax.rsqrt(jnp.mean(jnp.square(groups), axis=-1, keepdims=True) + c.norm_eps)
-        y = groups.reshape(B, T, c.d_inner) * w["gate_norm"].astype(f32)
+        y = ssd.gate_norm(y, x, z, w["D"], w["gate_norm"], c.n_groups, c.norm_eps)
+    return _mamba_out(y.astype(dtype), w)
+
+
+def _mamba_out(y, w):
     with jax.named_scope("mamba_out_proj"):
-        return _mm(y.astype(dtype), w["out_proj"])
+        return _mm(y, w["out_proj"])
 
 
 def _latent_moe(h, w, stacks, layer_index, c: NemotronHConfig, valid, chosen=None):
@@ -513,8 +512,10 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
                       use_pallas: bool = False, mesh=None, route=None):
     """One token for lanes 0..S-1 (lane b is slot b): the attention layer
     walks the pages; Mamba layers shift their slot's conv columns and take
-    one step of the recurrence on ``state["ssm"][layer, :S]`` in place, the
-    whole stack carried through the layer loops and never copied; expert
+    one step of the recurrence on ``state["ssm"][layer, :S]`` in place (one
+    kernel from the conv's rows to the gated, normed row the output
+    projection reads: ``ssd.update``), the whole stack carried through the
+    layer loops and never copied; expert
     layers route the live lanes. An inactive lane's state and pages are left
     as they were (its ``dt`` is 0) and it routes nowhere. ``route`` [n_moe, S,
     1, k]: the experts' choice given (an output check's)."""
@@ -559,8 +560,10 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
                     conv_all = jax.lax.dynamic_update_slice(conv_all, new[None], (at, 0, 0))
                 with jax.named_scope("ssm_update"):
                     a = -jnp.exp(w["A_log"].astype(jnp.float32))
-                    y, h_all = ssd.update(h_all, at, delta[:, 0], xs[:, 0], b[:, 0], c_[:, 0], a)
-                op = _mamba_post(y[:, None], xs, z, w, c, dt)
+                    # the row comes back gated and normed: the skip, the gate and the grouped norm are the kernel's
+                    y, h_all = ssd.update(h_all, at, delta[:, 0], xs[:, 0], b[:, 0], c_[:, 0], a, z=z[:, 0], d=w["D"],
+                                          norm=w["gate_norm"], eps=c.norm_eps, dtype=dt)
+                op = _mamba_out(y[:, None], w)
             else:
                 op, m = _latent_moe(h, w, stacks, at, c, active[:, None], None if route is None else route[at])
                 counts = counts + m

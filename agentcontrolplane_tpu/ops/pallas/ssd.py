@@ -27,12 +27,50 @@ A token that must leave the state as it is (padding past a row's length, an
 idle decode lane) is given ``dt = 0``: the decay is ``exp(0) = 1`` and the
 input term 0, so ``S`` passes through exactly. The callers mask ``dt``.
 
-``ssd_update`` (decode): one step over lanes ``0..S-1`` of layer ``row`` of
-the whole stacked state ``[layers, slots, J, N, LW]``, read and written IN
-PLACE (aliased to the output, the layer a prefetched scalar in the index
-maps): a step moves each live lane's 4 MiB once in and once out and nothing
-else of the stack, the contract ``ssm_update`` keeps. The grid is (lanes,
-groups): a block is ``lanes`` lanes of one group's heads.
+``ssd_update`` (decode): ONE kernel from the rows the conv leaves to the row
+the output projection reads. In: the whole stacked state ``[layers, slots, J,
+N, LW]`` (read and written IN PLACE, aliased to the output, the layer a
+prefetched scalar), ``dt [S, H]``, ``x [S, H, P]``, ``B``, ``C [S, G, N]``,
+the gate ``z [S, H P]`` and the layer's ``A``, ``D [H]`` and the grouped
+norm's weight ``[H P]``. Out: lanes ``0..S-1`` of layer ``row`` one step on,
+and ``RMSNorm_groups((y + D x) silu(z)) w`` as a row ``[S, H P]`` in the
+model's dtype, float32 until its last cast: the norm's groups are the
+recurrence's (``H P / G`` channels of one group's heads), so nothing of the
+epilogue leaves a lane. A step moves each live lane's 4 MiB once in and once
+out and nothing else of the stack, the contract ``ssm_update`` keeps.
+
+A grid step is ``LANES`` lanes WHOLE (4 MiB each, one piece of HBM), and the
+state's DMAs are issued by the kernel itself and take TURNS: one direction
+in flight at a time. What a step costs on a v5e, as measured with the
+kernel's parts taken out (PERF.md, PR 57; ms a call of 128 lanes, 1.074 GB
+of state moved, 1.311 ms at 819 GB/s): **the stream alone sets the time, the
+body none of it.** Under the BlockSpec pipeline (a fetch and a write-back
+always in flight together, blocks of 4 lanes of one group: the kernel until
+PR 57) a plain copy took 1.667, the same as the whole body, at 1, 2 and 4 MiB
+a block alike; with ``B`` and ``C`` constant 1.664, with ``y`` not reduced
+1.664. The same bytes moved by hand, a fetch to its end and then a store to
+its end: ``bytes / 702 GB/s + 0.28 us`` a transfer (1.815, 1.672, 1.601,
+1.567 at 1, 2, 4 and 8 MiB a transfer): a stream of ONE direction reaches 86%
+of the published rate, both directions at once 77-79%, and a transfer alone
+in flight pays its start's latency once. So the blocks are large (the
+latency) and whole lanes (one descriptor each way), and the directions
+alternate: the next step's fetch runs under this step's arithmetic, then this
+step's store runs alone, and the fetch after that is issued at the step's END
+so that the grid's own step-to-step work (0.1 us) falls under it: 1.626 at
+one lane a step, **1.591 at two** (16 MiB of buffers, a stated limit of 22),
+1.583 at four (38 MiB, which the decode block's other residents do not leave
+free). The arithmetic all fits under the fetch: a group of one lane is a
+tile ``[blocks, LW]`` of ``x``, ``z`` and the row out; ``dt`` is read as SMEM
+scalars and spread over a head's channels by selects (one exponential a
+head), ``A`` and ``D`` likewise; the columns of ``B`` and ``C`` are made once a
+lane and group by spreading a row over the sublanes and turning it, and kept
+in VMEM scratch; a block is two multiplies and an add an element, stored
+where it was read, then a multiply and a sum down the sublanes for ``y``; the
+skip, the gate and the norm run on the group's one tile. The loop over the
+groups is a ``fori_loop`` written out by the compiler (``unroll=True``), the
+lanes and blocks in Python: a body written out tile by tile in Python read
+the same on the device and took 80 s to trace (a decode program's set-up); a
+block loop left as a loop took 2.00.
 
 ``ssd_scan`` (prefill, continuation): the chunked form. Inside a chunk of
 ``CHUNK`` tokens, with ``cum`` the float32 running sum of ``dt A`` over the
@@ -67,7 +105,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 128  # tokens a grid step of the scan: the published `chunk_size`
 GROUP = 16  # the snapshot falls on a multiple of this (the page size divides into it or it into the page)
-LANES = 4  # decode lanes a grid step of the update: 2 MiB of one group's state in and as much out
+LANES = 2  # decode lanes a grid step of the update: their whole state of one layer, 4 MiB a lane in one piece of HBM
 
 
 def heads_per_tile(head_dim: int, heads_per_group: int) -> int:
@@ -247,58 +285,133 @@ def scan(dt, x, b, c, a, h0, snap_rel, n_chunks, kernel: bool | None = None):
 # ---------------------------------------------------------------------------
 
 
-def _update_kernel(row_ref, state_ref, decay_ref, xdt_ref, bt_ref, ct_ref, y_ref, out_ref, *, lanes):
-    del row_ref
-    blocks, _, lw = state_ref.shape[2:]
-    group = jax.lax.broadcasted_iota(jnp.int32, (1, bt_ref.shape[2]), 1) == pl.program_id(1)
-    for s in range(lanes):
-        # this group's B and C as columns down the sublanes, shared by its heads
-        b_col = jnp.sum(jnp.where(group, bt_ref[s], 0.0), axis=1, keepdims=True)
-        c_col = jnp.sum(jnp.where(group, ct_ref[s], 0.0), axis=1, keepdims=True)
-        for j in range(blocks):
-            at = slice(j * lw, (j + 1) * lw)
-            h = decay_ref[s, :, at] * state_ref[0, s, j] + b_col * xdt_ref[s, :, at]
-            out_ref[0, s, j] = h
-            y_ref[s, :, at] = jnp.sum(h * c_col, axis=0, keepdims=True)
+def _update_kernel(row_ref, dt_ref, a_ref, d_ref, state_hbm, x_ref, z_ref, b_ref, c_ref, w_ref, y_ref, out_hbm,
+                   buf, sem, bcol_ref, ccol_ref, sums_ref, *, lanes, head_dim, eps):
+    f32 = jnp.float32
+    k, last = pl.program_id(0), pl.num_programs(0) - 1
+    groups, blocks, lw = w_ref.shape
+    n = buf.shape[3]
+    hp = lw // head_dim
+    # a group's `blocks x lw` channels as one tile, a block a row: the head a channel is of, counted from the group's first
+    head_of = (jax.lax.broadcasted_iota(jnp.int32, (blocks, lw), 0) * hp
+               + jax.lax.broadcasted_iota(jnp.int32, (blocks, lw), 1) // head_dim)
+    slot = k % 2
+
+    def fetch(step, into):  # the whole state of a step's lanes in layer `row`: `lanes` x 4 MiB in one piece
+        return pltpu.make_async_copy(state_hbm.at[row_ref[0], pl.ds(step * lanes, lanes)], buf.at[into], sem.at[0, into])
+
+    def store(step, out_of):
+        return pltpu.make_async_copy(buf.at[out_of], out_hbm.at[row_ref[0], pl.ds(step * lanes, lanes)], sem.at[1, out_of])
+
+    def per_head(scalar, first):
+        """A group's tile from its heads' scalars (SMEM), head ``first`` on, each spread over its channels."""
+        tile = jnp.full((blocks, lw), scalar(first), f32)
+        for h in range(1, blocks * hp):
+            tile = jnp.where(head_of == h, scalar(first + h), tile)
+        return tile
+
+    # The state's DMAs are issued here and take TURNS: one direction in flight at a time, a fetch of the next step's
+    # lanes under this step's arithmetic, then this step's store alone. The next fetch but one is issued at the
+    # step's end, so that the grid's own step-to-step work falls under it.
+    @pl.when(k == 0)
+    def _():
+        fetch(0, 0).start()
+        fetch(0, 0).wait()
+
+        @pl.when(last > 0)
+        def _():
+            fetch(1, 1).start()
+
+    def group(g, carry):
+        first = g * (blocks * hp)
+        a, d = per_head(lambda h: a_ref[h], first), per_head(lambda h: d_ref[h], first)
+        for s in range(lanes):
+            lane = k * lanes + s
+            dt, x = per_head(lambda h: dt_ref[lane, h], first), x_ref[s, g]
+            decay, xdt = jnp.exp(dt * a), dt * x  # one exponential a head, spread over its channels
+            # this group's B and C down the sublanes and across the lanes, once a lane: a row spread over the
+            # sublanes and turned, kept in VMEM where a block's arithmetic reads it beside the block
+            bcol_ref[...] = jnp.broadcast_to(b_ref[s, pl.ds(g, 1), :], (lw, n)).T
+            ccol_ref[...] = jnp.broadcast_to(c_ref[s, pl.ds(g, 1), :], (lw, n)).T
+            for j in range(blocks):  # an element of state: two multiplies and an add, then a multiply and an add for y
+                at = g * blocks + j
+                h = decay[j:j + 1, :] * buf[slot, s, at] + bcol_ref[...] * xdt[j:j + 1, :]
+                buf[slot, s, at] = h
+                sums_ref[j:j + 1, :] = jnp.sum(h * ccol_ref[...], axis=0, keepdims=True)
+            # the skip, the gate and the grouped norm: the norm's group is this group's heads, `blocks * lw` channels
+            y = (sums_ref[...] + d * x) * jax.nn.silu(z_ref[s, g])
+            mean = jnp.sum(jnp.sum(y * y, axis=1, keepdims=True), axis=0, keepdims=True) / (blocks * lw)
+            y_ref[s, g] = (y * jax.lax.rsqrt(mean + eps) * w_ref[g]).astype(y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0, unroll=True)  # traced once, written out: the scheduler sees a lane whole
+
+    @pl.when(k < last)
+    def _():
+        fetch(k + 1, 1 - slot).wait()
+
+    store(k, slot).start()
+    store(k, slot).wait()
+
+    @pl.when(k + 1 < last)
+    def _():
+        fetch(k + 2, slot).start()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "lanes"))
-def ssd_update(state, row, dt, x, b, c, a, interpret: bool = False, lanes: int = LANES):
+@functools.partial(jax.jit, static_argnames=("eps", "dtype", "interpret", "lanes"))
+def ssd_update(state, row, dt, x, b, c, a, *, z, d, norm, eps, dtype, interpret: bool = False, lanes: int = LANES):
     """state [layers, slots, J, N, LW] float32 (stored order); row () int32,
     the layer; dt [S, H] (0: the lane's state passes through); x [S, H, P];
-    b, c [S, G, N]; a [H] -> (y [S, H, P], state with ``state[row, :S]`` one
-    step on). S <= slots."""
+    b, c [S, G, N]; a [H]; z [S, H * P] the gate; d [H] the skip; norm [H * P]
+    the grouped norm's weight -> (the gated, normed row [S, H * P] in
+    ``dtype``, state with ``state[row, :S]`` one step on). S <= slots."""
     f32 = jnp.float32
     S, H, P = x.shape
     G, N = b.shape[1:]
     J, _, LW = state.shape[2:]
-    dt, x = dt.astype(f32), x.astype(f32)
-    width = H // G * P
-    lanes = lanes if S % lanes == 0 else S
-    decay = jnp.repeat(jnp.exp(dt * a.astype(f32)), P, axis=1)[:, None, :]  # one exponential a head, a row over its lanes
-    per_lane = pl.BlockSpec((lanes, 1, width), lambda i, g, row: (i, 0, g))
-    columns = pl.BlockSpec((lanes, N, G), lambda i, g, row: (i, 0, 0))
-    block = pl.BlockSpec((1, lanes, J // G, N, LW), lambda i, g, row: (row[0], i, g, 0, 0))
+    assert J % G == 0, (J, G)
+    lanes = lanes if S % lanes == 0 else 1
+    tiles = (G, J // G, LW)  # a lane's `H * P` channels a group, a block and a block's lanes: a group is one tile
+    row_of = pl.BlockSpec((lanes,) + tiles, lambda k, *_: (k, 0, 0, 0))
+    columns = pl.BlockSpec((lanes, G, N), lambda k, *_: (k, 0, 0))
+    held = 2 * lanes * J * N * LW * 4  # two buffers of a step's lanes; the limit states them and 6 MiB for the rows, the columns and the compiler
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S // lanes, G),
-        in_specs=[block, per_lane, per_lane, columns, columns],
-        out_specs=[per_lane, block],
+        num_scalar_prefetch=4,  # the layer; dt, A and D a head, read as scalars and spread over a head's channels
+        grid=(S // lanes,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), row_of, row_of, columns, columns,
+                  pl.BlockSpec(tiles, lambda k, *_: (0, 0, 0))],
+        out_specs=[row_of, pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[pltpu.VMEM((2, lanes, J, N, LW), f32), pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((N, LW), f32), pltpu.VMEM((N, LW), f32), pltpu.VMEM((J // G, LW), f32)],
     )
     y, state = pl.pallas_call(
-        functools.partial(_update_kernel, lanes=lanes),
+        functools.partial(_update_kernel, lanes=lanes, head_dim=P, eps=eps),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, 1, H * P), f32), jax.ShapeDtypeStruct(state.shape, f32)],
-        input_output_aliases={1: 1},  # operand 0 is the prefetched scalar
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        out_shape=[jax.ShapeDtypeStruct((S,) + tiles, dtype), jax.ShapeDtypeStruct(state.shape, f32)],
+        input_output_aliases={4: 1},  # operands 0 to 3 are the prefetched scalars
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             vmem_limit_bytes=max(held + (6 << 20), 16 << 20)),
         interpret=interpret,
         name="ssm_update",  # the name `acpbench/device_scopes.py` files under mixer/ssm_update
-    )(jnp.reshape(row, (1,)).astype(jnp.int32), state, decay, _rows(dt, x)[:, None, :],
-      jnp.swapaxes(b.astype(f32), 1, 2), jnp.swapaxes(c.astype(f32), 1, 2))
-    return y.reshape(S, H, P), state
+    )(jnp.reshape(row, (1,)).astype(jnp.int32), dt.astype(f32), a.astype(f32), d.astype(f32), state,
+      x.astype(f32).reshape((S,) + tiles), z.astype(f32).reshape((S,) + tiles), b.astype(f32), c.astype(f32),
+      norm.astype(f32).reshape(tiles))
+    return y.reshape(S, H * P), state
 
 
-def ssd_update_reference(state, row, dt, x, b, c, a):
+def gate_norm(y, x, z, d, norm, n_groups: int, eps: float):
+    """What follows the recurrence in a Mamba-2 layer, float32: the skip ``y +
+    D x`` (y, x [.., H, P]; d [H]), the gate ``silu(z)`` (z [.., H * P]) and
+    the RMS norm over each of ``n_groups`` groups of channels, times ``norm``
+    [H * P] -> [.., H * P]."""
+    f32 = jnp.float32
+    y = (y + d.astype(f32)[:, None] * x).reshape(z.shape) * jax.nn.silu(z.astype(f32))
+    groups = y.reshape(*z.shape[:-1], n_groups, -1)
+    groups = groups * jax.lax.rsqrt(jnp.mean(jnp.square(groups), axis=-1, keepdims=True) + eps)
+    return groups.reshape(z.shape) * norm.astype(f32)
+
+
+def ssd_update_reference(state, row, dt, x, b, c, a, *, z, d, norm, eps, dtype):
     """``ssd_update`` in plain XLA, in the stored order."""
     f32 = jnp.float32
     S, H, P = x.shape
@@ -312,11 +425,13 @@ def ssd_update_reference(state, row, dt, x, b, c, a):
     c_col = jnp.repeat(c, per_block, axis=1)[..., None]
     h = decay * h + b_col * _rows(dt, x).reshape(S, J, 1, LW)
     y = jnp.sum(h * c_col, axis=2).reshape(S, H, P)
-    return y, jax.lax.dynamic_update_slice(state, h[None], (row, 0, 0, 0, 0))
+    return (gate_norm(y, x, z, d, norm, G, eps).astype(dtype),
+            jax.lax.dynamic_update_slice(state, h[None], (row, 0, 0, 0, 0)))
 
 
-def update(state, row, dt, x, b, c, a, kernel: bool | None = None):
-    """The decode step's recurrence, chosen as ``scan`` is."""
+def update(state, row, dt, x, b, c, a, kernel: bool | None = None, **epilogue):
+    """The decode step's recurrence and what follows it (``z``, ``d``, ``norm``,
+    ``eps``, ``dtype``: ``ssd_update``), chosen as ``scan`` is."""
     if kernel is None:
         kernel = jax.default_backend() == "tpu"
-    return (ssd_update if kernel else ssd_update_reference)(state, row, dt, x, b, c, a)
+    return (ssd_update if kernel else ssd_update_reference)(state, row, dt, x, b, c, a, **epilogue)

@@ -1359,6 +1359,13 @@ def test_nemotron_decode_block_updates_the_state_in_place(v5e, monkeypatch):
         params, cache, vec(S), vec(S), vec(S, 4096 // PAGE), vec(S, dt=jnp.bool_)).compile()
     text = compiled.as_text()
     assert all(name in text for name in ("ssm_update", "moe_gmm", "paged_page_walk"))
+    # the recurrence is ONE kernel a Mamba layer, from the conv's rows to the gated, normed row: a custom call where a
+    # Mamba layer's body is traced (the scan's period and the two written out), and no `ssd_gate_norm` op in the block
+    updates = [line for line in text.splitlines() if "custom-call(" in line and "ssm_update" in line.split(" = ")[0]]
+    assert len(updates) == 3, [line.split(" = ")[0].strip() for line in updates]
+    tiles = f"bf16[{S},{c.n_groups},{c.d_inner // c.n_groups // 128},128]"  # a lane's channels a group, a block, a block's lanes
+    assert all(tiles in line for line in updates), "the row leaves the kernel in the model's dtype"
+    assert "ssd_gate_norm" not in text
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
     state = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache["state"]))
     assert abs(weights - 5.50e9) < 0.01e9 and abs(state - 5.49e9) < 0.01e9

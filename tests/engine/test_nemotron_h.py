@@ -114,25 +114,64 @@ def test_the_decays_are_differences_of_the_running_sum_and_never_overflow():
     assert not bool(jnp.all(jnp.isfinite(y)))
 
 
-@pytest.mark.parametrize("S", [8, 4, 3])
-def test_the_update_kernel_steps_one_layers_lanes_in_place_and_no_other_row(S):
+def update_inputs(S, heads=H, head_dim=P, groups=G, d_state=N, layers=3, slots=9):
     rng = np.random.default_rng(S)
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
-    layers, slots = 3, 9
-    state = f(layers, slots, H // HP, N, HP * P)
-    dt = jnp.asarray(rng.uniform(1e-3, 0.5, (S, H)), jnp.float32).at[1].set(0.0)  # lane 1 idle
-    x, b, c, a = f(S, H, P), f(S, G, N), f(S, G, N), -jnp.asarray(rng.uniform(1, 16, (H,)), jnp.float32)
-    want_y, want = ssd.ssd_update_reference(state, jnp.int32(1), dt, x, b, c, a)
-    y, got = ssd.ssd_update(state, jnp.int32(1), dt, x, b, c, a, interpret=True)
+    hp = ssd.heads_per_tile(head_dim, heads // groups)
+    state = f(layers, slots, heads // hp, d_state, hp * head_dim)
+    dt = jnp.asarray(rng.uniform(1e-3, 0.5, (S, heads)), jnp.float32).at[1].set(0.0)  # lane 1 idle
+    a = -jnp.asarray(rng.uniform(1, 16, (heads,)), jnp.float32)
+    rows = (dt, f(S, heads, head_dim), f(S, groups, d_state), f(S, groups, d_state), a)
+    after = dict(z=f(S, heads * head_dim), d=f(heads), norm=f(heads * head_dim), eps=1e-5)
+    return state, rows, after
+
+
+@pytest.mark.parametrize("S", [8, 4, 3])
+def test_the_update_kernel_steps_one_layers_lanes_in_place_and_no_other_row(S):
+    state, (dt, x, b, c, a), after = update_inputs(S)
+    want_y, want = ssd.ssd_update_reference(state, jnp.int32(1), dt, x, b, c, a, dtype=jnp.float32, **after)
+    y, got = ssd.ssd_update(state, jnp.int32(1), dt, x, b, c, a, dtype=jnp.float32, interpret=True, **after)
     np.testing.assert_allclose(y, want_y, atol=1e-5)
     np.testing.assert_allclose(got, want, atol=1e-6)
     assert bool(jnp.array_equal(got[0], state[0])) and bool(jnp.array_equal(got[2], state[2]))
     assert bool(jnp.array_equal(got[1, S:], state[1, S:])) and bool(jnp.array_equal(got[1, 1], state[1, 1]))
-    # and the twin is the recurrence: one token of the per-token scan from the same state
+    # and the twin is the recurrence: one token of the per-token scan from the same state, then the skip, the gate
+    # and the grouped norm as the prefill runs them
     y1, end, _ = ssd.ssd_scan_reference(dt[:, None], x[:, None], b[:, None], c[:, None], a, state[1, :S],
                                         jnp.full((S,), -1))
-    np.testing.assert_allclose(want_y, y1[:, 0], atol=1e-5)
+    y1 = ssd.gate_norm(y1[:, 0], x, after["z"], after["d"], after["norm"], G, after["eps"])
+    np.testing.assert_allclose(want_y, y1, atol=1e-5)
     np.testing.assert_allclose(want[1, :S], end, atol=1e-6)
+
+
+@pytest.mark.parametrize("S,lanes,sizes,dtype", [
+    (8, 1, {}, jnp.float32), (8, 4, {}, jnp.float32), (6, 4, {}, jnp.bfloat16),  # the tiny sizes; S no multiple of `lanes`
+    (6, 4, dict(heads=8, head_dim=64, groups=2, d_state=128), jnp.float32),  # a block at the published widths
+    (3, 1, dict(heads=8, head_dim=64, groups=2, d_state=128), jnp.bfloat16),
+    (4, 4, dict(heads=8, head_dim=64, groups=4, d_state=128), jnp.bfloat16),  # hp = 2 = a group's heads
+])
+def test_the_fused_update_returns_the_gated_normed_row_and_the_stepped_state(S, lanes, sizes, dtype):
+    """One call from the conv's rows to the row `mamba_out_proj` reads: the
+    output row in the model's dtype, the stepped state, a `dt = 0` lane's
+    state bit for bit, the other layers of the stack and the slots past the
+    lanes untouched, at any `lanes` a grid step."""
+    state, (dt, x, b, c, a), after = update_inputs(S, **sizes)
+    want_y, want = ssd.ssd_update_reference(state, jnp.int32(1), dt, x, b, c, a, dtype=dtype, **after)
+    y, got = ssd.ssd_update(state, jnp.int32(1), dt, x, b, c, a, dtype=dtype, interpret=True, lanes=lanes, **after)
+    assert y.dtype == want_y.dtype == dtype and y.shape == (S, x.shape[1] * x.shape[2])
+    want_row = want_y.astype(jnp.float32)
+    atol = (1e-5 if dtype == jnp.float32 else 2 ** -7) * float(jnp.max(jnp.abs(want_row)))  # a bfloat16 row: its last bit
+    np.testing.assert_allclose(y.astype(jnp.float32), want_row, atol=atol)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert bool(jnp.array_equal(got[1, 1], state[1, 1]))  # dt = 0: the decay is 1 and the input term 0, exactly
+    assert bool(jnp.array_equal(got[0], state[0])) and bool(jnp.array_equal(got[2], state[2]))
+    assert bool(jnp.array_equal(got[1, S:], state[1, S:]))
+    # the row is the plain epilogue over the recurrence's y, a group's norm over its own channels alone
+    groups, heads = b.shape[1], x.shape[1]
+    h = ssd.logical(want[1, :S], x.shape[2])  # [S, H, P, N]
+    y0 = jnp.einsum("shpn,shn->shp", h, jnp.repeat(c, heads // groups, axis=1))
+    plain = ssd.gate_norm(y0, x, after["z"], after["d"], after["norm"], groups, after["eps"])
+    np.testing.assert_allclose(want_row, plain, atol=atol)
 
 
 # -- the latent, ungated expert layer ---------------------------------------------
