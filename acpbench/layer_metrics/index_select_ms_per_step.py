@@ -1,0 +1,10 @@
+"""Programs: device time a decode step of the leaf `index_select`
+(the choice of the topk rows of largest score),
+over all layers, in ms: the ops of the decode-block runs whose path holds the
+scope (`_sparse.leaf_seconds`). A program without the leaf gives None."""
+
+from . import _sparse
+
+
+def read(run):
+    return _sparse.ms_per_step(run, "index_select")

@@ -46,7 +46,8 @@ first that hits), and ``shardings`` is the family's own layout over a mesh
 (``params(mesh, config, abstract)``, ``paged_pool(mesh, quantize_kv)``) or
 None: a family that gives none has its weights and its whole cache tree held
 whole on every device (``models.kanana`` is such a family with no state a
-slot: a latent row a token in the pool, counters beside it). ``walk(config,
+slot: a latent row a token in the pool, counters beside it; ``models.keye`` is another: K and V pages and the sparse indexer's key a token
+beside them, a third leaf of the pool). ``walk(config,
 page_rows, dtype, tp, quantize_kv)`` names the compiled walk a decode step
 takes on a TPU as ``(pages_per_turn, turns_in_flight, bytes_in_flight)``, or
 None where the geometry falls to the XLA reference; ``page_leaf`` names the
@@ -63,7 +64,7 @@ weights), so ``config.n_layers`` counts its weights and
 
 from types import SimpleNamespace
 
-from . import exaone, jamba, kanana, lfm2, llama, mellum, nemotron_h, ouro
+from . import exaone, jamba, kanana, keye, lfm2, llama, mellum, nemotron_h, ouro
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -76,6 +77,7 @@ from .llama import (
 from .exaone import ExaoneConfig
 from .jamba import JambaConfig
 from .kanana import KananaConfig
+from .keye import KeyeConfig
 from .lfm2 import Lfm2Config
 from .mellum import MellumConfig
 from .nemotron_h import NemotronHConfig
@@ -83,7 +85,7 @@ from .ouro import OuroConfig
 
 __all__ = [
     "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "OuroConfig", "ExaoneConfig",
-    "NemotronHConfig",
+    "NemotronHConfig", "KeyeConfig",
     "decode_step", "forward", "init_kv_cache",
     "init_params", "kv_pages_that_fit", "page_bytes", "prefill", "preset", "programs",
 ]
@@ -91,7 +93,7 @@ __all__ = [
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone, nemotron_h)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone, nemotron_h, keye)]
     for table in tables:
         if name in table:
             return table[name]
@@ -275,8 +277,22 @@ _OURO = SimpleNamespace(
     decode_step_paged=ouro.decode_step_paged,
     counters=ouro.counters, describe_counters=ouro.describe_counters,
 )
+# kanana's seam (no state a slot, counters on the device) over the dense family's K and V pages and a third
+# leaf beside them, the sparse indexer's key a token (`ik`); its decode step chooses rows and fetches them by
+# row through XLA's gather, so it names no compiled walk
+_KEYE = SimpleNamespace(
+    family="keye", has_state=False, window_cache=False, draft_step=None, draft_rows=1,
+    refusals=keye.refusals, shardings=None, walk=lambda *geometry: None, page_leaf="k",
+    init_params=keye.init_params, init_paged_cache=keye.init_paged_cache,
+    prefill_paged_batch=keye.prefill_paged_batch,
+    prefill_paged_continue=keye.prefill_paged_continue,
+    prefill_paged_continue_kv=keye.prefill_paged_continue_kv,
+    decode_step_paged=keye.decode_step_paged,
+    counters=keye.counters, describe_counters=keye.describe_counters,
+)
 _FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM,
-             KananaConfig: _KANANA, OuroConfig: _OURO, ExaoneConfig: _EXAONE, NemotronHConfig: _NEMOTRON_H}
+             KananaConfig: _KANANA, OuroConfig: _OURO, ExaoneConfig: _EXAONE, NemotronHConfig: _NEMOTRON_H,
+             KeyeConfig: _KEYE}
 
 
 def programs(config) -> SimpleNamespace:

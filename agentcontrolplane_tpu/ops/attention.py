@@ -47,6 +47,7 @@ def causal_attention(
     positions: jax.Array | None = None,  # [B, T] for padded/packed inputs
     softcap: float = 0.0,
     window: int = 0,  # > 0: a query sees the last `window` positions, itself among them
+    keep: jax.Array | None = None,  # [B, T, T] bool: the keys a query may see, beside the causal mask
 ) -> jax.Array:
     """Full causal self-attention. With ``positions`` given, tokens attend
     only to tokens with position <= their own AND valid (position >= 0)."""
@@ -71,6 +72,8 @@ def causal_attention(
         )
         if window:
             mask = mask & (positions[:, None, None, :] > positions[:, None, :, None] - window)
+    if keep is not None:
+        mask = mask & keep[:, None]
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
@@ -310,3 +313,69 @@ def write_kv_token(
     k_cache = k_cache.at[slot_idx, positions].set(k_new.astype(k_cache.dtype))
     v_cache = v_cache.at[slot_idx, positions].set(v_new.astype(v_cache.dtype))
     return k_cache, v_cache
+
+
+# ---------------------------------------------------------------------------
+# Rows chosen by a learned indexer (models/keye.py)
+# ---------------------------------------------------------------------------
+
+
+def index_scores(qi: jax.Array, w: jax.Array, ki: jax.Array) -> jax.Array:
+    """The indexer's score of every key for every query, float32:
+    ``I(t, s) = sum_j w[t, j] * ReLU(qi[t, j] . ki[s])`` over the indexer's
+    heads ``j``, which share ONE key a row. ``qi`` [B, T, Hi, c], ``w`` [B, T,
+    Hi], ``ki`` [B, C, c'] with ``c' >= c`` (a row stored on whole lane tiles:
+    its first ``c`` values are the key, the rest zeros, and the query is
+    padded to match so that the stored row is contracted as it lies) -> [B,
+    T, C]. One product over all heads, then the heads' weighted sum as
+    float32 products and adds, not a matmul (the chip's default matmul rounds
+    float32 operands to bfloat16, which moved a decode step's scores by 0.3%
+    and one chosen row in eleven against the same program's prefill). The
+    compiler folds the sum into the product's epilogue: the per-head scores
+    of 2,048 queries against 24k keys (3.2 GB) are never written, where a
+    loop a head at a time wrote and read its running sum sixteen times
+    (1.98 ms against 10.0: PR 58's builder's chip runs; PERF.md, PR 59)."""
+    c = qi.shape[-1]
+    if ki.shape[-1] != c:
+        qi = jnp.pad(qi, ((0, 0), (0, 0), (0, 0), (0, ki.shape[-1] - c)))
+    dots = jnp.einsum("bthc,bsc->bhts", qi, ki, preferred_element_type=jnp.float32)
+    return jnp.sum(jnp.moveaxis(w.astype(jnp.float32), 2, 1)[..., None] * jax.nn.relu(dots), axis=1)
+
+
+def topk_rows_mask(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
+    """[N, C] float32 scores, [N, C] bool -> [N, C] bool: a row's ``k`` valid
+    columns of largest score (all of them where it has ``k`` or fewer),
+    ties at the threshold to the EARLIER column, as ``jax.lax.top_k`` breaks
+    them. The threshold is ``ops.sampling._topk_threshold``'s (~32
+    compare-and-count passes, no sort), snapped to the smallest score it
+    keeps so that the tie rule is exact: a block of 1,024 queries against
+    24k keys is 25 M scores, of which a sort a row would be the whole
+    prefill (PERF.md, PR 59)."""
+    from .sampling import _topk_threshold
+
+    least = jnp.min(jnp.where(valid, scores, jnp.inf), axis=-1, keepdims=True)
+    least = jnp.where(jnp.isfinite(least), least, 0.0)  # a row with no valid column
+    kk = jnp.minimum(k, jnp.sum(valid, axis=-1))
+    floor = _topk_threshold(jnp.where(valid, scores, least), kk)
+    thr = jnp.min(jnp.where(valid & (scores >= floor), scores, jnp.inf), axis=-1, keepdims=True)
+    above = valid & (scores > thr)
+    tied = valid & (scores == thr)
+    room = kk[:, None] - jnp.sum(above, axis=-1, keepdims=True)
+    # the running count that orders the tied columns is a dozen passes over the block on its own, and almost no
+    # row needs it: where every row's tied columns all fit (one score at the threshold, room for one) it is skipped
+    crowded = jnp.any(jnp.sum(tied, axis=-1, keepdims=True) > room)
+    return above | jax.lax.cond(crowded, lambda: tied & (jnp.cumsum(tied, axis=-1) <= room), lambda: tied)
+
+
+def topk_rows(scores: jax.Array, valid: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """[N, C] scores, [N, C] bool -> (columns [N, k] int32, chosen [N, k]
+    bool): the same choice as :func:`topk_rows_mask` as a list, for a walk
+    that fetches rows by their index. ``jax.lax.top_k`` gives equal values
+    in index order, which is the tie rule; a row with fewer than ``k`` valid
+    columns pads its list (``chosen`` False, column 0). At 16 lanes of 26k
+    scores the chip takes 0.39 ms for this and 0.58 for the threshold and a
+    compaction of its mask (PR 58's builder's chip runs; PERF.md, PR 59)."""
+    k = min(k, scores.shape[-1])
+    values, columns = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
+    chosen = values > -jnp.inf
+    return jnp.where(chosen, columns, 0).astype(jnp.int32), chosen

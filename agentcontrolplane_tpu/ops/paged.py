@@ -50,9 +50,13 @@ TRASH_PAGE = 0
 
 def init_kv_pages(
     n_layers: int, num_pages: int, page_size: int, n_kv_heads: int, head_dim: int,
-    dtype, quantize: bool = False,
+    dtype, quantize: bool = False, index_width: int = 0,
 ) -> dict:
     """Page pools [L, NP, P, H_kv * d] per k/v (the module's text). With
+    ``index_width`` a THIRD leaf rides the same page ids, ``"ik"`` [L, NP, P,
+    index_width]: the sparse indexer's key a token and layer
+    (``models/keye.py``), committed with K and V by :func:`kv_commit` and
+    shared, swapped and freed with its pages as any leaf is. With
     ``quantize`` the values are int8 and per-row-per-head f32 scales ride
     page-shaped twins ("ks"/"vs", [L, NP, P, H_kv]) indexed by the SAME page
     ids — scale storage is allocated, shared, swapped, and freed with its
@@ -66,7 +70,10 @@ def init_kv_pages(
             "ks": jnp.zeros(rows + (n_kv_heads,), dtype=jnp.float32),
             "vs": jnp.zeros(rows + (n_kv_heads,), dtype=jnp.float32),
         }
-    return {"k": jnp.zeros(rows + (width,), dtype=dtype), "v": jnp.zeros(rows + (width,), dtype=dtype)}
+    pool = {"k": jnp.zeros(rows + (width,), dtype=dtype), "v": jnp.zeros(rows + (width,), dtype=dtype)}
+    if index_width:
+        pool["ik"] = jnp.zeros(rows + (index_width,), dtype=dtype)
+    return pool
 
 
 def flat_pages(a: jax.Array) -> jax.Array:
@@ -460,6 +467,72 @@ def latent_decode_attention_reference_cache_plus_new(
     out = jnp.einsum("sht,stv->shv", p, rows[..., :value_width], precision=prec)
     out = out + p_self[..., None] * new[:, None, :value_width]
     return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]).astype(q.dtype)
+
+
+def sparse_decode_attention_reference_cache_plus_new(
+    q: jax.Array,  # [S, H, d]
+    pool: dict,  # {"k", "v", "ik"}: [L * NP, P, width] each (:func:`flat_pages`), WITHOUT the new token
+    block_tables: jax.Array,  # [S, max_pages]: ids of the flattened pool (:func:`layer_tables`)
+    seq_lens: jax.Array,  # [S] — tokens valid in the pages (excl. new)
+    new: dict,  # {"k", "v": [S, H_kv, d], "ik": [S, index width as stored]}: the new token's rows
+    qi: jax.Array,  # [S, Hi, c]: the indexer's queries, roped
+    wi: jax.Array,  # [S, Hi]: its heads' weights
+    topk: int,
+    given: Optional[jax.Array] = None,  # [S, topk] int32 positions, -1 none: a choice given, not made
+) -> tuple[jax.Array, jax.Array]:
+    """A decode step of attention over rows CHOSEN by a learned indexer
+    (``models/keye.py``), the new token's own row among the candidates:
+    every cached row of a lane is scored through ``ik`` (``index_scores``: a
+    gather of the lane's pages of the small leaf, 128 B a row where K and V
+    are 2 KiB), the ``topk`` of largest score are chosen (``index_select``:
+    ``ops.attention.topk_rows``; a lane with fewer rows takes them all, its
+    list padded and masked: one program either side of ``topk``), and K and
+    V are fetched BY ROW through the block table (``sparse_walk``: a chosen
+    position ``p`` is row ``table[p // P] * P + p % P`` of the pool
+    flattened over pages and rows; at one row in six to thirteen chosen
+    nearly every page holds one, so a walk by pages would read what the
+    dense walk reads) and attended densely, grouped. XLA's gather of
+    ``[S, topk, H_kv * d]`` rows; a kernel that walks by rows is
+    ``paged_sparse_walk``'s name to take (ROADMAP). -> (out [S, H, d],
+    positions chosen [S, topk] int32, -1 where a lane had fewer)."""
+    from .attention import index_scores, topk_rows
+
+    S, H, d = q.shape
+    P = pool["k"].shape[1]
+    C = block_tables.shape[1] * P
+    pos = jnp.arange(C, dtype=jnp.int32)
+    if given is None:
+        with jax.named_scope("index_scores"):
+            rows = pool["ik"][block_tables].reshape(S, C, -1)  # the lane's whole table, as the dense reference gathers
+            cached = index_scores(qi[:, None], wi[:, None], rows)[:, 0]  # [S, C]
+            own = index_scores(qi[:, None], wi[:, None], new["ik"][:, None])[:, 0]  # [S, 1]
+            scores = jnp.where(pos[None] == seq_lens[:, None], own, cached)
+        with jax.named_scope("index_select"):
+            chosen_pos, chosen = topk_rows(scores, pos[None] <= seq_lens[:, None], topk)
+    else:
+        chosen_pos, chosen = jnp.maximum(given, 0), given >= 0
+    with jax.named_scope("sparse_walk"):
+        # the page of a chosen position by a compare against the table's own index and a masked sum, which fuse
+        # to one pass: a gather of 32,768 single ids took 0.29 ms a layer, two thirds of the choice itself
+        hit = (chosen_pos // P)[:, :, None] == jnp.arange(block_tables.shape[1], dtype=chosen_pos.dtype)
+        page = jnp.sum(jnp.where(hit, block_tables[:, None, :], 0), axis=-1)
+        flat_row = page * P + chosen_pos % P
+        is_new = (chosen_pos == seq_lens[:, None]) & chosen
+
+        def fetch(name):
+            leaf = pool[name]
+            got = leaf.reshape((leaf.shape[0] * P,) + leaf.shape[2:])[flat_row]  # [S, topk, H_kv * d]
+            got = jnp.where(is_new[..., None], new[name].reshape(S, 1, -1).astype(got.dtype), got)
+            return got.reshape(S, got.shape[1], -1, d)
+
+        k, v = fetch("k"), fetch("v")
+        H_kv = k.shape[2]
+        q4 = q.reshape(S, H_kv, H // H_kv, d)
+        logits = jnp.einsum("skrd,snkd->skrn", q4, k, preferred_element_type=jnp.float32) * (d ** -0.5)
+        logits = jnp.where(chosen[:, None, None, :], logits, NEG_INF)
+        p = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("skrn,snkd->skrd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return out.reshape(S, H, d).astype(q.dtype), jnp.where(chosen, chosen_pos, -1)
 
 
 class PageAllocator:

@@ -89,23 +89,36 @@ def yarn_scale_frequencies(
 
 def apply_rope(
     x: jax.Array,  # [..., T, H, d]
-    positions: jax.Array,  # [..., T] int32
+    positions: jax.Array,  # [..., T] int32; with `sections`, [..., len(sections), T]
     theta: float = 500000.0,
     scaling: "tuple[float, float, float, int] | None" = None,
     yarn: "tuple[float, int, float, float, float] | None" = None,
+    sections: "tuple[int, ...] | None" = None,
 ) -> jax.Array:
     """Rotate q or k by position. Computed in float32, cast back.
     ``scaling`` = (factor, low_freq_factor, high_freq_factor,
     original_max_seq) applies the Llama-3.1 frequency rescale; ``yarn`` =
     (factor, original_max_seq, beta_fast, beta_slow, attention_factor)
-    YaRN's, cos and sin times its attention factor."""
+    YaRN's, cos and sin times its attention factor. ``sections`` (the
+    source's ``mrope_section``, summing to ``d / 2``) gives a token one
+    position an axis (time, height, width: ``models/keye.py``): frequency
+    ``i`` turns by the position of the axis whose section holds ``i``, the
+    halves rotated as ever. Equal rows give the one-position result bit for
+    bit: the same products of the same floats."""
     d = x.shape[-1]
+    if sections is not None and (sum(sections) != d // 2 or positions.shape[-2] != len(sections)):
+        raise ValueError(f"sections {sections} must sum to {d // 2} and positions {positions.shape} carry one row each")
     inv_freq = rope_frequencies(d, theta)  # [d/2]
     if scaling is not None:
         inv_freq = llama3_scale_frequencies(inv_freq, *scaling)
     if yarn is not None:
         inv_freq = yarn_scale_frequencies(inv_freq, *yarn[:4], theta)
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., T, d/2]
+    if sections is None:
+        positions = positions[..., None]
+    else:  # [..., A, T] -> [..., T, d/2]: each frequency's own axis' position
+        axis_of = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections), total_repeat_length=d // 2)
+        positions = jnp.take(jnp.moveaxis(positions, -2, -1), axis_of, axis=-1)
+    angles = positions.astype(jnp.float32) * inv_freq  # [..., T, d/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, d/2]
     sin = jnp.sin(angles)[..., None, :]
     if yarn is not None:

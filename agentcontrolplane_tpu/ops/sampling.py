@@ -32,7 +32,10 @@ def _topk_threshold(logits: jax.Array, k: jax.Array, turns=_BISECT_ITERS) -> jax
     """Per-row value t such that count(logits >= t) >= k and masking
     logits < t keeps the k largest (plus boundary ties). k >= V keeps all.
     [S, V], [S] -> [S, 1]. After no turn t is the row's minimum, which
-    masks nothing."""
+    masks nothing. Also the threshold of ``ops.attention.topk_rows_mask``
+    (sparse attention's prefill: each query's ``topk``-th largest index
+    score among 12k-26k, ``models/keye.py``), which snaps it to a score and
+    breaks ties itself: a change here is held to both callers' tests."""
     lo = jnp.min(logits, axis=-1)  # threshold below lowest keeps everything
     hi = jnp.max(logits, axis=-1)
 

@@ -16,12 +16,12 @@ import jax
 import numpy as np
 import pytest
 
-from agentcontrolplane_tpu.models import jamba, kanana, lfm2, llama, mellum, nemotron_h, ouro, preset
+from agentcontrolplane_tpu.models import jamba, kanana, keye, lfm2, llama, mellum, nemotron_h, ouro, preset
 from agentcontrolplane_tpu.testing import compiled, greedy_reference, padded_logits
 
 FAMILIES = {"llama": ("tiny", llama), "lfm2": ("lfm2-tiny", lfm2), "jamba": ("jamba-tiny", jamba),
             "mellum": ("mellum-tiny", mellum), "kanana": ("kanana-tiny", kanana), "ouro": ("ouro-tiny", ouro),
-            "nemotron_h": ("nemotron-h-tiny", nemotron_h)}
+            "nemotron_h": ("nemotron-h-tiny", nemotron_h), "keye": ("keye-tiny", keye)}
 WIDTH = 128
 
 

@@ -21,7 +21,7 @@ from agentcontrolplane_tpu.engine.lanes import DECODE, PREFILL
 from agentcontrolplane_tpu.observability import scopes
 
 SLOTS, PAGE, PAGES, CTX, BLOCK = 4, 16, 33, 128, 4
-FAMILIES = ["tiny", "moe-tiny", "lfm2-tiny", "jamba-tiny", "mellum-tiny", "kanana-tiny", "nemotron-h-tiny"]
+FAMILIES = ["tiny", "moe-tiny", "lfm2-tiny", "jamba-tiny", "mellum-tiny", "kanana-tiny", "nemotron-h-tiny", "keye-tiny"]
 
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([a-z][\w\-]*)\(")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
