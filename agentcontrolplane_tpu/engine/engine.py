@@ -1627,6 +1627,8 @@ class Engine:
             # what one page costs over every cache layer and leaf (scale
             # twins among them), read off the pool and not off the config
             self.page_bytes = page_bytes(self.cache, self.num_pages)  # acp: mirror (immutable)
+            self.page_leaves = sorted(  # acp: mirror (immutable) — the leaves those bytes are summed over
+                name for name, a in pool_leaves(self.cache).items() if a.shape[1] == self.num_pages)
             self._allocator = PageAllocator(
                 self.num_pages, track_scales=self.quantize_kv
             )
@@ -2363,6 +2365,8 @@ class Engine:
                 "table_uploads": self.table_uploads,
                 # what one page costs over every cache layer and leaf
                 "page_bytes": self.page_bytes,
+                # the leaves a page is made of, by name (scale twins among them)
+                "leaves": list(self.page_leaves),
             }
             model = programs(self.config)  # not the engine thread's fields: any thread asks
             if model.has_state:

@@ -46,8 +46,9 @@ first that hits), and ``shardings`` is the family's own layout over a mesh
 (``params(mesh, config, abstract)``, ``paged_pool(mesh, quantize_kv)``) or
 None: a family that gives none has its weights and its whole cache tree held
 whole on every device (``models.kanana`` is such a family with no state a
-slot: a latent row a token in the pool, counters beside it; ``models.keye`` is another: K and V pages and the sparse indexer's key a token
-beside them, a third leaf of the pool). ``walk(config,
+slot: a latent row a token in the pool, counters beside it; ``models.keye``
+is another: a token's K row and V row as one row of the leaf ``kv`` and the
+sparse indexer's key a token beside it, ``ik``). ``walk(config,
 page_rows, dtype, tp, quantize_kv)`` names the compiled walk a decode step
 takes on a TPU as ``(pages_per_turn, turns_in_flight, bytes_in_flight)``, or
 None where the geometry falls to the XLA reference; ``page_leaf`` names the
@@ -277,12 +278,12 @@ _OURO = SimpleNamespace(
     decode_step_paged=ouro.decode_step_paged,
     counters=ouro.counters, describe_counters=ouro.describe_counters,
 )
-# kanana's seam (no state a slot, counters on the device) over the dense family's K and V pages and a third
-# leaf beside them, the sparse indexer's key a token (`ik`); its decode step chooses rows and fetches them by
-# row through XLA's gather, so it names no compiled walk
+# kanana's seam (no state a slot, counters on the device) over a pool of its own, two leaves: `kv`, a token's K
+# row and V row as one row of 32-bit words, and `ik`, the sparse indexer's key a token; its decode step chooses rows and fetches
+# them by row through XLA's gather, one slice a chosen position, so it names no compiled walk
 _KEYE = SimpleNamespace(
     family="keye", has_state=False, window_cache=False, draft_step=None, draft_rows=1,
-    refusals=keye.refusals, shardings=None, walk=lambda *geometry: None, page_leaf="k",
+    refusals=keye.refusals, shardings=None, walk=lambda *geometry: None, page_leaf="kv",
     init_params=keye.init_params, init_paged_cache=keye.init_paged_cache,
     prefill_paged_batch=keye.prefill_paged_batch,
     prefill_paged_continue=keye.prefill_paged_continue,

@@ -34,16 +34,27 @@ With ``u = RMSNorm(x_t)`` for the token at position ``t``:
   softmax, the ``experts_per_token`` largest renormalised), of which this chip
   holds ``experts_held``; nothing stands in for the others.
 
-**What is cached**: K and V as every family's pool holds them, and a THIRD
-leaf, ``ik`` ``[n_layers, pages, P, ik_stored]``: the indexer's key a token
-and layer after its norm and rope, ``index_head_dim`` (64) values stored on a
-whole 128-lane tile (``ik_stored`` = 128, the rest zeros): 256 B a row
-beside K and V's 2,048. Stored 64 wide the chip pads the row to its tile
-anyway and the gather that scores a lane's rows runs 5.5 times slower (2.65
-ms against 0.48 a layer at 16 lanes of 26,624 rows: PR 58's builder's chip
-run); two rows a tile is a layout for a kernel (ROADMAP M10 (a)). The leaf
-rides the page ids of K and V: ``ops.paged.kv_commit`` writes it, and the
-engine's page helpers move it, with no line of its own.
+**What is cached**, a pool of the family's own (``ops.paged.init_row_pages``):
+``kv`` ``[n_layers, pages, P, n_kv_heads * head_dim]`` uint32 in bfloat16
+(twice as wide in float32), a token's K row (its heads side by side) and its
+V row as ONE row of 32-bit words, K's 16 bits low and V's high
+(``ops.paged.pack_kv_rows``): 2 KiB at the published sizes, the bfloat16
+values bit for bit. A chosen position's K and V are always read together,
+and XLA's gather of rows costs by the slice and the lane tiles it touches,
+not by its bytes: one gather of four tiles of whole words where two leaves
+took two gathers of four tiles of packed bfloat16 rows, and the halves come
+apart where the gathered rows are regrouped by head, a pass the two-leaf
+walk made too (side by side as ONE bfloat16 row of eight tiles the gather
+cost 1.43 of a 1 KiB row's and the split a pass of its own: PERF.md, PR 60).
+And ``ik`` ``[n_layers, pages, P, ik_stored]``: the
+indexer's key a token and layer after its norm and rope, ``index_head_dim``
+(64) values stored on a whole 128-lane tile (``ik_stored`` = 128, the rest
+zeros): 256 B a row beside K and V's 2,048. Stored 64 wide the chip pads the
+row to its tile anyway and the gather that scores a lane's rows runs 5.5
+times slower (2.65 ms against 0.48 a layer at 16 lanes of 26,624 rows: PR
+58's builder's chip run). Both leaves ride one list of page ids:
+``ops.paged.kv_commit`` writes them, and the engine's page helpers move
+them, with no line of their own.
 
 Two attention paths, equal in exact arithmetic
 (``tests/engine/test_keye.py``):
@@ -62,12 +73,13 @@ Two attention paths, equal in exact arithmetic
   24,576 rows against 64.6, PR 58's builder's chip runs); it skips no key
   the mask hides (ROADMAP M10 (b)) and refuses a ``T`` that is not whole
   blocks. Off the TPU it is the plain ``causal_attention(keep=)``, at the
-  tiny sizes a CPU runs. A continuation gathers a slot's ``ik`` rows as it
-  gathers K and V.
+  tiny sizes a CPU runs. A continuation gathers a slot's ``ik`` pages as it
+  gathers its ``kv`` pages, once, and splits them.
 - the decode step: ``ops.paged.sparse_decode_attention_reference_cache_plus_new``:
-  every cached row scored through ``ik``, ``jax.lax.top_k``, K and V fetched
-  by row. ONE program for lanes under and over ``index_topk`` rows: a lane
-  under it chooses all its rows and its list is padded and masked.
+  every cached row scored through ``ik``, ``jax.lax.top_k``, the chosen
+  ``kv`` rows fetched by row, once. ONE program for lanes under and over
+  ``index_topk`` rows: a lane under it chooses all its rows and its list is
+  padded and masked.
 
 Layout for XLA: ONE scan over the layers, all of one kind; the pool never
 passes through a conditional (PERF.md, PR 37); every program commits all
@@ -96,8 +108,8 @@ from ..ops.attention import (
 from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
 from ..ops.paged import (
-    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, layer_tables,
-    pool_leaves, sparse_decode_attention_reference_cache_plus_new,
+    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_row_pages, layer_tables,
+    pack_kv_rows, pool_leaves, sparse_decode_attention_reference_cache_plus_new, unpack_kv_rows,
 )
 from ..ops.rope import apply_rope
 from .lfm2 import _embed, _final_norm, _head_logits, _mm
@@ -207,9 +219,10 @@ def _layer_norm(x, weight, bias, eps):
 
 
 def _attention_op(h, w, c: KeyeConfig, positions, attend, positions3=None):
-    """-> (Op output [B, T, D], the layer's new rows ``{"k", "v": [B, T,
-    H_kv, d], "ik": [B, T, 1, ik_stored]}`` for the pool, whatever ``attend``
-    hands on). ``attend(q, k, v, qi [B, T, Hi, c], wi [B, T, Hi] float32, ik
+    """-> (Op output [B, T, D], the layer's new rows ``{"kv": [B, T, 1,
+    words] uint32 (``pack_kv_rows`` of K's and V's heads side by side), "ik":
+    [B, T, 1, ik_stored]}`` for the pool, whatever ``attend`` hands on).
+    ``attend(q, k, v, qi [B, T, Hi, c], wi [B, T, Hi] float32, ik
     [B, T, ik_stored]) -> ([B, T, H, d], extra)`` is the path. ``positions``
     [B, T] are the temporal ones; ``positions3`` [B, 3, T], where given, turn
     q and k an axis a section."""
@@ -232,7 +245,8 @@ def _attention_op(h, w, c: KeyeConfig, positions, attend, positions3=None):
     out, extra = attend(q, k, v, qi, wi, ik)
     with jax.named_scope("attn_out"):
         op = _mm(out.reshape(B, T, c.n_heads * c.head_dim), w["wo"])
-    return op, {"k": k, "v": v, "ik": ik[:, :, None, :]}, extra
+    kv = pack_kv_rows(*(t.reshape(B, T, -1).astype(h.dtype) for t in (k, v)))
+    return op, {"kv": kv[:, :, None, :], "ik": ik[:, :, None, :]}, extra
 
 
 def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=None, select=None, positions3=None,
@@ -245,7 +259,6 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
     -> (x, every layer's new rows ``{leaf: [n_layers, B, T, ...]}``, expert
     counters, and with ``tell`` what each layer chose: ``(rows, experts)``
     stacked over the layers, else None)."""
-    dt = x.dtype
     norm = lambda x, w: rms_norm(x, w, c.norm_eps)  # noqa: E731
     ff = params["ff"]
     stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
@@ -266,8 +279,7 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
                 logits = h.astype(jnp.float32) @ mine["router"].astype(jnp.float32)
                 experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), c.experts_per_token)[1] if chosen is None else chosen
             y, m = _experts(h, mine, stacks, index, c, valid, chosen)
-            out = {name: r.astype(dt) for name, r in rows.items()}
-            return (x + y, counts + m), (out, (told, experts) if tell else None)
+            return (x + y, counts + m), (rows, (told, experts) if tell else None)
 
     counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
     (x, counts), (rows, told) = jax.lax.scan(
@@ -384,7 +396,7 @@ def forward(params: dict, tokens: jax.Array, config: KeyeConfig, positions3: jax
 
 
 # ---------------------------------------------------------------------------
-# Serving: K, V and the indexer's key a token in the paged pool
+# Serving: a token's K|V row and the indexer's key in the paged pool
 # ---------------------------------------------------------------------------
 
 
@@ -394,7 +406,8 @@ def init_paged_cache(config: KeyeConfig, num_pages: int, page_size: int, quantiz
     if quantize_kv:
         raise ValueError("the keye family keeps K, V and the indexer's keys in the model's dtype: an int8 key of the "
                          "indexer needs a check that sees the rows it mis-chooses (ROADMAP M10 (c))")
-    cache = init_kv_pages(c.n_layers, num_pages, page_size, c.n_kv_heads, c.head_dim, c.dtype, index_width=c.ik_stored)
+    words = c.n_kv_heads * c.head_dim * jnp.dtype(c.dtype).itemsize // 2
+    cache = init_row_pages(c.n_layers, num_pages, page_size, kv=(words, jnp.uint32), ik=(c.ik_stored, c.dtype))
     cache["state"] = {"counts": jnp.zeros((2, 1 + COUNTS_HEAD + len(c.held) + SPARSE_COUNTS), jnp.uint32)}
     return cache
 
@@ -412,9 +425,9 @@ def _committed(cache, pool, counts, c: KeyeConfig, row, scored, live):
 
 def prefill_paged_batch(params, cache, tokens, lengths, page_ids, config: KeyeConfig, route=None, select=None,
                         tell: bool = False, interpret: bool = False):
-    """B whole prompts in one dispatch: each row's K, V and indexer keys into
-    its pages. -> (cache, logits [B, V]), and with ``tell`` what every layer
-    chose (``forward``'s)."""
+    """B whole prompts in one dispatch: each row's K|V rows and indexer keys
+    into its pages. -> (cache, logits [B, V]), and with ``tell`` what every
+    layer chose (``forward``'s)."""
     c = config
     B, T = tokens.shape
     positions, valid = _rows(lengths, jnp.zeros((B,), jnp.int32), T)
@@ -460,15 +473,15 @@ def _masked_attention(q, k, v, mask):
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, c: KeyeConfig):
     """Rows that start at ``starts`` (page-aligned) attend over the rows
-    chosen among their gathered prefix pages plus themselves: K, V and the
-    indexer's keys gathered (the whole table's, whatever the start), the
-    queries' scores made against cache and own rows together. Nothing is
-    written here. -> (x normed, new rows, counts, ik rows scored, live rows
-    a query)."""
+    chosen among their gathered prefix pages plus themselves: the ``kv`` pages
+    (split into K and V) and the indexer's keys gathered (the whole table's,
+    whatever the start), the queries' scores made against cache and own rows
+    together. Nothing is written here. -> (x normed, new rows, counts, ik
+    rows scored, live rows a query)."""
     B, T = tokens.shape
     positions, valid = _rows(lengths, starts, T)
     pool = pool_leaves(cache)
-    NP, P = pool["k"].shape[1:3]
+    NP, P = pool["kv"].shape[1:3]
     M = block_tables.shape[1]
     row_pos = jnp.arange(M * P)
     cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
@@ -478,12 +491,10 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
         def attend(q, k, v, qi, wi, ik):
             with jax.named_scope("full_gather"):
                 ids = layer_tables(block_tables, i, NP)
-                got = {name: gather_pages(pool, name, ids, q.dtype, heads).reshape((B, M * P) + shape)
-                       for name, heads, shape in (("k", c.n_kv_heads, k.shape[2:]), ("v", c.n_kv_heads, v.shape[2:]),
-                                                  ("ik", 1, (c.ik_stored,)))}
-                keys = jnp.concatenate([got["k"], k], axis=1)
-                values = jnp.concatenate([got["v"], v], axis=1)
-                index_keys = jnp.concatenate([got["ik"], ik], axis=1)
+                got = unpack_kv_rows(flat_pages(pool["kv"])[ids].reshape(B, M * P, -1), q.dtype)
+                keys, values = (jnp.concatenate([t.reshape((B, M * P) + k.shape[2:]), new], axis=1)
+                                for t, new in zip(got, (k, v)))
+                index_keys = jnp.concatenate([gather_pages(pool, "ik", ids, q.dtype, 1).reshape(B, M * P, -1), ik], axis=1)
 
             def block(blk):
                 q_b, qi_b, wi_b, pos_b = blk
@@ -526,7 +537,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
                       use_pallas: bool = False, mesh=None, route=None, select=None, tell: bool = False):
     """One token for lanes 0..S-1 (lane b is slot b): every layer scores the
     lane's cached rows through ``ik``, chooses, and attends over the chosen
-    rows of K and V fetched by row. ``use_pallas`` and ``mesh`` are what the
+    ``kv`` rows fetched by row. ``use_pallas`` and ``mesh`` are what the
     engine hands every family's step; neither changes anything here: the
     walk by rows is XLA's gather (module text). ``select`` [n_layers, S,
     index_topk] int32 positions (-1 none) is a choice handed in; with
@@ -535,14 +546,15 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     c = config
     S = tokens.shape[0]
     pool = pool_leaves(cache)
-    NP, P = pool["k"].shape[1:3]
+    NP, P = pool["kv"].shape[1:3]
     flat = {name: flat_pages(a) for name, a in pool.items()}
 
     def make_attend(i, given):
         def attend(q, k, v, qi, wi, ik):
             out, chosen = sparse_decode_attention_reference_cache_plus_new(
                 q[:, 0], flat, layer_tables(block_tables, i, NP), seq_lens,
-                {"k": k[:, 0], "v": v[:, 0], "ik": ik[:, 0]}, qi[:, 0], wi[:, 0], c.index_topk, given)
+                {"kv": pack_kv_rows(k.reshape(S, -1), v.reshape(S, -1)), "ik": ik[:, 0]}, qi[:, 0], wi[:, 0],
+                c.index_topk, given)
             return out[:, None], chosen
 
         return attend

@@ -17,6 +17,15 @@ flat view (:func:`commit_whole_pages`, :func:`commit_tokens`): a scatter
 windowed over the layer axis makes the compiler keep the pool layer-minor
 and relay all of it.
 
+Two families walk by another unit and keep a row of their own
+(:func:`init_row_pages`), on the same page ids and through the same helpers.
+Latent attention (``models/kanana.py``) keeps one leaf ``kv`` whose row is
+key and value at once. Attention over rows CHOSEN by a learned indexer
+(``models/keye.py``) keeps ``kv``, a token's K row and V row as ONE row of
+32-bit words (:func:`pack_kv_rows`: a chosen position's K and V are one slice
+of one gather, at the price of K's alone), and beside it ``ik``, the
+indexer's key a token.
+
 A layer that attends over a window keeps a **ring** a slot instead of a
 page list (``ring_*`` below, ``models/mellum.py``): ``ring`` pages of a pool
 of its own, fixed to the slot (slot ``s`` owns pages ``s * ring ..``, the
@@ -50,13 +59,9 @@ TRASH_PAGE = 0
 
 def init_kv_pages(
     n_layers: int, num_pages: int, page_size: int, n_kv_heads: int, head_dim: int,
-    dtype, quantize: bool = False, index_width: int = 0,
+    dtype, quantize: bool = False,
 ) -> dict:
     """Page pools [L, NP, P, H_kv * d] per k/v (the module's text). With
-    ``index_width`` a THIRD leaf rides the same page ids, ``"ik"`` [L, NP, P,
-    index_width]: the sparse indexer's key a token and layer
-    (``models/keye.py``), committed with K and V by :func:`kv_commit` and
-    shared, swapped and freed with its pages as any leaf is. With
     ``quantize`` the values are int8 and per-row-per-head f32 scales ride
     page-shaped twins ("ks"/"vs", [L, NP, P, H_kv]) indexed by the SAME page
     ids — scale storage is allocated, shared, swapped, and freed with its
@@ -70,10 +75,7 @@ def init_kv_pages(
             "ks": jnp.zeros(rows + (n_kv_heads,), dtype=jnp.float32),
             "vs": jnp.zeros(rows + (n_kv_heads,), dtype=jnp.float32),
         }
-    pool = {"k": jnp.zeros(rows + (width,), dtype=dtype), "v": jnp.zeros(rows + (width,), dtype=dtype)}
-    if index_width:
-        pool["ik"] = jnp.zeros(rows + (index_width,), dtype=dtype)
-    return pool
+    return {"k": jnp.zeros(rows + (width,), dtype=dtype), "v": jnp.zeros(rows + (width,), dtype=dtype)}
 
 
 def flat_pages(a: jax.Array) -> jax.Array:
@@ -97,14 +99,48 @@ def set_pages(arr: jax.Array, page_ids: jax.Array, blocks: jax.Array) -> jax.Arr
         return flat.reshape(arr.shape)
 
 
+def init_row_pages(n_layers: int, num_pages: int, page_size: int, **leaves: tuple) -> dict:
+    """A pool of leaves ``{name: [L, NP, P, width]}`` for a family whose row
+    is its own (``leaves``: ``name=(width, dtype)``): no ``"k"`` / ``"v"``
+    pair, no scale twin. Every helper below takes a pool's leaves as they
+    come, so these are committed, gathered, shared, swapped and restored as
+    the ``k`` / ``v`` pools are."""
+    return {name: jnp.zeros((n_layers, num_pages, page_size, width), dtype=dtype) for name, (width, dtype) in leaves.items()}
+
+
+def pack_kv_rows(k: jax.Array, v: jax.Array) -> jax.Array:
+    """A K row and a V row ``[..., n]`` of one dtype (2 or 4 bytes wide) as
+    ONE row of 32-bit words ``[..., n * itemsize / 2]`` uint32: word ``j``
+    holds the ``j``-th 16 bits of K's row in its low half and of V's row in
+    its high half, bit for bit (:func:`unpack_kv_rows` gives both back). On
+    the chip a bfloat16 array packs two ROWS a 32-bit sublane word, so a
+    gather of one bfloat16 row of ``2n`` values touches ``2n / 128`` lane
+    tiles and costs by them, twice a row of ``n``; this row is ``n / 128``
+    tiles of whole words and is fetched at the price of K's alone (PERF.md,
+    PR 60)."""
+    def halves(t):  # [..., n] -> uint16 [..., n * itemsize / 2] (a wider dtype comes apart on a minor axis)
+        return jax.lax.bitcast_convert_type(t, jnp.uint16).reshape(t.shape[:-1] + (-1,)).astype(jnp.uint32)
+
+    return halves(k) | (halves(v) << 16)
+
+
+def unpack_kv_rows(words: jax.Array, dtype) -> tuple[jax.Array, jax.Array]:
+    """:func:`pack_kv_rows`' rows back: uint32 ``[..., m]`` -> (K, V), each
+    ``[..., 2 * m / itemsize]`` of ``dtype``, the values that were packed."""
+    per = jnp.dtype(dtype).itemsize // 2
+
+    def whole(u):  # uint16 [..., m] -> dtype: `per` halves a value, joined on a minor axis
+        return jax.lax.bitcast_convert_type(u.reshape(u.shape[:-1] + (-1, per)) if per > 1 else u, dtype)
+
+    return whole((words & 0xFFFF).astype(jnp.uint16)), whole((words >> 16).astype(jnp.uint16))
+
+
 def init_latent_pages(n_layers: int, num_pages: int, page_size: int, width: int, dtype) -> dict:
-    """A pool of ONE leaf ``[L, NP, P, width]``: a row a token that is key
-    and value at once (latent attention, ``models/kanana.py``: the normed
-    latent and the roped shared key side by side, one head of ``width``).
-    There is no ``"v"`` and no scale twin; every helper below takes a pool's
-    leaves as they come, so this one is committed, gathered, shared, swapped
-    and restored as the ``k`` / ``v`` pools are."""
-    return {"kv": jnp.zeros((n_layers, num_pages, page_size, width), dtype=dtype)}
+    """A pool of ONE leaf ``"kv"`` ``[L, NP, P, width]``: a row a token that
+    is key and value at once (latent attention, ``models/kanana.py``: the
+    normed latent and the roped shared key side by side, one head of
+    ``width``)."""
+    return init_row_pages(n_layers, num_pages, page_size, kv=(width, dtype))
 
 
 def pool_leaves(cache: dict) -> dict:
@@ -471,10 +507,10 @@ def latent_decode_attention_reference_cache_plus_new(
 
 def sparse_decode_attention_reference_cache_plus_new(
     q: jax.Array,  # [S, H, d]
-    pool: dict,  # {"k", "v", "ik"}: [L * NP, P, width] each (:func:`flat_pages`), WITHOUT the new token
+    pool: dict,  # {"kv": [L * NP, P, words] uint32 (pack_kv_rows), "ik": [L * NP, P, width]} (:func:`flat_pages`), WITHOUT the new token
     block_tables: jax.Array,  # [S, max_pages]: ids of the flattened pool (:func:`layer_tables`)
     seq_lens: jax.Array,  # [S] — tokens valid in the pages (excl. new)
-    new: dict,  # {"k", "v": [S, H_kv, d], "ik": [S, index width as stored]}: the new token's rows
+    new: dict,  # {"kv": [S, words] uint32 (pack_kv_rows), "ik": [S, index width as stored]}: the new token's rows
     qi: jax.Array,  # [S, Hi, c]: the indexer's queries, roped
     wi: jax.Array,  # [S, Hi]: its heads' weights
     topk: int,
@@ -491,14 +527,18 @@ def sparse_decode_attention_reference_cache_plus_new(
     position ``p`` is row ``table[p // P] * P + p % P`` of the pool
     flattened over pages and rows; at one row in six to thirteen chosen
     nearly every page holds one, so a walk by pages would read what the
-    dense walk reads) and attended densely, grouped. XLA's gather of
-    ``[S, topk, H_kv * d]`` rows; a kernel that walks by rows is
-    ``paged_sparse_walk``'s name to take (ROADMAP). -> (out [S, H, d],
-    positions chosen [S, topk] int32, -1 where a lane had fewer)."""
+    dense walk reads) and attended densely, grouped. The family's pool holds
+    a token's K row and V row as ONE row of words of the leaf ``kv``
+    (:func:`pack_kv_rows`): they are always chosen together, and XLA's gather
+    costs by the slice and the lane tiles it touches, not by its bytes, so
+    the walk is ONE gather of ``[S, topk, words]``, taken apart after the
+    new token's row went in (PERF.md, PR 60). ``q``'s dtype is the rows'. ->
+    (out [S, H, d], positions chosen [S, topk] int32, -1 where a lane had
+    fewer)."""
     from .attention import index_scores, topk_rows
 
     S, H, d = q.shape
-    P = pool["k"].shape[1]
+    P = pool["kv"].shape[1]
     C = block_tables.shape[1] * P
     pos = jnp.arange(C, dtype=jnp.int32)
     if given is None:
@@ -518,14 +558,10 @@ def sparse_decode_attention_reference_cache_plus_new(
         page = jnp.sum(jnp.where(hit, block_tables[:, None, :], 0), axis=-1)
         flat_row = page * P + chosen_pos % P
         is_new = (chosen_pos == seq_lens[:, None]) & chosen
-
-        def fetch(name):
-            leaf = pool[name]
-            got = leaf.reshape((leaf.shape[0] * P,) + leaf.shape[2:])[flat_row]  # [S, topk, H_kv * d]
-            got = jnp.where(is_new[..., None], new[name].reshape(S, 1, -1).astype(got.dtype), got)
-            return got.reshape(S, got.shape[1], -1, d)
-
-        k, v = fetch("k"), fetch("v")
+        leaf = pool["kv"]
+        got = leaf.reshape((leaf.shape[0] * P, leaf.shape[2]))[flat_row]  # [S, topk, words]
+        got = jnp.where(is_new[..., None], new["kv"][:, None, :], got)
+        k, v = (t.reshape(S, t.shape[1], -1, d) for t in unpack_kv_rows(got, q.dtype))
         H_kv = k.shape[2]
         q4 = q.reshape(S, H_kv, H // H_kv, d)
         logits = jnp.einsum("skrd,snkd->skrn", q4, k, preferred_element_type=jnp.float32) * (d ** -0.5)
@@ -660,7 +696,8 @@ class HostKVEntry:
     bytes VERBATIM plus their per-row scale rows (``ks`` / ``vs``, [L, cut,
     H_kv] f32) — the host tier holds ~2x the tokens per byte, and a restore
     is bit-exact by construction (no requantization round trip); for a
-    latent pool the one leaf ``kv`` [L, cut, width]."""
+    latent pool the one leaf ``kv`` [L, cut, width]; for the sparse
+    family's ``kv`` [L, cut, words] uint32 (:func:`pack_kv_rows`) and ``ik``."""
 
     rid: str
     tokens: tuple

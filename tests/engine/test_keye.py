@@ -1,7 +1,8 @@
 """Keye-VL-2.0's language model through the normal path: the program against
 the plain reference (acpbench/families/keyevl_reference.py, which imports
-nothing of the program) for `forward`, prefill, continuation over cached `ik`
-rows and decode through pages past `topk`, with the choice of rows and
+nothing of the program) for `forward`, prefill, continuation over cached `kv` and `ik`
+rows and decode through pages past `topk` (one gather of rows of K|V words, bit
+for bit the two-leaf walk of PR 59), with the choice of rows and
 experts free and with it given; a lane under `topk` beside one over it in one
 step; three-axis rope on a grid span against the reference, and equal rows
 equal to the one-position rope bit for bit; the threshold selection against
@@ -85,7 +86,7 @@ def test_forward_agrees_with_the_plain_reference_free_and_given():
 @pytest.mark.parametrize("seed", [11, 2**31 + 7], ids=["seed-11", "seed-over-31-bits"])
 def test_program_agrees_with_the_plain_reference_through_the_pool(seed):
     """The family's cache check as every run of the cell makes it: the
-    prompt's prefill and 8 decode steps through the pool's three leaves, free
+    prompt's prefill and 8 decode steps through the pool's two leaves, free
     and telling; the longer prefills given; the reference given the same
     (prompts of 24-56 choose 8 rows of theirs, page 8). The choices agree
     with the free float32 reference's to the row."""
@@ -130,16 +131,180 @@ def test_a_lane_under_topk_beside_one_over_it_in_one_step_and_the_counters():
                              "rows_dense": (6 + 21 + 34) * 3, "lanes_past_topk": 2}
     assert got["prefill"]["rows_dense"] == sum(n * (n + 1) // 2 for n in (5, 20, 33)) * 3
     assert (got["topk"], got["ik_row_bytes_stored"], got["layers"]) == (8, 128 * 4, 3)
-    # the three leaves: K and V a row of the heads side by side, the indexer's key on a whole lane tile, the rest zeros
-    assert {n: a.shape[2:] for n, a in paged.pool_leaves(cache).items()} == {"k": (PAGE, 32), "v": (PAGE, 32), "ik": (PAGE, 128)}
+    # the two leaves: a token's K row and V row (each its heads side by side) as ONE row of 32-bit words (float32
+    # here: two words a value pair), the indexer's key on a whole lane tile, the rest zeros
+    assert {n: (a.shape[2:], a.dtype) for n, a in paged.pool_leaves(cache).items()} == {
+        "kv": ((PAGE, 64), jnp.uint32), "ik": ((PAGE, 128), jnp.float32)}
     ik = np.asarray(cache["ik"])
     assert np.abs(ik[:, 1, :, :8]).min() > 0 and not ik[..., 8:].any()
 
 
-def test_a_continuation_reads_the_ik_rows_it_did_not_write():
-    """16 tokens whole, the rest continued over the gathered K, V and `ik`
-    rows: the logits of `forward`; with the cached `ik` rows zeroed the
-    choice, and so the logits, change."""
+def two_leaf_walk(q, pool, block_tables, seq_lens, new, qi, wi, topk, given=None):
+    """PR 59's `ops.paged.sparse_decode_attention_reference_cache_plus_new`,
+    kept here as the double: K and V in two leaves, `fetch("k")` and
+    `fetch("v")`, two gathers by the same row ids."""
+    S, H, d = q.shape
+    P = pool["k"].shape[1]
+    C = block_tables.shape[1] * P
+    pos = jnp.arange(C, dtype=jnp.int32)
+    if given is None:
+        rows = pool["ik"][block_tables].reshape(S, C, -1)
+        cached = attention.index_scores(qi[:, None], wi[:, None], rows)[:, 0]
+        own = attention.index_scores(qi[:, None], wi[:, None], new["ik"][:, None])[:, 0]
+        scores = jnp.where(pos[None] == seq_lens[:, None], own, cached)
+        chosen_pos, chosen = attention.topk_rows(scores, pos[None] <= seq_lens[:, None], topk)
+    else:
+        chosen_pos, chosen = jnp.maximum(given, 0), given >= 0
+    hit = (chosen_pos // P)[:, :, None] == jnp.arange(block_tables.shape[1], dtype=chosen_pos.dtype)
+    page = jnp.sum(jnp.where(hit, block_tables[:, None, :], 0), axis=-1)
+    flat_row = page * P + chosen_pos % P
+    is_new = (chosen_pos == seq_lens[:, None]) & chosen
+
+    def fetch(name):
+        leaf = pool[name]
+        got = leaf.reshape((leaf.shape[0] * P,) + leaf.shape[2:])[flat_row]
+        got = jnp.where(is_new[..., None], new[name].reshape(S, 1, -1).astype(got.dtype), got)
+        return got.reshape(S, got.shape[1], -1, d)
+
+    with jax.named_scope("sparse_walk"):
+        k, v = fetch("k"), fetch("v")
+    H_kv = k.shape[2]
+    q4 = q.reshape(S, H_kv, H // H_kv, d)
+    logits = jnp.einsum("skrd,snkd->skrn", q4, k, preferred_element_type=jnp.float32) * (d ** -0.5)
+    logits = jnp.where(chosen[:, None, None, :], logits, paged.NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("skrn,snkd->skrd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return out.reshape(S, H, d).astype(q.dtype), jnp.where(chosen, chosen_pos, -1)
+
+
+def through_two_leaves(q, pool, block_tables, seq_lens, new, qi, wi, topk, given=None):
+    """The one-leaf pool handed to the double: the `kv` leaf and the new
+    token's row taken apart, the K rows one leaf and the V rows another."""
+    (k, v), (new_k, new_v) = paged.unpack_kv_rows(pool["kv"], q.dtype), paged.unpack_kv_rows(new["kv"], q.dtype)
+    heads = lambda t: t.reshape(t.shape[0], -1, q.shape[-1])  # noqa: E731
+    return two_leaf_walk(q, {"k": k, "v": v, "ik": pool["ik"]}, block_tables, seq_lens,
+                         {"k": heads(new_k), "v": heads(new_v), "ik": new["ik"]}, qi, wi, topk, given)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.float16], ids=["bfloat16", "float32", "float16"])
+def test_a_packed_row_gives_back_the_k_and_v_rows_bit_for_bit(dtype):
+    """`pack_kv_rows`: 16 bits of K low and of V high in every word, a row
+    of 2 KiB at 4 KV heads of 128 in bfloat16; any bit pattern comes back as
+    it went in (NaNs, infinities and denormals among them: the words are
+    made from bits, not values), under `jit` as outside it."""
+    bits = jnp.uint16 if jnp.dtype(dtype).itemsize == 2 else jnp.uint32
+    k, v = (jax.lax.bitcast_convert_type(jax.random.bits(jax.random.key(i), (3, 5, 512), bits), dtype) for i in (0, 1))
+    for fn in (lambda f: f, jax.jit):
+        words = fn(paged.pack_kv_rows)(k, v)
+        assert words.dtype == jnp.uint32 and words.shape == (3, 5, 512 * jnp.dtype(dtype).itemsize // 2)
+        back = fn(lambda w: paged.unpack_kv_rows(w, dtype))(words)
+        for got, want in zip(back, (k, v)):
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.array_equal(jax.lax.bitcast_convert_type(got, bits), jax.lax.bitcast_convert_type(want, bits))
+    if dtype == jnp.bfloat16:  # K is the low half: a word of K alone reads as K's bits
+        assert np.array_equal(paged.pack_kv_rows(k, jnp.zeros_like(v)), jax.lax.bitcast_convert_type(k, jnp.uint16))
+
+
+def prefilled_lanes(pc, params, dtype):
+    """Lanes of 5, 20 and 33 cached rows (one under `topk`, two over it) after their prefill, in `dtype`."""
+    pc = dataclasses.replace(pc, dtype=dtype)
+    params = jax.tree.map(lambda a: a.astype(dtype), params)
+    tokens, _ = text_tokens(B=3, T=40, seed=2)
+    lengths = jnp.asarray([5, 20, 33], jnp.int32)
+    cache, tables = paged_setup(pc, 3, 6)
+    ids = jnp.where(jnp.arange(40 // PAGE)[None] < -(-lengths // PAGE)[:, None], tables[:, : 40 // PAGE], 0)
+    prompt = jnp.where(jnp.arange(40)[None] < lengths[:, None], tokens, 0)
+    cache, _ = keye.prefill_paged_batch(params, cache, prompt, lengths, ids, pc)
+    return pc, params, cache, tables, jnp.asarray(tokens)[jnp.arange(3), lengths], lengths
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("given", [False, True], ids=["free", "given"])
+def test_a_decode_step_through_one_leaf_is_the_two_leaf_walk_bit_for_bit(monkeypatch, given, dtype):
+    """The same step, the same pool, with the walk this family had at PR 59
+    in the place of its own (`through_two_leaves`): the logits, the positions
+    chosen and the pool after the commit are equal bit for bit, for a lane
+    under `topk` beside two over it, with the choice free and with one handed
+    in (`select=`: each lane's latest rows, another choice than its own)."""
+    family, pc, mesh, params = built()
+    pc, params, cache, tables, last, lengths = prefilled_lanes(pc, params, dtype)
+    select = None
+    if given:
+        latest = lengths[:, None] - jnp.arange(pc.index_topk)[None]  # a lane's latest 8 of its rows and the new one
+        select = jnp.broadcast_to(jnp.where(latest >= 0, latest, -1), (pc.n_layers, 3, pc.index_topk)).astype(jnp.int32)
+
+    def step():
+        return keye.decode_step_paged(params, dict(cache), last, lengths, tables, jnp.ones((3,), bool), pc,
+                                      select=select, tell=True)
+
+    one, logits, (rows, _experts) = step()
+    monkeypatch.setattr(keye, "sparse_decode_attention_reference_cache_plus_new", through_two_leaves)
+    two, want, (want_rows, _experts) = step()
+    as_bits = lambda a: np.asarray(a if a.dtype == jnp.uint32 else a.astype(jnp.float32))  # noqa: E731
+    assert np.array_equal(as_bits(logits), as_bits(want)) and np.isfinite(as_bits(logits)).all()
+    assert np.array_equal(rows, want_rows) and (np.asarray(rows)[:, 0] >= 0).sum() == 6 * pc.n_layers
+    assert all(np.array_equal(as_bits(one[name]), as_bits(two[name])) for name in ("kv", "ik"))
+    if given:  # the rows handed in are the rows told, and another choice than the free one: `select=` is read
+        assert np.array_equal(rows, select)
+        assert not np.array_equal(rows, keye.decode_step_paged(params, dict(cache), last, lengths, tables,
+                                                               jnp.ones((3,), bool), pc, tell=True)[2][0])
+
+
+def equations(jaxpr, stack=""):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, each with the scopes it was traced under."""
+    for eqn in jaxpr.eqns:
+        under = f"{stack}/{eqn.source_info.name_stack}"
+        yield eqn.primitive.name, under
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub, under)
+
+
+def test_the_decode_program_holds_one_gather_under_sparse_walk():
+    """Counted by primitive in the decode step's jaxpr: ONE gather in the
+    scope `sparse_walk` (the chosen K|V rows) and one in `index_scores` (the
+    lane's `ik` pages); the double's walk, in its place, holds two. A later
+    edit that splits the row's fetch again is seen here, at no chip time."""
+    family, pc, mesh, params = built()
+    pc, params, cache, tables, last, lengths = prefilled_lanes(pc, params, jnp.float32)
+
+    def gathers(walk):
+        with pytest.MonkeyPatch.context() as mp:
+            if walk is not None:
+                mp.setattr(keye, "sparse_decode_attention_reference_cache_plus_new", walk)
+            traced = jax.make_jaxpr(lambda p, ca: keye.decode_step_paged(p, ca, last, lengths, tables, jnp.ones((3,), bool), pc))(
+                params, cache)
+        found = [under for name, under in equations(traced.jaxpr) if name == "gather"]
+        return {leaf: sum(leaf in under for under in found) for leaf in ("sparse_walk", "index_scores")}
+
+    assert gathers(None) == {"sparse_walk": 1, "index_scores": 1}
+    assert gathers(through_two_leaves)["sparse_walk"] == 2
+
+
+def test_the_cells_page_costs_what_it_cost_in_two_leaves_fewer():
+    """`models.page_bytes` of the cell's file: 2,304 B a token and layer
+    (K and V 2 x 4 x 128 x 2 B as one row of 512 words, the indexer's key 256
+    B) x 16 rows x 8 layers, what the three leaves of PR 59 cost; the pool is
+    two leaves."""
+    from agentcontrolplane_tpu import models
+
+    conf = next(c for c in spec.benchmark()["configs"] if c["name"] == "keye-vl2-30b-a3b-bf16-v5e1-ep8")
+    file = spec.load_json(spec.os.path.join(spec.ROOT, conf["file"]))
+    program = spec.family(file).program_config(file)
+    assert models.page_bytes(program, 16) == 2304 * 16 * 8
+    shapes = jax.eval_shape(lambda: keye.init_paged_cache(program, 9, 16))
+    assert {n: (a.shape, a.dtype) for n, a in paged.pool_leaves(shapes).items()} == {
+        "kv": ((8, 9, 16, 512), jnp.uint32), "ik": ((8, 9, 16, 128), jnp.bfloat16)}
+    assert paged.page_bytes(shapes, 9) == 2304 * 16 * 8
+
+
+def test_a_continuation_reads_the_kv_and_ik_rows_it_did_not_write():
+    """16 tokens whole, the rest continued over the gathered `kv` pages
+    (taken apart into K and V) and `ik` rows: the logits of `forward`; with
+    the cached `ik` rows zeroed the choice, and so the logits, change; with
+    the K half of the cached `kv` words zeroed, or the V half, or the halves
+    the other way round, the logits change: each half is read as what it was
+    written as. The words the prefill wrote come apart into the K and the V
+    of those tokens as written out here."""
     family, pc, mesh, params = built()
     tokens, _ = text_tokens(B=2, T=40, seed=3)
     full = keye.forward(params, jnp.asarray(tokens), pc)
@@ -152,9 +317,19 @@ def test_a_continuation_reads_the_ik_rows_it_did_not_write():
     ids = jnp.where(jnp.arange(3)[None] < -(-rest // PAGE)[:, None], tables[:, 2:5], 0)
     _cache, logits = keye.prefill_paged_continue(params, dict(cache), tail, rest, first, ids, tables, pc)
     np.testing.assert_allclose(logits, full[jnp.arange(2), lengths - 1], atol=3e-5, rtol=3e-5)
-    blind = {**cache, "ik": jnp.zeros_like(cache["ik"])}
-    _cache, other = keye.prefill_paged_continue(params, blind, tail, rest, first, ids, tables, pc)
-    assert float(jnp.abs(other - logits).max()) > 0.05
+    kv = cache["kv"]
+    for name, moved in (("ik", jnp.zeros_like(cache["ik"])), ("kv", (kv >> 16) << 16), ("kv", kv & 0xFFFF),
+                        ("kv", (kv >> 16) | (kv << 16))):
+        _cache, other = keye.prefill_paged_continue(params, {**cache, name: moved}, tail, rest, first, ids, tables, pc)
+        assert float(jnp.abs(other - logits).max()) > 0.05
+    # the first layer's rows as written: K (normed and roped) then V of the token's normed embedding
+    w = jax.tree.map(lambda a: a[0], params["attn"])
+    h = keye.rms_norm(params["embed"][jnp.asarray(tokens[:, :16])], w["ln1"], pc.norm_eps)
+    k = keye.rms_norm((h @ w["wk"]).reshape(2, 16, pc.n_kv_heads, pc.head_dim), w["k_norm"], pc.norm_eps)
+    k = apply_rope(k, jnp.broadcast_to(jnp.arange(16), (2, 16)), pc.rope_theta)
+    wrote_k, wrote_v = paged.unpack_kv_rows(kv[0][tables[:, :2]].reshape(2, 16, -1), jnp.float32)  # [B, T, heads merged]
+    np.testing.assert_allclose(wrote_k, k.reshape(2, 16, -1), atol=1e-5)
+    np.testing.assert_allclose(wrote_v, h @ w["wv"], atol=1e-5)
 
 
 @pytest.mark.parametrize("tier, block", [(16, 8), (48, 8), (24, 24), (20, 8)])
@@ -216,12 +391,14 @@ def test_causal_attention_with_keep_is_the_masked_softmax_and_the_blocked_attent
 
 def test_without_the_new_arguments_the_shared_ops_trace_to_the_parents_jaxprs():
     """`apply_rope` without `sections=`, `causal_attention` without `keep=`
-    and `init_kv_pages` without `index_width=` are what every other family
-    calls: each traces to the jaxpr the parent commit's function gave, by a
-    digest of its text taken on that tree (PR 57's, jax 0.9.0)."""
+    and `init_kv_pages` (which has no argument of this family's: its pool is
+    `init_row_pages`') are what every other family calls: each traces to the
+    jaxpr the function gave before the family came, by a digest of its text
+    taken on that tree (PR 57's, jax 0.9.0); the latent family's pool is the
+    one leaf it was."""
     import hashlib
+    import inspect
 
-    from agentcontrolplane_tpu.ops import paged
     from agentcontrolplane_tpu.ops import rope
 
     x, pos = jnp.zeros((2, 24, 4, 16)), jnp.zeros((2, 24), jnp.int32)
@@ -240,6 +417,9 @@ def test_without_the_new_arguments_the_shared_ops_trace_to_the_parents_jaxprs():
                "causal": "a14a73cf6b14e240", "causal_positions_window": "91cf105253f8c67b",
                "kv_pages": "1619fe8929d5a94e", "kv_pages_int8": "c1b42e2616f15e37"}
     assert {name: hashlib.sha256(str(j).encode()).hexdigest()[:16] for name, j in traced.items()} == parents
+    assert "index_width" not in inspect.signature(paged.init_kv_pages).parameters
+    latent = jax.eval_shape(lambda: paged.init_latent_pages(2, 9, 16, 640, jnp.bfloat16))
+    assert {n: (a.shape, a.dtype) for n, a in latent.items()} == {"kv": ((2, 9, 16, 640), jnp.bfloat16)}
 
 
 # -- the selection ---------------------------------------------------------------------------------
@@ -368,16 +548,29 @@ def test_an_unknown_control_is_an_error_and_the_family_documents_its_own():
 @pytest.mark.parametrize("control,number,least", [({"ik_int8": True}, "select_cache_miss_all", 1e-3),
                                                   ({"kv_int8": True}, "cache_excess", 0.5),
                                                   ({"ik_crossed": True}, "select_cache_miss", 0.3)])
-def test_each_cache_control_is_seen(control, number, least):
+def test_each_cache_control_is_seen(monkeypatch, control, number, least):
     """int8 indexer keys choose other rows in the decode steps (the logits,
     compared with the choice given, do not see it: the choices' own number
     does: at 8 values a key and 8 rows of 40 a few rows in a thousand, on the chip's sizes PERF.md has it);
-    int8 K and V show in the cache's excess."""
+    int8 K and V show in the cache's excess. The family file's `kv_int8`
+    names the leaves `k` and `v`, which the pool no longer has (PERF.md, Open
+    after PR 60 (1)): here the `kv` leaf itself is rounded a row and head (2 x
+    `n_kv_heads` heads, as `_as_int8` rounds) before each of the program's
+    decode steps, that is between its prefill and them and after each."""
     family, pc, mesh, params = built()
     s = check.sample(FILE["check"], FILE["vocab_size"], PAGE, 3)
+    step = keye.decode_step_paged
 
-    def reading(**kw):
-        pre, dec, chosen = family.cache_readings(FILE, pc, params, mesh, s, False, **kw)
+    def over_int8_rows(p, cache, *a, **kw):
+        k, v = paged.unpack_kv_rows(cache["kv"], pc.dtype)
+        rounded = family_module._as_int8(jnp.concatenate([k, v], axis=-1), 2 * pc.n_kv_heads)
+        return step(p, {**cache, "kv": paged.pack_kv_rows(*jnp.split(rounded, 2, axis=-1))}, *a, **kw)
+
+    def reading(kv_int8=False, **kw):
+        with monkeypatch.context() as mp:
+            if kv_int8:
+                mp.setattr(keye, "decode_step_paged", over_int8_rows)
+            pre, dec, chosen = family.cache_readings(FILE, pc, params, mesh, s, False, **kw)
         want = family.reference_logits(FILE, params, s["tokens"], s["rows"])  # given what that program chose
         return {**check.compare((pre, dec), want), **chosen}
 

@@ -22,7 +22,7 @@ _KEYE_SLOTS, _KEYE_PAGES, _KEYE_CTX = 16, 26625, 26624  # acpbench/configs/keye-
 def _keye(v5e, monkeypatch):
     """The benchmark's cut of the published config (8 layers, 16 of 128
     experts, an eighth of the vocabulary), abstract weights and the pool of
-    three leaves placed on one described chip, the expert layer steered onto
+    two leaves placed on one described chip, the expert layer steered onto
     its kernel."""
     import dataclasses
     import functools
@@ -46,9 +46,9 @@ def test_keye_decode_block_chooses_rows_and_copies_no_pool(v5e, monkeypatch):
     """The ENGINE's decode block (`make_decode_block` around the family's
     step through `models.programs`) at the cell's 16 lanes of 26,624 tokens:
     the resident set is the issue's arithmetic (1.71 GB of weights, 7.85 GB
-    of K, V and the indexer's keys stored a lane tile wide), the pool is
+    of K|V rows and the indexer's keys stored a lane tile wide), the pool is
     donated and no op copies a leaf of it (a layer's whole `ik` is 109 MB,
-    K or V 0.87 GB), the block's temporaries (16 lanes' gathered index keys
+    `kv` 1.74 GB), a layer's chosen rows are one gather of 32-bit words, the block's temporaries (16 lanes' gathered index keys
     and chosen rows) a small fraction of it, and the choice is `top_k`, not
     a sort of 26,624 scores."""
     import re
@@ -70,11 +70,15 @@ def test_keye_decode_block_chooses_rows_and_copies_no_pool(v5e, monkeypatch):
         vec(S, _KEYE_CTX // PAGE)).compile()
     text = compiled.as_text()
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
-    pool = sum(cache[name].size * 2 for name in ("k", "v", "ik"))
+    pool = sum(cache[name].size * cache[name].dtype.itemsize for name in ("kv", "ik"))
     assert abs(weights - 1.71e9) < 0.01e9 and abs(pool - 7.85e9) < 0.01e9
+    assert (cache["kv"].shape, cache["kv"].dtype) == ((8, _KEYE_PAGES, PAGE, 512), jnp.uint32)
     assert cache["ik"].shape == (8, _KEYE_PAGES, PAGE, 128)
-    for width in (512, 128):
-        assert not re.search(rf"= bf16\[8,{_KEYE_PAGES},16,{width}\]\S* copy\(", text), f"a copy of a {width}-wide leaf"
+    # the chosen rows of a layer are ONE gather of 16 lanes x 2,048 rows of 512 words (K | V), none of bfloat16 rows
+    gathers = re.findall(r"= (\w+)\[(?:32768|16,2048),(\d+)\]\S* gather\(", text)
+    assert gathers == [("u32", "512")], gathers
+    for leaf in ("u32[8,{},16,512]", "bf16[8,{},16,128]"):
+        assert not re.search(rf"= {re.escape(leaf.format(_KEYE_PAGES))}\S* copy\(", text), f"a copy of the leaf {leaf}"
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool
     assert mem.temp_size_in_bytes < pool // 20, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB"
@@ -145,7 +149,7 @@ def test_keye_prefill_compiles_under_the_engines_own_sampler_with_one_mask_body_
 def test_keye_continuation_compiles_at_the_spills_rows_over_a_full_table(v5e, monkeypatch):
     """`prefill_paged_continue` at the 16,384-row continuation the engine's
     prewarm runs (a resumed request's tail over a slot's whole table of
-    26,624 rows): K, V and the indexer's keys gathered, the queries' choice
+    26,624 rows): the `kv` pages and the indexer's keys gathered, the queries' choice
     in blocks of 512 rows, the attention under it folded 2,048 keys at a
     time; it fits beside the resident set."""
     keye, c, params, cache, vec = _keye(v5e, monkeypatch)

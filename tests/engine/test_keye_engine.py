@@ -11,6 +11,7 @@ weights, the invariant checker armed.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -55,8 +56,14 @@ def test_engine_serves_lanes_under_and_past_topk_in_one_batch_and_counts_what_it
             futures = [eng.submit(p, GREEDY) for p in ps]
         for p, f in zip(ps, futures):
             assert f.result(300).tokens == reference(p, 10)
-        assert set(eng.cache) == {"k", "v", "ik", "state"} and eng.cache["ik"].shape[1:] == (80, 8, CFG.ik_stored)
+        assert set(eng.cache) == {"kv", "ik", "state"} and eng.cache["ik"].shape[1:] == (80, 8, CFG.ik_stored)
+        # K and V as one row of 32-bit words: float32 here, two words a pair of values (bfloat16: one)
+        assert eng.cache["kv"].shape == (CFG.n_layers, 80, 8, 2 * CFG.n_kv_heads * CFG.head_dim)
+        assert eng.cache["kv"].dtype == jnp.uint32
         st = eng.stats()
+        # what says the one-row pool is what serves: two leaves a page, K and V's bytes and the key's a row and layer
+        row = (2 * CFG.n_kv_heads * CFG.head_dim + CFG.ik_stored) * 4
+        assert st["kv_pages"]["leaves"] == ["ik", "kv"] and st["kv_pages"]["page_bytes"] == row * 8 * CFG.n_layers
         sparse, moe = st["sparse"], st["moe"]
         assert (sparse["topk"], sparse["layers"], sparse["index_heads"], sparse["index_values"]) == (8, 3, 4, 8)
         assert sparse["ik_row_bytes_stored"] == 128 * 4  # float32 here; 256 B in bfloat16
@@ -99,15 +106,26 @@ def test_chunked_prefill_reads_ik_rows_it_did_not_write():
 
 
 @pytest.mark.parametrize("host_kv_bytes", [0, 1 << 22], ids=["recompute", "host-swap"])
-def test_preempt_and_resume_carry_the_pools_three_leaves(host_kv_bytes):
+def test_preempt_and_resume_carry_the_pools_two_leaves(host_kv_bytes):
     """An oversubscribed pool preempts; the resumed request recomputes, or
-    has its pages restored from a host entry whose leaves are K, V and `ik`,
-    moved by the engine's leaf-generic helpers with no line for the third."""
+    has its pages restored from a host entry whose leaves are `kv` and `ik`,
+    moved by the engine's leaf-generic helpers with no line for either: the
+    pages a swap-in wrote hold the entry's rows bit for bit, both leaves."""
     eng = make_engine(kv_pages=14, host_kv_bytes=host_kv_bytes)
-    entries = []
+    entries, restored = [], []
     if host_kv_bytes:
-        put = eng._host_pool.put
+        put, swap_in = eng._host_pool.put, eng._swap_in_rows
         eng._host_pool.put = lambda e: (entries.append(e), put(e))[1]
+
+        def swap_in_and_read_back(slot, entry, start, n):
+            took = swap_in(slot, entry, start, n)
+            pages = np.asarray(eng._slot_pages[slot][start // 8: (start + n) // 8])
+            for name, rows in entry.rows.items():
+                back = np.asarray(eng.cache[name])[:, pages]  # [L, pages, P, width]
+                restored.append((name, np.array_equal(back.reshape(back.shape[0], n, -1), rows[:, start: start + n])))
+            return took
+
+        eng._swap_in_rows = swap_in_and_read_back
     try:
         sp = SamplingParams(temperature=0.0, max_tokens=12)
         ps = prompts(*[20] * 6, seed=1)
@@ -120,7 +138,10 @@ def test_preempt_and_resume_carry_the_pools_three_leaves(host_kv_bytes):
         if host_kv_bytes:
             assert eng.kv_swap_outs >= 1 and eng.kv_swap_ins >= 1 and entries
             for e in entries:
-                assert set(e.rows) == {"k", "v", "ik"} and e.rows["ik"].shape == (CFG.n_layers, e.cut, CFG.ik_stored)
+                assert set(e.rows) == {"kv", "ik"} and e.rows["ik"].shape == (CFG.n_layers, e.cut, CFG.ik_stored)
+                assert e.rows["kv"].shape == (CFG.n_layers, e.cut, 2 * CFG.n_kv_heads * CFG.head_dim) and e.rows["kv"].any()
+                assert e.rows["kv"].dtype == np.uint32  # the pool's words as they are: a restore is bit for bit
+            assert {name for name, _ in restored} == {"kv", "ik"} and all(same for _, same in restored)
     finally:
         eng.stop()
 
@@ -140,7 +161,7 @@ def test_a_prefix_hit_a_park_and_an_export_serve_it():
         eng.submit(turn1, sp, park=True).result(120)
         assert eng.generate(turn2, sp).tokens == reference(turn2, 6) and eng.park_adoptions == 1
         out = eng.submit(turn2, sp, export_kv=True).result(120)
-        assert set(out.kv_handoff.rows) == {"k", "v", "ik"}
+        assert set(out.kv_handoff.rows) == {"kv", "ik"}
         assert other.inject_host_kv(out.kv_handoff)
         assert other.generate(turn2, sp).tokens == out.tokens and other.kv_swap_ins == 1
     finally:
@@ -164,5 +185,5 @@ def test_tensor_parallelism_and_int8_pages_are_refused_in_words_and_the_seam_nam
     with pytest.raises(ValueError, match="int8 key of the"):
         keye.init_paged_cache(CFG, 9, 8, quantize_kv=True)
     seam = programs(CFG)
-    assert (seam.family, seam.has_state, seam.window_cache, seam.draft_step, seam.page_leaf) == ("keye", False, False, None, "k")
+    assert (seam.family, seam.has_state, seam.window_cache, seam.draft_step, seam.page_leaf) == ("keye", False, False, None, "kv")
     assert seam.walk(CFG, 16, CFG.dtype, 1, False) is None and seam.shardings is None
