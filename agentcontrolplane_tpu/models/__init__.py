@@ -48,7 +48,10 @@ None: a family that gives none has its weights and its whole cache tree held
 whole on every device (``models.kanana`` is such a family with no state a
 slot: a latent row a token in the pool, counters beside it; ``models.keye``
 is another: a token's K row and V row as one row of the leaf ``kv`` and the
-sparse indexer's key a token beside it, ``ik``). ``walk(config,
+sparse indexer's key a token beside it, ``ik``; ``models.dots`` keeps latent
+rows at two ranks, the full layers' and their indexer's keys on the page list
+and the sliding layers' in a ring a slot, so it takes ``lanes`` and
+``mellum``'s refusals). ``walk(config,
 page_rows, dtype, tp, quantize_kv)`` names the compiled walk a decode step
 takes on a TPU as ``(pages_per_turn, turns_in_flight, bytes_in_flight)``, or
 None where the geometry falls to the XLA reference; ``page_leaf`` names the
@@ -65,7 +68,7 @@ weights), so ``config.n_layers`` counts its weights and
 
 from types import SimpleNamespace
 
-from . import exaone, jamba, kanana, keye, lfm2, llama, mellum, nemotron_h, ouro
+from . import dots, exaone, jamba, kanana, keye, lfm2, llama, mellum, nemotron_h, ouro
 from .llama import (
     PRESETS,
     LlamaConfig,
@@ -75,6 +78,7 @@ from .llama import (
     init_params,
     prefill,
 )
+from .dots import DotsConfig
 from .exaone import ExaoneConfig
 from .jamba import JambaConfig
 from .kanana import KananaConfig
@@ -86,7 +90,7 @@ from .ouro import OuroConfig
 
 __all__ = [
     "PRESETS", "LlamaConfig", "Lfm2Config", "JambaConfig", "MellumConfig", "KananaConfig", "OuroConfig", "ExaoneConfig",
-    "NemotronHConfig", "KeyeConfig",
+    "NemotronHConfig", "KeyeConfig", "DotsConfig",
     "decode_step", "forward", "init_kv_cache",
     "init_params", "kv_pages_that_fit", "page_bytes", "prefill", "preset", "programs",
 ]
@@ -94,7 +98,7 @@ __all__ = [
 
 def preset(name: str):
     """The config a name stands for, in whichever family has it."""
-    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone, nemotron_h, keye)]
+    tables = [module.PRESETS for module in (llama, lfm2, jamba, mellum, kanana, ouro, exaone, nemotron_h, keye, dots)]
     for table in tables:
         if name in table:
             return table[name]
@@ -222,14 +226,14 @@ _LLAMA = SimpleNamespace(
     decode_step_paged=llama.decode_step_paged,
 )
 
-def _with_state(family: str, m, window_cache: bool = False) -> SimpleNamespace:
+def _with_state(family: str, m, window_cache: bool = False, page_leaf: str = "k", walk=_kv_walk) -> SimpleNamespace:
     """A family with per-slot state: its module's programs take ``lanes``
     after the page ids; the engine hands both as one pair."""
     draft_step = getattr(m, "verify_step_paged", None)
     return SimpleNamespace(
         family=family, has_state=True, window_cache=window_cache,
-        refusals=_stateful_refusals(window_cache, draft_step is not None), shardings=None, walk=_kv_walk,
-        page_leaf="k", draft_step=draft_step, draft_rows=getattr(m, "ROWS", 1),
+        refusals=_stateful_refusals(window_cache, draft_step is not None), shardings=None, walk=walk,
+        page_leaf=page_leaf, draft_step=draft_step, draft_rows=getattr(m, "ROWS", 1),
         init_params=m.init_params,
         init_paged_cache=m.init_paged_cache,
         prefill_paged_batch=lambda params, cache, tokens, lengths, ids, config: (
@@ -291,9 +295,13 @@ _KEYE = SimpleNamespace(
     decode_step_paged=keye.decode_step_paged,
     counters=keye.counters, describe_counters=keye.describe_counters,
 )
+# mellum's seam (a ring a slot beside the page list, so its refusals) over latent rows at two ranks: the page list
+# holds the full layers' latent row `kv` and their indexer's key `ik`, the ring the sliding layers' latent row `wkv`;
+# a decode step chooses rows and fetches them by row, and gathers the ring, through XLA: it names no compiled walk
+_DOTS = _with_state("dots", dots, window_cache=True, page_leaf="kv", walk=lambda *geometry: None)
 _FAMILIES = {LlamaConfig: _LLAMA, Lfm2Config: _LFM2, JambaConfig: _JAMBA, MellumConfig: _MELLUM,
              KananaConfig: _KANANA, OuroConfig: _OURO, ExaoneConfig: _EXAONE, NemotronHConfig: _NEMOTRON_H,
-             KeyeConfig: _KEYE}
+             KeyeConfig: _KEYE, DotsConfig: _DOTS}
 
 
 def programs(config) -> SimpleNamespace:

@@ -318,7 +318,7 @@ def _row_blocks(t, rows: int):
     return jnp.moveaxis(t.reshape((B, T // rows, rows) + t.shape[2:]), 1, 0)
 
 
-def _prompt_mask(c: KeyeConfig, positions, qi, wi, ik):
+def _prompt_mask(c: KeyeConfig, positions, qi, wi, ik, tier: int | None = None):
     """[B, T, T] bool: the causal keys each query of a whole prompt chooses,
     ``MASK_BLOCK`` query rows at a time (index scores, then each row's
     ``index_topk``-th largest by ``topk_rows_mask``). The blocks of one TIER,
@@ -328,9 +328,13 @@ def _prompt_mask(c: KeyeConfig, positions, qi, wi, ik):
     causal pairs is rounded up to rectangles (21 of 36 squares of 4,096 at
     24,576 rows, where the pairs are 18; one map over the whole width would
     score all 36). A ``T`` that is not whole tiers (the CPU's tiny sizes) is
-    one tier, and one that is not whole blocks one block."""
+    one tier, and one that is not whole blocks one block. ``tier`` is the
+    tier's rows where they are not ``MASK_TIER`` (``models/dots.py`` asks for
+    one tier, the whole prompt: its masks are a twentieth of its prefill and
+    each tier is a body to compile)."""
     B, T = positions.shape
-    step = MASK_TIER if T % MASK_TIER == 0 else T
+    tier = tier or MASK_TIER
+    step = tier if T % tier == 0 else T
     R = MASK_BLOCK if step % MASK_BLOCK == 0 else step
     tiers = []
     for hi in range(step, T + 1, step):
@@ -364,8 +368,9 @@ def _whole_rows(c: KeyeConfig, positions, tell, interpret: bool = False):
                 if interpret or jax.default_backend() == "tpu":
                     from ..ops.pallas.masked_attention import masked_attention
 
-                    seen = mask.astype(jnp.int8)
-                    out = jnp.stack([masked_attention(q[b], k[b], v[b], seen[b], interpret=interpret) for b in range(B)])
+                    seen = mask.astype(jnp.int8)  # keys and values both `head_dim` wide, the scale the keys' own
+                    out = jnp.stack([masked_attention(q[b], k[b], v[b], seen[b], scale=c.head_dim ** -0.5,
+                                                      interpret=interpret) for b in range(B)])
                 else:
                     out = causal_attention(q, k, v, keep=mask)
             return out, _packed(mask) if tell else None
@@ -442,10 +447,11 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, config: KeyeCo
 
 
 def _masked_attention(q, k, v, mask):
-    """q [B, Tq, H, d] over keys [B, C, H_kv, d] under ``mask`` [B, Tq, C]:
-    dense where the keys are few, else ``KEY_BLOCK`` keys folded at a time
-    into an online softmax (the scores of 512 rows against 51k keys would be
-    3.3 GB at once)."""
+    """q [B, Tq, H, d] over keys [B, C, H_kv, d] and values [B, C, H_kv, dv]
+    (``dv`` need not be ``d``: ``models/dots.py``'s expanded latent rows)
+    under ``mask`` [B, Tq, C]: dense where the keys are few, else
+    ``KEY_BLOCK`` keys folded at a time into an online softmax (the scores
+    of 512 rows against 51k keys would be 3.3 GB at once)."""
     B, Tq, H, d = q.shape
     C = k.shape[1]
     n_rep = H // k.shape[2]
@@ -466,7 +472,7 @@ def _masked_attention(q, k, v, mask):
                                    seen[:, None], *carry, scale), None
 
     init = (jnp.full((B, H, Tq), -jnp.inf, jnp.float32), jnp.zeros((B, H, Tq), jnp.float32),
-            jnp.zeros((B, H, Tq, d), jnp.float32))
+            jnp.zeros((B, H, Tq, v.shape[-1]), jnp.float32))
     (_m, l, acc), _ = jax.lax.scan(step, init, blocks)
     return online_softmax_finalize(l, acc, q.dtype)
 

@@ -24,7 +24,9 @@ key and value at once. Attention over rows CHOSEN by a learned indexer
 (``models/keye.py``) keeps ``kv``, a token's K row and V row as ONE row of
 32-bit words (:func:`pack_kv_rows`: a chosen position's K and V are one slice
 of one gather, at the price of K's alone), and beside it ``ik``, the
-indexer's key a token.
+indexer's key a token. ``models/dots.py`` keeps three leaves of three widths:
+its full layers' latent row ``kv`` and their indexer's key ``ik`` on the page
+list, and its sliding layers' latent row ``wkv`` in a ring a slot.
 
 A layer that attends over a window keeps a **ring** a slot instead of a
 page list (``ring_*`` below, ``models/mellum.py``): ``ring`` pages of a pool
@@ -505,6 +507,46 @@ def latent_decode_attention_reference_cache_plus_new(
     return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]).astype(q.dtype)
 
 
+def chosen_rows(ik_pages, kv_rows_per_page: int, block_tables, seq_lens, new_ik, qi, wi, topk: int, given=None):
+    """What both decode steps over rows CHOSEN by a learned indexer share
+    (:func:`sparse_decode_attention_reference_cache_plus_new`,
+    :func:`sparse_latent_decode_attention_cache_plus_new`): every cached row
+    of a lane scored through the ``ik`` leaf with the new token's own score in
+    its place (``index_scores``), the ``topk`` of largest score chosen
+    (``index_select``; a lane with fewer rows takes them all, its list padded
+    and masked; ``given`` [S, topk] int32 positions, -1 none, is a choice
+    handed in); where each chosen position lies in the pool flattened over
+    pages and rows is :func:`chosen_flat_rows`'. -> (positions [S, topk] int32,
+    chosen [S, topk] bool)."""
+    from .attention import index_scores, topk_rows
+
+    S, P = block_tables.shape[0], kv_rows_per_page
+    C = block_tables.shape[1] * P
+    pos = jnp.arange(C, dtype=jnp.int32)
+    if given is None:
+        with jax.named_scope("index_scores"):
+            rows = ik_pages[block_tables].reshape(S, C, -1)  # the lane's whole table, as the dense reference gathers
+            cached = index_scores(qi[:, None], wi[:, None], rows)[:, 0]  # [S, C]
+            own = index_scores(qi[:, None], wi[:, None], new_ik[:, None])[:, 0]  # [S, 1]
+            scores = jnp.where(pos[None] == seq_lens[:, None], own, cached)
+        with jax.named_scope("index_select"):
+            chosen_pos, chosen = topk_rows(scores, pos[None] <= seq_lens[:, None], topk)
+    else:
+        chosen_pos, chosen = jnp.maximum(given, 0), given >= 0
+    return chosen_pos, chosen
+
+
+def chosen_flat_rows(chosen_pos, chosen, block_tables, seq_lens, P: int):
+    """(row of the pool flattened over pages and rows that holds each chosen
+    position, which chosen position is the new token's own): the page by a
+    compare against the table's own index and a masked sum, which fuse to one
+    pass (a gather of 32,768 single ids took 0.29 ms a layer, two thirds of
+    the choice itself)."""
+    hit = (chosen_pos // P)[:, :, None] == jnp.arange(block_tables.shape[1], dtype=chosen_pos.dtype)
+    page = jnp.sum(jnp.where(hit, block_tables[:, None, :], 0), axis=-1)
+    return page * P + chosen_pos % P, (chosen_pos == seq_lens[:, None]) & chosen
+
+
 def sparse_decode_attention_reference_cache_plus_new(
     q: jax.Array,  # [S, H, d]
     pool: dict,  # {"kv": [L * NP, P, words] uint32 (pack_kv_rows), "ik": [L * NP, P, width]} (:func:`flat_pages`), WITHOUT the new token
@@ -535,29 +577,11 @@ def sparse_decode_attention_reference_cache_plus_new(
     new token's row went in (PERF.md, PR 60). ``q``'s dtype is the rows'. ->
     (out [S, H, d], positions chosen [S, topk] int32, -1 where a lane had
     fewer)."""
-    from .attention import index_scores, topk_rows
-
     S, H, d = q.shape
     P = pool["kv"].shape[1]
-    C = block_tables.shape[1] * P
-    pos = jnp.arange(C, dtype=jnp.int32)
-    if given is None:
-        with jax.named_scope("index_scores"):
-            rows = pool["ik"][block_tables].reshape(S, C, -1)  # the lane's whole table, as the dense reference gathers
-            cached = index_scores(qi[:, None], wi[:, None], rows)[:, 0]  # [S, C]
-            own = index_scores(qi[:, None], wi[:, None], new["ik"][:, None])[:, 0]  # [S, 1]
-            scores = jnp.where(pos[None] == seq_lens[:, None], own, cached)
-        with jax.named_scope("index_select"):
-            chosen_pos, chosen = topk_rows(scores, pos[None] <= seq_lens[:, None], topk)
-    else:
-        chosen_pos, chosen = jnp.maximum(given, 0), given >= 0
+    chosen_pos, chosen = chosen_rows(pool["ik"], P, block_tables, seq_lens, new["ik"], qi, wi, topk, given)
     with jax.named_scope("sparse_walk"):
-        # the page of a chosen position by a compare against the table's own index and a masked sum, which fuse
-        # to one pass: a gather of 32,768 single ids took 0.29 ms a layer, two thirds of the choice itself
-        hit = (chosen_pos // P)[:, :, None] == jnp.arange(block_tables.shape[1], dtype=chosen_pos.dtype)
-        page = jnp.sum(jnp.where(hit, block_tables[:, None, :], 0), axis=-1)
-        flat_row = page * P + chosen_pos % P
-        is_new = (chosen_pos == seq_lens[:, None]) & chosen
+        flat_row, is_new = chosen_flat_rows(chosen_pos, chosen, block_tables, seq_lens, P)
         leaf = pool["kv"]
         got = leaf.reshape((leaf.shape[0] * P, leaf.shape[2]))[flat_row]  # [S, topk, words]
         got = jnp.where(is_new[..., None], new["kv"][:, None, :], got)
@@ -569,6 +593,75 @@ def sparse_decode_attention_reference_cache_plus_new(
         p = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("skrn,snkd->skrd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     return out.reshape(S, H, d).astype(q.dtype), jnp.where(chosen, chosen_pos, -1)
+
+
+def sparse_latent_decode_attention_cache_plus_new(
+    q: jax.Array,  # [S, H, width]: every head's absorbed query against the whole row
+    pool: dict,  # {"kv": [L * NP, P, width] latent rows, "ik": [L * NP, P, index width]} (:func:`flat_pages`), WITHOUT the new token
+    block_tables: jax.Array,  # [S, max_pages]: ids of the flattened pool (:func:`layer_tables`)
+    seq_lens: jax.Array,  # [S] — tokens valid in the pages (excl. new)
+    new: dict,  # {"kv": [S, width], "ik": [S, index width]}: the new token's rows
+    qi: jax.Array,  # [S, Hi, c]: the indexer's queries, roped
+    wi: jax.Array,  # [S, Hi]: its heads' weights
+    topk: int,
+    value_width: int,  # the row's first columns are the value
+    score_dim: int,  # softmax scale: score_dim ** -0.5
+    given: Optional[jax.Array] = None,  # [S, topk] int32 positions, -1 none: a choice given, not made
+) -> tuple[jax.Array, jax.Array]:
+    """A decode step of LATENT attention over rows chosen by a learned
+    indexer (``models/dots.py``): :func:`sparse_decode_attention_reference_cache_plus_new`'s
+    scoring and choice (``index_scores``, ``index_select``), then the chosen
+    LATENT rows fetched by row through the block table and attended in
+    absorbed form (``sparse_latent``): one row a token shared by all heads,
+    key as it stands and value in its first ``value_width`` columns, never
+    expanded a head (:func:`latent_decode_attention_reference_cache_plus_new`'s
+    form over a list of rows). One gather of ``[S, topk, width]`` and two
+    products whose operands are the rows' dtype, accumulated in float32. ->
+    (out [S, H, value_width] in q's dtype, positions chosen [S, topk] int32,
+    -1 where a lane had fewer)."""
+    P = pool["kv"].shape[1]
+    chosen_pos, chosen = chosen_rows(pool["ik"], P, block_tables, seq_lens, new["ik"], qi, wi, topk, given)
+    with jax.named_scope("sparse_latent"):
+        flat_row, is_new = chosen_flat_rows(chosen_pos, chosen, block_tables, seq_lens, P)
+        leaf = pool["kv"]
+        got = leaf.reshape((leaf.shape[0] * P, leaf.shape[2]))[flat_row]  # [S, topk, width]
+        got = jnp.where(is_new[..., None], new["kv"][:, None, :], got).astype(q.dtype)
+        logits = jnp.einsum("shw,snw->shn", q, got, preferred_element_type=jnp.float32) * (score_dim ** -0.5)
+        p = jax.nn.softmax(jnp.where(chosen[:, None, :], logits, NEG_INF), axis=-1)
+        out = jnp.einsum("shn,snv->shv", p.astype(got.dtype), got[..., :value_width], preferred_element_type=jnp.float32)
+    return out.astype(q.dtype), jnp.where(chosen, chosen_pos, -1)
+
+
+def ring_latent_decode_attention_cache_plus_new(
+    q: jax.Array,  # [S, H, width]: every head's absorbed query against the whole row
+    pages: jax.Array,  # [L * NW, P, width]: the window pool's one leaf (:func:`flat_pages`), WITHOUT the new token
+    ring_ids: jax.Array,  # [S, ring]: a slot's ring, ids of the flattened pool (:func:`ring_tables`, :func:`layer_tables`)
+    seq_lens: jax.Array,  # [S] — tokens committed before the new one
+    row_new: jax.Array,  # [S, width]: the new token's row
+    value_width: int,
+    score_dim: int,
+    row_positions: jax.Array,  # [S, ring * P]: the position each row of the ring holds (:func:`ring_positions`)
+    starts: jax.Array,  # [S]: the first position the query sees
+) -> jax.Array:
+    """A decode step of latent attention over a window kept as a RING of
+    latent rows a slot (``models/dots.py``'s sliding layers): the slot's
+    whole ring gathered (``ring`` pages, whatever the context), the rows
+    whose positions lie in ``starts .. seq_lens - 1`` and the new token's
+    own attended in absorbed form, operands in the rows' dtype and float32
+    accumulators. -> [S, H, value_width] in q's dtype."""
+    S, H, width = q.shape
+    rows = pages[ring_ids].reshape(S, -1, width).astype(q.dtype)  # [S, ring * P, width]
+    scale = score_dim ** -0.5
+    logits = jnp.einsum("shw,stw->sht", q, rows, preferred_element_type=jnp.float32) * scale
+    seen = (row_positions >= starts[:, None]) & (row_positions < seq_lens[:, None])
+    logits = jnp.where(seen[:, None, :], logits, NEG_INF)
+    new = row_new.astype(q.dtype)
+    self_logit = jnp.einsum("shw,sw->sh", q, new, preferred_element_type=jnp.float32) * scale
+    m = jnp.maximum(jnp.max(logits, axis=-1), self_logit)
+    p, p_self = jnp.where(seen[:, None, :], jnp.exp(logits - m[..., None]), 0.0), jnp.exp(self_logit - m)
+    out = jnp.einsum("sht,stv->shv", p.astype(rows.dtype), rows[..., :value_width], preferred_element_type=jnp.float32)
+    out = out + p_self[..., None] * new[:, None, :value_width].astype(jnp.float32)
+    return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]).astype(q.dtype)
 
 
 class PageAllocator:

@@ -21,6 +21,11 @@ twice). One grid step takes a block of queries of ALL the query heads of one
 KV head against one block of that head's keys: K, V and the mask tile are
 fetched once for the group, not once a head.
 
+Keys (with the queries) and values each have a width of their own, both
+whole lane tiles: ``models/keye.py`` passes 128 and 128, ``models/dots.py``
+its expanded latent rows, keys of 192 padded to 256 beside values of 128,
+and the scale of the 192.
+
 A query none of whose keys is unmasked (a bucket's padding row) gets the mean
 of the values it visited: finite, and read by nothing.
 """
@@ -39,10 +44,14 @@ BLOCK_K = 512  # keys a grid step folds in
 _MASKED = -1e30  # finite: a row masked so far keeps numbers, and the first real key's correction wipes them
 
 
-def serves(T: int, head_dim: int) -> bool:
-    """Whether the kernel takes a prompt of ``T`` rows: whole blocks of
-    queries and keys, heads of whole lane tiles."""
-    return T % BLOCK_K == 0 and T % BLOCK_Q == 0 and head_dim % 128 == 0
+def serves(T: int, key_width: int, value_width: int) -> bool:
+    """Whether the kernel takes a prompt of ``T`` rows whose heads' keys (and
+    queries) are ``key_width`` wide and values ``value_width``: whole blocks
+    of queries and keys, and each width whole lane tiles. The two widths need
+    not be equal (latent attention expanded: keys of 192 padded with zeros
+    to 256, which change no product, beside values of 128; the caller then
+    states the scale of the unpadded width)."""
+    return T % BLOCK_K == 0 and T % BLOCK_Q == 0 and key_width % 128 == 0 and value_width % 128 == 0
 
 
 def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float, n_rep: int):
@@ -75,17 +84,20 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref, *, scal
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, interpret: bool = False) -> jax.Array:
-    """q [T, H, d], k and v [T, H_kv, d], mask [T, T] int8 (1: query t sees
-    key s; nothing after a query is ever set) -> [T, H, d] in q's dtype:
-    ``softmax(q . k / sqrt(d))`` over the unmasked keys times v, grouped
-    ``H / H_kv`` query heads to a KV head. ``serves(T, d)`` must hold."""
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, scale: float | None = None,
+                     interpret: bool = False) -> jax.Array:
+    """q [T, H, d], k [T, H_kv, d], v [T, H_kv, dv], mask [T, T] int8 (1:
+    query t sees key s; nothing after a query is ever set) -> [T, H, dv] in
+    q's dtype: ``softmax(scale q . k)`` over the unmasked keys times v,
+    grouped ``H / H_kv`` query heads to a KV head. ``scale`` None is ``d **
+    -0.5``. ``serves(T, d, dv)`` must hold."""
     T, H, d = q.shape
-    H_kv = k.shape[1]
+    H_kv, dv = k.shape[1], v.shape[2]
     n_rep = H // H_kv
-    if not serves(T, d):
-        raise ValueError(f"masked_attention takes whole blocks of {BLOCK_Q} queries and {BLOCK_K} keys, not {T} rows of {d}")
+    if not serves(T, d, dv):
+        raise ValueError(f"masked_attention takes whole blocks of {BLOCK_Q} queries and {BLOCK_K} keys and widths of "
+                         f"whole lane tiles, not {T} rows of keys {d} and values {dv} wide")
     # heads first: a block is rows of one head, whole lane tiles wide
     qh = jnp.moveaxis(q, 1, 0).reshape(H_kv, n_rep, T, d)
     kh, vh = jnp.moveaxis(k, 1, 0), jnp.moveaxis(v, 1, 0)
@@ -99,19 +111,19 @@ def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, 
         in_specs=[
             pl.BlockSpec((None, n_rep, BLOCK_Q, d), lambda g, qi, ki: (g, 0, qi, 0)),
             pl.BlockSpec((1, BLOCK_K, d), lambda g, qi, ki: (g, last_seen(qi, ki), 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda g, qi, ki: (g, last_seen(qi, ki), 0)),
+            pl.BlockSpec((1, BLOCK_K, dv), lambda g, qi, ki: (g, last_seen(qi, ki), 0)),
             pl.BlockSpec((BLOCK_Q, BLOCK_K), lambda g, qi, ki: (qi, last_seen(qi, ki))),
         ],
-        out_specs=pl.BlockSpec((None, n_rep, BLOCK_Q, d), lambda g, qi, ki: (g, 0, qi, 0)),
+        out_specs=pl.BlockSpec((None, n_rep, BLOCK_Q, dv), lambda g, qi, ki: (g, 0, qi, 0)),
         scratch_shapes=[pltpu.VMEM((n_rep, BLOCK_Q, 1), jnp.float32), pltpu.VMEM((n_rep, BLOCK_Q, 1), jnp.float32),
-                        pltpu.VMEM((n_rep, BLOCK_Q, d), jnp.float32)],
+                        pltpu.VMEM((n_rep, BLOCK_Q, dv), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=d ** -0.5, n_rep=n_rep),
+        functools.partial(_kernel, scale=d ** -0.5 if scale is None else scale, n_rep=n_rep),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((H_kv, n_rep, T, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((H_kv, n_rep, T, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="masked_prefill_attention",
     )(qh, kh, vh, mask.astype(jnp.int8))
-    return jnp.moveaxis(out.reshape(H, T, d), 0, 1)
+    return jnp.moveaxis(out.reshape(H, T, dv), 0, 1)

@@ -653,7 +653,7 @@ def test_the_masked_attention_kernel_is_the_masked_dense_attention():
     seen = np.arange(T) != 700
     np.testing.assert_allclose(np.asarray(got)[seen], np.asarray(want)[seen], atol=2e-6)
     assert np.isfinite(np.asarray(got)).all()
-    assert ma.serves(24576, 128) and ma.serves(16384, 128) and not ma.serves(40, 128) and not ma.serves(1024, 16)
+    assert ma.serves(24576, 128, 128) and ma.serves(16384, 128, 128) and not ma.serves(40, 128, 128) and not ma.serves(1024, 16, 16)
     with pytest.raises(ValueError, match="whole blocks"):
         ma.masked_attention(q[:300], k[:300], v[:300], mask[:300, :300].astype(jnp.int8), interpret=True)
 
