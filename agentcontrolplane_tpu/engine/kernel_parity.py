@@ -9,7 +9,8 @@ difference judged against a tolerance set from the page dtype. The K/V walk
 (:func:`latent_walk_parity`) and a verify step's walks, several rows a lane
 in one query group over pages or a ring (:func:`verify_walk_parity`); and
 the routed experts' grouped matmul against ``jax.lax.ragged_dot`` over the
-same plan (:func:`expert_matmul_parity`).
+same plan (:func:`expert_matmul_parity`); and the indexer's choice of rows
+against ``jax.lax.top_k``'s set (:func:`index_select_parity`).
 """
 
 from __future__ import annotations
@@ -292,3 +293,29 @@ def expert_matmul_parity(seed: int, *, tokens: int = 128, k: int = 8, experts: i
         "ok": finite and top > 0.1 and err <= tol and landed[1] == 0
               and int(landed.sum()) > (routed // 2 if lean else routed * held // experts // 2),
     }
+
+
+def index_select_parity(seed: int, *, lanes: int = 16, columns: int = 26624, topk: int = 2048, coarse: bool = False,
+                        interpret: bool = False) -> dict:
+    """The indexer's choice (``ops.pallas.index_select``, compiled unless
+    ``interpret``) against ``ops.attention.topk_rows`` (``jax.lax.top_k``)
+    over the same scores: lanes of 0 rows, fewer than ``topk``, and up to the
+    whole width; ``coarse`` rounds the scores to a few values, so that whole
+    runs of columns tie at every threshold and the earlier must win. The
+    sets are equal or they are not: no tolerance."""
+    from ..ops.attention import topk_rows
+    from ..ops.pallas.index_select import index_select
+
+    rng = np.random.default_rng(seed)
+    scores = rng.normal(size=(lanes, columns)).astype(np.float32)
+    scores = np.round(scores * 2) / 2 if coarse else scores
+    lens = rng.integers(topk, columns, lanes).astype(np.int32)
+    lens[:3] = (0, topk // 2, columns - 1)[: min(3, lanes)]
+    scores, lens = jnp.asarray(scores), jnp.asarray(lens)
+    valid = jnp.arange(columns)[None] <= lens[:, None]
+    want = jnp.minimum(topk, lens + 1)
+    got, tied = jax.jit(lambda s, w: index_select(jnp.where(valid, s, -jnp.inf), w, topk, interpret=interpret))(scores, want)
+    columns_ref, chosen = jax.jit(lambda s: topk_rows(s, valid, topk))(scores)
+    got, columns_ref, chosen, want = (np.asarray(a) for a in (got, columns_ref, chosen, want))
+    wrong = [b for b in range(lanes) if got[b, : want[b]].tolist() != sorted(columns_ref[b][chosen[b]].tolist())]
+    return {"ok": not wrong, "lanes_wrong": wrong, "lanes_tied": int(np.asarray(tied).sum()), "seq_lens": np.asarray(lens).tolist()}

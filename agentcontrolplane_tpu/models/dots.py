@@ -70,14 +70,16 @@ Attention paths, equal in exact arithmetic (``tests/engine/test_dots.py``):
 - the decode step **absorbs**: ``q~_h = [W^UK_h^T q^N_h ; q^R_h]`` against
   the row as it lies, values its first ``kv_rank`` columns, ``W^UV_h`` after
   the softmax (``mla_absorb``). A full layer scores every cached ``ik`` row,
-  chooses (``jax.lax.top_k``), fetches the chosen LATENT rows by row and
-  attends over them (``ops.paged.sparse_latent_decode_attention_cache_plus_new``:
+  chooses (``ops.paged.chosen_rows``: on a TPU the kernel
+  ``ops/pallas/index_select.py``, a threshold held on the chip and no sort;
+  off it ``jax.lax.top_k``, the same set), fetches the chosen LATENT rows by
+  row and attends over them (``ops.paged.sparse_latent_decode_attention_cache_plus_new``:
   ``index_scores``, ``index_select``, ``sparse_latent``); ONE program either
   side of ``index_topk`` rows (a lane under it chooses all its rows, its
   list padded and masked). A sliding layer gathers its slot's ring and
   attends over the rows inside the window (``ring_latent``:
-  ``ops.paged.ring_latent_decode_attention_cache_plus_new``). Both are XLA: no
-  per-head K or V of the context is ever made, and no kernel walks either
+  ``ops.paged.ring_latent_decode_attention_cache_plus_new``). Both walks are
+  XLA: no per-head K or V of the context is ever made, and no kernel walks either
   (``ops/pallas/paged_attention.py``'s latent walk reads a block table from
   row 0 and every row: ROADMAP M1, M5, M10).
 
@@ -342,9 +344,10 @@ def _rope_first(x, positions, theta, n):
 def _attention_op(h, w, c: DotsConfig, g, positions, attend):
     """-> (Op output [B, T, D], the layer's new rows for the pool ``{"kv":
     [B, T, row_stored]}`` and, of a full layer, ``"ik"`` [B, T,
-    index_head_dim], whatever ``attend`` hands on). ``attend(q_nope [B, T, H,
+    index_head_dim], whatever ``attend`` hands on, the queries whose choice
+    the tie rule decided as it counts them). ``attend(q_nope [B, T, H,
     nope], q_pe [B, T, H, rope] roped, row [B, T, row_stored], w, index) ->
-    ([B, T, H, v], extra)`` is the path: expanded or absorbed; ``index`` is a
+    ([B, T, H, v], extra, tied)`` is the path: expanded or absorbed; ``index`` is a
     full layer's ``(qi [B, T, Hi, c], wi [B, T, Hi] float32, ik [B, T, c])``,
     None of a sliding layer."""
     B, T, _ = h.shape
@@ -367,11 +370,11 @@ def _attention_op(h, w, c: DotsConfig, g, positions, attend):
             ik = _rope_first(ki[:, :, None, :], positions, g.theta, g.rope)[:, :, 0, :].astype(h.dtype)
             wi = jnp.matmul(h, w["iw"].astype(h.dtype), preferred_element_type=jnp.float32)  # the accumulator, unrounded
         index, new = (qi, wi, ik), {"kv": row, "ik": ik}
-    out, extra = attend(q_nope, q_pe, row, w, index)
+    out, extra, tied = attend(q_nope, q_pe, row, w, index)
     with jax.named_scope("attn_gate"):
         out = out * jax.nn.sigmoid(_mm(h, w["wg"]).astype(jnp.float32)).astype(out.dtype)[..., None]
     with jax.named_scope("attn_out"):
-        return _mm(out.reshape(B, T, H * g.v), w["wo"]), new, extra
+        return _mm(out.reshape(B, T, H * g.v), w["wo"]), new, extra, tied
 
 
 def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, select=None, keep=lambda t: t,
@@ -387,8 +390,9 @@ def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, s
     layer's fresh rows before the loop stacks them (a prefill keeps a ring's
     worth: ``ring_newest``). -> (x, the full-type layers' new rows ``{"kv",
     "ik"}`` each [layers, B, T, width], the sliding layers' ``wkv`` [layers,
-    B, kept rows, width], expert counters, and with ``tell`` what the layers
-    chose: ``(rows [full-type layers, ...], experts [expert layers, B, T,
+    B, kept rows, width], expert counters and after them the queries whose
+    choice the tie rule decided (a decode step's lanes, over the full layers:
+    ``lanes_tied``), and with ``tell`` what the layers chose: ``(rows [full-type layers, ...], experts [expert layers, B, T,
     k])``, else None)."""
     kinds = layer_kinds(c)
     nd = c.first_dense
@@ -407,9 +411,9 @@ def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, s
         at = row + (nd if kind == "full" else 0)  # the layer of its leaves
         given = select[at] if full and select is not None else None
         with scopes.layer("attn"):
-            op, new, told = _attention_op(norm(x, weights["ln1"]), weights, c, c.full if full else c.swa, positions,
-                                          paths(full, at, given))
-            x = x + op
+            op, new, told, tied = _attention_op(norm(x, weights["ln1"]), weights, c, c.full if full else c.swa, positions,
+                                                paths(full, at, given))
+            x, counts = x + op, counts.at[-1].add(jnp.asarray(tied, jnp.uint32))
         out = {"rows": {name: (t if full else keep(t)).astype(dt) for name, t in new.items()}}
         with scopes.layer("ffn"):
             h = norm(x, weights["ln2"] if kind == "dense_full" else small["ln2"][index - nd])
@@ -425,12 +429,12 @@ def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, s
                     out["experts"] = (jax.lax.top_k(scores + mine["router_bias"], c.experts_per_token)[1]
                                       if chosen is None else chosen)
                 y, m = _experts(h, mine, stacks, e, c, valid, chosen)
-                x, counts = x + y, counts + m
+                x, counts = x + y, counts.at[:-1].add(m)
         if tell and full:
             out["chose"] = told
         return (x, counts), out
 
-    counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
+    counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held) + 1,), jnp.uint32)
     (x, counts), outs = scan_layers(kinds, (x, counts), layer)
     full_kinds = [k for k in ("dense_full", "full") if k in outs]
     rows = {name: jnp.concatenate([outs[k]["rows"][name] for k in full_kinds], axis=0) for name in ("kv", "ik")}
@@ -510,12 +514,12 @@ def _whole_rows(c: DotsConfig, positions, tell, interpret: bool = False):
                     else:
                         mask = _causal_ok(positions, positions) & _unpacked(given, T)
                 out = _attend_under(c, c.full, mask, q_nope, q_pe, row, w, interpret)
-            return out, _packed(mask) if tell else None
+            return out, _packed(mask) if tell else None, 0
 
         def attend_sliding(q_nope, q_pe, row, w, index):
             k, v = _expand(row, w["wuk"], w["wuv"], c.swa)
             with jax.named_scope("prefill_attention"):
-                return _banded(_queries(q_nope, q_pe), k, v, positions, c.sliding_window_size), None
+                return _banded(_queries(q_nope, q_pe), k, v, positions, c.sliding_window_size), None, 0
 
         return attend_full if full else attend_sliding
 
@@ -581,7 +585,7 @@ def _committed(cache, full, win, counts, c: DotsConfig, row, scored, positions, 
     sparse = jnp.stack([jnp.ones((), jnp.uint32), u32(scored), u32(jnp.sum(jnp.minimum(live, c.index_topk))),
                         u32(jnp.sum(live)), u32(jnp.sum(live > c.index_topk))])
     window = _window_counts(SimpleNamespace(window=c.sliding_window_size), positions, valid)
-    added = jnp.concatenate([counts, sparse, window])
+    added = jnp.concatenate([counts[:-1], sparse, counts[-1:], window])  # `_run_layers` counts the tied lanes after the experts
     return {**full, **win, "state": {"counts": cache["state"]["counts"].at[row].add(added)}}
 
 
@@ -702,7 +706,7 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
                     return jnp.moveaxis(out, 0, 1).reshape(B, T, G, g.v)
 
             out = jax.lax.map(group, (grouped(_queries(q_nope, q_pe), 2), grouped(w["wuk"], 0), grouped(w["wuv"], 0)))
-            return jnp.moveaxis(out, 0, 2).reshape(B, T, H, g.v), None
+            return jnp.moveaxis(out, 0, 2).reshape(B, T, H, g.v), None, 0
 
         def attend_sliding(q_nope, q_pe, row, w, index):
             g = c.swa
@@ -711,7 +715,7 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
             ring_k, ring_v = _expand(got, w["wuk"], w["wuv"], g)
             k, v = _expand(row, w["wuk"], w["wuv"], g)
             with jax.named_scope("prefill_attention"):
-                return _banded(_queries(q_nope, q_pe), k, v, positions, c.sliding_window_size, (ring_k, ring_v, ring_pos)), None
+                return _banded(_queries(q_nope, q_pe), k, v, positions, c.sliding_window_size, (ring_k, ring_v, ring_pos)), None, 0
 
         return attend_full if is_full else attend_sliding
 
@@ -746,16 +750,19 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
 
 def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, config: DotsConfig,
                       use_pallas: bool = False, mesh=None, route=None, select=None, tell: bool = False,
-                      window_rows: Optional[int] = None):
+                      window_rows: Optional[int] = None, interpret: bool = False):
     """One token for lanes 0..S-1 (lane b is slot b), absorbed: a full layer
     scores the lane's cached rows through ``ik``, chooses, and attends over
     the chosen latent rows fetched by row; a sliding layer over its ring from
     the window's edge on; an inactive lane's pages and ring are left as they
     were. ``use_pallas`` and ``mesh`` are what the engine hands every
-    family's step; neither changes anything here (module text). ``select``
+    family's step; neither changes anything here (module text): the choice's
+    kernel (``ops/pallas/index_select.py``) runs wherever the backend is a
+    TPU, and ``interpret`` runs it interpreted (tests). ``select``
     [full-type layers, S, index_topk] int32 positions (-1 none) is a choice
     handed in; with ``tell`` -> (cache, logits, (positions chosen [full-type
-    layers, S, index_topk], experts chosen [expert layers, S, 1, k])).
+    layers, S, index_topk] in no order a caller may count on, experts chosen
+    [expert layers, S, 1, k])).
     ``window_rows`` (an output check's control) sees another window than the
     model's."""
     c = config
@@ -788,10 +795,11 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         def attend_full(q_nope, q_pe, row, w, index):
             g = c.full
             qi, wi, ik = index
-            o_lat, chosen = sparse_latent_decode_attention_cache_plus_new(
+            o_lat, chosen, tied = sparse_latent_decode_attention_cache_plus_new(
                 absorbed(q_nope, q_pe, w, g), flat, layer_tables(block_tables, i, NP), seq_lens,
-                {"kv": row[:, 0], "ik": ik[:, 0]}, qi[:, 0], wi[:, 0], c.index_topk, g.kv_rank, g.qk_head_dim, given)
-            return values(o_lat, w), chosen
+                {"kv": row[:, 0], "ik": ik[:, 0]}, qi[:, 0], wi[:, 0], c.index_topk, g.kv_rank, g.qk_head_dim, given,
+                interpret)
+            return values(o_lat, w), chosen, jnp.sum(tied & active)
 
         def attend_sliding(q_nope, q_pe, row, w, index):
             g = c.swa
@@ -799,7 +807,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
             with jax.named_scope("ring_latent"):
                 o_lat = ring_latent_decode_attention_cache_plus_new(
                     q_row, wflat, layer_tables(rings, i, NW), seq_lens, row[:, 0], g.kv_rank, g.qk_head_dim, ring_pos, first)
-            return values(o_lat, w), None
+            return values(o_lat, w), None, 0
 
         return attend_full if is_full else attend_sliding
 
@@ -852,7 +860,7 @@ def describe_counters(config: DotsConfig, total) -> dict:
     def sparse(r):
         n = c.n_full
         return {"steps": int(r[cut]), "rows_scored": int(r[cut + 1]) * n, "rows_chosen": int(r[cut + 2]) * n,
-                "rows_dense": int(r[cut + 3]) * n, "lanes_past_topk": int(r[cut + 4])}
+                "rows_dense": int(r[cut + 3]) * n, "lanes_past_topk": int(r[cut + 4]), "lanes_tied": int(r[cut + 5])}
 
     def window(r):
         at = cut + SPARSE_COUNTS

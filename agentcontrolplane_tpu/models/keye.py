@@ -64,9 +64,9 @@ Two attention paths, equal in exact arithmetic
   index scores against the keys of its tier of ``MASK_TIER`` rows (one
   ``lax.map`` a tier: ``_prompt_mask``), each query's ``index_topk``-th
   largest (``topk_rows_mask``: a threshold found by compare-and-count, no
-  sort, where the decode step's 16 rows take ``jax.lax.top_k``: each is the
-  faster at its shape, and the two give one choice, ties and all), and that
-  mask under the causal rule. The attention under the whole ``[T, T]`` mask
+  sort, as the decode step's 16 rows find theirs inside one kernel,
+  ``ops/pallas/index_select.py``: the two give one choice, ties and all),
+  and that mask under the causal rule. The attention under the whole ``[T, T]`` mask
   is, on a TPU, the kernel of ``ops/pallas/masked_attention.py``, which
   keeps a block's scores in VMEM and reads the mask a tile at a time (XLA's
   blocked attention writes them to HBM three times: 190.6 ms a layer at
@@ -76,8 +76,12 @@ Two attention paths, equal in exact arithmetic
   tiny sizes a CPU runs. A continuation gathers a slot's ``ik`` pages as it
   gathers its ``kv`` pages, once, and splits them.
 - the decode step: ``ops.paged.sparse_decode_attention_reference_cache_plus_new``:
-  every cached row scored through ``ik``, ``jax.lax.top_k``, the chosen
-  ``kv`` rows fetched by row, once. ONE program for lanes under and over
+  every cached row scored through ``ik``, the ``index_topk`` of largest
+  score found by a threshold held on the chip (``ops.paged.chosen_rows``: on
+  a TPU the kernel ``ops/pallas/index_select.py``, 32 compare-and-count
+  passes over a lane's scores in VMEM, the tie rule and the compaction, no
+  sort; off it ``jax.lax.top_k``, the same set), the chosen ``kv`` rows
+  fetched by row, once. ONE program for lanes under and over
   ``index_topk`` rows: a lane under it chooses all its rows and its list is
   padded and masked.
 
@@ -89,7 +93,8 @@ The family keeps no state a slot and counts on the device:
 ``cache["state"]["counts"]`` ``[2, 1 + COUNTS_HEAD + held + SPARSE_COUNTS]``
 uint32, row 0 decode steps and row 1 prefills: the expert layers' counters
 as ``lfm2`` keeps them, then dispatches, and A LAYER's rows scored, rows
-chosen, rows a dense walk would read, and lanes past ``index_topk``.
+chosen, rows a dense walk would read, lanes past ``index_topk``, and (over
+all layers) the decode lanes whose choice the tie rule decided.
 """
 
 from __future__ import annotations
@@ -116,7 +121,7 @@ from .lfm2 import _embed, _final_norm, _head_logits, _mm
 from .lfm2 import describe_counters as _describe_moe
 from .mellum import _experts, _rows  # the same expert layer (a softmax router, top k renormalised) and row positions
 
-SPARSE_COUNTS = 5  # dispatches, rows scored, rows chosen, rows a dense walk would read, lanes past topk
+SPARSE_COUNTS = 6  # dispatches, rows scored, rows chosen, rows a dense walk would read, lanes past topk, lanes tied
 # query rows of a whole prompt whose index scores and threshold are made at once: 50 MB of float32 scores against
 # 24,576 keys, which the ~32 passes of the threshold find in the chip's near memory (at 2,048 rows, 200 MB, they took
 # 10.8 ms where two blocks of 1,024 take 3.5: PR 58's builder's chip runs; at 512 a 24,576-row prefill of 8 layers
@@ -221,9 +226,10 @@ def _layer_norm(x, weight, bias, eps):
 def _attention_op(h, w, c: KeyeConfig, positions, attend, positions3=None):
     """-> (Op output [B, T, D], the layer's new rows ``{"kv": [B, T, 1,
     words] uint32 (``pack_kv_rows`` of K's and V's heads side by side), "ik":
-    [B, T, 1, ik_stored]}`` for the pool, whatever ``attend`` hands on).
+    [B, T, 1, ik_stored]}`` for the pool, whatever ``attend`` hands on, the
+    queries whose choice the tie rule decided as it counts them).
     ``attend(q, k, v, qi [B, T, Hi, c], wi [B, T, Hi] float32, ik
-    [B, T, ik_stored]) -> ([B, T, H, d], extra)`` is the path. ``positions``
+    [B, T, ik_stored]) -> ([B, T, H, d], extra, tied)`` is the path. ``positions``
     [B, T] are the temporal ones; ``positions3`` [B, 3, T], where given, turn
     q and k an axis a section."""
     B, T, _ = h.shape
@@ -242,11 +248,11 @@ def _attention_op(h, w, c: KeyeConfig, positions, attend, positions3=None):
         ki = apply_rope(ki[:, :, None, :], positions, c.rope_theta)[:, :, 0, :]  # one key for all heads: one head
         ik = jnp.pad(ki.astype(h.dtype), ((0, 0), (0, 0), (0, c.ik_stored - c.index_head_dim)))
         wi = jnp.matmul(h, w["iw"].astype(h.dtype), preferred_element_type=jnp.float32)  # the accumulator, unrounded
-    out, extra = attend(q, k, v, qi, wi, ik)
+    out, extra, tied = attend(q, k, v, qi, wi, ik)
     with jax.named_scope("attn_out"):
         op = _mm(out.reshape(B, T, c.n_heads * c.head_dim), w["wo"])
     kv = pack_kv_rows(*(t.reshape(B, T, -1).astype(h.dtype) for t in (k, v)))
-    return op, {"kv": kv[:, :, None, :], "ik": ik[:, :, None, :]}, extra
+    return op, {"kv": kv[:, :, None, :], "ik": ik[:, :, None, :]}, extra, tied
 
 
 def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=None, select=None, positions3=None,
@@ -257,8 +263,10 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
     [n_layers, B, T, k] int32, where given, is every layer's choice of
     experts, taken as it is (an output check's; serving gives neither).
     -> (x, every layer's new rows ``{leaf: [n_layers, B, T, ...]}``, expert
-    counters, and with ``tell`` what each layer chose: ``(rows, experts)``
-    stacked over the layers, else None)."""
+    counters and after them the queries whose choice the tie rule decided
+    (a decode step's lanes, over all layers: ``lanes_tied``), and with
+    ``tell`` what each layer chose: ``(rows, experts)`` stacked over the
+    layers, else None)."""
     norm = lambda x, w: rms_norm(x, w, c.norm_eps)  # noqa: E731
     ff = params["ff"]
     stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
@@ -269,8 +277,8 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
         x, counts = carry
         weights, mine, index, chosen, given = scanned
         with scopes.layer("attn"):
-            op, rows, told = _attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attend(index, given),
-                                           positions3)
+            op, rows, told, tied = _attention_op(norm(x, weights["ln1"]), weights, c, positions,
+                                                 make_attend(index, given), positions3)
             x = x + op
         with scopes.layer("ffn"):
             h = norm(x, mine["ln2"])
@@ -279,9 +287,10 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
                 logits = h.astype(jnp.float32) @ mine["router"].astype(jnp.float32)
                 experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), c.experts_per_token)[1] if chosen is None else chosen
             y, m = _experts(h, mine, stacks, index, c, valid, chosen)
-            return (x + y, counts + m), (rows, (told, experts) if tell else None)
+            counts = counts + jnp.concatenate([m, jnp.asarray(tied, jnp.uint32)[None]])
+            return (x + y, counts), (rows, (told, experts) if tell else None)
 
-    counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
+    counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held) + 1,), jnp.uint32)
     (x, counts), (rows, told) = jax.lax.scan(
         body, (x, counts), (params["attn"], small, jnp.arange(n, dtype=jnp.int32), route, select))
     return x, rows, counts, told
@@ -373,7 +382,7 @@ def _whole_rows(c: KeyeConfig, positions, tell, interpret: bool = False):
                                                       interpret=interpret) for b in range(B)])
                 else:
                     out = causal_attention(q, k, v, keep=mask)
-            return out, _packed(mask) if tell else None
+            return out, _packed(mask) if tell else None, 0
 
         return attend
 
@@ -425,7 +434,8 @@ def _committed(cache, pool, counts, c: KeyeConfig, row, scored, live):
     live = live.reshape(-1)
     sparse = jnp.stack([jnp.ones((), jnp.uint32), u32(scored), u32(jnp.sum(jnp.minimum(live, c.index_topk))),
                         u32(jnp.sum(live)), u32(jnp.sum(live > c.index_topk))])
-    return {**pool, "state": {"counts": cache["state"]["counts"].at[row].add(jnp.concatenate([counts, sparse]))}}
+    added = jnp.concatenate([counts[:-1], sparse, counts[-1:]])  # `_run_layers` counts the tied lanes after the experts
+    return {**pool, "state": {"counts": cache["state"]["counts"].at[row].add(added)}}
 
 
 def prefill_paged_batch(params, cache, tokens, lengths, page_ids, config: KeyeConfig, route=None, select=None,
@@ -509,9 +519,9 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
                     return _masked_attention(q_b, keys, values, mask)
 
             if T <= CONTINUE_BLOCK or T % CONTINUE_BLOCK:
-                return block((q, qi, wi, positions)), None
+                return block((q, qi, wi, positions)), None, 0
             out = jax.lax.map(block, tuple(_row_blocks(t, CONTINUE_BLOCK) for t in (q, qi, wi, positions)))
-            return jnp.moveaxis(out, 0, 1).reshape(B, T, c.n_heads, c.head_dim), None
+            return jnp.moveaxis(out, 0, 1).reshape(B, T, c.n_heads, c.head_dim), None, 0
 
         return attend
 
@@ -540,15 +550,18 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
 
 
 def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, config: KeyeConfig,
-                      use_pallas: bool = False, mesh=None, route=None, select=None, tell: bool = False):
+                      use_pallas: bool = False, mesh=None, route=None, select=None, tell: bool = False,
+                      interpret: bool = False):
     """One token for lanes 0..S-1 (lane b is slot b): every layer scores the
     lane's cached rows through ``ik``, chooses, and attends over the chosen
     ``kv`` rows fetched by row. ``use_pallas`` and ``mesh`` are what the
     engine hands every family's step; neither changes anything here: the
-    walk by rows is XLA's gather (module text). ``select`` [n_layers, S,
-    index_topk] int32 positions (-1 none) is a choice handed in; with
-    ``tell`` -> (cache, logits, (positions chosen [L, S, index_topk],
-    experts chosen [L, S, 1, k]))."""
+    walk by rows is XLA's gather (module text), and the choice's kernel runs
+    wherever the backend is a TPU (``interpret`` runs it interpreted:
+    tests). ``select`` [n_layers, S, index_topk] int32 positions (-1 none)
+    is a choice handed in; with ``tell`` -> (cache, logits, (positions chosen
+    [L, S, index_topk] in no order a caller may count on, experts chosen [L,
+    S, 1, k]))."""
     c = config
     S = tokens.shape[0]
     pool = pool_leaves(cache)
@@ -557,11 +570,11 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
 
     def make_attend(i, given):
         def attend(q, k, v, qi, wi, ik):
-            out, chosen = sparse_decode_attention_reference_cache_plus_new(
+            out, chosen, tied = sparse_decode_attention_reference_cache_plus_new(
                 q[:, 0], flat, layer_tables(block_tables, i, NP), seq_lens,
                 {"kv": pack_kv_rows(k.reshape(S, -1), v.reshape(S, -1)), "ik": ik[:, 0]}, qi[:, 0], wi[:, 0],
-                c.index_topk, given)
-            return out[:, None], chosen
+                c.index_topk, given, interpret)
+            return out[:, None], chosen, jnp.sum(tied & active)
 
         return attend
 
@@ -593,7 +606,9 @@ def describe_counters(config: KeyeConfig, total) -> dict:
     taken over, ``min(rows it could see, index_topk)`` a query;
     ``rows_dense`` rows a dense walk would have read, all a query could see;
     ``lanes_past_topk`` queries (a decode step: lanes) that could see more
-    than ``index_topk`` and so left some out."""
+    than ``index_topk`` and so left some out; ``lanes_tied`` decode lanes, a
+    layer each, whose ``index_topk``-th score had more rows at it than room,
+    so that the tie rule (the earlier row) decided the set."""
     c = config
     cut = 1 + COUNTS_HEAD + len(c.held)
     if total is None:
@@ -602,7 +617,7 @@ def describe_counters(config: KeyeConfig, total) -> dict:
     def sparse(r):
         n = c.n_layers
         return {"steps": int(r[cut]), "rows_scored": int(r[cut + 1]) * n, "rows_chosen": int(r[cut + 2]) * n,
-                "rows_dense": int(r[cut + 3]) * n, "lanes_past_topk": int(r[cut + 4])}
+                "rows_dense": int(r[cut + 3]) * n, "lanes_past_topk": int(r[cut + 4]), "lanes_tied": int(r[cut + 5])}
 
     return {
         "moe": _describe_moe(c, [r[:cut] for r in total])["moe"],

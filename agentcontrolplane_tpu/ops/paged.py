@@ -507,7 +507,8 @@ def latent_decode_attention_reference_cache_plus_new(
     return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]).astype(q.dtype)
 
 
-def chosen_rows(ik_pages, kv_rows_per_page: int, block_tables, seq_lens, new_ik, qi, wi, topk: int, given=None):
+def chosen_rows(ik_pages, kv_rows_per_page: int, block_tables, seq_lens, new_ik, qi, wi, topk: int, given=None,
+                interpret: bool = False):
     """What both decode steps over rows CHOSEN by a learned indexer share
     (:func:`sparse_decode_attention_reference_cache_plus_new`,
     :func:`sparse_latent_decode_attention_cache_plus_new`): every cached row
@@ -516,24 +517,42 @@ def chosen_rows(ik_pages, kv_rows_per_page: int, block_tables, seq_lens, new_ik,
     (``index_select``; a lane with fewer rows takes them all, its list padded
     and masked; ``given`` [S, topk] int32 positions, -1 none, is a choice
     handed in); where each chosen position lies in the pool flattened over
-    pages and rows is :func:`chosen_flat_rows`'. -> (positions [S, topk] int32,
-    chosen [S, topk] bool)."""
+    pages and rows is :func:`chosen_flat_rows`'. The choice is a SET (the
+    walk's softmax is over one): on a TPU (or ``interpret``: tests) the
+    kernel ``ops.pallas.index_select`` finds it by a threshold and hands it in
+    ascending position, no sort; off the TPU ``ops.attention.topk_rows``
+    (``jax.lax.top_k``, by score) finds the same set, ties and all. ->
+    (positions [S, topk] int32, chosen [S, topk] bool, tied [S] bool: lanes
+    whose threshold had more rows at it than room, so that the tie rule, the
+    earlier row, decided)."""
     from .attention import index_scores, topk_rows
 
     S, P = block_tables.shape[0], kv_rows_per_page
     C = block_tables.shape[1] * P
+    if given is not None:
+        return jnp.maximum(given, 0), given >= 0, jnp.zeros((S,), bool)
     pos = jnp.arange(C, dtype=jnp.int32)
-    if given is None:
-        with jax.named_scope("index_scores"):
-            rows = ik_pages[block_tables].reshape(S, C, -1)  # the lane's whole table, as the dense reference gathers
-            cached = index_scores(qi[:, None], wi[:, None], rows)[:, 0]  # [S, C]
-            own = index_scores(qi[:, None], wi[:, None], new_ik[:, None])[:, 0]  # [S, 1]
-            scores = jnp.where(pos[None] == seq_lens[:, None], own, cached)
-        with jax.named_scope("index_select"):
-            chosen_pos, chosen = topk_rows(scores, pos[None] <= seq_lens[:, None], topk)
-    else:
-        chosen_pos, chosen = jnp.maximum(given, 0), given >= 0
-    return chosen_pos, chosen
+    valid = pos[None] <= seq_lens[:, None]
+    with jax.named_scope("index_scores"):
+        rows = ik_pages[block_tables].reshape(S, C, -1)  # the lane's whole table, as the dense reference gathers
+        cached = index_scores(qi[:, None], wi[:, None], rows)[:, 0]  # [S, C]
+        own = index_scores(qi[:, None], wi[:, None], new_ik[:, None])[:, 0]  # [S, 1]
+        scores = jnp.where(pos[None] == seq_lens[:, None], own, cached)
+    with jax.named_scope("index_select"):
+        k = min(topk, C)
+        want = jnp.minimum(k, seq_lens + 1)
+        if interpret or jax.default_backend() == "tpu":
+            from .pallas.index_select import index_select
+
+            chosen_pos, tied = index_select(jnp.where(valid, scores, -jnp.inf), want, k, interpret=interpret)
+            chosen = jnp.arange(k, dtype=jnp.int32)[None] < want[:, None]
+            chosen_pos = jnp.where(chosen, chosen_pos, 0)
+        else:
+            chosen_pos, chosen = topk_rows(scores, valid, topk)
+            # the least score chosen is the threshold: more rows at or above it than the lane takes
+            least = jnp.min(jnp.where(chosen, jnp.take_along_axis(scores, chosen_pos, axis=1), jnp.inf), axis=-1, keepdims=True)
+            tied = jnp.sum(valid & (scores >= least), axis=-1) > want
+    return chosen_pos, chosen, tied
 
 
 def chosen_flat_rows(chosen_pos, chosen, block_tables, seq_lens, P: int):
@@ -557,14 +576,16 @@ def sparse_decode_attention_reference_cache_plus_new(
     wi: jax.Array,  # [S, Hi]: its heads' weights
     topk: int,
     given: Optional[jax.Array] = None,  # [S, topk] int32 positions, -1 none: a choice given, not made
-) -> tuple[jax.Array, jax.Array]:
+    interpret: bool = False,  # the choice's kernel interpreted (tests)
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """A decode step of attention over rows CHOSEN by a learned indexer
     (``models/keye.py``), the new token's own row among the candidates:
     every cached row of a lane is scored through ``ik`` (``index_scores``: a
     gather of the lane's pages of the small leaf, 128 B a row where K and V
     are 2 KiB), the ``topk`` of largest score are chosen (``index_select``:
-    ``ops.attention.topk_rows``; a lane with fewer rows takes them all, its
-    list padded and masked: one program either side of ``topk``), and K and
+    :func:`chosen_rows`: a threshold held on the chip, no sort; a lane with
+    fewer rows takes them all, its list padded and masked: one program either
+    side of ``topk``), and K and
     V are fetched BY ROW through the block table (``sparse_walk``: a chosen
     position ``p`` is row ``table[p // P] * P + p % P`` of the pool
     flattened over pages and rows; at one row in six to thirteen chosen
@@ -575,11 +596,12 @@ def sparse_decode_attention_reference_cache_plus_new(
     costs by the slice and the lane tiles it touches, not by its bytes, so
     the walk is ONE gather of ``[S, topk, words]``, taken apart after the
     new token's row went in (PERF.md, PR 60). ``q``'s dtype is the rows'. ->
-    (out [S, H, d], positions chosen [S, topk] int32, -1 where a lane had
-    fewer)."""
+    (out [S, H, d], positions chosen [S, topk] int32 in no order a caller may
+    count on, -1 where a lane had fewer, lanes whose choice the tie rule
+    decided [S] bool)."""
     S, H, d = q.shape
     P = pool["kv"].shape[1]
-    chosen_pos, chosen = chosen_rows(pool["ik"], P, block_tables, seq_lens, new["ik"], qi, wi, topk, given)
+    chosen_pos, chosen, tied = chosen_rows(pool["ik"], P, block_tables, seq_lens, new["ik"], qi, wi, topk, given, interpret)
     with jax.named_scope("sparse_walk"):
         flat_row, is_new = chosen_flat_rows(chosen_pos, chosen, block_tables, seq_lens, P)
         leaf = pool["kv"]
@@ -592,7 +614,7 @@ def sparse_decode_attention_reference_cache_plus_new(
         logits = jnp.where(chosen[:, None, None, :], logits, NEG_INF)
         p = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("skrn,snkd->skrd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return out.reshape(S, H, d).astype(q.dtype), jnp.where(chosen, chosen_pos, -1)
+    return out.reshape(S, H, d).astype(q.dtype), jnp.where(chosen, chosen_pos, -1), tied
 
 
 def sparse_latent_decode_attention_cache_plus_new(
@@ -607,7 +629,8 @@ def sparse_latent_decode_attention_cache_plus_new(
     value_width: int,  # the row's first columns are the value
     score_dim: int,  # softmax scale: score_dim ** -0.5
     given: Optional[jax.Array] = None,  # [S, topk] int32 positions, -1 none: a choice given, not made
-) -> tuple[jax.Array, jax.Array]:
+    interpret: bool = False,  # the choice's kernel interpreted (tests)
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """A decode step of LATENT attention over rows chosen by a learned
     indexer (``models/dots.py``): :func:`sparse_decode_attention_reference_cache_plus_new`'s
     scoring and choice (``index_scores``, ``index_select``), then the chosen
@@ -618,9 +641,10 @@ def sparse_latent_decode_attention_cache_plus_new(
     form over a list of rows). One gather of ``[S, topk, width]`` and two
     products whose operands are the rows' dtype, accumulated in float32. ->
     (out [S, H, value_width] in q's dtype, positions chosen [S, topk] int32,
-    -1 where a lane had fewer)."""
+    -1 where a lane had fewer, lanes whose choice the tie rule decided [S]
+    bool)."""
     P = pool["kv"].shape[1]
-    chosen_pos, chosen = chosen_rows(pool["ik"], P, block_tables, seq_lens, new["ik"], qi, wi, topk, given)
+    chosen_pos, chosen, tied = chosen_rows(pool["ik"], P, block_tables, seq_lens, new["ik"], qi, wi, topk, given, interpret)
     with jax.named_scope("sparse_latent"):
         flat_row, is_new = chosen_flat_rows(chosen_pos, chosen, block_tables, seq_lens, P)
         leaf = pool["kv"]
@@ -629,7 +653,7 @@ def sparse_latent_decode_attention_cache_plus_new(
         logits = jnp.einsum("shw,snw->shn", q, got, preferred_element_type=jnp.float32) * (score_dim ** -0.5)
         p = jax.nn.softmax(jnp.where(chosen[:, None, :], logits, NEG_INF), axis=-1)
         out = jnp.einsum("shn,snv->shv", p.astype(got.dtype), got[..., :value_width], preferred_element_type=jnp.float32)
-    return out.astype(q.dtype), jnp.where(chosen, chosen_pos, -1)
+    return out.astype(q.dtype), jnp.where(chosen, chosen_pos, -1), tied
 
 
 def ring_latent_decode_attention_cache_plus_new(

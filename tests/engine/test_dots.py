@@ -123,19 +123,23 @@ def prefilled(pc, params, tokens, lengths, M=8, T=40):
     return cache, tables, lengths, logits
 
 
-def test_decode_steps_cross_topk_and_the_windows_edge_in_one_run_and_the_counters_count():
+@pytest.mark.parametrize("interpret", [False, True], ids=["top_k", "kernel"])
+def test_decode_steps_cross_topk_and_the_windows_edge_in_one_run_and_the_counters_count(interpret):
     """One decode program either side of `topk` (8 rows) and of the window
     (9 rows): a lane of 5 cached rows walks to 13 beside lanes of 20 and 33,
     every step against `forward` at its own length; the short lane's list is
     padded and masked until it holds 8 rows, and its ring holds fewer rows
-    than the window until it has 9; and what the counters count."""
+    than the window until it has 9; and what the counters count. With the
+    choice `jax.lax.top_k`'s (what a CPU serves) and the chip's kernel's,
+    interpreted (PR 62): the same numbers, `lanes_tied` among them."""
     family, pc, mesh, params = built()
     tokens, _ = text_tokens(B=3, T=48, seed=2)
     full = dots.forward(params, jnp.asarray(tokens), pc)
     cache, tables, lengths, logits = prefilled(pc, params, tokens, [5, 20, 33])
     np.testing.assert_allclose(logits, full[jnp.arange(3), lengths - 1], atol=5e-5, rtol=5e-5)
     live = []
-    step = jax.jit(lambda ca, tok, n: dots.decode_step_paged(params, ca, tok, n, tables, jnp.ones((3,), bool), pc, tell=True))
+    step = jax.jit(lambda ca, tok, n: dots.decode_step_paged(params, ca, tok, n, tables, jnp.ones((3,), bool), pc, tell=True,
+                                                             interpret=interpret))
     for j in range(8):
         cache, logits, (rows, experts) = step(cache, jnp.asarray(tokens)[jnp.arange(3), lengths + j], lengths + j)
         np.testing.assert_allclose(logits, full[jnp.arange(3), lengths + j], atol=5e-5, rtol=5e-5)
@@ -147,7 +151,7 @@ def test_decode_steps_cross_topk_and_the_windows_edge_in_one_run_and_the_counter
     sparse, window = got["sparse"], got["window"]
     seen = np.asarray(live)
     assert sparse["decode"] == {"steps": 8, "rows_scored": 8 * 3 * 8 * PAGE * 2, "rows_chosen": int(np.minimum(seen, 8).sum()) * 2,
-                                "rows_dense": int(seen.sum()) * 2, "lanes_past_topk": int((seen > 8).sum())}
+                                "rows_dense": int(seen.sum()) * 2, "lanes_past_topk": int((seen > 8).sum()), "lanes_tied": 0}
     assert window["decode"] == {"steps": 8, "rows_read": int(np.minimum(seen, 9).sum()), "rows_unwindowed": int(seen.sum()),
                                 "slots_past_window": int((seen > 9).sum())}
     assert sparse["prefill"]["rows_dense"] == sum(n * (n + 1) // 2 for n in (5, 20, 33)) * 2
@@ -194,11 +198,13 @@ def test_absorbed_attention_is_the_expanded_attention():
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
-def test_the_sparse_latent_step_attends_over_the_given_rows_alone_and_takes_the_new_row_from_its_argument():
+@pytest.mark.parametrize("interpret", [False, True], ids=["top_k", "kernel"])
+def test_the_sparse_latent_step_attends_over_the_given_rows_alone_and_takes_the_new_row_from_its_argument(interpret):
     """`ops.paged.sparse_latent_decode_attention_cache_plus_new` given a list
     of positions (the new token's own among them) against a masked dense
     softmax over the same latent rows; free, its choice is `top_k` of the
-    index scores with the new row's score in its place."""
+    index scores with the new row's score in its place, as a set: found by
+    `top_k` off the TPU and by the kernel on it (interpreted here)."""
     rng = np.random.default_rng(3)
     S, H, W, V, M, topk = 2, 3, 128, 96, 4, 6
     C = M * PAGE
@@ -209,7 +215,9 @@ def test_the_sparse_latent_step_attends_over_the_given_rows_alone_and_takes_the_
     new = {"kv": jnp.asarray(rng.normal(size=(S, W)), jnp.float32), "ik": jnp.asarray(rng.normal(size=(S, 16)), jnp.float32)}
     q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
     qi, wi = jnp.asarray(rng.normal(size=(S, 4, 16)), jnp.float32), jnp.asarray(rng.normal(size=(S, 4)), jnp.float32)
-    out, chosen = paged.sparse_latent_decode_attention_cache_plus_new(q, pool, tables, seq_lens, new, qi, wi, topk, V, 64)
+    out, chosen, tied = paged.sparse_latent_decode_attention_cache_plus_new(q, pool, tables, seq_lens, new, qi, wi, topk, V, 64,
+                                                                            interpret=interpret)
+    assert not np.asarray(tied).any()
     rows = pool["kv"][tables].reshape(S, C, W)
     keys = pool["ik"][tables].reshape(S, C, 16)
     for b in range(S):
@@ -223,7 +231,8 @@ def test_the_sparse_latent_step_attends_over_the_given_rows_alone_and_takes_the_
         logits = jnp.where(seen[None], q[b] @ ctx.T * 64 ** -0.5, -jnp.inf)
         np.testing.assert_allclose(out[b], jax.nn.softmax(logits, axis=-1) @ ctx[:, :V], atol=2e-5, rtol=2e-5)
     given = jnp.asarray([[0, 5, 19, -1, -1, -1], [27, 3, 2, 1, -1, -1]], jnp.int32)
-    out, told = paged.sparse_latent_decode_attention_cache_plus_new(q, pool, tables, seq_lens, new, qi, wi, topk, V, 64, given)
+    out, told, _tied = paged.sparse_latent_decode_attention_cache_plus_new(q, pool, tables, seq_lens, new, qi, wi, topk, V, 64,
+                                                                           given, interpret)
     assert np.array_equal(told, given)
     for b in range(S):
         n = int(seq_lens[b])

@@ -44,8 +44,10 @@ def test_dots_decode_block_chooses_latent_rows_and_copies_no_pool(v5e, monkeypat
     the resident set is the issue's arithmetic (5.15 GB of weights, 2.01 GB
     of latent rows and indexer keys on the page list, 0.12 GB of rings), the
     pool is donated and no op copies a leaf of it, a full layer's chosen rows
-    are one gather of 640-wide latent rows, and the leaves' names are in the
-    program's text."""
+    are one gather of 640-wide latent rows, the leaves' names are in the
+    program's text, and the choice is the kernel `index_select` at 64 heads'
+    scores of 20,480 columns, four lanes groups of eight, beside what the
+    block keeps (PR 62): no `sort` and no `top_k` of the context is left."""
     import re
 
     from agentcontrolplane_tpu import models
@@ -53,6 +55,7 @@ def test_dots_decode_block_chooses_latent_rows_and_copies_no_pool(v5e, monkeypat
     from agentcontrolplane_tpu.engine.lanes import DECODE
 
     dots, c, params, cache, vec = _dots(v5e, monkeypatch)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the step chooses its choice's kernel by the backend
     prog = models.programs(c)
     block = engine.make_decode_block(
         lambda p, pages, tokens, seq_lens, active, tables: prog.decode_step_paged(
@@ -79,6 +82,8 @@ def test_dots_decode_block_chooses_latent_rows_and_copies_no_pool(v5e, monkeypat
     assert mem.temp_size_in_bytes < 1.5e9, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB"
     for name in ("moe_gmm", "index_select", "sparse_latent", "ring_latent", "attn_gate", "mla_absorb"):
         assert name in text, name
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*index_select', text)) >= 1
+    assert not [line for line in text.splitlines() if "index_select" in line and (" sort(" in line or "top_k" in line)]
     assert 0.42 * 16e9 < _resident(compiled) < 0.60 * 16e9, f"{_resident(compiled) / 1e9:.2f} GB"
 
 

@@ -49,8 +49,9 @@ def test_keye_decode_block_chooses_rows_and_copies_no_pool(v5e, monkeypatch):
     of K|V rows and the indexer's keys stored a lane tile wide), the pool is
     donated and no op copies a leaf of it (a layer's whole `ik` is 109 MB,
     `kv` 1.74 GB), a layer's chosen rows are one gather of 32-bit words, the block's temporaries (16 lanes' gathered index keys
-    and chosen rows) a small fraction of it, and the choice is `top_k`, not
-    a sort of 26,624 scores."""
+    and chosen rows) a small fraction of it, and the choice is the kernel
+    `index_select` (PR 62: a threshold over a lane's 26,624 scores in VMEM,
+    two lane groups of eight): no `sort` and no `top_k` is left."""
     import re
 
     from agentcontrolplane_tpu import models
@@ -58,6 +59,7 @@ def test_keye_decode_block_chooses_rows_and_copies_no_pool(v5e, monkeypatch):
     from agentcontrolplane_tpu.engine.lanes import DECODE
 
     keye, c, params, cache, vec = _keye(v5e, monkeypatch)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the step chooses its choice's kernel by the backend
     prog = models.programs(c)
     block = engine.make_decode_block(
         lambda p, pages, tokens, seq_lens, active, tables: prog.decode_step_paged(
@@ -83,6 +85,8 @@ def test_keye_decode_block_chooses_rows_and_copies_no_pool(v5e, monkeypatch):
     assert mem.alias_size_in_bytes >= pool
     assert mem.temp_size_in_bytes < pool // 20, f"temporaries {mem.temp_size_in_bytes / 1e6:.0f} MB"
     assert "moe_gmm" in text and "index_select" in text and "sparse_walk" in text
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*index_select', text)) >= 1
+    assert not [line for line in text.splitlines() if "index_select" in line and (" sort(" in line or "top_k" in line)]
     assert 0.55 * 16e9 < _resident(compiled) < 0.65 * 16e9, f"{_resident(compiled) / 1e9:.2f} GB"
 
 

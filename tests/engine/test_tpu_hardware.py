@@ -103,6 +103,22 @@ def test_compiled_latent_walk_matches_reference(tpu, order):
     assert got["pages_per_turn"] == 32 and got["ok"], got
 
 
+@pytest.mark.parametrize("lanes, columns, coarse", [(16, 26624, False), (16, 26624, True), (32, 20480, True)],
+                         ids=["keyevl2", "keyevl2-ties", "dots3-ties"])
+def test_compiled_index_select_chooses_top_ks_set(tpu, lanes, columns, coarse):
+    """The indexer's choice COMPILED at the two sparse cells' sizes (2,048 of
+    a lane's 26,624 and 20,480 scores; lanes of 0 rows, of 1,024, and of the
+    whole width among them) against `jax.lax.top_k`'s set on the same chip,
+    on random scores (no lane tied) and on scores rounded until whole runs
+    of columns tie at every threshold (every long lane tied, the earlier
+    columns win)."""
+    from agentcontrolplane_tpu.engine.kernel_parity import index_select_parity
+
+    got = index_select_parity(9, lanes=lanes, columns=columns, coarse=coarse)
+    assert got["ok"], got
+    assert (got["lanes_tied"] >= lanes - 2) if coarse else got["lanes_tied"] == 0, got
+
+
 @pytest.mark.parametrize("window", [False, True], ids=["pages", "ring"])
 def test_compiled_verify_walk_matches_reference(tpu, window):
     """A verify step's two rows a lane in ONE query group of the walk,
