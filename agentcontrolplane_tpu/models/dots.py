@@ -60,7 +60,7 @@ Attention paths, equal in exact arithmetic (``tests/engine/test_dots.py``):
 
 - rows of tokens (prefill, continuation, ``forward``) **expand** rows to
   per-head K and V (``mla_expand``). A full layer makes its mask a block of
-  queries at a time (``models/keye.py``'s ``_prompt_mask``: index scores, the
+  queries at a time (``models/keye.py``'s ``prompt_mask``: index scores, the
   ``index_topk``-th largest a row) and attends under it ``HEAD_GROUP`` heads
   at a time (128 heads of a 16,384-row prompt's q, K, V and output are 3.2
   GB at once): on a TPU by the kernel of ``ops/pallas/masked_attention.py``
@@ -118,7 +118,7 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import causal_attention, continue_attention
+from ..ops.attention import CONTINUE_BLOCK, blocked_masked_attention, causal_attention, continue_attention
 from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
 from ..ops.paged import (
@@ -127,14 +127,14 @@ from ..ops.paged import (
     sparse_latent_decode_attention_cache_plus_new,
 )
 from ..ops.rope import apply_rope
-from .kanana import _experts, _rows  # the same expert layer (sigmoid, a selection bias, a shared expert) and row positions
-from .keye import (
-    CONTINUE_BLOCK, SPARSE_COUNTS, _causal_ok, _chosen_mask, _layer_norm, _masked_attention, _packed, _prompt_mask,
-    _row_blocks, _unpacked,
-)
-from .lfm2 import _embed, _final_norm, _head_logits, _mm, scan_layers
-from .lfm2 import describe_counters as _describe_moe
-from .mellum import WINDOW_COUNTS, _window_counts
+from .experts import describe_moe, routed_ff
+# the one family this file imports (`tests/engine/test_model_seam.py`): the chosen-rows machinery stays in `keye.py`
+# because the benchmark plants its controls of the choice on that module's globals by name (`index_scores`,
+# `topk_rows_mask`, `apply_rope`, `_layer_norm`: `acpbench/families/dots.py _planted`), and on `_layer_norm` and
+# `_rope_first` here: a function that read them from another module would silently no longer be planted on
+from .keye import SPARSE_COUNTS, _layer_norm, causal_ok, chosen_mask, packed, prompt_mask, row_blocks, unpacked
+from .stack import embed, final_norm, head_logits, key_positions, layer_row, mm, row_positions, scan_layers
+from .window import WINDOW_COUNTS, describe_window, slot_ring, window_counts
 
 HEAD_GROUP = 32  # heads a full layer's rows of tokens expand and attend at a time (module text)
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -353,28 +353,28 @@ def _attention_op(h, w, c: DotsConfig, g, positions, attend):
     B, T, _ = h.shape
     H = g.n_heads
     with jax.named_scope("attn_qkv"):
-        cq = rms_norm(_mm(h, w["wq_a"]), w["q_norm"], c.norm_eps) * jnp.asarray(g.a_q, h.dtype)
+        cq = rms_norm(mm(h, w["wq_a"]), w["q_norm"], c.norm_eps) * jnp.asarray(g.a_q, h.dtype)
         # outputs first, as the source stores a projection: the layout the decode step's compiler asks for (kanana)
         q_nope = jnp.einsum("btr,nr->btn", cq, w["wq_nope"].astype(h.dtype)).reshape(B, T, H, g.nope)
         q_pe = jnp.einsum("btr,nr->btn", cq, w["wq_pe"].astype(h.dtype)).reshape(B, T, H, g.rope)
         q_pe = apply_rope(q_pe, positions, g.theta)
-        lat = rms_norm(_mm(h, w["wkv_c"]), w["kv_norm"], c.norm_eps) * jnp.asarray(g.a_kv, h.dtype)
-        k_pe = apply_rope(_mm(h, w["wk_pe"])[..., None, :], positions, g.theta)[..., 0, :]  # one key for all heads
+        lat = rms_norm(mm(h, w["wkv_c"]), w["kv_norm"], c.norm_eps) * jnp.asarray(g.a_kv, h.dtype)
+        k_pe = apply_rope(mm(h, w["wk_pe"])[..., None, :], positions, g.theta)[..., 0, :]  # one key for all heads
         pad = jnp.zeros((B, T, g.row_stored - g.row_width), h.dtype)
         row = jnp.concatenate([lat.astype(h.dtype), k_pe.astype(h.dtype), pad], axis=-1)
     index, new = None, {"kv": row}
     if "iq" in w:
         with jax.named_scope("index_proj"):
-            qi = _rope_first(_mm(cq, w["iq"]).reshape(B, T, c.index_heads, c.index_head_dim), positions, g.theta, g.rope)
-            ki = _layer_norm(_mm(h, w["ik"]), w["ik_norm"], w["ik_bias"], c.norm_eps)
+            qi = _rope_first(mm(cq, w["iq"]).reshape(B, T, c.index_heads, c.index_head_dim), positions, g.theta, g.rope)
+            ki = _layer_norm(mm(h, w["ik"]), w["ik_norm"], w["ik_bias"], c.norm_eps)
             ik = _rope_first(ki[:, :, None, :], positions, g.theta, g.rope)[:, :, 0, :].astype(h.dtype)
             wi = jnp.matmul(h, w["iw"].astype(h.dtype), preferred_element_type=jnp.float32)  # the accumulator, unrounded
         index, new = (qi, wi, ik), {"kv": row, "ik": ik}
     out, extra, tied = attend(q_nope, q_pe, row, w, index)
     with jax.named_scope("attn_gate"):
-        out = out * jax.nn.sigmoid(_mm(h, w["wg"]).astype(jnp.float32)).astype(out.dtype)[..., None]
+        out = out * jax.nn.sigmoid(mm(h, w["wg"]).astype(jnp.float32)).astype(out.dtype)[..., None]
     with jax.named_scope("attn_out"):
-        return _mm(out.reshape(B, T, H * g.v), w["wo"]), new, extra, tied
+        return mm(out.reshape(B, T, H * g.v), w["wo"]), new, extra, tied
 
 
 def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, select=None, keep=lambda t: t,
@@ -401,13 +401,12 @@ def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, s
     ff = params["ff"]
     stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
     small = {name: ff[name] for name in ("ln2", "router", "router_bias", "sw1", "sw3", "sw2")}
-    row_of = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
     stack_of = {"dense_full": "dense", "full": "full", "sliding": "swa"}
 
     def layer(kind, carry, index, row):
         x, counts = carry
         full = kind != "sliding"
-        weights = row_of(params[stack_of[kind]], row)
+        weights = layer_row(params[stack_of[kind]], row)
         at = row + (nd if kind == "full" else 0)  # the layer of its leaves
         given = select[at] if full and select is not None else None
         with scopes.layer("attn"):
@@ -419,16 +418,17 @@ def _run_layers(params, c: DotsConfig, x, positions, valid, paths, route=None, s
             h = norm(x, weights["ln2"] if kind == "dense_full" else small["ln2"][index - nd])
             if kind == "dense_full":
                 with jax.named_scope("ffn_dense"):
-                    x = x + _mm(jax.nn.silu(_mm(h, weights["w1"])) * _mm(h, weights["w3"]), weights["w2"])
+                    x = x + mm(jax.nn.silu(mm(h, weights["w1"])) * mm(h, weights["w3"]), weights["w2"])
             else:
                 e = index - nd
-                mine = row_of(small, e)
+                mine = layer_row(small, e)
                 chosen = None if route is None else route[e]
                 if tell:
                     scores = jax.nn.sigmoid(h.astype(jnp.float32) @ mine["router"].astype(jnp.float32))
                     out["experts"] = (jax.lax.top_k(scores + mine["router_bias"], c.experts_per_token)[1]
                                       if chosen is None else chosen)
-                y, m = _experts(h, mine, stacks, e, c, valid, chosen)
+                y, m = routed_ff(h, mine, stacks, e, c, valid, chosen, score="sigmoid", bias=True,
+                                 scale=c.routed_scaling_factor, chunk=True, shared=True)
                 x, counts = x + y, counts.at[:-1].add(m)
         if tell and full:
             out["chose"] = told
@@ -510,11 +510,11 @@ def _whole_rows(c: DotsConfig, positions, tell, interpret: bool = False):
                     if given is None:
                         # ONE tier: the masks are 3 of a prefill's 54 ms a 1,000 tokens here, and a tier of its own
                         # is 2 s of this sandbox's compiler a program (4 tiers 19.3 s, one 13.1: PERF.md, PR 61)
-                        mask = _prompt_mask(c, positions, *index, tier=T)
+                        mask = prompt_mask(c, positions, *index, tier=T)
                     else:
-                        mask = _causal_ok(positions, positions) & _unpacked(given, T)
+                        mask = causal_ok(positions, positions) & unpacked(given, T)
                 out = _attend_under(c, c.full, mask, q_nope, q_pe, row, w, interpret)
-            return out, _packed(mask) if tell else None, 0
+            return out, packed(mask) if tell else None, 0
 
         def attend_sliding(q_nope, q_pe, row, w, index):
             k, v = _expand(row, w["wuk"], w["wuv"], c.swa)
@@ -537,11 +537,11 @@ def forward(params: dict, tokens: jax.Array, config: DotsConfig, select=None, ro
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     x, _rows_, _ring, _counts, told = _run_layers(
-        params, c, _embed(params, tokens, c), positions, jnp.ones((B, T), bool), _whole_rows(c, positions, tell, interpret),
+        params, c, embed(params, tokens, c), positions, jnp.ones((B, T), bool), _whole_rows(c, positions, tell, interpret),
         route, select, tell=tell)
     if rows is not None:
         x = x[jnp.arange(B)[:, None], rows]
-    logits = _head_logits(_final_norm(x, params, c), params, c)
+    logits = head_logits(final_norm(x, params, c), params, c)
     return (logits, told) if tell else logits
 
 
@@ -569,12 +569,6 @@ def _pools(cache: dict) -> tuple[dict, dict]:
     return {"kv": cache["kv"], "ik": cache["ik"]}, {"wkv": cache["wkv"]}
 
 
-def _ring(cache: dict, c: DotsConfig) -> tuple[int, int]:
-    """(pages of a ring, the slot whose ring nothing reads)."""
-    ring = ring_size(c.window, cache["wkv"].shape[2])
-    return ring, cache["wkv"].shape[1] // ring - 1
-
-
 def _committed(cache, full, win, counts, c: DotsConfig, row, scored, positions, valid):
     """The cache with its leaves replaced and the dispatch counted: the
     expert layers' counters, then ``keye``'s of A full layer (``scored`` the
@@ -584,7 +578,7 @@ def _committed(cache, full, win, counts, c: DotsConfig, row, scored, positions, 
     live = jnp.where(valid, positions + 1, 0).reshape(-1)
     sparse = jnp.stack([jnp.ones((), jnp.uint32), u32(scored), u32(jnp.sum(jnp.minimum(live, c.index_topk))),
                         u32(jnp.sum(live)), u32(jnp.sum(live > c.index_topk))])
-    window = _window_counts(SimpleNamespace(window=c.sliding_window_size), positions, valid)
+    window = window_counts(c.sliding_window_size, positions, valid)
     added = jnp.concatenate([counts[:-1], sparse, counts[-1:], window])  # `_run_layers` counts the tied lanes after the experts
     return {**full, **win, "state": {"counts": cache["state"]["counts"].at[row].add(added)}}
 
@@ -604,12 +598,12 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     slots, _snap_at = lanes
     B, T = tokens.shape
     zero = jnp.zeros((B,), jnp.int32)
-    positions, valid = _rows(lengths, zero, T)
+    positions, valid = row_positions(lengths, zero, T)
     full, win = _pools(cache)
-    ring, pad = _ring(cache, c)
+    ring, pad = slot_ring(cache["wkv"], c.window)
     keep, ring_ids = ring_newest(slots, zero, lengths, T, win["wkv"].shape[2], ring, pad)
     x, rows, fresh, counts, told = _run_layers(
-        params, c, _embed(params, tokens, c), positions, valid, _whole_rows(c, positions, tell, interpret), route, select,
+        params, c, embed(params, tokens, c), positions, valid, _whole_rows(c, positions, tell, interpret), route, select,
         keep, tell)
     with scopes.layer("commit"):
         full = commit_whole_pages(full, _paged(rows), page_ids)
@@ -617,7 +611,7 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
             win = commit_whole_pages(win, {"wkv": fresh[..., None, :]}, ring_ids)
         # a whole prompt's block of queries scores its causal keys' block columns: counted as the pairs it needs
         cache = _committed(cache, full, win, counts, c, 1, jnp.sum(lengths * (lengths + 1) // 2), positions, valid)
-    logits = _head_logits(_final_norm(x, params, c), params, c, last=lengths)
+    logits = head_logits(final_norm(x, params, c), params, c, last=lengths)
     return (cache, logits, told) if tell else (cache, logits)
 
 
@@ -663,15 +657,13 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     rows scored, positions, valid)."""
     slots, _snap_at = lanes
     B, T = tokens.shape
-    positions, valid = _rows(lengths, starts, T)
+    positions, valid = row_positions(lengths, starts, T)
     full, win = _pools(cache)
     NP, P = full["kv"].shape[1:3]
     NW = win["wkv"].shape[1]
-    ring, pad = _ring(cache, c)
+    ring, pad = slot_ring(cache["wkv"], c.window)
     M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
+    key_pos = key_positions(starts, positions, M * P)
     ring_pos = ring_positions(starts, ring, P)
     ring_pos = jnp.where(ring_pos < starts[:, None], ring_pos, -1)
     rings = ring_tables(jnp.minimum(slots, pad), ring)
@@ -687,11 +679,11 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
                 keys = jnp.concatenate([gather_pages(full, "ik", ids, row.dtype, 1).reshape(B, M * P, -1), ik], axis=1)
             with jax.named_scope("sparse_mask"):
                 if blocked:
-                    seen = jax.lax.map(lambda blk: _chosen_mask(c, blk[0], blk[1], keys, blk[2], key_pos),
-                                       tuple(_row_blocks(t, CONTINUE_BLOCK) for t in (qi, wi, positions)))
+                    seen = jax.lax.map(lambda blk: chosen_mask(c, blk[0], blk[1], keys, blk[2], key_pos),
+                                       tuple(row_blocks(t, CONTINUE_BLOCK) for t in (qi, wi, positions)))
                     mask = jnp.moveaxis(seen, 0, 1).reshape(B, T, -1)
                 else:
-                    mask = _chosen_mask(c, qi, wi, keys, positions, key_pos)
+                    mask = chosen_mask(c, qi, wi, keys, positions, key_pos)
             H = g.n_heads
             G, grouped = _head_groups(H)
 
@@ -700,9 +692,9 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
                 k, v = _expand(ctx, wuk, wuv, g)
                 with jax.named_scope("prefill_attention"):
                     if not blocked:
-                        return _masked_attention(q, k, v, mask)
-                    out = jax.lax.map(lambda blk: _masked_attention(blk[0], k, v, blk[1]),
-                                      tuple(_row_blocks(t, CONTINUE_BLOCK) for t in (q, mask)))
+                        return blocked_masked_attention(q, k, v, mask)
+                    out = jax.lax.map(lambda blk: blocked_masked_attention(blk[0], k, v, blk[1]),
+                                      tuple(row_blocks(t, CONTINUE_BLOCK) for t in (q, mask)))
                     return jnp.moveaxis(out, 0, 1).reshape(B, T, G, g.v)
 
             out = jax.lax.map(group, (grouped(_queries(q_nope, q_pe), 2), grouped(w["wuk"], 0), grouped(w["wuv"], 0)))
@@ -720,8 +712,8 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
         return attend_full if is_full else attend_sliding
 
     keep, ring_ids = ring_newest(jnp.minimum(slots, pad), starts, lengths, T, P, ring, pad)
-    x, rows, fresh, counts, _ = _run_layers(params, c, _embed(params, tokens, c), positions, valid, paths, keep=keep)
-    return _final_norm(x, params, c), rows, fresh, ring_ids, counts, jnp.sum(lengths) * (M * P + T), positions, valid
+    x, rows, fresh, counts, _ = _run_layers(params, c, embed(params, tokens, c), positions, valid, paths, keep=keep)
+    return final_norm(x, params, c), rows, fresh, ring_ids, counts, jnp.sum(lengths) * (M * P + T), positions, valid
 
 
 def _continue_commit(cache, new, page_ids, c: DotsConfig):
@@ -738,7 +730,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     """Continuation (a later chunk of a long prompt, a resumed request's
     tail): -> (cache, last-token logits [B, V])."""
     x, *new = _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    return _continue_commit(cache, new, page_ids, config), _head_logits(x, params, config, last=lengths)
+    return _continue_commit(cache, new, page_ids, config), head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -770,7 +762,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     full, win = _pools(cache)
     NP, P = full["kv"].shape[1:3]
     NW = win["wkv"].shape[1]
-    ring, pad = _ring(cache, c)
+    ring, pad = slot_ring(cache["wkv"], c.window)
     flat = {name: flat_pages(a) for name, a in full.items()}
     wflat = flat_pages(win["wkv"])
     rings = ring_tables(jnp.arange(S, dtype=jnp.int32), ring)
@@ -812,7 +804,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         return attend_full if is_full else attend_sliding
 
     positions = seq_lens[:, None]
-    x, rows, fresh, counts, told = _run_layers(params, c, _embed(params, tokens[:, None], c), positions, active[:, None],
+    x, rows, fresh, counts, told = _run_layers(params, c, embed(params, tokens[:, None], c), positions, active[:, None],
                                                paths, route, select, tell=tell)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
@@ -822,7 +814,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
             win = commit_tokens(win, {"wkv": fresh[:, :, 0, None, :]}, at, seq_lens % P)
         cache = _committed(cache, full, win, counts, c, 0, jnp.sum(active) * block_tables.shape[1] * P, positions,
                            active[:, None])
-    logits = _head_logits(_final_norm(x[:, 0], params, c), params, c)
+    logits = head_logits(final_norm(x[:, 0], params, c), params, c)
     return (cache, logits, told) if tell else (cache, logits)
 
 
@@ -862,19 +854,13 @@ def describe_counters(config: DotsConfig, total) -> dict:
         return {"steps": int(r[cut]), "rows_scored": int(r[cut + 1]) * n, "rows_chosen": int(r[cut + 2]) * n,
                 "rows_dense": int(r[cut + 3]) * n, "lanes_past_topk": int(r[cut + 4]), "lanes_tied": int(r[cut + 5])}
 
-    def window(r):
-        at = cut + SPARSE_COUNTS
-        return {"steps": int(r[at]), "rows_read": int(r[at + 1]), "rows_unwindowed": int(r[at + 2]),
-                "slots_past_window": int(r[at + 3])}
-
-    moe = _describe_moe(c, [r[:cut] for r in total])["moe"]
+    moe = describe_moe(c, [r[:cut] for r in total])["moe"]
     return {
         "moe": {**moe, "shared_width": c.shared_width},
         "sparse": {"topk": c.index_topk, "index_heads": c.index_heads, "index_values": c.index_head_dim,
                    "ik_row_bytes_stored": c.index_head_dim * itemsize, "row_values": c.full.row_width,
                    "row_bytes_stored": c.full.row_stored * itemsize, "layers": c.n_full,
                    "decode": sparse(total[0]), "prefill": sparse(total[1])},
-        "window": {"window": c.sliding_window_size, "window_layers": c.n_sliding, "full_layers": c.n_full,
-                   "row_values": c.swa.row_width, "row_bytes_stored": c.swa.row_stored * itemsize,
-                   "decode": window(total[0]), "prefill": window(total[1])},
+        **describe_window(total, cut + SPARSE_COUNTS, c.sliding_window_size, c.n_sliding, c.n_full,
+                          row_values=c.swa.row_width, row_bytes_stored=c.swa.row_stored * itemsize),
     }

@@ -6,12 +6,12 @@ family serves as its **drafter**.
 Every layer is ``h = x + Attn(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``:
 
 - ``Attn`` is GQA with an RMSNorm over each head of q and of k
-  (``lfm2._attention_op``). A ``sliding_attention`` layer turns q and k by
+  (``stack.attention_op``). A ``sliding_attention`` layer turns q and k by
   the one rope and sees the last ``window`` tokens, itself among them; a
   ``full_attention`` layer carries **no rotary** and sees every earlier
   token.
 - ``FF`` of the first ``first_dense`` layers is a SwiGLU of ``ffn_dim``; of
-  the others ``kanana._experts``: sigmoid scores, a selection bias in the
+  the others ``experts.routed_ff``: sigmoid scores, a selection bias in the
   choice only, top k renormalised and times ``routed_scaling_factor``, of
   which this chip holds ``experts_held``, plus the shared expert.
 - The MTP module (DeepSeek-V3's form): with ``x_i`` the stack's output at
@@ -80,17 +80,18 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
+from ..ops.attention import blocked_causal_attention, causal_attention
 from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
 from ..ops.paged import (
-    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, kv_commit, layer_tables,
+    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, init_kv_pages, kv_commit, layer_tables,
     paged_verify_attention_reference, ring_newest, ring_positions, ring_size, ring_tables,
 )
-from .kanana import _experts  # the routed experts beside the shared expert, as kanana's
-from .lfm2 import _attention_op, _embed, _final_norm, _head_logits, _mm, scan_layers
-from .mellum import WINDOW_COUNTS, _window_counts
-from .mellum import describe_counters as _describe_moe_window
+from .experts import describe_moe, routed_ff
+from .stack import (
+    attention_op, embed, final_norm, head_logits, key_positions, layer_row, mm, over_pages, row_positions, scan_layers,
+)
+from .window import WINDOW_COUNTS, describe_window, slot_ring, window_counts
 
 DRAFT_COUNTS = 4  # proposed, accepted, steps, rows committed
 ROWS = 2  # rows a verify step runs a lane: the committed token and the draft
@@ -228,7 +229,7 @@ def init_params(config: ExaoneConfig, key: jax.Array) -> dict:
 def _dense_ff(x, layer, c: ExaoneConfig):
     with scopes.layer("ffn"), jax.named_scope("ffn_dense"):
         h = rms_norm(x, layer["ln2"], c.norm_eps)
-        return x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
+        return x + mm(jax.nn.silu(mm(h, layer["w1"])) * mm(h, layer["w3"]), layer["w2"])
 
 
 def _run_layers(params, c: ExaoneConfig, x, positions, valid, make_attn, route=None, keep=lambda t: t,
@@ -245,13 +246,12 @@ def _run_layers(params, c: ExaoneConfig, x, positions, valid, make_attn, route=N
     pl = plan(c)
     dt = x.dtype
     norm = lambda x, w: rms_norm(x, w, c.norm_eps)  # noqa: E731
-    row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
     kept = {kind: ([], []) for kind in KINDS}
 
     def attention(kind, x, weights, at):
         full = kind == "full_attention"
         with scopes.layer("attn"):
-            op, k, v = _attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attn(full, at),
+            op, k, v = attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attn(full, at),
                                      walk=walk, rope=not full)
             x = x + op
         if not full:
@@ -277,12 +277,13 @@ def _run_layers(params, c: ExaoneConfig, x, positions, valid, make_attn, route=N
         def layer(kind, carry, index, at):
             x, counts = carry
             with scopes.layer("attn"):
-                weights = row(stack[kind], at)
+                weights = layer_row(stack[kind], at)
             x, k, v = attention(kind, x, weights, pl["before"][kind] + at)
             with scopes.layer("ffn"):
-                mine = row(small, index)
-                y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, valid,
-                                None if route is None else route[index])
+                mine = layer_row(small, index)
+                y, m = routed_ff(norm(x, mine["ln2"]), mine, stacks, index, c, valid,
+                                 None if route is None else route[index], score="sigmoid", bias=True,
+                                 scale=c.routed_scaling_factor, chunk=True, shared=True)
                 return (x + y, counts + m), (k, v)
 
         (x, counts), outs = scan_layers(pl["body"], (x, counts), layer)
@@ -302,14 +303,14 @@ def _mtp_rows(params, c: ExaoneConfig, hidden, next_tokens, attn_fn, walk="prefi
     V [B, R, H_kv, d])."""
     m = params["mtp"]
     with jax.named_scope("mtp_in_proj"):
-        e = _embed(params, next_tokens, c)
+        e = embed(params, next_tokens, c)
         with scopes.layer("ffn"):
             u = jnp.concatenate([rms_norm(hidden, m["hnorm"], c.norm_eps), rms_norm(e, m["enorm"], c.norm_eps)], -1)
-            u = _mm(u.astype(c.dtype), m["eh_proj"])
+            u = mm(u.astype(c.dtype), m["eh_proj"])
     with jax.named_scope("mtp_block"):
         layer = m["block"]
         with scopes.layer("attn"):
-            op, k, v = _attention_op(rms_norm(u, layer["ln1"], c.norm_eps), layer, c, None, attn_fn, walk=walk,
+            op, k, v = attention_op(rms_norm(u, layer["ln1"], c.norm_eps), layer, c, None, attn_fn, walk=walk,
                                      rope=False)
             x = u + op
         return _dense_ff(x, layer, c), k.astype(u.dtype), v.astype(u.dtype)
@@ -321,7 +322,7 @@ def _mtp_logits(params, c: ExaoneConfig, x, last=None):
     with jax.named_scope("mtp_head"):
         with scopes.layer("head"):
             x = rms_norm(x, params["mtp"]["norm"], c.norm_eps)
-        return _head_logits(x, params, c, last=last)
+        return head_logits(x, params, c, last=last)
 
 
 def forward(params: dict, tokens: jax.Array, config: ExaoneConfig) -> tuple[jax.Array, jax.Array]:
@@ -334,10 +335,10 @@ def forward(params: dict, tokens: jax.Array, config: ExaoneConfig) -> tuple[jax.
     def make_attn(full, i):
         return lambda q, k, v: causal_attention(q, k, v, positions, window=0 if full else c.window)
 
-    x, *_ = _run_layers(params, c, _embed(params, tokens, c), positions, jnp.ones((B, T), bool), make_attn)
+    x, *_ = _run_layers(params, c, embed(params, tokens, c), positions, jnp.ones((B, T), bool), make_attn)
     y, _k, _v = _mtp_rows(params, c, x[:, :-1], tokens[:, 1:],
                           lambda q, k, v: causal_attention(q, k, v, positions[:, :-1]))
-    return _head_logits(_final_norm(x, params, c), params, c), _mtp_logits(params, c, y)
+    return head_logits(final_norm(x, params, c), params, c), _mtp_logits(params, c, y)
 
 
 # ---------------------------------------------------------------------------
@@ -371,23 +372,11 @@ def _pools(cache: dict) -> tuple[dict, dict]:
     return {"k": cache["k"], "v": cache["v"]}, {"k": cache["wk"], "v": cache["wv"]}
 
 
-def _ring(cache: dict, c: ExaoneConfig) -> tuple[int, int]:
-    """(pages of a ring, the slot whose ring nothing reads)."""
-    ring = ring_size(c.window, cache["wk"].shape[2])
-    return ring, cache["wk"].shape[1] // ring - 1
-
-
-def _committed(cache, full, win, counts, window_counts, row, state=None, draft_counts=None):
+def _committed(cache, full, win, counts, windowed, row, state=None, draft_counts=None):
     zero = jnp.zeros((DRAFT_COUNTS,), jnp.uint32)
-    added = jnp.concatenate([counts, window_counts, zero if draft_counts is None else draft_counts])
+    added = jnp.concatenate([counts, windowed, zero if draft_counts is None else draft_counts])
     return {"k": full["k"], "v": full["v"], "wk": win["k"], "wv": win["v"],
             "state": {**cache["state"], **(state or {}), "counts": cache["state"]["counts"].at[row].add(added)}}
-
-
-def _rows(lengths, starts, T):
-    ar = jnp.arange(T)
-    valid = ar[None, :] < lengths[:, None]
-    return jnp.where(valid, starts[:, None] + ar[None, :], -1), valid
 
 
 def _pending(cache, x, lengths, slots, pad):
@@ -411,16 +400,16 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     slots, _snap_at = lanes
     B, T = tokens.shape
     zero = jnp.zeros((B,), jnp.int32)
-    positions, valid = _rows(lengths, zero, T)
+    positions, valid = row_positions(lengths, zero, T)
 
     def make_attn(full, i):
         return lambda q, k, v: blocked_causal_attention(q, k, v, positions, window=0 if full else c.window)
 
     full, win = _pools(cache)
-    ring, pad = _ring(cache, c)
+    ring, pad = slot_ring(cache["wk"], c.window)
     keep, ring_ids = ring_newest(slots, zero, lengths, T, win["k"].shape[2], ring, pad)
     x, wk, wv, fk, fv, counts = _run_layers(
-        params, c, _embed(params, tokens, c), positions, valid, make_attn, route, keep)
+        params, c, embed(params, tokens, c), positions, valid, make_attn, route, keep)
     # MTP row i: position i, the token after it the prompt's own; the last
     # position's next token is not the prompt's to give
     drafted = jnp.where(positions + 1 < lengths[:, None], positions, -1)
@@ -431,10 +420,10 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
         full = commit_whole_pages(full, new, page_ids)
         with jax.named_scope("window_commit"):
             win = commit_whole_pages(win, {"k": wk, "v": wv}, ring_ids)
-        cache = _committed(cache, full, win, counts, _window_counts(c, positions, valid), 1,
+        cache = _committed(cache, full, win, counts, window_counts(c.window, positions, valid), 1,
                            _pending(cache, x, lengths, slots, pad))
-    x = _final_norm(x, params, c)
-    return cache, _head_logits(x, params, c, last=lengths)
+    x = final_norm(x, params, c)
+    return cache, head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -447,41 +436,31 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     writes)."""
     slots, _snap_at = lanes
     B, T = tokens.shape
-    positions, valid = _rows(lengths, starts, T)
+    positions, valid = row_positions(lengths, starts, T)
     full, win = _pools(cache)
-    NP, P = full["k"].shape[1:3]
-    NW = win["k"].shape[1]
-    ring, pad = _ring(cache, c)
+    P = full["k"].shape[2]
+    ring, pad = slot_ring(cache["wk"], c.window)
     slots = jnp.minimum(slots, pad)
     M = block_tables.shape[1]
     row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    full_pos = jnp.concatenate([cache_pos, positions], axis=1)
+    full_pos = key_positions(starts, positions, M * P)
     ring_pos = ring_positions(starts, ring, P)
     ring_pos = jnp.where(ring_pos < starts[:, None], ring_pos, -1)
     win_pos = jnp.concatenate([ring_pos, positions], axis=1)
     rings = ring_tables(slots, ring)
 
-    def over_cache(q, k, v, pool, ids, i, n_pages, queries, key_pos, window=0):
-        """``q`` over layer ``i``'s gathered pages and the rows' own K/V."""
-        at = layer_tables(ids, i, n_pages)
-        k_rows = gather_pages(pool, "k", at, k.dtype, c.n_kv_heads).reshape(B, -1, *k.shape[2:])
-        v_rows = gather_pages(pool, "v", at, v.dtype, c.n_kv_heads).reshape(B, -1, *v.shape[2:])
-        return continue_attention(q, jnp.concatenate([k_rows, k], axis=1), jnp.concatenate([v_rows, v], axis=1),
-                                  queries, key_pos, window=window)
-
     def make_attn(is_full, i):
         def attn(q, k, v):
             with jax.named_scope("full_gather" if is_full else "window_walk"):
                 if is_full:
-                    return over_cache(q, k, v, full, block_tables, i, NP, positions, full_pos)
-                return over_cache(q, k, v, win, rings, i, NW, positions, win_pos, c.window)
+                    return over_pages(q, k, v, full, block_tables, i, c.n_kv_heads, positions, full_pos)
+                return over_pages(q, k, v, win, rings, i, c.n_kv_heads, positions, win_pos, window=c.window)
 
         return attn
 
     keep, ring_ids = ring_newest(slots, starts, lengths, T, P, ring, pad)
     x, wk, wv, fk, fv, counts = _run_layers(
-        params, c, _embed(params, tokens, c), positions, valid, make_attn, keep=keep)
+        params, c, embed(params, tokens, c), positions, valid, make_attn, keep=keep)
     # the MTP rows: T + 1 of them, row 0 the slot's pending row, row j + 1
     # position `starts + j`; row i's next token is tokens[i] (row T's none)
     owed = (starts > 0) & (lengths > 0) & (cache["state"]["pend"][slots] > 0)
@@ -494,19 +473,19 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
     def mtp_attn(q, k, v):
         with jax.named_scope("full_gather"):
-            return over_cache(q, k, v, full, block_tables, c.n_full, NP, drafted, mtp_pos)
+            return over_pages(q, k, v, full, block_tables, c.n_full, c.n_kv_heads, drafted, mtp_pos)
 
     _y, mk, mv = _mtp_rows(params, c, hidden, nxt, mtp_attn, walk=None)
     with scopes.layer("commit"):
         new = {"k": jnp.concatenate([fk, mk[None, :, 1:]]), "v": jnp.concatenate([fv, mv[None, :, 1:]])}
         before = jnp.maximum(starts - 1, 0)
         owed_at = (jnp.where(owed, block_tables[jnp.arange(B), before // P], TRASH_PAGE), before % P)
-        return (_final_norm(x, params, c), wk, wv, ring_ids, new, (mk[:, 0], mv[:, 0], owed_at), counts,
-                _window_counts(c, positions, valid), _pending(cache, x, lengths, slots, pad))
+        return (final_norm(x, params, c), wk, wv, ring_ids, new, (mk[:, 0], mv[:, 0], owed_at), counts,
+                window_counts(c.window, positions, valid), _pending(cache, x, lengths, slots, pad))
 
 
 def _continue_commit(cache, got, page_ids, c):
-    wk, wv, ring_ids, new, (ok, ov, (owed_page, owed_row)), counts, window_counts, state = got
+    wk, wv, ring_ids, new, (ok, ov, (owed_page, owed_row)), counts, windowed, state = got
     full, win = _pools(cache)
     NP = full["k"].shape[1]
     with scopes.layer("commit"):
@@ -519,7 +498,7 @@ def _continue_commit(cache, got, page_ids, c):
                 "v": flat_pages(full["v"]).at[at, owed_row].set(merge(ov).astype(full["v"].dtype)).reshape(full["v"].shape)}
         with jax.named_scope("window_commit"):
             win = commit_whole_pages(win, {"k": wk, "v": wv}, ring_ids)
-        return _committed(cache, full, win, counts, window_counts, 1, state)
+        return _committed(cache, full, win, counts, windowed, 1, state)
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -528,7 +507,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     tail): -> (cache, last-token logits [B, V])."""
     x, *got = _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, config)
     cache = _continue_commit(cache, got, page_ids, config)
-    return cache, _head_logits(x, params, config, last=lengths)
+    return cache, head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -547,7 +526,7 @@ def _step_attention(cache, c: ExaoneConfig, seq_lens, block_tables, use_pallas: 
     full, win = _pools(cache)
     NP, P = full["k"].shape[1:3]
     NW = win["k"].shape[1]
-    ring, _pad = _ring(cache, c)
+    ring, _pad = slot_ring(cache["wk"], c.window)
     flat = {"full": (flat_pages(full["k"]), flat_pages(full["v"])), "win": (flat_pages(win["k"]), flat_pages(win["v"]))}
     rings = ring_tables(jnp.arange(S, dtype=jnp.int32), ring)
     window = c.window if window_rows is None else window_rows
@@ -604,7 +583,7 @@ def _commit_step(cache, c: ExaoneConfig, new, seq_lens, block_tables, live, mtp=
     full = kv_commit(full, {"k": jnp.concatenate([fk, mk[None]]), "v": jnp.concatenate([fv, mv[None]])},
                      lambda arr, val: flat_pages(arr).at[ids, at].set(val).reshape(arr.shape))
     with jax.named_scope("window_commit"):
-        ring, pad = _ring(cache, c)
+        ring, pad = slot_ring(cache["wk"], c.window)
         where = seq_lens[:, None] + rows
         target = jnp.where(live, jnp.arange(S)[:, None], pad) * ring + jnp.mod(where // P, ring)
         win = commit_tokens(win, {"k": wk, "v": wv}, target, where % P)
@@ -622,8 +601,8 @@ def _stack_step(params, cache, rows, seq_lens, block_tables, live, c, use_pallas
     def make_attn(is_full, i):
         return lambda q, k, v: attend("full" if is_full else "win", i, q, k, v, seq_lens)
 
-    x, *new, counts = _run_layers(params, c, _embed(params, rows, c), positions, live, make_attn, route, walk=None)
-    return x, new, counts, _window_counts(c, positions, live)
+    x, *new, counts = _run_layers(params, c, embed(params, rows, c), positions, live, make_attn, route, walk=None)
+    return x, new, counts, window_counts(c.window, positions, live)
 
 
 def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, config: ExaoneConfig,
@@ -633,12 +612,12 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     held against, token for token. -> (cache, logits [S, V])."""
     c = config
     live = active[:, None]
-    x, new, counts, window_counts = _stack_step(
+    x, new, counts, windowed = _stack_step(
         params, cache, tokens[:, None], seq_lens, block_tables, live, c, use_pallas, route, window_rows)
     with scopes.layer("commit"):
         full, win = _commit_step(cache, c, new, seq_lens, block_tables, live)
-        cache = _committed(cache, full, win, counts, window_counts, 0)
-    return cache, _head_logits(_final_norm(x[:, 0], params, c), params, c)
+        cache = _committed(cache, full, win, counts, windowed, 0)
+    return cache, head_logits(final_norm(x[:, 0], params, c), params, c)
 
 
 def verify_step_paged(params, cache, tokens, seq_lens, block_tables, active, sampler, config: ExaoneConfig,
@@ -666,10 +645,10 @@ def verify_step_paged(params, cache, tokens, seq_lens, block_tables, active, sam
     draft, q_logits = sampler.propose(q_logits)
     # 2. verify: the stack over [t_n, d] at positions n, n + 1
     live = jnp.broadcast_to(active[:, None], (S, ROWS))
-    x, new, counts, window_counts = _stack_step(
+    x, new, counts, windowed = _stack_step(
         params, cache, jnp.stack([tokens, draft], axis=1), seq_lens, block_tables, live, c, use_pallas, route,
         window_rows)
-    logits = _head_logits(_final_norm(x, params, c).reshape(S * ROWS, -1), params, c).reshape(S, ROWS, -1)
+    logits = head_logits(final_norm(x, params, c).reshape(S * ROWS, -1), params, c).reshape(S, ROWS, -1)
     # 3. accept: one or two tokens a lane
     out, emitted, kept = sampler.accept(logits, draft, q_logits)
     with scopes.layer("commit"):
@@ -681,7 +660,7 @@ def verify_step_paged(params, cache, tokens, seq_lens, block_tables, active, sam
         u32 = lambda v: jnp.sum(v).astype(jnp.uint32)  # noqa: E731
         drafted = jnp.stack([u32(active), u32(active & kept), jnp.ones((), jnp.uint32),
                              u32(jnp.where(active, emitted, 0))])
-        cache = _committed(cache, full, win, counts, window_counts, 0, state, drafted)
+        cache = _committed(cache, full, win, counts, windowed, 0, state, drafted)
     return cache, out, emitted, {"logits": logits, "draft_logits": q_logits}
 
 
@@ -714,9 +693,10 @@ def describe_counters(config: ExaoneConfig, total) -> dict:
     if total is None:
         total = [[0] * (cut + DRAFT_COUNTS)] * 2
     proposed, accepted, steps, rows = (int(v) for v in total[0][cut:cut + DRAFT_COUNTS])
-    described = _describe_moe_window(c, [r[:cut] for r in total])
+    moe = describe_moe(c, [r[:cut - WINDOW_COUNTS] for r in total])["moe"]
     return {
-        **described, "moe": {**described["moe"], "shared_width": c.shared_width},
+        "moe": {**moe, "shared_width": c.shared_width},
+        **describe_window(total, cut - WINDOW_COUNTS, c.window, c.n_window, c.n_full),
         "drafter": {"proposed": proposed, "accepted": accepted, "steps": steps, "tokens": rows,
                     "tokens_per_step": rows / proposed if proposed else 0.0},
     }

@@ -69,14 +69,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import scopes
-from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
+from ..ops.attention import blocked_causal_attention, causal_attention
 from ..ops.norms import rms_norm
-from ..ops.paged import (
-    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, layer_tables,
-    paged_decode_attention_reference_cache_plus_new,
-)
+from ..ops.paged import TRASH_PAGE, commit_tokens, commit_whole_pages, init_kv_pages
 from ..ops.pallas import ssm_scan as ssm
-from .lfm2 import _embed, _final_norm, _head_logits, _kv, _rows_ctx, scan_layers  # the same for every family with state beside the pages
+from .recurrent import (  # noqa: F401  the seam's three among them
+    commit_state, conv_at, counters, install_state, saved_state, state_in,
+)
+from .stack import (
+    embed, final_norm, head_logits, kv_pool, layer_row, mm_weight_dtype, page_walk, plain_attention_op, prefix_attention,
+    rows_ctx, scan_layers,
+)
 
 N_COUNTERS = 4  # mamba_layers, rows, tokens, chunks
 STACK = {"mamba": "mamba", "attention": "attn"}  # a kind of layer -> its stack of weights in the tree
@@ -192,10 +195,6 @@ def init_params(config: JambaConfig, key: jax.Array) -> dict:
     return params
 
 
-def _mm(x, w, out=None):
-    return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=out or w.dtype)
-
-
 def _mamba_pre(h, layer, c: JambaConfig, conv_in, valid):
     """h [B, T, d] normed input; conv_in [B, taps-1, d_inner] (u before the
     row's first token). -> (u' f32 [B, T, d_inner], z f32, delta f32 (0 where
@@ -205,7 +204,7 @@ def _mamba_pre(h, layer, c: JambaConfig, conv_in, valid):
     di, n, r = c.d_inner, c.d_state, c.dt_rank
     T = h.shape[1]
     with jax.named_scope("mamba_in_proj"):
-        xz = _mm(h, layer["in_proj"], f32)
+        xz = mm_weight_dtype(h, layer["in_proj"], f32)
         # u in the model's dtype, the one the state keeps its columns in, so
         # that a decode step that reads three back convolves what the
         # prefill convolved
@@ -218,11 +217,11 @@ def _mamba_pre(h, layer, c: JambaConfig, conv_in, valid):
             conv = conv + layer["conv_b"].astype(f32)
         u_act = jax.nn.silu(conv)
     with jax.named_scope("mamba_x_proj"):
-        dbc = _mm(u_act.astype(h.dtype), layer["x_proj"], f32)
+        dbc = mm_weight_dtype(u_act.astype(h.dtype), layer["x_proj"], f32)
         dt = rms_norm(dbc[..., :r], layer["dt_norm"], c.norm_eps)
         b = rms_norm(dbc[..., r:r + n], layer["b_norm"], c.norm_eps)
         c_ = rms_norm(dbc[..., r + n:], layer["c_norm"], c.norm_eps)
-        delta = jax.nn.softplus(_mm(dt.astype(h.dtype), layer["dt_proj"], f32) + layer["dt_bias"].astype(f32))
+        delta = jax.nn.softplus(mm_weight_dtype(dt.astype(h.dtype), layer["dt_proj"], f32) + layer["dt_bias"].astype(f32))
         delta = jnp.where(valid[..., None], delta, 0.0)
     return u_act, z, delta, b, c_, u_ext
 
@@ -230,40 +229,14 @@ def _mamba_pre(h, layer, c: JambaConfig, conv_in, valid):
 def _mamba_post(y, u_act, z, layer, dtype):
     with jax.named_scope("mamba_out_proj"):
         y = (y + layer["D"].astype(jnp.float32) * u_act) * jax.nn.silu(z)
-        return _mm(y.astype(dtype), layer["out_proj"])
-
-
-def _conv_at(u_ext, rel, n: int):
-    """The n columns of u before token ``rel`` [B] of each row -> [B, n * d_inner]."""
-    T = u_ext.shape[1] - n
-    idx = jnp.clip(rel, 0, T)[:, None] + jnp.arange(n)[None, :]
-    got = jnp.take_along_axis(u_ext, idx[:, :, None], axis=1)
-    return got.reshape(got.shape[0], -1)
-
-
-def _attention_op(h, layer, c: JambaConfig, attn_fn, walk="prefill_attention"):
-    """-> (Op output, k, v): k and v are the layer's new rows for the pool.
-    ``walk`` is the scope ``attn_fn`` runs under (a decode step's: ``page_walk``)."""
-    B, T, _ = h.shape
-    with jax.named_scope("attn_qkv"):
-        q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
-        k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-        v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-    with jax.named_scope(walk):
-        out = attn_fn(q, k, v)
-    with jax.named_scope("attn_out"):
-        return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
+        return mm_weight_dtype(y.astype(dtype), layer["out_proj"])
 
 
 def _swiglu(x, ff, c: JambaConfig):
     """``x`` with the dense feed-forward's residual added."""
     with scopes.layer("ffn"), jax.named_scope("ffn_dense"):
         h = rms_norm(x, ff["ln2"], c.norm_eps)
-        return x + _mm(jax.nn.silu(_mm(h, ff["w1"])) * _mm(h, ff["w3"]), ff["w2"])
-
-
-def _row(tree, i):
-    return jax.tree_util.tree_map(lambda a: a[i], tree)
+        return x + mm_weight_dtype(jax.nn.silu(mm_weight_dtype(h, ff["w1"])) * mm_weight_dtype(h, ff["w3"]), ff["w2"])
 
 
 def _scanned(params, c: JambaConfig):
@@ -288,14 +261,14 @@ def _run_rows(params, c: JambaConfig, x, ctx, ssm_in, conv_in, make_attn):
 
     def attention(x, a_row, m_row):
         with scopes.layer("attn"):
-            layer = _row(params["attn"], a_row)
-            op, k, v = _attention_op(rms_norm(x, layer["ln1"], c.norm_eps), layer, c, make_attn(a_row))
+            layer = layer_row(params["attn"], a_row)
+            op, k, v = plain_attention_op(rms_norm(x, layer["ln1"], c.norm_eps), layer, c, make_attn(a_row))
             zh, zc = jnp.zeros(h_shape, f32), jnp.zeros(cv_shape, dt)
             return op, zh, zh, zc, zc, k.astype(dt), v.astype(dt)
 
     def mamba(x, a_row, m_row):
         with scopes.layer("mixer"):
-            layer = _row(params["mamba"], m_row)
+            layer = layer_row(params["mamba"], m_row)
             u_act, z, delta, b, c_, u_ext = _mamba_pre(
                 rms_norm(x, layer["ln1"], c.norm_eps), layer, c, conv_in[m_row].reshape(B, n, c.d_inner),
                 ctx["valid"])
@@ -305,7 +278,7 @@ def _run_rows(params, c: JambaConfig, x, ctx, ssm_in, conv_in, make_attn):
             op = _mamba_post(y, u_act, z, layer, dt)
             zero = jnp.zeros(kv_shape, dt)
             with jax.named_scope("mamba_conv"):
-                ends = _conv_at(u_ext, ctx["lengths"], n), _conv_at(u_ext, ctx["snap_rel"], n)
+                ends = conv_at(u_ext, ctx["lengths"], n), conv_at(u_ext, ctx["snap_rel"], n)
             return op, h_end, h_snap, *ends, zero, zero
 
     def body(x, scanned):
@@ -335,9 +308,9 @@ def forward(params: dict, tokens: jax.Array, config: JambaConfig) -> jax.Array:
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     ctx = {"positions": positions, "valid": jnp.ones((B, T), bool),
            "lengths": jnp.full((B,), T, jnp.int32), "snap_rel": jnp.full((B,), -1, jnp.int32)}
-    x, *_ = _run_rows(params, c, _embed(params, tokens, c), ctx, *_zero_state(c, B),
+    x, *_ = _run_rows(params, c, embed(params, tokens, c), ctx, *_zero_state(c, B),
                       lambda a: lambda q, k, v: causal_attention(q, k, v, positions))
-    return _head_logits(_final_norm(x, params, c), params, c)
+    return head_logits(final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -364,32 +337,6 @@ def _counts(c: JambaConfig, rows, tokens, chunks):
                                                 for x in (rows, tokens, chunks))])
 
 
-def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts):
-    """The cache with its pages replaced and the rows' state written: a
-    row's end state always, its snapshot where one fell inside the row. A
-    padding row names the last slot, which nothing reads."""
-    st = cache["state"]
-    with scopes.layer("commit"):
-        slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
-        out = {"snap": {}, "counters": st["counters"].at[1].add(counts)}
-        for name in ("ssm", "conv"):
-            out[name] = st[name].at[:, slots].set(ends[name].astype(st[name].dtype))
-            old = st["snap"][name][:, slots]
-            ok = snap_ok.reshape((1, -1) + (1,) * (old.ndim - 2))
-            out["snap"][name] = st["snap"][name].at[:, slots].set(jnp.where(ok, snaps[name].astype(old.dtype), old))
-        return {**pages, "state": out}
-
-
-def _state_in(cache, slots, starts):
-    """Zeros for a row that starts the sequence, the slot's state otherwise."""
-    st = cache["state"]
-    with scopes.layer("commit"):
-        slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
-        began = starts > 0
-        return (jnp.where(began[None, :, None, None], st["ssm"][:, slots], 0),
-                jnp.where(began[None, :, None], st["conv"][:, slots], 0))
-
-
 def _prefill_counts(c, lengths):
     with scopes.layer("commit"):
         return _counts(c, lengths > 0, lengths, -(-lengths // ssm.CHUNK))
@@ -402,15 +349,15 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     c = config
     slots, snap_at = lanes
     B, T = tokens.shape
-    ctx, snap_ok = _rows_ctx(lengths, jnp.zeros((B,), jnp.int32), snap_at, T)
+    ctx, snap_ok = rows_ctx(lengths, jnp.zeros((B,), jnp.int32), snap_at, T)
     positions = ctx["positions"]
     x, ends, snaps, new_k, new_v = _run_rows(
-        params, c, _embed(params, tokens, c), ctx, *_zero_state(c, B),
+        params, c, embed(params, tokens, c), ctx, *_zero_state(c, B),
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions))
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
-    cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, _prefill_counts(c, lengths))
-    x = _final_norm(x, params, c)
-    return cache, _head_logits(x, params, c, last=lengths)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
+    cache = commit_state(cache, pages, slots, ends, snaps, snap_ok, _prefill_counts(c, lengths))
+    x = final_norm(x, params, c)
+    return cache, head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -420,28 +367,12 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     snap_ok)."""
     slots, snap_at = lanes
     B, T = tokens.shape
-    ctx, snap_ok = _rows_ctx(lengths, starts, snap_at, T)
+    ctx, snap_ok = rows_ctx(lengths, starts, snap_at, T)
     positions = ctx["positions"]
-    pool = _kv(cache)
-    NP, P = pool["k"].shape[1], pool["k"].shape[2]
-    M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
-
-    def make_attn(a):
-        def attn(q, k, v):
-            ids = layer_tables(block_tables, a, NP)
-            k_rows = gather_pages(pool, "k", ids, k.dtype, c.n_kv_heads).reshape(B, M * P, *k.shape[2:])
-            v_rows = gather_pages(pool, "v", ids, v.dtype, c.n_kv_heads).reshape(B, M * P, *v.shape[2:])
-            return continue_attention(q, jnp.concatenate([k_rows, k], axis=1),
-                                      jnp.concatenate([v_rows, v], axis=1), positions, key_pos)
-
-        return attn
-
+    make_attn = prefix_attention(kv_pool(cache), block_tables, starts, positions, c.n_kv_heads)
     x, ends, snaps, new_k, new_v = _run_rows(
-        params, c, _embed(params, tokens, c), ctx, *_state_in(cache, slots, starts), make_attn)
-    return _final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok
+        params, c, embed(params, tokens, c), ctx, *state_in(cache, slots, starts), make_attn)
+    return final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -450,9 +381,9 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     prompt): -> (cache, last-token logits [B, V])."""
     x, new_k, new_v, ends, snaps, snap_ok = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
-    cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
-    return cache, _head_logits(x, params, config, last=lengths)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
+    cache = commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
+    return cache, head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -460,8 +391,8 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
     """The continuation's writes without the head (a mid chunk)."""
     _x, new_k, new_v, ends, snaps, snap_ok = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
-    return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
+    return commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths))
 
 
 def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, config: JambaConfig,
@@ -474,25 +405,11 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     0)."""
     c = config
     S = tokens.shape[0]
-    pool = _kv(cache)
-    NP, P = pool["k"].shape[1:3]
-    k_flat, v_flat = flat_pages(pool["k"]), flat_pages(pool["v"])
-    scales = (flat_pages(pool["ks"]), flat_pages(pool["vs"])) if "ks" in pool else (None, None)
+    pool = kv_pool(cache)
+    P = pool["k"].shape[2]
+    make_attn = page_walk(pool, block_tables, seq_lens, use_pallas)
     dt, f32 = c.dtype, jnp.float32
     n, di = c.d_conv - 1, c.d_inner
-
-    def make_attn(a):
-        def attn(q, k, v):
-            tables = layer_tables(block_tables, a, NP)
-            args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
-            if use_pallas:
-                from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
-
-                return paged_decode_attention_cache_plus_new(*args)[:, None]
-            return paged_decode_attention_reference_cache_plus_new(
-                *args, k_scales=scales[0], v_scales=scales[1])[:, None]
-
-        return attn
 
     # The stacked state is carried through the loops and each Mamba layer
     # updates its own row where it lies; it never enters a conditional,
@@ -501,10 +418,10 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     def layer(kind, carry, index, at):
         x, h_all, conv_all = carry
         with scopes.layer("attn" if kind == "attention" else "mixer"):
-            weights = _row(params[STACK[kind]], at)
+            weights = layer_row(params[STACK[kind]], at)
             h = rms_norm(x, weights["ln1"], c.norm_eps)
             if kind == "attention":
-                op, k, v = _attention_op(h, weights, c, make_attn(at), walk="page_walk")
+                op, k, v = plain_attention_op(h, weights, c, make_attn(at), walk="page_walk")
                 out = (k[:, 0].astype(dt), v[:, 0].astype(dt))
             else:
                 with jax.named_scope("mamba_conv"):
@@ -520,42 +437,20 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
                 out = ()
             x = x + op
         with scopes.layer("ffn"):
-            ff = _row(params["ff"], index)
+            ff = layer_row(params["ff"], index)
         return (_swiglu(x, ff, c), h_all, conv_all), out
 
     st = cache["state"]
     plan(c)  # refuses a pattern of one kind or of a kind it does not know
     (x, h_all, conv_all), outs = scan_layers(
-        c.layer_types, (_embed(params, tokens[:, None], c), st["ssm"], st["conv"]), layer)
+        c.layer_types, (embed(params, tokens[:, None], c), st["ssm"], st["conv"]), layer)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         pages = commit_tokens(pool, dict(zip(("k", "v"), outs["attention"])), target, seq_lens % P)
         counts = _counts(c, active, active, active)
         state = {"ssm": h_all, "conv": conv_all, "snap": st["snap"], "counters": st["counters"].at[0].add(counts)}
-    x = _final_norm(x[:, 0], params, c)
-    return {**pages, "state": state}, _head_logits(x, params, c)
-
-
-def install_state(cache: dict, slot, state: dict) -> dict:
-    """``state["ssm" | "conv"][:, slot]`` = the tree ``state`` ({"ssm"
-    [n_mamba, N, d_inner], "conv" [n_mamba, (taps-1) * d_inner]}): what a
-    continuation that starts past 0 in ``slot`` resumes from (a prefix
-    entry's, a parked turn's or a host entry's saved state)."""
-    st = cache["state"]
-    put = lambda a, s: jax.lax.dynamic_update_slice(  # noqa: E731
-        a, s.astype(a.dtype)[:, None], (0, slot) + (0,) * (a.ndim - 2))
-    return {**cache, "state": {**st, "ssm": put(st["ssm"], state["ssm"]), "conv": put(st["conv"], state["conv"])}}
-
-
-def saved_state(cache: dict, slot) -> dict:
-    """A copy of the slot's snapshot, as the tree ``install_state`` takes."""
-    take = lambda a: jax.lax.dynamic_slice(  # noqa: E731
-        a, (0, slot) + (0,) * (a.ndim - 2), (a.shape[0], 1) + a.shape[2:])[:, 0]
-    return jax.tree_util.tree_map(take, cache["state"]["snap"])
-
-
-def counters(cache: dict) -> jax.Array:
-    return cache["state"]["counters"]
+    x = final_norm(x[:, 0], params, c)
+    return {**pages, "state": state}, head_logits(x, params, c)
 
 
 def describe_counters(config: JambaConfig, total) -> dict:

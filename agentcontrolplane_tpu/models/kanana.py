@@ -93,20 +93,18 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
-from ..ops.moe import COUNTS_HEAD, routed_experts
+from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention_by_rows
+from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
 from ..ops.paged import (
     TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_latent_pages, layer_tables,
     latent_decode_attention_reference_cache_plus_new, pool_leaves,
 )
 from ..ops.rope import apply_rope, deinterleave_pairs
-from .lfm2 import _embed, _final_norm, _head_logits, _mm
-from .lfm2 import describe_counters as _describe_moe
+from .experts import describe_moe, routed_ff
+from .stack import embed, final_norm, head_logits, key_positions, mm, row_positions
 
 LATENT_COUNTS = 4  # dispatches, rows covered, rows expanded, rows fetched
-MOE_CHUNK = 2048  # tokens the routed FF takes at a time (models/mellum.py says why)
-CONTINUE_BLOCK = 512  # query rows a continuation attends at a time
 
 
 @dataclass(frozen=True)
@@ -276,41 +274,14 @@ def _attention_op(h, w, c: KananaConfig, positions, attend):
             B, T, c.n_heads, c.qk_nope_head_dim)
         q_pe = jnp.einsum("btd,nd->btn", h, w["wq_pe"].astype(h.dtype)).reshape(B, T, c.n_heads, c.qk_rope_head_dim)
         q_pe = apply_rope(q_pe, positions, c.rope_theta)
-        lat = rms_norm(_mm(h, w["wkv_c"]), w["kv_norm"], c.norm_eps)
+        lat = rms_norm(mm(h, w["wkv_c"]), w["kv_norm"], c.norm_eps)
         # one key for all heads: rotated as one head
-        k_pe = apply_rope(_mm(h, w["wk_pe"])[..., None, :], positions, c.rope_theta)[..., 0, :]
+        k_pe = apply_rope(mm(h, w["wk_pe"])[..., None, :], positions, c.rope_theta)[..., 0, :]
         pad = jnp.zeros((B, T, c.row_stored - c.row_width), h.dtype)
         rows = jnp.concatenate([lat.astype(h.dtype), k_pe.astype(h.dtype), pad], axis=-1)
     out, expanded = attend(q_nope, q_pe, rows, w)
     with jax.named_scope("attn_out"):
-        return _mm(out.reshape(B, T, c.n_heads * c.v_head_dim), w["wo"]), rows, expanded
-
-
-def _experts(x, ff, stacks, layer_index, c: KananaConfig, valid, chosen=None):
-    """The FF of expert layer ``layer_index`` (traced): the routed experts
-    held here (``ff`` holds the router, ``stacks`` every layer's experts
-    flattened to one leading axis, which the grouped matmul indexes from
-    ``layer_index * held``) plus the shared expert over every row. ``chosen``
-    [B, T, k] is a routing given and not made. -> (FF output, counters)."""
-    B, T, D = x.shape
-    k = c.experts_per_token
-
-    def routed(rows):
-        x, valid, chosen = rows
-        return routed_experts(x, ff["router"], *stacks, k, held=c.held, score="sigmoid", bias=ff["router_bias"],
-                              renormalize=c.norm_topk_prob, scale=c.routed_scaling_factor, valid=valid,
-                              expert_base=layer_index * len(c.held), chosen=chosen)
-
-    rows = (x.reshape(B * T, D), valid.reshape(B * T), None if chosen is None else chosen.reshape(B * T, k))
-    if B * T > MOE_CHUNK and B * T % MOE_CHUNK == 0:
-        chunked = jax.tree_util.tree_map(lambda a: a.reshape((-1, MOE_CHUNK) + a.shape[1:]), rows)
-        y, counts = jax.lax.map(routed, chunked)
-        counts = jnp.sum(counts, axis=0, dtype=jnp.uint32)
-    else:
-        y, counts = routed(rows)
-    with jax.named_scope("moe_shared"):
-        y = y.reshape(B, T, D) + _mm(jax.nn.silu(_mm(x, ff["sw1"])) * _mm(x, ff["sw3"]), ff["sw2"])
-    return y, jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
+        return mm(out.reshape(B, T, c.n_heads * c.v_head_dim), w["wo"]), rows, expanded
 
 
 def _run_layers(params, c: KananaConfig, x, positions, valid, make_attend, route=None):
@@ -331,7 +302,7 @@ def _run_layers(params, c: KananaConfig, x, positions, valid, make_attend, route
         pro_rows.append(rows.astype(dt)[None])
         with scopes.layer("ffn"), jax.named_scope("ffn_dense"):
             h = norm(x, layer["ln2"])
-            x = x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
+            x = x + mm(jax.nn.silu(mm(h, layer["w1"])) * mm(h, layer["w3"]), layer["w2"])
 
     counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
     n = c.n_layers - c.first_dense
@@ -348,7 +319,8 @@ def _run_layers(params, c: KananaConfig, x, positions, valid, make_attend, route
                                             make_attend(c.first_dense + index))
                 x = x + op
             with scopes.layer("ffn"):
-                y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, valid, chosen)
+                y, m = routed_ff(norm(x, mine["ln2"]), mine, stacks, index, c, valid, chosen, score="sigmoid", bias=True,
+                                 scale=c.routed_scaling_factor, chunk=True, shared=True)
                 return (x + y, counts + m, expanded + jnp.uint32(n)), rows.astype(dt)
 
         (x, counts, expanded), rows = jax.lax.scan(
@@ -374,8 +346,8 @@ def forward(params: dict, tokens: jax.Array, config: KananaConfig) -> jax.Array:
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     attend = _expanded(c, lambda q, k, v: causal_attention(q, k, v, positions))
-    x, *_ = _run_layers(params, c, _embed(params, tokens, c), positions, jnp.ones((B, T), bool), lambda i: attend)
-    return _head_logits(_final_norm(x, params, c), params, c)
+    x, *_ = _run_layers(params, c, embed(params, tokens, c), positions, jnp.ones((B, T), bool), lambda i: attend)
+    return head_logits(final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -401,26 +373,20 @@ def _committed(cache, pool, counts, covered, expanded, row, fetched=0):
     return {**pool, "state": {"counts": cache["state"]["counts"].at[row].add(added)}}
 
 
-def _rows(lengths, starts, T):
-    ar = jnp.arange(T)
-    valid = ar[None, :] < lengths[:, None]
-    return jnp.where(valid, starts[:, None] + ar[None, :], -1), valid
-
-
 def prefill_paged_batch(params, cache, tokens, lengths, page_ids, config: KananaConfig, route=None):
     """B whole prompts in one dispatch, expanded: each row's latent rows into
     its pages. -> (cache, logits [B, V])."""
     c = config
     B, T = tokens.shape
-    positions, valid = _rows(lengths, jnp.zeros((B,), jnp.int32), T)
+    positions, valid = row_positions(lengths, jnp.zeros((B,), jnp.int32), T)
     attend = _expanded(c, lambda q, k, v: blocked_causal_attention(q, k, v, positions))
     x, rows, counts, expanded = _run_layers(
-        params, c, _embed(params, tokens, c), positions, valid, lambda i: attend, route)
+        params, c, embed(params, tokens, c), positions, valid, lambda i: attend, route)
     with scopes.layer("commit"):
         pool = commit_whole_pages(pool_leaves(cache), {"kv": rows[..., None, :]}, page_ids)
         cache = _committed(cache, pool, counts, jnp.sum(lengths), expanded, 1)
-    x = _final_norm(x, params, c)
-    return cache, _head_logits(x, params, c, last=lengths)
+    x = final_norm(x, params, c)
+    return cache, head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, c: KananaConfig):
@@ -430,13 +396,11 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     attended densely. Nothing is written here. -> (x normed, new rows,
     counts, rows covered, rows expanded, rows fetched)."""
     B, T = tokens.shape
-    positions, valid = _rows(lengths, starts, T)
+    positions, valid = row_positions(lengths, starts, T)
     pool = pool_leaves(cache)
     NP, P = pool["kv"].shape[1:3]
     M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
+    key_pos = key_positions(starts, positions, M * P)
 
     def make_attend(i):
         def attend(q_nope, q_pe, rows, w):
@@ -446,21 +410,15 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
             k, v, n = _expand(ctx, w, c)
             q = jnp.concatenate([q_nope, q_pe], axis=-1)
             with jax.named_scope("prefill_attention"):
-                if T <= CONTINUE_BLOCK or T % CONTINUE_BLOCK:
-                    return continue_attention(q, k, v, positions, key_pos), n
                 # dense over the keys, a block of query rows at a time: the
                 # scores of 3,072 rows against 8,192 keys are 3.2 GB at once
-                split = lambda t: jnp.moveaxis(  # noqa: E731
-                    t.reshape((B, T // CONTINUE_BLOCK, CONTINUE_BLOCK) + t.shape[2:]), 1, 0)
-                out = jax.lax.map(lambda blk: continue_attention(blk[0], k, v, blk[1], key_pos),
-                                  (split(q), split(positions)))
-                return jnp.moveaxis(out, 0, 1).reshape(B, T, c.n_heads, c.v_head_dim), n
+                return continue_attention_by_rows(q, k, v, positions, key_pos), n
 
         return attend
 
-    x, rows, counts, expanded = _run_layers(params, c, _embed(params, tokens, c), positions, valid, make_attend)
+    x, rows, counts, expanded = _run_layers(params, c, embed(params, tokens, c), positions, valid, make_attend)
     live = jnp.where(lengths > 0, starts, 0)
-    return _final_norm(x, params, c), rows, counts, jnp.sum(lengths + live), expanded, B * M * P
+    return final_norm(x, params, c), rows, counts, jnp.sum(lengths + live), expanded, B * M * P
 
 
 def _continue_commit(cache, new, page_ids):
@@ -474,7 +432,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     """Continuation (a prefix hit's suffix, a later chunk of a long prompt, a
     resumed request's tail): -> (cache, last-token logits [B, V])."""
     x, *new = _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, config)
-    return _continue_commit(cache, new, page_ids), _head_logits(x, params, config, last=lengths)
+    return _continue_commit(cache, new, page_ids), head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables,
@@ -525,13 +483,13 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         return attend
 
     x, rows, counts, expanded = _run_layers(
-        params, c, _embed(params, tokens[:, None], c), seq_lens[:, None], active[:, None], make_attend, route)
+        params, c, embed(params, tokens[:, None], c), seq_lens[:, None], active[:, None], make_attend, route)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         pool = commit_tokens(pool, {"kv": rows[:, :, 0, None, :]}, target, seq_lens % P)
         cache = _committed(cache, pool, counts, jnp.sum(jnp.where(active, seq_lens + 1, 0)), expanded, 0, fetched)
-    x = _final_norm(x[:, 0], params, c)
-    return cache, _head_logits(x, params, c)
+    x = final_norm(x[:, 0], params, c)
+    return cache, head_logits(x, params, c)
 
 
 def counters(cache: dict) -> jax.Array:
@@ -563,7 +521,7 @@ def describe_counters(config: KananaConfig, total) -> dict:
         return {"steps": int(r[cut]), "rows_read": int(r[cut + 1]), "rows_expanded": int(r[cut + 2]),
                 "rows_fetched": int(r[cut + 3])}
 
-    moe = _describe_moe(c, [r[:cut] for r in total])["moe"]
+    moe = describe_moe(c, [r[:cut] for r in total])["moe"]
     return {
         "moe": {**moe, "shared_width": c.shared_width},
         "latent": {"row_values": c.row_width, "row_bytes_stored": c.row_stored * jnp.dtype(c.dtype).itemsize,

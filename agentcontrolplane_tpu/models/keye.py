@@ -62,7 +62,7 @@ Two attention paths, equal in exact arithmetic
 - rows of tokens (prefill, continuation, ``forward``): dense operations,
   the sparse result. A block of ``MASK_BLOCK`` query rows at a time, its
   index scores against the keys of its tier of ``MASK_TIER`` rows (one
-  ``lax.map`` a tier: ``_prompt_mask``), each query's ``index_topk``-th
+  ``lax.map`` a tier: ``prompt_mask``), each query's ``index_topk``-th
   largest (``topk_rows_mask``: a threshold found by compare-and-count, no
   sort, as the decode step's 16 rows find theirs inside one kernel,
   ``ops/pallas/index_select.py``: the two give one choice, ties and all),
@@ -106,10 +106,7 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import (
-    NEG_INF, causal_attention, index_scores, online_softmax_finalize, online_softmax_step, repeat_kv,
-    topk_rows_mask,
-)
+from ..ops.attention import CONTINUE_BLOCK, blocked_masked_attention, causal_attention, index_scores, topk_rows_mask
 from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
 from ..ops.paged import (
@@ -117,9 +114,8 @@ from ..ops.paged import (
     pack_kv_rows, pool_leaves, sparse_decode_attention_reference_cache_plus_new, unpack_kv_rows,
 )
 from ..ops.rope import apply_rope
-from .lfm2 import _embed, _final_norm, _head_logits, _mm
-from .lfm2 import describe_counters as _describe_moe
-from .mellum import _experts, _rows  # the same expert layer (a softmax router, top k renormalised) and row positions
+from .experts import describe_moe, routed_ff
+from .stack import embed, final_norm, head_logits, key_positions, mm, row_positions
 
 SPARSE_COUNTS = 6  # dispatches, rows scored, rows chosen, rows a dense walk would read, lanes past topk, lanes tied
 # query rows of a whole prompt whose index scores and threshold are made at once: 50 MB of float32 scores against
@@ -127,12 +123,10 @@ SPARSE_COUNTS = 6  # dispatches, rows scored, rows chosen, rows a dense walk wou
 # 10.8 ms where two blocks of 1,024 take 3.5: PR 58's builder's chip runs; at 512 a 24,576-row prefill of 8 layers
 # is 974 ms where blocks of 1,024 are 1,024 ms: my chip run, PR 59)
 MASK_BLOCK = 512
-# rows of a tier, whose blocks are one `lax.map` over the tier's keys (`_prompt_mask`): every bucket is whole tiers.
+# rows of a tier, whose blocks are one `lax.map` over the tier's keys (`prompt_mask`): every bucket is whole tiers.
 # The whole prefill at 24,576 rows, compile and run (my chip run, PR 59): blocks written out against their own keys
 # 30.9 s and 992 ms, tiers of 4,096 11.3 s and 974 ms, of 8,192 9.2 s and 1,060 ms, one map 5.8 s and 1,132 ms
 MASK_TIER = 4096
-CONTINUE_BLOCK = 512  # query rows a continuation attends at a time
-KEY_BLOCK = 2048  # keys a continuation's block of queries folds at a time
 
 
 @dataclass(frozen=True)
@@ -234,23 +228,23 @@ def _attention_op(h, w, c: KeyeConfig, positions, attend, positions3=None):
     q and k an axis a section."""
     B, T, _ = h.shape
     with jax.named_scope("attn_qkv"):
-        q = rms_norm(_mm(h, w["wq"]).reshape(B, T, c.n_heads, c.head_dim), w["q_norm"], c.norm_eps)
-        k = rms_norm(_mm(h, w["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim), w["k_norm"], c.norm_eps)
-        v = _mm(h, w["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
+        q = rms_norm(mm(h, w["wq"]).reshape(B, T, c.n_heads, c.head_dim), w["q_norm"], c.norm_eps)
+        k = rms_norm(mm(h, w["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim), w["k_norm"], c.norm_eps)
+        v = mm(h, w["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
         if positions3 is None:
             q, k = apply_rope(q, positions, c.rope_theta), apply_rope(k, positions, c.rope_theta)
         else:
             q = apply_rope(q, positions3, c.rope_theta, sections=c.mrope_section)
             k = apply_rope(k, positions3, c.rope_theta, sections=c.mrope_section)
     with jax.named_scope("index_proj"):
-        qi = apply_rope(_mm(h, w["iq"]).reshape(B, T, c.index_heads, c.index_head_dim), positions, c.rope_theta)
-        ki = _layer_norm(_mm(h, w["ik"]), w["ik_norm"], w["ik_bias"], c.norm_eps)
+        qi = apply_rope(mm(h, w["iq"]).reshape(B, T, c.index_heads, c.index_head_dim), positions, c.rope_theta)
+        ki = _layer_norm(mm(h, w["ik"]), w["ik_norm"], w["ik_bias"], c.norm_eps)
         ki = apply_rope(ki[:, :, None, :], positions, c.rope_theta)[:, :, 0, :]  # one key for all heads: one head
         ik = jnp.pad(ki.astype(h.dtype), ((0, 0), (0, 0), (0, c.ik_stored - c.index_head_dim)))
         wi = jnp.matmul(h, w["iw"].astype(h.dtype), preferred_element_type=jnp.float32)  # the accumulator, unrounded
     out, extra, tied = attend(q, k, v, qi, wi, ik)
     with jax.named_scope("attn_out"):
-        op = _mm(out.reshape(B, T, c.n_heads * c.head_dim), w["wo"])
+        op = mm(out.reshape(B, T, c.n_heads * c.head_dim), w["wo"])
     kv = pack_kv_rows(*(t.reshape(B, T, -1).astype(h.dtype) for t in (k, v)))
     return op, {"kv": kv[:, :, None, :], "ik": ik[:, :, None, :]}, extra, tied
 
@@ -286,7 +280,7 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
             if tell:
                 logits = h.astype(jnp.float32) @ mine["router"].astype(jnp.float32)
                 experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), c.experts_per_token)[1] if chosen is None else chosen
-            y, m = _experts(h, mine, stacks, index, c, valid, chosen)
+            y, m = routed_ff(h, mine, stacks, index, c, valid, chosen, chunk=True)
             counts = counts + jnp.concatenate([m, jnp.asarray(tied, jnp.uint32)[None]])
             return (x + y, counts), (rows, (told, experts) if tell else None)
 
@@ -296,38 +290,38 @@ def _run_layers(params, c: KeyeConfig, x, positions, valid, make_attend, route=N
     return x, rows, counts, told
 
 
-def _causal_ok(q_pos, k_pos):
+def causal_ok(q_pos, k_pos):
     """[B, Tq], [B, Tk] -> [B, Tq, Tk]: key at or before the query, both real."""
     return (k_pos[:, None, :] <= q_pos[:, :, None]) & (k_pos[:, None, :] >= 0) & (q_pos[:, :, None] >= 0)
 
 
-def _chosen_mask(c: KeyeConfig, qi, wi, ik, q_pos, k_pos):
+def chosen_mask(c: KeyeConfig, qi, wi, ik, q_pos, k_pos):
     """[B, Tq, Tk] bool: each query's ``index_topk`` causal keys of largest
     index score."""
     B, Tq, Tk = qi.shape[0], qi.shape[1], ik.shape[1]
     with jax.named_scope("index_scores"):
         scores = index_scores(qi, wi, ik)
     with jax.named_scope("index_select"):
-        ok = _causal_ok(q_pos, k_pos)
+        ok = causal_ok(q_pos, k_pos)
         return topk_rows_mask(scores.reshape(B * Tq, Tk), ok.reshape(B * Tq, Tk), c.index_topk).reshape(B, Tq, Tk)
 
 
-def _packed(mask):
+def packed(mask):
     """[..., Tk] bool -> [..., ceil(Tk / 8)] uint8, key ``s`` bit ``s % 8`` of byte ``s // 8``."""
     return jnp.packbits(mask, axis=-1, bitorder="little")
 
 
-def _unpacked(bits, n):
+def unpacked(bits, n):
     return jnp.unpackbits(bits, axis=-1, count=n, bitorder="little").astype(bool)
 
 
-def _row_blocks(t, rows: int):
+def row_blocks(t, rows: int):
     """[B, T, ...] -> [T / rows, B, rows, ...]: blocks of rows first, for a ``lax.map`` over them."""
     B, T = t.shape[:2]
     return jnp.moveaxis(t.reshape((B, T // rows, rows) + t.shape[2:]), 1, 0)
 
 
-def _prompt_mask(c: KeyeConfig, positions, qi, wi, ik, tier: int | None = None):
+def prompt_mask(c: KeyeConfig, positions, qi, wi, ik, tier: int | None = None):
     """[B, T, T] bool: the causal keys each query of a whole prompt chooses,
     ``MASK_BLOCK`` query rows at a time (index scores, then each row's
     ``index_topk``-th largest by ``topk_rows_mask``). The blocks of one TIER,
@@ -348,8 +342,8 @@ def _prompt_mask(c: KeyeConfig, positions, qi, wi, ik, tier: int | None = None):
     tiers = []
     for hi in range(step, T + 1, step):
         keys, key_pos = ik[:, :hi], positions[:, :hi]
-        blocks = tuple(_row_blocks(t[:, hi - step: hi], R) for t in (qi, wi, positions))
-        seen = jax.lax.map(lambda blk: _chosen_mask(c, blk[0], blk[1], keys, blk[2], key_pos), blocks)  # noqa: B023
+        blocks = tuple(row_blocks(t[:, hi - step: hi], R) for t in (qi, wi, positions))
+        seen = jax.lax.map(lambda blk: chosen_mask(c, blk[0], blk[1], keys, blk[2], key_pos), blocks)  # noqa: B023
         tiers.append(jnp.pad(jnp.moveaxis(seen, 0, 1).reshape(B, step, hi), ((0, 0), (0, 0), (0, T - hi))))
     return tiers[0] if len(tiers) == 1 else jnp.concatenate(tiers, axis=1)
 
@@ -358,7 +352,7 @@ def _whole_rows(c: KeyeConfig, positions, tell, interpret: bool = False):
     """The path of rows that attend over themselves alone (a whole prompt,
     ``forward``). ``given`` [B, T, ceil(T / 8)] uint8 is a choice of rows
     handed in, packed; with ``tell`` the path hands its own on, so packed.
-    The mask (``_prompt_mask``, or the choice given under the causal rule) is
+    The mask (``prompt_mask``, or the choice given under the causal rule) is
     handed whole (``[T, T]``: 604 MB of int8 at 24,576 tokens, a layer at a
     time) to the attention under it: on a TPU (or ``interpret``: tests) the
     kernel of ``ops/pallas/masked_attention.py``, which refuses a ``T`` it
@@ -371,9 +365,9 @@ def _whole_rows(c: KeyeConfig, positions, tell, interpret: bool = False):
             with jax.named_scope("prefill_attention"):
                 with jax.named_scope("sparse_mask"):
                     if given is None:
-                        mask = _prompt_mask(c, positions, qi, wi, ik)
+                        mask = prompt_mask(c, positions, qi, wi, ik)
                     else:
-                        mask = _causal_ok(positions, positions) & _unpacked(given, T)
+                        mask = causal_ok(positions, positions) & unpacked(given, T)
                 if interpret or jax.default_backend() == "tpu":
                     from ..ops.pallas.masked_attention import masked_attention
 
@@ -382,7 +376,7 @@ def _whole_rows(c: KeyeConfig, positions, tell, interpret: bool = False):
                                                       interpret=interpret) for b in range(B)])
                 else:
                     out = causal_attention(q, k, v, keep=mask)
-            return out, _packed(mask) if tell else None, 0
+            return out, packed(mask) if tell else None, 0
 
         return attend
 
@@ -401,11 +395,11 @@ def forward(params: dict, tokens: jax.Array, config: KeyeConfig, positions3: jax
     c = config
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T)) if positions3 is None else positions3[:, 0]
-    x, _rows, _counts, told = _run_layers(params, c, _embed(params, tokens, c), positions, jnp.ones((B, T), bool),
+    x, _rows, _counts, told = _run_layers(params, c, embed(params, tokens, c), positions, jnp.ones((B, T), bool),
                                           _whole_rows(c, positions, tell, interpret), route, select, positions3, tell)
     if rows is not None:
         x = x[jnp.arange(B)[:, None], rows]
-    logits = _head_logits(_final_norm(x, params, c), params, c)
+    logits = head_logits(final_norm(x, params, c), params, c)
     return (logits, told) if tell else logits
 
 
@@ -445,46 +439,15 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, config: KeyeCo
     layer chose (``forward``'s)."""
     c = config
     B, T = tokens.shape
-    positions, valid = _rows(lengths, jnp.zeros((B,), jnp.int32), T)
-    x, rows, counts, told = _run_layers(params, c, _embed(params, tokens, c), positions, valid,
+    positions, valid = row_positions(lengths, jnp.zeros((B,), jnp.int32), T)
+    x, rows, counts, told = _run_layers(params, c, embed(params, tokens, c), positions, valid,
                                         _whole_rows(c, positions, tell, interpret), route, select, tell=tell)
     with scopes.layer("commit"):
         pool = commit_whole_pages(pool_leaves(cache), rows, page_ids)
         # a whole prompt's block of queries scores its causal keys' block columns: counted as the pairs it needs
         cache = _committed(cache, pool, counts, c, 1, jnp.sum(lengths * (lengths + 1) // 2), positions + 1)
-    logits = _head_logits(_final_norm(x, params, c), params, c, last=lengths)
+    logits = head_logits(final_norm(x, params, c), params, c, last=lengths)
     return (cache, logits, told) if tell else (cache, logits)
-
-
-def _masked_attention(q, k, v, mask):
-    """q [B, Tq, H, d] over keys [B, C, H_kv, d] and values [B, C, H_kv, dv]
-    (``dv`` need not be ``d``: ``models/dots.py``'s expanded latent rows)
-    under ``mask`` [B, Tq, C]: dense where the keys are few, else
-    ``KEY_BLOCK`` keys folded at a time into an online softmax (the scores
-    of 512 rows against 51k keys would be 3.3 GB at once)."""
-    B, Tq, H, d = q.shape
-    C = k.shape[1]
-    n_rep = H // k.shape[2]
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    if C <= KEY_BLOCK or C % KEY_BLOCK:
-        logits = jnp.einsum("bthd,bchd->bhtc", q, repeat_kv(k, n_rep)).astype(jnp.float32) * scale
-        probs = jax.nn.softmax(jnp.where(mask[:, None], logits, NEG_INF), axis=-1).astype(q.dtype)
-        return jnp.einsum("bhtc,bchd->bthd", probs, repeat_kv(v, n_rep))
-    nb = C // KEY_BLOCK
-    blocks = (jnp.moveaxis(k.reshape(B, nb, KEY_BLOCK, *k.shape[2:]), 1, 0),
-              jnp.moveaxis(v.reshape(B, nb, KEY_BLOCK, *v.shape[2:]), 1, 0),
-              jnp.moveaxis(mask.reshape(B, Tq, nb, KEY_BLOCK), 2, 0))
-    qf = q.astype(jnp.float32)
-
-    def step(carry, blk):
-        kb, vb, seen = blk
-        return online_softmax_step(qf, repeat_kv(kb, n_rep).astype(jnp.float32), repeat_kv(vb, n_rep).astype(jnp.float32),
-                                   seen[:, None], *carry, scale), None
-
-    init = (jnp.full((B, H, Tq), -jnp.inf, jnp.float32), jnp.zeros((B, H, Tq), jnp.float32),
-            jnp.zeros((B, H, Tq, v.shape[-1]), jnp.float32))
-    (_m, l, acc), _ = jax.lax.scan(step, init, blocks)
-    return online_softmax_finalize(l, acc, q.dtype)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, c: KeyeConfig):
@@ -495,13 +458,11 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     together. Nothing is written here. -> (x normed, new rows, counts, ik
     rows scored, live rows a query)."""
     B, T = tokens.shape
-    positions, valid = _rows(lengths, starts, T)
+    positions, valid = row_positions(lengths, starts, T)
     pool = pool_leaves(cache)
     NP, P = pool["kv"].shape[1:3]
     M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
+    key_pos = key_positions(starts, positions, M * P)
 
     def make_attend(i, given):
         def attend(q, k, v, qi, wi, ik):
@@ -514,19 +475,19 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
             def block(blk):
                 q_b, qi_b, wi_b, pos_b = blk
-                mask = _chosen_mask(c, qi_b, wi_b, index_keys, pos_b, key_pos)
+                mask = chosen_mask(c, qi_b, wi_b, index_keys, pos_b, key_pos)
                 with jax.named_scope("prefill_attention"):
-                    return _masked_attention(q_b, keys, values, mask)
+                    return blocked_masked_attention(q_b, keys, values, mask)
 
             if T <= CONTINUE_BLOCK or T % CONTINUE_BLOCK:
                 return block((q, qi, wi, positions)), None, 0
-            out = jax.lax.map(block, tuple(_row_blocks(t, CONTINUE_BLOCK) for t in (q, qi, wi, positions)))
+            out = jax.lax.map(block, tuple(row_blocks(t, CONTINUE_BLOCK) for t in (q, qi, wi, positions)))
             return jnp.moveaxis(out, 0, 1).reshape(B, T, c.n_heads, c.head_dim), None, 0
 
         return attend
 
-    x, rows, counts, _ = _run_layers(params, c, _embed(params, tokens, c), positions, valid, make_attend)
-    return _final_norm(x, params, c), rows, counts, jnp.sum(lengths) * (M * P + T), positions + 1
+    x, rows, counts, _ = _run_layers(params, c, embed(params, tokens, c), positions, valid, make_attend)
+    return final_norm(x, params, c), rows, counts, jnp.sum(lengths) * (M * P + T), positions + 1
 
 
 def _continue_commit(cache, new, page_ids, c: KeyeConfig):
@@ -540,7 +501,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     """Continuation (a prefix hit's suffix, a later chunk of a long prompt, a
     resumed request's tail): -> (cache, last-token logits [B, V])."""
     x, *new = _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, config)
-    return _continue_commit(cache, new, page_ids, config), _head_logits(x, params, config, last=lengths)
+    return _continue_commit(cache, new, page_ids, config), head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, config: KeyeConfig):
@@ -578,14 +539,14 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
 
         return attend
 
-    x, rows, counts, told = _run_layers(params, c, _embed(params, tokens[:, None], c), seq_lens[:, None],
+    x, rows, counts, told = _run_layers(params, c, embed(params, tokens[:, None], c), seq_lens[:, None],
                                         active[:, None], make_attend, route, select, tell=tell)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         pool = commit_tokens(pool, {name: r[:, :, 0] for name, r in rows.items()}, target, seq_lens % P)
         live = jnp.where(active, seq_lens + 1, 0)
         cache = _committed(cache, pool, counts, c, 0, jnp.sum(active) * block_tables.shape[1] * P, live)
-    logits = _head_logits(_final_norm(x[:, 0], params, c), params, c)
+    logits = head_logits(final_norm(x[:, 0], params, c), params, c)
     return (cache, logits, told) if tell else (cache, logits)
 
 
@@ -620,7 +581,7 @@ def describe_counters(config: KeyeConfig, total) -> dict:
                 "rows_dense": int(r[cut + 3]) * n, "lanes_past_topk": int(r[cut + 4]), "lanes_tied": int(r[cut + 5])}
 
     return {
-        "moe": _describe_moe(c, [r[:cut] for r in total])["moe"],
+        "moe": describe_moe(c, [r[:cut] for r in total])["moe"],
         "sparse": {"topk": c.index_topk, "index_heads": c.index_heads, "index_values": c.index_head_dim,
                    "ik_row_bytes_stored": c.ik_stored * jnp.dtype(c.dtype).itemsize, "layers": c.n_layers,
                    "decode": sparse(total[0]), "prefill": sparse(total[1])},

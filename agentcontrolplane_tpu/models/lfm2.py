@@ -18,7 +18,7 @@ layers flattened to one axis, closed over and indexed by the grouped matmul
 itself. The expert layers run in one of two layouts (``_run_layers``):
 
 - the decode step, whose cost is the serving rate: a layer's kind is a fact
-  of the trace, never a value on the chip. ``segments`` reads
+  of the trace, never a value on the chip. ``stack.segments`` reads
   ``layer_types``: the longest stretch that repeats a period is a
   ``lax.scan`` over periods whose body holds one layer body a run of one
   kind (a run longer than one layer is an inner scan), what stands before
@@ -67,9 +67,6 @@ uses too.
 
 from __future__ import annotations
 
-import contextlib
-import functools
-import itertools
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -77,14 +74,15 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
-from ..ops.moe import COUNTS_HEAD, routed_experts
+from ..ops.attention import blocked_causal_attention, causal_attention
+from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
-from ..ops.paged import (
-    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, layer_tables,
-    paged_decode_attention_reference_cache_plus_new,
+from ..ops.paged import TRASH_PAGE, commit_tokens, commit_whole_pages, init_kv_pages
+from .experts import describe_moe as describe_counters  # noqa: F401  the seam's: ``Engine.stats()["moe"]``
+from .experts import routed_ff
+from .stack import (
+    attention_op, embed, final_norm, head_logits, kv_pool, layer_row, mm, page_walk, prefix_attention, rows_ctx, scan_layers,
 )
-from ..ops.rope import apply_rope
 
 PERIOD = ("attention", "conv", "conv", "conv")
 
@@ -176,90 +174,6 @@ def plan(c: Lfm2Config) -> dict:
     }
 
 
-def segments(kinds: tuple[str, ...]) -> list[tuple[int, tuple[tuple[str, int], ...]]]:
-    """``kinds`` in order as stretches ``(periods, runs)``: ``runs`` is one
-    period as runs of one kind ``(kind, layers)``. The stretch that covers
-    most layers by repeating a period at least twice (the shortest such
-    period, the earliest such stretch) is taken first, then what stands
-    before and after it in the same way; a layer that repeats nothing is a
-    stretch of one period of one layer."""
-    n = len(kinds)
-    best = None  # (layers covered, -period, -start) the larger the better
-    for span in range(1, n // 2 + 1):
-        for start in range(n - 2 * span + 1):
-            reps = 1
-            while kinds[start + reps * span:start + (reps + 1) * span] == kinds[start:start + span]:
-                reps += 1
-            if reps > 1 and (best is None or (reps * span, -span, -start) > best[0]):
-                best = ((reps * span, -span, -start), start, span, reps)
-    if best is None:
-        return [(1, ((kind, 1),)) for kind in kinds]
-    _, start, span, reps = best
-    runs = tuple((kind, len(list(group))) for kind, group in itertools.groupby(kinds[start:start + span]))
-    return segments(kinds[:start]) + [(reps, runs)] + segments(kinds[start + reps * span:])
-
-
-def _stack(parts: list):
-    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, axis=0), *parts)
-
-
-def _run(layer, kind: str, n: int, carry, index, row):
-    """``n`` layers of ``kind`` from place ``index`` and row ``row`` on: one
-    written out, more as a scan -> (carry, the layers' ``out`` stacked)."""
-    i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
-    if n == 1:
-        carry, out = layer[kind](carry, i32(index), i32(row))
-        return carry, jax.tree_util.tree_map(lambda a: a[None], out)
-    return jax.lax.scan(lambda carry, j: layer[kind](carry, i32(index + j), i32(row + j)), carry,
-                        jnp.arange(n, dtype=jnp.int32))
-
-
-def _stretch(layer, reps: int, runs, carry, at: int, rows: dict):
-    """``reps`` periods of ``runs`` (``segments``) from place ``at`` on,
-    ``rows[kind]`` layers of each kind before them: one period written out,
-    more as a scan over periods -> (carry, {kind: ``out`` stacked})."""
-    span = sum(n for _, n in runs)
-    each = {kind: sum(n for k, n in runs if k == kind) for kind, _ in runs}
-
-    def period(carry, p):
-        index, row = at + p * span, {kind: rows[kind] + p * each[kind] for kind in each}
-        got: dict[str, list] = {}
-        for kind, n in runs:
-            carry, out = _run(layer, kind, n, carry, index, row[kind])
-            got.setdefault(kind, []).append(out)
-            index, row[kind] = index + n, row[kind] + n
-        return carry, {kind: _stack(parts) for kind, parts in got.items()}
-
-    if reps == 1:
-        return period(carry, 0)
-    carry, out = jax.lax.scan(period, carry, jnp.arange(reps, dtype=jnp.int32))
-    return carry, jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), out)
-
-
-def scan_layers(kinds: tuple[str, ...], carry, layer):
-    """Run ``kinds`` in order as ``segments`` lays them out. ``layer(kind,
-    carry, index, row) -> (carry, out)`` is one layer: ``index`` () int32
-    its place in ``kinds`` and ``row`` its place among the layers of its
-    kind, a loop's counters or constants. Every loop body has one kind. A
-    kind's layer is traced and lowered ONCE a program however many loops and
-    written-out places run it (a ``jax.jit`` a kind: the places call one
-    function, which the compiler inlines; the published ``lfm2`` pattern has
-    four places for two kinds, and a place costs its expert FF's trace). ->
-    (carry, {kind: ``out`` stacked over the kind's layers in order}; a kind
-    without layers is not there)."""
-    outs: dict[str, list] = {}
-    at, rows = 0, dict.fromkeys(kinds, 0)
-    bodies = {kind: jax.jit(functools.partial(layer, kind)) for kind in rows}
-    for reps, runs in segments(tuple(kinds)):
-        carry, out = _stretch(bodies, reps, runs, carry, at, rows)
-        for kind, n in runs:
-            rows[kind] += reps * n
-            at += reps * n
-        for kind, part in out.items():
-            outs.setdefault(kind, []).append(part)
-    return carry, {kind: _stack(parts) for kind, parts in outs.items()}
-
-
 def init_params(config: Lfm2Config, key: jax.Array) -> dict:
     """Random init in the served layout: ``pro`` a tuple of whole layer
     dicts (the leading dense layers), and the expert layers' weights stacked
@@ -305,10 +219,6 @@ def init_params(config: Lfm2Config, key: jax.Array) -> dict:
     return params
 
 
-def _mm(x, w):
-    return x @ w.astype(x.dtype)
-
-
 def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
     """h [B, T, D] normed input; state_in [B, taps-1, D] (s before the
     row's first token). -> (Op output [B, T, D], state at each row's end
@@ -328,54 +238,13 @@ def _conv_op(h, layer, c: Lfm2Config, state_in, lengths, snap_rel):
         taps = layer["conv_w"].astype(jnp.float32)  # [D, taps]
         conv = sum(s_ext[:, j:j + T] * taps[:, j] for j in range(c.conv_taps))
         with jax.named_scope("conv_out_proj"):
-            out = _mm((c_ * conv).astype(h.dtype), layer["conv_out"])
+            out = mm((c_ * conv).astype(h.dtype), layer["conv_out"])
 
         def state_at(rel):  # the n values of s before token `rel` of the row
             idx = jnp.clip(rel, 0, T)[:, None] + jnp.arange(n)[None, :]
             return jnp.take_along_axis(s_ext, idx[:, :, None], axis=1).astype(h.dtype)
 
         return out, state_at(lengths), state_at(snap_rel)
-
-
-def _attention_op(h, layer, c, positions, attn_fn, yarn=None, walk="prefill_attention", rope=True):
-    """-> (Op output, k, v): k and v are the layer's new rows for the pool.
-    ``yarn`` (``ops.rope.apply_rope``'s) turns q and k by YaRN's frequencies
-    (``models/mellum.py``'s full layers). ``walk`` is the scope ``attn_fn``
-    runs under (a decode step's: ``page_walk``; None: it opens its own).
-    ``rope`` False leaves q and k unturned (``models/exaone.py``'s full
-    layers carry no position)."""
-    B, T, _ = h.shape
-    with jax.named_scope("attn_qkv"):
-        q = _mm(h, layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)
-        k = _mm(h, layer["wk"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-        v = _mm(h, layer["wv"]).reshape(B, T, c.n_kv_heads, c.head_dim)
-        q = rms_norm(q, layer["q_norm"], c.norm_eps)
-        k = rms_norm(k, layer["k_norm"], c.norm_eps)
-        if rope:
-            q = apply_rope(q, positions, c.rope_theta, yarn=yarn)
-            k = apply_rope(k, positions, c.rope_theta, yarn=yarn)
-    with jax.named_scope(walk) if walk else contextlib.nullcontext():
-        out = attn_fn(q, k, v)
-    with jax.named_scope("attn_out"):
-        return _mm(out.reshape(B, T, c.n_heads * c.head_dim), layer["wo"]), k, v
-
-
-def _experts(x, ff, stacks, layer_index, c: Lfm2Config, valid, chosen=None):
-    """The routed FF of expert layer ``layer_index`` (traced): ``ff`` holds
-    its router, ``stacks`` every expert layer's experts flattened to one
-    leading axis, which the grouped matmul indexes from ``layer_index *
-    held``: a slice of a stack handed to an opaque kernel would be copied
-    out first, every step. ``chosen`` [B, T, k] is a routing given and not
-    made (``route`` of the programs). -> (FF output [B, T, D], counters)."""
-    B, T, D = x.shape
-    y, counts = routed_experts(
-        x.reshape(B * T, D), ff["router"], *stacks, c.experts_per_token, held=c.held, score="sigmoid",
-        bias=ff["router_bias"] if c.use_expert_bias else None, renormalize=c.norm_topk_prob,
-        scale=c.routed_scaling_factor, valid=valid.reshape(B * T),
-        expert_base=layer_index * len(c.held),
-        chosen=None if chosen is None else chosen.reshape(B * T, c.experts_per_token),
-    )
-    return y.reshape(B, T, D), jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
 
 
 def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None, by_kind=False):
@@ -416,7 +285,7 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
             if kind == "conv":
                 op, *out = _conv_op(h, layer, c, conv_state[at], ctx["lengths"], ctx["snap_rel"])
             else:
-                op, *out = _attention_op(h, layer, c, ctx["positions"], make_attn(at),
+                op, *out = attention_op(h, layer, c, ctx["positions"], make_attn(at),
                                          walk="page_walk" if by_kind else "prefill_attention")
             return op, tuple(o.astype(dt) for o in out)
 
@@ -430,14 +299,13 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
         with scopes.layer("ffn"), jax.named_scope("ffn_dense"):
             x = x + op
             h = norm(x, layer["ln2"])
-            x = x + _mm(jax.nn.silu(_mm(h, layer["w1"])) * _mm(h, layer["w3"]), layer["w2"])
+            x = x + mm(jax.nn.silu(mm(h, layer["w1"])) * mm(h, layer["w3"]), layer["w2"])
 
     counts = jnp.zeros((1 + COUNTS_HEAD + len(c.held),), jnp.uint32)
     if pl_["body"]:
         ff = params["ff"]
         stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
         small = {name: ff[name] for name in ("ln2", "router", "router_bias")}
-        row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
         stack = {"attention": params["attn"], "conv": params["conv"]}
 
         def expert_layer(carry, op, mine, index, chosen):
@@ -445,16 +313,17 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
             x, counts = carry
             with scopes.layer("ffn"):
                 x = x + op
-                y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, ctx["valid"], chosen)
+                y, m = routed_ff(norm(x, mine["ln2"]), mine, stacks, index, c, ctx["valid"], chosen, score="sigmoid",
+                                 bias=c.use_expert_bias, scale=c.routed_scaling_factor)
                 return x + y, counts + m
 
         if by_kind:
             def layer(kind, carry, index, at):
                 with scopes.layer("mixer" if kind == "conv" else "attn"):
-                    weights = row(stack[kind], at)
+                    weights = layer_row(stack[kind], at)
                 op, out = operator(kind, carry[0], weights, at)
                 with scopes.layer("ffn"):
-                    mine, chosen = row(small, index), None if route is None else route[index]
+                    mine, chosen = layer_row(small, index), None if route is None else route[index]
                 return expert_layer(carry, op, mine, index, chosen), out
 
             (x, counts), outs = scan_layers(pl_["body"], (x, counts), layer)
@@ -465,7 +334,7 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
             def branch(kind):
                 def run(x, at):  # -> (Op, end, snap, k, v), zeros for the other kind's
                     with scopes.layer("mixer" if kind == "conv" else "attn"):
-                        weights = row(stack[kind], at[kind])
+                        weights = layer_row(stack[kind], at[kind])
                     op, out = operator(kind, x, weights, at[kind])
                     zero = jnp.zeros(kv_shape if kind == "conv" else (B, n, D), dt)
                     return (op, *out, zero, zero) if kind == "conv" else (op, zero, zero, *out)
@@ -497,26 +366,6 @@ def _run_layers(params, c: Lfm2Config, x, ctx, conv_state, make_attn, route=None
                 cat(vs, kv_shape, dt), counts)
 
 
-def _head_logits(x, params, c: Lfm2Config, last=None):
-    """The output head; ``last`` [B] (true lengths) picks each row's last real
-    token of ``x`` [B, T, D] first."""
-    with scopes.layer("head"):
-        if last is not None:
-            x = x[jnp.arange(x.shape[0]), last - 1]
-        head = params["embed"].T if c.tie_embeddings else params["lm_head"]
-        return (x.astype(c.dtype) @ head.astype(c.dtype)).astype(jnp.float32)
-
-
-def _final_norm(x, params, c):
-    with scopes.layer("head"):
-        return rms_norm(x, params["norm"], c.norm_eps)
-
-
-def _embed(params, tokens, c: Lfm2Config):
-    with scopes.layer("embed"):
-        return params["embed"][tokens].astype(c.dtype)
-
-
 def _zero_state(c: Lfm2Config, B: int) -> jax.Array:
     """Every conv layer's state before a sequence starts."""
     return jnp.zeros((c.n_conv, B, c.conv_taps - 1, c.dim), c.dtype)
@@ -529,9 +378,9 @@ def forward(params: dict, tokens: jax.Array, config: Lfm2Config) -> jax.Array:
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     ctx = {"positions": positions, "valid": jnp.ones((B, T), bool),
            "lengths": jnp.full((B,), T, jnp.int32), "snap_rel": jnp.zeros((B,), jnp.int32)}
-    x, *_ = _run_layers(params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
+    x, *_ = _run_layers(params, c, embed(params, tokens, c), ctx, _zero_state(c, B),
                         lambda a: lambda q, k, v: causal_attention(q, k, v, positions))
-    return _head_logits(_final_norm(x, params, c), params, c)
+    return head_logits(final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -553,10 +402,6 @@ def init_paged_cache(config: Lfm2Config, num_pages: int, page_size: int, quantiz
     return cache
 
 
-def _kv(cache: dict) -> dict:
-    return {k: v for k, v in cache.items() if k != "state"}
-
-
 def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, row):
     """The cache with its pages replaced and the rows' state written: a
     row's end state always, its snapshot where one fell inside the row."""
@@ -567,15 +412,6 @@ def _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, row):
         snap = st["snap"].at[:, slots].set(
             jnp.where(snap_ok[None, :, None, None], snaps.astype(old.dtype), old), mode="drop")
         return {**pages, "state": {"conv": conv, "snap": snap, "moe": st["moe"].at[row].add(counts)}}
-
-
-def _rows_ctx(lengths, starts, snap_at, T):
-    ar = jnp.arange(T)
-    valid = ar[None, :] < lengths[:, None]
-    positions = jnp.where(valid, starts[:, None] + ar[None, :], -1)
-    snap_rel = snap_at - starts
-    ctx = {"positions": positions, "valid": valid, "lengths": lengths, "snap_rel": snap_rel}
-    return ctx, (snap_rel >= 0) & (snap_rel <= lengths) & (lengths > 0)
 
 
 def _state_in(cache, slots, starts):
@@ -595,15 +431,15 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     slots, snap_at = lanes
     B, T = tokens.shape
     zero = jnp.zeros((B,), jnp.int32)
-    ctx, snap_ok = _rows_ctx(lengths, zero, snap_at, T)
+    ctx, snap_ok = rows_ctx(lengths, zero, snap_at, T)
     positions = ctx["positions"]
     x, ends, snaps, new_k, new_v, counts = _run_layers(
-        params, c, _embed(params, tokens, c), ctx, _zero_state(c, B),
+        params, c, embed(params, tokens, c), ctx, _zero_state(c, B),
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions), route)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
     cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, counts, 1)
-    x = _final_norm(x, params, c)
-    return cache, _head_logits(x, params, c, last=lengths)
+    x = final_norm(x, params, c)
+    return cache, head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -613,28 +449,12 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     snap_ok, counts)."""
     slots, snap_at = lanes
     B, T = tokens.shape
-    ctx, snap_ok = _rows_ctx(lengths, starts, snap_at, T)
+    ctx, snap_ok = rows_ctx(lengths, starts, snap_at, T)
     positions = ctx["positions"]
-    pool = _kv(cache)
-    NP, P = pool["k"].shape[1], pool["k"].shape[2]
-    M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
-
-    def make_attn(a):
-        def attn(q, k, v):
-            ids = layer_tables(block_tables, a, NP)
-            k_rows = gather_pages(pool, "k", ids, k.dtype, c.n_kv_heads).reshape(B, M * P, *k.shape[2:])
-            v_rows = gather_pages(pool, "v", ids, v.dtype, c.n_kv_heads).reshape(B, M * P, *v.shape[2:])
-            return continue_attention(q, jnp.concatenate([k_rows, k], axis=1),
-                                      jnp.concatenate([v_rows, v], axis=1), positions, key_pos)
-
-        return attn
-
+    make_attn = prefix_attention(kv_pool(cache), block_tables, starts, positions, c.n_kv_heads)
     x, ends, snaps, new_k, new_v, counts = _run_layers(
-        params, c, _embed(params, tokens, c), ctx, _state_in(cache, slots, starts), make_attn)
-    return _final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok, counts
+        params, c, embed(params, tokens, c), ctx, _state_in(cache, slots, starts), make_attn)
+    return final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok, counts
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -643,9 +463,9 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     prompt): -> (cache, last-token logits [B, V])."""
     x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
     cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
-    return cache, _head_logits(x, params, config, last=lengths)
+    return cache, head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -653,7 +473,7 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
     """The continuation's writes without the head (a mid chunk)."""
     _x, new_k, new_v, ends, snaps, snap_ok, counts = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
     return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, counts, 1)
 
 
@@ -664,41 +484,22 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     lane's state and pages are left as they were."""
     c = config
     S = tokens.shape[0]
-    pool = _kv(cache)
-    NP, P = pool["k"].shape[1:3]
-    # the walk takes the merged pool as it is; the XLA reference splits the
-    # heads on what it gathers
-    k_flat, v_flat = flat_pages(pool["k"]), flat_pages(pool["v"])
-    scales = (flat_pages(pool["ks"]), flat_pages(pool["vs"])) if "ks" in pool else (None, None)
+    pool = kv_pool(cache)
+    P = pool["k"].shape[2]
+    make_attn = page_walk(pool, block_tables, seq_lens, use_pallas)
     ctx = {"positions": seq_lens[:, None], "valid": active[:, None],
            "lengths": jnp.ones((S,), jnp.int32), "snap_rel": jnp.zeros((S,), jnp.int32)}
-
-    def make_attn(a):
-        def attn(q, k, v):
-            tables = layer_tables(block_tables, a, NP)
-            args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
-            if use_pallas:
-                from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
-
-                out = paged_decode_attention_cache_plus_new(*args)
-            else:
-                out = paged_decode_attention_reference_cache_plus_new(
-                    *args, k_scales=scales[0], v_scales=scales[1])
-            return out[:, None]
-
-        return attn
-
     st = cache["state"]
     x, ends, _snaps, new_k, new_v, counts = _run_layers(
-        params, c, _embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route, by_kind=True)
+        params, c, embed(params, tokens[:, None], c), ctx, st["conv"][:, :S], make_attn, route, by_kind=True)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         pages = commit_tokens(pool, {"k": new_k[:, :, 0], "v": new_v[:, :, 0]}, target, seq_lens % P)
         conv = st["conv"].at[:, :S].set(
             jnp.where(active[None, :, None, None], ends.astype(st["conv"].dtype), st["conv"][:, :S]))
         cache = {**pages, "state": {"conv": conv, "snap": st["snap"], "moe": st["moe"].at[0].add(counts)}}
-    x = _final_norm(x[:, 0], params, c)
-    return cache, _head_logits(x, params, c)
+    x = final_norm(x[:, 0], params, c)
+    return cache, head_logits(x, params, c)
 
 
 def install_state(cache: dict, slot, state: jax.Array) -> dict:
@@ -719,22 +520,3 @@ def saved_state(cache: dict, slot) -> jax.Array:
 def counters(cache: dict) -> jax.Array:
     """The expert layers' counters as the programs keep them (``moe``)."""
     return cache["state"]["moe"]
-
-
-def describe_counters(config: Lfm2Config, total) -> dict:
-    """``Engine.stats()["moe"]`` from the counters summed by the engine
-    (``total`` [2, 1 + COUNTS_HEAD + held], None before the first dispatch):
-    decode steps and prefills apart, expert layers run, (token, choice)
-    pairs routed (padding lanes route nowhere and are not counted), pairs
-    that landed on held experts, held experts read (an expert with a pair
-    in a layer), and the pairs each held expert took."""
-    held = len(config.held)
-    if total is None:
-        total = [[0] * (1 + COUNTS_HEAD + held)] * 2
-
-    def row(r):
-        return {"expert_layers": int(r[0]), "pairs_routed": int(r[1]), "pairs_held": int(r[2]),
-                "experts_read": int(r[3]), "tokens_per_held_expert": [int(n) for n in r[4:]]}
-
-    return {"moe": {"experts": config.n_experts, "held": held, "experts_per_token": config.experts_per_token,
-                    "decode": row(total[0]), "prefill": row(total[1])}}

@@ -363,7 +363,7 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
 
 
 
-def _embed(params: dict, tokens: jax.Array, c: LlamaConfig) -> jax.Array:
+def embed(params: dict, tokens: jax.Array, c: LlamaConfig) -> jax.Array:
     with scopes.layer("embed"):
         x = params["embed"][tokens].astype(c.dtype)
         if c.embed_scale:  # gemma normalizes embeddings by sqrt(dim)
@@ -371,16 +371,16 @@ def _embed(params: dict, tokens: jax.Array, c: LlamaConfig) -> jax.Array:
         return x
 
 
-def _final_norm_w(params: dict, c: LlamaConfig) -> jax.Array:
+def final_norm_w(params: dict, c: LlamaConfig) -> jax.Array:
     return params["norm"] + 1.0 if c.norm_plus_one else params["norm"]
 
 
 def _final_norm(x: jax.Array, params: dict, c: LlamaConfig) -> jax.Array:
     with scopes.layer("head"):
-        return rms_norm(x, _final_norm_w(params, c), c.norm_eps)
+        return rms_norm(x, final_norm_w(params, c), c.norm_eps)
 
 
-def _head_logits(x: jax.Array, params: dict, c: LlamaConfig, last: Optional[jax.Array] = None) -> jax.Array:
+def head_logits(x: jax.Array, params: dict, c: LlamaConfig, last: Optional[jax.Array] = None) -> jax.Array:
     """lm_head projection -> float32 logits; applies gemma-2's final logit
     soft-capping when configured (cap * tanh(logits / cap)). ``last`` [B]
     (true lengths) picks each row's last real token of ``x`` [B, T, D] first."""
@@ -395,7 +395,7 @@ def _head_logits(x: jax.Array, params: dict, c: LlamaConfig, last: Optional[jax.
         return logits
 
 
-def _attn_mlp(
+def attn_mlp(
     x: jax.Array,  # [B, T, D]
     layer: dict,  # one layer's params (unstacked)
     config: LlamaConfig,
@@ -522,7 +522,7 @@ def forward(
         attn = partial(causal_attention, softcap=c.attn_logit_softcap)
 
     def body(x, layer):
-        out, _, _ = _attn_mlp(
+        out, _, _ = attn_mlp(
             x,
             layer,
             c,
@@ -536,11 +536,11 @@ def forward(
         # isolates iterations; CSE prevention only matters for unrolled use)
         body = jax.checkpoint(body, prevent_cse=False)
 
-    x = _embed(params, tokens, c)
+    x = embed(params, tokens, c)
 
     x, _ = jax.lax.scan(body, x, params["layers"])
     x = _final_norm(x, params, c)
-    return _head_logits(x, params, c)
+    return head_logits(x, params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -640,11 +640,11 @@ def prefill_batch(
     T = tokens.shape[1]
     ar = jnp.arange(T)
     positions = jnp.where(ar[None, :] < lengths[:, None], ar[None, :], -1)  # [B,T]
-    x = _embed(params, tokens, c)  # [B, T, D]
+    x = embed(params, tokens, c)  # [B, T, D]
 
     def body(carry, layer):
         x = carry
-        out, k, v = _attn_mlp(
+        out, k, v = attn_mlp(
             x,
             layer,
             c,
@@ -665,7 +665,7 @@ def prefill_batch(
     )
     # (padded tail is garbage but never read: decode masks by seq_len)
     x = _final_norm(x, params, c)
-    logits = _head_logits(x, params, c, last=lengths)
+    logits = head_logits(x, params, c, last=lengths)
     return cache, logits
 
 
@@ -705,7 +705,7 @@ def _continue_forward(
     B, T = tokens.shape
     ar = jnp.arange(T)
     positions = jnp.where(ar[None, :] < lengths[:, None], starts[:, None] + ar[None, :], -1)
-    x = _embed(params, tokens, c)
+    x = embed(params, tokens, c)
     C = cache["k"].shape[2]
     # scatter indices for the suffix writes; clamped so bucket padding can
     # never write past the row (clamped garbage lands at C-1, which is
@@ -738,7 +738,7 @@ def _continue_forward(
             attn.new_kv = (k, v)
             return out
 
-        out, _, _ = _attn_mlp(x, layer, c, positions, attn)
+        out, _, _ = attn_mlp(x, layer, c, positions, attn)
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -769,7 +769,7 @@ def prefill_continue(
     makes multi-turn agent conversations cheap (each turn's prompt extends
     the previous one). Returns (cache, last-token logits [B, V])."""
     cache, x = _continue_forward(params, cache, tokens, lengths, starts, slots, config)
-    logits = _head_logits(x, params, config, last=lengths)
+    logits = head_logits(x, params, config, last=lengths)
     return cache, logits
 
 
@@ -816,7 +816,7 @@ def verify_continue(
     cache, x = _continue_forward(
         params, cache, tokens, lengths, starts, jnp.arange(B), config
     )
-    return cache, _head_logits(x, params, config)
+    return cache, head_logits(x, params, config)
 
 
 # ---------------------------------------------------------------------------
@@ -851,11 +851,11 @@ def prefill_paged_batch(
     T = tokens.shape[1]
     ar = jnp.arange(T)
     positions = jnp.where(ar[None, :] < lengths[:, None], ar[None, :], -1)
-    x = _embed(params, tokens, c)
+    x = embed(params, tokens, c)
 
     def body(carry, layer):
         x = carry
-        out, k, v = _attn_mlp(
+        out, k, v = attn_mlp(
             x, layer, c, positions,
             lambda q, k, v: blocked_causal_attention(
                 q, k, v, positions, softcap=c.attn_logit_softcap
@@ -869,7 +869,7 @@ def prefill_paged_batch(
     x, (new_k, new_v) = jax.lax.scan(body, x, params["layers"])
     pages = commit_whole_pages(pages, {"k": new_k, "v": new_v}, page_ids)
     x = _final_norm(x, params, c)
-    logits = _head_logits(x, params, c, last=lengths)
+    logits = head_logits(x, params, c, last=lengths)
     return pages, logits
 
 
@@ -908,7 +908,7 @@ def _paged_continue_forward(
     B, T = tokens.shape
     ar = jnp.arange(T)
     positions = jnp.where(ar[None, :] < lengths[:, None], starts[:, None] + ar[None, :], -1)
-    x = _embed(params, tokens, c)
+    x = embed(params, tokens, c)
     max_pages = block_tables.shape[1]
 
     NP, P = pages["k"].shape[1:3]
@@ -957,7 +957,7 @@ def _paged_continue_forward(
             attn.new_kv = (k, v)
             return out
 
-        out, _, _ = _attn_mlp(x, layer, c, positions, attn)
+        out, _, _ = attn_mlp(x, layer, c, positions, attn)
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -987,7 +987,7 @@ def prefill_paged_continue(
     )
     # one scatter commits the suffix blocks for every layer
     pages = commit_whole_pages(pages, {"k": new_k, "v": new_v}, page_ids)
-    logits = _head_logits(x, params, config, last=lengths)
+    logits = head_logits(x, params, config, last=lengths)
     return pages, logits
 
 
@@ -1036,7 +1036,7 @@ def verify_paged_continue(
     with scopes.layer("commit"):
         target, offset = token_write_targets(block_tables, starts, lengths, P, T)
         pages = commit_tokens(pages, {"k": new_k, "v": new_v}, target, offset)
-    return pages, _head_logits(x, params, config)
+    return pages, head_logits(x, params, config)
 
 
 def decode_step_paged(
@@ -1064,7 +1064,7 @@ def decode_step_paged(
     c = config
     S = tokens.shape[0]
     positions = seq_lens[:, None]
-    x = _embed(params, tokens[:, None], c)
+    x = embed(params, tokens[:, None], c)
     quantized = "ks" in pages
     NP, P = pages["k"].shape[1:3]
     k_flat, v_flat = flat_pages(pages["k"]), flat_pages(pages["v"])
@@ -1105,7 +1105,7 @@ def decode_step_paged(
             attn.new_kv = (k[:, 0], v[:, 0])
             return out[:, None]
 
-        out, _, _ = _attn_mlp(x, layer, c, positions, attn, walk="page_walk")
+        out, _, _ = attn_mlp(x, layer, c, positions, attn, walk="page_walk")
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -1118,7 +1118,7 @@ def decode_step_paged(
         target = jnp.where(active, target, TRASH_PAGE)
         pages = commit_tokens(pages, {"k": new_k, "v": new_v}, target, seq_lens % P)
     x = _final_norm(x[:, 0], params, c)
-    logits = _head_logits(x, params, c)
+    logits = head_logits(x, params, c)
     return pages, logits
 
 
@@ -1160,7 +1160,7 @@ def decode_step(
     c = config
     W = tokens.shape[0]
     positions = seq_lens[:, None]  # the new token's position, [W, 1]
-    x = _embed(params, tokens[:, None], c)  # [W, 1, D]
+    x = embed(params, tokens[:, None], c)  # [W, 1, D]
 
     def body(carry, scanned):
         x = carry
@@ -1177,7 +1177,7 @@ def decode_step(
             attn.new_kv = (k[:, 0], v[:, 0])
             return out[:, None]
 
-        out, _, _ = _attn_mlp(x, layer, c, positions, attn, walk="decode_attention")
+        out, _, _ = attn_mlp(x, layer, c, positions, attn, walk="decode_attention")
         return out, attn.new_kv
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -1195,5 +1195,5 @@ def decode_step(
         lambda arr, val: arr.at[:, slot_idx, write_rows].set(val),
     )
     x = _final_norm(x[:, 0], params, c)  # [S, D]
-    logits = _head_logits(x, params, c)
+    logits = head_logits(x, params, c)
     return cache, logits

@@ -63,22 +63,16 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
-from ..ops.moe import COUNTS_HEAD, routed_experts
+from ..ops.attention import blocked_causal_attention, causal_attention
+from ..ops.moe import COUNTS_HEAD
 from ..ops.norms import rms_norm
 from ..ops.paged import (
-    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages,
-    layer_tables, paged_decode_attention_reference_cache_plus_new, ring_newest, ring_positions, ring_size, ring_tables,
+    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, init_kv_pages, layer_tables,
+    paged_decode_attention_reference_cache_plus_new, ring_newest, ring_positions, ring_size, ring_tables,
 )
-from .lfm2 import _attention_op, _embed, _final_norm, _head_logits  # GQA with q/k norms, embedding and head: as lfm2's
-from .lfm2 import describe_counters as _describe_moe
-
-WINDOW_COUNTS = 4  # dispatches, rows read, rows with no window, lanes past the window
-# tokens the routed FF takes at a time: a long prefill's rows go through the
-# grouped matmul a chunk at a time, so that its sorted copies of the rows
-# (tokens x k of them, in and out) stay a chunk wide: at 8,192 tokens they
-# were 1.7 GB of a prefill's 2.6 GB of temporaries beside 12.9 GB resident
-MOE_CHUNK = 2048
+from .experts import describe_moe, routed_ff
+from .stack import attention_op, embed, final_norm, head_logits, key_positions, layer_row, over_pages, row_positions
+from .window import WINDOW_COUNTS, describe_window, slot_ring, window_counts
 
 
 def _pattern(span: int, periods: int) -> tuple[str, ...]:
@@ -201,31 +195,6 @@ def init_params(config: MellumConfig, key: jax.Array) -> dict:
     }
 
 
-def _experts(x, ff, stacks, layer_index, c: MellumConfig, valid, chosen=None):
-    """The routed FF of layer ``layer_index`` (traced): ``ff`` holds its
-    router, ``stacks`` every layer's experts flattened to one leading axis,
-    which the grouped matmul indexes from ``layer_index * held``. ``chosen``
-    [B, T, k] is a routing given and not made (``route`` of the programs).
-    -> (FF output [B, T, D], counters)."""
-    B, T, D = x.shape
-    k = c.experts_per_token
-
-    def routed(rows):
-        x, valid, chosen = rows
-        return routed_experts(x, ff["router"], *stacks, k, held=c.held, score="softmax",
-                              renormalize=c.norm_topk_prob, valid=valid, expert_base=layer_index * len(c.held),
-                              chosen=chosen)
-
-    rows = (x.reshape(B * T, D), valid.reshape(B * T), None if chosen is None else chosen.reshape(B * T, k))
-    if B * T > MOE_CHUNK and B * T % MOE_CHUNK == 0:
-        chunked = jax.tree_util.tree_map(lambda a: a.reshape((-1, MOE_CHUNK) + a.shape[1:]), rows)
-        y, counts = jax.lax.map(routed, chunked)
-        counts = jnp.sum(counts, axis=0, dtype=jnp.uint32)
-    else:
-        y, counts = routed(rows)
-    return y.reshape(B, T, D), jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
-
-
 def _run_layers(params, c: MellumConfig, x, positions, valid, make_attn, route=None, keep=lambda t: t,
                 walk="prefill_attention"):
     """The whole stack. ``make_attn(full, i)`` gives the attention function
@@ -246,17 +215,16 @@ def _run_layers(params, c: MellumConfig, x, positions, valid, make_attn, route=N
     ff = params["ff"]
     stacks = tuple(ff[name].reshape((-1,) + ff[name].shape[2:]) for name in ("w1", "w3", "w2"))
     small = {name: ff[name] for name in ("ln2", "router")}
-    row = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
 
     def layer(x, counts, stack, full: bool, i, index, chosen):
         with scopes.layer("attn"):
-            weights = row(stack, i)
-            op, k, v = _attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attn(full, i),
+            weights = layer_row(stack, i)
+            op, k, v = attention_op(norm(x, weights["ln1"]), weights, c, positions, make_attn(full, i),
                                      yarn=c.yarn if full else None, walk=walk)
             x = x + op
         with scopes.layer("ffn"):
-            mine = row(small, index)
-            y, m = _experts(norm(x, mine["ln2"]), mine, stacks, index, c, valid, chosen)
+            mine = layer_row(small, index)
+            y, m = routed_ff(norm(x, mine["ln2"]), mine, stacks, index, c, valid, chosen, chunk=True)
             return x + y, counts + m, k.astype(dt), v.astype(dt)
 
     def one_period(carry, scanned):
@@ -293,8 +261,8 @@ def forward(params: dict, tokens: jax.Array, config: MellumConfig) -> jax.Array:
     def make_attn(full, i):
         return lambda q, k, v: causal_attention(q, k, v, positions, window=0 if full else c.window)
 
-    x, *_ = _run_layers(params, c, _embed(params, tokens, c), positions, jnp.ones((B, T), bool), make_attn)
-    return _head_logits(_final_norm(x, params, c), params, c)
+    x, *_ = _run_layers(params, c, embed(params, tokens, c), positions, jnp.ones((B, T), bool), make_attn)
+    return head_logits(final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -323,34 +291,10 @@ def _pools(cache: dict) -> tuple[dict, dict]:
     return {"k": cache["k"], "v": cache["v"]}, {"k": cache["wk"], "v": cache["wv"]}
 
 
-def _ring(cache: dict, c: MellumConfig) -> tuple[int, int]:
-    """(pages of a ring, the slot whose ring nothing reads)."""
-    ring = ring_size(c.window, cache["wk"].shape[2])
-    return ring, cache["wk"].shape[1] // ring - 1
-
-
-def _window_counts(c: MellumConfig, positions, valid):
-    """What the window layers' attention covers over the queries at
-    ``positions`` [B, T] (``valid`` [B, T]), one layer's: rows read, rows
-    there would be with no window, and the rows (a decode step: the lanes)
-    whose query lies past the window."""
-    seen = jnp.where(valid, positions + 1, 0).astype(jnp.uint32)
-    return jnp.stack([
-        jnp.ones((), jnp.uint32), jnp.sum(jnp.minimum(seen, c.window)), jnp.sum(seen),
-        jnp.sum((jnp.max(seen, axis=1) > c.window).astype(jnp.uint32)),
-    ])
-
-
-def _committed(cache, full, win, counts, window_counts, row):
-    added = jnp.concatenate([counts, window_counts])
+def _committed(cache, full, win, counts, windowed, row):
+    added = jnp.concatenate([counts, windowed])
     return {"k": full["k"], "v": full["v"], "wk": win["k"], "wv": win["v"],
             "state": {"counts": cache["state"]["counts"].at[row].add(added)}}
-
-
-def _rows(lengths, starts, T):
-    ar = jnp.arange(T)
-    valid = ar[None, :] < lengths[:, None]
-    return jnp.where(valid, starts[:, None] + ar[None, :], -1), valid
 
 
 def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config: MellumConfig, route=None):
@@ -361,23 +305,23 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     slots, _snap_at = lanes
     B, T = tokens.shape
     zero = jnp.zeros((B,), jnp.int32)
-    positions, valid = _rows(lengths, zero, T)
+    positions, valid = row_positions(lengths, zero, T)
 
     def make_attn(full, i):
         return lambda q, k, v: blocked_causal_attention(q, k, v, positions, window=0 if full else c.window)
 
     full, win = _pools(cache)
-    ring, pad = _ring(cache, c)
+    ring, pad = slot_ring(cache["wk"], c.window)
     keep, ring_ids = ring_newest(slots, zero, lengths, T, win["k"].shape[2], ring, pad)
     x, wk, wv, fk, fv, counts = _run_layers(
-        params, c, _embed(params, tokens, c), positions, valid, make_attn, route, keep)
+        params, c, embed(params, tokens, c), positions, valid, make_attn, route, keep)
     with scopes.layer("commit"):
         full = commit_whole_pages(full, {"k": fk, "v": fv}, page_ids)
         with jax.named_scope("window_commit"):
             win = commit_whole_pages(win, {"k": wk, "v": wv}, ring_ids)
-        cache = _committed(cache, full, win, counts, _window_counts(c, positions, valid), 1)
-    x = _final_norm(x, params, c)
-    return cache, _head_logits(x, params, c, last=lengths)
+        cache = _committed(cache, full, win, counts, window_counts(c.window, positions, valid), 1)
+    x = final_norm(x, params, c)
+    return cache, head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -389,15 +333,12 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     to, new full k, v, counts, window counts)."""
     slots, _snap_at = lanes
     B, T = tokens.shape
-    positions, valid = _rows(lengths, starts, T)
+    positions, valid = row_positions(lengths, starts, T)
     full, win = _pools(cache)
-    NP, P = full["k"].shape[1:3]
-    NW = win["k"].shape[1]
-    ring, pad = _ring(cache, c)
+    P = full["k"].shape[2]
+    ring, pad = slot_ring(cache["wk"], c.window)
     M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    full_pos = jnp.concatenate([cache_pos, positions], axis=1)
+    full_pos = key_positions(starts, positions, M * P)
     # the ring's rows hold the newest `ring` pages before `starts`; what lies
     # before a query's window is masked by `window`, as among the new rows
     ring_pos = ring_positions(starts, ring, P)
@@ -406,37 +347,30 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     rings = ring_tables(jnp.minimum(slots, pad), ring)
 
     def make_attn(is_full, i):
-        pool, ids, n_pages, key_pos, window = (
-            (full, block_tables, NP, full_pos, 0) if is_full else (win, rings, NW, win_pos, c.window))
+        pool, ids, key_pos, window = (full, block_tables, full_pos, 0) if is_full else (win, rings, win_pos, c.window)
 
         def attn(q, k, v):
-            scope = "full_gather" if is_full else "window_walk"
-            with jax.named_scope(scope):
-                at = layer_tables(ids, i, n_pages)
-                k_rows = gather_pages(pool, "k", at, k.dtype, c.n_kv_heads).reshape(B, -1, *k.shape[2:])
-                v_rows = gather_pages(pool, "v", at, v.dtype, c.n_kv_heads).reshape(B, -1, *v.shape[2:])
-                return continue_attention(q, jnp.concatenate([k_rows, k], axis=1),
-                                          jnp.concatenate([v_rows, v], axis=1), positions, key_pos,
-                                          window=window)
+            with jax.named_scope("full_gather" if is_full else "window_walk"):
+                return over_pages(q, k, v, pool, ids, i, c.n_kv_heads, positions, key_pos, window=window)
 
         return attn
 
     keep, ring_ids = ring_newest(jnp.minimum(slots, pad), starts, lengths, T, P, ring, pad)
     x, wk, wv, fk, fv, counts = _run_layers(
-        params, c, _embed(params, tokens, c), positions, valid, make_attn, keep=keep)
-    x = _final_norm(x, params, c)
+        params, c, embed(params, tokens, c), positions, valid, make_attn, keep=keep)
+    x = final_norm(x, params, c)
     with scopes.layer("commit"):
-        return x, wk, wv, ring_ids, fk, fv, counts, _window_counts(c, positions, valid)
+        return x, wk, wv, ring_ids, fk, fv, counts, window_counts(c.window, positions, valid)
 
 
 def _continue_commit(cache, new, page_ids):
-    wk, wv, ring_ids, fk, fv, counts, window_counts = new
+    wk, wv, ring_ids, fk, fv, counts, windowed = new
     full, win = _pools(cache)
     with scopes.layer("commit"):
         full = commit_whole_pages(full, {"k": fk, "v": fv}, page_ids)
         with jax.named_scope("window_commit"):
             win = commit_whole_pages(win, {"k": wk, "v": wv}, ring_ids)
-        return _committed(cache, full, win, counts, window_counts, 1)
+        return _committed(cache, full, win, counts, windowed, 1)
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -445,7 +379,7 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     tail): -> (cache, last-token logits [B, V])."""
     x, *new = _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, config)
     cache = _continue_commit(cache, new, page_ids)
-    return cache, _head_logits(x, params, config, last=lengths)
+    return cache, head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -466,7 +400,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     full, win = _pools(cache)
     NP, P = full["k"].shape[1:3]
     NW = win["k"].shape[1]
-    ring, pad = _ring(cache, c)
+    ring, pad = slot_ring(cache["wk"], c.window)
     k_flat, v_flat = flat_pages(full["k"]), flat_pages(full["v"])
     wk_flat, wv_flat = flat_pages(win["k"]), flat_pages(win["v"])
     positions = seq_lens[:, None]
@@ -497,16 +431,16 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
         return attn
 
     x, wk, wv, fk, fv, counts = _run_layers(
-        params, c, _embed(params, tokens[:, None], c), positions, active[:, None], make_attn, route, walk=None)
+        params, c, embed(params, tokens[:, None], c), positions, active[:, None], make_attn, route, walk=None)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         full = commit_tokens(full, {"k": fk[:, :, 0], "v": fv[:, :, 0]}, target, seq_lens % P)
         with jax.named_scope("window_commit"):
             at = jnp.where(active, jnp.arange(S), pad) * ring + jnp.mod(seq_lens // P, ring)
             win = commit_tokens(win, {"k": wk[:, :, 0], "v": wv[:, :, 0]}, at, seq_lens % P)
-        cache = _committed(cache, full, win, counts, _window_counts(c, positions, active[:, None]), 0)
-    x = _final_norm(x[:, 0], params, c)
-    return cache, _head_logits(x, params, c)
+        cache = _committed(cache, full, win, counts, window_counts(c.window, positions, active[:, None]), 0)
+    x = final_norm(x[:, 0], params, c)
+    return cache, head_logits(x, params, c)
 
 
 def install_state(cache: dict, slot, state) -> dict:
@@ -527,25 +461,12 @@ def counters(cache: dict) -> jax.Array:
 
 
 def describe_counters(config: MellumConfig, total) -> dict:
-    """``Engine.stats()``'s ``"moe"`` (the keys ``lfm2`` gives) and
-    ``"window"`` from the counters summed by the engine (``total`` [2, 1 +
-    COUNTS_HEAD + held + WINDOW_COUNTS], None before the first dispatch),
-    decode steps and prefills apart. ``window``: ``steps`` dispatches,
-    ``rows_read`` the rows one window layer's attention covered over their
-    queries (the query's own among them), ``rows_unwindowed`` what it would
-    have covered with no window, ``slots_past_window`` the lanes (rows of a
-    prefill) whose last query lay past the window."""
+    """``Engine.stats()``'s ``"moe"`` (``experts.describe_moe``) and
+    ``"window"`` (``window.describe_window``) from the counters summed by the
+    engine (``total`` [2, 1 + COUNTS_HEAD + held + WINDOW_COUNTS], None before
+    the first dispatch)."""
     c = config
     cut = 1 + COUNTS_HEAD + len(c.held)
     if total is None:
         total = [[0] * (cut + WINDOW_COUNTS)] * 2
-
-    def window(r):
-        return {"steps": int(r[cut]), "rows_read": int(r[cut + 1]), "rows_unwindowed": int(r[cut + 2]),
-                "slots_past_window": int(r[cut + 3])}
-
-    return {
-        **_describe_moe(c, [r[:cut] for r in total]),
-        "window": {"window": c.window, "window_layers": c.n_window, "full_layers": c.n_full,
-                   "decode": window(total[0]), "prefill": window(total[1])},
-    }
+    return {**describe_moe(c, [r[:cut] for r in total]), **describe_window(total, cut, c.window, c.n_window, c.n_full)}

@@ -94,22 +94,21 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import scopes
-from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention
+from ..ops.attention import blocked_causal_attention, causal_attention, continue_attention_by_rows
 from ..ops.moe import COUNTS_HEAD, routed_experts
 from ..ops.norms import rms_norm
-from ..ops.paged import (
-    TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, layer_tables,
-    paged_decode_attention_reference_cache_plus_new,
-)
+from ..ops.paged import TRASH_PAGE, commit_tokens, commit_whole_pages, init_kv_pages
 from ..ops.pallas import ssd
-from .jamba import _attention_op, _commit_state, _conv_at, _mm, _row
-# the seam's `install_state`, `saved_state` and `counters` (`models/__init__.py` `_with_state`): the tree {"ssm",
-# "conv"} under the cache's "state", whatever the leaves' ranks, is handed over as `jamba`'s is
-from .jamba import counters, install_state, saved_state  # noqa: F401
-from .lfm2 import _embed, _final_norm, _head_logits, _kv, _rows_ctx, scan_layers  # the same for every family with state beside the pages
+from .experts import describe_moe
+from .recurrent import (  # noqa: F401  the seam's three among them
+    commit_state, conv_at, counters, install_state, saved_state, state_in,
+)
+from .stack import (
+    embed, final_norm, head_logits, kv_pool, layer_row, mm_weight_dtype, page_walk, plain_attention_op, prefix_attention,
+    rows_ctx, scan_layers,
+)
 
 N_SSM = 4  # mamba_layers, rows, tokens, chunks
-CONTINUE_BLOCK = 512  # query rows a continuation attends at a time
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}  # a character of `hybrid_override_pattern` -> the kind of its block
 STACK = {"mamba": "mamba", "attention": "attn", "moe": "moe"}  # a kind -> its stack of weights in the tree
 SCOPE = {"mamba": "mixer", "attention": "attn", "moe": "ffn"}  # a kind -> the device scope its block is filed under
@@ -274,7 +273,7 @@ def _mamba_pre(h, w, c: NemotronHConfig, conv_in, valid):
     di, cd, gn = c.d_inner, c.conv_channels, c.n_groups * c.d_state
     B, T, _ = h.shape
     with jax.named_scope("mamba_in_proj"):
-        zxbcdt = _mm(h, w["in_proj"], f32)
+        zxbcdt = mm_weight_dtype(h, w["in_proj"], f32)
         # xBC in the model's dtype, the one the state keeps its columns in, so that a decode step that
         # reads three back convolves what the prefill convolved
         z, xbc, dt = zxbcdt[..., :di], zxbcdt[..., di:di + cd].astype(h.dtype), zxbcdt[..., di + cd:]
@@ -298,7 +297,7 @@ def _mamba_post(y, x, z, w, c: NemotronHConfig, dtype):
 
 def _mamba_out(y, w):
     with jax.named_scope("mamba_out_proj"):
-        return _mm(y, w["out_proj"])
+        return mm_weight_dtype(y, w["out_proj"])
 
 
 def _latent_moe(h, w, stacks, layer_index, c: NemotronHConfig, valid, chosen=None):
@@ -311,15 +310,15 @@ def _latent_moe(h, w, stacks, layer_index, c: NemotronHConfig, valid, chosen=Non
     k = c.experts_per_token
     x = h.reshape(B * T, D)
     with jax.named_scope("latent_down"):
-        u = _mm(x, w["down"])
+        u = mm_weight_dtype(x, w["down"])
     y, counts = routed_experts(
         x, w["router"], stacks[0], None, stacks[1], k, held=c.held, score="sigmoid", bias=w["router_bias"],
         renormalize=c.norm_topk_prob, scale=c.routed_scaling_factor, valid=valid.reshape(B * T), act=relu2,
         expert_base=layer_index * len(c.held), chosen=None if chosen is None else chosen.reshape(B * T, k), u=u)
     with jax.named_scope("latent_up"):
-        y = _mm(y, w["up"])
+        y = mm_weight_dtype(y, w["up"])
     with jax.named_scope("moe_shared"):
-        y = y + _mm(relu2(_mm(x, w["sw1"])), w["sw2"])
+        y = y + mm_weight_dtype(relu2(mm_weight_dtype(x, w["sw1"])), w["sw2"])
     return y.reshape(B, T, D), jnp.concatenate([jnp.ones((1,), jnp.uint32), counts])
 
 
@@ -350,10 +349,10 @@ def _run_rows(params, c: NemotronHConfig, x, ctx, ssm_in, conv_in, make_attn, ro
     def layer(kind, carry, index, at):
         x, counts = carry
         with scopes.layer(SCOPE[kind]):
-            w = _row(params[STACK[kind]], at)
+            w = layer_row(params[STACK[kind]], at)
             h = rms_norm(x, w["ln"], c.norm_eps)
             if kind == "attention":
-                op, k, v = _attention_op(h, w, c, make_attn(at))
+                op, k, v = plain_attention_op(h, w, c, make_attn(at))
                 out = (k.astype(dt), v.astype(dt))
             elif kind == "mamba":
                 xs, b, c_, z, delta, ext = _mamba_pre(h, w, c, conv_in[at].reshape(B, n, cd), ctx["valid"])
@@ -362,7 +361,7 @@ def _run_rows(params, c: NemotronHConfig, x, ctx, ssm_in, conv_in, make_attn, ro
                     y, h_end, h_snap = ssd.scan(delta, xs, b, c_, a, ssm_in[at], ctx["snap_rel"], n_chunks)
                 op = _mamba_post(y, xs, z, w, c, dt)
                 with jax.named_scope("mamba_conv"):
-                    out = (h_end, h_snap, _conv_at(ext, ctx["lengths"], n), _conv_at(ext, ctx["snap_rel"], n))
+                    out = (h_end, h_snap, conv_at(ext, ctx["lengths"], n), conv_at(ext, ctx["snap_rel"], n))
             else:
                 op, m = _latent_moe(h, w, stacks, at, c, ctx["valid"], None if route is None else route[at])
                 counts, out = counts + m, ()
@@ -385,9 +384,9 @@ def forward(params: dict, tokens: jax.Array, config: NemotronHConfig) -> jax.Arr
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     ctx = {"positions": positions, "valid": jnp.ones((B, T), bool),
            "lengths": jnp.full((B,), T, jnp.int32), "snap_rel": jnp.full((B,), -1, jnp.int32)}
-    x, *_ = _run_rows(params, c, _embed(params, tokens, c), ctx, *_zero_state(c, B),
+    x, *_ = _run_rows(params, c, embed(params, tokens, c), ctx, *_zero_state(c, B),
                       lambda a: lambda q, k, v: causal_attention(q, k, v, positions))
-    return _head_logits(_final_norm(x, params, c), params, c)
+    return head_logits(final_norm(x, params, c), params, c)
 
 
 # ---------------------------------------------------------------------------
@@ -416,16 +415,6 @@ def _counts(c: NemotronHConfig, rows, tokens, chunks, moe):
     return jnp.concatenate([ssm_part, moe])
 
 
-def _state_in(cache, slots, starts):
-    """Zeros for a row that starts the sequence, the slot's state otherwise."""
-    st = cache["state"]
-    with scopes.layer("commit"):
-        slots = jnp.clip(slots, 0, st["ssm"].shape[1] - 1)
-        began = starts > 0
-        return (jnp.where(began[None, :, None, None, None], st["ssm"][:, slots], 0),
-                jnp.where(began[None, :, None], st["conv"][:, slots], 0))
-
-
 def _prefill_counts(c, lengths, moe):
     with scopes.layer("commit"):
         return _counts(c, lengths > 0, lengths, -(-lengths // ssd.CHUNK), moe)
@@ -438,15 +427,15 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, lanes, config:
     c = config
     slots, snap_at = lanes
     B, T = tokens.shape
-    ctx, snap_ok = _rows_ctx(lengths, jnp.zeros((B,), jnp.int32), snap_at, T)
+    ctx, snap_ok = rows_ctx(lengths, jnp.zeros((B,), jnp.int32), snap_at, T)
     positions = ctx["positions"]
     x, ends, snaps, new_k, new_v, moe = _run_rows(
-        params, c, _embed(params, tokens, c), ctx, *_zero_state(c, B),
+        params, c, embed(params, tokens, c), ctx, *_zero_state(c, B),
         lambda a: lambda q, k, v: blocked_causal_attention(q, k, v, positions), route)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
-    cache = _commit_state(cache, pages, slots, ends, snaps, snap_ok, _prefill_counts(c, lengths, moe))
-    x = _final_norm(x, params, c)
-    return cache, _head_logits(x, params, c, last=lengths)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
+    cache = commit_state(cache, pages, slots, ends, snaps, snap_ok, _prefill_counts(c, lengths, moe))
+    x = final_norm(x, params, c)
+    return cache, head_logits(x, params, c, last=lengths)
 
 
 def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables, lanes, c):
@@ -456,36 +445,14 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
     snap_ok, expert counters)."""
     slots, snap_at = lanes
     B, T = tokens.shape
-    ctx, snap_ok = _rows_ctx(lengths, starts, snap_at, T)
+    ctx, snap_ok = rows_ctx(lengths, starts, snap_at, T)
     positions = ctx["positions"]
-    pool = _kv(cache)
-    NP, P = pool["k"].shape[1], pool["k"].shape[2]
-    M = block_tables.shape[1]
-    row_pos = jnp.arange(M * P)
-    cache_pos = jnp.where(row_pos[None, :] < starts[:, None], row_pos[None, :], -1)
-    key_pos = jnp.concatenate([cache_pos, positions], axis=1)
-
-    def make_attn(a):
-        def attn(q, k, v):
-            ids = layer_tables(block_tables, a, NP)
-            k_rows = gather_pages(pool, "k", ids, k.dtype, c.n_kv_heads).reshape(B, M * P, *k.shape[2:])
-            v_rows = gather_pages(pool, "v", ids, v.dtype, c.n_kv_heads).reshape(B, M * P, *v.shape[2:])
-            keys, values = jnp.concatenate([k_rows, k], axis=1), jnp.concatenate([v_rows, v], axis=1)
-            if T <= CONTINUE_BLOCK or T % CONTINUE_BLOCK:
-                return continue_attention(q, keys, values, positions, key_pos)
-            # dense over the keys, a block of query rows at a time (models/kanana.py): the scores of
-            # 2,048 rows of 32 heads against 6,144 keys are 1.6 GB at once, beside 11.5 GB resident
-            split = lambda t: jnp.moveaxis(  # noqa: E731
-                t.reshape((B, T // CONTINUE_BLOCK, CONTINUE_BLOCK) + t.shape[2:]), 1, 0)
-            out = jax.lax.map(lambda blk: continue_attention(blk[0], keys, values, blk[1], key_pos),
-                              (split(q), split(positions)))
-            return jnp.moveaxis(out, 0, 1).reshape(q.shape)
-
-        return attn
-
+    # dense over the keys, a block of query rows at a time: the scores of 2,048 rows of 32 heads against 6,144 keys
+    # are 1.6 GB at once, beside 11.5 GB resident
+    make_attn = prefix_attention(kv_pool(cache), block_tables, starts, positions, c.n_kv_heads, continue_attention_by_rows)
     x, ends, snaps, new_k, new_v, moe = _run_rows(
-        params, c, _embed(params, tokens, c), ctx, *_state_in(cache, slots, starts), make_attn)
-    return _final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok, moe
+        params, c, embed(params, tokens, c), ctx, *state_in(cache, slots, starts), make_attn)
+    return final_norm(x, params, c), new_k, new_v, ends, snaps, snap_ok, moe
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -494,9 +461,9 @@ def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, blo
     prompt): -> (cache, last-token logits [B, V])."""
     x, new_k, new_v, ends, snaps, snap_ok, moe = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
-    cache = _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths, moe))
-    return cache, _head_logits(x, params, config, last=lengths)
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
+    cache = commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths, moe))
+    return cache, head_logits(x, params, config, last=lengths)
 
 
 def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, block_tables, lanes,
@@ -504,8 +471,8 @@ def prefill_paged_continue_kv(params, cache, tokens, lengths, starts, page_ids, 
     """The continuation's writes without the head (a mid chunk)."""
     _x, new_k, new_v, ends, snaps, snap_ok, moe = _paged_continue_forward(
         params, cache, tokens, lengths, starts, block_tables, lanes, config)
-    pages = commit_whole_pages(_kv(cache), {"k": new_k, "v": new_v}, page_ids)
-    return _commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths, moe))
+    pages = commit_whole_pages(kv_pool(cache), {"k": new_k, "v": new_v}, page_ids)
+    return commit_state(cache, pages, lanes[0], ends, snaps, snap_ok, _prefill_counts(config, lengths, moe))
 
 
 def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, config: NemotronHConfig,
@@ -521,35 +488,21 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     1, k]: the experts' choice given (an output check's)."""
     c = config
     S = tokens.shape[0]
-    pool = _kv(cache)
-    NP, P = pool["k"].shape[1:3]
-    k_flat, v_flat = flat_pages(pool["k"]), flat_pages(pool["v"])
-    scales = (flat_pages(pool["ks"]), flat_pages(pool["vs"])) if "ks" in pool else (None, None)
+    pool = kv_pool(cache)
+    P = pool["k"].shape[2]
+    make_attn = page_walk(pool, block_tables, seq_lens, use_pallas)
     dt = c.dtype
     n, cd = c.d_conv - 1, c.conv_channels
     stacks = _stacks(params)
 
-    def make_attn(a):
-        def attn(q, k, v):
-            tables = layer_tables(block_tables, a, NP)
-            args = (q[:, 0], k_flat, v_flat, tables, seq_lens, k[:, 0], v[:, 0])
-            if use_pallas:
-                from ..ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
-
-                return paged_decode_attention_cache_plus_new(*args)[:, None]
-            return paged_decode_attention_reference_cache_plus_new(
-                *args, k_scales=scales[0], v_scales=scales[1])[:, None]
-
-        return attn
-
     def layer(kind, carry, index, at):
         x, h_all, conv_all, counts = carry
         with scopes.layer(SCOPE[kind]):
-            w = _row(params[STACK[kind]], at)
+            w = layer_row(params[STACK[kind]], at)
             h = rms_norm(x, w["ln"], c.norm_eps)
             out = ()
             if kind == "attention":
-                op, k, v = _attention_op(h, w, c, make_attn(at), walk="page_walk")
+                op, k, v = plain_attention_op(h, w, c, make_attn(at), walk="page_walk")
                 out = (k[:, 0].astype(dt), v[:, 0].astype(dt))
             elif kind == "mamba":
                 with jax.named_scope("mamba_conv"):
@@ -572,14 +525,14 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
     st = cache["state"]
     plan(c)  # refuses a kind it does not know or a kind without layers
     (x, h_all, conv_all, moe), outs = scan_layers(
-        c.layer_types, (_embed(params, tokens[:, None], c), st["ssm"], st["conv"], _moe_counts(c)), layer)
+        c.layer_types, (embed(params, tokens[:, None], c), st["ssm"], st["conv"], _moe_counts(c)), layer)
     with scopes.layer("commit"):
         target = jnp.where(active, block_tables[jnp.arange(S), seq_lens // P], TRASH_PAGE)
         pages = commit_tokens(pool, dict(zip(("k", "v"), outs["attention"])), target, seq_lens % P)
         counts = _counts(c, active, active, active, moe)
         state = {"ssm": h_all, "conv": conv_all, "snap": st["snap"], "counters": st["counters"].at[0].add(counts)}
-    x = _final_norm(x[:, 0], params, c)
-    return {**pages, "state": state}, _head_logits(x, params, c)
+    x = final_norm(x[:, 0], params, c)
+    return {**pages, "state": state}, head_logits(x, params, c)
 
 
 def describe_counters(config: NemotronHConfig, total) -> dict:
@@ -590,7 +543,7 @@ def describe_counters(config: NemotronHConfig, total) -> dict:
     the scan, each summed over the Mamba layers, and the bytes of state a
     slot holds. ``moe``: expert layers run, (token, choice) pairs routed,
     pairs that landed on held experts, held experts read, the pairs each
-    held expert took (``models/lfm2.py``'s group)."""
+    held expert took (``experts.describe_moe``)."""
     held = len(config.held)
     if total is None:
         total = [[0] * (N_SSM + 1 + COUNTS_HEAD + held)] * 2
@@ -598,14 +551,8 @@ def describe_counters(config: NemotronHConfig, total) -> dict:
     def ssm_row(r):
         return {"mamba_layers": int(r[0]), "rows": int(r[1]), "tokens": int(r[2]), "chunks": int(r[3])}
 
-    def moe_row(r):
-        r = r[N_SSM:]
-        return {"expert_layers": int(r[0]), "pairs_routed": int(r[1]), "pairs_held": int(r[2]),
-                "experts_read": int(r[3]), "tokens_per_held_expert": [int(n) for n in r[4:]]}
-
     return {
         "ssm": {"state_bytes_per_slot": config.state_bytes_per_slot, "decode": ssm_row(total[0]),
                 "prefill": ssm_row(total[1])},
-        "moe": {"experts": config.n_experts, "held": held, "experts_per_token": config.experts_per_token,
-                "decode": moe_row(total[0]), "prefill": moe_row(total[1])},
+        **describe_moe(config, [r[N_SSM:] for r in total]),
     }

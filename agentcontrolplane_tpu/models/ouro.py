@@ -14,7 +14,7 @@ the last norm is applied between the loops, not once before the head.
 ``Attn_l`` is plain multi-head attention (no bias, rotary over the whole
 head in the half-split form, causal softmax over ``sqrt(head_dim)``) over
 **this loop's own** keys and values; ``MLP_l`` a SwiGLU. The layer is
-``llama._attn_mlp`` with ``post_norms=True`` as it stands: this module
+``llama.attn_mlp`` with ``post_norms=True`` as it stands: this module
 brings the loops around it and nothing of a layer.
 
 **What is cached**: K and V of loop ``t``, layer ``l`` in cache layer ``t *
@@ -45,7 +45,7 @@ commit (a 256-token bucket at the published sizes is 0.4 GB a sequence), so
 
 One departure from the dense family's layout, changing no result: ``wq``
 and ``wk`` are kept **outputs first** (``[n_layers, H d, D]``, as the source
-stores every matrix) and handed to ``_attn_mlp`` transposed, which the
+stores every matrix) and handed to ``attn_mlp`` transposed, which the
 compiler folds into the product. Kept inputs first, the chip's compiler
 copied both stacks transposed before the first step of every decode block
 and at the head of every prefill: 0.8 GB of temporaries in each program at
@@ -75,8 +75,10 @@ from ..ops.paged import (
     TRASH_PAGE, commit_tokens, commit_whole_pages, flat_pages, gather_pages, init_kv_pages, layer_tables,
     paged_decode_attention_reference_cache_plus_new, pool_leaves,
 )
+# the one family this file imports (`tests/engine/test_model_seam.py`): `OuroConfig(LlamaConfig)` says so in its type,
+# and the layer IS the dense family's (`llama.attn_mlp` with `post_norms=True`), so an edit there is an edit here
 from . import llama
-from .llama import LlamaConfig, _attn_mlp, _embed, _final_norm_w, _head_logits
+from .llama import LlamaConfig, attn_mlp, embed, final_norm_w, head_logits
 
 OUT_FIRST = ("wq", "wk")  # projections kept outputs first, as the source stores every matrix (module text)
 COUNTS_HEAD = 3  # tokens, passes, cache rows; then a count a loop of the exits taken there
@@ -136,14 +138,14 @@ def exit_choice(gates: jax.Array, threshold: float) -> jax.Array:
 
 def _run_loops(params, c: OuroConfig, x, positions, make_attn, pick, scope: str = "prefill_attention"):
     """The stack ``c.loops`` times. ``make_attn(index)`` gives the attention
-    of cache layer ``index`` (traced: ``t * n_layers + l``) as ``_attn_mlp``
+    of cache layer ``index`` (traced: ``t * n_layers + l``) as ``attn_mlp``
     takes it, a function that leaves its new rows on ``.new_kv``, ``scope``
     the name that operator's ops go by; ``pick(h [B, T, D]) -> [B, D]`` takes
     the row whose logits are read.
     -> (every loop's state of that row [loops, B, D], its gates [loops, B]
     float32, new K and V [loops * n_layers, B, T, H_kv, d] each)."""
     L = c.n_layers
-    norm_w = _final_norm_w(params, c)
+    norm_w = final_norm_w(params, c)
     # the query and key projections are indexed where they are used and not
     # by the scan: the read of a layer's row (a sixth of a decode step's
     # weight bytes) so carries the scope of the product it is for, where the
@@ -157,7 +159,7 @@ def _run_loops(params, c: OuroConfig, x, positions, make_attn, pick, scope: str 
             with scopes.layer("attn"), jax.named_scope("attn_qkv"):
                 weights = {**weights, **{name: a[l].T for name, a in indexed.items()}}
             attn = make_attn(t * L + l)
-            x, _, _ = _attn_mlp(x, weights, c, positions, attn, walk=scope)
+            x, _, _ = attn_mlp(x, weights, c, positions, attn, walk=scope)
             return x, attn.new_kv
 
         x, new = jax.lax.scan(layer, x, (scanned_layers, jnp.arange(L, dtype=jnp.int32)))
@@ -182,7 +184,7 @@ def _exit_logits(params, c: OuroConfig, h, gates):
         with jax.named_scope("exit_select"):
             chosen = jnp.take_along_axis(h, e[None, :, None], axis=0)[0]
         with jax.named_scope("head_product"):
-            return _head_logits(chosen, params, c), e
+            return head_logits(chosen, params, c), e
 
 
 def forward(params: dict, tokens: jax.Array, config: OuroConfig) -> jax.Array:
@@ -199,7 +201,7 @@ def forward(params: dict, tokens: jax.Array, config: OuroConfig) -> jax.Array:
 
         return attn
 
-    h, gates, _, _ = _run_loops(params, c, _embed(params, tokens, c), positions, make_attn,
+    h, gates, _, _ = _run_loops(params, c, embed(params, tokens, c), positions, make_attn,
                                 lambda x: x.reshape(B * T, c.dim))
     return _exit_logits(params, c, h, gates)[0].reshape(B, T, -1)
 
@@ -264,7 +266,7 @@ def prefill_paged_batch(params, cache, tokens, lengths, page_ids, config: OuroCo
 
         return attn
 
-    h, gates, new_k, new_v = _run_loops(params, c, _embed(params, tokens, c), positions, make_attn,
+    h, gates, new_k, new_v = _run_loops(params, c, embed(params, tokens, c), positions, make_attn,
                                         _last_row(lengths))
     logits, e = _exit_logits(params, c, h, gates)
     return _commit_pages(cache, c, new_k, new_v, page_ids, lengths, e), logits
@@ -296,7 +298,7 @@ def _paged_continue_forward(params, cache, tokens, lengths, starts, block_tables
 
         return attn
 
-    return _run_loops(params, c, _embed(params, tokens, c), positions, make_attn, _last_row(lengths))
+    return _run_loops(params, c, embed(params, tokens, c), positions, make_attn, _last_row(lengths))
 
 
 def prefill_paged_continue(params, cache, tokens, lengths, starts, page_ids, block_tables, config: OuroConfig):
@@ -339,7 +341,7 @@ def decode_step_paged(params, cache, tokens, seq_lens, block_tables, active, con
 
         return attn
 
-    h, gates, new_k, new_v = _run_loops(params, c, _embed(params, tokens[:, None], c), seq_lens[:, None], make_attn,
+    h, gates, new_k, new_v = _run_loops(params, c, embed(params, tokens[:, None], c), seq_lens[:, None], make_attn,
                                         lambda x: x[:, 0], scope="page_walk")
     logits, e = _exit_logits(params, c, h, gates)
     with scopes.layer("commit"):

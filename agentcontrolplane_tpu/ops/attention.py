@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+CONTINUE_BLOCK = 512  # query rows a continuation attends at a time
+KEY_BLOCK = 2048  # keys a block of queries under a mask folds at a time
 
 
 def _softcap(logits: jax.Array, cap: float) -> jax.Array:
@@ -280,6 +282,53 @@ def continue_attention(
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhtc,bchd->bthd", probs, v)
+
+
+def continue_attention_by_rows(q, k_rows, v_rows, positions, key_positions, block: int | None = None):
+    """:func:`continue_attention`, ``block`` (``CONTINUE_BLOCK``) query rows
+    at a time where ``T`` is whole blocks and more than one: dense over the
+    keys, one ``lax.map`` over the blocks of rows, so that the scores held at
+    once are a block's (3,072 rows of 32 heads against 8,192 keys are 3.2 GB).
+    Keys and values may differ in width."""
+    B, T = positions.shape
+    R = block or CONTINUE_BLOCK
+    if T <= R or T % R:
+        return continue_attention(q, k_rows, v_rows, positions, key_positions)
+    split = lambda t: jnp.moveaxis(t.reshape((B, T // R, R) + t.shape[2:]), 1, 0)  # noqa: E731
+    out = jax.lax.map(lambda blk: continue_attention(blk[0], k_rows, v_rows, blk[1], key_positions),
+                      (split(q), split(positions)))
+    return jnp.moveaxis(out, 0, 1).reshape((B, T) + out.shape[3:])
+
+
+def blocked_masked_attention(q, k, v, mask):
+    """q [B, Tq, H, d] over keys [B, C, H_kv, d] and values [B, C, H_kv, dv]
+    (``dv`` need not be ``d``: latent rows expanded) under ``mask`` [B, Tq,
+    C]: dense where the keys are few, else ``KEY_BLOCK`` keys folded at a
+    time into an online softmax (the scores of 512 rows against 51k keys
+    would be 3.3 GB at once)."""
+    B, Tq, H, d = q.shape
+    C = k.shape[1]
+    n_rep = H // k.shape[2]
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    if C <= KEY_BLOCK or C % KEY_BLOCK:
+        logits = jnp.einsum("bthd,bchd->bhtc", q, repeat_kv(k, n_rep)).astype(jnp.float32) * scale
+        probs = jax.nn.softmax(jnp.where(mask[:, None], logits, NEG_INF), axis=-1).astype(q.dtype)
+        return jnp.einsum("bhtc,bchd->bthd", probs, repeat_kv(v, n_rep))
+    nb = C // KEY_BLOCK
+    blocks = (jnp.moveaxis(k.reshape(B, nb, KEY_BLOCK, *k.shape[2:]), 1, 0),
+              jnp.moveaxis(v.reshape(B, nb, KEY_BLOCK, *v.shape[2:]), 1, 0),
+              jnp.moveaxis(mask.reshape(B, Tq, nb, KEY_BLOCK), 2, 0))
+    qf = q.astype(jnp.float32)
+
+    def step(carry, blk):
+        kb, vb, seen = blk
+        return online_softmax_step(qf, repeat_kv(kb, n_rep).astype(jnp.float32), repeat_kv(vb, n_rep).astype(jnp.float32),
+                                   seen[:, None], *carry, scale), None
+
+    init = (jnp.full((B, H, Tq), -jnp.inf, jnp.float32), jnp.zeros((B, H, Tq), jnp.float32),
+            jnp.zeros((B, H, Tq, v.shape[-1]), jnp.float32))
+    (_m, l, acc), _ = jax.lax.scan(step, init, blocks)
+    return online_softmax_finalize(l, acc, q.dtype)
 
 
 def write_kv(

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..models.llama import LlamaConfig, _attn_mlp, _embed, _final_norm_w, _head_logits
+from ..models.llama import LlamaConfig, attn_mlp as _attn_mlp, embed as _embed, final_norm_w as _final_norm_w, head_logits as _head_logits
 from ..ops.attention import causal_attention
 from ..ops.norms import rms_norm
 from .mesh import param_specs

@@ -1,6 +1,6 @@
 """The barrier between the q / k projections and their split to heads
-(`models/llama.py _attn_mlp`) is the identity on values, and it is in the
-programs of the families that run `_attn_mlp` and in no other family's.
+(`models/llama.py attn_mlp`) is the identity on values, and it is in the
+programs of the families that run `attn_mlp` and in no other family's.
 
 What it does to the chip's program (no layer's `wq` / `wk` staged in fast
 memory, no stack copied) is held by `test_chip_compile.py`; here, on the
@@ -46,7 +46,7 @@ def _without_barrier(monkeypatch):
 
 
 def _block(c, int8: bool):
-    """One layer's `_attn_mlp` jitted afresh -> (out, q, k, v), and its operands."""
+    """One layer's `attn_mlp` jitted afresh -> (out, q, k, v), and its operands."""
     params = llama.init_params(c, jax.random.key(3))
     keys = iter(jax.random.split(jax.random.key(4), 8))
     for name in ("bq", "bk", "bv"):  # drawn, not the zeros a fresh model starts with
@@ -67,7 +67,7 @@ def _block(c, int8: bool):
             seen["q"] = q
             return causal_attention(q, k, v, positions)
 
-        out, k, v = llama._attn_mlp(x, layer, c, positions, attn)
+        out, k, v = llama.attn_mlp(x, layer, c, positions, attn)
         return out, seen["q"], k, v
 
     return block, (x, layer, positions)
@@ -131,12 +131,12 @@ def _lowered_decode_step(name: str, preset: str) -> str:
 @pytest.mark.parametrize("family", FAMILIES, ids=lambda f: f[0])
 def test_a_family_with_its_own_attn_qkv_lowers_to_the_same_decode_step(family, monkeypatch):
     """`lfm2`, `jamba`, `mellum` and `kanana` project q and k in bodies of
-    their own: they name nothing of `_attn_mlp`, their decode step holds no
+    their own: they name nothing of `attn_mlp`, their decode step holds no
     barrier, and its lowered text is the same with `optimization_barrier`
     taken out of jax altogether."""
     name, preset = family
     model = importlib.import_module(f"agentcontrolplane_tpu.models.{name}")
-    assert not hasattr(model, "_attn_mlp") and not hasattr(model, "llama")
+    assert not hasattr(model, "attn_mlp") and not hasattr(model, "llama")
     text = _lowered_decode_step(name, preset)
     assert "optimization_barrier" not in text
     _without_barrier(monkeypatch)
@@ -145,5 +145,5 @@ def test_a_family_with_its_own_attn_qkv_lowers_to_the_same_decode_step(family, m
 
 def test_the_families_that_run_attn_mlp_do_hold_the_barrier():
     """The control of the case above: `ouro`'s decode step, which runs
-    `_attn_mlp`, lowers WITH the barrier, so that comparison can see one."""
+    `attn_mlp`, lowers WITH the barrier, so that comparison can see one."""
     assert "optimization_barrier" in _lowered_decode_step("ouro", "ouro-tiny")

@@ -518,7 +518,7 @@ def _projection_stacks(c, tp: int = 1) -> list:
 
 @pytest.mark.parametrize("case", _BLOCKS + [("ouro-2.6b-v5e1",)], ids=lambda c: c[0])
 def test_no_decode_block_stages_a_layers_wq_or_wk_in_fast_memory(v5e, case):
-    """The q and k products are plain matmuls: `_attn_mlp` keeps q and k as
+    """The q and k products are plain matmuls: `attn_mlp` keeps q and k as
     `[B, T, heads * head_dim]` behind one `optimization_barrier` before the
     split to heads, so the compiler cannot fold the split into the product
     and has no reason to want the weight heads-major. Held here for the
@@ -690,9 +690,9 @@ def _lfm2(v5e, monkeypatch):
     layer steered onto its kernel."""
     import functools
 
-    from agentcontrolplane_tpu.models import lfm2
+    from agentcontrolplane_tpu.models import experts, lfm2
 
-    monkeypatch.setattr(lfm2, "routed_experts", functools.partial(lfm2.routed_experts, kernel=True))
+    monkeypatch.setattr(experts, "routed_experts", functools.partial(experts.routed_experts, kernel=True))
     c = lfm2.PRESETS["lfm2-24b-a2b-ep8"]
     one_chip = SingleDeviceSharding(v5e[0])
     place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
@@ -1034,9 +1034,9 @@ def _mellum(v5e, monkeypatch):
     steered onto its kernel."""
     import functools
 
-    from agentcontrolplane_tpu.models import mellum
+    from agentcontrolplane_tpu.models import experts, mellum
 
-    monkeypatch.setattr(mellum, "routed_experts", functools.partial(mellum.routed_experts, kernel=True))
+    monkeypatch.setattr(experts, "routed_experts", functools.partial(experts.routed_experts, kernel=True))
     c = mellum.PRESETS["mellum2-12b-a2.5b-ep4"]
     one_chip = SingleDeviceSharding(v5e[0])
     place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
@@ -1119,9 +1119,9 @@ def _kanana(v5e, monkeypatch):
     layer steered onto its kernel."""
     import functools
 
-    from agentcontrolplane_tpu.models import kanana
+    from agentcontrolplane_tpu.models import experts, kanana
 
-    monkeypatch.setattr(kanana, "routed_experts", functools.partial(kanana.routed_experts, kernel=True))
+    monkeypatch.setattr(experts, "routed_experts", functools.partial(experts.routed_experts, kernel=True))
     c = kanana.PRESETS["kanana-2-30b-a3b-ep16"]
     one_chip = SingleDeviceSharding(v5e[0])
     place = lambda tree: jax.tree_util.tree_map(  # noqa: E731

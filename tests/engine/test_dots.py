@@ -1,25 +1,22 @@
-"""dots3-note-prev's language model through the normal path: the program
-against the plain reference (acpbench/families/dots_reference.py, which
-imports nothing of the program) for `forward`, prefill, continuation and
-decode through the pool of three leaves (latent rows and indexer keys on the
-page list, a ring of latent rows a slot), with the choice of rows and
-experts free and with it given; absorbed against expanded attention; a
-context that crosses `topk` and the window's edge inside one run of decode
-steps; the blocked continuation against the plain one; the masked kernel at
-unequal key and value widths; the sixteen shares of an expert layer summing
-to the uncut layer with the shared expert counted once; every `assumed`
-control and every cache control seen by the comparison. The engine serving
-it: `test_dots_engine.py`.
+"""dots3-note-prev's language model through the normal path, the PROGRAMS
+through the caches: the program against the plain reference
+(acpbench/families/dots_reference.py, which imports nothing of the program)
+for `forward`, prefill, continuation and decode through the pool of three
+leaves (latent rows and indexer keys on the page list, a ring of latent rows a
+slot), with the choice of rows and experts free and with it given; a context
+that crosses `topk` and the window's edge inside one run of decode steps; the
+blocked continuation against the plain one; every cache control seen by the
+comparison, and a fault planted in the program's indexer refused. The
+operators and the reference's controls: `test_dots_operators.py` (apart, so
+that each file stays under the 300 s a file of ROADMAP's tier-1 budget). The
+engine serving it: `test_dots_engine.py`.
 
 CPU, tiny sizes (a dense full layer, an expert full layer, three sliding
 layers; 4 heads over a latent of 24, a window of 9 rows at 2 heads over a
 latent of 40, an indexer of 4 heads of 16 that chooses 8 rows, 16 experts
-top-2 of which 2 held), float32, seeded weights. Budget: this file adds ~60 s
-to the tier-1 run (913 s of its 1,470 at PR 60), `test_dots_engine.py` ~30 s,
-`test_dots_compile.py` ~60 s, `tests/acpbench/test_dots_spec.py` ~30 s.
+top-2 of which 2 held), float32, seeded weights.
 """
 
-import dataclasses
 import functools
 
 import jax
@@ -27,38 +24,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from acpbench import check, spec
-from acpbench.families import dots as family_module
+from acpbench import check
 from acpbench.families import dots_reference
-from agentcontrolplane_tpu.models import dots, keye, preset
+from agentcontrolplane_tpu.models import dots, keye
 from agentcontrolplane_tpu.ops import attention, paged
-from agentcontrolplane_tpu.ops.moe import routed_experts
-from agentcontrolplane_tpu.parallel.mesh import make_mesh
 
-FILE = spec.load_json(spec.os.path.join(spec.ROOT, "tests/acpbench/data/tiny-config-dots.json"))
-PAGE = FILE["engine"]["page_size"]
-ONE_CHIP = lambda: make_mesh({"tp": 1}, devices=jax.devices()[:1])  # noqa: E731
-
-
-@functools.lru_cache(maxsize=None)
-def built(seed=5):
-    family = spec.family(FILE)
-    pc = dataclasses.replace(family.program_config(FILE), dtype=jnp.float32)
-    return family, pc, ONE_CHIP(), family.weights(FILE, pc, ONE_CHIP(), seed)
-
-
-def sizes():
-    return family_module._sizes(FILE)
-
-
-def text_tokens(B=2, T=40, seed=1):
-    tokens = np.random.default_rng(seed).integers(0, 256, (B, T)).astype(np.int32)
-    return tokens, np.tile(np.arange(T), (B, 1))
-
-
-def lanes(B):
-    return jnp.arange(B, dtype=jnp.int32), jnp.zeros((B,), jnp.int32)
-
+from ._dots_cases import FILE, PAGE, built, lanes, prefilled, sizes, text_tokens
 
 # -- the program against the plain reference ---------------------------------------------------------
 
@@ -106,23 +77,6 @@ def test_program_agrees_with_the_plain_reference_through_the_pool_and_the_rings(
     assert numbers["prefill_rel_rms"] < 3e-5 and numbers["decode_rel_rms"] < 3e-5, numbers
     assert set(chosen.values()) == {0.0} and "select_cache_miss" in chosen
 
-
-def paged_setup(pc, B, M):
-    cache = dots.init_paged_cache(pc, 1 + B * M, PAGE, max_slots=B)
-    tables = (1 + jnp.arange(B * M, dtype=jnp.int32)).reshape(B, M)
-    return cache, tables
-
-
-def prefilled(pc, params, tokens, lengths, M=8, T=40):
-    B = tokens.shape[0]
-    cache, tables = paged_setup(pc, B, M)
-    lengths = jnp.asarray(lengths, jnp.int32)
-    ids = jnp.where(jnp.arange(T // PAGE)[None] < -(-lengths // PAGE)[:, None], tables[:, : T // PAGE], 0)
-    prompt = jnp.where(jnp.arange(T)[None] < lengths[:, None], jnp.asarray(tokens)[:, :T], 0)
-    cache, logits = dots.prefill_paged_batch(params, cache, prompt, lengths, ids, lanes(B), pc)
-    return cache, tables, lengths, logits
-
-
 @pytest.mark.parametrize("interpret", [False, True], ids=["top_k", "kernel"])
 def test_decode_steps_cross_topk_and_the_windows_edge_in_one_run_and_the_counters_count(interpret):
     """One decode program either side of `topk` (8 rows) and of the window
@@ -164,84 +118,6 @@ def test_decode_steps_cross_topk_and_the_windows_edge_in_one_run_and_the_counter
     assert cache["wkv"].shape == (3, 4 * ring, PAGE, 128)
     assert not np.asarray(cache["kv"])[..., 32:].any() and not np.asarray(cache["wkv"])[..., 48:].any()  # stored on whole tiles
 
-
-def test_absorbed_attention_is_the_expanded_attention():
-    """A full layer's and a sliding layer's decode step (absorbed: the query
-    through `W_UK`, the row as it lies, `W_UV` after the softmax) against the
-    same rows expanded to per-head K and V and attended plainly."""
-    family, pc, mesh, params = built()
-    key = jax.random.key(7)
-    for g, w, window in ((pc.full, jax.tree_util.tree_map(lambda a: a[0], params["full"]), 0),
-                         (pc.swa, jax.tree_util.tree_map(lambda a: a[0], params["swa"]), 9)):
-        S, C = 3, 24
-        rows = jax.random.normal(jax.random.fold_in(key, g.n_heads), (S, C, g.row_stored)).at[..., g.row_width:].set(0.0)
-        q_nope = jax.random.normal(jax.random.fold_in(key, 1), (S, g.n_heads, g.nope))
-        q_pe = jax.random.normal(jax.random.fold_in(key, 2), (S, g.n_heads, g.rope))
-        k, v = dots._expand(rows, w["wuk"], w["wuv"], g)
-        positions = jnp.full((S, 1), C - 1)
-        q = jnp.concatenate([q_nope, q_pe], axis=-1)[:, None]
-        key_pos = jnp.broadcast_to(jnp.arange(C), (S, C))
-        want = attention.continue_attention(q, k, v, positions, key_pos, window=window)[:, 0]
-        q_lat = jnp.einsum("shn,hnc->shc", q_nope, w["wuk"])
-        q_row = jnp.concatenate([q_lat, q_pe, jnp.zeros((S, g.n_heads, g.row_stored - g.row_width))], axis=-1)
-        # the cached rows as one ring a lane: C - 1 rows in pages of 8, the new token's own as the self term
-        ring = (C - 1 + PAGE - 1) // PAGE
-        pool = jnp.zeros((S * ring, PAGE, g.row_stored)).reshape(S, ring * PAGE, -1).at[:, : C - 1].set(rows[:, : C - 1])
-        pool = pool.reshape(S * ring, PAGE, g.row_stored)
-        ids = jnp.arange(S * ring).reshape(S, ring)
-        seq_lens = jnp.full((S,), C - 1)
-        row_positions = jnp.broadcast_to(jnp.arange(ring * PAGE), (S, ring * PAGE))
-        first = jnp.maximum(seq_lens + 1 - window, 0) if window else jnp.zeros((S,), jnp.int32)
-        o_lat = paged.ring_latent_decode_attention_cache_plus_new(q_row, pool, ids, seq_lens, rows[:, C - 1], g.kv_rank,
-                                                                  g.qk_head_dim, row_positions, first)
-        got = jnp.einsum("shc,hcv->shv", o_lat, w["wuv"])
-        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("interpret", [False, True], ids=["top_k", "kernel"])
-def test_the_sparse_latent_step_attends_over_the_given_rows_alone_and_takes_the_new_row_from_its_argument(interpret):
-    """`ops.paged.sparse_latent_decode_attention_cache_plus_new` given a list
-    of positions (the new token's own among them) against a masked dense
-    softmax over the same latent rows; free, its choice is `top_k` of the
-    index scores with the new row's score in its place, as a set: found by
-    `top_k` off the TPU and by the kernel on it (interpreted here)."""
-    rng = np.random.default_rng(3)
-    S, H, W, V, M, topk = 2, 3, 128, 96, 4, 6
-    C = M * PAGE
-    pool = {"kv": jnp.asarray(rng.normal(size=(1 + S * M, PAGE, W)), jnp.float32),
-            "ik": jnp.asarray(rng.normal(size=(1 + S * M, PAGE, 16)), jnp.float32)}
-    tables = (1 + jnp.arange(S * M, dtype=jnp.int32)).reshape(S, M)
-    seq_lens = jnp.asarray([19, 27], jnp.int32)
-    new = {"kv": jnp.asarray(rng.normal(size=(S, W)), jnp.float32), "ik": jnp.asarray(rng.normal(size=(S, 16)), jnp.float32)}
-    q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
-    qi, wi = jnp.asarray(rng.normal(size=(S, 4, 16)), jnp.float32), jnp.asarray(rng.normal(size=(S, 4)), jnp.float32)
-    out, chosen, tied = paged.sparse_latent_decode_attention_cache_plus_new(q, pool, tables, seq_lens, new, qi, wi, topk, V, 64,
-                                                                            interpret=interpret)
-    assert not np.asarray(tied).any()
-    rows = pool["kv"][tables].reshape(S, C, W)
-    keys = pool["ik"][tables].reshape(S, C, 16)
-    for b in range(S):
-        n = int(seq_lens[b])
-        ctx = jnp.concatenate([rows[b, :n], new["kv"][b][None]])
-        scores = attention.index_scores(qi[b][None, None], wi[b][None, None], jnp.concatenate([keys[b, :n], new["ik"][b][None]])[None])[0, 0]
-        want_rows = sorted(np.asarray(jax.lax.top_k(scores, topk)[1]).tolist())
-        assert sorted(np.asarray(chosen[b]).tolist()) == want_rows
-        seen = np.zeros(n + 1, bool)
-        seen[want_rows] = True
-        logits = jnp.where(seen[None], q[b] @ ctx.T * 64 ** -0.5, -jnp.inf)
-        np.testing.assert_allclose(out[b], jax.nn.softmax(logits, axis=-1) @ ctx[:, :V], atol=2e-5, rtol=2e-5)
-    given = jnp.asarray([[0, 5, 19, -1, -1, -1], [27, 3, 2, 1, -1, -1]], jnp.int32)
-    out, told, _tied = paged.sparse_latent_decode_attention_cache_plus_new(q, pool, tables, seq_lens, new, qi, wi, topk, V, 64,
-                                                                           given, interpret)
-    assert np.array_equal(told, given)
-    for b in range(S):
-        n = int(seq_lens[b])
-        ctx = jnp.concatenate([rows[b, :n], new["kv"][b][None]])
-        picked = ctx[np.asarray(given[b])[np.asarray(given[b]) >= 0]]
-        np.testing.assert_allclose(out[b], jax.nn.softmax(q[b] @ picked.T * 64 ** -0.5, axis=-1) @ picked[:, :V],
-                                   atol=2e-5, rtol=2e-5)
-
-
 @pytest.mark.parametrize("block", [8, 16], ids=["blocks-of-8", "blocks-of-16"])
 def test_a_continuation_in_blocks_of_query_rows_is_the_whole_one_and_reads_rows_it_did_not_write(monkeypatch, block):
     """A prompt prefilled to a page-aligned cut and continued (the engine's
@@ -253,7 +129,7 @@ def test_a_continuation_in_blocks_of_query_rows_is_the_whole_one_and_reads_rows_
     reads both parts' rows through pages and ring."""
     family, pc, mesh, params = built()
     monkeypatch.setattr(dots, "CONTINUE_BLOCK", block)
-    monkeypatch.setattr(keye, "KEY_BLOCK", 32)
+    monkeypatch.setattr(attention, "KEY_BLOCK", 32)
     tokens, _ = text_tokens(B=2, T=64, seed=4)
     full = dots.forward(params, jnp.asarray(tokens), pc)
     cut, lengths = jnp.asarray([16, 24], jnp.int32), jnp.asarray([47, 52], jnp.int32)
@@ -269,105 +145,7 @@ def test_a_continuation_in_blocks_of_query_rows_is_the_whole_one_and_reads_rows_
                                            jnp.ones((2,), bool), pc)
     np.testing.assert_allclose(logits, full[jnp.arange(2), lengths], atol=5e-5, rtol=5e-5)
 
-
-def test_the_masked_kernel_takes_a_key_width_and_a_value_width_and_a_prefill_through_it_is_the_prefill_through_xla():
-    """`ops/pallas/masked_attention.py` interpreted at keys of 256 (192
-    values and 64 zeros) beside values of 128, the scale the 192's, against
-    the masked dense softmax; `serves` states the widths; and the family's
-    prefill through the kernel (head groups, keys padded to a lane tile: the
-    tiny widths 24 -> 128) is its prefill through `causal_attention`."""
-    from agentcontrolplane_tpu.ops.pallas import masked_attention as ma
-
-    rng = np.random.default_rng(5)
-    T, H = 512, 2
-    q = jnp.asarray(rng.normal(size=(T, H, 192)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(T, H, 192)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(T, H, 128)), jnp.float32)
-    t = np.arange(T)
-    mask = jnp.asarray((t[None, :] <= t[:, None]) & (rng.random((T, T)) < 0.3) | (t[None, :] == t[:, None]))
-    widen = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 64)))  # noqa: E731
-    got = ma.masked_attention(widen(q), widen(k), v, mask.astype(jnp.int8), scale=192 ** -0.5, interpret=True)
-    want = attention.causal_attention(q[None], k[None], v[None], keep=mask[None])[0]
-    assert got.shape == (T, H, 128)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-    assert ma.serves(512, 256, 128) and ma.serves(512, 128, 128)
-    assert not ma.serves(512, 192, 128) and not ma.serves(512, 256, 64) and not ma.serves(300, 256, 128)
-    with pytest.raises(ValueError, match="widths of whole lane tiles"):
-        ma.masked_attention(q, k, v, mask.astype(jnp.int8), interpret=True)
-    family, pc, mesh, params = built()
-    tokens, _ = text_tokens(B=1, T=512, seed=6)
-    plain, (chose, _routed) = dots.forward(params, jnp.asarray(tokens), pc, tell=True, rows=jnp.asarray([[40, 300, 511]]))
-    kernel = dots.forward(params, jnp.asarray(tokens), pc, interpret=True, rows=jnp.asarray([[40, 300, 511]]))
-    np.testing.assert_allclose(kernel, plain, atol=1e-4, rtol=1e-4)
-    assert chose.shape == (2, 1, 512, 64)
-
-
-def test_the_sixteen_shares_of_an_expert_layer_and_one_shared_expert_sum_to_the_uncut_layer():
-    """A layer's FF summed over sixteen chips' routed shares (each told which
-    16 of 256 it holds, each routing over all 256 by the sigmoid and the
-    bias, top 8 renormalised) plus the shared expert ONCE is the uncut
-    reference's layer; nothing stands in for the absent chips in a share."""
-    N, D, F, E, k = 24, 64, 32, 256, 8
-    keys = jax.random.split(jax.random.key(3), 9)
-    x = jax.random.normal(keys[0], (N, D))
-    layer = {"router": jax.random.normal(keys[1], (D, E)) * D ** -0.5,
-             "router_bias": 0.03 * jax.random.normal(keys[2], (E,)),
-             "w1": jax.random.normal(keys[3], (E, D, F)) * D ** -0.5,
-             "w3": jax.random.normal(keys[4], (E, D, F)) * D ** -0.5,
-             "w2": jax.random.normal(keys[5], (E, F, D)) * F ** -0.5,
-             "sw1": jax.random.normal(keys[6], (D, F)) * D ** -0.5, "sw3": jax.random.normal(keys[7], (D, F)) * D ** -0.5,
-             "sw2": jax.random.normal(keys[8], (F, D)) * F ** -0.5}
-    model = {"experts_per_token": k, "held": tuple(range(E)), "norm_topk_prob": True, "routed_scaling_factor": 1.0}
-    whole = dots_reference._experts(x[None], layer, model, None)[0][0]
-    shared = dots_reference._experts(x[None], layer, model, None, routed=False)[0][0]
-    total, landed = shared, 0
-    for share in range(16):
-        held = tuple(range(16 * share, 16 * share + 16))
-        ids = np.array(held)
-        y, counts = routed_experts(x, layer["router"], layer["w1"][ids], layer["w3"][ids], layer["w2"][ids], k, held=held,
-                                   score="sigmoid", bias=layer["router_bias"], renormalize=True, interpret=share % 8 == 0)
-        total, landed = total + y, landed + int(counts[1])
-        assert float(jnp.abs(y + shared - whole).max()) > 0.01  # a share is not the layer
-    assert landed == N * k  # every (token, choice) pair landed on exactly one share
-    np.testing.assert_allclose(total, whole, atol=5e-5)
-    # and through the program's own expert layer: one chip's share is its routed part plus the shared expert
-    family, pc, mesh, params = built()
-    h = jax.random.normal(keys[0], (1, 12, pc.dim))
-    e = 1
-    mine = jax.tree_util.tree_map(lambda a: a[e], {n: params["ff"][n] for n in ("ln2", "router", "router_bias", "sw1", "sw3", "sw2")})
-    stacks = tuple(params["ff"][n].reshape((-1,) + params["ff"][n].shape[2:]) for n in ("w1", "w3", "w2"))
-    y, _counts = dots._experts(h, mine, stacks, jnp.int32(e), pc, jnp.ones((1, 12), bool))
-    np.testing.assert_allclose(y, dots_reference.layer_output(params, sizes(), e, h), atol=5e-5, rtol=5e-5)
-
-
-# -- the controls ----------------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("control,least", [
-    ("int8", 5e-3), ("gate_off", 0.1), ("rescale_off", 0.1), ("index_norm_off", 0.02), ("index_rope_off", 0.02),
-    ("recent", 0.05), ("dense", 0.05), ("window_off", 0.05), ("shared_off", 0.1), ("bf16_free", 1e-4),
-])
-def test_each_reference_control_moves_the_logits(control, least):
-    family, pc, mesh, params = built()
-    s = check.sample(FILE["check"], FILE["vocab_size"], PAGE, 3)
-    want = dots_reference.logits(params, sizes(), s["tokens"], s["rows"])
-    moved = check.compare(family.reference_logits(FILE, params, s["tokens"], s["rows"], lower=control), want)
-    assert moved["logit_rel_rms"] > least, (control, moved["logit_rel_rms"])
-
-
-def test_an_unknown_control_is_an_error_and_the_family_documents_its_own():
-    family, pc, mesh, params = built()
-    with pytest.raises(ValueError, match="no control 'fp4'"):
-        family.reference_logits(FILE, params, [[0]], [[0]], lower="fp4")
-    for name in dots_reference.CONTROLS:
-        assert f'"{name}"' in family_module.__doc__ + dots_reference.__doc__, name
-    big, tiny = preset("dots3-note-prev"), preset("dots-tiny")
-    assert (big.index_topk, big.sliding_window_size, big.window, big.n_full, big.n_sliding) == (2048, 513, 528, 13, 33)
-    assert (big.full.row_width, big.full.row_stored, big.swa.row_width, big.swa.row_stored) == (576, 640, 1088, 1152)
-    assert (round(big.full.a_q, 2), round(big.full.a_kv, 2), round(big.swa.a_kv, 2)) == (2.24, 3.16, 2.24)
-    assert (tiny.index_topk, tiny.sliding_window_size, tiny.n_full, tiny.n_sliding) == (8, 9, 2, 3)
-    with pytest.raises(ValueError, match="leading dense layers are full_attention"):
-        dots.layer_kinds(dataclasses.replace(tiny, layer_types=("sliding_attention",) + tiny.layer_types[1:]))
+# -- the cache's controls --------------------------------------------------------------------------
 
 
 @functools.lru_cache(maxsize=None)

@@ -23,10 +23,10 @@ def _dots(v5e, monkeypatch):
     import dataclasses
     import functools
 
-    from agentcontrolplane_tpu.models import dots, kanana
+    from agentcontrolplane_tpu.models import dots, experts
 
-    # the expert layer is `kanana._experts` by import: steered there
-    monkeypatch.setattr(kanana, "routed_experts", functools.partial(kanana.routed_experts, kernel=True))
+    # the expert layer is `experts.routed_ff`: steered there
+    monkeypatch.setattr(experts, "routed_experts", functools.partial(experts.routed_experts, kernel=True))
     c = dataclasses.replace(dots.PRESETS["dots3-note-prev"], layer_types=dots._pattern(5), vocab_size=19008,
                             experts_held=tuple(range(16)))
     one_chip = SingleDeviceSharding(v5e[0])

@@ -23,7 +23,7 @@ import pytest
 from acpbench import check, spec
 from acpbench.families import exaone_reference
 from acpbench.families.exaone import forced_sampler
-from agentcontrolplane_tpu.models import exaone, preset, programs
+from agentcontrolplane_tpu.models import exaone, experts, preset, programs
 from agentcontrolplane_tpu.ops import paged
 from agentcontrolplane_tpu.ops.sampling import masked_logits, speculative_sample
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
@@ -255,7 +255,7 @@ def test_the_eight_shares_of_a_layers_experts_add_up_to_the_uncut_layer():
     for held in [whole_held] + [(2 * i, 2 * i + 1) for i in range(8)]:
         c = dataclasses.replace(pc, experts_held=held)
         mine = tuple(ff[name][jnp.asarray(held)] for name in ("w1", "w3", "w2"))
-        y, _ = exaone._experts(x, ff, mine, 0, c, jnp.ones((2, 24), bool))
+        y, _ = experts.routed_ff(x, ff, mine, 0, c, jnp.ones((2, 24), bool), score="sigmoid", bias=True, scale=c.routed_scaling_factor, chunk=True, shared=True)
         if len(held) == 16:
             whole = y
             continue

@@ -25,9 +25,9 @@ def _exaone(v5e, monkeypatch):
     import dataclasses
     import functools
 
-    from agentcontrolplane_tpu.models import exaone, kanana
+    from agentcontrolplane_tpu.models import exaone, experts
 
-    monkeypatch.setattr(kanana, "routed_experts", functools.partial(kanana.routed_experts, kernel=True))
+    monkeypatch.setattr(experts, "routed_experts", functools.partial(experts.routed_experts, kernel=True))
     c = dataclasses.replace(exaone.PRESETS["k-exaone-236b-a23b"], layer_types=exaone._pattern(5), vocab_size=19200,
                             experts_held=tuple(range(16)))
     one_chip = SingleDeviceSharding(v5e[0])

@@ -235,11 +235,11 @@ LAYOUTS = {
 
 @pytest.mark.parametrize("name", list(LAYOUTS))
 def test_any_layer_pattern_serves_what_forward_computes(name):
-    """The pattern's layout for a decode step (`models/lfm2.py segments`);
+    """The pattern's layout for a decode step (`models/stack.py segments`);
     prefill and continuation (through the one body that switches on the
     kind) and decode steps through that layout give `forward`'s logits and
     the K, V, `h` and conv columns of one prefill."""
-    from agentcontrolplane_tpu.models.lfm2 import segments
+    from agentcontrolplane_tpu.models.stack import segments
 
     from .test_lfm2 import serves_what_forward_computes
 

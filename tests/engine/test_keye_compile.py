@@ -27,10 +27,10 @@ def _keye(v5e, monkeypatch):
     import dataclasses
     import functools
 
-    from agentcontrolplane_tpu.models import keye, mellum
+    from agentcontrolplane_tpu.models import experts, keye
 
-    # the expert layer is `mellum._experts` by import: steered there
-    monkeypatch.setattr(mellum, "routed_experts", functools.partial(mellum.routed_experts, kernel=True))
+    # the expert layer is `experts.routed_ff`: steered there
+    monkeypatch.setattr(experts, "routed_experts", functools.partial(experts.routed_experts, kernel=True))
     c = dataclasses.replace(keye.PRESETS["keye-vl-2.0-30b-a3b"], n_layers=8, vocab_size=18992,
                             experts_held=tuple(range(16)))
     one_chip = SingleDeviceSharding(v5e[0])
@@ -117,7 +117,7 @@ def test_keye_prefill_compiles_under_the_engines_own_sampler_with_one_mask_body_
     it fits beside the resident set (the `[T, T]` mask of a layer, 604 MB at
     24,576 rows, and a block's float32 scores are its temporaries), holds
     the attention kernel once (one layer body), and holds the mask's
-    threshold search once a TIER of 4,096 rows (`keye._prompt_mask`: a
+    threshold search once a TIER of 4,096 rows (`keye.prompt_mask`: a
     `lax.map` over a tier's blocks), not once a block of query rows: the
     unrolled blocks were 25.8 s of this sandbox's compiler at 24,576 rows
     where the tiers are 13.4 (PERF.md, PR 59)."""

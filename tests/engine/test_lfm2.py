@@ -20,7 +20,7 @@ import pytest
 
 from acpbench import check, spec
 from acpbench.families import lfm2_reference, lfm2_weights
-from agentcontrolplane_tpu.models import lfm2, preset
+from agentcontrolplane_tpu.models import lfm2, preset, stack
 from agentcontrolplane_tpu.ops.moe import moe_ffn_reference, route_scores, route_topk, routed_experts
 from agentcontrolplane_tpu.parallel.mesh import make_mesh
 from agentcontrolplane_tpu.testing import compiled
@@ -117,7 +117,7 @@ def test_any_layer_pattern_serves_what_forward_computes(name):
     one body that switches on the kind) and decode steps through that layout
     give `forward`'s logits and one prefill's cache."""
     kinds, dense, layout = LAYOUTS[name]
-    assert lfm2.segments(tuple(kinds[dense:])) == layout
+    assert stack.segments(tuple(kinds[dense:])) == layout
     cfg = dataclasses.replace(preset("lfm2-tiny"), layer_types=tuple(kinds), num_dense_layers=dense)
     serves_what_forward_computes(lfm2, cfg, ("conv",))
 

@@ -26,7 +26,7 @@ from acpbench.families import mellum_reference
 from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
 from agentcontrolplane_tpu.engine.invariants import verify_engine
 from agentcontrolplane_tpu.engine.tokenizer import ByteTokenizer
-from agentcontrolplane_tpu.models import mellum, preset, programs
+from agentcontrolplane_tpu.models import experts, mellum, preset, programs
 from agentcontrolplane_tpu.ops import paged
 from agentcontrolplane_tpu.ops.pallas.paged_attention import paged_decode_attention_cache_plus_new
 from agentcontrolplane_tpu.ops.rope import apply_rope, rope_frequencies, yarn_correction_range, yarn_scale_frequencies
@@ -249,7 +249,7 @@ def test_the_four_shares_of_a_layers_experts_add_up_to_the_uncut_layer():
     for held in [tuple(range(8))] + [(2 * i, 2 * i + 1) for i in range(4)]:
         c = dataclasses.replace(pc, experts_held=held)
         mine = {name: ff[name][jnp.asarray(held)] for name in ("w1", "w3", "w2")}
-        y, _ = mellum._experts(x, ff, tuple(mine[n] for n in ("w1", "w3", "w2")), 0, c, jnp.ones((2, 24), bool))
+        y, _ = experts.routed_ff(x, ff, tuple(mine[n] for n in ("w1", "w3", "w2")), 0, c, jnp.ones((2, 24), bool), chunk=True)
         if len(held) == 8:
             whole = y
             continue
@@ -281,7 +281,7 @@ def test_a_long_prefills_experts_run_a_chunk_of_tokens_at_a_time(monkeypatch):
         return logits, np.asarray(mellum.counters(cache))[1]
 
     whole = [prefill(None), prefill(route)]
-    monkeypatch.setattr(mellum, "MOE_CHUNK", 32)
+    monkeypatch.setattr(experts, "MOE_CHUNK", 32)
     for (want, counts), given in zip(whole, (None, route)):
         got, chunked = prefill(given)
         np.testing.assert_allclose(got, want, atol=2e-5)

@@ -143,7 +143,7 @@ def test_expert_parallel_forward_matches_replicated_no_weight_allgather():
 
 def test_moe_serves_through_the_engine():
     """The MoE family drops into the serving engine unchanged (the MLP swap
-    lives inside _attn_mlp): greedy generation, both KV layouts, identical
+    lives inside attn_mlp): greedy generation, both KV layouts, identical
     tokens."""
     from agentcontrolplane_tpu.engine.engine import Engine, SamplingParams
     from agentcontrolplane_tpu.engine.tokenizer import ByteTokenizer

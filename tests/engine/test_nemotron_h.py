@@ -355,7 +355,7 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
 def test_the_seam_finds_the_family_by_the_configs_type_and_the_presets_are_the_issues():
     from agentcontrolplane_tpu import models
     from agentcontrolplane_tpu.engine.engine import Engine
-    from agentcontrolplane_tpu.models.lfm2 import segments
+    from agentcontrolplane_tpu.models.stack import segments
 
     cfg = preset("nemotron-h-tiny")
     assert programs(cfg) is models._NEMOTRON_H and programs(cfg).has_state and programs(cfg).family == "nemotron_h"

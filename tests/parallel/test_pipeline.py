@@ -161,7 +161,7 @@ def test_stage_apply_and_head_match_plain_forward_with_softcaps():
     reproduce the plain forward exactly. Before the fix, _stage_apply
     dropped the attention cap and pipeline_forward skipped the final cap —
     silently training a different model than configured."""
-    from agentcontrolplane_tpu.models.llama import _embed, _final_norm_w, _head_logits
+    from agentcontrolplane_tpu.models.llama import embed, final_norm_w, head_logits
     from agentcontrolplane_tpu.ops.norms import rms_norm
     from agentcontrolplane_tpu.parallel.pipeline import _stage_apply
 
@@ -172,10 +172,10 @@ def test_stage_apply_and_head_match_plain_forward_with_softcaps():
         dtype=jnp.int32,
     )
     positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
-    x = _embed(params, tokens, c)
+    x = embed(params, tokens, c)
     x = _stage_apply(params["layers"], x, positions, c)
-    x = rms_norm(x, _final_norm_w(params, c), c.norm_eps)
-    logits = _head_logits(x, params, c)
+    x = rms_norm(x, final_norm_w(params, c), c.norm_eps)
+    logits = head_logits(x, params, c)
     ref = forward(params, tokens, c)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref), rtol=2e-4, atol=2e-4)
     # and the caps genuinely bite on this config (the comparison above is
